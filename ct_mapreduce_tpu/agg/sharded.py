@@ -37,11 +37,11 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ct_mapreduce_tpu.core import packing
 from ct_mapreduce_tpu.ops import buckettable, hashtable, pipeline
-from ct_mapreduce_tpu.utils.jax_compat import shard_map
 
 AXIS = "shard"
 

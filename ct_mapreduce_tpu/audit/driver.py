@@ -179,7 +179,7 @@ class AuditDriver:
                 dec = leaflib.decode_json_entry(start + i, e)
             except leaflib.LeafDecodeError:
                 # Undecodable entries still go to the sink — its native
-                # decoder owns the error taxonomy; the pre-pass only
+                # decoder owns the error classes; the pre-pass only
                 # tracks that it had nothing to route.
                 ana.decode_failed += 1
                 ana.keep.append((e["leaf_input"],

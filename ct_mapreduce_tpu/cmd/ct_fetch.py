@@ -90,6 +90,27 @@ class ProgressPrinter:
         print(file=sys.stderr)
 
 
+def announce_device() -> bool:
+    """``backend = tpu`` start-up: say which device JAX gave this
+    process (platform, kind, count). False when that is JAX's own CPU
+    fallback — a run that was meant for the chip must not finish on the
+    host with exit code 0. ``JAX_PLATFORMS`` naming ``cpu`` is how a
+    CPU run is asked for on purpose."""
+    import jax
+
+    devices = jax.devices()
+    platform = devices[0].platform
+    print(f"device: platform={platform} kind={devices[0].device_kind} "
+          f"count={len(devices)}", file=sys.stderr)
+    asked = os.environ.get("JAX_PLATFORMS", "").lower().split(",")
+    if platform == "cpu" and "cpu" not in asked:
+        print("error: backend = tpu, but JAX found no accelerator and "
+              "fell back to the CPU (set JAX_PLATFORMS=cpu to run there "
+              "on purpose)", file=sys.stderr)
+        return False
+    return True
+
+
 def build_sink(config: CTConfig, database, backend=None):
     """Pick the store path: per-entry host store (reference parity) or
     the batched device pipeline (single-chip or mesh-sharded per
@@ -197,6 +218,12 @@ def main(argv: list[str] | None = None) -> int:
         print(f"error: workerId {fleet_worker_id} outside "
               f"[0, numWorkers={num_workers})", file=sys.stderr)
         return 2
+    if config.backend == "tpu":
+        from ct_mapreduce_tpu.utils import compile_cache
+
+        compile_cache.configure()  # before the first trace
+        if not announce_device():
+            return 2
     base_state_path = config.agg_state_path
     config.agg_state_path = worker_state_path(
         config.agg_state_path, fleet_worker_id, num_workers)

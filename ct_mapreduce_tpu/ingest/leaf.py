@@ -152,7 +152,7 @@ def decode_entry(
 def decode_json_entry(index: int, obj: dict) -> DecodedEntry:
     """Decode one element of a get-entries JSON response. Base64 is
     validated strictly — bad encodings raise :class:`LeafDecodeError`
-    (same taxonomy as structural decode failures), keeping this path,
+    (same error class as structural decode failures), keeping this path,
     the Python batch fallback, and the native decoder in agreement."""
     try:
         li = base64.b64decode(obj["leaf_input"], validate=True)

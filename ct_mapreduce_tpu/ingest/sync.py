@@ -407,9 +407,8 @@ class AggregatorSink:
                 "ingest", "decode_ns_per_entry",
                 value=(time.monotonic() - t_dec) / len(lis) * 1e9)
         # When the batch decoded wide but every cert fits half the
-        # pad, ship the narrow view — H2D bytes halve (the dominant
-        # cost on tunneled links), at the price of one extra compiled
-        # step variant.
+        # pad, ship the narrow view — H2D bytes halve, at the price
+        # of one extra compiled step variant.
         data = dec.data
         if (narrow >= 512 and data.shape[1] > narrow
                 and dec.length.max(initial=0) <= narrow):

@@ -199,7 +199,7 @@ uint64_t fnv1a(const uint8_t* p, int64_t n) {
 
 extern "C" {
 
-// Status codes per entry (mirrors ingest/leaf.py error taxonomy).
+// Status codes per entry (mirrors ingest/leaf.py error classes).
 enum {
   CTMR_OK = 0,
   CTMR_BAD_B64 = 1,

@@ -164,10 +164,9 @@ def _pallas_enabled(batch: int) -> bool:
 
     if os.environ.get("CTMR_PALLAS", "1") != "1":
         return False
-    try:
-        if jax.default_backend() != "tpu":
-            return False
-    except RuntimeError:
+    # A backend that cannot initialise raises here, on purpose: that
+    # is an error, not a reason to pick the XLA scan in silence.
+    if jax.default_backend() != "tpu":
         return False
     from ct_mapreduce_tpu.ops import pallas_sha256
 

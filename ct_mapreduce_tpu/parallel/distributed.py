@@ -127,10 +127,9 @@ def device_barrier(tag: str = "barrier") -> None:
     psum over all devices forces a synchronizing collective."""
     import jax
     import jax.numpy as jnp
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     import numpy as np
-
-    from ct_mapreduce_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     devices = np.asarray(jax.devices())
     mesh = Mesh(devices, ("all",))

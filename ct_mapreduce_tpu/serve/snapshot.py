@@ -60,6 +60,15 @@ from ct_mapreduce_tpu.telemetry.metrics import (
 )
 
 
+def _on_tpu() -> bool:
+    """Whether device serving runs on a TPU backend — where a device
+    copy that cannot land or answer is an error, never a quiet switch
+    to the host mirror."""
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
 class TableView:
     """One immutable epoch of aggregator state, query-ready.
 
@@ -130,9 +139,12 @@ class TableView:
         its own device — a replica never holds the full global rows on
         any one chip — wrapped as a ready probe state (rows + count on
         the SAME device, so the jitted kernel runs without cross-device
-        transfers). Any failure to pin (no device, OOM, backend down)
-        flips the view to the host-numpy mirror permanently — the next
-        epoch's capture retries the device path."""
+        transfers). On a TPU backend a copy that cannot land is an
+        error and propagates: the host mirror is what ``serveDevice =
+        false`` selects, not something a chip run slides into. On other
+        backends a failure to pin flips the view to the host-numpy
+        mirror permanently — the next epoch's capture retries the
+        device path."""
         if not self._device:
             return self
         try:
@@ -158,6 +170,8 @@ class TableView:
             else:
                 self._dev_rows = jnp.asarray(self.rows)
         except Exception:
+            if _on_tpu():
+                raise
             incr_counter("serve", "device_fallback")
             self._device = False
             self._dev_rows = None
@@ -219,9 +233,11 @@ class TableView:
                             lanes=int(fps.shape[0])):
                 return self._contains_device_pinned(fps)
         except Exception:
-            # A pinned copy that stops answering (device reset, backend
+            # Off the TPU, a pinned copy that stops answering (backend
             # teardown mid-run) degrades to the host mirror instead of
             # failing the batch; the next epoch retries the device.
+            if _on_tpu():
+                raise
             incr_counter("serve", "device_fallback")
             self._device = False
             self._dev_rows = None
@@ -513,12 +529,9 @@ class ReplicaPool:
 
     def _resolve_devices(self) -> Optional[list]:
         if self._devices is None and self._device:
-            try:
-                import jax
+            import jax
 
-                self._devices = list(jax.devices())
-            except Exception:
-                self._devices = []
+            self._devices = list(jax.devices())
         return self._devices or None
 
     def _capture(self) -> TableView:

@@ -461,7 +461,7 @@ def fleet_health(payloads: dict, num_workers: int,
     now = time.time() if now is None else now
     workers: dict = {}
     degraded: list = []
-    epochs: list = []
+    epoch_of: dict = {}
     leaders = 0
     for wid, doc in sorted(payloads.items()):
         fleet = doc.get("fleet", {}) or {}
@@ -478,7 +478,7 @@ def fleet_health(payloads: dict, num_workers: int,
         if entry["role"] == "leader":
             leaders += 1
         if entry["epoch"] is not None:
-            epochs.append(int(entry["epoch"]))
+            epoch_of[wid] = int(entry["epoch"])
         if age > liveness_timeout_s:
             degraded.append(f"worker {wid} stale ({age:.1f}s)")
         for reason in entry["slo_degraded"]:
@@ -486,9 +486,14 @@ def fleet_health(payloads: dict, num_workers: int,
     missing = sorted(set(range(num_workers)) - set(payloads))
     for wid in missing:
         degraded.append(f"worker {wid} not reporting")
+    epochs = epoch_of.values()
     epoch_skew = (max(epochs) - min(epochs)) if epochs else 0
     if epoch_skew > 1:
-        degraded.append(f"leader-epoch skew {epoch_skew}")
+        # Name who lags: a reason that blames nobody sends the operator
+        # through every worker's page.
+        behind = ", ".join(f"worker {wid}" for wid, e in sorted(
+            epoch_of.items()) if e == min(epochs))
+        degraded.append(f"leader-epoch skew {epoch_skew} ({behind} behind)")
     if payloads and leaders == 0:
         degraded.append("no leader reporting")
     chain_depths = {

@@ -13,14 +13,23 @@ import sys
 
 import pytest
 
+from tests.conftest import compile_cache_env
+
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def _share_compile_cache(monkeypatch, tmp_path_factory):
+    """One persistent compile cache for a leg's worker subprocesses
+    (tools/fleet.py children inherit the environment)."""
+    cache = tmp_path_factory.getbasetemp().parent / "fleet-xla-cache"
+    for name, value in compile_cache_env(cache).items():
+        monkeypatch.setenv(name, value)
 
 
 @pytest.mark.timeout(120)
 def test_bench_smoke_overlap_gate(monkeypatch):
-    # Same ambient-sitecustomize workaround as bench.main(): keep the
-    # smoke on CPU even outside pytest/conftest (run_smoke also forces
-    # the cpu platform itself).
+    # Keep the smoke on CPU even outside pytest/conftest (run_smoke
+    # also forces the cpu platform itself).
     import jax
 
     if os.environ.get("CT_TPU_TESTS", "") == "":
@@ -90,10 +99,10 @@ def test_bench_smoke_overlap_gate(monkeypatch):
         # with the serial lane, the mean chunks/dispatch hitting K,
         # the ingest.h2d span/bytes instrumentation, H2D hidden behind
         # the envelope's compute, the span-counted execution-fusion
-        # structure, and the tunneled-toll-modeled >=1.3x acceptance
-        # inequality (raw walls are parity-neutral on the 1-core CI
-        # box — see the honesty note in run_smoke / BENCHLOG round
-        # 11); here we pin those numbers.
+        # structure, and the per-dispatch-toll-modeled >=1.3x
+        # acceptance inequality (raw walls are parity-neutral on the
+        # 1-core CI box — see the honesty note in run_smoke); here we
+        # pin those numbers.
         assert out["smoke_staged_modeled_vs_overlap"] >= 1.3
         assert (out["smoke_staged_execs"]
                 * out["smoke_staged_chunks_per_dispatch"]
@@ -128,8 +137,7 @@ def test_bench_smoke_fleet_gate(tmp_path_factory, monkeypatch):
         jax.config.update("jax_platforms", "cpu")
     # Shared persistent compile cache for the worker subprocesses —
     # all compile identical tiny CPU programs.
-    monkeypatch.setenv("CT_COMPILE_CACHE", str(
-        tmp_path_factory.getbasetemp().parent / "fleet-xla-cache"))
+    _share_compile_cache(monkeypatch, tmp_path_factory)
     import bench
 
     out = bench.run_fleet_smoke()  # raises BenchError on any miss
@@ -164,8 +172,7 @@ def test_bench_smoke_obs_gate(tmp_path_factory, monkeypatch):
     # Shared persistent compile cache for the worker subprocesses —
     # safe here (no SIGKILL/restart sequence; SIGSTOP/SIGCONT and a
     # clean SIGTERM only — see the spawn_worker cache caveat).
-    monkeypatch.setenv("CT_COMPILE_CACHE", str(
-        tmp_path_factory.getbasetemp().parent / "fleet-xla-cache"))
+    _share_compile_cache(monkeypatch, tmp_path_factory)
     import bench
 
     out = bench.run_obs_smoke()  # raises BenchError on any miss

@@ -1,19 +1,15 @@
-"""CT_COMPILE_CACHE: persistent XLA compilation cache wiring.
+"""The persistent XLA compilation cache and where it is placed.
 
-The bench's three legs repaid ~580 s of repeated compile in
-BENCH_r05.json; on locally-compiling stacks the persistent cache
-removes that tax across processes. This tier-1 test pins the contract:
-with the cache enabled, a SECOND trace of the same step shape is a
-cache HIT (observed through jax's own monitoring events), not a
-recompile.
+``utils/compile_cache.configure`` is the one place the program decides
+that: ``JAX_COMPILATION_CACHE_DIR`` when the operator set it (JAX
+reads the variable itself, nothing is set in code), else one fixed
+directory inside the checkout. With a cache in place, a SECOND trace
+of the same step shape is a cache HIT (observed through jax's own
+monitoring events), not a recompile.
 
-The probe runs in a SUBPROCESS (round-17 budget audit): its
-``jax.clear_caches()`` — required to prove the persistent hit — used
-to wipe every in-memory executable of the whole tier-1 process
-mid-suite, so everything compiled by the (alphabetically earlier)
-bench-smoke legs was silently recompiled by every later test file.
-Isolating it repaid ~1 subprocess jax startup to save several
-kernel-family recompiles per suite run.
+The hit probe runs in a SUBPROCESS: its ``jax.clear_caches()`` —
+required to prove the persistent hit — would otherwise wipe every
+in-memory executable of the whole tier-1 process mid-suite.
 """
 
 import os
@@ -22,7 +18,7 @@ import sys
 
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from tests.conftest import compile_cache_env
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -31,12 +27,11 @@ import os, sys
 import numpy as np
 import jax
 
-if os.environ.get("CT_TPU_TESTS", "") == "":
-    jax.config.update("jax_platforms", "cpu")
-import bench
+from ct_mapreduce_tpu.utils import compile_cache
 
-cache_dir = os.environ["CT_COMPILE_CACHE"]
-assert bench.maybe_enable_compile_cache() == cache_dir
+cache_dir = os.environ["JAX_COMPILATION_CACHE_DIR"]
+assert compile_cache.configure() == cache_dir
+assert jax.config.jax_compilation_cache_dir == cache_dir
 
 from jax._src import monitoring
 
@@ -81,7 +76,7 @@ print("CACHE-HIT-OK")
 @pytest.mark.timeout(120)
 def test_second_trace_of_same_step_shape_is_cache_hit(tmp_path):
     env = dict(os.environ)
-    env["CT_COMPILE_CACHE"] = str(tmp_path)
+    env.update(compile_cache_env(tmp_path))
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.environ.get("PYTHONPATH", ""), REPO) if p)
     proc = subprocess.run(
@@ -91,3 +86,23 @@ def test_second_trace_of_same_step_shape_is_cache_hit(tmp_path):
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "CACHE-HIT-OK" in proc.stdout, (proc.stdout,
                                            proc.stderr[-500:])
+
+
+def test_cache_is_placed_from_outside_or_at_one_fixed_path(monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set, no directory is set in
+    code; unset, it is <checkout>/.jax_cache — the same on every call."""
+    import jax
+
+    from ct_mapreduce_tpu.utils import compile_cache
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: updates.append((name, value)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    assert compile_cache.configure() == "/somewhere/else"
+    assert updates == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    fixed = os.path.join(REPO, ".jax_cache")
+    assert compile_cache.configure() == fixed
+    assert compile_cache.configure() == fixed
+    assert updates == [("jax_compilation_cache_dir", fixed)] * 2

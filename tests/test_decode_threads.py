@@ -78,7 +78,7 @@ def _assert_batches_equal(a, b, ctx):
 def test_decode_byte_exact_across_thread_counts():
     lis, eds = _wire_corpus()
     base = leafpack.decode_raw_batch(lis, eds, 2048, threads=1)
-    # The corpus must actually exercise the status taxonomy.
+    # The corpus must actually exercise the status codes.
     assert len(set(base.status.tolist())) >= 4
     for t in (2, 3, 7, 16):
         got = leafpack.decode_raw_batch(lis, eds, 2048, threads=t)

@@ -363,7 +363,9 @@ def test_fleet_health_rollup():
     p1a["fleet"]["checkpoint_epoch"] = 3
     body = fleetobs.fleet_health({0: p0a, 1: p1a}, 2, 10.0, now=now)
     assert body["healthy"] is False
-    assert any("skew" in r for r in body["degraded"])
+    # ... and the reason names who lags (a SIGSTOP'd follower trips
+    # this rule before its payload goes stale).
+    assert "leader-epoch skew 2 (worker 1 behind)" in body["degraded"]
 
     # No leader reporting → degraded.
     p0b, p1b = _health_payloads(now)

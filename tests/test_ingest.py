@@ -455,7 +455,7 @@ def test_device_queue_depth_pipelines_submissions():
 
 def test_raw_batch_row_narrowing():
     """When every cert fits half the pad, the sink ships the narrow row
-    view (H2D bytes halve on tunneled links) and results are identical."""
+    view (H2D bytes halve) and results are identical."""
     import base64
 
     from ct_mapreduce_tpu.agg.aggregator import TpuAggregator
@@ -556,7 +556,10 @@ def test_cursor_saved_on_download_error():
         return log.transport(url)
 
     db = _db()
-    c = CTLogClient(log.url, transport=failing_transport)
+    # A 500 takes the retry lane (jittered 0.5 s–5 min, 100 tries): no
+    # real sleeps, and a budget small enough to give up inside the test.
+    c = CTLogClient(log.url, transport=failing_transport,
+                    sleep=lambda _s: None, max_retries=3)
     w = LogWorker(c, db)
     q = queue.Queue()
     with pytest.raises(CTClientError):

@@ -32,6 +32,8 @@ from pathlib import Path
 
 import pytest
 
+from tests.conftest import compile_cache_env
+
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 _CHILD = textwrap.dedent("""
@@ -147,7 +149,8 @@ def compile_cache(tmp_path_factory, monkeypatch):
     CPU programs, so only the first pays (spawn_worker forwards the
     env)."""
     path = str(tmp_path_factory.getbasetemp().parent / "fleet-xla-cache")
-    monkeypatch.setenv("CT_COMPILE_CACHE", path)
+    for name, value in compile_cache_env(path).items():
+        monkeypatch.setenv(name, value)
     return path
 
 

@@ -66,3 +66,33 @@ def test_dispatcher_stays_on_xla_off_tpu(monkeypatch):
     # CPU backend → dispatcher must fall back to the XLA path (no error).
     out = np.asarray(sha256.sha256_fingerprint64(blocks))
     assert out.shape == (64, 4)
+
+
+def test_dispatcher_picks_pallas_on_tpu(monkeypatch):
+    """The gate's other side, which only the chip takes: on a backend
+    named ``tpu`` the ingest widths select the Pallas kernel, and a
+    backend that cannot initialise is an error, not the XLA scan."""
+    import jax
+
+    monkeypatch.setenv("CTMR_PALLAS", "1")
+    monkeypatch.delenv("CTMR_SHA_TILE", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert sha256._pallas_enabled(65536)
+    assert sha256._pallas_enabled(16384)
+    assert not sha256._pallas_enabled(640)  # shape rule: tile must divide
+    picked = []
+    monkeypatch.setattr(
+        pallas_sha256, "sha256_fingerprint64_pallas",
+        lambda block: picked.append(block.shape) or block[..., :4])
+    sha256.sha256_fingerprint64(np.zeros((16384, 16), np.uint32))
+    assert picked == [(16384, 16)]
+    monkeypatch.setenv("CTMR_PALLAS", "0")  # the explicit opt-out stays
+    assert not sha256._pallas_enabled(65536)
+    monkeypatch.setenv("CTMR_PALLAS", "1")
+
+    def down():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "default_backend", down)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        sha256._pallas_enabled(65536)

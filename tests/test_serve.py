@@ -725,6 +725,35 @@ def test_view_device_fallback_to_host(template, monkeypatch):
         tmetrics.set_sink(prev)
 
 
+def test_view_device_pin_failure_is_an_error_on_tpu(template, monkeypatch):
+    """On a TPU backend a replica that cannot be pinned raises instead
+    of sliding onto the host mirror (serve.device_fallback stays 0)."""
+    import jax
+    import jax.numpy as jnp
+    from ct_mapreduce_tpu.telemetry import metrics as tmetrics
+
+    agg = TpuAggregator(capacity=1 << 12, batch_size=64)
+    agg.ingest([(syncerts.stamp_serial(template, j), template.issuer_der)
+                for j in range(4)])
+    issuer_id, eh = _identity(template)
+    idx = agg.registry.index_of_issuer_id(issuer_id)
+    sink = tmetrics.InMemSink()
+    prev = tmetrics.get_sink()
+    tmetrics.set_sink(sink)
+    try:
+        view = capture_view(agg, epoch=1, device=True)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(jnp, "asarray", lambda *a, **k: (
+            (_ for _ in ()).throw(RuntimeError("RESOURCE_EXHAUSTED"))))
+        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+            view.lookup([(idx, eh, _serial_bytes(template, 0))])
+        assert view._device is True  # not latched to the host mirror
+        counters = sink.snapshot()["counters"]
+        assert counters.get("serve.device_fallback", 0) == 0
+    finally:
+        tmetrics.set_sink(prev)
+
+
 # -- hot-serial cache ------------------------------------------------------
 
 

@@ -1,0 +1,116 @@
+"""The main path's kernels compile for the chip — asked of the TPU
+compiler installed here, for a v5e that is described and not attached
+— plus the two start-up checks that keep a chip run from finishing on
+the CPU. Nothing here runs on a device; ``python chip_smoke.py`` (on
+the chip) is what runs.
+
+The topology is described inside a module-scoped fixture, so only the
+worker that is handed this file loads the TPU library.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("lanes", [65536, 1048576])
+def test_pallas_sha_kernel_compiles_for_v5e(one_chip, lanes):
+    """The Mosaic SHA-256 kernel at the CLI's batch width and at the
+    2^20-lane width the July records used."""
+    import jax
+    import jax.numpy as jnp
+
+    from ct_mapreduce_tpu.ops import pallas_sha256
+
+    block = jax.ShapeDtypeStruct((lanes, 16), jnp.uint32, sharding=one_chip)
+    compiled = jax.jit(
+        pallas_sha256.sha256_fingerprint64_pallas).lower(block).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_contains_compiles_for_v5e_at_serve_width(one_chip):
+    """The query plane's membership probe at one of its pow2-padded
+    widths, against the 2^26-slot table chip_smoke.py keeps resident."""
+    import jax
+    import jax.numpy as jnp
+
+    from ct_mapreduce_tpu.ops import buckettable
+
+    shape = jax.eval_shape(lambda: buckettable.make_table(1 << 26))
+    table = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        shape)
+    keys = jax.ShapeDtypeStruct((4096, 4), jnp.uint32, sharding=one_chip)
+    compiled = buckettable.contains.lower(table, keys).compile()
+    mem = compiled.memory_analysis()
+    # The table is an argument, never copied: 2^22 bucket rows × 512 B.
+    assert mem.argument_size_in_bytes >= (1 << 22) * 512
+    assert mem.temp_size_in_bytes < 1 << 30
+
+
+def _tpu_ini(tmp_path, log_url="https://ct.example.com/none"):
+    ini = tmp_path / "ct.ini"
+    ini.write_text(
+        f"logList = {log_url}\n"
+        "backend = tpu\n"
+        "batchSize = 64\n"
+        "tableBits = 8\n"
+        "healthAddr = \n"
+    )
+    return ini
+
+
+def test_ct_fetch_names_its_device(tmp_path, monkeypatch, capsys):
+    """``backend = tpu`` prints one line naming platform, device kind
+    and count before any work (here: the CPU the tests ask for)."""
+    import jax
+
+    from ct_mapreduce_tpu.cmd import ct_fetch
+    from ct_mapreduce_tpu.ingest import ctclient
+
+    monkeypatch.setattr(
+        ctclient, "_urllib_transport",
+        lambda url: (200, {}, b'{"tree_size": 0, "timestamp": 0}'))
+    ct_fetch.main(["-config", str(_tpu_ini(tmp_path)), "-nobars"])
+    dev = jax.devices()[0]
+    assert (f"device: platform=cpu kind={dev.device_kind} "
+            f"count={len(jax.devices())}") in capsys.readouterr().err
+
+
+def test_cpu_nobody_asked_for_is_refused(tmp_path, monkeypatch, capsys):
+    """JAX's own CPU fallback (``JAX_PLATFORMS`` unset) must not carry
+    a ``backend = tpu`` run to exit code 0, and chip_smoke.py refuses
+    anything but a TPU whatever the environment says."""
+    import chip_smoke
+    from ct_mapreduce_tpu.cmd import ct_fetch
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    assert ct_fetch.main(
+        ["-config", str(_tpu_ini(tmp_path)), "-nobars"]) == 2
+    assert "fell back to the CPU" in capsys.readouterr().err
+    with pytest.raises(SystemExit) as refused:
+        chip_smoke.require_tpu(1)
+    assert refused.value.code not in (0, None)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    with pytest.raises(SystemExit):
+        chip_smoke.require_tpu(1)

@@ -21,7 +21,7 @@ def test_fused_step_on_hardware():
     import jax.numpy as jnp
 
     from ct_mapreduce_tpu.core import packing
-    from ct_mapreduce_tpu.ops import hashtable, pipeline
+    from ct_mapreduce_tpu.ops import buckettable, pipeline
     from ct_mapreduce_tpu.utils import syncerts
 
     assert on_tpu(), "CT_TPU_TESTS=1 requires a TPU backend"
@@ -33,7 +33,7 @@ def test_fused_step_on_hardware():
 
     step = jax.jit(pipeline.ingest_core, donate_argnums=(0,),
                    static_argnames=("num_issuers", "max_probes"))
-    table = hashtable.make_table(1 << 14)
+    table = buckettable.make_table(1 << 14)
     table, out = step(
         table, datas[0], lens[0], issuer_idx, valid,
         jnp.int32(500_000), jnp.int32(packing.DEFAULT_BASE_HOUR),
@@ -92,7 +92,7 @@ def test_fused_step_parity_at_production_width():
     import jax.numpy as jnp
 
     from ct_mapreduce_tpu.core import packing
-    from ct_mapreduce_tpu.ops import hashtable, pipeline
+    from ct_mapreduce_tpu.ops import buckettable, pipeline
     from ct_mapreduce_tpu.utils import syncerts
 
     assert on_tpu()
@@ -104,7 +104,7 @@ def test_fused_step_parity_at_production_width():
 
     step = jax.jit(pipeline.ingest_core, donate_argnums=(0,),
                    static_argnames=("num_issuers", "max_probes"))
-    table = hashtable.make_table(1 << 20)
+    table = buckettable.make_table(1 << 20)
     table, out = step(
         table, datas[0], lens[0], issuer_idx, valid,
         jnp.int32(500_000), jnp.int32(packing.DEFAULT_BASE_HOUR),

@@ -177,32 +177,6 @@ def filter_bytes(state_paths: list[str], fp_rate: float = 0.01) -> bytes:
     return build_from_merged(merged, fp_rate=fp_rate).to_bytes()
 
 
-def _enable_compile_cache() -> None:
-    """CT_COMPILE_CACHE for worker processes (same contract as
-    bench.maybe_enable_compile_cache): the W children compile the same
-    tiny CPU programs — share one cache dir and only the first pays.
-
-    CT_COMPILE_CACHE_READONLY=1 makes this process consume the cache
-    without ever writing entries (the min-compile-time gate set
-    unreachably high): the mode for SIGKILL targets, which must never
-    write a shared cache (a kill mid-write leaves a truncated
-    executable that poisons every later reader — see spawn_worker)."""
-    path = os.environ.get("CT_COMPILE_CACHE", "")
-    if not path:
-        return
-    read_only = os.environ.get("CT_COMPILE_CACHE_READONLY", "0") == "1"
-    import jax
-
-    try:
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          1e9 if read_only else 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        pass  # jax-version dependent; the cache is an optimization only
-
-
 # -- one worker process --------------------------------------------------
 
 
@@ -290,7 +264,9 @@ def read_cursors(redis_addr: str, fixture: dict,
 
 def child_main(args) -> int:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    _enable_compile_cache()
+    from ct_mapreduce_tpu.utils import compile_cache
+
+    compile_cache.configure()
     with open(args.fixture) as fh:
         fixture = json.load(fh)
     install_transport(fixture, throttle_ms=args.throttle_ms)
@@ -355,27 +331,31 @@ def spawn_worker(worker_id: int, workers: int, fixture_path: str,
                  metrics_port: int = 0,
                  ini_lines: tuple = (),
                  extra_env: dict = None) -> subprocess.Popen:
-    """Spawn one worker process. Pass ``compile_cache=False`` (no
-    persistent cache) for every process involved in a kill-and-resume
-    sequence. Observed on this jax/XLA CPU build (stress data in
-    BENCHLOG round 14): when the restarted worker shares a persistent
-    compilation cache, its native heap intermittently corrupts — XLA
-    ``Check failed: allocation.size() == ...`` / ``is_tuple_``
-    aborts, glibc ``corrupted size vs. prev_size``, or (worst)
-    a clean exit whose checkpointed table rows are recycled-heap
-    garbage. The trigger wasn't fully pinned (a read-only cache for
-    the victim did not clear it; a clean no-kill restart never
-    reproduces), but cache exclusion is the configuration repeatedly
-    validated corruption-free. ``compile_cache_readonly=True``
-    (consume without writing) remains for processes that only need
-    protection against truncated-entry WRITES."""
+    """Spawn one worker process, pinned to the CPU: a chip belongs to
+    one process, so W workers on chips would need W chips.
+
+    Pass ``compile_cache=False`` (the child runs with
+    ``JAX_ENABLE_COMPILATION_CACHE=false``) for every process involved
+    in a kill-and-resume sequence. Observed on this jax/XLA CPU build:
+    when the restarted worker shares a persistent compilation cache,
+    its native heap intermittently corrupts — XLA ``Check failed:
+    allocation.size() == ...`` / ``is_tuple_`` aborts, glibc
+    ``corrupted size vs. prev_size``, or (worst) a clean exit whose
+    checkpointed table rows are recycled-heap garbage. The trigger
+    wasn't fully pinned (a read-only cache for the victim did not
+    clear it; a clean no-kill restart never reproduces), but cache
+    exclusion is the configuration repeatedly validated
+    corruption-free. ``compile_cache_readonly=True`` (consume entries,
+    never write one) remains for processes that only need protection
+    against truncated-entry WRITES."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env.pop("CT_TPU_TESTS", None)
     if not compile_cache:
-        env.pop("CT_COMPILE_CACHE", None)
+        env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     if compile_cache_readonly:
-        env["CT_COMPILE_CACHE_READONLY"] = "1"
+        # No compile is ever slow enough to be worth writing.
+        env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "1e9"
     env["PYTHONPATH"] = str(REPO)
     argv = [
         sys.executable, str(Path(__file__).resolve()), "--child",

@@ -1,6 +1,5 @@
-"""Insert-only cost probe: the dedup-table insert under the trusted
-timing contract (jitted fori_loop sweeps + synchronous value read —
-`jax.block_until_ready` is not honored on this stack, BENCHLOG.md).
+"""Insert-only cost probe: the dedup-table insert under the bench's
+timing contract (jitted fori_loop sweeps + synchronous value read).
 
 Isolates the table insert from the rest of the fused step so insert
 formulation changes iterate without the full ~200s step compile: keys

@@ -1,10 +1,6 @@
-"""Honest per-stage cost of the fused step, bench-methodology edition.
+"""Per-stage cost of the fused step, bench-methodology edition.
 
-tools/microbench.py times stages with `jax.block_until_ready`, which
-this stack does not reliably honor (BENCHLOG round-1 postmortem) — its
-per-stage numbers can be off by orders of magnitude (0.18 ms for a
-1 GB parse = 5.8 TB/s, 7x the chip's HBM). This probe times each stage
-the way bench.py times the headline: the stage runs inside a jitted
+This probe times each stage the way bench.py times the headline: the stage runs inside a jitted
 `lax.fori_loop` sweep whose input is re-stamped per sweep (so nothing
 is loop-invariant), accumulates a scalar that depends on every stage
 output (so nothing is dead), and every chunk ends with a synchronous
