@@ -1,0 +1,602 @@
+"""One run of one cell: set-up, the window of fixed work, the drain, and
+the comparison with the fixture's arithmetic.
+
+The process that calls :func:`run_cell` holds the chip: it calls
+``ct_fetch.main`` itself, in its main thread, and conducts the run from
+a second thread. From the program it takes the entry points a user has
+(``ct-fetch``, ``storage-statistics``, ``/healthz``) and its telemetry
+(the metrics sink's samples, the span tracer); the load comes from
+``logserver.py``, a process of its own on cores of its own.
+"""
+
+from __future__ import annotations
+
+import gc
+import http.client
+import json
+import os
+import shutil
+import signal
+import socket
+import subprocess
+import sys
+import threading
+import time
+
+import numpy as np
+
+import fixture as fx
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+WORK = os.path.join(ROOT, ".bench_work")  # per-run state; git-ignored
+STATE_FILE = "agg.npz"  # aggStatePath's name; its manifest and segments share the prefix
+FOLD_SAMPLE = "ct-fetch.completeBatch"  # one sample per batch folded
+# The store thread's timers, for the diagnosis line's timeline.
+STORE_THREAD = ("ct-fetch.decodeBatch", "ct-fetch.storeCertificate",
+                FOLD_SAMPLE)
+
+
+class RunFailed(RuntimeError):
+    """The run cannot report: something outside ``correct`` broke."""
+
+
+def free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def http_json(port: int, path: str, body: dict | None = None,
+              timeout: float = 60.0) -> tuple[int, dict]:
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=timeout)
+    try:
+        if body is None:
+            conn.request("GET", path)
+        else:
+            conn.request("POST", path, body=json.dumps(body),
+                         headers={"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        raw = resp.read()
+        return resp.status, (json.loads(raw) if raw else {})
+    finally:
+        conn.close()
+
+
+def log_spec(traffic: dict, seconds: float, batch: int) -> fx.LogSpec:
+    g = next(g for g in traffic["generators"] if g["kind"] == "log_replay")
+    if g["warmup_entries"] % batch or batch % g["page"]:
+        raise RunFailed("warm-up and pages must fill whole batches")
+    if g["ramp_batches"] < 1:
+        raise RunFailed("the window opens at the ramp's last fold: "
+                        "ramp_batches must be 1 or more")
+    return fx.LogSpec(
+        logs=g["logs"], page=g["page"], dup_share=g["dup_share"],
+        leaf_mix=g["leaf_mix"], issuers=g["issuers"], zipf_s=g["zipf_s"],
+        warmup_entries=g["warmup_entries"],
+        window_entries=fx.window_entries(
+            g["window_entries_per_second"], seconds, g["logs"], batch),
+        ramp_entries=-(-g["ramp_batches"] // g["logs"]) * g["logs"] * batch,
+        tail_entries=-(-g["tail_batches"] // g["logs"]) * g["logs"] * batch)
+
+
+class Child:
+    """A load generator: a process that reads a spec file, says one
+    JSON line when it is ready, and ends when its stdin closes."""
+
+    def __init__(self, script: str, spec: dict, workdir: str):
+        self.name = script
+        path = os.path.join(workdir, script + ".spec.json")
+        with open(path, "w") as fh:
+            json.dump(spec, fh)
+        env = {k: v for k, v in os.environ.items()
+               if not k.startswith(("JAX_", "XLA_", "TPU_"))}
+        self.proc = subprocess.Popen(
+            [sys.executable, os.path.join(HERE, script), path],
+            stdin=subprocess.PIPE, stdout=subprocess.PIPE, env=env, cwd=ROOT)
+
+    def ready(self) -> dict:
+        line = self.proc.stdout.readline()
+        if not line:
+            raise RunFailed(f"{self.name} ended before it was ready "
+                            f"(rc {self.proc.poll()})")
+        return json.loads(line)
+
+    def stop(self, timeout: float = 60.0) -> None:
+        if self.proc.poll() is None:
+            try:
+                self.proc.stdin.close()
+                self.proc.wait(timeout=timeout)
+            except (OSError, subprocess.TimeoutExpired):
+                self.proc.kill()
+                self.proc.wait()
+        self.proc.stdout.close()
+
+
+class FoldStamper:
+    """A fan-out emitter on the program's metrics sink (the place StatsD
+    rides): stamps the instant each batch's fold returns, from the
+    sample the program already records around it."""
+
+    def __init__(self):
+        self.stamps: list[float] = []
+        # Every sample and counter the program emits, with its instant:
+        # the per-layer readers sum them over the window.
+        self.samples: list[tuple[float, str, float]] = []
+        self.counters: list[tuple[float, str, float]] = []
+        self.cond = threading.Condition()
+
+    def add_sample(self, key: str, value: float) -> None:
+        now = time.monotonic()
+        self.samples.append((now, key, value))
+        if key == FOLD_SAMPLE:
+            with self.cond:
+                self.stamps.append(now)
+                self.cond.notify_all()
+
+    def incr_counter(self, key: str, value: float) -> None:
+        self.counters.append((time.monotonic(), key, value))
+
+    def set_gauge(self, key: str, value: float) -> None:
+        pass
+
+    def wait_for(self, count: int, deadline: float, alive) -> None:
+        with self.cond:
+            while len(self.stamps) < count:
+                if time.monotonic() > deadline:
+                    raise RunFailed(f"{len(self.stamps)} of {count} batches "
+                                    "folded at the deadline")
+                if not alive():
+                    raise RunFailed("ct-fetch returned before the window "
+                                    "was folded")
+                self.cond.wait(0.5)
+
+
+class CompileLog:
+    """What XLA compiled and when, from JAX's monitoring events (copy of
+    ``chip_smoke.py::CompileLog``, with a time on each)."""
+
+    def __init__(self):
+        import jax.monitoring
+
+        self.events: list[tuple[float, float]] = []  # (t_end, seconds)
+        self.cache_hits = 0
+        self.cache_misses = 0
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _duration(self, event: str, seconds: float, **_kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.events.append((time.monotonic(), seconds))
+
+    def _event(self, event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            self.cache_hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.cache_misses += 1
+
+    def within(self, lo: float, hi: float) -> int:
+        return sum(lo <= t <= hi for t, _ in self.events)
+
+
+class GcLog:
+    def __init__(self):
+        self.pauses: list[tuple[float, float, int]] = []
+        self._t0 = 0.0
+        gc.callbacks.append(self._cb)
+
+    def _cb(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t0 = time.monotonic()
+        else:
+            now = time.monotonic()
+            self.pauses.append((now, now - self._t0, info["generation"]))
+
+    def close(self) -> None:
+        gc.callbacks.remove(self._cb)
+
+
+def write_ini(config: dict, workdir: str, name: str, state_path: str,
+              log_urls: list[str], metrics_port: int) -> str:
+    lines = [f"logList = {', '.join(log_urls)}",
+             f"aggStatePath = {state_path}",
+             f"metricsPort = {metrics_port}"]
+    for key, value in config["directives"].items():
+        if isinstance(value, bool):
+            value = "true" if value else "false"
+        lines.append(f"{key} = {value}")
+    ini = os.path.join(workdir, name)
+    with open(ini, "w") as fh:
+        fh.write("\n".join(lines) + "\n")
+    return ini
+
+
+def quantile(values: list[float], q: float) -> float:
+    """Nearest-rank quantile of a non-empty list."""
+    s = sorted(values)
+    return s[min(len(s) - 1, max(0, int(np.ceil(q * len(s))) - 1))]
+
+
+class Conductor:
+    """The second thread of the ct-fetch process: waits for the warm-up
+    round, opens the log, stamps the folds, waits for the checkpoint,
+    keeps the checkpoint's files as they stand at that instant, reads
+    the live counters and sends SIGINT."""
+
+    def __init__(self, *, fixture, spec, config, workdir, trace_on,
+                 log_port, metrics_port, compiles, marks):
+        self.fixture = fixture
+        self.spec = spec
+        self.workdir = workdir
+        self.trace_on = trace_on
+        self.log_port = log_port
+        self.metrics_port = metrics_port
+        self.compiles = compiles
+        self.marks = marks  # set-up, itemised: (name, monotonic)
+        self.stamper = FoldStamper()
+        self.fetch_returned = threading.Event()
+        self.failure: BaseException | None = None
+        self.out: dict = {}
+        self.batch = int(config["directives"]["batchSize"])
+
+    # -- helpers ---------------------------------------------------------
+    def mark(self, name: str) -> float:
+        now = time.monotonic()
+        self.marks.append((name, now))
+        return now
+
+    def alive(self) -> bool:
+        return not self.fetch_returned.is_set()
+
+    def healthz(self) -> dict:
+        return http_json(self.metrics_port, "/healthz")[1]
+
+    def wait_idle(self, want_pos: dict[int, int], deadline: float) -> float:
+        """The first instant /healthz reads stage ``idle`` with every
+        log's cursor where ``want_pos`` says."""
+        while True:
+            if not self.alive():
+                raise RunFailed("ct-fetch returned before it was idle")
+            if time.monotonic() > deadline:
+                raise RunFailed("ct-fetch not idle at the deadline")
+            try:
+                health = self.healthz()
+            except OSError:
+                time.sleep(0.1)
+                continue
+            now = time.monotonic()
+            pos = {u: p["pos"] for u, p in health["progress"].items()}
+            done = all(
+                any(u.endswith(f"/log{k}") and p == want
+                    for u, p in pos.items()) or want == 0
+                for k, want in want_pos.items())
+            if health["stage"] == "idle" and done:
+                return now
+            time.sleep(0.05)
+
+    def install_stamper(self) -> None:
+        from ct_mapreduce_tpu.telemetry import metrics
+
+        metrics.set_sink(metrics.get_sink(), self.stamper)
+
+    def keep_checkpoint(self) -> str:
+        """The checkpoint's files as they stand now, hard-linked into a
+        directory of their own. The program lands every file by rename,
+        so a link keeps this instant's bytes whatever is saved later
+        (the next poll's round, the exit save): the report is made from
+        what was on disk when the program said it was durable."""
+        kept = os.path.join(self.workdir, "durable")
+        os.makedirs(kept)
+        for name in sorted(os.listdir(self.workdir)):
+            path = os.path.join(self.workdir, name)
+            if name.startswith(STATE_FILE) and os.path.isfile(path):
+                try:
+                    os.link(path, os.path.join(kept, name))
+                except OSError:  # a file system without hard links
+                    shutil.copy2(path, os.path.join(kept, name))
+        return kept
+
+    # -- the run ---------------------------------------------------------
+    def run(self) -> None:
+        try:
+            self._run()
+        except BaseException as err:
+            self.failure = err
+        finally:
+            if self.alive():
+                os.kill(os.getpid(), signal.SIGINT)
+
+    def _run(self) -> None:
+        log0 = self.fixture.logs[0]
+        warm_batches = log0.warm // self.batch
+        ramp_batches = self.spec.ramp_entries // self.batch
+        window_batches = self.spec.window_entries // self.batch
+        round_batches = ramp_batches + window_batches \
+            + self.spec.tail_entries // self.batch
+        deadline = time.monotonic() + 1100.0
+        while True:  # the metrics endpoint is up once main has its sink
+            try:
+                self.healthz()
+                break
+            except OSError:
+                if not self.alive() or time.monotonic() > deadline:
+                    raise RunFailed("ct-fetch never served /healthz")
+                time.sleep(0.05)
+        self.install_stamper()
+        self.mark("ct_fetch_serving")
+        self.stamper.wait_for(warm_batches, deadline, self.alive)
+        self.mark("warmup_folded")
+        self.wait_idle({0: log0.warm}, deadline)
+        self.mark("warmup_durable")
+
+        tracer = None
+        if self.trace_on:
+            import tracing
+
+            tracer = tracing.WindowTrace(os.path.join(self.workdir, "profile"))
+            tracer.start()
+            self.mark("trace_started")
+        gclog = GcLog()
+        t_open = http_json(self.log_port, "/control/open", timeout=300)[1][
+            "opened_at"]
+        self.mark("log_opened")
+        self.stamper.wait_for(warm_batches + round_batches,
+                              time.monotonic() + 600.0, self.alive)
+        folds = list(self.stamper.stamps)[:warm_batches + round_batches]
+        if tracer is not None:
+            tracer.stop()
+        want = {k: log.total for k, log in enumerate(self.fixture.logs)}
+        t_durable = self.wait_idle(want, time.monotonic() + 600.0)
+        kept = self.keep_checkpoint()
+        gclog.close()
+
+        # The window: from the fold of the ramp's last batch to the fold
+        # of its own last batch, so many whole batch periods of a
+        # pipeline that was full before and stays full after (the tail).
+        t_first = folds[warm_batches + ramp_batches - 1]
+        t_folded = folds[warm_batches + ramp_batches + window_batches - 1]
+        stamps = http_json(self.log_port, "/control/stamps")[1]
+        pages = sorted((p for p in stamps["pages"] if p[4] >= t_open),
+                       key=lambda p: p[4])
+        if not pages:
+            raise RunFailed("the log served no page after it was opened")
+        t_last_page = max(p[4] for p in pages if p[1] + p[2] == want[p[0]])
+        self.out.update(
+            t_open=t_open, t_first=t_first, t_folded=t_folded,
+            t_round_folded=folds[-1], t_last_page=t_last_page,
+            t_durable=t_durable, kept=kept,
+            folds=folds[warm_batches:], pages=pages,
+            extra_folds=len(self.stamper.stamps) - len(folds),
+            samples=[e for e in self.stamper.samples if e[0] >= t_open],
+            counters=[e for e in self.stamper.counters if e[0] >= t_open],
+            compiles_in_window=self.compiles.within(t_open, t_durable),
+            gc=[p for p in gclog.pauses if t_first <= p[0] <= t_folded],
+            tracer=tracer)
+        self.out["live"] = self.live_facts()
+        self.out["peak_bytes"] = peak_device_bytes()
+
+    def live_facts(self) -> dict:
+        """After the checkpoint: what the running process says."""
+        from ct_mapreduce_tpu.telemetry import metrics
+
+        health = self.healthz()
+        snap = metrics.get_sink().snapshot()
+        return {
+            "cursor": {u: p["pos"] for u, p in health["progress"].items()},
+            "submitted": snap["counters"].get("ct-fetch.insertCertificate"),
+            "retries": {k: v for k, v in snap["counters"].items()
+                        if k.startswith("ingest.retry")},
+            "store_errors": snap["counters"].get("ct-fetch.storeError", 0),
+        }
+
+
+def peak_device_bytes() -> int | None:
+    import jax
+
+    stats = [d.memory_stats() for d in jax.devices()]
+    return max((s["peak_bytes_in_use"] for s in stats if s), default=None)
+
+
+def report_child(ini: str) -> dict:
+    """``storage-statistics -json`` over the checkpoint ``ini`` names:
+    a process that cannot see the chip and reads only the files."""
+    env = dict(os.environ, PYTHONPATH=ROOT)
+    res = subprocess.run(
+        [sys.executable, "-m", "ct_mapreduce_tpu.cmd.storage_statistics",
+         "-config", ini, "-json"],
+        capture_output=True, text=True, timeout=300, env=env, cwd=ROOT)
+    if res.returncode != 0:
+        raise RunFailed(f"storage-statistics rc {res.returncode}: "
+                        f"{res.stderr[-500:]}")
+    return json.loads(res.stdout)
+
+
+def compare(fixture: fx.RunFixture, tpl: fx.Templates, spec: fx.LogSpec,
+            out: dict, report: dict) -> list[dict]:
+    """Every number compared, beside its limit. All are exact. The
+    report is of the checkpoint as it stood at ``t_durable``."""
+    want_by = fixture.expected_by_issuer()
+    ids = tpl.issuer_ids[: spec.issuers]
+    got = {i["id"]: i["serials"] for i in report["issuers"]}
+    live = out["live"]
+    checks = [
+        {"what": "durable report: unique serials",
+         "got": report["totals"]["serials"], "want": fixture.expected_unique()},
+        {"what": "durable report: per-issuer counts that differ",
+         "got": sum(got.get(ids[k], 0) != int(want_by[k])
+                    for k in range(spec.issuers))
+         + len(set(got) - set(ids)), "want": 0},
+        {"what": "durable report: expiry groups other than the fixture's",
+         "got": len({e for i in report["issuers"] for e in i["expDates"]}
+                    - {tpl.exp_date_id}), "want": 0},
+        {"what": "live: entries submitted to the device",
+         "got": int(live["submitted"] or 0), "want": fixture.offered},
+        {"what": "live: cursors short of or past their log's end",
+         "got": sum(p != fixture.logs[int(u.rsplit("log", 1)[1])].total
+                    for u, p in live["cursor"].items())
+         + abs(len(live["cursor"]) - spec.logs), "want": 0},
+        {"what": "live: store errors", "got": int(live["store_errors"]),
+         "want": 0},
+        {"what": "round: batches folded beyond the fixture's",
+         "got": out["extra_folds"], "want": 0},
+        {"what": "round: programs compiled", "got": out["compiles_in_window"],
+         "want": 0},
+    ]
+    for c in checks:
+        c["ok"] = c["got"] == c["want"]
+    return checks
+
+
+class Prepared:
+    """What a run needs before JAX is loaded: its work directory, its
+    logs' sizes and the log server, started first because it builds
+    every page of the run before it says it is ready."""
+
+    def __init__(self, config: dict, traffic: dict, *, seed: int,
+                 seconds: float, loadgen_cores: list[int]):
+        shutil.rmtree(WORK, ignore_errors=True)
+        os.makedirs(WORK)
+        self.config, self.seed = config, seed
+        self.batch = int(config["directives"]["batchSize"])
+        self.spec = log_spec(traffic, seconds, self.batch)
+        self.logsrv = Child("logserver.py", {
+            "seed": seed, "log_spec": self.spec.__dict__,
+            "cores": loadgen_cores}, WORK)
+
+    def close(self) -> None:
+        self.logsrv.stop()
+
+
+def run_cell(prep: Prepared, *, trace_on: bool, t_start: float,
+             device: dict) -> dict:
+    """Set up, run the window, drain, compare. Returns the result line's
+    fields plus ``diagnosis`` and ``setup`` for the lines before it."""
+    marks: list[tuple[str, float]] = [("process_start", t_start)]
+    config, spec, seed, batch = prep.config, prep.spec, prep.seed, prep.batch
+    conductor = None
+    try:
+        import jax  # noqa: F401  (the device was checked by the caller)
+
+        from ct_mapreduce_tpu import native
+        from ct_mapreduce_tpu.cmd import ct_fetch
+
+        compiles = CompileLog()
+        marks.append(("jax_ready", time.monotonic()))
+        if not native.available():
+            raise RunFailed("ctmr_native.cpp did not build")
+        marks.append(("native_ready", time.monotonic()))
+        fixture = fx.RunFixture(spec, seed)
+        tpl = fx.Templates()
+        marks.append(("fixture_ready", time.monotonic()))
+        log_port = prep.logsrv.ready()["port"]
+        marks.append(("log_server_ready", time.monotonic()))
+        metrics_port = free_port()
+        urls = [f"http://127.0.0.1:{log_port}/log{k}"
+                for k in range(spec.logs)]
+        ini = write_ini(config, WORK, "ct-fetch.ini",
+                        os.path.join(WORK, STATE_FILE), urls, metrics_port)
+        headroom = None
+        if trace_on:
+            import tracing
+
+            tracing.enable_program_spans()
+            headroom = loadgen_rate(log_port, spec)
+        conductor = Conductor(
+            fixture=fixture, spec=spec, config=config, workdir=WORK,
+            trace_on=trace_on, log_port=log_port, metrics_port=metrics_port,
+            compiles=compiles, marks=marks)
+        thread = threading.Thread(target=conductor.run, name="bench-conductor")
+        # ct-fetch puts back the handler it found: a SIGINT that lands
+        # just after it has returned must find this one, not Python's.
+        signal.signal(signal.SIGINT, lambda *_: None)
+        thread.start()
+        try:
+            rc = ct_fetch.main(["-config", ini, "-nobars"])
+        finally:
+            conductor.fetch_returned.set()
+            thread.join()
+            signal.signal(signal.SIGINT, signal.default_int_handler)
+        if conductor.failure is not None:
+            raise conductor.failure
+        if rc != 0:
+            raise RunFailed(f"ct-fetch exited {rc}")
+    finally:
+        prep.close()
+
+    out = conductor.out
+    report = report_child(write_ini(
+        config, WORK, "report.ini", os.path.join(out["kept"], STATE_FILE),
+        urls, metrics_port))
+    checks = compare(fixture, tpl, spec, out, report)
+    n = spec.window_entries
+    values = {
+        "ingest_entries_per_s": n / (out["t_folded"] - out["t_first"]),
+        "setup_s": out["t_first"] - t_start,
+    }
+    in_round = spec.ramp_entries + n + spec.tail_entries
+    failed_entries = in_round - min(in_round, len(out["folds"]) * batch)
+    if not all(c["ok"] for c in checks[:2]):
+        failed_entries = max(failed_entries, abs(
+            fixture.expected_unique() - report["totals"]["serials"]))
+    t0 = out["t_first"]
+    # Between two pages, as the log server saw it: response written to
+    # next request read, (instant, seconds).
+    gaps = [(b[3], b[3] - a[5]) for a, b in zip(out["pages"], out["pages"][1:])]
+    serve = [p[5] - p[3] for p in out["pages"]]
+    diagnosis = {
+        "seconds_from_window_open": {
+            "log_opened": out["t_open"] - t0,
+            "folds": [f - t0 for f in out["folds"]],
+            "window_folded": out["t_folded"] - t0,
+            "last_page": out["t_last_page"] - t0,
+            "round_folded": out["t_round_folded"] - t0,
+            "durable": out["t_durable"] - t0},
+        "ramp_window_tail_entries": [spec.ramp_entries, n, spec.tail_entries],
+        "pages": len(out["pages"]),
+        "page_client_gap_ms": {q: quantile([g for _, g in gaps], q / 100) * 1e3
+                               for q in (50, 95, 99, 100)} if gaps else {},
+        "page_server_ms": {q: quantile(serve, q / 100) * 1e3
+                           for q in (50, 95, 100)},
+        "store_thread": [[round(t - t0, 3), key.split(".")[1], round(v, 3)]
+                         for t, key, v in out["samples"]
+                         if key in STORE_THREAD],
+        "longest_page_gaps": sorted(
+            ([round(t - t0, 3), round(g * 1e3, 1)] for t, g in gaps),
+            key=lambda g: -g[1])[:12],
+        "retries": out["live"]["retries"],
+        "gc_pauses": {"count": len(out["gc"]),
+                      "seconds": sum(p[1] for p in out["gc"]),
+                      "longest": max((p[1] for p in out["gc"]), default=0.0)},
+        "compile": {"programs": len(compiles.events),
+                    "seconds": sum(s for _, s in compiles.events),
+                    "cache_hits": compiles.cache_hits,
+                    "cache_misses": compiles.cache_misses},
+    }
+    names = [m[0] for m in marks] + ["window_open"]
+    times = [m[1] for m in marks] + [out["t_first"]]
+    setup = {names[i + 1]: times[i + 1] - times[i]
+             for i in range(len(times) - 1)}
+    return {
+        "correct": all(c["ok"] for c in checks),
+        "attempted": n, "failed": min(n, failed_entries),
+        "values": values, "checks": checks, "diagnosis": diagnosis,
+        "setup": setup, "out": out, "spec": spec,
+        "compiles": compiles, "headroom": headroom, "config": config,
+        "device": dict(device, memory_peak_bytes=out["peak_bytes"]),
+    }
+
+
+def loadgen_rate(log_port: int, spec: fx.LogSpec, pages: int = 48) -> float:
+    """Entries per second the log server alone serves: warm-up pages
+    fetched back to back by one thread that does nothing with them."""
+    conn = http.client.HTTPConnection("127.0.0.1", log_port, timeout=30)
+    t0 = time.monotonic()
+    got = 0
+    for k in range(pages):
+        start = (k * spec.page) % spec.warmup_entries
+        conn.request("GET", f"/log0/ct/v1/get-entries?start={start}"
+                            f"&end={start + spec.page - 1}")
+        conn.getresponse().read()
+        got += spec.page
+    conn.close()
+    return got / (time.monotonic() - t0)

@@ -1,0 +1,30 @@
+"""A number the harness itself took, by the host's clock or from JAX:
+params ``which`` names it.
+"""
+
+
+def read(params: dict, ctx: dict):
+    which = params["which"]
+    out = ctx["out"]
+    if which == "loadgen_headroom_x":
+        # Entries per second the log server serves alone, over the rate
+        # at which the window took them.
+        return ctx["headroom"] / (ctx["entries"] / ctx["seconds"])
+    if which == "ckpt_mb_per_s":
+        # The table's bytes over the time from the round's last fold to
+        # idle: the round's checkpoint and the cursor save, nothing else.
+        bits = int(ctx["config"]["directives"]["tableBits"])
+        return (1 << bits) * 32 / 1e6 / (out["t_durable"]
+                                         - out["t_round_folded"])
+    if which == "drain_s":
+        # From the response that carries the round's last entry to the
+        # checkpoint and cursor on disk (/healthz idle).
+        return out["t_durable"] - out["t_last_page"]
+    if which == "compile_programs":
+        return float(len(ctx["compiles"].events))
+    if which == "compile_seconds":
+        return sum(s for _, s in ctx["compiles"].events)
+    if which == "peak_hbm_gb":
+        peak = ctx["device"]["memory_peak_bytes"]
+        return None if peak is None else peak / 1e9
+    raise ValueError(f"unknown harness number {which!r}")

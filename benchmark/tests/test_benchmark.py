@@ -1,0 +1,332 @@
+"""Tests of the benchmark's own arithmetic and of its comparison, run by
+hand on the CPU:  python3 -m pytest benchmark/tests -q
+
+The rehearsals drive a whole run through ``harness.run_cell`` at a tiny
+table, without the look for a chip that ``run.py`` makes first.
+"""
+
+from __future__ import annotations
+
+import base64
+import gzip
+import json
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+ROOT = os.path.dirname(BENCH)
+sys.path.insert(0, BENCH)
+
+import fixture as fx  # noqa: E402
+import harness  # noqa: E402
+import kernel_cost  # noqa: E402
+import logserver  # noqa: E402
+import tracing  # noqa: E402
+
+SPEC = dict(logs=2, page=64, dup_share=0.05,
+            leaf_mix={"rsa2048": 0.7, "ec_p256": 0.3}, issuers=16,
+            zipf_s=1.1, warmup_entries=1024, window_entries=4096)
+
+
+def test_window_is_whole_batches():
+    assert fx.window_entries(9000, 35) == 5 * 65536
+    assert fx.window_entries(9000, 20) == 3 * 65536
+    assert fx.window_entries(100, 1) == 2 * 65536  # never under two
+    assert fx.window_entries(9000, 35, logs=3) == 6 * 65536
+    for seconds in (1, 10, 20, 35, 51):
+        assert fx.window_entries(7000, seconds) % 65536 == 0
+
+
+@pytest.mark.parametrize("seed", [0, 7, 2**31 + 12345])
+def test_fixture_counts_against_a_slow_recount(seed):
+    """N - D and the per-issuer counts, recounted entry by entry."""
+    run = fx.RunFixture(fx.LogSpec(**SPEC), seed)
+    seen: dict[int, int] = {}
+    for log in run.logs:
+        assert log.total == (1024 if log.index == 0 else 0) + 2048
+        for i in range(log.total):
+            serial = int(log.serial_of[i])
+            if log.is_dup[i]:
+                assert serial in seen  # repeats an earlier entry's
+                assert seen[serial] == int(log.issuer_of[i])
+            else:
+                assert serial not in seen
+                seen[serial] = int(log.issuer_of[i])
+    assert run.expected_unique() == len(seen) == run.offered - run.duplicates
+    recount = np.bincount(list(seen.values()), minlength=16)
+    assert (run.expected_by_issuer() == recount).all()
+    assert run.duplicates > 0
+    # Zipf: the first issuer signs most.
+    assert recount[0] == recount.max()
+    again = fx.RunFixture(fx.LogSpec(**SPEC), seed)
+    assert (again.logs[1].serial_of == run.logs[1].serial_of).all()
+
+
+def test_pages_are_rfc6962_and_carry_the_fixture():
+    x509 = pytest.importorskip("cryptography.x509")
+    import hashlib
+
+    from cryptography.hazmat.primitives import serialization
+
+    tpl = fx.Templates()
+    log = fx.RunFixture(fx.LogSpec(**SPEC), 5).logs[0]
+    doc = json.loads(log.page_body(tpl, 128, 999))
+    assert len(doc["entries"]) == 64  # cut to a page
+    for j, e in enumerate(doc["entries"]):
+        leaf = base64.b64decode(e["leaf_input"])
+        assert leaf[:2] == b"\x00\x00" and leaf[10:12] == b"\x00\x00"
+        n = int.from_bytes(leaf[12:15], "big")
+        der, ext = leaf[15:15 + n], leaf[15 + n:]
+        assert ext == b"\x00\x00"
+        cert = x509.load_der_x509_certificate(der)
+        want = int(log.serial_of[128 + j])
+        assert cert.serial_number == int("4d" + "%030x" % want, 16)
+        extra = base64.b64decode(e["extra_data"])
+        m = int.from_bytes(extra[3:6], "big")
+        ca = x509.load_der_x509_certificate(extra[6:6 + m])
+        assert cert.issuer == ca.subject
+        spki = ca.public_key().public_bytes(
+            serialization.Encoding.DER,
+            serialization.PublicFormat.SubjectPublicKeyInfo)
+        assert base64.urlsafe_b64encode(hashlib.sha256(spki).digest()) \
+            .decode() == tpl.issuer_ids[int(log.issuer_of[128 + j])]
+
+
+def test_log_server_serves_the_fixture_pages_from_memory():
+    spec = fx.LogSpec(**dict(SPEC, ramp_entries=2048, tail_entries=2048))
+    state = logserver.LogState(spec, 11)
+    tpl = fx.Templates()
+    for log in state.run.logs:
+        assert log.total == (1024 if log.index == 0 else 0) + 4096
+        starts = [s for k, s in state.bodies if k == log.index]
+        assert sorted(starts) == list(range(0, log.total, 64))
+        for start in (0, 64, log.total - 64):
+            assert state.bodies[log.index, start] == \
+                log.page_body(tpl, start, start + 999)
+    assert state.tree_size(state.run.logs[0]) == 1024
+    assert state.tree_size(state.run.logs[1]) == 0
+
+
+def test_kernel_cost():
+    cost = kernel_cost.sha256_single_block(65536)
+    assert cost["hbm_bytes"] == 65536 * 96
+    assert cost["int32_ops"] == 65536 * (48 * 21 + 64 * 36 + 8)
+
+
+def test_trace_reduction_synthetic():
+    xp = {"devices": {"/device:TPU:0": {
+        "ops": [("a", 0.0, 1.0), ("b", 0.5, 1.0), ("a", 5.0, 1.0),
+                ("k", 6.00001, 0.5)],
+        "modules": [("jit_x", 0.0, 1.5)]}}, "host": []}
+    spans = {"x": [(1.0, 3.0)], "y": [(2.0, 4.0), (4.5, 5.0)]}
+    r = tracing.reduce_trace(xp, 0.0, 6.50001, spans)
+    assert r["busy_s"] == pytest.approx(3.0)
+    gaps = dict(r["idle_gaps"])
+    assert gaps["x"] == pytest.approx(1.125)
+    assert gaps["y"] == pytest.approx(1.875)
+    assert gaps["no_span"] == pytest.approx(0.5)
+    assert r["device_ops"][0] == ["a", 2.0]
+    # Clipped to a window: half of the first op, and the gap up to 3.0.
+    r = tracing.reduce_trace(xp, 0.25, 3.0, spans)
+    assert r["busy_s"] == pytest.approx(1.25)
+    assert r["window_s"] == pytest.approx(2.75)
+    assert dict(r["idle_gaps"])["x"] == pytest.approx(1.5 * 1.5 / 2.5)
+    assert tracing.short("%while.74 = (s32[]{:T(128)}, u32[4,1]) while(") \
+        == "while.74"
+    # A span that wraps another leaves the gap to the inner one.
+    r = tracing.reduce_trace(xp, 0.0, 6.50001, {
+        "ingest.decode": [(1.5, 5.0)], "native.decode_batch": [(2.0, 4.0)]})
+    assert dict(r["idle_gaps"]) == pytest.approx(
+        {"native.decode_batch": 2.0, "no_span": 1.5,
+         "within_program": 1e-5}, abs=1e-9)
+
+
+def test_trace_reduction_on_the_recorded_trace():
+    """A traced window of ``ct-fetch`` ingest on a TPU v5e, flattened by
+    ``tracing.load_xplane`` and kept as JSON: the numbers below were read
+    off it once by hand-checked arithmetic and must not move."""
+    path = os.path.join(HERE, "data", "recorded_trace.json.gz")
+    if not os.path.exists(path):
+        pytest.skip("no recorded trace in this checkout")
+    with gzip.open(path, "rt") as fh:
+        doc = json.load(fh)
+    spans = {k: [tuple(iv) for iv in v] for k, v in doc["host_spans"].items()}
+    lo, hi = doc["window"]
+    r = tracing.reduce_trace(doc["xplane"], lo, hi, spans)
+    plane = next(iter(doc["xplane"]["devices"].values()))
+    assert 0.0 < r["busy_s"] < hi - lo
+    assert r["busy_s"] <= sum(d for _n, _s, d in plane["ops"]) + 1e-9
+    idle = sum(v for _k, v in r["idle_gaps"])
+    assert idle == pytest.approx((hi - lo) - r["busy_s"], abs=1e-3)
+    assert r["busy_s"] == pytest.approx(doc["expect"]["busy_s"], rel=1e-9)
+    assert r["device_ops"][0][0] == doc["expect"]["top_op"]
+    # The readers on it: one 65,536-lane ingest step lies in these five
+    # seconds, 25.2 ms of device time, its SHA kernel 0.278 ms.
+    from readers import device_time, kernel_roofline
+
+    ctx = {"trace": r, "config": {"directives": {"batchSize": 65536}},
+           "device": {"kind": "TPU v5 lite"}}
+    assert device_time.read(
+        {"what": "modules", "match": "ingest", "lanes_per_call": "batchSize",
+         "scale": 1e9}, ctx) == pytest.approx(385.05, abs=0.01)
+    roof = kernel_roofline.read(
+        {"match": "_single_block_pallas", "cost": "sha256_single_block",
+         "lanes_per_call": "batchSize"}, ctx)
+    assert roof == pytest.approx(2.762, abs=0.001) and roof < 100.0
+    assert device_time.read({"what": "idle_pct"}, ctx) \
+        == pytest.approx(99.465, abs=0.001)
+    with pytest.raises(KeyError):
+        kernel_roofline.read(
+            {"match": "_single_block_pallas", "cost": "sha256_single_block",
+             "lanes_per_call": 65536},
+            dict(ctx, device={"kind": "some other chip"}))
+
+
+def rehearse(*args: str) -> dict:
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    res = subprocess.run(
+        [sys.executable, os.path.join(HERE, "rehearse.py"), *args],
+        capture_output=True, text=True, timeout=600, env=env, cwd=ROOT)
+    assert res.stdout.strip(), res.stderr[-2000:]
+    return json.loads(res.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("logs", [1, 3])
+def test_sound_run_is_correct(logs):
+    """The whole run at a tiny table; ``logs`` = 3 is the traffic file's
+    parameter of the cell PERF.md keeps for later."""
+    line = rehearse(str(logs), "31337")
+    assert line["values"]["ingest_entries_per_s"] > 0 and line["failed"] == 0
+    if logs == 1:
+        assert line["correct"] is True
+    else:
+        # Every count is exact. What the program does with several logs
+        # today (each log's exit save flushes a partial batch) the run
+        # reports as a batch beyond the fixture's, and may as a compile.
+        assert set(line["not_ok"]) <= {
+            "round: batches folded beyond the fixture's",
+            "round: programs compiled"}
+
+
+def test_traced_rehearsal_reads_the_host_layers():
+    """The traced path end to end; without a chip only the metrics of
+    the host's layers have something to read."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    res = subprocess.run(
+        [sys.executable, os.path.join(HERE, "rehearse.py"), "1", "31339",
+         "trace"], capture_output=True, text=True, timeout=600, env=env,
+        cwd=ROOT)
+    lines = [json.loads(x) for x in res.stdout.strip().splitlines()]
+    assert lines[-1]["correct"] is True, res.stderr[-2000:]
+    metrics = next(x for x in lines if isinstance(x, list))[0]
+    for name in ("fetch.us_per_entry", "decode.ns_per_entry",
+                 "fold.us_per_entry", "ckpt.drain_s", "ckpt.mb_per_s",
+                 "loadgen.headroom_x", "compile.programs"):
+        assert metrics[name]["value"] > 0, name
+    assert "device.idle_pct" not in metrics
+
+
+def test_lost_entry_is_not_correct():
+    """The control, at a size a test can hold: the timed path broken
+    underneath, and ``correct`` comes out false."""
+    line = rehearse("1", "31338", "notrace", "lost_entry")
+    assert line["correct"] is False and line["failed"] == 3
+
+
+def test_deferred_checkpoint_is_not_correct():
+    """The program says idle before the round's checkpoint is on disk.
+    What is compared is the file as it stood at that instant: the
+    warm-up round's."""
+    line = rehearse("1", "31338", "notrace", "deferred_checkpoint")
+    assert line["correct"] is False
+    assert line["not_ok"][0] == "durable report: unique serials"
+    kept = harness.report_child(
+        os.path.join(ROOT, ".bench_work", "report.ini"))
+    assert 0 < kept["totals"]["serials"] <= 1024  # the warm-up batch's
+
+
+def test_run_py_refuses_without_the_chip():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    res = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
+         "backfill-1log", "--seed", "1", "--seconds", "2", "--trace", "0"],
+        capture_output=True, text=True, timeout=300, env=env, cwd=ROOT)
+    assert res.returncode == 3
+    assert res.stdout.strip() == ""
+    assert "needs 1 TPU device" in res.stderr
+
+
+def test_benchmark_json_keeps_the_contract():
+    """The limits the driver checks before any run, as far as a test can
+    restate them."""
+    import re
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+        raw = fh.read()
+    assert len(raw.encode()) <= 64 * 1024
+    b = json.loads(raw)
+    assert set(b) == {"command", "paths", "run_seconds", "configs",
+                      "workloads", "end_to_end", "per_layer"}
+    name = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+    unit = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+    line = lambda s: 1 <= len(s) <= 200 and "\n" not in s and "\t" not in s  # noqa: E731
+    assert isinstance(b["run_seconds"], int) and 1 <= b["run_seconds"] <= 51
+    assert all(line(w) for w in b["command"]) and len(b["command"]) <= 32
+    configs = {c["name"]: c for c in b["configs"]}
+    for c in b["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert name.match(c["name"]) and line(c["source"]) and line(c["why"])
+        assert c["file"].startswith("benchmark/") and os.path.exists(
+            os.path.join(ROOT, c["file"]))
+        assert len(c["reduced"]) <= 16 and all(name.match(k) for k in c["reduced"])
+        with open(os.path.join(ROOT, c["file"])) as fh:
+            assert set(c["reduced"]) == set(json.load(fh)["reduced"])
+    cells = {w["name"] for w in b["workloads"]}
+    assert len(cells) == len(b["workloads"])
+    for w in b["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert name.match(w["name"]) and name.match(w["traffic"])
+        assert w["config"] in configs and w["chips"] in (1, 4) and line(w["why"])
+        assert os.path.exists(os.path.join(BENCH, "traffic",
+                                           w["traffic"] + ".json"))
+    assert {w["config"] for w in b["workloads"]} == set(configs)
+    e2e = {m["name"]: m for m in b["end_to_end"]}
+    assert "setup_s" in e2e and e2e["setup_s"]["bound"] <= 0.25
+    for m in b["end_to_end"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "bound",
+                                          "source"}
+        assert name.match(m["name"]) and unit.match(m["unit"])
+        assert m["better"] in ("lower", "higher") and 0.01 <= m["bound"] <= 0.25
+        assert m["source"] in ("host_clock", "device_trace")
+        assert set(m.get("workloads", cells)) <= cells
+    for cell in cells:
+        mine = [m for m in b["end_to_end"] if cell in m.get("workloads", cells)]
+        assert len(mine) >= 2 and "setup_s" in {m["name"] for m in mine}
+    names = set(e2e)
+    for m in b["per_layer"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
+                                          "layer", "moves"}
+        assert name.match(m["name"]) and unit.match(m["unit"])
+        assert m["name"] not in names
+        names.add(m["name"])
+        assert m["source"] in ("device_trace", "program_span",
+                               "program_counter", "host_clock")
+        assert line(m["layer"]) and m["moves"] in e2e
+        for cell in m.get("workloads", cells):
+            assert cell in e2e[m["moves"]].get("workloads", cells)
+        assert os.path.exists(os.path.join(BENCH, "layers",
+                                           m["name"] + ".json"))
+        if m["name"].endswith("_roofline"):
+            assert m["unit"] == "%"
+    bad = re.compile(r"[^A-Za-z0-9_.\-/]")
+    for base, _dirs, files in os.walk(BENCH):
+        if "__pycache__" in base:
+            continue
+        for f in files:
+            rel = os.path.relpath(os.path.join(base, f), ROOT)
+            assert not bad.search(rel), rel
