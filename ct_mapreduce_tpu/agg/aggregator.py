@@ -58,7 +58,11 @@ from ct_mapreduce_tpu.filter.cache import content_token, serial_hash
 from ct_mapreduce_tpu.filter.spill import SpillCaptureRing
 from ct_mapreduce_tpu.ops import buckettable, der_kernel, hashtable, pipeline
 from ct_mapreduce_tpu.telemetry import trace
-from ct_mapreduce_tpu.telemetry.metrics import incr_counter, set_gauge
+from ct_mapreduce_tpu.telemetry.metrics import (
+    incr_counter,
+    measure,
+    set_gauge,
+)
 
 
 # Layout selection lives beside the insert dispatch (CTMR_TABLE,
@@ -173,6 +177,8 @@ class PendingIngest:
     instead of blocking on N's readback.
     """
 
+    batch = 0  # the raw chunk's number in the trace; the sink sets it
+
     def __init__(self, agg: "TpuAggregator", chunks, res: IngestResult,
                  data: np.ndarray, length: np.ndarray) -> None:
         self._agg = agg
@@ -257,6 +263,8 @@ class PendingPreparsed:
     step's single packed array (plus the overflow-bitmask fallback on
     a compacted-flag spill) instead of twelve per-lane buffers."""
 
+    batch = 0  # as PendingIngest's
+
     def __init__(self, agg: "TpuAggregator", out, plan: _PreparsedPlan,
                  res: IngestResult) -> None:
         self._agg = agg
@@ -320,6 +328,8 @@ class PendingStaged:
     K chunks through the very same ``_consume_out``/``_host_lanes``
     code the serial path uses."""
 
+    batch = 0  # as PendingIngest's
+
     def __init__(self, agg: "TpuAggregator", out, chunks,
                  res: IngestResult, chunk_width: int) -> None:
         self._agg = agg
@@ -342,7 +352,8 @@ class PendingStaged:
                 agg._inflight_lanes = max(
                     0, agg._inflight_lanes - len(self._res.was_unknown))
                 res = self._res
-                P = np.asarray(self._out.packed)  # the one packed read
+                with trace.span("fold.wait_device", cat="fold"):
+                    P = np.asarray(self._out.packed)  # the one packed read
                 counts = np.asarray(self._out.issuer_unknown_counts)
                 serials = (np.asarray(self._out.serials)
                            if agg.want_serials else None)
@@ -500,6 +511,9 @@ class TpuAggregator:
         # twice concurrently is waste and widens buffer-lifetime
         # exposure for no benefit.
         self._save_lock = threading.Lock()
+        # What the save in progress wrote: (kind, bytes in, bytes out);
+        # under the save lock.
+        self._save_note = ("noop", 0, 0)
         self.table = self._make_table(capacity)
         # Bucket tables round capacity up to whole buckets; load-factor
         # arithmetic must use the real slot count.
@@ -1572,7 +1586,8 @@ class TpuAggregator:
             # separate buffer read is its own D2H round trip — see
             # _pack_out). wu/etc. are fresh arrays, so the
             # cross-encoding guard below may flip lanes freely.
-            P = np.asarray(_pack_out(out))
+            with trace.span("fold.wait_device", cat="fold"):
+                P = np.asarray(_pack_out(out))
             flags = P[0]
             hl = (flags & 1) != 0
             wu = ((flags >> 1) & 1) != 0
@@ -1980,7 +1995,11 @@ class TpuAggregator:
         land before the manifest that names them, so a torn tick is
         invisible to the loader.
         """
-        with self._save_lock:
+        # A save a cursor save causes carries that span's ``reason``
+        # (exit / savePeriod / fleet); one with none is its caller's
+        # own: ct-fetch's at a round's end, an idle fleet tick's.
+        with measure("ckpt", "save"), self._save_lock, \
+                trace.span("ckpt.save", cat="ckpt") as sp:
             self.complete_outstanding()
             knobs = self._ckpt_resolved()
             wrote_segment = False
@@ -1996,6 +2015,9 @@ class TpuAggregator:
                         wrote_segment = self._save_segment(path, man)
             if not wrote_segment:
                 self._save_full(path, knobs, compacting=compacting)
+            kind, bytes_in, bytes_out = self._save_note
+            sp.set(kind=kind, bytes_in=bytes_in, bytes_out=bytes_out)
+        incr_counter("ckpt", "bytes_written", value=float(bytes_out))
         # Filter emission runs OUTSIDE the save lock (the checkpoint
         # bytes above are already durable): a multi-second scaled
         # build must not block the fleet-cadence save fan-out or a
@@ -2036,8 +2058,6 @@ class TpuAggregator:
         try:
             with os.fdopen(fd, "wb") as fh:
                 self._write_npz(fh, host_items)
-                fh.flush()
-                os.fsync(fh.fileno())
             os.replace(tmp_path, path)
         except BaseException:
             with contextlib.suppress(OSError):
@@ -2047,16 +2067,19 @@ class TpuAggregator:
             self._ckpt_mark_dirty_lost("base save failed")
             raise
         incr_counter("ckpt", "full_saves")
+        written = os.path.getsize(path)
+        self._save_note = ("full", int(self.table.rows.nbytes), written)
         if knobs.mode == ckpt.MODE_INCREMENTAL:
             ckpt.kill_point("base-post-rename")
-            base_sha = ckpt.file_sha256(path)
-            ckpt.write_manifest(path, {
-                "format": ckpt.FORMAT,
-                "baseSha256": base_sha,
-                "maxChain": knobs.max_chain,
-                "chain": [],
-            })
-            ckpt.cleanup_segments(path)
+            with trace.span("ckpt.seal", cat="ckpt", bytes=written):
+                base_sha = ckpt.file_sha256(path)
+                ckpt.write_manifest(path, {
+                    "format": ckpt.FORMAT,
+                    "baseSha256": base_sha,
+                    "maxChain": knobs.max_chain,
+                    "chain": [],
+                })
+                ckpt.cleanup_segments(path)
             self._ckpt_path = path
             self._ckpt_base_sha = base_sha
             self._ckpt_tip_token = base_sha
@@ -2127,6 +2150,7 @@ class TpuAggregator:
             # Nothing churned since the last durable tick: the chain
             # on disk already restores to exactly this state.
             self._ckpt_shadow = shadow
+            self._save_note = ("noop", 0, 0)
             return True
         seq = self._ckpt_chain_len + 1
         data, header = ckpt.encode_segment(
@@ -2151,6 +2175,9 @@ class TpuAggregator:
         self._ckpt_tip_token = header["targetSha256"]
         self._ckpt_chain_len = seq
         self._ckpt_shadow = shadow
+        self._save_note = (
+            "segment", header["devRowBytes"] + header["hostRowBytes"],
+            len(data))
         incr_counter("ckpt", "segments_written")
         incr_counter("ckpt", "segment_bytes", value=float(len(data)))
         incr_counter("ckpt", "dirty_rows",
@@ -2326,6 +2353,9 @@ class TpuAggregator:
                   f"{type(err).__name__}: {err}", file=sys.stderr)
 
     def _write_npz(self, fh, host_items) -> None:
+        """The whole snapshot into ``fh``, flushed and synced: the
+        table's copy off the device (``ckpt.d2h``), then compression
+        and the write (``ckpt.write``)."""
         layout = ("bucket" if isinstance(self.table, buckettable.BucketTable)
                   else "open")
         # ONE device fetch for the whole table: the .keys/.meta
@@ -2337,8 +2367,9 @@ class TpuAggregator:
         # device memory whose lifetime it doesn't own (table swaps and
         # donation policies are backend-dependent); the copy bounds
         # the exposure to a memcpy made while swaps are locked out.
-        with self._table_lock:
+        with trace.span("ckpt.d2h", cat="ckpt") as sp, self._table_lock:
             rows = np.array(self.table.rows, copy=True)
+            sp.set(bytes=int(rows.nbytes))
         if layout == "bucket":
             slots = rows[:, : buckettable.SLOTS * 5].reshape(-1, 5)
         else:
@@ -2370,50 +2401,54 @@ class TpuAggregator:
                 extra["filter_hashes"] = np.array(
                     [format(hashes.get((i, e), 0), "032x").encode()
                      for i, e, _ in f_items], dtype=object)
-        np.savez_compressed(
-            fh,
-            # (keys, meta, count) stays the cross-version wire format;
-            # `layout` records slot positioning (bucket i//SLOTS vs
-            # open-addressed chains) and `n_shards` the key-routing
-            # topology, so restore rebuilds the same structure — or
-            # re-hashes via the reinsertion path (_restore_table /
-            # ShardedDedup.bulk_insert_np) when either differs.
-            layout=np.array(layout),
-            n_shards=np.int64(self._topology_shards()),
-            keys=slots[:, :4],
-            meta=slots[:, 4],
-            count=np.asarray(self.table.count),
-            registry=np.frombuffer(
-                self.registry.to_json().encode(), dtype=np.uint8
-            ),
-            base_hour=np.int64(self.base_hour),
-            issuer_totals=self.issuer_totals,
-            verify_verified=self.verify_verified,
-            verify_failed=self.verify_failed,
-            host_keys=np.array(
-                [(i, e) for i, e, _ in host_items], dtype=np.int64
-            ).reshape(-1, 2),
-            host_vals=np.array([v for _, _, v in host_items], dtype=object),
-            # json.dumps preserves dict insertion order, so the key
-            # iteration must be sorted too or the serialized bytes
-            # depend on fold arrival order (ctmrlint: determinism).
-            crl_sets=np.frombuffer(
-                json.dumps(
-                    {str(k): sorted(v)
-                     for k, v in sorted(self.crl_sets.items())}
-                ).encode(),
-                dtype=np.uint8,
-            ),
-            dn_sets=np.frombuffer(
-                json.dumps(
-                    {str(k): sorted(v)
-                     for k, v in sorted(self.dn_sets.items())}
-                ).encode(),
-                dtype=np.uint8,
-            ),
-            allow_pickle=True,
-            **extra,
-        )
+        with trace.span("ckpt.write", cat="ckpt") as sp:
+            np.savez_compressed(
+                fh,
+                # (keys, meta, count) stays the cross-version wire format;
+                # `layout` records slot positioning (bucket i//SLOTS vs
+                # open-addressed chains) and `n_shards` the key-routing
+                # topology, so restore rebuilds the same structure — or
+                # re-hashes via the reinsertion path (_restore_table /
+                # ShardedDedup.bulk_insert_np) when either differs.
+                layout=np.array(layout),
+                n_shards=np.int64(self._topology_shards()),
+                keys=slots[:, :4],
+                meta=slots[:, 4],
+                count=np.asarray(self.table.count),
+                registry=np.frombuffer(
+                    self.registry.to_json().encode(), dtype=np.uint8
+                ),
+                base_hour=np.int64(self.base_hour),
+                issuer_totals=self.issuer_totals,
+                verify_verified=self.verify_verified,
+                verify_failed=self.verify_failed,
+                host_keys=np.array(
+                    [(i, e) for i, e, _ in host_items], dtype=np.int64
+                ).reshape(-1, 2),
+                host_vals=np.array([v for _, _, v in host_items], dtype=object),
+                # json.dumps preserves dict insertion order, so the key
+                # iteration must be sorted too or the serialized bytes
+                # depend on fold arrival order (ctmrlint: determinism).
+                crl_sets=np.frombuffer(
+                    json.dumps(
+                        {str(k): sorted(v)
+                         for k, v in sorted(self.crl_sets.items())}
+                    ).encode(),
+                    dtype=np.uint8,
+                ),
+                dn_sets=np.frombuffer(
+                    json.dumps(
+                        {str(k): sorted(v)
+                         for k, v in sorted(self.dn_sets.items())}
+                    ).encode(),
+                    dtype=np.uint8,
+                ),
+                allow_pickle=True,
+                **extra,
+            )
+            fh.flush()
+            os.fsync(fh.fileno())
+            sp.set(bytes=fh.tell())
 
     def _asarray(self, arr: np.ndarray):
         """Checkpoint rows → table-state arrays (device put). The

@@ -31,6 +31,7 @@ import urllib.request
 from dataclasses import dataclass
 from typing import Callable, Optional
 
+from ct_mapreduce_tpu.telemetry import trace
 from ct_mapreduce_tpu.telemetry.metrics import incr_counter, measure
 from ct_mapreduce_tpu.utils.backoff import JitteredBackoff
 
@@ -109,13 +110,18 @@ class CTLogClient:
 
     # -- plumbing --------------------------------------------------------
     def _get_json(self, path: str) -> dict:
+        return json.loads(self._get_body(path)[0])
+
+    def _get_body(self, path: str) -> tuple[bytes, int]:
+        """The 200 response's body and the attempts it took; retries
+        and their sleeps happen in here."""
         url = f"{self.log_url}/ct/v1/{path}"
         backoff = JitteredBackoff(min_s=0.5, max_s=300.0)
         status = 429
-        for _ in range(self.max_retries):
+        for attempt in range(1, self.max_retries + 1):
             status, headers, body = self.transport(url)
             if status == 200:
-                return json.loads(body)
+                return body, attempt
             if status in RETRYABLE_STATUSES:
                 # ct-fetch.go:426-437: jittered 500ms-5min, honor
                 # Retry-After seconds when the server sends one. 5xx
@@ -167,22 +173,30 @@ class CTLogClient:
         if end < start:
             return []
         end = min(end, start + self.page_size - 1)
+        # The getRawEntries timer keeps what it always held (socket
+        # wait, body read and JSON parse); the two spans divide it.
         with measure("LogWorker", self.short_url, "getRawEntries"):
-            obj = self._get_json(f"get-entries?start={start}&end={end}")
-        entries = obj.get("entries", [])
+            with trace.span("fetch.get_entries", cat="fetch") as sp:
+                body, attempts = self._get_body(
+                    f"get-entries?start={start}&end={end}")
+                sp.set(bytes=len(body), attempts=attempts)
+            with trace.span("fetch.parse_json", cat="fetch") as sp:
+                entries = json.loads(body).get("entries", [])
+                sp.set(n=len(entries))
         if 0 < len(entries) < end - start + 1:
             # Short page on a full-window ask: adopt the server's size.
             if len(entries) < self.page_size:
                 self.page_size = len(entries)
                 incr_counter("ingest", "window_clamp")
-        return [
-            RawEntry(
-                index=start + i,
-                leaf_input=e["leaf_input"],
-                extra_data=e.get("extra_data", ""),
-            )
-            for i, e in enumerate(entries)
-        ]
+        with trace.span("fetch.parse_json", cat="fetch", n=len(entries)):
+            return [
+                RawEntry(
+                    index=start + i,
+                    leaf_input=e["leaf_input"],
+                    extra_data=e.get("extra_data", ""),
+                )
+                for i, e in enumerate(entries)
+            ]
 
     def get_entry_and_proof(self, index: int, tree_size: int) -> dict:
         """ct-getcert's fetch path (get-entry-and-proof)."""

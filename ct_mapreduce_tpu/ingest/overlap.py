@@ -267,7 +267,8 @@ class OverlapIngestPipeline:
         t0 = time.perf_counter()
         try:
             with trace.span("ingest.decode", cat="ingest",
-                            entries=len(pairs)):
+                            entries=len(pairs),
+                            batch=getattr(pairs, "batch", 0)):
                 return self._sink._prepare_chunk(pairs)
         finally:
             self._add_busy("decode", time.perf_counter() - t0)
@@ -303,7 +304,8 @@ class OverlapIngestPipeline:
             # gauge / the bench's e2e dispatch budget.
             t_lock = time.perf_counter()
             try:
-                with trace.span("ingest.submit_locked", cat="ingest"), \
+                with trace.span("ingest.submit_locked", cat="ingest",
+                                batch=getattr(prep, "batch", 0)), \
                         self._sink._dispatch_lock:
                     lock_s = time.perf_counter() - t_lock
                     self._add_busy("lock", lock_s)
@@ -360,7 +362,8 @@ class OverlapIngestPipeline:
             kind, payload, der_of = item
             t0 = time.perf_counter()
             try:
-                with trace.span("ingest.drain", cat="ingest"):
+                with trace.span("ingest.drain", cat="ingest",
+                                batch=getattr(payload, "batch", 0)):
                     if kind == "pending":
                         self._sink._complete_item(payload, der_of)
                     else:  # "result": oversized exact lane, already folded

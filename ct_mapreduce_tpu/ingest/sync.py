@@ -40,7 +40,11 @@ from typing import Optional, Protocol
 
 from ct_mapreduce_tpu.core import der as hostder
 from ct_mapreduce_tpu.core.types import CertificateLog
-from ct_mapreduce_tpu.ingest.ctclient import BATCH_SIZE, CTLogClient
+from ct_mapreduce_tpu.ingest.ctclient import (
+    BATCH_SIZE,
+    CTLogClient,
+    short_url,
+)
 from ct_mapreduce_tpu.ingest.leaf import (
     DecodedEntry,
     LeafDecodeError,
@@ -189,7 +193,8 @@ class AggregatorSink:
         self._allocated: set[tuple[str, str]] = set()
         self._pem_lock = threading.Lock()  # overlap drains from a thread
         self._pending: list[tuple[bytes, bytes]] = []
-        self._pending_raw: list[tuple[str, str]] = []
+        self._pending_raw = _RawChunk()
+        self._batch_seq = 0  # raw chunks cut so far; under _lock
         self._lock = threading.Lock()
         self._dispatch_lock = threading.Lock()  # one device stream
         # Host↔device pipelining (deviceQueueDepth, SURVEY §2.2 PP row;
@@ -312,28 +317,40 @@ class AggregatorSink:
     def store_raw_batch(self, raw: "RawBatch") -> None:
         """Accumulate an undecoded get-entries response; decoded and
         dispatched natively in flush-size chunks."""
-        pairs = list(zip(raw.leaf_inputs, raw.extra_datas))
-        chunk: Optional[list[tuple[str, str]]] = None
-        with self._lock:
-            self._pending_raw.extend(pairs)
-            self.entries_in += len(pairs)
-            if len(self._pending_raw) >= self.flush_size:
-                chunk, self._pending_raw = self._pending_raw, []
+        chunk: Optional[_RawChunk] = None
+        with trace.span("sink.accumulate", cat="sink", n=len(raw)) as sp:
+            pairs = list(zip(raw.leaf_inputs, raw.extra_datas))
+            with self._lock:
+                self._pending_raw.extend(pairs)
+                self._pending_raw.add_page(raw)
+                self.entries_in += len(pairs)
+                if len(self._pending_raw) >= self.flush_size:
+                    chunk = self._cut_raw()
+                    sp.set(batch=chunk.batch, pages=chunk.pages)
         if chunk:
             self._dispatch_raw(chunk)
 
-    def _dispatch_raw(self, pairs: list[tuple[str, str]]) -> None:
+    def _cut_raw(self) -> "_RawChunk":
+        """Take what has accumulated as one chunk and number it: the
+        ``batch`` every span it causes carries. Caller holds ``_lock``."""
+        chunk, self._pending_raw = self._pending_raw, _RawChunk()
+        self._batch_seq += 1
+        chunk.batch = self._batch_seq
+        return chunk
+
+    def _dispatch_raw(self, pairs: "_RawChunk") -> None:
         if self._overlap is not None:
             # Overlapped mode: the chunk enters the three-stage
             # scheduler; decode happens on its pool, submission on its
             # ordered submit thread, completion on its drain consumer.
             self._overlap.submit_chunk(pairs)
             return
-        with trace.span("ingest.decode", cat="ingest", entries=len(pairs)):
+        with trace.span("ingest.decode", cat="ingest", entries=len(pairs),
+                        batch=pairs.batch):
             prep = self._prepare_chunk(pairs)
         t_lock = time.monotonic()
-        with trace.span("ingest.submit_locked", cat="ingest"), \
-                self._dispatch_lock:
+        with trace.span("ingest.submit_locked", cat="ingest",
+                        batch=pairs.batch), self._dispatch_lock:
             # Lock wait sampled apart from the storeCertificate
             # envelope (see ingest/overlap.py's submit loop): multiple
             # store workers contend here, and the wait is not submit
@@ -361,7 +378,6 @@ class AggregatorSink:
         """Stage 1 — decode + pack + H2D submit, NO aggregator-state
         mutation beyond the (thread-safe) issuer registry: safe to run
         on any thread, concurrently with device work and drains."""
-        from ct_mapreduce_tpu.ingest.leaf import LeafDecodeError, decode_entry
         from ct_mapreduce_tpu.native import leafpack
 
         lis = [p[0] for p in pairs]
@@ -382,7 +398,6 @@ class AggregatorSink:
             max_li_raw = max((len(s) for s in lis), default=0) * 3 // 4
             if max_li_raw + 64 <= narrow:
                 pad = narrow
-        t_dec = time.monotonic()
         with metrics.measure("ct-fetch", "decodeBatch"):
             dec = leafpack.decode_raw_batch(
                 lis, eds, pad, workers=self.decode_workers,
@@ -396,16 +411,23 @@ class AggregatorSink:
                     threads=self.decode_threads,
                 )
         # Host-feed observability: the resolved intra-chunk thread
-        # count (gauge) and this chunk's decode cost (ns/entry sample)
-        # — the two numbers that say whether the feed is scaling.
+        # count; the chunk's decode cost is ct-fetch.decodeBatch.
         if len(lis):
             metrics.set_gauge(
                 "ingest", "decode_threads",
                 value=float(leafpack.resolve_threads(
                     len(lis), self.decode_threads or self.decode_workers)))
-            metrics.add_sample(
-                "ingest", "decode_ns_per_entry",
-                value=(time.monotonic() - t_dec) / len(lis) * 1e9)
+        with trace.span("decode.pack", cat="decode"):
+            return self._pack_chunk(pairs, lis, eds, dec)
+
+    def _pack_chunk(self, pairs, lis, eds, dec) -> "_PreparedChunk":
+        """The Python and numpy half of stage 1, after the decoder has
+        returned: narrow view, issuer registry, status accounting, the
+        optional extraction passes and the H2D enqueue."""
+        from ct_mapreduce_tpu.ingest.leaf import LeafDecodeError, decode_entry
+        from ct_mapreduce_tpu.native import leafpack
+
+        narrow = self.PAD_LEN // 2
         # When the batch decoded wide but every cert fits half the
         # pad, ship the narrow view — H2D bytes halve, at the price
         # of one extra compiled step variant.
@@ -562,6 +584,7 @@ class AggregatorSink:
             oversized=oversized, sidecar=sidecar,
             walker_fallback=walker_fallback,
             scts=scts, verify_eligible=verify_eligible,
+            batch=getattr(pairs, "batch", 0),
         )
 
     def _submit_verify(self, prep: "_PreparedChunk") -> None:
@@ -691,6 +714,7 @@ class AggregatorSink:
                                  value=float(buf.nbytes))
         pending = agg.ingest_staged_submit(
             data, length, issuer_idx, valid, host_chunks)
+        pending.batch = ring[0].batch  # an envelope folds as its first
         decs = [p.dec for p in ring]
 
         def der_of(pos, _decs=decs, _b=b):
@@ -741,6 +765,7 @@ class AggregatorSink:
                     prep.data, prep.length, prep.issuer_idx, prep.valid,
                     host_data=prep.host_data,
                 )
+            pending.batch = prep.batch
             dec = prep.dec
             items.append((
                 "pending", pending,
@@ -773,8 +798,14 @@ class AggregatorSink:
         host-lane work for flagged lanes — the counterpart of the
         (async-enqueue) storeCertificate/h2dSubmit samples."""
         with metrics.measure("ct-fetch", "completeBatch"), \
-                trace.span("device.readback", cat="device"):
+                trace.span("device.readback", cat="device",
+                           batch=pending.batch):
             res = pending.complete()
+        # With the completeBatch sample just emitted, the contract a
+        # reader's window rests on (docs/METRICS.md): one sample per
+        # folded batch, in fold order, then the lanes it folded.
+        metrics.incr_counter("ct-fetch", "foldedEntries",
+                             value=float(len(res.was_unknown)))
         self._store_pems(res, der_of)
 
     def _drain_inflight(self, keep: int) -> None:
@@ -785,9 +816,11 @@ class AggregatorSink:
             self._complete_item(pending, der_of)
 
     def flush(self) -> None:
+        raw = None
         with self._lock:
             batch, self._pending = self._pending, []
-            raw, self._pending_raw = self._pending_raw, []
+            if self._pending_raw:
+                raw = self._cut_raw()
         if batch:
             self._dispatch(batch)
         if raw:
@@ -909,12 +942,35 @@ class _PreparedChunk:
     scts: object = None  # verify.sct.SctBatch — verify lane active
     verify_eligible: object = None  # bool[n] — decoded-OK lanes as of
     # extraction time (pre sidecar-split)
+    batch: int = 0  # the raw chunk's number (0: not from a raw cut)
 
 
 @dataclass
 class _QueueItem:
     entry: DecodedEntry
     log_url: str
+
+
+class _RawChunk(list):
+    """The ``(leaf_input, extra_data)`` pairs accumulating toward one
+    device batch, with the pages they came from; the sink's cut gives
+    the chunk its number, the identity its spans share."""
+
+    batch = 0
+
+    def __init__(self) -> None:
+        super().__init__()
+        # [log, first index, last index], the log as fetch.page names it
+        self.pages: list[list] = []
+
+    def add_page(self, raw: "RawBatch") -> None:
+        log = short_url(raw.log_url)
+        first, last = raw.start_index, raw.start_index + len(raw) - 1
+        if (self.pages and self.pages[-1][0] == log
+                and self.pages[-1][2] + 1 == first):
+            self.pages[-1][2] = last  # contiguous: one range
+        else:
+            self.pages.append([log, first, last])
 
 
 @dataclass
@@ -1000,19 +1056,35 @@ class LogWorker:
         aggregate snapshot) at its next batch boundary."""
         self._save_signal.set()
 
-    def save_state(self) -> None:
+    def save_state(self, reason: str = "exit") -> None:
         """Persist the cursor (ct-fetch.go:371-392): dual-written by
         the database facade (cache + backend). ``pre_save`` (e.g. the
         aggregate snapshot) must succeed first — a cursor must never
-        durably advance past entries whose aggregation isn't durable."""
-        if self.pre_save is not None:
-            self.pre_save()
-        self.log_state.max_entry = self.position
-        if self.last_entry_time is not None:
-            self.log_state.last_entry_time = self.last_entry_time
-        self.log_state.last_update_time = datetime.now(timezone.utc)
-        with metrics.measure("LogWorker", self.client.short_url, "saveState"):
-            self.database.save_log_state(self.log_state)
+        durably advance past entries whose aggregation isn't durable.
+        ``reason`` (exit / savePeriod / fleet) names the save in the
+        trace, and the checkpoint it causes."""
+        with trace.span("fetch.save_cursor", cat="fetch",
+                        log=self.client.short_url, position=self.position,
+                        reason=reason):
+            if self.pre_save is not None:
+                self.pre_save()
+            self.log_state.max_entry = self.position
+            if self.last_entry_time is not None:
+                self.log_state.last_entry_time = self.last_entry_time
+            self.log_state.last_update_time = datetime.now(timezone.utc)
+            with metrics.measure("LogWorker", self.client.short_url,
+                                 "saveState"):
+                self.database.save_log_state(self.log_state)
+
+    def _periodic_save(self, next_save: float, save_period_s: float) -> float:
+        """Save if the fleet asked or the period is up; returns when
+        the next periodic save is due."""
+        asked = self._save_signal.is_set()
+        if not asked and time.monotonic() < next_save:
+            return next_save
+        self._save_signal.clear()
+        self.save_state("fleet" if asked else "savePeriod")
+        return time.monotonic() + save_period_s
 
     def run(
         self,
@@ -1055,50 +1127,23 @@ class LogWorker:
         next_save = time.monotonic() + save_period_s
         index = self.position
         while index <= self.end_pos and not stop.is_set():
+            if raw_batches:
+                with trace.span("fetch.page", cat="fetch",
+                                log=self.client.short_url,
+                                start=index) as page:
+                    got = self._fetch_raw_page(index, out, stop, progress)
+                    page.set(n=got)
+                if got <= 0:
+                    break
+                enqueued += got
+                index = self.position
+                next_save = self._periodic_save(next_save, save_period_s)
+                continue
             batch = self.client.get_raw_entries(
                 index, min(index + BATCH_SIZE - 1, self.end_pos)
             )
             if not batch:
                 break
-            if raw_batches:
-                item = RawBatch(
-                    leaf_inputs=[r.leaf_input for r in batch],
-                    extra_datas=[r.extra_data for r in batch],
-                    start_index=batch[0].index,
-                    log_url=self.client.log_url,
-                )
-                submitted = False
-                while not stop.is_set():
-                    try:
-                        out.put(item, timeout=0.25)
-                        submitted = True
-                        break
-                    except queue.Full:
-                        continue
-                if not submitted:
-                    break  # cursor stays put: batch never reached a worker
-                enqueued += len(batch)
-                index = batch[-1].index + 1
-                self.position = index
-                # Last DECODABLE timestamp — a garbage final entry must
-                # not lose the good entries' timestamps (per-entry-path
-                # parity: it updates per decoded entry).
-                for raw in reversed(batch):
-                    ts = decode_leaf_timestamp(raw.leaf_input)
-                    if ts is not None:
-                        self.last_entry_time = datetime.fromtimestamp(
-                            ts / 1000.0, tz=timezone.utc
-                        )
-                        break
-                self._publish_lag()
-                if progress is not None:
-                    progress(self.client.short_url, self.position, self.end_pos)
-                if (self._save_signal.is_set()
-                        or time.monotonic() >= next_save):
-                    self._save_signal.clear()
-                    self.save_state()
-                    next_save = time.monotonic() + save_period_s
-                continue
             for raw in batch:
                 try:
                     with metrics.measure(
@@ -1145,14 +1190,58 @@ class LogWorker:
                 self._publish_lag()
                 if progress is not None:
                     progress(self.client.short_url, self.position, self.end_pos)
-                if (self._save_signal.is_set()
-                        or time.monotonic() >= next_save):
-                    self._save_signal.clear()
-                    self.save_state()
-                    next_save = time.monotonic() + save_period_s
+                next_save = self._periodic_save(next_save, save_period_s)
                 if stop.is_set():
                     break
         return enqueued
+
+    def _fetch_raw_page(self, index: int, out, stop, progress) -> int:
+        """One get-entries response from ``index`` into the queue,
+        undecoded; returns the entries enqueued (0: the log gave none,
+        or the run stopped while the queue was full — the cursor then
+        stays put, the page never reached a worker)."""
+        batch = self.client.get_raw_entries(
+            index, min(index + BATCH_SIZE - 1, self.end_pos)
+        )
+        if not batch:
+            return 0
+        with trace.span("fetch.parse_json", cat="fetch", n=len(batch)):
+            item = RawBatch(
+                leaf_inputs=[r.leaf_input for r in batch],
+                extra_datas=[r.extra_data for r in batch],
+                start_index=batch[0].index,
+                log_url=self.client.log_url,
+            )
+        # Back-pressure: the time the downloader stands still because
+        # the store side has not taken what it already fetched.
+        submitted = False
+        with trace.span("fetch.enqueue", cat="fetch", depth=out.qsize()), \
+                metrics.measure("LogWorker", self.client.short_url,
+                                "submitToChannel"):
+            while not stop.is_set():
+                try:
+                    out.put(item, timeout=0.25)
+                    submitted = True
+                    break
+                except queue.Full:
+                    continue
+        if not submitted:
+            return 0
+        self.position = batch[-1].index + 1
+        # Last DECODABLE timestamp — a garbage final entry must
+        # not lose the good entries' timestamps (per-entry-path
+        # parity: it updates per decoded entry).
+        for raw in reversed(batch):
+            ts = decode_leaf_timestamp(raw.leaf_input)
+            if ts is not None:
+                self.last_entry_time = datetime.fromtimestamp(
+                    ts / 1000.0, tz=timezone.utc
+                )
+                break
+        self._publish_lag()
+        if progress is not None:
+            progress(self.client.short_url, self.position, self.end_pos)
+        return len(batch)
 
 
 class _AccountingQueue:
@@ -1167,6 +1256,9 @@ class _AccountingQueue:
     def put(self, item, timeout=None) -> None:
         self._inner.put(item, timeout=timeout)
         self._on_put(item)
+
+    def qsize(self) -> int:
+        return self._inner.qsize()
 
 
 class LogSyncEngine:
@@ -1241,7 +1333,10 @@ class LogSyncEngine:
     # -- consumers ------------------------------------------------------
     def _store_worker(self) -> None:
         while True:
-            item = self.entry_queue.get()
+            # Starvation: the store side waiting for the downloaders.
+            with trace.span("sink.queue_wait", cat="sink"), \
+                    metrics.measure("ct-fetch", "queueWait"):
+                item = self.entry_queue.get()
             try:
                 if item is None:
                     return
@@ -1294,7 +1389,8 @@ class LogSyncEngine:
         the sink (a per-log watermark — the downloader is the one
         waiting, so its count only drains; other logs keep flowing),
         then run the checkpoint hook to flush + snapshot."""
-        with self._outstanding_cond:
+        with trace.span("ckpt.wait_outstanding", cat="ckpt"), \
+                self._outstanding_cond:
             self._outstanding_cond.wait_for(
                 lambda: self._outstanding.get(log_url, 0) <= 0
             )

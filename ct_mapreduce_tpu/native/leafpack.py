@@ -266,6 +266,17 @@ def _concat_b64(strings: Sequence[str]) -> tuple[bytes, np.ndarray]:
     return b"".join(parts), offs
 
 
+def _concat_inputs(leaf_inputs: Sequence[str],
+                   extra_datas: Sequence[str]) -> tuple:
+    """Both base64 columns as one buffer and offsets each: Python over
+    every string, with the GIL held."""
+    with trace.span("decode.concat_b64", cat="decode") as sp:
+        li_buf, li_off = _concat_b64(leaf_inputs)
+        ed_buf, ed_off = _concat_b64(extra_datas)
+        sp.set(bytes=len(li_buf) + len(ed_buf))
+    return li_buf, li_off, ed_buf, ed_off
+
+
 def decode_raw_batch(
     leaf_inputs: Sequence[str],
     extra_datas: Sequence[str],
@@ -401,8 +412,7 @@ def _decode_native_into(
     share one span), or None on native scratch overflow."""
     n = len(leaf_inputs)
     data, length, ts, ety, status = out
-    li_buf, li_off = _concat_b64(leaf_inputs)
-    ed_buf, ed_off = _concat_b64(extra_datas)
+    li_buf, li_off, ed_buf, ed_off = _concat_inputs(leaf_inputs, extra_datas)
     issuer_off = np.zeros((n,), np.int64)
     issuer_len = np.zeros((n,), np.int32)
     # Issuer chain certs are ~1-2 KB; extra_data is an upper bound.
@@ -417,18 +427,23 @@ def _decode_native_into(
     i32p = ctypes.POINTER(ctypes.c_int32)
     u8p = ctypes.POINTER(ctypes.c_uint8)
 
-    used = lib.ctmr_decode_entries(
-        n,
-        li_buf, li_off.ctypes.data_as(i64p),
-        ed_buf, ed_off.ctypes.data_as(i64p),
-        pad_len,
-        data.ctypes.data_as(u8p), length.ctypes.data_as(i32p),
-        ts.ctypes.data_as(i64p), ety.ctypes.data_as(i32p),
-        issuer_buf.ctypes.data_as(u8p), issuer_cap,
-        issuer_off.ctypes.data_as(i64p), issuer_len.ctypes.data_as(i32p),
-        status.ctypes.data_as(i32p),
-        scratch.ctypes.data_as(u8p), scratch.shape[0],
-    )
+    # The one call that releases the GIL; what stands around it in
+    # native.decode_batch is Python.
+    with trace.span("decode.native_call", cat="decode", threads=1,
+                    pad=int(pad_len)):
+        used = lib.ctmr_decode_entries(
+            n,
+            li_buf, li_off.ctypes.data_as(i64p),
+            ed_buf, ed_off.ctypes.data_as(i64p),
+            pad_len,
+            data.ctypes.data_as(u8p), length.ctypes.data_as(i32p),
+            ts.ctypes.data_as(i64p), ety.ctypes.data_as(i32p),
+            issuer_buf.ctypes.data_as(u8p), issuer_cap,
+            issuer_off.ctypes.data_as(i64p),
+            issuer_len.ctypes.data_as(i32p),
+            status.ctypes.data_as(i32p),
+            scratch.ctypes.data_as(u8p), scratch.shape[0],
+        )
     if used < 0:
         return None
     return issuer_off, issuer_len, issuer_buf[:used]
@@ -449,8 +464,7 @@ def _decode_native_mt(
     when a chunk's issuer slice overflowed (caller retries serial)."""
     n = len(leaf_inputs)
     data, length, ts, ety, status = out
-    li_buf, li_off = _concat_b64(leaf_inputs)
-    ed_buf, ed_off = _concat_b64(extra_datas)
+    li_buf, li_off, ed_buf, ed_off = _concat_inputs(leaf_inputs, extra_datas)
     issuer_off = np.zeros((n,), np.int64)
     issuer_len = np.zeros((n,), np.int32)
     # Chunk bounds mirror the C split exactly: lane [n*t//T, n*(t+1)//T).
@@ -473,19 +487,22 @@ def _decode_native_mt(
     i32p = ctypes.POINTER(ctypes.c_int32)
     u8p = ctypes.POINTER(ctypes.c_uint8)
 
-    rc = lib.ctmr_decode_entries_mt(
-        n,
-        li_buf, li_off.ctypes.data_as(i64p),
-        ed_buf, ed_off.ctypes.data_as(i64p),
-        pad_len,
-        data.ctypes.data_as(u8p), length.ctypes.data_as(i32p),
-        ts.ctypes.data_as(i64p), ety.ctypes.data_as(i32p),
-        issuer_buf.ctypes.data_as(u8p), issuer_buf.shape[0],
-        issuer_off.ctypes.data_as(i64p), issuer_len.ctypes.data_as(i32p),
-        status.ctypes.data_as(i32p),
-        scratch.ctypes.data_as(u8p), scratch_each,
-        threads, chunk_used.ctypes.data_as(i64p),
-    )
+    with trace.span("decode.native_call", cat="decode",
+                    threads=int(threads), pad=int(pad_len)):
+        rc = lib.ctmr_decode_entries_mt(
+            n,
+            li_buf, li_off.ctypes.data_as(i64p),
+            ed_buf, ed_off.ctypes.data_as(i64p),
+            pad_len,
+            data.ctypes.data_as(u8p), length.ctypes.data_as(i32p),
+            ts.ctypes.data_as(i64p), ety.ctypes.data_as(i32p),
+            issuer_buf.ctypes.data_as(u8p), issuer_buf.shape[0],
+            issuer_off.ctypes.data_as(i64p),
+            issuer_len.ctypes.data_as(i32p),
+            status.ctypes.data_as(i32p),
+            scratch.ctypes.data_as(u8p), scratch_each,
+            threads, chunk_used.ctypes.data_as(i64p),
+        )
     if rc < 0:
         return None
     return [

@@ -24,7 +24,22 @@ are per-CHUNK, but the disabled path must still cost nothing):
   ``CTMR_TRACE_JAX=1``) additionally enters a
   ``jax.profiler.TraceAnnotation`` per span, so when a jax profiler
   trace (``profileDir``) runs alongside, the host-side stage spans
-  line up with the device timeline in the same viewer.
+  line up with the device timeline in the same viewer. The span's
+  scalar arguments ride along as the event's stats; the event's name
+  stays the bare span name.
+- **Cause and identity.** Every event carries an ``id`` and the
+  ``parent`` id of the span that enclosed it on the same thread (0 at
+  a thread's root), from a thread-local stack: a span's self time is
+  its ``dur`` less its children's by ``parent``, whatever other
+  threads record under the same name. The identity arguments
+  (``batch``, ``reason``) flow down that stack — a span that does not
+  set one takes its parent's — so every span one ingest batch causes
+  carries the same ``batch`` without each callee being handed it;
+  work that continues on another thread passes ``batch=`` itself.
+  :meth:`_Span.set` adds arguments known only once the work is done.
+- **Drops are counted.** The ring forgets its oldest events;
+  :func:`dropped` (and ``otherData.dropped`` in an export) says how
+  many, so a reader can refuse a window it did not see whole.
 
 Enabling: the ``CTMR_TRACE=<path>`` environment variable (read at
 import, so every entry point — ct-fetch, bench, tests — gets it for
@@ -48,6 +63,7 @@ skew-corrected timeline.
 from __future__ import annotations
 
 import atexit
+import itertools
 import json
 import os
 import threading
@@ -61,8 +77,15 @@ DEFAULT_RING = 1 << 16  # events; ~25 MB worst case, bounds long runs
 # Process-wide attrs (fleet worker id, leader epoch) merged into every
 # recorded event; span-local args win on key collisions.
 _proc_attrs: dict = {}
-# Per-thread trace context: (trace_id, parent_id) or absent.
+# Per-thread trace context: (trace_id, parent_id) or absent; and the
+# thread's stack of live spans (``stack``).
 _ctx = threading.local()
+# Identity arguments: a span that does not set one takes its parent's.
+_INHERITED = ("batch", "reason")
+# What a TraceAnnotation can carry as a stat without breaking the
+# ``name#k=v,...#`` encoding the profiler parses.
+_STAT_BREAKERS = frozenset("#,=")
+_tracer_serials = itertools.count(1)
 
 
 def set_process_attrs(**attrs) -> None:
@@ -172,6 +195,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args):
+        return self
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -179,7 +205,8 @@ _NULL_SPAN = _NullSpan()
 class _Span:
     """One live span; records a complete ("X") event on exit."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_ann")
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_ann",
+                 "id", "parent")
 
     def __init__(self, tracer: "SpanTracer", name: str, cat: str, args):
         self._tracer = tracer
@@ -188,12 +215,36 @@ class _Span:
         self._args = args
         self._ann = None
 
+    def set(self, **args):
+        """Add arguments known only once the work is under way (bytes
+        read, attempts made, the batch a cut produced). They reach the
+        ring, and the spans opened under this one afterwards; the
+        profiler's mirror was given its stats at entry."""
+        self._args.update(args)
+        return self
+
     def __enter__(self):
+        stack = getattr(_ctx, "stack", None)
+        if stack is None:
+            stack = _ctx.stack = []
+        self.id = next(self._tracer._ids)
+        if stack:
+            top = stack[-1]
+            self.parent = top.id
+            for key in _INHERITED:
+                if key in top._args and key not in self._args:
+                    self._args[key] = top._args[key]
+        else:
+            self.parent = 0
+        stack.append(self)
         if self._tracer.jax_annotations:
             try:
                 from jax.profiler import TraceAnnotation
 
-                self._ann = TraceAnnotation(self._name)
+                self._ann = TraceAnnotation(self._name, **{
+                    k: v for k, v in self._args.items()
+                    if isinstance(v, (int, float))
+                    or (isinstance(v, str) and _STAT_BREAKERS.isdisjoint(v))})
                 self._ann.__enter__()
             except Exception:
                 self._ann = None  # tracing must never break the pipeline
@@ -207,8 +258,13 @@ class _Span:
                 self._ann.__exit__(*exc)
             except Exception:
                 pass
+        stack = _ctx.stack
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif self in stack:  # exited out of order: keep the rest sound
+            stack.remove(self)
         self._tracer._complete(self._name, self._cat, self._t0, t1,
-                               self._args)
+                               self._args, self.id, self.parent)
         return False
 
 
@@ -221,6 +277,10 @@ class SpanTracer:
         self.jax_annotations = bool(jax_annotations)
         # deque.append is GIL-atomic: the hot path never takes a lock.
         self._events: deque = deque(maxlen=self.ring_size)
+        self._ids = itertools.count(1)  # next() is GIL-atomic
+        # Events appended, per thread: each entry has one writer, so
+        # the sum is exact with no lock on the hot path.
+        self._appended: dict[int, int] = {}
         self._t0_ns = time.perf_counter_ns()
         # Anchors recorded back to back: wall-clock (place the ring in
         # real time) and CLOCK_MONOTONIC (the clock the fleet fabric's
@@ -230,7 +290,11 @@ class SpanTracer:
         self.mono_t0 = time.monotonic()
         self._pid = os.getpid()
         self._threads_lock = threading.Lock()
-        self._thread_names: dict[int, str] = {}
+        # (ident, name) of every thread that recorded here. The OS
+        # hands a dead thread's ident to the next one, so names are
+        # kept per thread (a thread-local mark), not per ident.
+        self._thread_names: set[tuple[int, str]] = set()
+        self._serial = next(_tracer_serials)
 
     # -- recording -------------------------------------------------------
     def now_us(self) -> float:
@@ -240,11 +304,17 @@ class SpanTracer:
 
     def _tid(self) -> int:
         tid = threading.get_ident()
-        if tid not in self._thread_names:
+        if getattr(_ctx, "named_in", 0) != self._serial:
+            _ctx.named_in = self._serial
             with self._threads_lock:
-                self._thread_names.setdefault(
-                    tid, threading.current_thread().name)
+                self._thread_names.add(
+                    (tid, threading.current_thread().name))
+                self._appended.setdefault(tid, 0)
         return tid
+
+    def _append(self, ev: dict) -> None:
+        self._appended[ev["tid"]] += 1
+        self._events.append(ev)
 
     def _tagged_args(self, args) -> Optional[dict]:
         """Span args merged with the process attrs and the calling
@@ -262,7 +332,7 @@ class SpanTracer:
         return merged
 
     def _complete(self, name: str, cat: str, t0_ns: int, t1_ns: int,
-                  args) -> None:
+                  args, span_id: int, parent: int) -> None:
         ev = {
             "name": name,
             "ph": "X",
@@ -270,18 +340,21 @@ class SpanTracer:
             "dur": max(t1_ns - t0_ns, 0) / 1e3,
             "pid": self._pid,
             "tid": self._tid(),
+            "id": span_id,
+            "parent": parent,
         }
         if cat:
             ev["cat"] = cat
         tagged = self._tagged_args(args)
         if tagged:
             ev["args"] = tagged
-        self._events.append(ev)
+        self._append(ev)
 
     def span(self, name: str, cat: str = "", **args) -> _Span:
         return _Span(self, name, cat, args)
 
     def instant(self, name: str, cat: str = "", **args) -> None:
+        stack = getattr(_ctx, "stack", None)
         ev = {
             "name": name,
             "ph": "i",
@@ -289,13 +362,15 @@ class SpanTracer:
             "ts": self.now_us(),
             "pid": self._pid,
             "tid": self._tid(),
+            "id": next(self._ids),
+            "parent": stack[-1].id if stack else 0,
         }
         if cat:
             ev["cat"] = cat
         tagged = self._tagged_args(args)
         if tagged:
             ev["args"] = tagged
-        self._events.append(ev)
+        self._append(ev)
 
     # -- reading / export ------------------------------------------------
     def events(self) -> list[dict]:
@@ -304,12 +379,25 @@ class SpanTracer:
             meta = [
                 {"name": "thread_name", "ph": "M", "pid": self._pid,
                  "tid": tid, "args": {"name": tname}}
-                for tid, tname in sorted(self._thread_names.items())
+                for tid, tname in sorted(self._thread_names)
             ]
         return meta + list(self._events)
 
+    def dropped(self) -> int:
+        """Events the ring has forgotten since construction (or the
+        last :meth:`clear`). They are always the oldest: a window that
+        starts after the oldest retained event ended was seen whole."""
+        with self._threads_lock:
+            appended = sum(self._appended.values())
+        return max(0, appended - len(self._events))
+
     def clear(self) -> None:
-        self._events.clear()
+        """Empty the ring and its drop count. For a reader between
+        windows: an event appended while this runs may be miscounted."""
+        with self._threads_lock:
+            self._events.clear()
+            for tid in self._appended:
+                self._appended[tid] = 0
 
     def export(self, path: Optional[str] = None) -> Optional[str]:
         """Write the Chrome trace JSON; returns the path (None if no
@@ -325,7 +413,8 @@ class SpanTracer:
                           "mono_t0": self.mono_t0,
                           "pid": self._pid,
                           "process_attrs": get_process_attrs(),
-                          "ring_size": self.ring_size},
+                          "ring_size": self.ring_size,
+                          "dropped": self.dropped()},
         }
         try:
             with open(path, "w") as fh:
@@ -407,6 +496,12 @@ def snapshot_events() -> list[dict]:
     """Current ring contents (for the flight recorder); [] when off."""
     t = _tracer
     return t.events() if t is not None else []
+
+
+def dropped() -> int:
+    """Events the ring has forgotten; 0 when off."""
+    t = _tracer
+    return t.dropped() if t is not None else 0
 
 
 def export(path: Optional[str] = None) -> Optional[str]:

@@ -123,7 +123,7 @@ def test_measure_times_the_block():
 
 def test_snapshot_percentiles():
     """p50/p95/p99 join min/mean/max (the mean hides the tail that
-    matters for dispatchLockWait / decode_ns_per_entry)."""
+    matters for dispatchLockWait / decodeBatch)."""
     sink = InMemSink()
     for i in range(1, 101):
         sink.add_sample("lat", float(i))

@@ -10,8 +10,16 @@ genuinely overlapping.
 
 Usage:
   python tools/traceview.py /tmp/trace.json [--stages name1,name2,...]
+  python tools/traceview.py /tmp/trace.json --batch 7
+  python tools/traceview.py /tmp/trace.json --batches
   python tools/traceview.py --merge w0.json w1.json ... \
       [--skew pairs.json] [--out merged.json]
+
+``--batch N`` prints one ingest batch's lineage: the pages the sink's
+cut recorded for it, then every span that carries ``batch=N`` as a
+tree by ``parent``, per thread, with each span's self time (its
+duration less its children's). ``--batches`` prints one row per batch:
+the decode's phases, the submit, the fold's device wait.
 
 ``--merge`` (round 23) stitches the per-process trace files of a live
 ``tools/fleet.py`` run into ONE Perfetto-loadable timeline: each file
@@ -141,6 +149,81 @@ def stage_summary(events: list[dict], stages=None,
     return out
 
 
+def self_us(spans: list[dict]) -> dict:
+    """``(pid, id)`` -> the span's duration less its children's, by
+    ``parent`` (events of a tracer that records none are left out)."""
+    out = {(e["pid"], e["id"]): e.get("dur", 0.0)
+           for e in spans if "id" in e}
+    for e in spans:
+        up = (e["pid"], e.get("parent", 0))
+        if up in out:
+            out[up] -= e.get("dur", 0.0)
+    return out
+
+
+def batch_lineage(events: list[dict], batch: int) -> list[str]:
+    """The lines ``--batch`` prints."""
+    spans = complete_spans(events)
+    mine = [e for e in spans if e.get("args", {}).get("batch") == batch]
+    if not mine:
+        return []
+    selfs = self_us(spans)
+    threads = {e["tid"]: e["args"]["name"] for e in events
+               if e.get("ph") == "M" and e.get("name") == "thread_name"}
+    t0 = mine[0]["ts"]
+    lines = []
+    for e in mine:
+        for log, first, last in e["args"].get("pages", []):
+            lines.append(f"pages  {log} [{first}, {last}]")
+    ids = {e["id"] for e in mine}
+    kids = defaultdict(list)
+    for e in mine:
+        kids[e["parent"] if e["parent"] in ids else 0].append(e)
+
+    def walk(e, depth):
+        args = {k: v for k, v in e["args"].items()
+                if k not in ("batch", "pages")}
+        lines.append(
+            f"{(e['ts'] - t0) / 1e3:>10.1f} ms  {'  ' * depth}{e['name']}"
+            f"  {e['dur'] / 1e3:.2f} ms"
+            f" (self {selfs[e['pid'], e['id']] / 1e3:.2f})"
+            + (f"  {args}" if args else ""))
+        for k in kids[e["id"]]:
+            walk(k, depth + 1)
+
+    for tid in dict.fromkeys(e["tid"] for e in kids[0]):
+        lines.append(f"thread {threads.get(tid, tid)}")
+        for e in kids[0]:
+            if e["tid"] == tid:
+                walk(e, 1)
+    return lines
+
+
+BATCH_COLUMNS = ("decode.concat_b64", "decode.native_call", "decode.pack",
+                 "native.decode_batch", "ingest.decode", "ingest.submit",
+                 "fold.wait_device", "device.fold")
+
+
+def batch_table(events: list[dict]) -> list[dict]:
+    """One row per batch: milliseconds under each of ``BATCH_COLUMNS``
+    (``native.decode_batch`` as self time: row allocation and issuer
+    grouping), with the native call's ``threads`` and ``pad``."""
+    spans = complete_spans(events)
+    selfs = self_us(spans)
+    rows: dict[int, dict] = {}
+    for e in spans:
+        batch = e.get("args", {}).get("batch")
+        if not batch or e["name"] not in BATCH_COLUMNS:
+            continue
+        row = rows.setdefault(batch, {"batch": batch, "t_s": e["ts"] / 1e6})
+        ms = (selfs[e["pid"], e["id"]] if e["name"] == "native.decode_batch"
+              else e["dur"]) / 1e3
+        row[e["name"]] = row.get(e["name"], 0.0) + ms
+        if e["name"] == "decode.native_call":
+            row["threads"], row["pad"] = e["args"]["threads"], e["args"]["pad"]
+    return [rows[b] for b in sorted(rows)]
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("trace", nargs="+",
@@ -157,6 +240,10 @@ def main(argv=None) -> int:
                          "coordinator fabric (with --merge)")
     ap.add_argument("--out", default="",
                     help="write the merged trace here (with --merge)")
+    ap.add_argument("--batch", type=int, default=0,
+                    help="print this ingest batch's lineage")
+    ap.add_argument("--batches", action="store_true",
+                    help="print one row per ingest batch")
     args = ap.parse_args(argv)
     stages = [s for s in args.stages.split(",") if s] or None
     if args.merge:
@@ -172,6 +259,19 @@ def main(argv=None) -> int:
         return 2
     else:
         events = load(args.trace[0])
+    if args.batch:
+        lines = batch_lineage(events, args.batch)
+        print("\n".join(lines) or f"no span carries batch={args.batch}")
+        return 0 if lines else 1
+    if args.batches:
+        print(f"{'batch':>5} {'t_s':>8} {'thr':>3} {'pad':>5} "
+              + " ".join(f"{c.split('.')[1][:11]:>11}" for c in BATCH_COLUMNS))
+        for row in batch_table(events):
+            print(f"{row['batch']:>5} {row['t_s']:>8.2f} "
+                  f"{row.get('threads', 0):>3} {row.get('pad', 0):>5} "
+                  + " ".join(f"{row.get(c, 0.0):>11.1f}"
+                             for c in BATCH_COLUMNS))
+        return 0
     summary = stage_summary(events, stages=stages)
     wall = summary.pop("_wall_s")
     if not summary:
