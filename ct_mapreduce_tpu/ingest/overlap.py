@@ -7,8 +7,9 @@ the host decoded, the classic host-feed bottleneck that deep request
 pipelining solves (cf. the FPGA ECDSA verification engine's request
 queue, PAPERS.md). This module closes the gap structurally: while
 batch N runs on device, batch N+1 decodes on a background pool through
-the native leafpack path (``decode_raw_batch`` releases the GIL) and
-its H2D transfer is submitted; batch N−1's drain (host-lane readback +
+the native leafpack path (``decode_raw_batch`` releases the GIL for
+its one native call: 41% of a decode on the benchmark's host since
+PR 26, 3% before; PERF.md §5) and its H2D transfer is submitted; batch N−1's drain (host-lane readback +
 backend flush) is consumed from a bounded queue on a dedicated thread.
 With decode and device fully overlapped, e2e wall drops toward
 ``max(decode, device)`` instead of their sum.

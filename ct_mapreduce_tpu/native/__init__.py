@@ -180,6 +180,42 @@ def load() -> Optional[ctypes.CDLL]:
             lib.has_sct = True
         except AttributeError:
             lib.has_sct = False
+        # Un-joined base64 columns (PR 26): the decoder reads each `str`
+        # where it lies. The gather walks Python objects, so it is
+        # called through a PyDLL handle (GIL held) and given the four
+        # CPython functions it needs by address; the decode itself
+        # stays on the GIL-releasing handle. Same stale-library
+        # contract: callers check `has_strs` and join in Python.
+        try:
+            lib.ctmr_decode_entries_strs.restype = ctypes.c_int64
+            lib.ctmr_decode_entries_strs.argtypes = [
+                ctypes.c_int64,
+                ctypes.c_void_p, i64p,
+                ctypes.c_void_p, i64p,
+                ctypes.c_int64,
+                u8p, i32p,
+                i64p, i32p,
+                u8p, ctypes.c_int64,
+                i64p, i32p,
+                i32p,
+                u8p, ctypes.c_int64,
+                ctypes.c_int64, i64p,
+            ]
+            gather = ctypes.PyDLL(so).ctmr_gather_strs
+            gather.restype = ctypes.c_int64
+            gather.argtypes = [
+                ctypes.py_object, ctypes.c_int64,
+                ctypes.c_void_p, i64p,
+            ] + [ctypes.c_void_p] * 4
+            api = ctypes.pythonapi
+            lib.gather_strs = gather
+            lib.gather_pyapi = tuple(
+                ctypes.cast(fn, ctypes.c_void_p)
+                for fn in (api.PyList_GetItem, api.PyUnicode_AsUTF8AndSize,
+                           api.PyUnicode_GetLength, api.PyErr_Clear))
+            lib.has_strs = ctypes.sizeof(ctypes.c_void_p) == 8
+        except (AttributeError, OSError):
+            lib.has_strs = False
         _LIB = lib
         return _LIB
 

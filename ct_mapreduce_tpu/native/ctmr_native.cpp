@@ -195,6 +195,23 @@ uint64_t fnv1a(const uint8_t* p, int64_t n) {
   return h;
 }
 
+// One base64 column of a batch. Entry i is `off[i+1] - off[i]` bytes
+// long (prefix sums, n+1 of them) and starts at `ptr[i]` when the
+// column is n separate strings read where they lie, else at
+// `buf + off[i]` in the one joined buffer.
+struct B64Col {
+  const char* buf;
+  const char* const* ptr;
+  const int64_t* off;
+  const char* at(int64_t i) const { return ptr ? ptr[i] : buf + off[i]; }
+  int64_t size(int64_t i) const { return off[i + 1] - off[i]; }
+  // The column as lane `lo` onward sees it: `off` entries are absolute,
+  // so shifting the index re-bases lanes while byte addressing stays.
+  B64Col from(int64_t lo) const {
+    return B64Col{buf, ptr ? ptr + lo : nullptr, off + lo};
+  }
+};
+
 }  // namespace
 
 extern "C" {
@@ -215,8 +232,9 @@ enum {
 
 // Decode one get-entries batch and pack leaf certificates.
 //
-// Inputs: n entries; leaf_input/extra_data base64 blobs concatenated in
-// `li_buf`/`ed_buf` with offsets (n+1 entries, prefix-sum style).
+// Inputs: n entries; leaf_input/extra_data base64 as one B64Col each
+// (joined in `li_buf`/`ed_buf` with n+1 prefix-sum offsets for the
+// ctmr_decode_entries ABI, or strings in place for _strs).
 // Outputs:
 //   data      [n, pad_len] uint8  — packed certificate DER (zero-padded)
 //   length    [n] int32           — true DER length (0 on error lanes)
@@ -227,10 +245,8 @@ enum {
 //       sequentially; issuer_cap is its capacity.
 //   status    [n] int32
 // Returns bytes used in issuer_buf, or -1 if issuer_buf overflowed.
-int64_t ctmr_decode_entries(
-    int64_t n,
-    const char* li_buf, const int64_t* li_off,
-    const char* ed_buf, const int64_t* ed_off,
+static int64_t decode_entries(
+    int64_t n, const B64Col li_col, const B64Col ed_col,
     int64_t pad_len,
     uint8_t* data, int32_t* length,
     int64_t* ts_ms, int32_t* entry_ty,
@@ -259,8 +275,8 @@ int64_t ctmr_decode_entries(
     std::memset(row, 0, (size_t)pad_len);
 
     // -- leaf_input ---------------------------------------------------
-    const char* li = li_buf + li_off[i];
-    int64_t li_n = li_off[i + 1] - li_off[i];
+    const char* li = li_col.at(i);
+    int64_t li_n = li_col.size(i);
     if ((li_n * 3) / 4 + 4 > scratch_cap) { status[i] = CTMR_BAD_B64; continue; }
     int64_t li_dec = b64_decode(li, li_n, scratch);
     if (li_dec < 0) { status[i] = CTMR_BAD_B64; continue; }
@@ -304,8 +320,8 @@ int64_t ctmr_decode_entries(
     const uint8_t* cert_src = scratch + cert_off;
 
     // -- extra_data ---------------------------------------------------
-    const char* ed = ed_buf + ed_off[i];
-    int64_t ed_n = ed_off[i + 1] - ed_off[i];
+    const char* ed = ed_col.at(i);
+    int64_t ed_n = ed_col.size(i);
     uint8_t* ed_scratch = scratch + (li_dec + 7) / 8 * 8;
     int64_t ed_cap = scratch_cap - (li_dec + 7) / 8 * 8;
     int64_t ed_dec = 0;
@@ -398,6 +414,23 @@ int64_t ctmr_decode_entries(
     issuer_used += chain_issuer_len;
   }
   return issuer_used;
+}
+
+int64_t ctmr_decode_entries(
+    int64_t n,
+    const char* li_buf, const int64_t* li_off,
+    const char* ed_buf, const int64_t* ed_off,
+    int64_t pad_len,
+    uint8_t* data, int32_t* length,
+    int64_t* ts_ms, int32_t* entry_ty,
+    uint8_t* issuer_buf, int64_t issuer_cap,
+    int64_t* issuer_off, int32_t* issuer_len,
+    int32_t* status,
+    uint8_t* scratch, int64_t scratch_cap) {
+  return decode_entries(
+      n, B64Col{li_buf, nullptr, li_off}, B64Col{ed_buf, nullptr, ed_off},
+      pad_len, data, length, ts_ms, entry_ty, issuer_buf, issuer_cap,
+      issuer_off, issuer_len, status, scratch, scratch_cap);
 }
 
 // ---------------------------------------------------------------------
@@ -802,10 +835,8 @@ int64_t ctmr_pack_ders(
 // per-chunk groups by DER bytes in chunk order (= lane order), which
 // reproduces the serial first-appearance group order exactly.
 
-int64_t ctmr_decode_entries_mt(
-    int64_t n,
-    const char* li_buf, const int64_t* li_off,
-    const char* ed_buf, const int64_t* ed_off,
+static int64_t decode_entries_mt(
+    int64_t n, const B64Col li_col, const B64Col ed_col,
     int64_t pad_len,
     uint8_t* data, int32_t* length,
     int64_t* ts_ms, int32_t* entry_ty,
@@ -822,11 +853,8 @@ int64_t ctmr_decode_entries_mt(
   pool::WorkerPool::get().run(T, T, [&](int t) {
     int64_t lo = n * t / T, hi = n * (t + 1) / T;
     int64_t base = (int64_t)t * iss_each;
-    // li_off/ed_off entries are absolute offsets into the shared
-    // buffers, so passing the shifted pointer re-bases lane indexing
-    // while byte addressing stays global.
-    int64_t used = ctmr_decode_entries(
-        hi - lo, li_buf, li_off + lo, ed_buf, ed_off + lo, pad_len,
+    int64_t used = decode_entries(
+        hi - lo, li_col.from(lo), ed_col.from(lo), pad_len,
         data + lo * pad_len, length + lo, ts_ms + lo, entry_ty + lo,
         issuer_buf + base, iss_each, issuer_off + lo, issuer_len + lo,
         status + lo, scratch + (int64_t)t * scratch_each, scratch_each);
@@ -845,6 +873,85 @@ int64_t ctmr_decode_entries_mt(
     total += chunk_used[t];
   }
   return total;
+}
+
+int64_t ctmr_decode_entries_mt(
+    int64_t n,
+    const char* li_buf, const int64_t* li_off,
+    const char* ed_buf, const int64_t* ed_off,
+    int64_t pad_len,
+    uint8_t* data, int32_t* length,
+    int64_t* ts_ms, int32_t* entry_ty,
+    uint8_t* issuer_buf, int64_t issuer_cap,
+    int64_t* issuer_off, int32_t* issuer_len,
+    int32_t* status,
+    uint8_t* scratch, int64_t scratch_each,
+    int64_t threads, int64_t* chunk_used) {
+  return decode_entries_mt(
+      n, B64Col{li_buf, nullptr, li_off}, B64Col{ed_buf, nullptr, ed_off},
+      pad_len, data, length, ts_ms, entry_ty, issuer_buf, issuer_cap,
+      issuer_off, issuer_len, status, scratch, scratch_each, threads,
+      chunk_used);
+}
+
+// The same decode over base64 columns that were never joined: entry i
+// of a column is the `off[i+1] - off[i]` bytes at `ptr[i]`, as
+// ctmr_gather_strs found them inside the batch's Python strings. The
+// caller keeps those strings alive and unchanged for the call; nothing
+// here touches a Python object, so it runs with the GIL released.
+// `threads` 1 is the serial pass (one chunk, the whole issuer_buf).
+int64_t ctmr_decode_entries_strs(
+    int64_t n,
+    const char* const* li_ptr, const int64_t* li_off,
+    const char* const* ed_ptr, const int64_t* ed_off,
+    int64_t pad_len,
+    uint8_t* data, int32_t* length,
+    int64_t* ts_ms, int32_t* entry_ty,
+    uint8_t* issuer_buf, int64_t issuer_cap,
+    int64_t* issuer_off, int32_t* issuer_len,
+    int32_t* status,
+    uint8_t* scratch, int64_t scratch_each,
+    int64_t threads, int64_t* chunk_used) {
+  return decode_entries_mt(
+      n, B64Col{nullptr, li_ptr, li_off}, B64Col{nullptr, ed_ptr, ed_off},
+      pad_len, data, length, ts_ms, entry_ty, issuer_buf, issuer_cap,
+      issuer_off, issuer_len, status, scratch, scratch_each, threads,
+      chunk_used);
+}
+
+// Where the bytes of a list's `str` items lie: ptr[i] and the prefix
+// sums off[0..n] for list[i], without copying a byte. Called with the
+// GIL HELD (the Python side loads this entry point through
+// ctypes.PyDLL) and handed the four CPython functions it needs by
+// address, so the library builds without Python's headers. An item
+// qualifies when it is a `str` whose UTF-8 form is its own storage,
+// i.e. ASCII (utf-8 size == length); for anything else (`bytes`, None,
+// a non-ASCII `str`) the pending error is cleared and -1 returned: the
+// caller then takes the joining path, which raises or decodes as it
+// always has. Returns the column's total bytes.
+typedef void* (*ctmr_list_getitem_fn)(void*, int64_t);
+typedef const char* (*ctmr_as_utf8_fn)(void*, int64_t*);
+typedef int64_t (*ctmr_get_length_fn)(void*);
+typedef void (*ctmr_err_clear_fn)(void);
+
+int64_t ctmr_gather_strs(
+    void* list, int64_t n, const char** ptr, int64_t* off,
+    ctmr_list_getitem_fn list_getitem, ctmr_as_utf8_fn as_utf8,
+    ctmr_get_length_fn get_length, ctmr_err_clear_fn err_clear) {
+  static_assert(sizeof(int64_t) == sizeof(void*), "Py_ssize_t is int64");
+  off[0] = 0;
+  for (int64_t i = 0; i < n; ++i) {
+    void* item = list_getitem(list, i);  // borrowed
+    int64_t size = 0;
+    const char* p = item ? as_utf8(item, &size) : nullptr;
+    if (p == nullptr || get_length(item) != size) {
+      err_clear();
+      return -1;
+    }
+    ptr[i] = p;
+    off[i + 1] = off[i] + size;
+  }
+  return off[n];
 }
 
 void ctmr_extract_sidecars_mt(

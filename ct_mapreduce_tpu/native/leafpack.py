@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import ctypes
 from dataclasses import dataclass
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -266,15 +266,57 @@ def _concat_b64(strings: Sequence[str]) -> tuple[bytes, np.ndarray]:
     return b"".join(parts), offs
 
 
-def _concat_inputs(leaf_inputs: Sequence[str],
-                   extra_datas: Sequence[str]) -> tuple:
-    """Both base64 columns as one buffer and offsets each: Python over
-    every string, with the GIL held."""
+def _gather_strs(lib, strings: Sequence[str]) -> Optional[tuple]:
+    """``(ptr, off, items)`` for a column of ASCII ``str``: ``ptr[i]``
+    is where ``items[i]``'s bytes lie and ``off`` their prefix sums —
+    one native pass, no byte copied. ``items`` is this call's own list
+    of the strings: the pointers are good for as long as it lives.
+    None when some item is anything else (the caller joins)."""
+    items = list(strings)
+    ptr = np.empty((len(items),), np.uintp)
+    off = np.empty((len(items) + 1,), np.int64)
+    total = lib.gather_strs(
+        items, len(items), ptr.ctypes.data,
+        off.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+        *lib.gather_pyapi)
+    return None if total < 0 else (ptr, off, items)
+
+
+class _B64Columns(NamedTuple):
+    """Both base64 columns as the native decoder takes them. Entry i of
+    a column is ``off[i+1] - off[i]`` bytes; ``li``/``ed`` are the
+    joined buffers (``bytes``) when ``owner`` is None, else arrays of
+    pointers into the strings that ``owner`` keeps alive."""
+
+    li: object
+    li_off: np.ndarray  # int64[n + 1]
+    ed: object
+    ed_off: np.ndarray  # int64[n + 1]
+    owner: Optional[tuple]
+
+
+def _b64_columns(lib, leaf_inputs: Sequence[str],
+                 extra_datas: Sequence[str]) -> _B64Columns:
+    """The batch's base64 as the native call reads it, GIL held. Lists
+    of ASCII ``str`` (what the JSON parser hands the fetch layer) are
+    read in place: one C pass a column finds each string's bytes,
+    ``joined`` 0. Anything else (``bytes`` items, a non-ASCII ``str``,
+    a library without the entry point) is encoded and joined into one
+    buffer a column as before, ``joined`` 1, and raises what that
+    always raised."""
     with trace.span("decode.concat_b64", cat="decode") as sp:
-        li_buf, li_off = _concat_b64(leaf_inputs)
-        ed_buf, ed_off = _concat_b64(extra_datas)
-        sp.set(bytes=len(li_buf) + len(ed_buf))
-    return li_buf, li_off, ed_buf, ed_off
+        cols = None
+        if getattr(lib, "has_strs", False):
+            li = _gather_strs(lib, leaf_inputs)
+            ed = _gather_strs(lib, extra_datas) if li else None
+            if ed:
+                cols = _B64Columns(*li[:2], *ed[:2], owner=(li[2], ed[2]))
+        if cols is None:
+            cols = _B64Columns(*_concat_b64(leaf_inputs),
+                               *_concat_b64(extra_datas), owner=None)
+        sp.set(bytes=int(cols.li_off[-1] + cols.ed_off[-1]),
+               joined=int(cols.owner is None))
+    return cols
 
 
 def decode_raw_batch(
@@ -301,17 +343,18 @@ def _decode_raw_batch(
 
     ``threads`` > 1 splits the batch across the native library's
     persistent worker pool — one ctypes call, lane ranges decoded in
-    parallel inside C++ with the GIL released — so on multi-core TPU
-    hosts decode scales with cores (it is the e2e ingest bottleneck at
-    ~200k entries/s per core; a 10M entries/s chip needs tens of
-    decode cores feeding it). ``workers`` is the legacy alias for the
-    same knob (used when ``threads`` is unset). Default: the
+    parallel inside C++ with the GIL released. Measured on the
+    benchmark's TPU v5e host (PERF.md §5, PR 26: 65,536 entries, pad
+    2048, 13 threads): the call takes 32-46 ms, and the Python around
+    it — finding the strings, allocating rows, grouping issuers —
+    another 15-25 ms with the GIL held. ``workers`` is the legacy alias
+    for the same knob (used when ``threads`` is unset). Default: the
     :func:`resolve_threads` policy (``CTMR_DECODE_THREADS`` env →
     ``CTMR_DECODE_WORKERS`` → ``os.cpu_count()``, bounded so each
     chunk keeps >= 2048 entries).
 
     Determinism: per-lane outputs are written by exactly one chunk
-    into disjoint ranges, and per-chunk issuer groups merge by DER
+    into disjoint ranges, and the chunks' issuer spans merge by DER
     bytes in chunk (= lane) order, so the returned
     :class:`DecodedBatch` is byte-identical across thread counts
     (pinned by tests/test_decode_threads.py).
@@ -338,145 +381,93 @@ def _decode_raw_batch(
     status = np.zeros((n,), np.int32)
     out = (data, length, ts, ety, status)
 
-    if t > 1:
-        spans = _decode_native_mt(
-            lib, leaf_inputs, extra_datas, pad_len, out, t)
-        if spans is not None:
-            # Merge per-chunk issuer groups by DER bytes in chunk
-            # order (a handful per chunk — per-group work, never
-            # per-entry). Chunks are contiguous lane ranges in lane
-            # order, so the merged group order equals the serial
-            # pass's first-appearance order.
-            group = np.full((n,), -1, np.int32)
-            group_issuers: list = []
-            gid_of: dict = {}
-            for (lo, hi), span in spans:
-                c_group, c_issuers = _issuer_groups(hi - lo, *span)
-                remap = np.full((len(c_issuers) + 1,), -1, np.int32)
-                for g, der in enumerate(c_issuers):
-                    remap[g] = _assign_gid(gid_of, group_issuers, der)
-                group[lo:hi] = remap[c_group]
-            return DecodedBatch(data, length, ts, ety, None, status,
-                                issuer_group=group,
-                                group_issuers=group_issuers)
+    cols = _b64_columns(lib, leaf_inputs, extra_datas)
+    span = _decode_native(lib, cols, pad_len, out, t)
+    if span is None and t > 1:
         # A chunk's issuer slice overflowed (pathologically skewed
         # extra_data) — retry serial with the undivided buffer.
-
-    span = _decode_native_into(lib, leaf_inputs, extra_datas, pad_len, out)
+        t = 1
+        span = _decode_native(lib, cols, pad_len, out, t)
     if span is None:  # issuer scratch overflow — impossible by sizing
         return _decode_python(leaf_inputs, extra_datas, pad_len)
-    group, group_issuers = _issuer_groups(n, *span)
+    group, group_issuers = _issuer_groups(*span, chunks=t)
     return DecodedBatch(data, length, ts, ety, None, status,
                         issuer_group=group, group_issuers=group_issuers)
 
 
 def _issuer_groups(
-    n: int,
     issuer_off: np.ndarray,
     issuer_len: np.ndarray,
     issuer_buf: np.ndarray,
+    chunks: int = 1,
 ) -> tuple:
-    """Vectorized grouping of entries by issuer span.
+    """Group a whole batch's entries by issuer DER: ``(group,
+    group_issuers)`` in first-appearance order, as the pure-Python
+    lane's dict gives them.
 
     The native decoder dedups identical issuer DERs into shared
-    (off, len) spans, so grouping is a numpy unique over the span ids
-    — no per-entry byte hashing in Python."""
-    has = issuer_len > 0
-    # off < issuer_cap (< 2^42), len < 2^21 (pad-scale certs): the
-    # combined key fits int64 losslessly.
-    combo = issuer_off * (1 << 21) + issuer_len
-    group = np.full((n,), -1, np.int32)
-    if not has.any():
-        return group, []
-    uniq, inverse = np.unique(combo[has], return_inverse=True)
-    group[has] = inverse.astype(np.int32)
-    buf = issuer_buf.tobytes()
-    group_issuers = [
-        buf[int(c) >> 21 : (int(c) >> 21) + (int(c) & ((1 << 21) - 1))]
-        for c in uniq
-    ]
+    (off, len) spans — per chunk, each chunk appending into its own
+    slice of ``issuer_buf`` in lane order — so one numpy unique over
+    the span ids visits the spans in (chunk, first appearance) order,
+    and only a DER that several chunks (or a full dedup table) wrote
+    twice is merged by its bytes: per-span work, never per-entry.
+    Each span is sliced out on its own; nothing the size of the
+    buffer is copied."""
+    with trace.span("decode.issuer_groups", cat="decode",
+                    chunks=int(chunks)) as sp:
+        group = np.full((len(issuer_off),), -1, np.int32)
+        group_issuers: list = []
+        has = issuer_len > 0
+        nbytes = 0
+        if has.any():
+            # off < issuer_cap (< 2^42), len < 2^21 (pad-scale certs):
+            # the combined key fits int64 losslessly.
+            combo = issuer_off[has] * (1 << 21) + issuer_len[has]
+            uniq, inverse = np.unique(combo, return_inverse=True)
+            remap = np.empty((len(uniq),), np.int32)
+            gid_of: dict = {}
+            for g, c in enumerate(uniq.tolist()):
+                off, ln = c >> 21, c & ((1 << 21) - 1)
+                der = issuer_buf[off:off + ln].tobytes()
+                nbytes += ln
+                remap[g] = _assign_gid(gid_of, group_issuers, der)
+            group[has] = remap[inverse]
+        sp.set(groups=len(group_issuers), bytes=nbytes)
     return group, group_issuers
 
 
-
-def _decode_native_into(
+def _decode_native(
     lib,
-    leaf_inputs: Sequence[str],
-    extra_datas: Sequence[str],
-    pad_len: int,
-    out: tuple,
-) -> Optional[tuple]:
-    """Run the native decoder writing into caller-provided row views
-    ``out = (data, length, ts, ety, status)``; returns the issuer span
-    arrays ``(issuer_off, issuer_len, issuer_buf)`` (identical DERs
-    share one span), or None on native scratch overflow."""
-    n = len(leaf_inputs)
-    data, length, ts, ety, status = out
-    li_buf, li_off, ed_buf, ed_off = _concat_inputs(leaf_inputs, extra_datas)
-    issuer_off = np.zeros((n,), np.int64)
-    issuer_len = np.zeros((n,), np.int32)
-    # Issuer chain certs are ~1-2 KB; extra_data is an upper bound.
-    issuer_cap = max(len(ed_buf), 4096)
-    issuer_buf = np.zeros((issuer_cap,), np.uint8)
-    # Scratch must hold one decoded leaf_input + extra_data.
-    max_li = int(np.max(np.diff(li_off))) if n else 0
-    max_ed = int(np.max(np.diff(ed_off))) if n else 0
-    scratch = np.zeros((max(max_li + max_ed + 64, 4096),), np.uint8)
-
-    i64p = ctypes.POINTER(ctypes.c_int64)
-    i32p = ctypes.POINTER(ctypes.c_int32)
-    u8p = ctypes.POINTER(ctypes.c_uint8)
-
-    # The one call that releases the GIL; what stands around it in
-    # native.decode_batch is Python.
-    with trace.span("decode.native_call", cat="decode", threads=1,
-                    pad=int(pad_len)):
-        used = lib.ctmr_decode_entries(
-            n,
-            li_buf, li_off.ctypes.data_as(i64p),
-            ed_buf, ed_off.ctypes.data_as(i64p),
-            pad_len,
-            data.ctypes.data_as(u8p), length.ctypes.data_as(i32p),
-            ts.ctypes.data_as(i64p), ety.ctypes.data_as(i32p),
-            issuer_buf.ctypes.data_as(u8p), issuer_cap,
-            issuer_off.ctypes.data_as(i64p),
-            issuer_len.ctypes.data_as(i32p),
-            status.ctypes.data_as(i32p),
-            scratch.ctypes.data_as(u8p), scratch.shape[0],
-        )
-    if used < 0:
-        return None
-    return issuer_off, issuer_len, issuer_buf[:used]
-
-
-def _decode_native_mt(
-    lib,
-    leaf_inputs: Sequence[str],
-    extra_datas: Sequence[str],
+    cols: _B64Columns,
     pad_len: int,
     out: tuple,
     threads: int,
-) -> Optional[list]:
-    """One ``ctmr_decode_entries_mt`` call decoding ``threads``
-    contiguous lane ranges in parallel on the native worker pool.
-    Returns ``[((lo, hi), (issuer_off, issuer_len, issuer_buf))]`` per
-    chunk (spans carry GLOBAL offsets into the shared buffer), or None
-    when a chunk's issuer slice overflowed (caller retries serial)."""
-    n = len(leaf_inputs)
+) -> Optional[tuple]:
+    """The one native call of a batch, writing into caller-provided
+    row views ``out = (data, length, ts, ety, status)``: ``threads``
+    contiguous lane ranges decoded in parallel on the native worker
+    pool (1 = the serial pass). Returns the batch's ``(issuer_off,
+    issuer_len, issuer_buf)`` — identical DERs of one chunk share one
+    span; spans carry GLOBAL offsets into the shared buffer, chunk
+    ``t``'s within its slice ``[t * iss_each, (t + 1) * iss_each)`` —
+    or None when a chunk's issuer slice overflowed."""
+    li_off, ed_off = cols.li_off, cols.ed_off
+    n = len(li_off) - 1
     data, length, ts, ety, status = out
-    li_buf, li_off, ed_buf, ed_off = _concat_inputs(leaf_inputs, extra_datas)
     issuer_off = np.zeros((n,), np.int64)
     issuer_len = np.zeros((n,), np.int32)
     # Chunk bounds mirror the C split exactly: lane [n*t//T, n*(t+1)//T).
     bounds = [(n * t) // threads for t in range(threads + 1)]
     # Each chunk's issuer slice must hold that chunk's chain bytes;
-    # its base64 extra_data length is a safe upper bound on them.
+    # its base64 extra_data length is a safe upper bound on them
+    # (issuer chain certs are ~1-2 KB).
     iss_each = max(
         4096,
         max(int(ed_off[bounds[t + 1]] - ed_off[bounds[t]])
             for t in range(threads)),
     )
     issuer_buf = np.zeros((threads * iss_each,), np.uint8)
+    # A chunk's scratch must hold one decoded leaf_input + extra_data.
     max_li = int(np.max(np.diff(li_off))) if n else 0
     max_ed = int(np.max(np.diff(ed_off))) if n else 0
     scratch_each = max(max_li + max_ed + 64, 4096)
@@ -486,13 +477,22 @@ def _decode_native_mt(
     i64p = ctypes.POINTER(ctypes.c_int64)
     i32p = ctypes.POINTER(ctypes.c_int32)
     u8p = ctypes.POINTER(ctypes.c_uint8)
-
+    pool = (threads, chunk_used.ctypes.data_as(i64p))
+    if cols.owner is not None:
+        fn, li, ed = (lib.ctmr_decode_entries_strs,
+                      cols.li.ctypes.data, cols.ed.ctypes.data)
+    elif threads > 1:
+        fn, li, ed = lib.ctmr_decode_entries_mt, cols.li, cols.ed
+    else:  # also what a stale library without the pool still has
+        fn, li, ed, pool = lib.ctmr_decode_entries, cols.li, cols.ed, ()
+    # The one call that releases the GIL; what stands around it in
+    # native.decode_batch is Python.
     with trace.span("decode.native_call", cat="decode",
                     threads=int(threads), pad=int(pad_len)):
-        rc = lib.ctmr_decode_entries_mt(
+        rc = fn(
             n,
-            li_buf, li_off.ctypes.data_as(i64p),
-            ed_buf, ed_off.ctypes.data_as(i64p),
+            li, li_off.ctypes.data_as(i64p),
+            ed, ed_off.ctypes.data_as(i64p),
             pad_len,
             data.ctypes.data_as(u8p), length.ctypes.data_as(i32p),
             ts.ctypes.data_as(i64p), ety.ctypes.data_as(i32p),
@@ -501,18 +501,11 @@ def _decode_native_mt(
             issuer_len.ctypes.data_as(i32p),
             status.ctypes.data_as(i32p),
             scratch.ctypes.data_as(u8p), scratch_each,
-            threads, chunk_used.ctypes.data_as(i64p),
+            *pool,
         )
     if rc < 0:
         return None
-    return [
-        ((bounds[t], bounds[t + 1]),
-         (issuer_off[bounds[t]:bounds[t + 1]],
-          issuer_len[bounds[t]:bounds[t + 1]],
-          issuer_buf))
-        for t in range(threads)
-        if bounds[t + 1] > bounds[t]
-    ]
+    return issuer_off, issuer_len, issuer_buf
 
 
 def pack_ders(ders: Sequence[bytes], pad_len: int,

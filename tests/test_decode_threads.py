@@ -148,3 +148,154 @@ def test_legacy_workers_alias_routes_through_pool():
     a = leafpack.decode_raw_batch(lis, eds, 2048, workers=1)
     b = leafpack.decode_raw_batch(lis, eds, 2048, workers=4)
     _assert_batches_equal(a, b, "workers=4")
+
+
+# -- issuer grouping without the buffer copy (PR 26) ----------------------
+
+_CORPORA: dict = {}
+
+
+def _grouping_corpus(name: str):
+    """Small wire batches shaped around the issuer grouping (a few
+    leaves, many lanes; chunks are ``n * t // T`` lane ranges):
+
+    - ``interleaved``: three issuers round-robin, so every chunk of
+      every thread count holds each issuer and the merge by DER bytes
+      has to fold the chunks' copies into one group each;
+    - ``nochain_chunk``: lanes [n/4, n/2) — one whole chunk at 4
+      threads — carry no chain at all (``NO_CHAIN``), the others two
+      issuers;
+    - ``empty_extra``: every ``extra_data`` empty, no issuer anywhere;
+    - ``late_issuer``: a second issuer that first appears in the last
+      lanes only, after a run of bad lanes.
+    """
+    if name in _CORPORA:
+        return _CORPORA[name]
+    from ct_mapreduce_tpu.ingest import leaf as leaflib
+
+    issuers = [certgen.make_cert(serial=1 + k, issuer_cn=f"Grp CA {k}",
+                                 is_ca=True, key_seed=k) for k in range(3)]
+    leaves = [certgen.make_cert(serial=5000 + j, issuer_cn=f"Grp CA {j % 3}")
+              for j in range(6)]
+    eds = [base64.b64encode(leaflib.encode_extra_data([c])).decode()
+           for c in issuers]
+    n = 208
+    lis_out, eds_out = [], []
+    for j in range(n):
+        li = leaflib.encode_leaf_input(leaves[j % 6],
+                                       timestamp_ms=1700000000000 + j)
+        li_b64 = base64.b64encode(li).decode()
+        if name == "interleaved":
+            ed_b64 = eds[j % 3]
+        elif name == "nochain_chunk":
+            ed_b64 = "" if n // 4 <= j < n // 2 else eds[j % 2]
+        elif name == "empty_extra":
+            ed_b64 = ""
+        else:  # late_issuer
+            ed_b64 = eds[1] if j >= n - 5 else eds[0]
+            if n - 40 <= j < n - 5:
+                li_b64 = "!" + li_b64[1:]
+        lis_out.append(li_b64)
+        eds_out.append(ed_b64)
+    want = leafpack._decode_python(lis_out, eds_out, 2048)
+    _CORPORA[name] = (lis_out, eds_out, want)
+    return _CORPORA[name]
+
+
+@pytest.mark.parametrize("as_bytes", [False, True], ids=["str", "bytes"])
+@pytest.mark.parametrize("threads", [1, 2, 4, 13])
+@pytest.mark.parametrize(
+    "corpus", ["interleaved", "nochain_chunk", "empty_extra", "late_issuer"])
+def test_issuer_groups_match_python_lane(corpus, threads, as_bytes):
+    """Every thread count, ``str`` and ``bytes`` columns alike, gives
+    the pure-Python lane's batch: arrays, group ids, group order."""
+    lis, eds, want = _grouping_corpus(corpus)
+    if as_bytes:
+        lis = [s.encode() for s in lis]
+        eds = [s.encode() for s in eds]
+    got = leafpack.decode_raw_batch(lis, eds, 2048, threads=threads)
+    _assert_batches_equal(want, got, f"{corpus} threads={threads}")
+    n_groups = {"interleaved": 3, "nochain_chunk": 2, "empty_extra": 0,
+                "late_issuer": 2}[corpus]
+    assert len(got.group_issuers) == n_groups
+    if corpus == "nochain_chunk":
+        n = len(lis)
+        assert set(got.status[n // 4: n // 2].tolist()) == {leafpack.NO_CHAIN}
+        assert (got.issuer_group[n // 4: n // 2] == -1).all()
+
+
+def test_issuer_groups_materialise_spans_not_the_buffer():
+    """Grouping over a 64 MB shared issuer buffer (13 chunks' slices)
+    allocates what its lanes and spans need (index arrays of a few
+    bytes a lane, 13 x 16 DERs): under 1 MB, not the buffer."""
+    import tracemalloc
+
+    from ct_mapreduce_tpu.telemetry import trace
+
+    chunks, each, lanes = 13, (64 << 20) // 13 + 1, 601
+    buf = np.zeros((chunks * each,), np.uint8)
+    n = chunks * lanes
+    off = np.zeros((n,), np.int64)
+    ln = np.zeros((n,), np.int32)
+    ders = [bytes([k + 1]) * (1200 + 17 * k) for k in range(16)]
+    for c in range(chunks):
+        pos = c * each
+        spans = []
+        for der in ders:  # every chunk wrote its own copy of each DER
+            buf[pos:pos + len(der)] = np.frombuffer(der, np.uint8)
+            spans.append((pos, len(der)))
+            pos += len(der)
+        for i in range(lanes):
+            j = c * lanes + i
+            if i % 7:
+                off[j], ln[j] = spans[(i * 5 + c) % 16]
+    trace.enable(ring_size=64)
+    try:
+        tracemalloc.start()
+        group, group_issuers = leafpack._issuer_groups(
+            off, ln, buf, chunks=chunks)
+        _, peak = tracemalloc.get_traced_memory()
+        tracemalloc.stop()
+        events = [e for e in trace.snapshot_events()
+                  if e["name"] == "decode.issuer_groups"]
+    finally:
+        trace.disable()
+    assert peak < (1 << 20), peak
+    # One group per DER whatever chunk wrote it, in the order the first
+    # chunk's slice holds them (the decoder appends on first appearance).
+    assert group_issuers == ders
+    assert (group[ln == 0] == -1).all()
+    for j in (1, lanes + 2, n - 1):
+        assert group_issuers[group[j]] == bytes(
+            buf[off[j]:off[j] + ln[j]])
+    args = events[-1]["args"]
+    assert args["chunks"] == 13 and args["groups"] == 16
+    assert args["bytes"] == 13 * sum(len(d) for d in ders) < (1 << 20)
+
+
+def test_traceview_batches_has_issuer_groups_and_self_is_the_residue(
+        tmp_path):
+    """``traceview --batches``: a column for ``decode.issuer_groups``,
+    and ``native.decode_batch`` shown as what its children leave."""
+    from ct_mapreduce_tpu.telemetry import trace
+    from tools import traceview
+
+    lis, eds, _want = _grouping_corpus("interleaved")
+    trace.enable(ring_size=256)
+    try:
+        with trace.span("ingest.decode", cat="ingest", batch=3):
+            leafpack.decode_raw_batch(lis, eds, 2048, threads=4)
+        path = trace.export(str(tmp_path / "ring.json"))
+    finally:
+        trace.disable()
+    events = traceview.load(path)
+    (row,) = traceview.batch_table(events)
+    assert row["batch"] == 3 and row["threads"] == 4
+    assert "decode.issuer_groups" in traceview.BATCH_COLUMNS
+    kids = sum(row[c] for c in ("decode.concat_b64", "decode.native_call",
+                                "decode.issuer_groups"))
+    whole = next(e["dur"] for e in events
+                 if e.get("name") == "native.decode_batch") / 1e3
+    assert row["native.decode_batch"] == pytest.approx(whole - kids)
+    assert 0 <= row["native.decode_batch"] < whole
+    assert traceview.main([path, "--batches"]) == 0

@@ -244,3 +244,95 @@ def test_wire_mutation_fuzz_native_python_agreement():
     np.testing.assert_array_equal(nat.timestamp_ms, py.timestamp_ms)
     np.testing.assert_array_equal(nat.entry_type, py.entry_type)
     assert nat.issuers == py.issuers
+
+
+# -- base64 columns read in place, and the join as the fallback (PR 26) ----
+
+
+class _Str(str):
+    """A ``str`` subclass: not compact storage, still ASCII."""
+
+
+def _column_case(case, lis, eds):
+    if case == "str_subclass":
+        return [_Str(s) for s in lis], [_Str(s) for s in eds]
+    if case == "tuple_of_str":
+        return tuple(lis), tuple(eds)
+    if case == "bytes":
+        return [s.encode() for s in lis], [s.encode() for s in eds]
+    if case == "mixed_str_bytes":
+        return ([s.encode() if i % 2 else s for i, s in enumerate(lis)],
+                [s if i % 2 else s.encode() for i, s in enumerate(eds)])
+    if case == "bytes_high_bit":  # not base64, not ASCII: a status
+        return ([s.encode() for s in lis[:-1]] + [b"\xff\xfe" + b"A" * 6],
+                [s.encode() for s in eds])
+    return lis, eds  # "str", "stale_library"
+
+
+@pytest.mark.skipif(not available(), reason="no C++ compiler")
+@pytest.mark.parametrize("threads", [1, 3])
+@pytest.mark.parametrize("case,joined", [
+    ("str", 0), ("str_subclass", 0), ("tuple_of_str", 0), ("bytes", 1),
+    ("mixed_str_bytes", 1), ("bytes_high_bit", 1), ("stale_library", 1)])
+def test_b64_columns_in_place_or_joined_same_batch(
+        case, joined, threads, monkeypatch):
+    """Columns the decoder can read in place (ASCII ``str`` items) are
+    not joined; every other input takes the join as before. Both give
+    the batch the pure-Python lane gives."""
+    from ct_mapreduce_tpu.native import load
+    from ct_mapreduce_tpu.telemetry import trace
+
+    lis, eds, _expect, _issuer = _wire_batch()
+    lis, eds = _column_case(case, lis * 5, eds * 5)
+    if case == "stale_library":  # a prebuilt .so without the entry point
+        monkeypatch.setattr(load(), "has_strs", False)
+    want = leafpack._decode_python(list(lis), list(eds), 2048)
+    trace.enable(ring_size=64)
+    try:
+        got = leafpack.decode_raw_batch(lis, eds, 2048, threads=threads)
+        spans = [e for e in trace.snapshot_events()
+                 if e["name"] == "decode.concat_b64"]
+    finally:
+        trace.disable()
+    for fld in ("data", "length", "timestamp_ms", "entry_type", "status",
+                "issuer_group"):
+        np.testing.assert_array_equal(
+            getattr(got, fld), getattr(want, fld), err_msg=f"{case}: {fld}")
+    assert got.group_issuers == want.group_issuers
+    assert got.issuers == want.issuers
+    assert spans[-1]["args"]["joined"] == joined
+    assert spans[-1]["args"]["bytes"] == sum(map(len, lis)) + sum(map(len, eds))
+    if case == "bytes_high_bit":
+        assert got.status[-1] == leafpack.BAD_B64
+
+
+@pytest.mark.skipif(not available(), reason="no C++ compiler")
+@pytest.mark.parametrize("stale", [False, True], ids=["in_place", "stale"])
+@pytest.mark.parametrize("case,exc", [
+    ("non_ascii_leaf_input", UnicodeEncodeError),
+    ("non_ascii_extra_data", UnicodeEncodeError),
+    ("none_item", TypeError),
+    ("int_item", TypeError)])
+def test_b64_columns_bad_items_raise_as_the_join_does(
+        case, exc, stale, monkeypatch):
+    """An item the fast path cannot take is no new failure mode: the
+    call raises what the Python join has always raised for it, with or
+    without the in-place entry point, and leaves no error behind."""
+    from ct_mapreduce_tpu.native import load
+
+    lis, eds, _expect, _issuer = _wire_batch()
+    if case == "non_ascii_leaf_input":
+        lis[2] = lis[2][:9] + "é" + lis[2][10:]
+    elif case == "non_ascii_extra_data":
+        eds[0] = "✓" + eds[0][1:]
+    elif case == "none_item":
+        eds[1] = None
+    else:
+        lis[-1] = 7
+    if stale:
+        monkeypatch.setattr(load(), "has_strs", False)
+    with pytest.raises(exc):
+        leafpack.decode_raw_batch(lis, eds, 2048, threads=2)
+    # and the next batch decodes
+    lis, eds, expect, issuer = _wire_batch()
+    _check(leafpack.decode_raw_batch(lis, eds, 2048), expect, issuer)

@@ -199,15 +199,17 @@ def batch_lineage(events: list[dict], batch: int) -> list[str]:
     return lines
 
 
-BATCH_COLUMNS = ("decode.concat_b64", "decode.native_call", "decode.pack",
+BATCH_COLUMNS = ("decode.concat_b64", "decode.native_call",
+                 "decode.issuer_groups", "decode.pack",
                  "native.decode_batch", "ingest.decode", "ingest.submit",
                  "fold.wait_device", "device.fold")
 
 
 def batch_table(events: list[dict]) -> list[dict]:
     """One row per batch: milliseconds under each of ``BATCH_COLUMNS``
-    (``native.decode_batch`` as self time: row allocation and issuer
-    grouping), with the native call's ``threads`` and ``pad``."""
+    (``native.decode_batch`` as self time: what its three children
+    leave, the row allocation), with the native call's ``threads`` and
+    ``pad``."""
     spans = complete_spans(events)
     selfs = self_us(spans)
     rows: dict[int, dict] = {}
