@@ -35,6 +35,12 @@ def zipf_weights(n: int, s: float) -> np.ndarray:
     return w / w.sum()
 
 
+def quantile(values: list[float], q: float) -> float:
+    """Nearest-rank quantile of a non-empty list."""
+    s = sorted(values)
+    return s[min(len(s) - 1, max(0, int(np.ceil(q * len(s))) - 1))]
+
+
 def window_entries(rate_per_s: float, seconds: float, logs: int = 1,
                    batch: int = BATCH) -> int:
     """Entries of one run's window: whole batches, at least two, and the

@@ -6,13 +6,16 @@ The process that calls :func:`run_cell` holds the chip: it calls
 a second thread. From the program it takes the entry points a user has
 (``ct-fetch``, ``storage-statistics``, ``/healthz``) and its telemetry
 (the metrics sink's samples, the span tracer); the load comes from
-``logserver.py``, a process of its own on cores of its own.
+``logserver.py`` and, where the traffic file names further generators,
+from ``generators/<kind>.py``: processes of their own on cores of their
+own.
 """
 
 from __future__ import annotations
 
 import gc
 import http.client
+import importlib
 import json
 import os
 import shutil
@@ -22,8 +25,6 @@ import subprocess
 import sys
 import threading
 import time
-
-import numpy as np
 
 import fixture as fx
 
@@ -35,6 +36,9 @@ FOLD_SAMPLE = "ct-fetch.completeBatch"  # one sample per batch folded
 # The store thread's timers, for the diagnosis line's timeline.
 STORE_THREAD = ("ct-fetch.decodeBatch", "ct-fetch.storeCertificate",
                 FOLD_SAMPLE)
+REPLAY = "log_replay"  # the one generator every traffic file has: logserver.py
+# The harness's own numbers; a generator's ``VALUES`` may repeat neither.
+VALUES = ("ingest_entries_per_s", "setup_s")
 
 
 class RunFailed(RuntimeError):
@@ -64,7 +68,11 @@ def http_json(port: int, path: str, body: dict | None = None,
 
 
 def log_spec(traffic: dict, seconds: float, batch: int) -> fx.LogSpec:
-    g = next(g for g in traffic["generators"] if g["kind"] == "log_replay")
+    replay = [g for g in traffic["generators"] if g["kind"] == REPLAY]
+    if len(replay) != 1:
+        raise RunFailed(f"a traffic file has exactly one {REPLAY} generator, "
+                        f"not {len(replay)}")
+    g = replay[0]
     if g["warmup_entries"] % batch or batch % g["page"]:
         raise RunFailed("warm-up and pages must fill whole batches")
     if g["ramp_batches"] < 1:
@@ -80,13 +88,46 @@ def log_spec(traffic: dict, seconds: float, batch: int) -> fx.LogSpec:
         tail_entries=-(-g["tail_batches"] // g["logs"]) * g["logs"] * batch)
 
 
+def load_generators(traffic: dict) -> list[tuple[str, dict, object]]:
+    """``(kind, parameters, module)`` for every generator of the traffic
+    file beside the log: kind ``K`` is ``generators/K.py``, which runs
+    as a :class:`Child` and whose ``summarise`` reads its rows after the
+    run. Loads neither JAX nor the program."""
+    found, values = [], list(VALUES)
+    for g in traffic["generators"]:
+        kind = g["kind"]
+        if kind == REPLAY:
+            continue
+        if not (kind.isidentifier() and os.path.isfile(
+                os.path.join(HERE, "generators", kind + ".py"))):
+            raise RunFailed(f"no generator of kind {kind!r}: "
+                            f"benchmark/generators/{kind}.py is not there")
+        module = importlib.import_module("generators." + kind)
+        values += module.VALUES
+        found.append((kind, g, module))
+    twice = sorted({v for v in values if values.count(v) > 1})
+    if twice:
+        raise RunFailed(f"values given twice: {', '.join(twice)}")
+    kinds = [kind for kind, _g, _module in found]
+    if len(set(kinds)) < len(kinds):
+        raise RunFailed(f"a kind of generator given twice: {kinds}")
+    return found
+
+
 class Child:
     """A load generator: a process that reads a spec file, says one
-    JSON line when it is ready, and ends when its stdin closes."""
+    JSON line when it is ready, and ends when its stdin closes. A
+    generator beside the log is told, a JSON line each on its stdin,
+    when the program has its warm-up round on disk (``warm``: it warms
+    up what its operations use, then says it is ready), when the log
+    ``opened`` (it starts its operations), when the round was ``folded``
+    (it starts no more) and to ``stop``: it then writes its rows to the
+    file its spec names and says so."""
 
-    def __init__(self, script: str, spec: dict, workdir: str):
-        self.name = script
-        path = os.path.join(workdir, script + ".spec.json")
+    def __init__(self, script: str, spec: dict, workdir: str,
+                 name: str | None = None):
+        self.name = name or script
+        path = os.path.join(workdir, self.name + ".spec.json")
         with open(path, "w") as fh:
             json.dump(spec, fh)
         env = {k: v for k, v in os.environ.items()
@@ -101,6 +142,20 @@ class Child:
             raise RunFailed(f"{self.name} ended before it was ready "
                             f"(rc {self.proc.poll()})")
         return json.loads(line)
+
+    def tell(self, **message) -> None:
+        try:
+            self.proc.stdin.write(json.dumps(message).encode() + b"\n")
+            self.proc.stdin.flush()
+        except OSError:
+            raise RunFailed(f"{self.name} ended before the run did "
+                            f"(rc {self.proc.poll()})") from None
+
+    def rows(self):
+        """What the generator recorded, handed back through a file."""
+        self.tell(stop=True)
+        with open(self.ready()["rows"]) as fh:
+            return json.load(fh)
 
     def stop(self, timeout: float = 60.0) -> None:
         if self.proc.poll() is None:
@@ -197,10 +252,12 @@ class GcLog:
 
 
 def write_ini(config: dict, workdir: str, name: str, state_path: str,
-              log_urls: list[str], metrics_port: int) -> str:
+              log_urls: list[str], ports: dict[str, int]) -> str:
+    """``ports`` maps a port directive to the free port taken for it:
+    ``metricsPort`` and those the configuration's ``ports`` key asks for."""
     lines = [f"logList = {', '.join(log_urls)}",
-             f"aggStatePath = {state_path}",
-             f"metricsPort = {metrics_port}"]
+             f"aggStatePath = {state_path}"]
+    lines += [f"{key} = {port}" for key, port in ports.items()]
     for key, value in config["directives"].items():
         if isinstance(value, bool):
             value = "true" if value else "false"
@@ -211,12 +268,6 @@ def write_ini(config: dict, workdir: str, name: str, state_path: str,
     return ini
 
 
-def quantile(values: list[float], q: float) -> float:
-    """Nearest-rank quantile of a non-empty list."""
-    s = sorted(values)
-    return s[min(len(s) - 1, max(0, int(np.ceil(q * len(s))) - 1))]
-
-
 class Conductor:
     """The second thread of the ct-fetch process: waits for the warm-up
     round, opens the log, stamps the folds, waits for the checkpoint,
@@ -224,11 +275,12 @@ class Conductor:
     the live counters and sends SIGINT."""
 
     def __init__(self, *, fixture, spec, config, workdir, trace_on,
-                 log_port, metrics_port, compiles, marks):
+                 log_port, metrics_port, compiles, marks, children=()):
         self.fixture = fixture
         self.spec = spec
         self.workdir = workdir
         self.trace_on = trace_on
+        self.children = children  # the generators beside the log
         self.log_port = log_port
         self.metrics_port = metrics_port
         self.compiles = compiles
@@ -328,6 +380,12 @@ class Conductor:
         self.mark("warmup_folded")
         self.wait_idle({0: log0.warm}, deadline)
         self.mark("warmup_durable")
+        if self.children:
+            for child in self.children:
+                child.tell(warm=time.monotonic())
+            for child in self.children:
+                child.ready()
+            self.mark("generators_ready")
 
         tracer = None
         if self.trace_on:
@@ -339,16 +397,21 @@ class Conductor:
         gclog = GcLog()
         t_open = http_json(self.log_port, "/control/open", timeout=300)[1][
             "opened_at"]
+        for child in self.children:
+            child.tell(opened=t_open)
         self.mark("log_opened")
         self.stamper.wait_for(warm_batches + round_batches,
                               time.monotonic() + 600.0, self.alive)
         folds = list(self.stamper.stamps)[:warm_batches + round_batches]
+        for child in self.children:
+            child.tell(folded=folds[-1])
         if tracer is not None:
             tracer.stop()
         want = {k: log.total for k, log in enumerate(self.fixture.logs)}
         t_durable = self.wait_idle(want, time.monotonic() + 600.0)
         kept = self.keep_checkpoint()
         gclog.close()
+        rows = {child.name: child.rows() for child in self.children}
 
         # The window: from the fold of the ramp's last batch to the fold
         # of its own last batch, so many whole batch periods of a
@@ -364,8 +427,9 @@ class Conductor:
         self.out.update(
             t_open=t_open, t_first=t_first, t_folded=t_folded,
             t_round_folded=folds[-1], t_last_page=t_last_page,
-            t_durable=t_durable, kept=kept,
+            t_durable=t_durable, kept=kept, rows=rows,
             folds=folds[warm_batches:], pages=pages,
+            all_pages=stamps["pages"],
             extra_folds=len(self.stamper.stamps) - len(folds),
             samples=[e for e in self.stamper.samples if e[0] >= t_open],
             counters=[e for e in self.stamper.counters if e[0] >= t_open],
@@ -449,22 +513,49 @@ def compare(fixture: fx.RunFixture, tpl: fx.Templates, spec: fx.LogSpec,
 
 class Prepared:
     """What a run needs before JAX is loaded: its work directory, its
-    logs' sizes and the log server, started first because it builds
-    every page of the run before it says it is ready."""
+    logs' sizes, its generators by kind, a free port for every port
+    directive, and the log server, started first because it builds
+    every page of the run before it says it is ready. Whatever is wrong
+    with the cell's files fails here."""
 
     def __init__(self, config: dict, traffic: dict, *, seed: int,
                  seconds: float, loadgen_cores: list[int]):
         shutil.rmtree(WORK, ignore_errors=True)
         os.makedirs(WORK)
-        self.config, self.seed = config, seed
+        self.config, self.seed, self.seconds = config, seed, seconds
+        self.cores = loadgen_cores
         self.batch = int(config["directives"]["batchSize"])
         self.spec = log_spec(traffic, seconds, self.batch)
+        self.generators = load_generators(traffic)
+        names = ["metricsPort", *config.get("ports", [])]
+        twice = [n for n in names if names.count(n) > 1
+                 or n in config["directives"]]
+        if twice:
+            raise RunFailed(f"port directives given twice: {sorted(set(twice))}")
+        self.ports = {name: free_port() for name in names}
+        self.children: list[Child] = []
         self.logsrv = Child("logserver.py", {
             "seed": seed, "log_spec": self.spec.__dict__,
             "cores": loadgen_cores}, WORK)
 
+    def start_generators(self, log_port: int) -> list[Child]:
+        """Once the log server listens: each generator beside it gets
+        its own parameters, the run's seed and length, the logs' sizes,
+        the ports by name and the file to leave its rows in."""
+        for name, params, _module in self.generators:
+            self.children.append(Child(
+                os.path.join("generators", params["kind"] + ".py"), {
+                    "generator": params, "seed": self.seed,
+                    "seconds": self.seconds, "log_spec": self.spec.__dict__,
+                    "ports": self.ports, "log_port": log_port,
+                    "cores": self.cores,
+                    "rows": os.path.join(WORK, name + ".rows.json")},
+                WORK, name=name))
+        return self.children
+
     def close(self) -> None:
-        self.logsrv.stop()
+        for child in [self.logsrv, *self.children]:
+            child.stop()
 
 
 def run_cell(prep: Prepared, *, trace_on: bool, t_start: float,
@@ -490,11 +581,11 @@ def run_cell(prep: Prepared, *, trace_on: bool, t_start: float,
         marks.append(("fixture_ready", time.monotonic()))
         log_port = prep.logsrv.ready()["port"]
         marks.append(("log_server_ready", time.monotonic()))
-        metrics_port = free_port()
+        metrics_port = prep.ports["metricsPort"]
         urls = [f"http://127.0.0.1:{log_port}/log{k}"
                 for k in range(spec.logs)]
         ini = write_ini(config, WORK, "ct-fetch.ini",
-                        os.path.join(WORK, STATE_FILE), urls, metrics_port)
+                        os.path.join(WORK, STATE_FILE), urls, prep.ports)
         headroom = None
         if trace_on:
             import tracing
@@ -504,7 +595,8 @@ def run_cell(prep: Prepared, *, trace_on: bool, t_start: float,
         conductor = Conductor(
             fixture=fixture, spec=spec, config=config, workdir=WORK,
             trace_on=trace_on, log_port=log_port, metrics_port=metrics_port,
-            compiles=compiles, marks=marks)
+            compiles=compiles, marks=marks,
+            children=prep.start_generators(log_port))
         thread = threading.Thread(target=conductor.run, name="bench-conductor")
         # ct-fetch puts back the handler it found: a SIGINT that lands
         # just after it has returned must find this one, not Python's.
@@ -526,7 +618,7 @@ def run_cell(prep: Prepared, *, trace_on: bool, t_start: float,
     out = conductor.out
     report = report_child(write_ini(
         config, WORK, "report.ini", os.path.join(out["kept"], STATE_FILE),
-        urls, metrics_port))
+        urls, prep.ports))
     checks = compare(fixture, tpl, spec, out, report)
     n = spec.window_entries
     values = {
@@ -538,6 +630,27 @@ def run_cell(prep: Prepared, *, trace_on: bool, t_start: float,
     if not all(c["ok"] for c in checks[:2]):
         failed_entries = max(failed_entries, abs(
             fixture.expected_unique() - report["totals"]["serials"]))
+    parts = {REPLAY: {"attempted": n, "failed": min(n, failed_entries)}}
+    generator_notes: dict[str, dict] = {}
+    window = {k: out[k] for k in ("t_open", "t_first", "t_folded")}
+    window["pages"] = out["all_pages"]
+    for name, params, module in prep.generators:
+        try:
+            part = module.summarise(out["rows"][name],
+                                    dict(window, generator=params),
+                                    fixture, config)
+        except ValueError as err:
+            raise RunFailed(f"{name}: {err}") from None
+        if sorted(part["values"]) != sorted(module.VALUES):
+            raise RunFailed(f"{name} gave {sorted(part['values'])}, not the "
+                            f"values it states: {sorted(module.VALUES)}")
+        values.update(part["values"])
+        checks += [{"what": f"{name}: {c['what']}", "got": c["got"],
+                    "want": c["want"], "ok": c["got"] == c["want"]}
+                   for c in part["checks"]]
+        parts[name] = {"attempted": part["attempted"],
+                       "failed": part["failed"]}
+        generator_notes[name] = part.get("diagnosis", {})
     t0 = out["t_first"]
     # Between two pages, as the log server saw it: response written to
     # next request read, (instant, seconds).
@@ -553,9 +666,9 @@ def run_cell(prep: Prepared, *, trace_on: bool, t_start: float,
             "durable": out["t_durable"] - t0},
         "ramp_window_tail_entries": [spec.ramp_entries, n, spec.tail_entries],
         "pages": len(out["pages"]),
-        "page_client_gap_ms": {q: quantile([g for _, g in gaps], q / 100) * 1e3
+        "page_client_gap_ms": {q: fx.quantile([g for _, g in gaps], q / 100) * 1e3
                                for q in (50, 95, 99, 100)} if gaps else {},
-        "page_server_ms": {q: quantile(serve, q / 100) * 1e3
+        "page_server_ms": {q: fx.quantile(serve, q / 100) * 1e3
                            for q in (50, 95, 100)},
         "store_thread": [[round(t - t0, 3), key.split(".")[1], round(v, 3)]
                          for t, key, v in out["samples"]
@@ -571,6 +684,7 @@ def run_cell(prep: Prepared, *, trace_on: bool, t_start: float,
                     "seconds": sum(s for _, s in compiles.events),
                     "cache_hits": compiles.cache_hits,
                     "cache_misses": compiles.cache_misses},
+        **generator_notes,
     }
     names = [m[0] for m in marks] + ["window_open"]
     times = [m[1] for m in marks] + [out["t_first"]]
@@ -578,7 +692,9 @@ def run_cell(prep: Prepared, *, trace_on: bool, t_start: float,
              for i in range(len(times) - 1)}
     return {
         "correct": all(c["ok"] for c in checks),
-        "attempted": n, "failed": min(n, failed_entries),
+        "attempted": sum(p["attempted"] for p in parts.values()),
+        "failed": sum(p["failed"] for p in parts.values()),
+        "by_generator": parts,
         "values": values, "checks": checks, "diagnosis": diagnosis,
         "setup": setup, "out": out, "spec": spec,
         "compiles": compiles, "headroom": headroom, "config": config,

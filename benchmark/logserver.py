@@ -16,6 +16,7 @@ stamps beside its own) when its first byte is about to be written.
   /<log>/ct/v1/get-sth, /<log>/ct/v1/get-entries?start=&end=
   /control/open       open every log; answers {"opened_at": t}
   /control/stamps     every page served so far, for the parent
+                      (?since=n: from the n-th on, for one that polls)
 
 Started as ``python logserver.py <spec.json>``; prints one JSON line
 with its port once it listens.
@@ -71,7 +72,7 @@ def make_handler(state: LogState):
             parsed = urlparse(self.path)
             path = parsed.path
             if path.startswith("/control/"):
-                return self._control(path)
+                return self._control(path, parse_qs(parsed.query))
             try:
                 k = int(path.split("/")[1].removeprefix("log"))
                 log = state.run.logs[k]
@@ -98,7 +99,7 @@ def make_handler(state: LogState):
                 state.pages.append((k, start, count, t_req, t_resp,
                                     time.monotonic()))
 
-        def _control(self, path: str) -> None:
+        def _control(self, path: str, query: dict) -> None:
             if path == "/control/open":
                 with state.lock:
                     if state.opened_at is None:
@@ -106,8 +107,8 @@ def make_handler(state: LogState):
                 body = {"opened_at": state.opened_at}
             elif path == "/control/stamps":
                 with state.lock:
-                    body = {"opened_at": state.opened_at,
-                            "pages": list(state.pages)}
+                    body = {"opened_at": state.opened_at, "pages": state.pages[
+                        int(query.get("since", ["0"])[0]):]}
             else:
                 return self._send(404, b"not found")
             self._send(200, json.dumps(body).encode())

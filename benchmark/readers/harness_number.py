@@ -1,5 +1,6 @@
 """A number the harness itself took, by the host's clock or from JAX:
-params ``which`` names it.
+params ``which`` names it, or one of the run's named values (``res["values"]``:
+``ingest_entries_per_s``, ``setup_s`` and what the cell's generators gave).
 """
 
 
@@ -27,4 +28,6 @@ def read(params: dict, ctx: dict):
     if which == "peak_hbm_gb":
         peak = ctx["device"]["memory_peak_bytes"]
         return None if peak is None else peak / 1e9
+    if which in ctx["values"]:  # the harness's two and the generators'
+        return ctx["values"][which]
     raise ValueError(f"unknown harness number {which!r}")

@@ -11,12 +11,18 @@ t_durable]``; spans are clipped to it); ``self`` (true: less the
 children, by ``parent``); ``per`` (entry | batch | window_seconds |
 span:<name> | none); ``scale``.
 
-Nothing to read (None) when the program's tracer records no ``parent``
-or counts no drops (a program older than these spans), when no span
-matches, or when the ring dropped events inside the phase.
+Not in this program (``layers.ABSENT``: the metric is left out) when
+the program's tracer is off, records no ``parent`` or counts no drops (a
+program older than these spans), or when the whole run's ring holds no
+event of the span's family (``fetch.`` of ``fetch.enqueue``) and
+dropped nothing. Nothing to read (None: a listed metric fails the run)
+when the family is there and no span of the name lies in the phase (a
+span renamed), or when the ring dropped events inside the phase.
 """
 
 from __future__ import annotations
+
+from layers import ABSENT
 
 
 def live_ring() -> dict | None:
@@ -96,13 +102,26 @@ def uncovered_seconds(ring: dict, spans: list[dict], lo: float, hi: float,
     return worst
 
 
+def family_absent(ring: dict, spans: list[dict], name: str) -> bool:
+    """No span of ``name``'s family in the whole run (and the ring
+    forgot nothing, so the whole run is what it holds)."""
+    family = name.split(".", 1)[0] + "."
+    return not ring["dropped"] and not any(
+        e["name"].startswith(family) for e in spans)
+
+
 def read(params: dict, ctx: dict):
     ring = ctx["ring"] if "ring" in ctx else live_ring()
     if ring is None:
-        return None
+        return ABSENT
     spans = [e for e in ring["events"] if e.get("ph") == "X"]
     if any("parent" not in e for e in spans):
-        return None
+        return ABSENT
+    named = [params["span"]] if "span" in params else []
+    if params.get("per", "").startswith("span:"):
+        named.append(params["per"][5:])
+    if any(family_absent(ring, spans, name) for name in named):
+        return ABSENT
     lo, hi = phase_bounds(params.get("phase", "window"), ctx["out"])
     if hi <= lo or not seen_whole(ring, spans, lo):
         return None

@@ -7,10 +7,12 @@ Finds the cell in ``BENCHMARK.json``, its configuration under
 ``benchmark/configs/`` and its traffic under ``benchmark/traffic/``;
 runs it once on the machine it is started on (see ``harness.py``); and
 prints, as its last line, one JSON object: ``correct``, ``attempted``,
-``failed``, ``metrics`` (the cell's end-to-end metrics with ``--trace
-0``, its per-layer metrics with ``--trace 1``) and ``device``. Lines
-before it itemise the set-up, the run's phases and every number
-compared beside its limit.
+``failed`` (the sums over the traffic's generators, each apart under
+``by_generator``), ``metrics`` (the cell's end-to-end metrics with
+``--trace 0``, its per-layer metrics with ``--trace 1``), ``device``
+and, last, ``compared``: every number compared beside its limit. Lines
+before it itemise the set-up and the run's phases; the numbers compared
+are also the last lines on standard error.
 
 Without the cell's TPU devices it exits 3 and prints no result.
 """
@@ -133,10 +135,9 @@ def main(argv: list[str] | None = None, before=None) -> int:
 
     print(json.dumps({"setup_s_itemised": res["setup"]}), flush=True)
     print(json.dumps({"diagnosis": res["diagnosis"]}), flush=True)
-    for check in res["checks"]:
-        print(json.dumps({"compared": check}), flush=True)
     line = {"correct": res["correct"], "attempted": res["attempted"],
-            "failed": res["failed"], "device": res["device"]}
+            "failed": res["failed"], "by_generator": res["by_generator"],
+            "device": res["device"]}
     if args.trace:
         import layers
 
@@ -153,6 +154,10 @@ def main(argv: list[str] | None = None, before=None) -> int:
         line["metrics"] = {
             m["name"]: {"value": res["values"][m["name"]], "unit": m["unit"]}
             for m in bench["end_to_end"] if reports(m, args.workload)}
+    line["compared"] = res["checks"]
+    for check in res["checks"]:
+        print(json.dumps({"compared": check}), file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

@@ -48,4 +48,25 @@ def deferred_checkpoint() -> None:
     ingest_model.IngestModel.save = save
 
 
-BREAKS = {"lost_entry": lost_entry, "deferred_checkpoint": deferred_checkpoint}
+def wrong_answer() -> None:
+    """Answers exact -> every seventh membership answer of the query
+    plane says the opposite, as one from a stale or approximate tier
+    would: a serial that was fed long ago comes back unknown, one never
+    fed comes back known. For cells with a query generator."""
+    from ct_mapreduce_tpu.serve import server
+
+    real = server.MembershipOracle.query_raw
+    seen = {"answers": 0}
+
+    def query_raw(self, items, timeout_s=None):
+        out = []
+        for known, epoch, staleness in real(self, items, timeout_s=timeout_s):
+            seen["answers"] += 1
+            out.append((known ^ (seen["answers"] % 7 == 0), epoch, staleness))
+        return out
+
+    server.MembershipOracle.query_raw = query_raw
+
+
+BREAKS = {"lost_entry": lost_entry, "deferred_checkpoint": deferred_checkpoint,
+          "wrong_answer": wrong_answer}

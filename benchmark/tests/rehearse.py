@@ -3,7 +3,10 @@
 without its look for a chip. For rehearsals on the CPU and for the tests
 beside this file; ``run.py`` has no option that reaches it.
 
-  JAX_PLATFORMS=cpu python3 benchmark/tests/rehearse.py [logs] [seed] [trace|notrace] [break]
+  JAX_PLATFORMS=cpu python3 benchmark/tests/rehearse.py [logs] [seed] [trace|notrace] [break] [key=value ...]
+
+``traffic=<file>`` runs that traffic file as it stands (its sizes have
+to be a rehearsal's own) in place of the cell's cut to size.
 """
 
 from __future__ import annotations
@@ -36,7 +39,51 @@ def tiny(logs: int) -> tuple[dict, dict]:
     return config, traffic
 
 
+def run_once(config: dict, traffic: dict, *, seed: int, seconds: float,
+             trace_on: bool, loadgen_cores: list[int],
+             t_start: float) -> dict | None:
+    """``run.py``'s run without its look for a chip; None (said on
+    standard error) where the run cannot report."""
+    import harness
+
+    try:
+        prep = harness.Prepared(config, traffic, seed=seed, seconds=seconds,
+                                loadgen_cores=loadgen_cores)
+    except harness.RunFailed as err:
+        print(f"rehearse.py: {err} (jax loaded: {'jax' in sys.modules})",
+              file=sys.stderr)
+        return None
+    try:
+        import jax
+
+        devs = jax.devices()
+        device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+                  "count": len(devs)}
+        return harness.run_cell(prep, trace_on=trace_on, t_start=t_start,
+                                device=device)
+    except harness.RunFailed as err:
+        print(f"rehearse.py: {err}", file=sys.stderr)
+        return None
+    finally:
+        prep.close()
+
+
+def report(res: dict) -> None:
+    print(json.dumps({"setup": res["setup"]}))
+    print(json.dumps({"diagnosis": res["diagnosis"]}))
+    for c in res["checks"]:
+        print(json.dumps(c))
+    print(json.dumps({"correct": res["correct"], "attempted": res["attempted"],
+                      "failed": res["failed"], "values": res["values"],
+                      "not_ok": [c["what"] for c in res["checks"]
+                                 if not c["ok"]],
+                      "device": res["device"],
+                      "by_generator": res["by_generator"]}), flush=True)
+
+
 def main(argv: list[str]) -> int:
+    more = dict(a.split("=", 1) for a in argv if "=" in a)
+    argv = [a for a in argv if "=" not in a]
     logs = int(argv[0]) if argv else 1
     seed = int(argv[1]) if len(argv) > 1 else 2468013579
     trace_on = len(argv) > 2 and argv[2] == "trace"
@@ -49,21 +96,14 @@ def main(argv: list[str]) -> int:
 
         breaks.BREAKS[argv[3]]()
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import harness
-
     config, traffic = tiny(logs)
-    prep = harness.Prepared(config, traffic, seed=seed, seconds=6.0,
-                            loadgen_cores=loadgen_cores)
-    try:
-        import jax
-
-        devs = jax.devices()
-        device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
-                  "count": len(devs)}
-        res = harness.run_cell(prep, trace_on=trace_on, t_start=T_START,
-                               device=device)
-    finally:
-        prep.close()
+    if "traffic" in more:
+        with open(more["traffic"]) as fh:
+            traffic = json.load(fh)
+    res = run_once(config, traffic, seed=seed, seconds=6.0, trace_on=trace_on,
+                   loadgen_cores=loadgen_cores, t_start=T_START)
+    if res is None:
+        return 4
     if trace_on:
         import layers
 
@@ -71,15 +111,7 @@ def main(argv: list[str]) -> int:
             bench = json.load(fh)
         # No chip, so the device's metrics have nothing to read here.
         print(json.dumps(layers.read_all(bench, CELL, res, strict=False)))
-    print(json.dumps({"setup": res["setup"]}))
-    print(json.dumps({"diagnosis": res["diagnosis"]}))
-    for c in res["checks"]:
-        print(json.dumps(c))
-    print(json.dumps({"correct": res["correct"], "attempted": res["attempted"],
-                      "failed": res["failed"], "values": res["values"],
-                      "not_ok": [c["what"] for c in res["checks"]
-                                 if not c["ok"]],
-                      "device": res["device"]}))
+    report(res)
     return 0 if res["correct"] else 1
 
 

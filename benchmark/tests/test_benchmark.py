@@ -138,12 +138,17 @@ def test_trace_reduction_synthetic():
     assert dict(r["idle_gaps"])["x"] == pytest.approx(1.5 * 1.5 / 2.5)
     assert tracing.short("%while.74 = (s32[]{:T(128)}, u32[4,1]) while(") \
         == "while.74"
-    # A span that wraps another leaves the gap to the inner one.
+    # A span that wraps others leaves them their parts of the gap and
+    # keeps its own self time.
     r = tracing.reduce_trace(xp, 0.0, 6.50001, {
-        "ingest.decode": [(1.5, 5.0)], "native.decode_batch": [(2.0, 4.0)]})
+        "ingest.decode": [(1.5, 5.0)], "native.decode_batch": [(2.0, 4.0)],
+        "decode.native_call": [(2.5, 3.0)], "decode.pack": [(3.0, 3.75)]})
     assert dict(r["idle_gaps"]) == pytest.approx(
-        {"native.decode_batch": 2.0, "no_span": 1.5,
+        {"ingest.decode": 1.5, "native.decode_batch": 0.75,
+         "decode.native_call": 0.5, "decode.pack": 0.75, "no_span": 0.0,
          "within_program": 1e-5}, abs=1e-9)
+    assert tracing.less([(0, 10), (12, 14)], [(1, 2), (3, 4), (9, 13)]) \
+        == [(0, 1), (2, 3), (4, 9), (13, 14)]
 
 
 def test_trace_reduction_on_the_recorded_trace():
@@ -187,11 +192,15 @@ def test_trace_reduction_on_the_recorded_trace():
             dict(ctx, device={"kind": "some other chip"}))
 
 
-def rehearse(*args: str) -> dict:
+def rehearsal(*args: str) -> subprocess.CompletedProcess:
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    res = subprocess.run(
+    return subprocess.run(
         [sys.executable, os.path.join(HERE, "rehearse.py"), *args],
         capture_output=True, text=True, timeout=600, env=env, cwd=ROOT)
+
+
+def rehearse(*args: str) -> dict:
+    res = rehearsal(*args)
     assert res.stdout.strip(), res.stderr[-2000:]
     return json.loads(res.stdout.strip().splitlines()[-1])
 
@@ -202,6 +211,13 @@ def test_sound_run_is_correct(logs):
     parameter of the cell PERF.md keeps for later."""
     line = rehearse(str(logs), "31337")
     assert line["values"]["ingest_entries_per_s"] > 0 and line["failed"] == 0
+    # Key for key what a run with the log alone always printed, and the
+    # parts by generator.
+    assert list(line) == ["correct", "attempted", "failed", "values",
+                          "not_ok", "device", "by_generator"]
+    assert list(line["values"]) == ["ingest_entries_per_s", "setup_s"]
+    assert line["by_generator"] == {"log_replay": {
+        "attempted": line["attempted"], "failed": 0}}
     if logs == 1:
         assert line["correct"] is True
     else:
@@ -248,6 +264,152 @@ def test_deferred_checkpoint_is_not_correct():
     kept = harness.report_child(
         os.path.join(ROOT, ".bench_work", "report.ini"))
     assert 0 < kept["totals"]["serials"] <= 1024  # the warm-up batch's
+
+
+def query_step(*args: str) -> dict:
+    """``sweep_query.py``'s cell (the log and ``query_poisson``, a
+    configuration that asks for ``queryPort``) cut to a rehearsal's
+    size, through ``harness.Prepared`` and ``run_cell``."""
+    res = subprocess.run(
+        [sys.executable, os.path.join(HERE, "sweep_query.py"), "step", "40",
+         *args, "tiny"], capture_output=True, text=True, timeout=600, cwd=ROOT)
+    assert res.stdout.strip(), res.stderr[-2000:]
+    return json.loads(res.stdout.strip().splitlines()[-1])
+
+
+def query_traffic(tmp_path, name: str, generators) -> str:
+    """The sweep's traffic file cut to size, its generators beside the
+    log replaced by ``generators(them)``, as a file."""
+    sys.path.insert(0, HERE)
+    import sweep_query
+
+    _config, traffic = sweep_query.cell(40.0, tiny=True)
+    traffic["generators"][1:] = generators(traffic["generators"][1:])
+    path = os.path.join(tmp_path, name)
+    with open(path, "w") as fh:
+        json.dump(traffic, fh)
+    return path
+
+
+def test_query_generator_beside_the_log_is_correct():
+    """A traffic file with ``query_poisson`` beside the log and a
+    configuration that asks for a port: files and nothing else. The port
+    taken reaches the ini and the generator's spec."""
+    line = query_step("31340")
+    assert line["correct"] is True, line["not_ok"]
+    parts = line["by_generator"]
+    assert list(parts) == ["log_replay", "query_poisson"]
+    assert parts["query_poisson"]["attempted"] > 100
+    assert parts["query_poisson"]["failed"] == 0
+    values = line["values"]
+    assert values["query_sent"] == parts["query_poisson"]["attempted"]
+    assert 0 < values["query_p50_ms"] <= values["query_p95_ms"] \
+        <= values["query_p99_ms"] < 10_000
+    work = os.path.join(ROOT, ".bench_work")
+    with open(os.path.join(work, "query_poisson.spec.json")) as fh:
+        spec = json.load(fh)
+    with open(os.path.join(work, "ct-fetch.ini")) as fh:
+        ini = dict(x.strip().split(" =", 1) for x in fh if " =" in x)
+    assert set(spec["ports"]) == {"metricsPort", "queryPort"}
+    assert int(ini["queryPort"]) == spec["ports"]["queryPort"]
+    assert int(ini["metricsPort"]) == spec["ports"]["metricsPort"]
+    assert spec["generator"]["kind"] == "query_poisson"
+    # The harness names neither the kind nor the directive.
+    for name in ("harness.py", "run.py", "layers.py"):
+        with open(os.path.join(BENCH, name)) as fh:
+            text = fh.read()
+        assert "query_poisson" not in text and "queryPort" not in text
+
+
+def test_wrong_answer_is_not_correct():
+    """The control of a cell with queries: every seventh membership
+    answer inverted underneath, and ``correct`` comes out false by the
+    generator's own check."""
+    line = query_step("31341", "wrong_answer")
+    assert line["correct"] is False
+    assert [w[:40] for w in line["not_ok"]] == [
+        "query_poisson: answers that contradict t"]
+    assert line["by_generator"]["query_poisson"]["failed"] > 0
+    assert line["by_generator"]["log_replay"]["failed"] == 0
+
+
+def test_a_cell_whose_files_are_wrong_fails_before_jax_loads(tmp_path):
+    res = rehearsal("1", "5", "notrace", "traffic=" + query_traffic(
+        tmp_path, "unknown.json", lambda gs: [dict(gs[0], kind="no_such_kind")]))
+    assert res.returncode == 4 and res.stdout.strip() == ""
+    assert "benchmark/generators/no_such_kind.py is not there" in res.stderr
+    assert "(jax loaded: False)" in res.stderr
+    # Two generators that give the same values: a name given twice.
+    twice = query_traffic(tmp_path, "twice.json",
+                          lambda gs: [gs[0], dict(gs[0], rate_per_s=8)])
+    res = rehearsal("1", "5", "notrace", "traffic=" + twice)
+    assert res.returncode == 4 and res.stdout.strip() == ""
+    assert "values given twice: query_failed, query_p50_ms" in res.stderr
+    assert "(jax loaded: False)" in res.stderr
+    # No log, or two, is no traffic file either.
+    with open(twice) as fh:
+        traffic = json.load(fh)
+    with pytest.raises(harness.RunFailed, match="exactly one log_replay"):
+        harness.log_spec(dict(traffic, generators=traffic["generators"][1:]),
+                         6.0, 1024)
+
+
+def test_query_summary_counts_what_a_user_would():
+    """``query_poisson.summarise`` on rows written by hand: latency from
+    the instant a request was due, ten deadlines for one that failed,
+    and the two checks."""
+    from generators import query_poisson as qp
+
+    spec = fx.LogSpec(**dict(SPEC, logs=1))
+    fixture = fx.RunFixture(spec, 3)
+    params = {"rate_per_s": 50.0, "known_share": 0.5, "zipf_s": 0.99,
+              "min_age_s": 2.0, "deadline_s": 1.0}
+    schedule = qp.Schedule(3, params)
+    due = [100.0 + a[0] for a in schedule.chunk()]
+    due = [t for t in due if 101.0 < t <= 103.0]
+    assert 60 < len(due) < 140
+    total = fixture.logs[0].total
+    # The log served entries [0, 2048) at 90 s and the rest at 102 s.
+    pages = [[0, 0, 2048, 89.9, 89.95, 90.0],
+             [0, 2048, total - 2048, 101.9, 101.95, 102.0]]
+    window = {"t_open": 100.0, "t_first": 101.0, "t_folded": 103.0,
+              "pages": pages, "generator": params}
+    rows = [[t, t + 0.001, t + 0.020, 200, 1, 0, 7, True] for t in due]
+    rows += [[100.5, 100.5, 100.6, 200, 1, 0, 7, False]]  # before the window
+    summarise = lambda rows: qp.summarise(  # noqa: E731
+        {"requests": rows, "generator": {}}, window, fixture, {})
+    got = summarise(rows)
+    assert got["attempted"] == len(due) and got["failed"] == 0
+    assert [c["got"] for c in got["checks"]] == [0, 0]
+    assert got["values"]["query_p99_ms"] == pytest.approx(20.0)
+    assert sorted(got["values"]) == sorted(qp.VALUES)
+    # Refused, late, broken and wrong: each one failed, ten deadlines.
+    rows[0][3] = 429
+    rows[1][2] = rows[1][0] + 1.5
+    rows[2][3], rows[2][7] = 0, None
+    rows[3][7] = False          # a fed-and-aged serial answered unknown
+    rows[4][4:8] = 0, 0, total + 5, True  # a never-fed one answered known
+    got = summarise(rows)
+    assert got["failed"] == 5 and got["values"]["query_failed"] == 5.0
+    assert got["values"]["query_p99_ms"] == pytest.approx(10_000.0)
+    assert got["values"]["query_p50_ms"] == pytest.approx(20.0)
+    assert [c["got"] for c in got["checks"]] == [2, 0]
+    # The generator's own faults: a serial not yet aged (entry 3000 was
+    # served at 102 s), a fed one sent as never fed, one row missing; and
+    # one sent late, which is counted apart.
+    rows = [[t, t + 0.001, t + 0.020, 200, 1, 0, 7, True] for t in due]
+    rows[0][1] += 0.2
+    rows[1][6] = 3000
+    rows[2][4:8] = 0, 0, 9, True
+    got = summarise(rows[:-1])
+    assert [c["got"] for c in got["checks"]] == [1, 3]
+    assert got["values"]["query_sent_late"] == 1.0
+    with pytest.raises(ValueError):
+        summarise(rows[:0])
+    aged = qp.Aged(1)
+    aged.add(pages)
+    assert [aged.entries(0, t) for t in (89.0, 90.0, 101.0, 102.5)] \
+        == [0, 2048, 2048, total]
 
 
 def test_run_py_refuses_without_the_chip():

@@ -1,6 +1,6 @@
 """Tests of ``readers/span_ring.py``, the reader of the program's own
-span ring, and of the per-layer metrics that wait in
-``pending_per_layer.json``:  python3 -m pytest benchmark/tests -q
+span ring, and of the nine per-layer metrics that read it:
+python3 -m pytest benchmark/tests -q
 """
 
 from __future__ import annotations
@@ -18,7 +18,15 @@ BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, BENCH)
 
+import harness  # noqa: E402
+import layers  # noqa: E402
+from layers import ABSENT  # noqa: E402
 from readers import span_ring  # noqa: E402
+
+RING_METRICS = (
+    "fetch.blocked_share", "fetch.http_us_per_entry",
+    "fetch.parse_us_per_entry", "sink.starved_share", "decode.native_share",
+    "ckpt.save_s", "ckpt.d2h_s", "ckpt.write_s", "host.unattributed_share")
 
 THREADS = [{"ph": "M", "name": "thread_name", "tid": 1,
             "args": {"name": "sync-http://log"}},
@@ -97,8 +105,12 @@ def test_self_time_takes_the_children_off_by_parent():
 def test_one_span_over_another():
     assert read({"span": "decode.native_call", "per": "span:ingest.decode",
                  "scale": 100.0}) == pytest.approx(25.0)
+    # The base's family is nowhere in the run: not in this program.
     assert read({"span": "decode.native_call",
-                 "per": "span:no.such.span"}) is None
+                 "per": "span:no.such.span"}) is ABSENT
+    # Its family is there and the name is not: a span renamed.
+    assert read({"span": "decode.native_call",
+                 "per": "span:ingest.no_such_span"}) is None
 
 
 def test_args_filter_and_the_drain_phase():
@@ -125,14 +137,45 @@ def test_uncovered_is_the_worst_of_the_named_threads():
     assert read(dict(params, uncovered=["nobody-"])) is None
 
 
-def test_nothing_to_read_on_a_missing_span_or_an_older_program():
+def test_a_renamed_span_is_nothing_to_read_and_an_older_program_is_absent():
+    # The family is in the run, the name is not: a span renamed.
     assert read({"span": "fetch.between_pages"}) is None
+    # No span of the family anywhere in the run: not in this program.
+    assert read({"span": "serve.wait"}) is ABSENT
+    # ... unless the ring forgot events: then nobody can tell.
+    assert read({"span": "serve.wait"}, ctx_of(EVENTS, dropped=7)) is None
     # A tracer that records no parents (the program before these spans).
     old = [{k: v for k, v in e.items() if k not in ("id", "parent")}
            for e in EVENTS]
-    assert read({"span": "fetch.enqueue"}, ctx_of(old)) is None
+    assert read({"span": "fetch.enqueue"}, ctx_of(old)) is ABSENT
     with pytest.raises(ValueError):
         read({"span": "fetch.enqueue", "phase": "warmup"})
+
+
+def test_an_absent_metric_is_left_out_and_an_empty_one_fails_by_name():
+    """``layers.read_metrics`` on ``BENCHMARK.json``'s own entries: a
+    program without the ``fetch.`` family leaves ``fetch.blocked_share``
+    out and names it; one that has the family and no ``fetch.enqueue`` in
+    the window fails the run by the metric's name."""
+    entries = [{"name": "fetch.blocked_share", "unit": "%",
+                "workloads": ["backfill-1log"]},
+               {"name": "sink.starved_share", "unit": "%",
+                "workloads": ["backfill-1log"]}]
+    no_fetch = [e for e in EVENTS if not e["name"].startswith("fetch.")]
+    metrics, absent = layers.read_metrics(entries, "backfill-1log",
+                                          ctx_of(no_fetch))
+    assert absent == ["fetch.blocked_share"]
+    assert metrics == {"sink.starved_share": {
+        "value": pytest.approx(50.0), "unit": "%"}}
+    assert layers.read_metrics(entries, "some-other-cell",
+                               ctx_of(no_fetch)) == ({}, [])
+    none_in_window = [e for e in EVENTS if e["name"] != "fetch.enqueue"]
+    with pytest.raises(harness.RunFailed, match="fetch.blocked_share"):
+        layers.read_metrics(entries, "backfill-1log", ctx_of(none_in_window))
+    # The rehearsal's way (strict off) leaves both kinds out.
+    assert layers.read_metrics(entries[:1], "backfill-1log",
+                               ctx_of(none_in_window), strict=False) \
+        == ({}, [])
 
 
 def test_nothing_to_read_from_a_window_the_ring_did_not_see_whole():
@@ -155,19 +198,20 @@ def recorded():
             "entries": doc["entries"], "batches": doc["batches"]}
 
 
-def pending():
-    with open(os.path.join(BENCH, "pending_per_layer.json")) as fh:
-        return json.load(fh)["per_layer"]
+def ring_metrics():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+        listed = {m["name"]: m for m in json.load(fh)["per_layer"]}
+    return [listed[name] for name in RING_METRICS]
 
 
-def test_pending_metrics_on_the_recorded_ring():
+def test_ring_metrics_on_the_recorded_ring():
     """The ring of a tiny CPU rehearsal (six 1,024-entry batches in the
     window, 64-entry pages), trimmed to the window and the drain. The
     numbers were read off it once and must not move; two of them are
     recounted here the slow way."""
     ctx = recorded()
     got = {}
-    for entry in pending():
+    for entry in ring_metrics():
         with open(os.path.join(BENCH, "layers",
                                entry["name"] + ".json")) as fh:
             spec = json.load(fh)
@@ -194,36 +238,32 @@ def test_pending_metrics_on_the_recorded_ring():
     assert got["ckpt.d2h_s"] + got["ckpt.write_s"] <= got["ckpt.save_s"]
 
 
-def test_pending_entries_keep_the_contract_and_are_not_listed_yet():
+def test_ring_metrics_are_listed_after_the_thirteen():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
         bench = json.load(fh)
-    layers = {m["layer"] for m in bench["per_layer"]}
-    e2e = {m["name"] for m in bench["end_to_end"]}
-    listed = {m["name"] for m in bench["per_layer"]}
-    assert len(pending()) == 9
-    for m in pending():
-        assert set(m) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert m["source"] == "program_span" and m["layer"] in layers
-        assert m["moves"] in e2e and m["workloads"] == ["backfill-1log"]
-        assert m["name"] not in listed  # see pending_per_layer.json
-        assert os.path.exists(os.path.join(BENCH, "layers",
-                                           m["name"] + ".json"))
+    assert len(bench["per_layer"]) == 22
+    assert [m["name"] for m in bench["per_layer"][13:]] == list(RING_METRICS)
+    for m in ring_metrics():
+        assert m["source"] == "program_span"
+        assert m["workloads"] == ["backfill-1log"]
+        with open(os.path.join(BENCH, "layers", m["name"] + ".json")) as fh:
+            assert json.load(fh)["reader"] == "span_ring"
 
 
-def test_rehearsal_prints_the_pending_metrics():
-    """The traced run end to end on the CPU, with the pending metrics
-    read beside the listed ones: every one of the nine has something to
-    read, and the two fetch spans divide the old fetch timer."""
+def test_rehearsal_prints_the_ring_metrics():
+    """The traced run end to end on the CPU: every one of the nine has
+    something to read, and the two fetch spans divide the old fetch
+    timer."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     res = subprocess.run(
-        [sys.executable, os.path.join(HERE, "pending.py"), "rehearse", "1",
+        [sys.executable, os.path.join(HERE, "rehearse.py"), "1",
          "31342", "trace"], capture_output=True, text=True, timeout=600,
         env=env, cwd=ROOT)
     lines = [json.loads(x) for x in res.stdout.strip().splitlines()]
     assert lines[-1]["correct"] is True, res.stderr[-2000:]
+    assert not any("absent" in x for x in lines if isinstance(x, dict))
     metrics = next(x for x in lines if isinstance(x, list))[0]
-    for m in pending():
+    for m in ring_metrics():
         assert metrics[m["name"]]["value"] >= 0, m["name"]
         assert metrics[m["name"]]["unit"] == m["unit"]
     for name in ("fetch.blocked_share", "sink.starved_share",

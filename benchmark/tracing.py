@@ -8,6 +8,7 @@ checked against the recorded trace in ``tests/data``.
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import time
@@ -21,7 +22,8 @@ PAGE_IN_FLIGHT = "loadgen.page_in_flight"
 BETWEEN_PAGES = "fetch.between_pages"
 ANCHOR = "bench.anchor"
 # The program's span names (telemetry/trace.py callers), by family.
-SPAN_PREFIXES = ("ingest.", "device.", "native.", "mesh.")
+SPAN_PREFIXES = ("ingest.", "device.", "native.", "mesh.", "fetch.", "sink.",
+                 "decode.", "fold.", "ckpt.")
 # Device gaps shorter than this lie inside one program (between its
 # ops) and are not worth a name each.
 MIN_GAP_S = 200e-6
@@ -124,11 +126,39 @@ class Covered:
         return self.upto(hi) - self.upto(lo)
 
 
-# Spans that wrap another span of the program: where both are in the
-# profile the inner one names the gap.
-WRAPS = {"ingest.decode": "native.decode_batch",
-         "device.readback": "device.fold",
-         "ingest.submit_locked": "ingest.submit"}
+# Spans that wrap other spans of the program: where an inner one is in
+# the profile it names its part of a gap, and the outer one keeps what
+# no inner one covers (its self time).
+WRAPS = {"ingest.decode": ("native.decode_batch",),
+         "native.decode_batch": ("decode.concat_b64", "decode.native_call",
+                                 "decode.issuer_groups", "decode.pack"),
+         "device.readback": ("device.fold",),
+         "device.fold": ("fold.wait_device",),
+         "ingest.submit_locked": ("ingest.submit",),
+         "fetch.page": ("fetch.get_entries", "fetch.parse_json",
+                        "fetch.enqueue"),
+         "fetch.save_cursor": ("ckpt.wait_outstanding",),
+         "ckpt.save": ("ckpt.d2h", "ckpt.write", "ckpt.seal")}
+
+
+def less(outer: list[tuple[float, float]],
+         inner: list[tuple[float, float]]) -> list[tuple[float, float]]:
+    """The parts of ``outer``'s intervals that no interval of ``inner``
+    covers."""
+    cuts = union(inner)
+    ends = [b for _a, b in cuts]
+    out = []
+    for lo, hi in outer:
+        for k in range(bisect.bisect_right(ends, lo), len(cuts)):
+            a, b = cuts[k]
+            if a >= hi:
+                break
+            if a > lo:
+                out.append((lo, a))
+            lo = b
+        if lo < hi:
+            out.append((lo, hi))
+    return out
 
 
 def short(op: str) -> str:
@@ -157,8 +187,9 @@ def reduce_trace(xp: dict, lo: float, hi: float,
     gaps: dict[str, float] = {}
     op_calls: dict[str, int] = {}
     module_calls: dict[str, int] = {}
-    host_spans = {k: v for k, v in host_spans.items()
-                  if WRAPS.get(k) not in host_spans}
+    host_spans = {k: less(v, [iv for inner in WRAPS.get(k, ())
+                              for iv in host_spans.get(inner, ())])
+                  for k, v in host_spans.items()}
     named = {name: Covered(ivals) for name, ivals in host_spans.items()}
     covered = Covered([iv for ivals in host_spans.values() for iv in ivals])
     first_plane = True
