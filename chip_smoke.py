@@ -447,19 +447,19 @@ def drive(fixture: Fixture, log: LogServer, workdir: str, *, table_bits: int,
 
 
 def serve_timers() -> dict:
-    """Seconds the query plane spent capturing the table (the device →
-    host read) and swapping a replica in (capture plus the pin back
-    onto the device), from the process's own metrics sink."""
+    """Seconds the query plane spent swapping a replica in (the
+    capture under the locks plus the wait for the copy on the device),
+    from the process's own metrics sink."""
     from ct_mapreduce_tpu.telemetry import metrics
 
     samples = metrics.get_sink().snapshot().get("samples", {})
     out = {}
-    for key in ("serve.snapshot_capture_s", "serve.replica_swap_s"):
-        s = samples.get(key)
-        if s and s.get("count"):
-            out[key] = {"count": s["count"],
-                        "mean_s": round(s["sum"] / s["count"], 3),
-                        "max_s": round(s["max"], 3)}
+    s = samples.get("serve.replica_swap_s")
+    if s and s.get("count"):
+        out["serve.replica_swap_s"] = {
+            "count": s["count"],
+            "mean_s": round(s["sum"] / s["count"], 3),
+            "max_s": round(s["max"], 3)}
     return out
 
 

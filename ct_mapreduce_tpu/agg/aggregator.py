@@ -502,6 +502,16 @@ class TpuAggregator:
         # Guards self.table swaps vs concurrent reads: the donated step
         # invalidates the previous table buffer, so a contains probe or
         # checkpoint read racing a submit would touch a deleted array.
+        # What a holder may count on: self.table is a live, fully-
+        # stepped buffer, and device work DISPATCHED against it under
+        # the lock (a probe, the query plane's snapshot_copy) reads it
+        # before any later step's donation reuses its memory — every
+        # step swaps self.table under this lock, so it is dispatched
+        # after; one device runs its programs in dispatch order; and a
+        # donation waits for the reads enqueued before it. So a
+        # device-side reader may release the lock once its program is
+        # dispatched and wait for the result outside; only a host
+        # fetch (np.asarray) has to finish under it.
         # Lock order where both are held: _fold_lock, then _table_lock.
         self._table_lock = threading.RLock()
         # Serializes whole checkpoint writes: the fleet cadence thread

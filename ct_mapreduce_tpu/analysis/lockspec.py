@@ -11,7 +11,7 @@ rank then documents where they'd sit if composition ever nests them.
 The chain the ISSUE names (``agg/aggregator.py:482-494``,
 ``ingest/sync.py:185-189``) is the trunk::
 
-    serve.manager/pool_refresh/pool   (10-14)  snapshot capture wrappers
+    serve.pool_refresh/pool           (12-14)  snapshot capture wrappers
         ingest.dispatch               (20)     ONE device stream
             agg.save                  (24)     checkpoint writer
                 agg.pending           (30)     per-pending claim
@@ -57,9 +57,6 @@ class LockDecl:
 # statically distinguishable).
 LOCKS: tuple[LockDecl, ...] = (
     # -- serve plane (outermost: may wrap a full aggregate capture) -----
-    LockDecl("serve.manager", "ct_mapreduce_tpu/serve/snapshot.py",
-             "SnapshotManager", "_lock", 10,
-             "view refresh; held across capture_view -> agg.fold"),
     LockDecl("serve.pool_refresh", "ct_mapreduce_tpu/serve/snapshot.py",
              "ReplicaPool", "_refresh_lock", 12,
              "one capture in flight; held across capture + pin"),

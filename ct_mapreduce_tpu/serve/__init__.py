@@ -35,6 +35,5 @@ from ct_mapreduce_tpu.serve.batcher import (  # noqa: F401
 from ct_mapreduce_tpu.serve.cache import HotSerialCache  # noqa: F401
 from ct_mapreduce_tpu.serve.snapshot import (  # noqa: F401
     ReplicaPool,
-    SnapshotManager,
     TableView,
 )

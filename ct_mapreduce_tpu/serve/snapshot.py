@@ -7,10 +7,12 @@ touched live state mid-step could see a torn table or a half-folded
 batch. Instead of per-query locking, the query plane reads an
 **immutable epoch-pinned view**: :func:`capture_view` takes the
 aggregator's fold lock, then the table lock (the established global
-order — see ``TpuAggregator.__init__``), copies the table rows to host
-memory through the same one-fetch read the checkpoint writer uses, and
-freezes the host-lane serial sets. Every query against that view is
-lock-free and sees one consistent epoch.
+order — see ``TpuAggregator.__init__``), copies the table rows, and
+freezes the host-lane serial sets. A device view's copy is made on the
+device (:func:`snapshot_copy`, dispatched under the table lock in
+front of the next ingest step; the rows never cross the host link); a
+host mirror's is the one-fetch read the checkpoint writer uses. Every
+query against that view is lock-free and sees one consistent epoch.
 
 Consistency contract (pinned by the threaded stress test in
 tests/test_serve.py): any serial whose ingest was **acked** (its
@@ -21,21 +23,21 @@ a serial never fed cannot read known (membership is exact, not
 probabilistic: the 128-bit fingerprint's false-positive odds are the
 same ones the dedup itself already accepts).
 
-Staleness is a bound, not an accident: :class:`SnapshotManager`
-refreshes the view when it is older than ``max_staleness_s`` and every
-response carries the view's epoch and age, so a consumer can tell
-"known as of 0.3 s ago" from "known as of now".
+Staleness is a bound, not an accident: :class:`ReplicaPool` swaps its
+stalest view for a new epoch once it is older than ``max_staleness_s``
+and every response carries the view's epoch and age, so a consumer can
+tell "known as of 0.3 s ago" from "known as of now".
 
-:class:`ReplicaPool` (round 12) is the production tier of the same
-idea: N epoch-pinned **device** views serve round-robin, refreshed
-STAGGERED — one replica swaps to a new epoch at a time, captured and
-pinned on a background thread — so a capture (the table D2H under the
-fold/table locks, which contends with ingest) never stalls the serving
-path, and serving itself runs the jitted ``contains`` kernels on
-pinned device copies instead of sharing a host core with ingest's
-numpy. On a mesh the pool pins **per-shard row blocks**, each on its
-shard's own device (queries route by ``shard_of_np`` exactly like
-ingest lanes); on one chip it pins N full copies. Mixed epochs across
+:class:`ReplicaPool` holds N epoch-pinned views (**device** views in
+production) that serve round-robin, refreshed STAGGERED — one replica
+swaps to a new epoch at a time, captured and waited for on a
+background thread — so a capture (the fold/table locks, which contend
+with ingest) never stalls the serving path, and serving itself runs
+the jitted ``contains`` kernels on device copies instead of sharing a
+host core with ingest's numpy. On a mesh a replica serves from
+**per-shard row blocks**, the copy's own shards, each on its shard's
+device (queries route by ``shard_of_np`` exactly like ingest lanes);
+on one chip the pool holds N full copies. Mixed epochs across
 replicas are safe by construction: every view is individually
 consistent, answers carry the serving view's epoch + age, and
 membership is monotone (a serial is never deleted), so an older
@@ -48,6 +50,8 @@ import threading
 import time
 from typing import Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ct_mapreduce_tpu.core import packing
@@ -64,27 +68,49 @@ def _on_tpu() -> bool:
     """Whether device serving runs on a TPU backend — where a device
     copy that cannot land or answer is an error, never a quiet switch
     to the host mirror."""
-    import jax
-
     return jax.default_backend() == "tpu"
+
+
+@jax.jit
+def snapshot_copy(rows):
+    """A device view's copy of the table rows: one program per table
+    shape, reading and writing the whole table in device memory (a
+    mesh-sharded table keeps its sharding, shard for shard). The call
+    only dispatches it; :meth:`TableView.pin` waits for the result."""
+    return jnp.copy(rows)
+
+
+def _shard_blocks(dev_rows, layout: str) -> list:
+    """A row-sharded copy's own shards as ready probe states, shard
+    ``i``'s contiguous row block on shard ``i``'s device (rows + count
+    on the SAME device, so the jitted kernel never crosses chips)."""
+    state_cls = (buckettable.BucketTable if layout == "bucket"
+                 else hashtable.TableState)
+    shards = sorted(dev_rows.addressable_shards,
+                    key=lambda s: s.index[0].start or 0)
+    return [state_cls(s.data,
+                      jax.device_put(np.zeros((), np.int32), s.device))
+            for s in shards]
 
 
 class TableView:
     """One immutable epoch of aggregator state, query-ready.
 
-    ``rows`` is the host copy of the dedup table (fused layout rows for
-    either table layout; for a sharded aggregator the global
-    row-concatenated array, shard ``i`` owning the ``i``-th contiguous
-    block). ``host_serials`` maps ``(issuer_idx, exp_hour)`` to a
-    frozen set of exact-lane serial bytes. Membership is the union of
-    the two domains, mirroring the aggregator's own cross-domain
-    guards.
+    The dedup table's rows (fused layout rows for either table layout;
+    for a sharded aggregator the global row-concatenated array, shard
+    ``i`` owning the ``i``-th contiguous block) are held where the view
+    probes them: a device view holds ``dev_rows``, the copy
+    :func:`capture_view` made on the device, and no host array; a host
+    mirror holds ``rows``. ``host_serials`` maps ``(issuer_idx,
+    exp_hour)`` to a frozen set of exact-lane serial bytes. Membership
+    is the union of the two domains, mirroring the aggregator's own
+    cross-domain guards.
     """
 
     def __init__(
         self,
         epoch: int,
-        rows: np.ndarray,
+        rows: Optional[np.ndarray],
         layout: str,
         n_shards: int,
         max_probes: int,
@@ -96,13 +122,13 @@ class TableView:
         registry,
         table_fill: int,
         capacity: int,
-        device: bool = False,
-        devices: Optional[list] = None,
+        dev_rows=None,
         created_wall: Optional[float] = None,
         verify_counts: Optional[dict] = None,
     ) -> None:
         self.epoch = epoch
-        self.rows = rows
+        self.rows = rows  # host mirror; None while the view is on device
+        self.n_rows = int((rows if dev_rows is None else dev_rows).shape[0])
         self.layout = layout
         self.n_shards = n_shards
         self.max_probes = max_probes
@@ -123,69 +149,55 @@ class TableView:
         # the surfaced staleness errs larger, never smaller.
         self.created_wall = (time.time() if created_wall is None
                              else created_wall)
-        self._device = bool(device)
-        self._devices = devices  # explicit placement targets (pool mode)
-        self._dev_rows = None  # pinned device copy (device mode)
-        self._dev_blocks = None  # per-shard pinned states (sharded pool)
+        self._dev_rows = dev_rows  # the device copy (device views)
+        # its shards as probe states, one a shard (device views)
+        self._dev_blocks = (None if dev_rows is None
+                            else _shard_blocks(dev_rows, layout))
         self.replica_ix = None  # pool slot this view serves from
+
+    @property
+    def _device(self) -> bool:
+        """Whether the view answers from its device copy."""
+        return self._dev_rows is not None
 
     def age_s(self) -> float:
         return max(0.0, time.time() - self.created_wall)
 
     def pin(self) -> "TableView":
-        """Materialize the device copy NOW, on the caller's (refresh)
-        thread, so the serving path never pays the H2D transfer. In a
-        sharded pool each shard's contiguous row block is placed on
-        its own device — a replica never holds the full global rows on
-        any one chip — wrapped as a ready probe state (rows + count on
-        the SAME device, so the jitted kernel runs without cross-device
-        transfers). On a TPU backend a copy that cannot land is an
-        error and propagates: the host mirror is what ``serveDevice =
-        false`` selects, not something a chip run slides into. On other
-        backends a failure to pin flips the view to the host-numpy
-        mirror permanently — the next epoch's capture retries the
-        device path."""
-        if not self._device:
-            return self
-        try:
-            import jax
-            import jax.numpy as jnp
-
-            if self.n_shards > 1 and self._devices:
-                block = self.rows.shape[0] // self.n_shards
-                state_cls = (buckettable.BucketTable
-                             if self.layout == "bucket"
-                             else hashtable.TableState)
-                blocks = []
-                for s in range(self.n_shards):
-                    dev = self._devices[s % len(self._devices)]
-                    rows = jax.device_put(
-                        self.rows[s * block : (s + 1) * block], dev)
-                    count = jax.device_put(np.zeros((), np.int32), dev)
-                    blocks.append(state_cls(rows, count))
-                self._dev_blocks = blocks
-            elif self._devices:
-                self._dev_rows = jax.device_put(self.rows,
-                                                self._devices[0])
-            else:
-                self._dev_rows = jnp.asarray(self.rows)
-        except Exception:
-            if _on_tpu():
-                raise
-            incr_counter("serve", "device_fallback")
-            self._device = False
-            self._dev_rows = None
-            self._dev_blocks = None
+        """Wait for the device copy NOW, on the caller's (refresh)
+        thread, so the serving path never waits for it. The view was
+        given its copy at capture, on the device: there is nothing to
+        transfer. On a TPU backend a copy that cannot land is an error
+        and propagates: the host mirror is what ``serveDevice = false``
+        selects, not something a chip run slides into. On other
+        backends the view becomes a host mirror permanently — the next
+        epoch's capture retries the device path."""
+        if self._device:
+            try:
+                self._dev_rows.block_until_ready()
+            except Exception:
+                if _on_tpu():
+                    raise
+                self._to_host_mirror()
         return self
+
+    def _to_host_mirror(self) -> None:
+        """Off the TPU only: this view answers from the host from now
+        on. The mirror is read from the view's own device copy, which
+        is then dropped."""
+        incr_counter("serve", "device_fallback")
+        self.rows = np.asarray(self._dev_rows)
+        self._dev_rows = None
+        self._dev_blocks = None
 
     # -- membership ------------------------------------------------------
     def contains_fps(self, fps: np.ndarray) -> np.ndarray:
         """bool[n] membership of fingerprint rows ``uint32[n, 4]``
-        against the pinned table — host NumPy by default; ``device``
-        views pin one device copy and run the jitted ``contains``
-        kernels on pow2-padded batches (log-bounded compile shapes)."""
+        against the view's table — a host mirror probes in NumPy; a
+        device view runs the jitted ``contains`` kernels on its device
+        copy, on pow2-padded batches (log-bounded compile shapes)."""
         n = int(len(fps))
-        if n == 0 or self.rows.shape[0] == 0:
+        if n == 0 or self.n_rows == 0:
             return np.zeros((n,), bool)
         fps = np.asarray(fps, np.uint32).reshape(n, 4)
         if self._device:
@@ -208,7 +220,7 @@ class TableView:
 
         dest = shard_of_np(fps, self.n_shards)
         out = np.zeros((fps.shape[0],), bool)
-        block = self.rows.shape[0] // self.n_shards
+        block = self.n_rows // self.n_shards
         for s in np.unique(dest):
             sel = dest == s
             local = self.rows[s * block : (s + 1) * block]
@@ -221,76 +233,39 @@ class TableView:
         return out
 
     def _contains_device(self, fps: np.ndarray) -> np.ndarray:
-        if self._dev_rows is None and self._dev_blocks is None:
-            # Pinned once per view: queries must never touch the live
-            # (donated-through) table buffer. pin() flips the view to
-            # the host mirror when no device copy can land.
-            self.pin()
-            if not self._device:
-                return self._contains_host(fps)
         try:
             with trace.span("serve.contains_device", cat="serve",
                             lanes=int(fps.shape[0])):
                 return self._contains_device_pinned(fps)
         except Exception:
-            # Off the TPU, a pinned copy that stops answering (backend
+            # Off the TPU, a device copy that stops answering (backend
             # teardown mid-run) degrades to the host mirror instead of
             # failing the batch; the next epoch retries the device.
             if _on_tpu():
                 raise
-            incr_counter("serve", "device_fallback")
-            self._device = False
-            self._dev_rows = None
-            self._dev_blocks = None
+            self._to_host_mirror()
             return self._contains_host(fps)
 
     def _contains_device_pinned(self, fps: np.ndarray) -> np.ndarray:
-        import jax.numpy as jnp
+        if self.n_shards == 1:
+            return self._probe_state(self._dev_blocks[0], fps)
+        # Shard-routed: home shard on host (the ingest routing hash),
+        # then the jitted single-table probe against that shard's
+        # block on that shard's device.
+        from ct_mapreduce_tpu.agg.sharded import shard_of_np
 
-        n = fps.shape[0]
-        if self._dev_blocks is not None:
-            # Shard-routed: home shard on host (the ingest routing
-            # hash), then the jitted single-table probe against that
-            # shard's pinned block on that shard's device.
-            from ct_mapreduce_tpu.agg.sharded import shard_of_np
-
-            dest = shard_of_np(fps, self.n_shards)
-            out = np.zeros((n,), bool)
-            for s in np.unique(dest):
-                sel = dest == s
-                out[sel] = self._probe_state(self._dev_blocks[s],
-                                             fps[sel])
-            return out
-        width = max(16, 1 << max(0, (n - 1).bit_length()))
-        if width != n:
-            fps = np.pad(fps, ((0, width - n), (0, 0)))
-        keys = jnp.asarray(fps)
-        if self.n_shards > 1:
-            from ct_mapreduce_tpu.agg import sharded
-
-            fn = (sharded._contains_global_bucket
-                  if self.layout == "bucket" else sharded._contains_global)
-            found = fn(self._dev_rows, keys, n_shards=self.n_shards,
-                       max_probes=self.max_probes)
-        elif self.layout == "bucket":
-            found = buckettable.contains(
-                buckettable.BucketTable(self._dev_rows,
-                                        jnp.zeros((), jnp.int32)),
-                keys, max_probes=self.max_probes)
-        else:
-            found = hashtable.contains(
-                hashtable.TableState(self._dev_rows,
-                                     jnp.zeros((), jnp.int32)),
-                keys, max_probes=self.max_probes)
-        return np.asarray(found)[:n]
+        dest = shard_of_np(fps, self.n_shards)
+        out = np.zeros((fps.shape[0],), bool)
+        for s in np.unique(dest):
+            sel = dest == s
+            out[sel] = self._probe_state(self._dev_blocks[s], fps[sel])
+        return out
 
     def _probe_state(self, state, fps: np.ndarray) -> np.ndarray:
-        """Jitted contains against one pinned probe state, pow2-padded
+        """Jitted contains against one probe state, pow2-padded
         (min 16) so compile shapes stay log-bounded — the same rule as
         the aggregator's `_device_contains`. Keys are placed on the
         state's device so the kernel never crosses chips."""
-        import jax
-
         n = fps.shape[0]
         width = max(16, 1 << max(0, (n - 1).bit_length()))
         if width != n:
@@ -366,32 +341,50 @@ class TableView:
         return meta
 
 
-def capture_view(agg, epoch: int, device: bool = False,
-                 devices: Optional[list] = None) -> TableView:
+def capture_view(agg, epoch: int, device: bool = False) -> TableView:
     """Pin one epoch of ``agg`` (TpuAggregator, ShardedAggregator, or
     the host snapshot reader) into an immutable :class:`TableView`.
 
     Lock order is fold → table, matching every other cross-state reader
     (``grow``, ``drain``): holding the fold lock freezes the host-lane
     sets mid-nothing (folds serialize on it), and the table lock
-    guarantees the row fetch reads a live, fully-stepped buffer. The
-    row read is the checkpoint writer's one-fetch idiom
-    (``_write_npz``): a single D2H of ``table.rows`` rather than
+    guarantees the row read is dispatched against a live, fully-stepped
+    buffer.
+
+    A device view takes its rows by :func:`snapshot_copy`, on the
+    device: the table lock is held for the DISPATCH only
+    (``TpuAggregator._table_lock``'s contract: a device read dispatched
+    under it runs in front of the next step's donation) and
+    :meth:`TableView.pin` waits for the copy off every lock. A host
+    mirror (``device=False``; off the TPU, also a device view whose
+    copy cannot be dispatched) reads the rows to host memory under the
+    table lock, the checkpoint writer's one-fetch idiom
+    (``_write_npz``): a single fetch of ``table.rows`` rather than
     per-field property reads."""
     t0 = time.time()
+    rows = dev_rows = None
     with agg._fold_lock:
         with agg._table_lock:
             dedup = getattr(agg, "dedup", None)
             if dedup is not None:  # mesh-sharded: global row view
-                rows = np.asarray(dedup.rows)
+                live = dedup.rows
                 layout = dedup.layout
                 n_shards = dedup.n_shards
             else:
+                live = agg.table.rows
                 layout = ("bucket"
                           if isinstance(agg.table, buckettable.BucketTable)
                           else "open")
-                rows = np.asarray(agg.table.rows)
                 n_shards = 1
+            if device:
+                try:
+                    dev_rows = snapshot_copy(live)
+                except Exception:
+                    if _on_tpu():
+                        raise
+                    incr_counter("serve", "device_fallback")
+            if dev_rows is None:
+                rows = np.asarray(live)
         host_serials = {k: frozenset(v)
                         for k, v in agg.host_serials.items() if v}
         issuer_totals = agg.issuer_totals.copy()
@@ -405,94 +398,29 @@ def capture_view(agg, epoch: int, device: bool = False,
         host_serials=host_serials, issuer_totals=issuer_totals,
         crl_counts=crl_counts, dn_counts=dn_counts, registry=agg.registry,
         table_fill=table_fill,
-        capacity=getattr(agg, "capacity", rows.shape[0]),
-        device=device,
-        devices=devices,
+        capacity=agg.capacity,
+        dev_rows=dev_rows,
         created_wall=t0,
         verify_counts=verify_counts,
     )
 
 
-class SnapshotManager:
-    """Bounded-staleness view cache: ``view()`` returns the current
-    epoch, refreshing (at most one capture in flight — concurrent
-    requesters coalesce on the losing side of the lock) once the view
-    is older than ``max_staleness_s``. ``refresh()`` forces a new
-    epoch, e.g. after a checkpoint restore."""
-
-    def __init__(self, agg, max_staleness_s: float = 1.0,
-                 device: bool = False) -> None:
-        self._agg = agg
-        self.max_staleness_s = float(max_staleness_s)
-        self._device = bool(device)
-        self._lock = threading.Lock()
-        self._view: Optional[TableView] = None
-        self._epoch = 0
-        self._refreshing = False
-
-    @property
-    def refresh_in_flight(self) -> bool:
-        """True while a capture is running — readers that raced past
-        the staleness check are being served the previous view for the
-        capture's full duration, so staleness can transiently exceed
-        the bound; this flag (surfaced in stats()/healthz) plus the
-        ``serve.snapshot_age_s`` gauge make that window observable."""
-        return self._refreshing
-
-    def view(self) -> TableView:
-        v = self._view
-        if v is not None and v.age_s() <= self.max_staleness_s:
-            set_gauge("serve", "snapshot_age_s", value=v.age_s())
-            return v
-        with self._lock:
-            v = self._view  # a concurrent refresher may have won
-            if v is not None and v.age_s() <= self.max_staleness_s:
-                return v
-            return self._refresh_locked()
-
-    def refresh(self) -> TableView:
-        with self._lock:
-            return self._refresh_locked()
-
-    def _refresh_locked(self) -> TableView:
-        self._epoch += 1
-        self._refreshing = True
-        try:
-            with trace.span("serve.snapshot", cat="serve",
-                            epoch=self._epoch), \
-                    measure("serve", "snapshot_capture_s"):
-                v = capture_view(self._agg, self._epoch,
-                                 device=self._device)
-        finally:
-            self._refreshing = False
-        self._view = v
-        incr_counter("serve", "snapshot_refresh")
-        set_gauge("serve", "snapshot_epoch", value=float(self._epoch))
-        set_gauge("serve", "snapshot_age_s", value=v.age_s())
-        return v
-
-    def stats(self) -> dict:
-        v = self._view
-        return {
-            "snapshot_epoch": v.epoch if v else 0,
-            "snapshot_age_s": round(v.age_s(), 6) if v else None,
-            "refresh_in_flight": self._refreshing,
-        }
-
-
 class ReplicaPool:
-    """N epoch-pinned device views serving round-robin with STAGGERED
-    refresh — the query plane's answer to "serve and ingest share a
-    core" (BENCHLOG round 10).
+    """N epoch-pinned views serving round-robin with STAGGERED refresh
+    — the query plane's one manager of views, and its answer to "serve
+    and ingest share a core" (BENCHLOG round 10).
 
     Every replica is a full, individually consistent :class:`TableView`
-    pinned on device at capture time (``pin()`` runs on the refresh
-    thread, never the serving path). ``view()`` hands out replicas
-    round-robin; when the STALEST replica outlives ``max_staleness_s``
-    (or the pool is not yet full), one background capture swaps that
-    single replica to a fresh epoch — one at a time, so the D2H +
-    fold/table-lock cost of a capture is paid off the serving path and
-    at most one capture contends with ingest at any moment.
+    whose device copy is made at capture time, on the device, and
+    waited for by ``pin()`` on the refresh thread, never the serving
+    path. ``view()`` hands out replicas round-robin; when the STALEST
+    replica outlives ``max_staleness_s`` (or the pool is not yet
+    full), one background capture swaps that single replica to a fresh
+    epoch — one at a time, so the fold/table-lock cost of a capture
+    (the copy's dispatch, and the freeze of the host-lane sets) is
+    paid off the serving path, at most one capture contends with
+    ingest at any moment, and at most one table copy beyond the pool's
+    N is alive (the new replica beside the one it replaces).
 
     Mixed epochs across replicas are part of the contract, not a race:
     a batch is answered entirely by one replica, carries that replica's
@@ -501,21 +429,20 @@ class ReplicaPool:
     (the minimum live epoch) is the validity horizon the hot-serial
     cache keys against.
 
-    Placement: on a mesh-sharded aggregator each replica pins one
-    per-shard row block per device (``TableView.pin``'s shard-routed
-    mode) so no chip ever holds the full global rows; on one chip the
-    pool holds N full pinned copies. ``device=False`` degrades every
-    replica to the host-numpy mirror (and any pin failure does the
-    same per view, loudly, via ``serve.device_fallback``)."""
+    Placement: a replica's copy lives where the live table lives. On a
+    mesh-sharded aggregator it serves from one per-shard row block per
+    device, so no chip ever holds the full global rows; on one chip the
+    pool holds N full copies beside the live table. ``device=False``
+    makes every replica a host-numpy mirror (and, off the TPU, a copy
+    that fails does the same per view, loudly, via
+    ``serve.device_fallback``)."""
 
     def __init__(self, agg, n_replicas: int = 2,
-                 max_staleness_s: float = 1.0, device: bool = True,
-                 devices: Optional[list] = None) -> None:
+                 max_staleness_s: float = 1.0, device: bool = True) -> None:
         self._agg = agg
         self.n_replicas = max(1, int(n_replicas))
         self.max_staleness_s = float(max_staleness_s)
         self._device = bool(device)
-        self._devices = devices
         self._lock = threading.Lock()  # replica list + counters
         self._refresh_lock = threading.Lock()  # one capture at a time
         self._replicas: list[TableView] = []
@@ -525,14 +452,12 @@ class ReplicaPool:
 
     @property
     def refresh_in_flight(self) -> bool:
+        """True while a capture is running — readers are being served
+        the previous views for the capture's full duration, so
+        staleness can transiently exceed the bound; this flag (surfaced
+        in stats()/healthz) plus the ``serve.snapshot_age_s`` gauge
+        make that window observable."""
         return self._refreshing
-
-    def _resolve_devices(self) -> Optional[list]:
-        if self._devices is None and self._device:
-            import jax
-
-            self._devices = list(jax.devices())
-        return self._devices or None
 
     def _capture(self) -> TableView:
         with self._lock:
@@ -540,9 +465,8 @@ class ReplicaPool:
             epoch = self._epoch
         with trace.span("serve.snapshot", cat="serve", epoch=epoch), \
                 measure("serve", "replica_swap_s"):
-            v = capture_view(self._agg, epoch, device=self._device,
-                             devices=self._resolve_devices())
-            v.pin()  # transfer on THIS thread, not the serving path
+            v = capture_view(self._agg, epoch, device=self._device)
+            v.pin()  # wait on THIS thread, not the serving path
         return v
 
     def _adopt(self, v: TableView) -> None:
@@ -570,8 +494,8 @@ class ReplicaPool:
             self._refreshing = False
 
     def refresh(self) -> TableView:
-        """Force one staggered swap NOW (synchronous): capture + pin a
-        new epoch and replace the stalest replica (or fill an empty
+        """Force one staggered swap NOW (synchronous): capture a new
+        epoch, wait for it, and replace the stalest replica (or fill an empty
         pool slot). Serving continues on the other replicas meanwhile."""
         with self._refresh_lock:
             return self._refresh_holding_lock()

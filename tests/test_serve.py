@@ -34,7 +34,6 @@ from ct_mapreduce_tpu.serve.server import (
 )
 from ct_mapreduce_tpu.serve.snapshot import (
     ReplicaPool,
-    SnapshotManager,
     capture_view,
 )
 from ct_mapreduce_tpu.utils import syncerts
@@ -297,20 +296,33 @@ def test_view_sharded_aggregator(template):
 
 
 def test_snapshot_manager_staleness_refresh(template):
+    """A pool of one replica is the single-view manager: a fresh view
+    is returned as is, ``refresh()`` raises the epoch, and a stale pool
+    swaps in the background while it goes on serving."""
     agg = TpuAggregator(capacity=1 << 12, batch_size=64)
-    mgr = SnapshotManager(agg, max_staleness_s=1000.0)
-    v1 = mgr.view()
+    mgr = ReplicaPool(agg, n_replicas=1, max_staleness_s=1000.0)
+    v1 = mgr.view()  # the first capture is synchronous
     assert mgr.view() is v1  # fresh enough → same epoch
     v2 = mgr.refresh()
     assert v2.epoch == v1.epoch + 1
+    assert mgr.view() is v2
     mgr.max_staleness_s = 0.0
+    assert mgr.view().epoch >= v2.epoch  # served at once, never blocked
+    deadline = time.time() + 60
+    while mgr.stats()["snapshot_epoch"] <= v2.epoch \
+            and time.time() < deadline:
+        time.sleep(0.005)
+    mgr.max_staleness_s = 1000.0  # the swap that landed is fresh enough
     assert mgr.view().epoch > v2.epoch  # stale → refreshed
+    assert mgr.stats()["replicas"] == 1  # swapped, not grown
 
 
 # -- the concurrency acceptance test --------------------------------------
 
 
-def test_concurrent_ingest_query_consistency(template):
+@pytest.mark.parametrize("device", [True, False],
+                         ids=["device-views", "host-views"])
+def test_concurrent_ingest_query_consistency(template, device):
     """Ingest and query race for real: a writer thread feeds batches
     through a growing table (capacity starts at 1<<10 so grow-and-
     rehash fires mid-run) while reader threads query through a
@@ -329,7 +341,7 @@ def test_concurrent_ingest_query_consistency(template):
     _, eh = _identity(template)
     stale = 0.05
     oracle = MembershipOracle(agg, max_batch=256, max_delay_s=0.002,
-                              max_staleness_s=stale)
+                              max_staleness_s=stale, device=device)
     fresh_ages: list[float] = []
     epoch_walls: dict[int, float] = {}  # epoch -> capture-start wall
     acked: dict[int, float] = {}
@@ -428,7 +440,9 @@ def test_concurrent_ingest_query_consistency(template):
     assert fresh_ages, "no answers recorded"
     assert pool_stats["snapshot_epoch"] >= 3, pool_stats
     assert pool_stats["replicas"] >= 2, pool_stats
-    # And the final state is complete: every fed serial present.
+    assert pool_stats["replica_device"] == [device] * 2, pool_stats
+    # And the final state is complete: every fed serial present, by
+    # the host mirror (the reference).
     final = capture_view(agg, epoch=99)
     items = [(issuer_idx, eh, _serial_bytes(template, j))
              for j in range(n_batches * batch)]
@@ -613,7 +627,9 @@ def test_serve_batch_spans_recorded(template):
 # -- replica pool (round 12) ----------------------------------------------
 
 
-def test_replica_pool_mixed_epoch_parity_fuzz(template):
+@pytest.mark.parametrize("device", [True, False],
+                         ids=["device-views", "host-views"])
+def test_replica_pool_mixed_epoch_parity_fuzz(template, device):
     """N replicas at MIXED epochs through table growth must agree with
     the serial truth set: on every replica, every serial acked before
     that replica's capture reads known, and ghosts read absent at
@@ -623,10 +639,11 @@ def test_replica_pool_mixed_epoch_parity_fuzz(template):
     issuer_idx = agg.registry.get_or_assign(template.issuer_der)
     _, eh = _identity(template)
     pool = ReplicaPool(agg, n_replicas=3, max_staleness_s=1e9,
-                       device=True)
+                       device=device)
     rng = np.random.default_rng(7)
     acked = 0
     truth_at_capture: dict[int, int] = {}
+    mirrors = {}  # epoch -> a host mirror of the same instant
     for _stage in range(6):  # 576 lanes through a 1<<10 table ⇒ grows
         agg.ingest([
             (syncerts.stamp_serial(template, acked + i),
@@ -636,6 +653,7 @@ def test_replica_pool_mixed_epoch_parity_fuzz(template):
         acked += 96
         v = pool.refresh()  # staggered: swaps exactly ONE replica
         truth_at_capture[v.epoch] = acked
+        mirrors[v.epoch] = capture_view(agg, epoch=v.epoch)
     assert agg.capacity > 1 << 10, "table never grew"
     reps = list(pool._replicas)
     assert len(reps) == 3
@@ -647,6 +665,8 @@ def test_replica_pool_mixed_epoch_parity_fuzz(template):
         items = [(issuer_idx, eh, _serial_bytes(template, j))
                  for j in pick + ghosts]
         got = r.lookup(items)
+        assert r._device is device
+        assert np.array_equal(got, mirrors[r.epoch].lookup(items))
         for k, j in enumerate(pick):
             if j < n_known:
                 assert got[k], (
@@ -681,8 +701,9 @@ def test_replica_pool_shard_routed_block_pinning(template):
     for v in pool._replicas:
         assert v.n_shards == mesh.devices.size
         assert v._dev_blocks is not None, "replica pinned no blocks"
-        assert v._dev_rows is None, "replica pinned the full global rows"
-        block = v.rows.shape[0] // v.n_shards
+        assert v.rows is None, "a device replica made a host array"
+        block = v.n_rows // v.n_shards
+        assert len(v._dev_blocks) == v.n_shards
         for s, state in enumerate(v._dev_blocks):
             assert state.rows.shape[0] == block
             assert list(state.rows.devices()) == [devs[s % len(devs)]]
@@ -695,9 +716,20 @@ def test_replica_pool_shard_routed_block_pinning(template):
         assert np.array_equal(got, host)
 
 
-def test_view_device_fallback_to_host(template, monkeypatch):
-    """A view that cannot pin a device copy degrades to the host
-    mirror (serve.device_fallback) instead of failing the batch."""
+def _fail(msg):
+    def boom(*_a, **_k):
+        raise RuntimeError(msg)
+    return boom
+
+
+@pytest.mark.parametrize("where", ["copy", "probe"])
+def test_view_device_fallback_to_host(template, monkeypatch, where):
+    """Off the TPU, a view whose device copy cannot be made (the
+    failure is injected at ``snapshot_copy``), or whose copy stops
+    answering (at the membership kernel), becomes a host mirror
+    (serve.device_fallback) instead of failing the batch."""
+    from ct_mapreduce_tpu.ops import buckettable, hashtable
+    from ct_mapreduce_tpu.serve import snapshot as snapmod
     from ct_mapreduce_tpu.telemetry import metrics as tmetrics
 
     agg = TpuAggregator(capacity=1 << 12, batch_size=64)
@@ -709,14 +741,20 @@ def test_view_device_fallback_to_host(template, monkeypatch):
     prev = tmetrics.get_sink()
     tmetrics.set_sink(sink)
     try:
-        view = capture_view(agg, epoch=1, device=True)
-        import jax.numpy as jnp
-        monkeypatch.setattr(jnp, "asarray", lambda *a, **k: (
-            (_ for _ in ()).throw(RuntimeError("no device"))))
+        if where == "copy":
+            monkeypatch.setattr(snapmod, "snapshot_copy",
+                                _fail("no device"))
+            view = capture_view(agg, epoch=1, device=True)
+        else:
+            view = capture_view(agg, epoch=1, device=True)
+            assert view._device is True and view.rows is None
+            for mod in (hashtable, buckettable):  # whichever layout
+                monkeypatch.setattr(mod, "contains", _fail("torn down"))
         items = [(idx, eh, _serial_bytes(template, j)) for j in range(12)]
         got = view.lookup(items)
         assert got[:10].all() and not got[10:].any()
         assert view._device is False  # latched to the host path
+        assert view.rows is not None and view._dev_rows is None
         counters = sink.snapshot()["counters"]
         assert counters.get("serve.device_fallback", 0) >= 1
         # Subsequent lookups answer from the host mirror directly.
@@ -726,10 +764,11 @@ def test_view_device_fallback_to_host(template, monkeypatch):
 
 
 def test_view_device_pin_failure_is_an_error_on_tpu(template, monkeypatch):
-    """On a TPU backend a replica that cannot be pinned raises instead
-    of sliding onto the host mirror (serve.device_fallback stays 0)."""
+    """On a TPU backend a replica whose copy cannot land raises instead
+    of sliding onto the host mirror (serve.device_fallback stays 0),
+    and the pool's next capture takes the device again."""
     import jax
-    import jax.numpy as jnp
+    from ct_mapreduce_tpu.serve import snapshot as snapmod
     from ct_mapreduce_tpu.telemetry import metrics as tmetrics
 
     agg = TpuAggregator(capacity=1 << 12, batch_size=64)
@@ -741,17 +780,85 @@ def test_view_device_pin_failure_is_an_error_on_tpu(template, monkeypatch):
     prev = tmetrics.get_sink()
     tmetrics.set_sink(sink)
     try:
-        view = capture_view(agg, epoch=1, device=True)
+        pool = ReplicaPool(agg, n_replicas=1, device=True)
+        real_copy = snapmod.snapshot_copy
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        monkeypatch.setattr(jnp, "asarray", lambda *a, **k: (
-            (_ for _ in ()).throw(RuntimeError("RESOURCE_EXHAUSTED"))))
+        monkeypatch.setattr(snapmod, "snapshot_copy",
+                            _fail("RESOURCE_EXHAUSTED"))
         with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
-            view.lookup([(idx, eh, _serial_bytes(template, 0))])
+            pool.view()
+        assert pool.stats()["replicas"] == 0  # no host mirror adopted
+        assert pool.refresh_in_flight is False
+        monkeypatch.setattr(snapmod, "snapshot_copy", real_copy)
+        view = pool.view()
         assert view._device is True  # not latched to the host mirror
+        assert view.lookup([(idx, eh, _serial_bytes(template, 0))])[0]
         counters = sink.snapshot()["counters"]
         assert counters.get("serve.device_fallback", 0) == 0
     finally:
         tmetrics.set_sink(prev)
+
+
+def test_device_view_holds_no_host_rows(template):
+    """A device view of an unsharded aggregator is a copy of the live
+    table ON the device: no host array of the table exists after the
+    capture, nor after serving (the rows never cross the host link)."""
+    agg = TpuAggregator(capacity=1 << 12, batch_size=64)
+    agg.ingest([(syncerts.stamp_serial(template, j), template.issuer_der)
+                for j in range(20)])
+    issuer_id, eh = _identity(template)
+    idx = agg.registry.index_of_issuer_id(issuer_id)
+    items = [(idx, eh, _serial_bytes(template, j)) for j in range(30)]
+    pool = ReplicaPool(agg, n_replicas=2, max_staleness_s=1e9,
+                       device=True).warm()
+    for v in list(pool._replicas) + [capture_view(agg, 9, device=True)]:
+        assert v._device is True and v.rows is None
+        assert v._dev_rows is not agg.table.rows  # a copy, not the table
+        assert v._dev_rows.shape == agg.table.rows.shape
+        assert v.capacity == agg.capacity and v.n_rows > 0
+        got = v.lookup(items)
+        assert got[:20].all() and not got[20:].any()
+        assert v._device is True and v.rows is None  # after serving too
+    # The host mirror is what device=False selects, and only that.
+    host = capture_view(agg, epoch=10, device=False)
+    assert isinstance(host.rows, np.ndarray) and host._dev_rows is None
+    assert np.array_equal(host.rows, np.asarray(pool.view()._dev_rows))
+
+
+@pytest.mark.parametrize("device", [True, False],
+                         ids=["device-view", "host-mirror"])
+def test_view_outlives_steps_and_growth(template, device):
+    """A view answers as of its capture however far the live table
+    moves on: further batches step (and, on an accelerator, donate)
+    the buffer the copy was taken from, and grow-and-rehash replaces
+    it. A serial fed after the capture reads unknown there and known
+    in the next epoch."""
+    agg = TpuAggregator(capacity=1 << 10, batch_size=64,
+                        max_capacity=1 << 14, grow_at=0.55)
+    issuer_idx = agg.registry.get_or_assign(template.issuer_der)
+    _, eh = _identity(template)
+
+    def feed(lo, hi):
+        for j0 in range(lo, hi, 64):
+            agg.ingest([(syncerts.stamp_serial(template, j),
+                         template.issuer_der) for j in range(j0, j0 + 64)])
+
+    feed(0, 128)
+    view = capture_view(agg, epoch=1, device=device).pin()
+    cap0 = agg.capacity
+    feed(128, 896)  # 896 lanes > 0.55 x 1024 ⇒ grow fires
+    assert agg.capacity > cap0, "table never grew"
+    items = [(issuer_idx, eh, _serial_bytes(template, j))
+             for j in range(896)]
+    got = view.lookup(items)
+    assert view._device is device
+    assert got[:128].all(), "acked before the capture, unknown in it"
+    assert not got[128:].any(), "fed after the capture, known in it"
+    assert view.capacity == cap0
+    nxt = capture_view(agg, epoch=2, device=device).pin()
+    assert nxt.lookup(items).all()
+    assert not nxt.lookup(
+        [(issuer_idx, eh, _serial_bytes(template, 10**6))])[0]
 
 
 # -- hot-serial cache ------------------------------------------------------
@@ -885,27 +992,35 @@ def test_refresh_in_flight_and_age_surfaced(template, monkeypatch):
     prev = tmetrics.get_sink()
     tmetrics.set_sink(sink)
     try:
-        mgr = SnapshotManager(agg, max_staleness_s=1000.0)
+        mgr = ReplicaPool(agg, n_replicas=1, max_staleness_s=1000.0)
         assert mgr.refresh_in_flight is False
         seen = {}
         orig = snapmod.capture_view
 
-        def spying_capture(a, epoch, device=False, devices=None):
+        def spying_capture(a, epoch, device=False):
             seen["in_flight"] = mgr.refresh_in_flight
-            return orig(a, epoch, device=device, devices=devices)
+            return orig(a, epoch, device=device)
 
         monkeypatch.setattr(snapmod, "capture_view", spying_capture)
         mgr.refresh()
-        monkeypatch.setattr(snapmod, "capture_view", orig)
-        assert seen["in_flight"] is True  # flag held across the capture
+        assert seen.pop("in_flight") is True  # flag held across the capture
         assert mgr.refresh_in_flight is False
+        # ... and across a background swap of a stale pool.
+        mgr.max_staleness_s = 0.0
+        mgr.view()
+        deadline = time.time() + 60
+        while mgr.stats()["snapshot_epoch"] < 2 and time.time() < deadline:
+            time.sleep(0.005)
+        mgr.max_staleness_s = 1000.0
+        monkeypatch.setattr(snapmod, "capture_view", orig)
+        assert seen["in_flight"] is True
         st = mgr.stats()
-        assert st["refresh_in_flight"] is False
-        assert st["snapshot_epoch"] == 1 and st["snapshot_age_s"] >= 0
+        assert st["snapshot_epoch"] == 2 and st["snapshot_age_s"] >= 0
+        assert st["replicas"] == 1
         mgr.view()
         gauges = sink.snapshot()["gauges"]
         assert "serve.snapshot_age_s" in gauges
-        # The pool surfaces the same observability per replica set.
+        # A fuller pool surfaces the same observability per replica.
         pool = ReplicaPool(agg, n_replicas=2, max_staleness_s=1e9,
                            device=False).warm()
         pst = pool.stats()
