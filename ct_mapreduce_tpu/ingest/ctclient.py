@@ -31,6 +31,11 @@ import urllib.request
 from dataclasses import dataclass
 from typing import Callable, Optional
 
+from ct_mapreduce_tpu.native.leafpack import (
+    EntryPage,
+    page_of_strings,
+    scan_entries,
+)
 from ct_mapreduce_tpu.telemetry import trace
 from ct_mapreduce_tpu.telemetry.metrics import incr_counter, measure
 from ct_mapreduce_tpu.utils.backoff import JitteredBackoff
@@ -78,6 +83,30 @@ class RawEntry:
     index: int
     leaf_input: str  # base64, as served
     extra_data: str
+
+
+def _parse_entries(body, _asked: int, sp) -> list[dict]:
+    """A get-entries body as the JSON parser's list of entry objects."""
+    entries = json.loads(body).get("entries", [])
+    sp.set(n=len(entries))
+    return entries
+
+
+def _parse_page(body, asked: int, sp) -> EntryPage:
+    """A get-entries body as an :class:`EntryPage`: by the native scan
+    where it takes the bytes, else by ``json.loads``."""
+    if not isinstance(body, bytes):  # an injected transport's str
+        body = body.encode() if isinstance(body, str) else bytes(body)
+    page = scan_entries(body, asked)
+    scanned = page is not None
+    if not scanned:
+        entries = json.loads(body).get("entries", [])
+        page = page_of_strings(
+            [e["leaf_input"] for e in entries],
+            [e.get("extra_data", "") for e in entries])
+    incr_counter("ingest", "page", "scanned" if scanned else "json_fallback")
+    sp.set(n=len(page), scanned=int(scanned))
+    return page
 
 
 class CTClientError(RuntimeError):
@@ -163,31 +192,37 @@ class CTLogClient:
             tree_head_signature=obj.get("tree_head_signature", ""),
         )
 
-    def get_raw_entries(self, start: int, end: int) -> list[RawEntry]:
-        """Entries ``[start, end]`` inclusive, like ct-go's
-        GetRawEntries; the server may truncate the range. The first
-        truncated response clamps this client's window to the page
-        size the server demonstrated, so every later request asks for
-        exactly what the log serves instead of re-discovering the cap
-        one oversized range at a time."""
-        if end < start:
-            return []
+    def _get_entries(self, start: int, end: int, parse):
+        """The response for ``[start, end]``, cut to this client's
+        window, as ``parse(body, asked, span)`` reads it (anything with
+        a length: the entry count). The ``getRawEntries`` timer keeps
+        what it always held (socket wait, body read and parse); the two
+        spans divide it. The first truncated response clamps the window
+        to the page size the server demonstrated, so every later request
+        asks for exactly what the log serves instead of re-discovering
+        the cap one oversized range at a time."""
         end = min(end, start + self.page_size - 1)
-        # The getRawEntries timer keeps what it always held (socket
-        # wait, body read and JSON parse); the two spans divide it.
+        asked = end - start + 1
         with measure("LogWorker", self.short_url, "getRawEntries"):
             with trace.span("fetch.get_entries", cat="fetch") as sp:
                 body, attempts = self._get_body(
                     f"get-entries?start={start}&end={end}")
                 sp.set(bytes=len(body), attempts=attempts)
             with trace.span("fetch.parse_json", cat="fetch") as sp:
-                entries = json.loads(body).get("entries", [])
-                sp.set(n=len(entries))
-        if 0 < len(entries) < end - start + 1:
+                entries = parse(body, asked, sp)
+        if 0 < len(entries) < asked:
             # Short page on a full-window ask: adopt the server's size.
-            if len(entries) < self.page_size:
-                self.page_size = len(entries)
-                incr_counter("ingest", "window_clamp")
+            self.page_size = len(entries)
+            incr_counter("ingest", "window_clamp")
+        return entries
+
+    def get_raw_entries(self, start: int, end: int) -> list[RawEntry]:
+        """Entries ``[start, end]`` inclusive, like ct-go's
+        GetRawEntries, one object an entry; the server may truncate the
+        range (see :meth:`_get_entries`)."""
+        if end < start:
+            return []
+        entries = self._get_entries(start, end, _parse_entries)
         with trace.span("fetch.parse_json", cat="fetch", n=len(entries)):
             return [
                 RawEntry(
@@ -197,6 +232,21 @@ class CTLogClient:
                 )
                 for i, e in enumerate(entries)
             ]
+
+    def get_entry_page(self, start: int, end: int) -> EntryPage:
+        """The same request for the raw-batch path: the response stays
+        the bytes the transport returned and a native scan, GIL
+        released, finds where each entry's two base64 values lie in
+        them; entry ``start + i`` is the page's i-th. Whether a page is
+        scanned is the scanner's answer about these bytes (an escape, a
+        byte outside ASCII, an odd member, a library without it: no),
+        and a page it does not take is parsed with ``json.loads`` as
+        :meth:`get_raw_entries` does, raising what that raises, and laid
+        out in the same form. ``ingest.page.scanned`` and
+        ``ingest.page.json_fallback`` count which, a page each."""
+        if end < start:
+            return page_of_strings([], [])
+        return self._get_entries(start, end, _parse_page)
 
     def get_entry_and_proof(self, index: int, tree_size: int) -> dict:
         """ct-getcert's fetch path (get-entry-and-proof)."""

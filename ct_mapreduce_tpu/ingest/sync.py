@@ -52,6 +52,7 @@ from ct_mapreduce_tpu.ingest.leaf import (
     leaf_timestamp_ms as decode_leaf_timestamp,
 )
 from ct_mapreduce_tpu.config import profile as platprofile
+from ct_mapreduce_tpu.native.leafpack import EntryPage, StrPage
 from ct_mapreduce_tpu.telemetry import metrics, trace
 
 ENTRY_QUEUE_CAPACITY = 16384  # ct-fetch.go:132
@@ -319,11 +320,9 @@ class AggregatorSink:
         dispatched natively in flush-size chunks."""
         chunk: Optional[_RawChunk] = None
         with trace.span("sink.accumulate", cat="sink", n=len(raw)) as sp:
-            pairs = list(zip(raw.leaf_inputs, raw.extra_datas))
             with self._lock:
-                self._pending_raw.extend(pairs)
                 self._pending_raw.add_page(raw)
-                self.entries_in += len(pairs)
+                self.entries_in += len(raw)
                 if len(self._pending_raw) >= self.flush_size:
                     chunk = self._cut_raw()
                     sp.set(batch=chunk.batch, pages=chunk.pages)
@@ -338,19 +337,19 @@ class AggregatorSink:
         chunk.batch = self._batch_seq
         return chunk
 
-    def _dispatch_raw(self, pairs: "_RawChunk") -> None:
+    def _dispatch_raw(self, chunk: "_RawChunk") -> None:
         if self._overlap is not None:
             # Overlapped mode: the chunk enters the three-stage
             # scheduler; decode happens on its pool, submission on its
             # ordered submit thread, completion on its drain consumer.
-            self._overlap.submit_chunk(pairs)
+            self._overlap.submit_chunk(chunk)
             return
-        with trace.span("ingest.decode", cat="ingest", entries=len(pairs),
-                        batch=pairs.batch):
-            prep = self._prepare_chunk(pairs)
+        with trace.span("ingest.decode", cat="ingest", entries=len(chunk),
+                        batch=chunk.batch):
+            prep = self._prepare_chunk(chunk)
         t_lock = time.monotonic()
         with trace.span("ingest.submit_locked", cat="ingest",
-                        batch=pairs.batch), self._dispatch_lock:
+                        batch=chunk.batch), self._dispatch_lock:
             # Lock wait sampled apart from the storeCertificate
             # envelope (see ingest/overlap.py's submit loop): multiple
             # store workers contend here, and the wait is not submit
@@ -374,14 +373,13 @@ class AggregatorSink:
                              if self.chunks_per_dispatch > 1
                              else self.device_queue_depth)
 
-    def _prepare_chunk(self, pairs: list[tuple[str, str]]) -> "_PreparedChunk":
+    def _prepare_chunk(self, chunk: "_RawChunk") -> "_PreparedChunk":
         """Stage 1 — decode + pack + H2D submit, NO aggregator-state
         mutation beyond the (thread-safe) issuer registry: safe to run
         on any thread, concurrently with device work and drains."""
         from ct_mapreduce_tpu.native import leafpack
 
-        lis = [p[0] for p in pairs]
-        eds = [p[1] for p in pairs]
+        pages = chunk.b64_pages()
         # Row-width bucketing, now BEFORE the decode: the decoder's
         # allocation+memset scale with the pad (measured +47% decode
         # time at 2048 vs 1024 for 2^20-entry batches), and base64
@@ -395,32 +393,32 @@ class AggregatorSink:
         narrow = self.PAD_LEN // 2
         pad = self.PAD_LEN
         if narrow >= 512:
-            max_li_raw = max((len(s) for s in lis), default=0) * 3 // 4
+            max_li_raw = chunk.max_leaf_input_len() * 3 // 4
             if max_li_raw + 64 <= narrow:
                 pad = narrow
         with metrics.measure("ct-fetch", "decodeBatch"):
-            dec = leafpack.decode_raw_batch(
-                lis, eds, pad, workers=self.decode_workers,
+            dec = leafpack.decode_raw_pages(
+                pages, pad, workers=self.decode_workers,
                 threads=self.decode_threads,
             )
             if (pad < self.PAD_LEN
                     and bool((dec.status == leafpack.TOO_LONG).any())):
                 pad = self.PAD_LEN
-                dec = leafpack.decode_raw_batch(
-                    lis, eds, pad, workers=self.decode_workers,
+                dec = leafpack.decode_raw_pages(
+                    pages, pad, workers=self.decode_workers,
                     threads=self.decode_threads,
                 )
         # Host-feed observability: the resolved intra-chunk thread
         # count; the chunk's decode cost is ct-fetch.decodeBatch.
-        if len(lis):
+        if len(chunk):
             metrics.set_gauge(
                 "ingest", "decode_threads",
                 value=float(leafpack.resolve_threads(
-                    len(lis), self.decode_threads or self.decode_workers)))
+                    len(chunk), self.decode_threads or self.decode_workers)))
         with trace.span("decode.pack", cat="decode"):
-            return self._pack_chunk(pairs, lis, eds, dec)
+            return self._pack_chunk(chunk, dec)
 
-    def _pack_chunk(self, pairs, lis, eds, dec) -> "_PreparedChunk":
+    def _pack_chunk(self, chunk: "_RawChunk", dec) -> "_PreparedChunk":
         """The Python and numpy half of stage 1, after the decoder has
         returned: narrow view, issuer registry, status accounting, the
         optional extraction passes and the H2D enqueue."""
@@ -436,7 +434,7 @@ class AggregatorSink:
                 and dec.length.max(initial=0) <= narrow):
             data = data[:, :narrow]
 
-        n = len(pairs)
+        n = len(chunk)
         issuer_idx = np.zeros((n,), np.int32)
         oversized: list[tuple[bytes, bytes]] = []
         # Every DecodedBatch producer computes issuer groups
@@ -490,9 +488,9 @@ class AggregatorSink:
             try:
                 import base64
 
+                li, ed = chunk.entry_b64(int(i))
                 e = decode_entry(
-                    int(i), base64.b64decode(lis[i]),
-                    base64.b64decode(eds[i] or "")
+                    int(i), base64.b64decode(li), base64.b64decode(ed or "")
                 )
             except LeafDecodeError:
                 metrics.incr_counter("ct-fetch", "parseLeafError")
@@ -584,7 +582,7 @@ class AggregatorSink:
             oversized=oversized, sidecar=sidecar,
             walker_fallback=walker_fallback,
             scts=scts, verify_eligible=verify_eligible,
-            batch=getattr(pairs, "batch", 0),
+            batch=chunk.batch,
         )
 
     def _submit_verify(self, prep: "_PreparedChunk") -> None:
@@ -951,19 +949,26 @@ class _QueueItem:
     log_url: str
 
 
-class _RawChunk(list):
-    """The ``(leaf_input, extra_data)`` pairs accumulating toward one
-    device batch, with the pages they came from; the sink's cut gives
-    the chunk its number, the identity its spans share."""
+class _RawChunk:
+    """The get-entries responses accumulating toward one device batch,
+    kept as they came (a response is never taken apart into entries);
+    the sink's cut gives the chunk its number, the identity its spans
+    share. ``len()`` is the entry count."""
 
     batch = 0
 
     def __init__(self) -> None:
-        super().__init__()
+        self.raws: list[RawBatch] = []
+        self.entries = 0
         # [log, first index, last index], the log as fetch.page names it
         self.pages: list[list] = []
 
+    def __len__(self) -> int:
+        return self.entries
+
     def add_page(self, raw: "RawBatch") -> None:
+        self.raws.append(raw)
+        self.entries += len(raw)
         log = short_url(raw.log_url)
         first, last = raw.start_index, raw.start_index + len(raw) - 1
         if (self.pages and self.pages[-1][0] == log
@@ -972,20 +977,63 @@ class _RawChunk(list):
         else:
             self.pages.append([log, first, last])
 
+    def b64_pages(self) -> list:
+        """What ``leafpack.decode_raw_pages`` takes, a page a response."""
+        return [raw.page for raw in self.raws]
 
-@dataclass
+    def max_leaf_input_len(self) -> int:
+        """The longest base64 ``leaf_input`` of the chunk."""
+        return max((raw.page.max_leaf_input_len() for raw in self.raws),
+                   default=0)
+
+    def entry_b64(self, i: int) -> tuple:
+        """Entry ``i``'s base64 pair, for the few lanes that go back
+        through the per-entry decoder."""
+        for raw in self.raws:
+            if i < len(raw):
+                return raw.page.leaf_input(i), raw.page.extra_data(i)
+            i -= len(raw)
+        raise IndexError(i)
+
+
 class RawBatch:
     """One get-entries response, undecoded — the raw-batch fast path
     hands whole responses to the sink, which decodes them natively
-    (ct_mapreduce_tpu.native.leafpack) with no per-entry Python."""
+    (ct_mapreduce_tpu.native.leafpack) with no per-entry Python.
 
-    leaf_inputs: list[str]
-    extra_datas: list[str]
-    start_index: int
-    log_url: str
+    ``page`` is the response: two lists of base64 strings
+    (``leafpack.StrPage``: tests, the bench, the audit driver and the
+    tuner build it so) or its bytes with where each value lies in them
+    (``leafpack.EntryPage``: what the downloader enqueues). Reading
+    ``leaf_inputs`` or ``extra_datas`` of the second kind cuts the
+    strings out of the body and makes it the first kind from then on,
+    so code that edits the lists finds its edit decoded."""
+
+    def __init__(self, leaf_inputs: Optional[list] = None,
+                 extra_datas: Optional[list] = None, start_index: int = 0,
+                 log_url: str = "", page: Optional[EntryPage] = None):
+        self.page = (page if page is not None
+                     else StrPage(leaf_inputs, extra_datas))
+        self.start_index = start_index
+        self.log_url = log_url
 
     def __len__(self) -> int:
-        return len(self.leaf_inputs)
+        return len(self.page)
+
+    def _lists(self) -> StrPage:
+        if isinstance(self.page, EntryPage):
+            self.page = StrPage(*(
+                [b.decode("utf-8", "surrogatepass") for b in col]
+                for col in self.page.items()))
+        return self.page
+
+    @property
+    def leaf_inputs(self) -> list:
+        return self._lists().leaf_inputs
+
+    @property
+    def extra_datas(self) -> list:
+        return self._lists().extra_datas
 
 
 class LogWorker:
@@ -1200,18 +1248,13 @@ class LogWorker:
         undecoded; returns the entries enqueued (0: the log gave none,
         or the run stopped while the queue was full — the cursor then
         stays put, the page never reached a worker)."""
-        batch = self.client.get_raw_entries(
+        page = self.client.get_entry_page(
             index, min(index + BATCH_SIZE - 1, self.end_pos)
         )
-        if not batch:
+        if not len(page):
             return 0
-        with trace.span("fetch.parse_json", cat="fetch", n=len(batch)):
-            item = RawBatch(
-                leaf_inputs=[r.leaf_input for r in batch],
-                extra_datas=[r.extra_data for r in batch],
-                start_index=batch[0].index,
-                log_url=self.client.log_url,
-            )
+        item = RawBatch(start_index=index, log_url=self.client.log_url,
+                        page=page)
         # Back-pressure: the time the downloader stands still because
         # the store side has not taken what it already fetched.
         submitted = False
@@ -1227,12 +1270,12 @@ class LogWorker:
                     continue
         if not submitted:
             return 0
-        self.position = batch[-1].index + 1
+        self.position = index + len(page)
         # Last DECODABLE timestamp — a garbage final entry must
         # not lose the good entries' timestamps (per-entry-path
         # parity: it updates per decoded entry).
-        for raw in reversed(batch):
-            ts = decode_leaf_timestamp(raw.leaf_input)
+        for i in reversed(range(len(page))):
+            ts = decode_leaf_timestamp(page.leaf_input(i))
             if ts is not None:
                 self.last_entry_time = datetime.fromtimestamp(
                     ts / 1000.0, tz=timezone.utc
@@ -1241,7 +1284,7 @@ class LogWorker:
         self._publish_lag()
         if progress is not None:
             progress(self.client.short_url, self.position, self.end_pos)
-        return len(batch)
+        return len(page)
 
 
 class _AccountingQueue:

@@ -216,6 +216,21 @@ def load() -> Optional[ctypes.CDLL]:
             lib.has_strs = ctypes.sizeof(ctypes.c_void_p) == 8
         except (AttributeError, OSError):
             lib.has_strs = False
+        # get-entries page scan (PR 30): offsets of the two base64
+        # columns inside a response body; no Python object touched, so
+        # it stays on this GIL-releasing handle. Same stale-library
+        # contract: callers check `has_scan` and parse the page in
+        # Python. The offsets are only of use to a decoder that reads
+        # pointer columns, hence has_strs.
+        try:
+            lib.ctmr_scan_entries.restype = ctypes.c_int64
+            lib.ctmr_scan_entries.argtypes = [
+                ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,
+                i64p, i64p, i64p, i64p,
+            ]
+            lib.has_scan = lib.has_strs
+        except AttributeError:
+            lib.has_scan = False
         _LIB = lib
         return _LIB
 

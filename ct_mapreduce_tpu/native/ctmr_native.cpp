@@ -1477,3 +1477,148 @@ void ctmr_extract_scts_v2_mt(
 }
 
 }  // extern "C"
+
+// ---------------------------------------------------------------------
+// get-entries page scan (PR 30): where the two base64 columns lie in a
+// response body, so that the raw-batch path hands the decoder above
+// pointers into the bytes the socket returned and builds no Python
+// object per entry. The scan answers for the bytes in front of it: it
+// takes exactly the documents for which offsets into the body ARE what
+// a JSON parser would return (one object, `entries` an array of objects
+// whose members are strings, every string free of anything a parser
+// would rewrite or refuse), and says -1 for all else, malformed or
+// merely unusual; the caller then parses the page with json.loads.
+
+namespace jsonscan {
+
+struct Cur {
+  const char* p;
+  const char* end;
+  void ws() {
+    while (p < end && (*p == ' ' || *p == '\n' || *p == '\r' || *p == '\t'))
+      ++p;
+  }
+  bool eat(char ch) {
+    if (p < end && *p == ch) { ++p; return true; }
+    return false;
+  }
+};
+
+// The string whose opening quote the cursor stands on, as the span
+// [*s, *s + *n) of the body: true only when those bytes are the value
+// itself. A backslash (an escape to resolve, or a quote that is not
+// the closing one), a control byte (a parser refuses it) or a byte
+// outside ASCII makes it false. The closing quote is found by memchr
+// and the span checked in one pass of byte arithmetic the compiler
+// vectorises: a page is 2.6 KB of base64 an entry, and a loop that
+// branches on every byte would cost more than parsing the page did.
+bool plain_string(Cur& c, const char** s, int64_t* n) {
+  if (!c.eat('"')) return false;
+  const char* b = c.p;
+  const char* q = (const char*)std::memchr(b, '"', (size_t)(c.end - b));
+  if (q == nullptr) return false;
+  uint8_t bad = 0;
+  for (const char* x = b; x < q; ++x) {
+    uint8_t ch = (uint8_t)*x;
+    // ch < 0x20 or ch >= 0x80, or the backslash
+    bad |= (uint8_t)((uint8_t)(ch - 0x20) >= 0x60) | (uint8_t)(ch == '\\');
+  }
+  if (bad) return false;
+  *s = b;
+  *n = q - b;
+  c.p = q + 1;
+  return true;
+}
+
+inline bool is_key(const char* s, int64_t n, const char* name, int64_t len) {
+  return n == len && std::memcmp(s, name, (size_t)len) == 0;
+}
+
+}  // namespace jsonscan
+
+extern "C" {
+
+// Entry count of the get-entries response `body[0, len)`, with entry
+// i's leaf_input at body[li_off[i], li_off[i] + li_len[i]) and its
+// extra_data likewise (absent: length 0 at offset 0); -1 when the scan
+// does not take the document (see above) or it holds more than `cap`
+// entries. Touches no Python object: loaded on the GIL-releasing
+// handle.
+int64_t ctmr_scan_entries(
+    const char* body, int64_t len, int64_t cap,
+    int64_t* li_off, int64_t* li_len, int64_t* ed_off, int64_t* ed_len) {
+  using jsonscan::is_key;
+  jsonscan::Cur c{body, body + len};
+  const char* s = nullptr;
+  int64_t sn = 0;
+  int64_t n = 0;
+  bool seen_entries = false;
+  c.ws();
+  if (!c.eat('{')) return -1;
+  c.ws();
+  if (c.eat('}')) return -1;  // no `entries`: the parser's to answer
+  for (;;) {
+    if (!jsonscan::plain_string(c, &s, &sn)) return -1;
+    c.ws();
+    if (!c.eat(':')) return -1;
+    c.ws();
+    if (is_key(s, sn, "entries", 7)) {
+      if (seen_entries) return -1;  // a parser keeps the last one
+      seen_entries = true;
+      if (!c.eat('[')) return -1;
+      c.ws();
+      if (!c.eat(']')) {
+        for (;;) {
+          if (!c.eat('{')) return -1;
+          if (n >= cap) return -1;
+          bool has_li = false, has_ed = false;
+          ed_off[n] = 0;
+          ed_len[n] = 0;
+          c.ws();
+          if (c.eat('}')) return -1;  // no leaf_input
+          for (;;) {
+            const char* k = nullptr;
+            int64_t kn = 0;
+            if (!jsonscan::plain_string(c, &k, &kn)) return -1;
+            c.ws();
+            if (!c.eat(':')) return -1;
+            c.ws();
+            if (!jsonscan::plain_string(c, &s, &sn)) return -1;
+            if (is_key(k, kn, "leaf_input", 10)) {
+              if (has_li) return -1;
+              has_li = true;
+              li_off[n] = s - body;
+              li_len[n] = sn;
+            } else if (is_key(k, kn, "extra_data", 10)) {
+              if (has_ed) return -1;
+              has_ed = true;
+              ed_off[n] = s - body;
+              ed_len[n] = sn;
+            }
+            c.ws();
+            if (c.eat(',')) { c.ws(); continue; }
+            if (c.eat('}')) break;
+            return -1;
+          }
+          if (!has_li) return -1;
+          ++n;
+          c.ws();
+          if (c.eat(',')) { c.ws(); continue; }
+          if (c.eat(']')) break;
+          return -1;
+        }
+      }
+    } else if (!jsonscan::plain_string(c, &s, &sn)) {
+      return -1;  // a member that is no string: the parser's
+    }
+    c.ws();
+    if (c.eat(',')) { c.ws(); continue; }
+    if (c.eat('}')) break;
+    return -1;
+  }
+  c.ws();
+  if (c.p != c.end || !seen_entries) return -1;
+  return n;
+}
+
+}  // extern "C"

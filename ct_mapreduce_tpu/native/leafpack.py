@@ -1,8 +1,11 @@
 """Batch leaf decoding + packing on top of the native library.
 
-``decode_raw_batch`` takes one get-entries response worth of base64
-strings and produces the packed device arrays plus per-entry issuer
-DER — the whole-host fast path between the HTTP client and the device
+``decode_raw_pages`` takes a chunk of get-entries responses, each kept
+as the bytes the transport returned (:class:`EntryPage`, found by
+:func:`scan_entries`) or given as two lists of base64 strings
+(:class:`StrPage`; ``decode_raw_batch`` is the one-page form), and
+produces the packed device arrays plus per-entry issuer DER — the
+whole-host fast path between the HTTP client and the device
 pipeline. Falls back to the pure-Python leaf codec
 (:mod:`ct_mapreduce_tpu.ingest.leaf`) entry by entry when the native
 library is unavailable, with identical results (the conformance tests
@@ -282,40 +285,196 @@ def _gather_strs(lib, strings: Sequence[str]) -> Optional[tuple]:
     return None if total < 0 else (ptr, off, items)
 
 
+@dataclass
+class EntryPage:
+    """One get-entries response kept as bytes. Entry i's base64
+    ``leaf_input`` is ``body[li_off[i] : li_off[i] + li_len[i]]`` and
+    its ``extra_data`` likewise (length 0: absent or empty). ``body`` is
+    what the transport returned when the native scan took the page,
+    else the page's strings laid end to end (:func:`page_of_strings`);
+    either way nothing exists per entry but four integers."""
+
+    body: bytes
+    li_off: np.ndarray  # int64[n]
+    li_len: np.ndarray  # int64[n]
+    ed_off: np.ndarray  # int64[n]
+    ed_len: np.ndarray  # int64[n]
+
+    def __len__(self) -> int:
+        return len(self.li_off)
+
+    def leaf_input(self, i: int) -> bytes:
+        off = int(self.li_off[i])
+        return self.body[off:off + int(self.li_len[i])]
+
+    def extra_data(self, i: int) -> bytes:
+        off = int(self.ed_off[i])
+        return self.body[off:off + int(self.ed_len[i])]
+
+    def items(self) -> tuple[list, list]:
+        """Both columns as lists of ``bytes``, one object an entry: for
+        the lanes that want that (no native library, a stale one)."""
+        body = self.body
+        return tuple(
+            [body[o:o + n] for o, n in zip(off.tolist(), ln.tolist())]
+            for off, ln in ((self.li_off, self.li_len),
+                            (self.ed_off, self.ed_len)))
+
+    def max_leaf_input_len(self) -> int:
+        return int(self.li_len.max(initial=0))
+
+
+@dataclass
+class StrPage:
+    """One get-entries response as two lists of base64 strings, an item
+    an entry: what tests, the bench, the audit driver and the tuner
+    hand the sink. The same reading interface as :class:`EntryPage`."""
+
+    leaf_inputs: Sequence
+    extra_datas: Sequence
+
+    def __len__(self) -> int:
+        return len(self.leaf_inputs)
+
+    def leaf_input(self, i: int):
+        return self.leaf_inputs[i]
+
+    def extra_data(self, i: int):
+        return self.extra_datas[i]
+
+    def items(self) -> tuple:
+        return self.leaf_inputs, self.extra_datas
+
+    def max_leaf_input_len(self) -> int:
+        return max(map(len, self.leaf_inputs), default=0)
+
+
+def scan_entries(body: bytes, cap: int) -> Optional[EntryPage]:
+    """The page a get-entries response ``body`` of at most ``cap``
+    entries is, found by one native pass with the GIL released; None
+    where the scanner says the bytes are not its kind (an escape, a
+    byte outside ASCII, a member that is no string, more than ``cap``
+    entries, anything malformed) or the library has no scanner. The
+    caller then parses the body as JSON, which accepts or raises as it
+    always has."""
+    lib = load_native()
+    if lib is None or not getattr(lib, "has_scan", False):
+        return None
+    cols = np.empty((4, max(int(cap), 1)), np.int64)
+    i64p = ctypes.POINTER(ctypes.c_int64)
+    n = lib.ctmr_scan_entries(
+        body, len(body), cols.shape[1],
+        *(cols[k].ctypes.data_as(i64p) for k in range(4)))
+    if n < 0:
+        return None
+    return EntryPage(body, *(cols[k, :n] for k in range(4)))
+
+
+def page_of_strings(leaf_inputs: Sequence[str],
+                    extra_datas: Sequence[str]) -> EntryPage:
+    """The same page form from the strings a JSON parser returned:
+    laid end to end in one buffer, every ``leaf_input`` then every
+    ``extra_data``. A character outside ASCII stays in as its UTF-8
+    bytes (no base64, so that entry's status says so)."""
+    n = len(leaf_inputs)
+    parts = []
+    for col in (leaf_inputs, extra_datas):
+        for s in col:
+            if not isinstance(s, str):  # a JSON number, null, object
+                raise TypeError(
+                    f"get-entries value is {type(s).__name__}, not a string")
+            parts.append(s.encode("utf-8", "surrogatepass"))
+    lens = np.fromiter(map(len, parts), np.int64, 2 * n)
+    offs = np.cumsum(lens) - lens
+    return EntryPage(b"".join(parts), offs[:n], lens[:n], offs[n:], lens[n:])
+
+
 class _B64Columns(NamedTuple):
     """Both base64 columns as the native decoder takes them. Entry i of
     a column is ``off[i+1] - off[i]`` bytes; ``li``/``ed`` are the
     joined buffers (``bytes``) when ``owner`` is None, else arrays of
-    pointers into the strings that ``owner`` keeps alive."""
+    pointers into the bodies and strings that ``owner`` keeps alive."""
 
     li: object
     li_off: np.ndarray  # int64[n + 1]
     ed: object
     ed_off: np.ndarray  # int64[n + 1]
-    owner: Optional[tuple]
+    owner: Optional[list]
 
 
-def _b64_columns(lib, leaf_inputs: Sequence[str],
-                 extra_datas: Sequence[str]) -> _B64Columns:
-    """The batch's base64 as the native call reads it, GIL held. Lists
-    of ASCII ``str`` (what the JSON parser hands the fetch layer) are
-    read in place: one C pass a column finds each string's bytes,
-    ``joined`` 0. Anything else (``bytes`` items, a non-ASCII ``str``,
-    a library without the entry point) is encoded and joined into one
-    buffer a column as before, ``joined`` 1, and raises what that
-    always raised."""
+def _flatten(pages: Sequence) -> tuple[list, list]:
+    """A chunk's pages as two lists, one item an entry."""
+    lis: list = []
+    eds: list = []
+    for page in pages:
+        li, ed = page.items()
+        lis.extend(li)
+        eds.extend(ed)
+    return lis, eds
+
+
+def _ptr_columns(lib, pages: Sequence, n: int) -> tuple[_B64Columns, int]:
+    """Pointer columns over a chunk's pages, no base64 byte moved: a
+    page kept as bytes gives ``address of body + offsets`` (one numpy
+    expression a column), a :class:`StrPage` of ASCII ``str`` is read where
+    the strings lie (:func:`_gather_strs`), and a page of anything else
+    is joined first, raising what that join always raised. Returns the
+    columns and how many pages were joined."""
+    ptr = np.empty((2, n), np.uintp)
+    off = np.zeros((2, n + 1), np.int64)
+    owner: list = []
+    joined = 0
+    a = 0
+
+    def place(col: int, b: int, buf: bytes, offs, lens) -> None:
+        # bytes -> a read-only view, no copy: its address is the buffer's
+        addr = np.frombuffer(buf, np.uint8).ctypes.data if buf else 0
+        np.add(offs, addr, out=ptr[col, a:b], casting="unsafe")
+        off[col, a + 1:b + 1] = lens
+        owner.append(buf)
+
+    for page in pages:
+        b = a + len(page)
+        if isinstance(page, EntryPage):
+            place(0, b, page.body, page.li_off, page.li_len)
+            place(1, b, page.body, page.ed_off, page.ed_len)
+            a = b
+            continue
+        li = _gather_strs(lib, page.leaf_inputs)
+        ed = _gather_strs(lib, page.extra_datas) if li else None
+        if ed:
+            for col, (p, o, items) in enumerate((li, ed)):
+                ptr[col, a:b] = p
+                off[col, a + 1:b + 1] = np.diff(o)
+                owner.append(items)
+        else:
+            joined += 1
+            for col, (buf, o) in enumerate(map(_concat_b64, page.items())):
+                place(col, b, buf, o[:-1], np.diff(o))
+        a = b
+    np.cumsum(off, axis=1, out=off)
+    return _B64Columns(ptr[0], off[0], ptr[1], off[1], owner), joined
+
+
+def _b64_columns(lib, pages: Sequence, n: int) -> _B64Columns:
+    """A chunk's base64 (``n`` entries) as the native call reads it,
+    GIL held; each of ``pages`` is an :class:`EntryPage` or a
+    :class:`StrPage`. Nothing is copied where the decoder
+    can be pointed at the bytes (:func:`_ptr_columns`); ``joined``
+    counts the pages that had to be encoded and joined as before
+    (``bytes`` items, a non-ASCII ``str``), and with a library that
+    reads no pointer columns that is the whole chunk, one buffer a
+    column."""
     with trace.span("decode.concat_b64", cat="decode") as sp:
-        cols = None
         if getattr(lib, "has_strs", False):
-            li = _gather_strs(lib, leaf_inputs)
-            ed = _gather_strs(lib, extra_datas) if li else None
-            if ed:
-                cols = _B64Columns(*li[:2], *ed[:2], owner=(li[2], ed[2]))
-        if cols is None:
-            cols = _B64Columns(*_concat_b64(leaf_inputs),
-                               *_concat_b64(extra_datas), owner=None)
+            cols, joined = _ptr_columns(lib, pages, n)
+        else:
+            lis, eds = _flatten(pages)
+            cols = _B64Columns(*_concat_b64(lis), *_concat_b64(eds),
+                               owner=None)
+            joined = len(pages)
         sp.set(bytes=int(cols.li_off[-1] + cols.ed_off[-1]),
-               joined=int(cols.owner is None))
+               joined=joined, pages=len(pages))
     return cols
 
 
@@ -326,20 +485,35 @@ def decode_raw_batch(
     workers: Optional[int] = None,
     threads: Optional[int] = None,
 ) -> DecodedBatch:
-    with trace.span("native.decode_batch", cat="native",
-                    entries=len(leaf_inputs), pad=int(pad_len)):
-        return _decode_raw_batch(leaf_inputs, extra_datas, pad_len,
-                                 workers=workers, threads=threads)
+    """One page given as two lists of base64 strings: see
+    :func:`decode_raw_pages`."""
+    return decode_raw_pages([StrPage(leaf_inputs, extra_datas)], pad_len,
+                            workers=workers, threads=threads)
 
 
-def _decode_raw_batch(
-    leaf_inputs: Sequence[str],
-    extra_datas: Sequence[str],
+def decode_raw_pages(
+    pages: Sequence,
     pad_len: int,
     workers: Optional[int] = None,
     threads: Optional[int] = None,
 ) -> DecodedBatch:
-    """Decode one get-entries response into packed device arrays.
+    n = sum(map(len, pages))
+    with trace.span("native.decode_batch", cat="native",
+                    entries=n, pad=int(pad_len)):
+        return _decode_raw_pages(pages, n, pad_len,
+                                 workers=workers, threads=threads)
+
+
+def _decode_raw_pages(
+    pages: Sequence,
+    n: int,
+    pad_len: int,
+    workers: Optional[int] = None,
+    threads: Optional[int] = None,
+) -> DecodedBatch:
+    """Decode a chunk of get-entries responses (``n`` entries in all,
+    each page an :class:`EntryPage` or a :class:`StrPage`) into packed
+    device arrays, as if they were one response.
 
     ``threads`` > 1 splits the batch across the native library's
     persistent worker pool — one ctypes call, lane ranges decoded in
@@ -361,14 +535,13 @@ def _decode_raw_batch(
     """
     import os
 
-    n = len(leaf_inputs)
     # CTMR_NATIVE=0 forces the pure-Python lane (read per call, not at
     # load: the bench's CPU smoke flips it mid-process to rebalance the
     # decode stage; results are byte-identical by the conformance suite).
     lib = (None if os.environ.get("CTMR_NATIVE", "1") == "0"
            else load_native())
     if lib is None:
-        return _decode_python(leaf_inputs, extra_datas, pad_len)
+        return _decode_python(*_flatten(pages), pad_len)
 
     t = resolve_threads(n, threads if threads else workers)
     if not getattr(lib, "has_mt", False):
@@ -381,7 +554,7 @@ def _decode_raw_batch(
     status = np.zeros((n,), np.int32)
     out = (data, length, ts, ety, status)
 
-    cols = _b64_columns(lib, leaf_inputs, extra_datas)
+    cols = _b64_columns(lib, pages, n)
     span = _decode_native(lib, cols, pad_len, out, t)
     if span is None and t > 1:
         # A chunk's issuer slice overflowed (pathologically skewed
@@ -389,7 +562,7 @@ def _decode_raw_batch(
         t = 1
         span = _decode_native(lib, cols, pad_len, out, t)
     if span is None:  # issuer scratch overflow — impossible by sizing
-        return _decode_python(leaf_inputs, extra_datas, pad_len)
+        return _decode_python(*_flatten(pages), pad_len)
     group, group_issuers = _issuer_groups(*span, chunks=t)
     return DecodedBatch(data, length, ts, ety, None, status,
                         issuer_group=group, group_issuers=group_issuers)

@@ -683,11 +683,11 @@ def test_raw_batch_narrow_decode_and_redecode():
     ed = base64.b64encode(leaflib.encode_extra_data([issuer_der])).decode()
 
     pads_seen = []
-    orig = leafpack.decode_raw_batch
+    orig = leafpack.decode_raw_pages
 
-    def spy(lis, eds, pad_len, workers=None, threads=None):
+    def spy(pages, pad_len, workers=None, threads=None):
         pads_seen.append(pad_len)
-        return orig(lis, eds, pad_len, workers=workers, threads=threads)
+        return orig(pages, pad_len, workers=workers, threads=threads)
 
 
     # (a) all-small batch: ONE decode at the narrow width.
@@ -697,7 +697,7 @@ def test_raw_batch_narrow_decode_and_redecode():
     lis = [base64.b64encode(
         leaflib.encode_leaf_input(der, i)).decode()
         for i, der in enumerate(small)]
-    leafpack.decode_raw_batch = spy
+    leafpack.decode_raw_pages = spy
     try:
         sink.store_raw_batch(RawBatch(lis, [ed] * len(lis), 0, "log"))
         sink.flush()
@@ -729,7 +729,7 @@ def test_raw_batch_narrow_decode_and_redecode():
         assert pads_seen == [sink2.PAD_LEN // 2, sink2.PAD_LEN]
         assert agg2.drain().total == len(small) + 1
     finally:
-        leafpack.decode_raw_batch = orig
+        leafpack.decode_raw_pages = orig
 
 
 def test_oversized_issuer_gets_own_status_no_redecode():
@@ -774,21 +774,21 @@ def test_oversized_issuer_gets_own_status_no_redecode():
         dec.status, leafpack._decode_python(lis, eds, 2048).status)
 
     pads_seen = []
-    orig = leafpack.decode_raw_batch
+    orig = leafpack.decode_raw_pages
 
-    def spy(l, e, pad_len, workers=None, threads=None):
+    def spy(pages, pad_len, workers=None, threads=None):
         pads_seen.append(pad_len)
-        return orig(l, e, pad_len, workers=workers, threads=threads)
+        return orig(pages, pad_len, workers=workers, threads=threads)
 
     agg = TpuAggregator(capacity=1 << 12, batch_size=64,
                         now=datetime.datetime(2025, 1, 1, tzinfo=UTC))
     sink = AggregatorSink(agg, flush_size=64)
-    leafpack.decode_raw_batch = spy
+    leafpack.decode_raw_pages = spy
     try:
         sink.store_raw_batch(RawBatch(lis, eds, 0, "log"))
         sink.flush()
     finally:
-        leafpack.decode_raw_batch = orig
+        leafpack.decode_raw_pages = orig
     # Narrow pre-decode, ONE decode — the overloaded status used to
     # force [narrow, full] here.
     assert pads_seen == [sink.PAD_LEN // 2], pads_seen

@@ -20,7 +20,7 @@ import pytest
 from ct_mapreduce_tpu.agg.aggregator import TpuAggregator
 from ct_mapreduce_tpu.ingest import leaf as leaflib
 from ct_mapreduce_tpu.ingest.sync import AggregatorSink, LogSyncEngine
-from ct_mapreduce_tpu.native import leafpack
+from ct_mapreduce_tpu.native import available, leafpack
 from ct_mapreduce_tpu.storage.certdb import FilesystemDatabase
 from ct_mapreduce_tpu.storage.mockbackend import MockBackend
 from ct_mapreduce_tpu.storage.mockcache import MockRemoteCache
@@ -178,8 +178,8 @@ def test_page_spans_say_what_was_fetched(tmp_path):
         got = [e for e in kids if e["name"] == "fetch.get_entries"]
         assert len(got) == 1 and got[0]["args"]["attempts"] == 1
         assert got[0]["args"]["bytes"] > PAGE * 100
-        assert {e["args"]["n"] for e in kids
-                if e["name"] == "fetch.parse_json"} == {PAGE}
+        parsed = [e["args"] for e in kids if e["name"] == "fetch.parse_json"]
+        assert parsed == [{"n": PAGE, "scanned": int(available())}]
         put = [e for e in kids if e["name"] == "fetch.enqueue"]
         assert len(put) == 1 and put[0]["args"]["depth"] >= 0
         assert sum(e["dur"] for e in kids) <= page["dur"] + 1e-3

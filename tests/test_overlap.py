@@ -220,19 +220,19 @@ def test_issuer_too_long_status_skips_futile_redecode():
     # Sink level: the narrow pre-decode stays a SINGLE decode (the old
     # overloaded TOO_LONG forced a futile full-width redecode here).
     pads_seen = []
-    orig = leafpack.decode_raw_batch
+    orig = leafpack.decode_raw_pages
 
-    def spy(l, e, pad_len, workers=None, threads=None):
+    def spy(pages, pad_len, workers=None, threads=None):
         pads_seen.append(pad_len)
-        return orig(l, e, pad_len, workers=workers, threads=threads)
+        return orig(pages, pad_len, workers=workers, threads=threads)
 
     agg, sink = make_sink(overlap_workers=0, flush_size=64)
-    leafpack.decode_raw_batch = spy
+    leafpack.decode_raw_pages = spy
     try:
         sink.store_raw_batch(RawBatch(lis, eds, 0, "log"))
         sink.flush()
     finally:
-        leafpack.decode_raw_batch = orig
+        leafpack.decode_raw_pages = orig
     assert pads_seen == [sink.PAD_LEN // 2], pads_seen
     # ... and the oversized-issuer entry still counted, exactly once.
     assert agg.drain().total == len(small) + 1

@@ -1,0 +1,522 @@
+"""A get-entries page as bytes (PR 30): the native scan that finds the
+two base64 columns in a response body against ``json.loads``, the
+page-returning client call against ``get_raw_entries``, and the whole
+raw-batch path with the scan against the same path with every page
+parsed as JSON.
+
+The scanner's contract is differential: for any body it either yields
+exactly the strings ``json.loads`` yields, or says "not mine" and the
+page then parses, or raises, exactly as it always has.
+"""
+
+import base64
+import datetime
+import json
+
+import numpy as np
+import pytest
+
+from ct_mapreduce_tpu.agg.aggregator import TpuAggregator
+from ct_mapreduce_tpu.ingest import leaf as leaflib
+from ct_mapreduce_tpu.ingest.ctclient import CTLogClient
+from ct_mapreduce_tpu.ingest.sync import (
+    AggregatorSink,
+    LogSyncEngine,
+    RawBatch,
+)
+from ct_mapreduce_tpu.native import available, leafpack, load
+from ct_mapreduce_tpu.storage.certdb import FilesystemDatabase
+from ct_mapreduce_tpu.storage.mockbackend import MockBackend
+from ct_mapreduce_tpu.storage.mockcache import MockRemoteCache
+from ct_mapreduce_tpu.telemetry import metrics, trace
+from ct_mapreduce_tpu.utils import minicert
+from tests.fakelog import FakeLog
+
+needs_native = pytest.mark.skipif(not available(), reason="no C++ compiler")
+
+NOW = datetime.datetime(2025, 1, 1, tzinfo=datetime.timezone.utc)
+LI = ["AAAAAAF/abc+/w==", "QUJD", "Zm9vYmFy"]
+ED = ["AAAA", "", "ZXh0cmE="]
+
+
+@pytest.fixture(autouse=True)
+def clean_telemetry():
+    trace.disable()
+    metrics.set_sink(metrics.InMemSink())
+    yield
+    trace.disable()
+    metrics.set_sink(metrics.InMemSink())
+
+
+def entries(lis=LI, eds=ED):
+    return [{"leaf_input": li, "extra_data": ed} for li, ed in zip(lis, eds)]
+
+
+def compact(obj) -> bytes:
+    return json.dumps(obj, separators=(",", ":")).encode()
+
+
+def body_of(lis=LI, eds=ED) -> bytes:
+    return compact({"entries": entries(lis, eds)})
+
+
+BODY = body_of()
+
+# name -> (body, does the scanner take it)
+CASES = {
+    "compact": (BODY, True),
+    "spaced": (json.dumps({"entries": entries()}).encode(), True),
+    "indented": (json.dumps({"entries": entries()}, indent=2).encode(), True),
+    "tabs_and_crlf": (
+        b'\r\n\t{ "entries"\t:\r\n[ {"leaf_input" :\t"QUJD" ,\r\n'
+        b'"extra_data": "AAAA"}\t]\r\n}\r\n ', True),
+    "extra_data_first": (compact({"entries": [
+        {"extra_data": ed, "leaf_input": li} for li, ed in zip(LI, ED)]}),
+        True),
+    "extra_members": (compact({"entries": [
+        {"sct": "c2N0", "leaf_input": li, "note": "", "extra_data": ed,
+         "z": "{[,:]}"} for li, ed in zip(LI, ED)]}), True),
+    "top_level_string_member": (compact(
+        {"note": "x y", "entries": entries(), "more": ""}), True),
+    "extra_data_absent": (compact(
+        {"entries": [{"leaf_input": li} for li in LI]}), True),
+    "extra_data_absent_in_some": (compact({"entries": [
+        {"leaf_input": "QUJD"}, {"leaf_input": "QUJE", "extra_data": "AAAA"},
+        {"leaf_input": "QUJF"}]}), True),
+    "empty_entries": (b'{"entries":[]}', True),
+    "empty_entries_spaced": (b' { "entries" : [ ] } ', True),
+    "one_entry": (body_of(LI[:1], ED[:1]), True),
+    "slash_in_value": (body_of(["ab/d", "////"], ["/w==", "AA/A"]), True),
+    "trailing_whitespace": (BODY + b"\n \t\r\n", True),
+    "leading_whitespace": (b"\n\n  " + BODY, True),
+    "value_of_length_0": (body_of(["", "QUJD"], ["", ""]), True),
+    "value_of_1mb": (body_of(["QUJD" * (1 << 18), "QUJD"],
+                             ["AAAA", "QUJD" * (1 << 18)]), True),
+    "not_base64_but_plain": (body_of(["a b~c!", "{}[]:,"], ["#", " "]), True),
+    "del_byte_in_value": (
+        b'{"entries":[{"leaf_input":"AB\x7fC","extra_data":"AAAA"}]}', True),
+    "other_key_repeated": (
+        b'{"entries":[{"x":"1","leaf_input":"QUJD","x":"2"}]}', True),
+    # -- not the scanner's: json.loads parses these --------------------
+    "escaped_slash_in_value": (
+        b'{"entries":[{"leaf_input":"ab\\/d","extra_data":"AAAA"}]}', False),
+    "escaped_slash_in_extra_data": (
+        b'{"entries":[{"leaf_input":"QUJD","extra_data":"AA\\/A"}]}', False),
+    "escaped_quote_in_value": (
+        b'{"entries":[{"leaf_input":"ab\\"d","extra_data":"AAAA"}]}', False),
+    "unicode_escape_in_value": (
+        b'{"entries":[{"leaf_input":"QU\\u004aD"}]}', False),
+    "escape_in_key": (
+        b'{"entries":[{"leaf\\u005finput":"QUJD","extra_data":"AAAA"}]}',
+        False),
+    "escape_in_other_member": (
+        b'{"entries":[{"leaf_input":"QUJD","note":"a\\nb"}]}', False),
+    "non_ascii_in_value": (
+        '{"entries":[{"leaf_input":"QUé","extra_data":"✓AAA"}]}'.encode(),
+        False),
+    "non_ascii_in_other_member": (
+        '{"entries":[{"leaf_input":"QUJD","note":"é"}]}'.encode(), False),
+    "leaf_input_repeated": (
+        b'{"entries":[{"leaf_input":"QUJD","leaf_input":"QUJE"}]}', False),
+    "extra_data_repeated": (
+        b'{"entries":[{"leaf_input":"QUJD","extra_data":"AAAA",'
+        b'"extra_data":"AAAB"}]}', False),
+    "entries_repeated": (
+        b'{"entries":[{"leaf_input":"QUJD"}],'
+        b'"entries":[{"leaf_input":"QUJE"}]}', False),
+    "number_member": (
+        b'{"entries":[{"leaf_input":"QUJD","index":7}]}', False),
+    "null_bool_members": (
+        b'{"entries":[{"leaf_input":"QUJD","a":null,"b":true,"c":false}]}',
+        False),
+    "nested_member": (
+        b'{"entries":[{"leaf_input":"QUJD","sth":{"a":["b"]}}]}', False),
+    "top_level_number_member": (
+        b'{"count":3,"entries":[{"leaf_input":"QUJD"}]}', False),
+    "no_entries_member": (b'{"error":"none"}', False),
+    "empty_object": (b"{}", False),
+    "utf8_bom": (b"\xef\xbb\xbf" + BODY, False),
+    "utf16": (BODY.decode().encode("utf-16"), False),
+    # -- not the scanner's: the parent's parse raises for these --------
+    "trailing_bytes": (BODY + b"x", False),
+    "trailing_brace": (BODY + b"}", False),
+    "two_documents": (BODY + BODY, False),
+    "empty_body": (b"", False),
+    "only_whitespace": (b"  \n", False),
+    "top_level_array": (b'[{"leaf_input":"QUJD"}]', False),
+    "entries_an_object": (b'{"entries":{"leaf_input":"QUJD"}}', False),
+    "entries_a_string": (b'{"entries":"QUJD"}', False),
+    "entry_a_string": (b'{"entries":["QUJD"]}', False),
+    "leaf_input_missing": (b'{"entries":[{"extra_data":"AAAA"}]}', False),
+    "entry_an_empty_object": (b'{"entries":[{}]}', False),
+    "leaf_input_a_number": (b'{"entries":[{"leaf_input":5}]}', False),
+    "extra_data_null": (
+        b'{"entries":[{"leaf_input":"QUJD","extra_data":null}]}', False),
+    "control_byte_in_value": (
+        b'{"entries":[{"leaf_input":"QU\nJD"}]}', False),
+    "nul_byte_in_value": (b'{"entries":[{"leaf_input":"QU\x00JD"}]}', False),
+    "trailing_comma_in_array": (
+        b'{"entries":[{"leaf_input":"QUJD"},]}', False),
+    "trailing_comma_in_entry": (
+        b'{"entries":[{"leaf_input":"QUJD",}]}', False),
+    "trailing_comma_at_top": (
+        b'{"entries":[{"leaf_input":"QUJD"}],}', False),
+    "missing_colon": (b'{"entries":[{"leaf_input" "QUJD"}]}', False),
+    "missing_comma": (
+        b'{"entries":[{"leaf_input":"QUJD"}{"leaf_input":"QUJE"}]}', False),
+    "single_quotes": (b"{'entries':[]}", False),
+    "unquoted_key": (b'{entries:[]}', False),
+    "form_feed_as_whitespace": (b'{"entries":\x0c[]}', False),
+    "invalid_utf8": (b'{"entries":[{"leaf_input":"QU\xff\xfeJD"}]}', False),
+}
+# A body cut short anywhere is malformed: cut the compact body inside a
+# key, inside each value, between members, between entries, before each
+# closing bracket.
+CUTS = sorted({1, 2, 5, 10, 11, 12, 13, 14, 15, 26, 27, 28, 30, 44, 45, 46,
+               58, 59, 60, 64, 65, 66, 67, 68, len(BODY) // 2,
+               len(BODY) - 3, len(BODY) - 2, len(BODY) - 1})
+for _cut in CUTS:
+    CASES[f"cut_at_{_cut}"] = (BODY[:_cut], False)
+
+
+def client_for(body: bytes) -> CTLogClient:
+    return CTLogClient("ct.example.com/scan",
+                       transport=lambda url: (200, {}, body))
+
+
+def outcome(call):
+    """What a parse gives: the two columns as ``str``, or what it
+    raised."""
+    try:
+        got = call()
+    except Exception as err:
+        return ("raises", type(err))
+    return ("parses",) + got
+
+
+def parent_parse(body: bytes):
+    """The per-entry client call, untouched by the page path: what the
+    raw-batch path built its lists from before."""
+    got = client_for(body).get_raw_entries(0, 999)
+    return ([e.leaf_input for e in got], [e.extra_data for e in got])
+
+
+def page_strings(page) -> tuple:
+    return tuple([b.decode("utf-8", "surrogatepass") for b in col]
+                 for col in page.items())
+
+
+def counters() -> dict:
+    return {k: v for k, v in metrics.get_sink().snapshot()["counters"].items()
+            if k.startswith("ingest.page.")}
+
+
+@needs_native
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_scanner_yields_what_json_yields_or_declines(name):
+    """The scan itself: a page whose every slice is the string
+    ``json.loads`` returns, in order, or no page at all."""
+    body, scanned = CASES[name]
+    page = leafpack.scan_entries(body, 1000)
+    assert (page is not None) == scanned
+    if page is None:
+        return
+    want = json.loads(body)["entries"]
+    assert len(page) == len(want)
+    lis, eds = page_strings(page)
+    assert lis == [e["leaf_input"] for e in want]
+    assert eds == [e.get("extra_data", "") for e in want]
+    assert page.body is body  # nothing copied
+    for arr in (page.li_off, page.li_len, page.ed_off, page.ed_len):
+        assert arr.dtype == np.int64 and arr.shape == (len(want),)
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_page_call_parses_or_raises_as_the_per_entry_call_does(name):
+    """Through the client: the page holds exactly the strings
+    ``get_raw_entries`` returns, or the call raises. One span, one
+    counter a page, and they say whether the scan took it."""
+    body, scanned = CASES[name]
+    scanned = scanned and available()
+    want = outcome(lambda: parent_parse(body))
+    if want[0] == "parses" and not all(
+            isinstance(s, str) for col in want[1:] for s in col):
+        # a value that is no string: the parent enqueued it and the
+        # store thread's join raised TypeError; the page call raises it
+        want = ("raises", TypeError)
+    trace.enable(ring_size=64)
+    got = outcome(lambda: page_strings(
+        client_for(body).get_entry_page(0, 999)))
+    spans = [e for e in trace.snapshot_events()
+             if e["name"] == "fetch.parse_json"]
+    assert got == want
+    assert len(spans) == 1
+    if got[0] == "parses":
+        assert spans[0]["args"] == {"n": len(got[1]), "scanned": int(scanned)}
+        assert counters() == {
+            "ingest.page.scanned" if scanned
+            else "ingest.page.json_fallback": 1.0}
+    else:
+        assert counters() == {}
+
+
+@needs_native
+def test_library_without_the_scanner_takes_the_fallback(monkeypatch):
+    """A prebuilt library from before the scanner (``has_scan`` false)
+    parses every page as JSON, into the same page form."""
+    monkeypatch.setattr(load(), "has_scan", False)
+    assert leafpack.scan_entries(BODY, 1000) is None
+    page = client_for(BODY).get_entry_page(0, 999)
+    assert page_strings(page) == (LI, ED)
+    assert page.body is not BODY
+    assert counters() == {"ingest.page.json_fallback": 1.0}
+
+
+@needs_native
+def test_more_entries_than_asked_for_is_not_scanned():
+    """The arrays hold what was asked for; a server that answers with
+    more is parsed as JSON, and every entry arrives."""
+    assert leafpack.scan_entries(BODY, 2) is None
+    assert len(leafpack.scan_entries(BODY, 3)) == 3
+    page = client_for(BODY).get_entry_page(10, 11)
+    assert page_strings(page) == (LI, ED)
+    assert counters() == {"ingest.page.json_fallback": 1.0}
+
+
+def test_page_call_keeps_the_window_clamp_and_empty_range():
+    """Same request shaping as ``get_raw_entries``: nothing fetched for
+    an empty range, and a short page clamps the window."""
+    log = FakeLog()
+    log.max_batch = 4
+    for li in ["QUJD"] * 10:
+        log.entries.append({"leaf_input": li, "extra_data": ""})
+    c = CTLogClient(log.url, transport=log.transport)
+    assert len(c.get_entry_page(5, 4)) == 0
+    assert len(c.get_entry_page(0, 9)) == 4 and c.page_size == 4
+    assert len(c.get_entry_page(4, 9)) == 4
+    assert len(c.get_entry_page(8, 9)) == 2 and c.page_size == 4
+    snap = metrics.get_sink().snapshot()["counters"]
+    assert snap["ingest.window_clamp"] == 1.0
+
+
+def test_page_of_strings_keeps_non_ascii_as_utf8():
+    page = leafpack.page_of_strings(["QUé", "QUJD"], ["✓", "\ud800"])
+    assert page_strings(page) == (["QUé", "QUJD"], ["✓", "\ud800"])
+    assert page.leaf_input(0) == "QUé".encode() and page.extra_data(1) == (
+        "\ud800".encode("utf-8", "surrogatepass"))
+
+
+# -- the whole raw-batch path, scanned against parsed ----------------------
+
+PAGE, BATCH, N_ENTRIES = 8, 32, 104  # three whole batches and a tail
+ISSUERS = [minicert.make_cert(serial=1 + k, issuer_cn=f"Scan CA {k}",
+                              is_ca=True) for k in range(3)]
+FUTURE = datetime.datetime(2031, 1, 1, tzinfo=datetime.timezone.utc)
+
+
+def wire_entries() -> list[dict]:
+    out = []
+    for j in range(N_ENTRIES):
+        k = (j * 7) % 3
+        leaf = minicert.make_cert(
+            serial=100 + j - (j % 13 == 5),  # a few duplicate serials
+            issuer_cn=f"Scan CA {k}", subject_cn=f"s{j}.example",
+            is_ca=False, not_after=FUTURE)
+        li = base64.b64encode(leaflib.encode_leaf_input(leaf, 1000 + j))
+        ed = base64.b64encode(leaflib.encode_extra_data([ISSUERS[k]]))
+        out.append({"leaf_input": li.decode(), "extra_data": ed.decode()})
+    out[17]["leaf_input"] = "!!notbase64!!"  # one entry no lane decodes
+    out[40]["extra_data"] = ""  # and one with no chain
+    return out
+
+
+def run_engine(log_entries, compact_bodies: bool):
+    """The engine as ct-fetch wires it on the TPU backend, every
+    ``DecodedBatch`` the sink decoded on the way, the aggregate and the
+    cursor."""
+    log = FakeLog()
+    log.max_batch = PAGE
+    log.entries = log_entries
+    transport = log.transport
+    if compact_bodies:  # as real logs emit it; FakeLog spaces its JSON
+        def transport(url):
+            status, headers, body = log.transport(url)
+            return status, headers, compact(json.loads(body))
+    decoded = []
+    orig = leafpack.decode_raw_pages
+
+    def spy(pages, pad_len, workers=None, threads=None):
+        decoded.append(orig(pages, pad_len, workers=workers, threads=threads))
+        return decoded[-1]
+
+    agg = TpuAggregator(capacity=1 << 12, batch_size=BATCH, now=NOW)
+    sink = AggregatorSink(agg, flush_size=BATCH)
+    db = FilesystemDatabase(MockBackend(), MockRemoteCache())
+    engine = LogSyncEngine(sink, db, num_threads=1, raw_batches=True)
+    leafpack.decode_raw_pages = spy
+    try:
+        engine.start_store_threads()
+        engine.sync_log(log.url, transport=transport)
+        engine.wait_for_downloads(timeout=120)
+        engine.stop()
+        sink.close()
+    finally:
+        leafpack.decode_raw_pages = orig
+    assert not engine.errors, engine.errors
+    state = db.get_log_state("ct.example.com/fake")
+    snap = agg.drain()
+    return decoded, snap, (state.max_entry, state.last_entry_time)
+
+
+def assert_same_batches(got, want):
+    assert len(got) == len(want)
+    for a, b in zip(got, want):
+        for fld in ("data", "length", "timestamp_ms", "entry_type",
+                    "status", "issuer_group"):
+            x, y = getattr(a, fld), getattr(b, fld)
+            assert x.dtype == y.dtype and x.tobytes() == y.tobytes(), fld
+        assert a.group_issuers == b.group_issuers
+
+
+@needs_native
+@pytest.mark.parametrize("compact_bodies", [True, False],
+                         ids=["compact", "spaced"])
+def test_engine_scanned_and_parsed_give_the_same_batches(
+        compact_bodies, monkeypatch):
+    """The same log through ``LogSyncEngine`` with the scan, and with
+    the library's flag saying it has none: byte-identical decoded
+    batches, the same aggregate, the same cursor."""
+    wire = wire_entries()
+    scanned = run_engine(wire, compact_bodies)
+    assert counters() == {"ingest.page.scanned": float(N_ENTRIES // PAGE)}
+    metrics.set_sink(metrics.InMemSink())
+    monkeypatch.setattr(load(), "has_scan", False)
+    parsed = run_engine(wire, compact_bodies)
+    assert counters() == {"ingest.page.json_fallback":
+                          float(N_ENTRIES // PAGE)}
+    assert len(scanned[0]) == -(-N_ENTRIES // BATCH)
+    assert_same_batches(scanned[0], parsed[0])
+    for a, b in ((scanned[1], parsed[1]),):
+        assert a.total == b.total and a.total > 0
+        assert dict(a.counts) == dict(b.counts)
+    assert scanned[2] == parsed[2] and scanned[2][0] == N_ENTRIES
+
+
+@needs_native
+def test_a_page_the_scanner_declines_rides_in_the_same_chunk():
+    """A log that escapes ``/`` on some pages (legal JSON): those pages
+    parse as before, the others are scanned, one chunk decodes both and
+    gives what an all-parsed run gives."""
+    wire = wire_entries()
+    log = FakeLog()
+    log.max_batch = PAGE
+    log.entries = wire
+    calls = {"n": 0}
+
+    def escaping(url):
+        status, headers, body = log.transport(url)
+        if "get-entries" in url:
+            calls["n"] += 1
+            if calls["n"] % 3 == 0:
+                body = body.replace(b"/", b"\\/")
+        return status, headers, body
+
+    agg = TpuAggregator(capacity=1 << 12, batch_size=BATCH, now=NOW)
+    sink = AggregatorSink(agg, flush_size=BATCH)
+    engine = LogSyncEngine(sink, FilesystemDatabase(
+        MockBackend(), MockRemoteCache()), num_threads=1, raw_batches=True)
+    engine.start_store_threads()
+    engine.sync_log(log.url, transport=escaping)
+    engine.wait_for_downloads(timeout=120)
+    engine.stop()
+    sink.close()
+    assert not engine.errors, engine.errors
+    pages = N_ENTRIES // PAGE
+    assert counters() == {"ingest.page.scanned": float(pages - pages // 3),
+                          "ingest.page.json_fallback": float(pages // 3)}
+    mixed = agg.drain()
+    _, want, _ = run_engine(wire, compact_bodies=False)
+    assert mixed.total == want.total
+    assert dict(mixed.counts) == dict(want.counts)
+
+
+def chunk_pages(kinds):
+    """The wire entries cut into pages of PAGE, each in the form its
+    kind says: ``list`` (two lists of str), ``scan`` (a scanned body),
+    ``join`` (the fallback's joined buffer)."""
+    wire = wire_entries()[:PAGE * len(kinds)]
+    pages = []
+    for k, kind in enumerate(kinds):
+        part = wire[k * PAGE:(k + 1) * PAGE]
+        lis = [e["leaf_input"] for e in part]
+        eds = [e["extra_data"] for e in part]
+        if kind == "list":
+            pages.append(leafpack.StrPage(lis, eds))
+        elif kind == "scan":
+            pages.append(leafpack.scan_entries(
+                compact({"entries": part}), PAGE))
+        else:
+            pages.append(leafpack.page_of_strings(lis, eds))
+    assert all(p is not None for p in pages)
+    return pages
+
+
+@needs_native
+@pytest.mark.parametrize("stale", [False, True], ids=["in_place", "stale"])
+@pytest.mark.parametrize("threads", [1, 3])
+@pytest.mark.parametrize("kinds", [
+    ("scan",) * 6, ("join",) * 6, ("scan", "list", "scan", "join", "list",
+                                   "scan"), ("list", "scan") * 3])
+def test_mixed_pages_decode_to_the_rows_all_lists_give(
+        kinds, threads, stale, monkeypatch):
+    """Whatever forms a chunk's pages have, the decoder sees the same
+    entries in the same order: rows, statuses, timestamps and issuer
+    groups equal the all-list chunk's and the pure-Python lane's."""
+    if stale:  # a library that reads no pointer columns joins the chunk
+        monkeypatch.setattr(load(), "has_strs", False)
+    want = leafpack.decode_raw_pages(chunk_pages(("list",) * len(kinds)),
+                                     2048, threads=1)
+    got = leafpack.decode_raw_pages(chunk_pages(kinds), 2048,
+                                    threads=threads)
+    assert_same_batches([got], [want])
+    lis, eds = leafpack._flatten(chunk_pages(kinds))
+    assert_same_batches([got], [leafpack._decode_python(lis, eds, 2048)])
+    assert (got.status != leafpack.OK).sum() == 2
+
+
+@needs_native
+def test_sink_takes_list_and_page_batches_in_one_chunk():
+    """``store_raw_batch`` with a list-form batch between scanned pages:
+    one chunk, the same aggregate as all-list; and a page-form batch
+    whose lists are read (and edited) becomes list form."""
+    wire = wire_entries()[:BATCH]
+
+    def feed(forms):
+        agg = TpuAggregator(capacity=1 << 12, batch_size=BATCH, now=NOW)
+        sink = AggregatorSink(agg, flush_size=BATCH)
+        for k, form in enumerate(forms):
+            part = wire[k * PAGE:(k + 1) * PAGE]
+            if form == "list":
+                raw = RawBatch([e["leaf_input"] for e in part],
+                               [e["extra_data"] for e in part],
+                               k * PAGE, "log")
+            else:
+                raw = RawBatch(start_index=k * PAGE, log_url="log",
+                               page=leafpack.scan_entries(
+                                   compact({"entries": part}), PAGE))
+                assert len(raw) == PAGE
+                if form == "thawed":
+                    raw.leaf_inputs[-1] = raw.leaf_inputs[0]
+                    raw.extra_datas[-1] = raw.extra_datas[0]
+                    assert isinstance(raw.page, leafpack.StrPage)
+            sink.store_raw_batch(raw)
+        sink.flush()
+        sink.close()
+        return agg.drain()
+
+    want = feed(["list"] * 4)
+    got = feed(["page", "list", "page", "page"])
+    assert got.total == want.total > 0
+    assert dict(got.counts) == dict(want.counts)
+    # the edit is decoded: one entry seen twice, one never
+    assert feed(["page", "thawed", "page", "page"]).total == want.total - 1
