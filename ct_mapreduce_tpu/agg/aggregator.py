@@ -483,7 +483,7 @@ class TpuAggregator:
         grow_at: float = 0.55,
         max_capacity: int = 1 << 28,
     ) -> None:
-        # ADVICE r05 grow-livelock fix: round the ceiling DOWN to a
+        # Round-5 grow-livelock fix: round the ceiling DOWN to a
         # capacity the active layout can actually build — bucket
         # layouts only reach 24·2^k slots, open layouts powers of two;
         # neither ever reaches a ragged 2^m+r ceiling — so maybe_grow's

@@ -21,17 +21,6 @@ env names that used to live inline in ``resolve_*`` bodies — their
 section resolved via ``resolve_section("<name>", ...)`` must be
 documented in MIGRATING.md as ``knobs.<name>`` (the profile file
 format is operator API too).
-
-Round 21 (the autotuner) adds the tune registry as a surface: every
-``Knob`` declared in a ``_*_KNOBS`` tuple that a ``resolve_section``
-call consumes must appear in ``tune/registry.py`` — either with a
-declared sweep ladder (``SWEEPABLE``) or with a justified exclusion
-(``EXCLUDED``, justification >= 15 chars). A new knob that lands in
-neither table would make the autotuner silently stale against the
-knob surface; a registry entry naming no declared knob is ghost
-configuration. The diff is pure AST (the registry is import-light and
-both tables are literals), mirroring ``tune.registry.audit()`` which
-re-derives the same diff at runtime for the tests.
 """
 
 from __future__ import annotations
@@ -43,8 +32,6 @@ from ct_mapreduce_tpu.analysis.engine import Checker, Ctx, Project
 
 CONFIG_RELPATH = "ct_mapreduce_tpu/config/config.py"
 MIGRATING_RELPATH = "MIGRATING.md"
-REGISTRY_RELPATH = "ct_mapreduce_tpu/tune/registry.py"
-MIN_JUSTIFICATION = 15  # chars — the ctmrlint.baseline discipline
 
 # Directives inherited 1:1 from the reference's config.go — their
 # operator docs are the reference's; MIGRATING.md documents deltas.
@@ -68,11 +55,6 @@ class ConfigParityChecker(Checker):
         self.resolve_envs: dict[str, str] = {}
         # profile section -> first "path:line" of a resolve_section call
         self.profile_sections: dict[str, str] = {}
-        # (module relpath, knob-tuple var) -> [(knob name, lineno)]
-        self.knob_decls: dict[tuple, list] = {}
-        # section -> (relpath, knob-tuple var, lineno) from the
-        # resolve_section("<name>", <VAR>, ...) association
-        self.section_vars: dict[str, tuple] = {}
         self._resolve_stack = 0
 
     # -- collect CTMR_* envs inside resolve_* functions ------------------
@@ -104,43 +86,6 @@ class ConfigParityChecker(Checker):
                 self.profile_sections.setdefault(
                     node.args[0].value,
                     f"{ctx.module.relpath}:{node.lineno}")
-                if len(node.args) > 1 and isinstance(
-                        node.args[1], ast.Name):
-                    self.section_vars.setdefault(
-                        node.args[0].value,
-                        (ctx.module.relpath, node.args[1].id,
-                         node.lineno))
-
-    # -- collect Knob names from _*_KNOBS tuple declarations -------------
-    def visit_Assign(self, node: ast.Assign, ctx: Ctx) -> None:
-        for t in node.targets:
-            if not (isinstance(t, ast.Name) and t.id.startswith("_")
-                    and t.id.endswith("_KNOBS")):
-                continue
-            decls: list = []
-            for sub in ast.walk(node.value):
-                if not isinstance(sub, ast.Call):
-                    continue
-                fn = sub.func
-                cname = (fn.id if isinstance(fn, ast.Name)
-                         else fn.attr if isinstance(fn, ast.Attribute)
-                         else None)
-                if cname != "Knob":
-                    continue
-                kname = None
-                if sub.args and isinstance(
-                        sub.args[0], ast.Constant) and isinstance(
-                        sub.args[0].value, str):
-                    kname = sub.args[0].value
-                else:
-                    for kw in sub.keywords:
-                        if kw.arg == "name" and isinstance(
-                                kw.value, ast.Constant) and isinstance(
-                                kw.value.value, str):
-                            kname = kw.value.value
-                if kname is not None:
-                    decls.append((kname, sub.lineno))
-            self.knob_decls[(ctx.module.relpath, t.id)] = decls
 
     # -- diff the four surfaces ------------------------------------------
     @staticmethod
@@ -169,123 +114,7 @@ class ConfigParityChecker(Checker):
                         chunks.append(sub.value)
         return "\n".join(chunks)
 
-    # -- diff Knob declarations against the tune registry ----------------
-    @staticmethod
-    def _registry_tables(tree: ast.AST) -> dict:
-        """{'SWEEPABLE'|'EXCLUDED': {section: {knob: (lineno, value)}}}
-        from the registry's top-level dict literals."""
-        out: dict = {}
-        for node in ast.walk(tree):
-            if not (isinstance(node, ast.Assign) and any(
-                    isinstance(t, ast.Name)
-                    and t.id in ("SWEEPABLE", "EXCLUDED")
-                    for t in node.targets)):
-                continue
-            name = node.targets[0].id
-            table: dict = {}
-            if isinstance(node.value, ast.Dict):
-                for sk, sv in zip(node.value.keys, node.value.values):
-                    if not (isinstance(sk, ast.Constant) and isinstance(
-                            sk.value, str)
-                            and isinstance(sv, ast.Dict)):
-                        continue
-                    entries: dict = {}
-                    for kk, kv in zip(sv.keys, sv.values):
-                        if isinstance(kk, ast.Constant) and isinstance(
-                                kk.value, str):
-                            try:
-                                val = ast.literal_eval(kv)
-                            except ValueError:
-                                val = None
-                            entries[kk.value] = (kk.lineno, val)
-                    table[sk.value] = entries
-            out[name] = table
-        return out
-
-    def _check_tune_registry(self, project: Project) -> None:
-        reg = project.module(REGISTRY_RELPATH)
-        if reg is None:
-            self.report(REGISTRY_RELPATH, 0, "tune-registry-missing",
-                        "tune registry module not found — the knob "
-                        "inventory the autotuner sweeps")
-            return
-        tables = self._registry_tables(reg.tree)
-        sweep = tables.get("SWEEPABLE", {})
-        excl = tables.get("EXCLUDED", {})
-        if not sweep and not excl:
-            self.report(REGISTRY_RELPATH, 0, "tune-no-tables",
-                        "SWEEPABLE/EXCLUDED dict literals not found — "
-                        "registry refactor? update config_parity.py")
-            return
-
-        declared: dict[str, dict] = {}  # section -> {knob: "path:line"}
-        for section, (relpath, var, lineno) in sorted(
-                self.section_vars.items()):
-            decls = self.knob_decls.get((relpath, var))
-            if decls is None:
-                self.report(relpath, lineno, f"tune-knobs-var:{section}",
-                            f"resolve_section('{section}', {var}, ...) "
-                            f"consumes {var} but no matching _*_KNOBS "
-                            f"tuple declaration was found in {relpath}")
-                continue
-            declared[section] = {k: f"{relpath}:{ln}" for k, ln in decls}
-
-        for section, knobs in sorted(declared.items()):
-            s_tab = sweep.get(section, {})
-            e_tab = excl.get(section, {})
-            for knob, where in sorted(knobs.items()):
-                relpath, _, line = where.rpartition(":")
-                hit_s, hit_e = knob in s_tab, knob in e_tab
-                if hit_s and hit_e:
-                    self.report(
-                        relpath, int(line),
-                        f"tune-both:{section}.{knob}",
-                        f"knob {section}.{knob} is both sweepable and "
-                        f"excluded in the tune registry")
-                elif not (hit_s or hit_e):
-                    self.report(
-                        relpath, int(line),
-                        f"tune-unregistered:{section}.{knob}",
-                        f"knob {section}.{knob} is in neither SWEEPABLE "
-                        f"nor EXCLUDED in {REGISTRY_RELPATH} — declare "
-                        f"a sweep ladder or a justified exclusion")
-            for knob, (line, ladder) in sorted(s_tab.items()):
-                if knob not in knobs:
-                    self.report(
-                        REGISTRY_RELPATH, line,
-                        f"tune-ghost:{section}.{knob}",
-                        f"registry sweeps {section}.{knob} but no such "
-                        f"Knob is declared for the section")
-                elif not (isinstance(ladder, list) and ladder):
-                    self.report(
-                        REGISTRY_RELPATH, line,
-                        f"tune-ladder:{section}.{knob}",
-                        f"sweep ladder for {section}.{knob} must be a "
-                        f"non-empty list literal")
-            for knob, (line, why) in sorted(e_tab.items()):
-                if knob not in knobs:
-                    self.report(
-                        REGISTRY_RELPATH, line,
-                        f"tune-ghost:{section}.{knob}",
-                        f"registry excludes {section}.{knob} but no "
-                        f"such Knob is declared for the section")
-                elif not (isinstance(why, str)
-                          and len(why) >= MIN_JUSTIFICATION):
-                    self.report(
-                        REGISTRY_RELPATH, line,
-                        f"tune-justification:{section}.{knob}",
-                        f"exclusion of {section}.{knob} needs a "
-                        f">= {MIN_JUSTIFICATION} char justification")
-
-        for section in sorted(set(sweep) | set(excl)):
-            if section not in self.section_vars:
-                self.report(
-                    REGISTRY_RELPATH, 0, f"tune-section:{section}",
-                    f"registry section {section} is never resolved via "
-                    f"resolve_section() — stale inventory")
-
     def finish(self, project: Project) -> None:
-        self._check_tune_registry(project)
         cfg = project.module(CONFIG_RELPATH)
         if cfg is None:
             self.report(CONFIG_RELPATH, 0, "missing",

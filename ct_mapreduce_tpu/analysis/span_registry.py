@@ -8,8 +8,8 @@ becomes ``*``) and diff against the backtick-quoted bullets of the
 ``## Trace spans`` sections in ``docs/METRICS.md`` — the same file,
 split by section so span names and metric keys each get exactly one
 registry. Wildcards match both directions, same as metric keys:
-``tools/traceview.py --merge`` timelines and the bench occupancy legs
-key on these names, so an undocumented span is dashboard drift just
+``tools/traceview.py --merge`` timelines and the benchmark's span-ring
+readers key on these names, so an undocumented span is dashboard drift just
 like an undocumented counter.
 """
 

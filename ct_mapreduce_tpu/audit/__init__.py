@@ -33,7 +33,7 @@ from typing import Optional
 from ct_mapreduce_tpu.config import profile as platprofile
 
 _AUDIT_KNOBS = (
-    # Identity/policy knobs — never swept (tune/registry.py EXCLUDED).
+    # Identity/policy knobs.
     platprofile.Knob("auditLogList", "CTMR_AUDIT_LOG_LIST", "",
                      parse=str, is_set=platprofile.nonempty_str),
     platprofile.Knob("auditQuarantineDir", "CTMR_AUDIT_QUARANTINE_DIR",

@@ -47,6 +47,8 @@ import argparse
 import json
 import sys
 
+from ct_mapreduce_tpu.cmd import glue_issuer_ids
+
 
 def _build(args, out) -> int:
     from ct_mapreduce_tpu.agg import merge
@@ -258,7 +260,7 @@ def main(argv: list[str] | None = None, out=None) -> int:
                    choices=("mlbf", "clubcard"))
     c.add_argument("-out", "--out", required=True)
 
-    args = parser.parse_args(argv)
+    args = parser.parse_args(glue_issuer_ids(argv))
     out = out or sys.stdout
     if args.cmd == "build":
         return _build(args, out)

@@ -23,6 +23,7 @@ import argparse
 import json
 import sys
 
+from ct_mapreduce_tpu.cmd import glue_issuer_ids
 from ct_mapreduce_tpu.serve.client import QueryClient, QueryError
 
 
@@ -42,7 +43,7 @@ def main(argv: list[str] | None = None, out=None) -> int:
                         help="fetch query-plane health instead of querying")
     parser.add_argument("-timeoutMs", "--timeoutMs", type=int, default=0,
                         help="per-request deadline (0 = none)")
-    args = parser.parse_args(argv)
+    args = parser.parse_args(glue_issuer_ids(argv))
     out = out or sys.stdout
 
     client = QueryClient(args.addr)

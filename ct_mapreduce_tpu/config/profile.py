@@ -26,16 +26,14 @@ A profile is a JSON file (the ``platformProfile`` directive or the
                "filter":  {"filterFpRate": 0.005},
                "distrib": {"maxDeltaChain": 8}}}
 
-so the autotuner campaign (ROADMAP item 1) emits a versioned data
-file and every subsystem picks its knobs up with zero code changes —
-"a tuned device profile is a data file, not a PR". Knob names inside a
+so a deployment can hand in a versioned data file and every subsystem
+picks its knobs up with zero code changes. Knob names inside a
 section are the directive spellings (``chunksPerDispatch``, not
 ``chunks_per_dispatch``). Unknown sections/knobs are ignored (forward
 compatibility); an unreadable profile warns once and resolves as if
 absent (the config layer's unparseable-value tolerance).
 
-Round 21 (the autotuner, ``ct_mapreduce_tpu/tune/``) grows two
-optional top-level blocks:
+Two optional top-level blocks:
 
 - ``"fingerprint"``: the platform identity the profile was measured
   on (:func:`current_fingerprint` — jax backend, device kind, device
@@ -43,11 +41,10 @@ optional top-level blocks:
   host's fingerprint on the keys BOTH sides carry; a mismatch warns
   once and the profile resolves as if absent — a v5e-tuned profile
   must never silently steer a CPU box (or vice versa). Profiles
-  without the block (round-18 hand-written ones) load as before.
+  without the block load as before.
 - ``"provenance"``: per-knob measurement evidence (curves, reps,
-  wall seconds) written by ``tune/emit.py``. The loader tolerates and
-  ignores it — provenance is for humans and for ``ctmr-tune show``,
-  never for resolution.
+  wall seconds). The loader tolerates and ignores it — provenance is
+  for humans, never for resolution.
 
 The config-parity lint rule covers this layer: every ``CTMR_*`` env
 named in a :class:`Knob` spec must be documented in MIGRATING.md, and
@@ -160,8 +157,7 @@ def load_profile(path: str) -> Optional[dict]:
 
 
 def invalidate_cache(path: Optional[str] = None) -> None:
-    """Drop the load cache for one path (or all): the autotuner emits
-    a profile and immediately resolves through it, and tests rewrite
+    """Drop the load cache for one path (or all): tests rewrite
     profile bytes at a reused path."""
     if path is None:
         _cache.clear()
@@ -212,18 +208,12 @@ class Knob:
     post: Optional[Callable[[Any], Any]] = None
 
 
-# Layer names, in precedence order — the vocabulary `ctmr-tune show`
-# and explain_section() speak.
-LAYERS = ("explicit", "env", "profile", "default")
-
-
-def _resolve_knob(section: str, knob: Knob,
-                  explicit: dict) -> tuple[Any, str]:
-    """One knob through the four-layer ladder: (pre-post value,
-    winning layer name)."""
+def _resolve_knob(section: str, knob: Knob, explicit: dict) -> Any:
+    """One knob through the four-layer ladder (explicit, env, profile,
+    default): the pre-post value."""
     ev = explicit.get(knob.name)
     if ev is not None and knob.is_set(ev):
-        return ev, "explicit"
+        return ev
     if knob.env:
         raw = os.environ.get(knob.env, "")
         if raw:
@@ -233,11 +223,11 @@ def _resolve_knob(section: str, knob: Knob,
                 parsed = None
             test = knob.env_is_set or knob.is_set
             if parsed is not None and test(parsed):
-                return parsed, "env"
+                return parsed
     pv = profile_value(section, knob.name)
     if pv is not None and knob.is_set(pv):
-        return pv, "profile"
-    return knob.default, "default"
+        return pv
+    return knob.default
 
 
 def resolve_section(section: str, knobs: tuple,
@@ -247,26 +237,10 @@ def resolve_section(section: str, knobs: tuple,
     strings)."""
     out = {}
     for knob in knobs:
-        value, _ = _resolve_knob(section, knob, explicit)
+        value = _resolve_knob(section, knob, explicit)
         if knob.post is not None:
             value = knob.post(value)
         out[knob.name] = value
-    return out
-
-
-def explain_section(section: str, knobs: tuple,
-                    explicit: Optional[dict] = None) -> dict:
-    """The debuggability half of the ladder (`ctmr-tune show`): the
-    SAME resolution as :func:`resolve_section`, but each knob maps to
-    ``{"value": <post-processed>, "layer": <winning layer>}`` so an
-    operator can see which of explicit/env/profile/default actually
-    decided every knob."""
-    out = {}
-    for knob in knobs:
-        value, layer = _resolve_knob(section, knob, explicit or {})
-        if knob.post is not None:
-            value = knob.post(value)
-        out[knob.name] = {"value": value, "layer": layer}
     return out
 
 

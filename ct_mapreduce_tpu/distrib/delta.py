@@ -12,7 +12,7 @@ re-serializes under (mixed-format deltas are a loud
 upstream of the codec: per-group-universe ``CTMRFL02`` artifacts
 confine churn to the touched groups, so untouched groups diff equal
 and ship ZERO bytes — no sparse-XOR salvage of globally-reshaped
-layers needed (the CTMRDL01 structural floor BENCHLOG r19 measured).
+layers needed (the CTMRDL01 structural floor round 19 measured).
 
 - **removed** — (issuer, expDate) groups present in the base but not
   the target;

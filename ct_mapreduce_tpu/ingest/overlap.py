@@ -1,6 +1,6 @@
 """Overlapped ingest: decode ‖ H2D/device ‖ drain as a staged pipeline.
 
-The round-5 e2e budget (BENCH_r05.json) was almost perfectly
+The round-5 e2e budget (July installation) was almost perfectly
 serialized: decode 26.1 s, device wait 21.4 s, drain 4.9 s of a 57.6 s
 wall for 2M entries — the device idle more than half the time while
 the host decoded, the classic host-feed bottleneck that deep request
@@ -30,7 +30,7 @@ order the producer handed them in (decode runs ahead out of order, a
 reorder point at the submit thread restores it), and completions are
 FIFO — so the dedup table sees the same insertion order as the serial
 path and results are parity-identical (asserted by
-tests/test_overlap.py and the bench smoke gate).
+tests/test_overlap.py).
 
 Failure contract: a stage exception (decode worker raise, submit
 failure, drain failure) latches the pipeline into a failed state —
@@ -111,12 +111,12 @@ class OverlapIngestPipeline:
         self._prepared_sem = threading.BoundedSemaphore(self._max_prepared)
         self._closed = False
         # Per-stage busy seconds (wall time spent inside the stage) —
-        # the occupancy gauges bench.py reports. Busy sums exceeding
-        # the wall clock is the overlap actually happening. "lock" is
-        # the submit thread's wait for the sink's dispatch lock —
-        # sampled SEPARATELY so the submit gauge (and the bench's
-        # storeCertificate-derived dispatch budget) measures submit
-        # work, not lock contention.
+        # the occupancy gauges. Busy sums exceeding the wall clock is
+        # the overlap actually happening. "lock" is the submit
+        # thread's wait for the sink's dispatch lock — sampled
+        # SEPARATELY so the submit gauge (and a dispatch budget
+        # derived from storeCertificate) measures submit work, not
+        # lock contention.
         self.busy = {"decode": 0.0, "submit": 0.0, "drain": 0.0,
                      "lock": 0.0}
         self._busy_lock = threading.Lock()
@@ -302,7 +302,7 @@ class OverlapIngestPipeline:
             # storeCertificate envelope (its own busy bucket + the
             # dispatchLockWait sample): lock contention is not submit
             # work, and folding it in overstated the submit occupancy
-            # gauge / the bench's e2e dispatch budget.
+            # gauge and any dispatch budget derived from it.
             t_lock = time.perf_counter()
             try:
                 with trace.span("ingest.submit_locked", cat="ingest",

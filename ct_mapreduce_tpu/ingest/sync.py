@@ -830,10 +830,9 @@ class AggregatorSink:
             self._overlap.drain_all()
         # Same storeCertificate envelope as the dispatch path, so every
         # completeBatch sample is NESTED inside a storeCertificate
-        # sample — the bench's budget breakdown subtracts one from the
-        # other and flush-path completes must not skew it. (In overlap
-        # mode completes are NOT nested — they run on the drain thread
-        # — and the bench computes the budget accordingly.)
+        # sample — a budget breakdown subtracts one from the other and
+        # flush-path completes must not skew it. (In overlap mode
+        # completes are NOT nested — they run on the drain thread.)
         t_lock = time.monotonic()
         with self._dispatch_lock:
             metrics.add_sample("ct-fetch", "dispatchLockWait",
@@ -1002,8 +1001,7 @@ class RawBatch:
     (ct_mapreduce_tpu.native.leafpack) with no per-entry Python.
 
     ``page`` is the response: two lists of base64 strings
-    (``leafpack.StrPage``: tests, the bench, the audit driver and the
-    tuner build it so) or its bytes with where each value lies in them
+    (``leafpack.StrPage``: tests and the audit driver build it so) or its bytes with where each value lies in them
     (``leafpack.EntryPage``: what the downloader enqueues). Reading
     ``leaf_inputs`` or ``extra_datas`` of the second kind cuts the
     strings out of the body and makes it the first kind from then on,

@@ -327,8 +327,7 @@ class EntryPage:
 @dataclass
 class StrPage:
     """One get-entries response as two lists of base64 strings, an item
-    an entry: what tests, the bench, the audit driver and the tuner
-    hand the sink. The same reading interface as :class:`EntryPage`."""
+    an entry: what tests and the audit driver hand the sink. The same reading interface as :class:`EntryPage`."""
 
     leaf_inputs: Sequence
     extra_datas: Sequence
@@ -536,8 +535,8 @@ def _decode_raw_pages(
     import os
 
     # CTMR_NATIVE=0 forces the pure-Python lane (read per call, not at
-    # load: the bench's CPU smoke flips it mid-process to rebalance the
-    # decode stage; results are byte-identical by the conformance suite).
+    # load: a test may flip it mid-process; results are byte-identical
+    # by the conformance suite).
     lib = (None if os.environ.get("CTMR_NATIVE", "1") == "0"
            else load_native())
     if lib is None:

@@ -4,7 +4,7 @@ Drop-in alternative to :mod:`ct_mapreduce_tpu.ops.hashtable` (same
 Redis-SADD semantics as the reference's per-certificate ``WasUnknown``
 round trip, /root/reference/storage/knowncertificates.go:38-55), built
 from the primitives the hardware actually favors. Measured on one
-v5e chip at 2^20 lanes (tools/randacc.py, docs/randacc_r04_run.log):
+v5e chip at 2^20 lanes (July installation; docs/randacc_r04_run.log):
 
   gather/scatter of 5-word rows:   13.6 / 86.5 ns per lane
   gather/scatter of 128-word rows: 12.0 / 11.8 ns per lane
@@ -108,8 +108,8 @@ WINDOW = _window_from_env()
 #:   scan-only — occupancy scan AND skip the fill-word write (the
 #:               exact round-4 program, for A/B timing)
 #:
-#: MEASURED (round 5, tools/insertcost.py at 2^20 lanes / cap 2^26 on
-#: one v5e): scan-only 65.8, scan 66.5, cache 133 ns/entry. Writing
+#: MEASURED (round 5, July installation, at 2^20 lanes / cap 2^26 on
+#: one v5e; the probe is in git history): scan-only 65.8, scan 66.5, cache 133 ns/entry. Writing
 #: the cached count is free; READING it in place of the occupancy
 #: scan — the "obvious" win — DOUBLES insert cost (the single-column
 #: read replaces a reduce that XLA fused into the gather, and the
@@ -373,8 +373,8 @@ def insert(
         # (no [B, 1] materialization — see the layout rule above), and
         # candidates hold distinct slots, so the wheres commute.
         #
-        # NOTE (round-5 negative result, measured via tools/insertcost
-        # A/B on one v5e): a "cheaper" two-pass variant — build each
+        # NOTE (round-5 negative result, an insert-cost A/B on one v5e,
+        # July installation, git history): a "cheaper" two-pass variant — build each
         # lane's own candidate block once, then OR the WINDOW-1
         # following lanes' blocks into the head via [B, 128] row shifts
         # — DOUBLED insert cost (130 vs 66 ns/entry at 2^20 lanes).
@@ -446,7 +446,7 @@ def insert(
     # Unsort the per-lane outcome by SORTING on the carried lane ids
     # (a permutation of 0..b-1, so the sort reproduces lane order
     # exactly). A sort is the cheap primitive on this hardware — 2.6
-    # vs 13 ns/lane for the equivalent scatter (tools/randacc.py).
+    # vs 13 ns/lane for the equivalent scatter (July installation).
     # Lanes that left the loop still pending (round budget) also
     # overflow.
     res_sorted = (

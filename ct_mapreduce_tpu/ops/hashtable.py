@@ -5,7 +5,8 @@ NOTE: since round 4 this slot-granular layout is the FALLBACK
 :mod:`ct_mapreduce_tpu.ops.buckettable`, whose measured insert is
 ~10x cheaper on v5e (709 vs ~68 ns/entry at 2^20 lanes — this
 module's per-round 5-word row scatter alone prices at 86.5 ns/lane
-from tile-misalignment; see tools/randacc.py and BENCHLOG round 4).
+from tile-misalignment; July installation's random-access probe,
+git history).
 Kept for layout comparisons and pre-round-4 checkpoint compatibility.
 
 This is the TPU-native replacement for the reference's per-certificate
@@ -330,8 +331,8 @@ def contains(state: TableState, keys: jax.Array, max_probes: int = 32) -> jax.Ar
     positions per gather, with a ``while_loop`` that exits as soon as
     every lane has hit a match or an empty slot — the common case is
     ONE table gather, not ``max_probes`` of them (random-access table
-    ops are latency-priced per lane on TPU: ~13-15 ns/lane measured,
-    tools/randacc.py)."""
+    ops are latency-priced per lane on TPU: ~13-15 ns/lane measured on
+    the July installation, git history)."""
     capacity = state.rows.shape[0]
     keys = _desentinel(keys.astype(jnp.uint32))
     home = _home_slot(keys, capacity)

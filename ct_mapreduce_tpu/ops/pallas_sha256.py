@@ -33,7 +33,7 @@ from ct_mapreduce_tpu.ops.sha256 import _H0, _K
 # Lanes per grid step. The r03 hardware number (0.50 ms @ 16,384 lanes)
 # sits ~30x above the VPU's theoretical throughput for 64 unrolled
 # rounds, which smells like per-grid-step overhead — CTMR_SHA_TILE
-# exists so tools/sha_sweep.py can measure the tile curve on hardware
+# exists so that the tile curve can be measured on hardware
 # (VMEM comfortably fits tiles up to ~16K: [16, T] block + [8, T] out
 # + ~24 live [T] vectors ≈ 2.9 MB at T=8192).
 LANE_TILE = 512  # shipped default: the r03-measured configuration

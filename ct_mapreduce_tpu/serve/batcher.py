@@ -3,7 +3,7 @@
 The device ``contains`` kernels (and their vectorized host mirrors)
 are batch ops: one probe of 512 lanes costs barely more than one probe
 of 1 — random-access table reads are latency-priced per DISPATCH, not
-per lane (tools/randacc.py). An online query plane therefore wants the
+per lane (July installation, git history). An online query plane therefore wants the
 inference-serving discipline: concurrent single-key requests coalesce
 into one batch, bounded by a max batch size and a max delay, with
 admission control so overload sheds loudly instead of queueing without
@@ -19,8 +19,7 @@ and scatters results back. Guarantees:
 - **Bounded wait.** A request waits at most ``max_delay_s`` for its
   batch to form, plus at most one in-flight batch execution before its
   own runs (single worker, FIFO) — so p99 wait ≤ max_delay + ~2×batch
-  execution, asserted from the ``serve.wait``/``serve.batch`` spans by
-  the bench serve leg.
+  execution, readable from the ``serve.wait``/``serve.batch`` spans.
 - **Bounded queue.** Admission beyond ``max_queue_lanes`` queued lanes
   raises :class:`Overloaded` immediately (the ``serve.shed`` counter);
   nothing is silently dropped and nothing queues unboundedly.

@@ -23,7 +23,7 @@ Endpoints:
   clients need no direct log access.
 
 The oracle half (:class:`MembershipOracle`) is independent of HTTP —
-the bench serve leg and tests drive it in-process — and composes the
+tests drive it in-process — and composes the
 three serving primitives, hottest first:
 :class:`~ct_mapreduce_tpu.serve.cache.HotSerialCache` (memoized
 answers, epoch-floor validated), :class:`~ct_mapreduce_tpu.serve.

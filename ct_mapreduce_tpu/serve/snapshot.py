@@ -408,7 +408,7 @@ def capture_view(agg, epoch: int, device: bool = False) -> TableView:
 class ReplicaPool:
     """N epoch-pinned views serving round-robin with STAGGERED refresh
     — the query plane's one manager of views, and its answer to "serve
-    and ingest share a core" (BENCHLOG round 10).
+    and ingest share a core" (round 10).
 
     Every replica is a full, individually consistent :class:`TableView`
     whose device copy is made at capture time, on the device, and
@@ -501,8 +501,8 @@ class ReplicaPool:
             return self._refresh_holding_lock()
 
     def warm(self) -> "ReplicaPool":
-        """Fill every pool slot synchronously (bench/sweep setup, so
-        the timed window never includes a capture)."""
+        """Fill every pool slot synchronously (set-up, so that a
+        timed window never includes a capture)."""
         while True:
             with self._lock:
                 if len(self._replicas) >= self.n_replicas:

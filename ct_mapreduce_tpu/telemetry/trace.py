@@ -42,7 +42,7 @@ are per-CHUNK, but the disabled path must still cost nothing):
   many, so a reader can refuse a window it did not see whole.
 
 Enabling: the ``CTMR_TRACE=<path>`` environment variable (read at
-import, so every entry point — ct-fetch, bench, tests — gets it for
+import, so every entry point — ct-fetch, tests — gets it for
 free) or the ``tracePath`` config directive / an explicit
 :func:`enable` call. When a path is set, the ring is exported there at
 interpreter exit; callers may also :func:`export` eagerly.

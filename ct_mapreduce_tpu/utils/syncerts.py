@@ -314,7 +314,7 @@ def build_device_batches(
     arrays. A per-(batch, lane) uint32 counter (``g * batch + lane``,
     big-endian) is stamped into serial content bytes 12..16 — unique up
     to 2^32 lanes; bytes 4..8 are left zero for callers that restamp a
-    per-sweep epoch on device (bench.py's mega_step). H2D traffic is
+    per-sweep epoch on device. H2D traffic is
     one ~1 KB template row instead of gigabytes of host-stamped rows.
     """
     import jax
@@ -394,96 +394,3 @@ def zipf_weights(n: int, s: float = 1.1) -> np.ndarray:
     """Zipf issuer split (CT reality: a handful of CAs dominate)."""
     w = 1.0 / np.arange(1, n + 1, dtype=np.float64) ** s
     return w / w.sum()
-
-
-@dataclass
-class MixedBatchSet:
-    """Device-resident mixed-template batches + the per-lane stamping
-    metadata benchmark steps need."""
-
-    datas: "object"  # uint8[G, B, pad] device array
-    lens: "object"  # int32[G, B] device array
-    issuer_idx: np.ndarray  # int32[B] — registry index per lane
-    epoch_cols: np.ndarray  # int32[B, 3] — serial bytes 1..4 per lane
-    template_of: np.ndarray  # int32[B]
-    templates: list  # list[CertTemplate]
-
-
-def build_mixed_device_batches(
-    templates: list[CertTemplate],
-    weights: np.ndarray,
-    n_batches: int,
-    batch: int,
-    pad_len: int,
-    seed: int = 0,
-) -> MixedBatchSet:
-    """Resident batches mixing several templates (issuers, key types,
-    serial lengths) in one device batch — the realistic-mix benchmark
-    shape (real CT streams interleave RSA/ECDSA certs of many CAs,
-    /root/reference/cmd/ct-fetch/ct-fetch.go:416-424).
-
-    Stamping schema, uniform across serial lengths 8..20: serial
-    content byte 0 stays the template's positive 0x4D; bytes 1..4 are
-    the per-sweep epoch window (24 bits, restamped on device by the
-    bench step via ``epoch_cols``); the LAST 4 bytes are the lane
-    counter. Disjoint for every length >= 8.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    rng = np.random.RandomState(seed)
-    t_count = len(templates)
-    if t_count < 1:
-        raise ValueError("need at least one template")
-    w = np.asarray(weights, np.float64)
-    w = w / w.sum()
-    template_of = rng.choice(t_count, size=batch, p=w).astype(np.int32)
-
-    tpl_rows = np.zeros((t_count, pad_len), np.uint8)
-    tpl_lens = np.zeros((t_count,), np.int32)
-    ser_off = np.zeros((t_count,), np.int32)
-    ser_len = np.zeros((t_count,), np.int32)
-    for i, t in enumerate(templates):
-        raw = np.frombuffer(t.leaf_der, dtype=np.uint8)
-        if raw.size > pad_len:
-            raise ValueError(
-                f"template {i} ({raw.size}B) exceeds pad length {pad_len}")
-        tpl_rows[i, : raw.size] = raw
-        tpl_lens[i] = raw.size
-        ser_off[i] = t.serial_off
-        ser_len[i] = t.serial_len
-
-    off_of = ser_off[template_of]  # int32[B]
-    lane_cols = (off_of[:, None] + ser_len[template_of][:, None] - 4
-                 + np.arange(4, dtype=np.int32)[None, :])  # [B, 4]
-    epoch_cols = off_of[:, None] + np.arange(1, 4, dtype=np.int32)[None, :]
-
-    @jax.jit
-    def build(tpl_rows, template_of, lane_cols):
-        data = tpl_rows[template_of]  # [B, pad] gather
-        data = jnp.broadcast_to(data, (n_batches,) + data.shape)
-        cnt = (jnp.arange(n_batches, dtype=jnp.uint32)[:, None] * batch
-               + jnp.arange(batch, dtype=jnp.uint32)[None, :])
-        cb = jnp.stack(
-            [(cnt >> 24) & 0xFF, (cnt >> 16) & 0xFF,
-             (cnt >> 8) & 0xFF, cnt & 0xFF], axis=-1
-        ).astype(jnp.uint8)  # [G, B, 4]
-        rows_ix = jnp.arange(batch, dtype=jnp.int32)[None, :, None]
-        return data.at[
-            jnp.arange(n_batches, dtype=jnp.int32)[:, None, None],
-            rows_ix, lane_cols[None, :, :],
-        ].set(cb)
-
-    datas = build(jax.device_put(tpl_rows), jax.device_put(template_of),
-                  jax.device_put(lane_cols))
-    lens = jnp.broadcast_to(
-        jnp.asarray(tpl_lens[template_of], dtype=jnp.int32)[None, :],
-        (n_batches, batch))
-    return MixedBatchSet(
-        datas=datas,
-        lens=lens,
-        issuer_idx=template_of.copy(),
-        epoch_cols=epoch_cols.astype(np.int32),
-        template_of=template_of,
-        templates=list(templates),
-    )

@@ -227,7 +227,7 @@ class SignatureVerifier:
         self._inflight: deque[_PendingVerify] = deque()
         set_gauge("verify", "precomp_window", value=float(self.window))
         # Scalar outcomes (also exported as verify.* counters; kept
-        # here so tests and the bench leg can read exact totals).
+        # here so tests can read exact totals).
         self.stats = {
             "device_lanes": 0, "host_lanes": 0, "no_sct": 0,
             "no_key": 0, "verified": 0, "failed": 0, "batches": 0,

@@ -48,6 +48,33 @@ from ct_mapreduce_tpu.analysis import witness as _lock_witness  # noqa: E402
 _lock_witness.install()
 
 
+@pytest.fixture
+def benchmark_checkout(tmp_path, monkeypatch, request):
+    """For the modules that run ``benchmark/tests`` in tier-1 (each names
+    the module it imported from there ``theirs``). The harness keeps a run's
+    state in ``<checkout>/.bench_work``, one a checkout, so rehearsals on
+    two workers would wipe each other's. Each test gets a directory
+    shaped like the checkout (links to ``benchmark/``, the package and
+    ``BENCHMARK.json``) and the imported tests find their files from
+    there: ``benchmark/`` computes every path from where its files lie.
+    A rehearsal's child is a whole ``ct-fetch`` run of a one-chip cell,
+    so it must not inherit the eight virtual devices asked for above (a
+    mesh over them is another, far slower program)."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    root = tmp_path / "checkout"
+    root.mkdir()
+    for name in ("benchmark", "ct_mapreduce_tpu", "BENCHMARK.json"):
+        os.symlink(os.path.join(repo, name), root / name)
+    bench = os.path.join(str(root), "benchmark")
+    theirs = request.module.theirs
+    monkeypatch.setattr(theirs, "ROOT", str(root))
+    monkeypatch.setattr(theirs, "BENCH", bench)
+    monkeypatch.setattr(theirs, "HERE", os.path.join(bench, "tests"))
+    monkeypatch.setenv("XLA_FLAGS", " ".join(
+        f for f in os.environ.get("XLA_FLAGS", "").split()
+        if "xla_force_host_platform_device_count" not in f))
+
+
 def on_tpu() -> bool:
     import jax
 

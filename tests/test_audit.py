@@ -30,7 +30,6 @@ from ct_mapreduce_tpu.ingest import leaf as leaflib
 from ct_mapreduce_tpu.verify import host as vhost
 from ct_mapreduce_tpu.verify import sct as sctlib
 
-from tests import certgen
 
 UTC = datetime.timezone.utc
 FUTURE = datetime.datetime(2031, 6, 15, tzinfo=UTC)
@@ -108,12 +107,22 @@ def _with_exts(der: bytes, fn) -> bytes:
         0x30, sctlib._wrap_tlv(0x30, body) + rest)
 
 
+def _kat_cert(**kw) -> bytes:
+    """RNG-free bytes for the pinned digests: `certgen.make_cert` signs
+    with a fresh random key wherever `cryptography` is installed."""
+    from ct_mapreduce_tpu.utils import minicert
+
+    return minicert.make_cert(
+        org="Unit Test Corp", country="US", serial_len=None,
+        spki_seed="certgen-key:0",
+        not_before=datetime.datetime(2024, 1, 1, tzinfo=UTC),
+        not_after=FUTURE, **kw)
+
+
 def _kat_materials():
-    issuer = certgen.make_cert(
-        serial=1, issuer_cn="KAT CA", is_ca=True, not_after=FUTURE)
-    leaf = certgen.make_cert(
-        serial=7, issuer_cn="KAT CA", subject_cn="kat.example",
-        is_ca=False, not_after=FUTURE)
+    issuer = _kat_cert(serial=1, issuer_cn="KAT CA", is_ca=True)
+    leaf = _kat_cert(serial=7, issuer_cn="KAT CA",
+                     subject_cn="kat.example", is_ca=False)
     signer = loglistlib.adopt_production_id(
         sctlib.EcSctSigner("audit-kat"))
     der = sctlib.attach_sct(leaf, signer, TS_KAT, issuer_der=issuer)
@@ -571,6 +580,57 @@ def test_driver_tile_scaling():
     assert rep.unknown_log == 3 * e["unknown_log"]
     assert sum(rep.per_log.values()) == 3 * e["sct_lanes"]
     assert sum(v for v, _ in rep.per_issuer.values()) == 3 * e["verified"]
+
+
+def test_checked_in_shard_tallies_and_per_issuer_oracle():
+    """The checked-in 1,024-entry shard through the whole audit path:
+    every tally is the fixture's ground truth, nothing is quarantined
+    on the real corpus (the native scanner and its mirror agree on
+    every lane, and the divergence was measured where the scanner
+    exists), and the per-issuer verified/failed folds equal a
+    reference recomputed lane by lane on the host with the pure-python
+    verifier."""
+    path = os.path.join(os.path.dirname(__file__), "data",
+                        "recorded_shard.json.gz")
+    doc = drvlib.load_recorded(path)
+    log_list = loglistlib.parse_log_list(doc["log_list"])
+    drv = drvlib.AuditDriver(log_list, batch_size=16, flush_size=16,
+                             batch_width=32)
+    rep = drv.run_recorded(doc)
+
+    want = fxlib.expected_tallies()
+    got = {"entries": rep.entries, "sct_lanes": rep.sct_lanes,
+           "no_sct": rep.no_sct, "verified": rep.verified,
+           "failed": rep.failed, "no_key": rep.verifier_no_key,
+           "device_lanes": rep.device_lanes, "host_lanes": rep.host_lanes,
+           "retired": rep.retired, "out_of_interval": rep.out_of_interval,
+           "unknown_log": rep.unknown_log}
+    assert got == {name: want[name] for name in got}
+    assert min(got.values()) > 0  # every lane class is in the corpus
+    assert rep.quarantined == 0
+    if _native_sct_available():
+        assert rep.divergence_measured
+
+    reg = log_list.registry()
+    oracle: dict = {}
+    for page in doc["pages"]:
+        start = int(page.get("start", 0))
+        for i, e in enumerate(page["entries"]):
+            dec = leaflib.decode_json_entry(start + i, e)
+            ikh = (sctlib.issuer_key_hash_of(dec.issuer_der)
+                   if dec.issuer_der else sctlib.ZERO_IKH)
+            status, sct, digest, _, _ = sctlib.extract_sct_lane(
+                dec.cert_der, ikh)
+            if status == sctlib.SCT_NONE or sct is None:
+                continue
+            key = reg.get(sct.log_id)
+            if key is None:
+                continue  # no_key lanes fold into no per-issuer row
+            ok = sctlib.host_verify_sct(digest, sct, key)
+            v, f = oracle.get(ikh, (0, 0))
+            oracle[ikh] = (v + int(ok), f + int(not ok))
+    assert len(rep.per_issuer) == 8
+    assert sorted(rep.per_issuer.values()) == sorted(oracle.values())
 
 
 def test_driver_emits_filter_artifact(tmp_path):

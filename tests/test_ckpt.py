@@ -3,7 +3,7 @@
 The contract under test: a versioned chain — one full **base**
 snapshot plus append-only **delta segments** carrying only each epoch
 tick's churn — restores STATE-IDENTICAL to the ck01 full-save oracle
-(tune.harness.ckpt_state_digest), stays bounded by ``ckptMaxChain``
+(tests/ckptstate.py::ckpt_state_digest), stays bounded by ``ckptMaxChain``
 via compaction anchors, survives tampering/truncation with loud
 ``CkptError``s (a listed-but-broken chain must never half-load), heals
 the one legal stale artifact (a manifest older than its base), and a
@@ -32,7 +32,7 @@ if os.environ.get("CT_TPU_TESTS", "") == "":
 
 from ct_mapreduce_tpu.agg import ckpt
 from ct_mapreduce_tpu.agg.aggregator import HostSnapshotAggregator
-from ct_mapreduce_tpu.tune import harness
+from tests import ckptstate as harness
 
 ENTRIES = 400
 BITS = 12
@@ -88,7 +88,6 @@ def test_segment_codec_rejects_corruption():
 # -- chain round trip vs the ck01 oracle ---------------------------------
 
 
-@pytest.mark.slow
 def test_chain_restore_matches_ck01_oracle(tmp_path):
     agg, eh, path = _mk(tmp_path)
     agg.save_checkpoint(path)          # base
@@ -332,7 +331,7 @@ _KILL_CHILD = textwrap.dedent("""
     sys.path.insert(0, sys.argv[1])
     path, point = sys.argv[2], sys.argv[3]
 
-    from ct_mapreduce_tpu.tune import harness
+    from tests import ckptstate as harness
 
     agg, eh = harness.build_aggregator(400, 12)
     agg.configure_checkpointing(mode="ck02")

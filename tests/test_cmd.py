@@ -517,6 +517,21 @@ def test_ct_query_cli(capsys):
     assert rc == 2
 
 
+@pytest.mark.parametrize("flag", ["-issuer", "-issuerMeta"])
+def test_ct_query_takes_an_issuer_id_that_begins_with_a_dash(flag):
+    """One issuerID in 64 begins with ``-`` (base64url). Such an ID after
+    its flag is a value, not an option: the call gets as far as the
+    plane, which is not there (2), and is not refused as usage
+    (``SystemExit``)."""
+    from ct_mapreduce_tpu.cmd import ct_query
+
+    argv = ["-addr", "127.0.0.1:1", flag,
+            "-5T3W1fDwyr37nUKmB4sOcdnSGqMysFozk-41H4WBtc="]
+    if flag == "-issuer":
+        argv += ["-expDate", "2031-06-15-00", "-serial", "4d00"]
+    assert ct_query.main(argv, out=io.StringIO()) == 2
+
+
 def test_ct_fetch_starts_query_plane(tmp_path, monkeypatch):
     """queryPort on ct-fetch: the query plane answers membership for
     the serials the run just ingested — asserted from inside the run

@@ -391,3 +391,56 @@ def test_platform_profile_feeds_every_resolver(tmp_path, monkeypatch):
     usage = CTConfig().usage()
     for d in ("platformProfile", "distribHistory", "maxDeltaChain"):
         assert d in usage
+
+
+def _profile_file(tmp_path, name: str, doc: dict) -> str:
+    import json
+
+    path = str(tmp_path / name)
+    with open(path, "w") as fh:
+        json.dump(doc, fh)
+    return path
+
+
+def test_fingerprint_match_and_mismatch(tmp_path):
+    import os
+
+    from ct_mapreduce_tpu.config import profile as platprofile
+
+    base = {"version": 1, "knobs": {"staging": {"stagingDepth": 3}}}
+    ok = _profile_file(tmp_path, "ok.json", dict(
+        base, fingerprint={"host_cores": os.cpu_count() or 1}))
+    bad = _profile_file(tmp_path, "bad.json", dict(
+        base, fingerprint={"host_cores": -1}))
+    legacy = _profile_file(tmp_path, "legacy.json", base)  # no fingerprint
+    try:
+        assert platprofile.load_profile(ok) is not None
+        assert platprofile.load_profile(bad) is None  # warn + ignore
+        assert platprofile.load_profile(legacy) is not None
+        # Partial fingerprints compare only shared keys.
+        assert platprofile.fingerprint_matches({})
+        assert platprofile.fingerprint_matches(
+            {"unknown_key": "whatever"})
+        assert not platprofile.fingerprint_matches(
+            {"host_cores": -1}, {"host_cores": 4})
+    finally:
+        platprofile.invalidate_cache()
+
+
+def test_provenance_tolerant_load(tmp_path):
+    from ct_mapreduce_tpu.config import profile as platprofile
+
+    base = {"version": 1, "knobs": {"staging": {"stagingDepth": 2}}}
+    odd = _profile_file(tmp_path, "odd.json", dict(
+        base, provenance={
+            "future_section": {"future_measure": {"anything": [1]}}},
+        extra_future_block=42))
+    bad = _profile_file(tmp_path, "bad.json", dict(
+        base, provenance=["not", "a", "dict"]))
+    try:
+        loaded = platprofile.load_profile(odd)
+        assert loaded is not None  # unknown provenance content is fine
+        assert loaded["knobs"]["staging"]["stagingDepth"] == 2
+        assert platprofile.load_profile(bad) is None  # wrong shape
+    finally:
+        platprofile.invalidate_cache()

@@ -602,6 +602,27 @@ def test_zstd_encoding_leg(served_pair):
     assert wire[0] == wire[1]
 
 
+def test_pull_storm_over_two_workers_moves_deltas_not_artifacts():
+    """A small client storm against two serving workers, counted in
+    pulls and bytes (no clock): the workers serve identical bytes
+    (``run_storm`` raises before the storm otherwise, and validates
+    sampled delta chains against the full artifact), every pull class
+    occurs, warm clients revalidate with 304s, and the clients that
+    held an earlier epoch fetch under a fifth of what full pulls would
+    have cost them."""
+    from tools import pullstorm
+
+    report = pullstorm.run_storm(
+        clients=200, epochs=4, groups=24, per_group=30, churn=2,
+        workers=2, threads=8, validate_every=10)
+    assert report["worker_parity"] == 1 and report["workers"] == 2
+    for kind in ("304", "delta", "full"):
+        assert report["pulls"][kind]["count"] > 0, report["pulls"]
+    assert report["ratio_304"] > 0.1
+    assert report["delta_304_vs_full"] < 0.20
+    assert report["wire_vs_counterfactual"] < 0.5
+
+
 def test_pullstorm_force_zstd_flag():
     """`tools/pullstorm.py --force-zstd` drives every compressible
     pull through zstd end to end (skips without the module; the flag

@@ -16,8 +16,7 @@ its python mirror (verify/sct.extract_scts_np).
 Compile budget: each (curve, window, width) shape is its own ~15-20 s
 XLA compile on the 1-core CI box, so tier-1 pays exactly THREE
 compiles — legacy P-256, windowed P-256, windowed P-384, all at the
-shared width 32 (and the lane tests + bench smoke reuse the windowed
-ones). The multi-window/multi-width sweeps and the 416-case fuzz
+shared width 32 (and the lane tests reuse the windowed ones). The multi-window/multi-width sweeps and the 416-case fuzz
 matrix run as ``slow`` tests.
 """
 
@@ -265,7 +264,7 @@ def test_p384_known_answer_corpus():
     """The P-384 device lane's own KAT corpus (full edge classes +
     windowed edges), verdict-bit-identical to the host reference —
     the ONE tier-1 P-384 compile (windowed, width 32; the lane tests
-    and bench smoke reuse it)."""
+    reuse it)."""
     cases = _kat_corpus(C384) + _window_edge_corpus(C384)
     _run_corpus(cases, window=W, c=C384)
 
@@ -307,8 +306,7 @@ def test_mutation_fuzz_device_host_parity(window, curve):
 
     @slow since round 15 (tier-1 budget banking): the verdict-parity
     contract stays tier-1-gated by the KAT corpora, the windowed-edge
-    and zero-lane-isolation batches, and the CT_BENCH_SMOKE verify
-    leg; this sweep re-walks the same kernels per configuration."""
+    and zero-lane-isolation batches, and tests/test_verify_lane.py; this sweep re-walks the same kernels per configuration."""
     c = C if curve == "p256" else C384
     nbits = 8 * c.byte_len
     rng = random.Random(0x5C7 + window)

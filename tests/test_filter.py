@@ -156,6 +156,38 @@ def test_zero_false_negatives_across_layouts(monkeypatch, layout, grow):
         assert art.query(iss, eh, sb), (iss, eh, sb.hex())
 
 
+def test_capture_is_the_report_and_false_positives_stay_in_budget():
+    """Two things the emitted artifact owes its readers beyond zero
+    false negatives: the capture it is built from equals the drained
+    report group for group (a replayed duplicate counts once in both),
+    and serials never fed (21 bytes long, so no probe can be an
+    included identity) read known at no more than twice the target
+    rate, from a cascade and not a list of fingerprints."""
+    fp_rate = 0.01
+    agg = TpuAggregator(capacity=1 << 10, batch_size=64)
+    agg.enable_filter_capture()
+    agg.ingest(corpus(n=300, dupes=40))
+    agg.ingest(corpus(n=200, dupes=20, issuer_cn="Filter CA B",
+                      issuer=ISSUER_DER_B, base=5000))
+    snap = agg.drain()
+    captured = {
+        (agg.registry.issuer_at(idx).id(), ExpDate.from_unix_hour(eh).id()):
+            len(serials)
+        for (idx, eh), serials in agg.filter_capture.items()}
+    assert captured == dict(snap.counts)
+    assert sorted(captured.values()) == [200, 300]
+
+    art = build_from_aggregator(agg, fp_rate=fp_rate)
+    rng = np.random.default_rng(20260805)
+    probes = [rng.integers(0, 256, 21, dtype=np.uint8).tobytes()
+              for _ in range(4000)]
+    hits = sum(int(np.asarray(art.query_group(g, probes)).sum())
+               for _key, g in sorted(art.groups.items()))
+    assert hits / (len(probes) * len(art.groups)) <= 2 * fp_rate
+    assert 0 < art.bits_per_entry() < 64
+    assert art.max_layers() >= 1
+
+
 def test_zero_false_negatives_sharded_layout():
     import jax
     from jax.sharding import Mesh

@@ -362,7 +362,7 @@ def test_dl02_untouched_groups_ship_zero_bytes():
     # The wire cost is one group's block plus the JSON header; at
     # fixture scale the header dominates, so only pin that the link
     # undercuts the full artifact — the ≤3% ratio is measured at 10⁷
-    # by tools/filtercost.py --delta (BENCHLOG round 20).
+    # by tools/filtercost.py --delta (round 20).
     assert header["payloadBytes"] < len(b2) / 3
     assert len(link) < len(b2)
 

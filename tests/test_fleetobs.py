@@ -6,8 +6,8 @@ batcher's trace-context adoption.
 
 The live W=2 cross-process legs (one merged timeline across worker
 pids, fleet-scrape parity against per-worker scrapes, SIGSTOP →
-rollup 503) run in bench.run_obs_smoke, gated by
-tests/test_bench_smoke.py; everything here is in-process."""
+rollup 503) are tests/test_multiprocess.py::
+test_fleet_observability_plane_live; everything here is in-process."""
 
 import http.server
 import json

@@ -85,56 +85,6 @@ def test_cli_exit_codes_violations_and_error(tmp_path):
                  str(tmp_path / "missing.baseline")]) == 2
 
 
-def test_config_parity_tune_registry_diff(tmp_path):
-    """Round 21: the config-parity rule diffs _*_KNOBS declarations
-    against tune/registry.py — every violation class fires on a
-    synthetic package, and the real package stays clean (the gate
-    above already proves zero new baseline entries)."""
-    from ct_mapreduce_tpu.analysis.config_parity import (
-        ConfigParityChecker)
-
-    pkg = tmp_path / "ct_mapreduce_tpu"
-    (pkg / "tune").mkdir(parents=True)
-    (pkg / "sub.py").write_text(
-        "_FOO_KNOBS = (\n"
-        "    Knob('alpha', 'CTMR_ALPHA', 1),\n"
-        "    Knob('beta', 'CTMR_BETA', 2),\n"
-        "    Knob('gamma', 'CTMR_GAMMA', 3),\n"
-        "    Knob('delta', 'CTMR_DELTA', 4),\n"
-        ")\n"
-        "def resolve_foo():\n"
-        "    return resolve_section('foo', _FOO_KNOBS, {})\n")
-    (pkg / "tune" / "registry.py").write_text(
-        "SWEEPABLE = {\n"
-        "    'foo': {'alpha': [1, 2], 'beta': [], 'ghostk': [1]},\n"
-        "    'stale': {},\n"
-        "}\n"
-        "EXCLUDED = {\n"
-        "    'foo': {'alpha': 'a justification well past fifteen',\n"
-        "            'gamma': 'short'},\n"
-        "}\n")
-    live, _, _ = run_analysis(pkg, checkers=[ConfigParityChecker()])
-    symbols = {f.symbol for f in live}
-    assert {"tune-both:foo.alpha",        # in both tables
-            "tune-ladder:foo.beta",       # empty sweep ladder
-            "tune-justification:foo.gamma",  # < 15 chars
-            "tune-unregistered:foo.delta",   # in neither table
-            "tune-ghost:foo.ghostk",      # registry names no Knob
-            "tune-section:stale",         # section never resolved
-            } <= symbols
-    # Declared-and-registered cleanly (alpha minus the dup) raises
-    # nothing else tune-flavored beyond the planted six.
-    assert len([s for s in symbols if s.startswith("tune-")]) == 6
-
-    # A package with no tune/registry.py (pre-round-21 layout) gets
-    # exactly the missing-registry finding, not a crash.
-    pkg2 = tmp_path / "p2" / "ct_mapreduce_tpu"
-    pkg2.mkdir(parents=True)
-    (pkg2 / "m.py").write_text("x = 1\n")
-    live2, _, _ = run_analysis(pkg2, checkers=[ConfigParityChecker()])
-    assert "tune-registry-missing" in {f.symbol for f in live2}
-
-
 def test_cli_rule_selection_and_listing(capsys):
     from ct_mapreduce_tpu.analysis.cli import main
 

@@ -235,8 +235,7 @@ def test_fleet_kill_and_resume(tmp_path, compile_cache):
         # Victim run: throttled downloads + a 300 ms checkpoint cadence
         # guarantee >=1 durable (cursor, aggregate) checkpoint lands
         # mid-ingest; then SIGKILL — no graceful shutdown path runs.
-        # Cache policy (see tools/fleet.py::spawn_worker and BENCHLOG
-        # round 14): the victim consumes the suite's warm cache
+        # Cache policy (see tools/fleet.py::spawn_worker, round 14): the victim consumes the suite's warm cache
         # READ-ONLY (a kill can then never leave a truncated entry),
         # and the RESUMED process runs with NO persistent cache at all
         # — with one, this box's jax build intermittently corrupts the
@@ -315,6 +314,253 @@ def test_fleet_kill_and_resume(tmp_path, compile_cache):
     assert merged["total"] > 0
 
 
+@pytest.mark.timeout(240)
+def test_fleet_healthz_section_served_live(tmp_path):
+    """A one-worker fleet over the redis coordinator, run in this
+    process with ``metricsPort`` set: while it ingests, ``/healthz``
+    carries the ``fleet`` section (role, membership, the partition map,
+    the leader's checkpoint epoch), and the aggregate it leaves equals
+    the serial run's. The downloads are throttled so that the run
+    outlasts several 150 ms epochs and many polls."""
+    import threading
+    import urllib.request
+
+    from tools import fleet as harness
+
+    from ct_mapreduce_tpu.agg.aggregator import HostSnapshotAggregator
+    from ct_mapreduce_tpu.cmd import ct_fetch
+    from ct_mapreduce_tpu.ingest import ctclient
+    from ct_mapreduce_tpu.utils.miniredis import MiniRedis
+
+    fixture = harness.build_fixture(
+        str(tmp_path / "fixture.json"), n_logs=2, entries_per_log=64,
+        dupes=6, max_batch=64)
+    urls = list(fixture["logs"])
+    port = _free_port()
+    ini = str(tmp_path / "worker.ini")
+    state = str(tmp_path / "agg.npz")
+    bodies = []
+    stop = threading.Event()
+
+    def poll():
+        while not stop.is_set():
+            try:
+                with urllib.request.urlopen(
+                        f"http://127.0.0.1:{port}/healthz",
+                        timeout=1) as resp:
+                    body = json.loads(resp.read())
+                if "fleet" in body:
+                    bodies.append(body["fleet"])
+            except (OSError, ValueError):
+                pass
+            time.sleep(0.05)
+
+    server = MiniRedis().start()
+    orig_transport = ctclient._urllib_transport
+    paced = harness.FixtureTransport(fixture, throttle_ms=150)
+    paced.max_batch = 16
+    ctclient._urllib_transport = paced
+    poller = threading.Thread(target=poll, daemon=True)
+    try:
+        harness.write_worker_ini(
+            ini, fixture, state, redis_addr=server.address,
+            checkpoint_period="150ms", coordinator="redis",
+            metrics_port=port)
+        poller.start()
+        rc = ct_fetch.main(["-config", ini, "-nobars"])
+    finally:
+        stop.set()
+        poller.join(5)
+        ctclient._urllib_transport = orig_transport
+        server.stop()
+    assert rc == 0
+    assert bodies, "no /healthz body carried the fleet section"
+    assert bodies[-1]["role"] == "leader"
+    assert bodies[-1]["workers_alive"] == [0]
+    part = next((b["partition"] for b in bodies if b["partition"]), None)
+    assert part == {u: 0 for u in urls}
+    assert max(b.get("checkpoint_epoch", 0) for b in bodies) >= 1
+
+    agg = HostSnapshotAggregator(capacity=1 << 10)
+    agg.load_checkpoint(state)
+    assert harness.snapshot_jsonable(agg.drain()) == \
+        harness.run_serial_reference(fixture, str(tmp_path))
+
+
+@pytest.mark.timeout(420)
+def test_fleet_observability_plane_live(tmp_path, compile_cache):
+    """The fleet-wide observability plane over two live worker
+    processes (tests/test_fleetobs.py holds the same pieces to their
+    contracts in one process): the rollup on ``/healthz/fleet`` sees
+    both workers and a leader; once ingest is quiet the fleet's insert
+    counter on ``/metrics/fleet`` is the sum of the two workers' own
+    ``/metrics`` and every fleet counter in that body the sum of its
+    ``{worker=...}`` lines; a stopped worker turns the rollup 503 with
+    a reason that names it, and the rollup recovers when it continues;
+    and a query's ``trace_id``, minted in this process, is on a span of
+    the worker that served it once the three exports are merged into
+    one timeline. No time is asserted."""
+    import re
+    import urllib.error
+    import urllib.request
+
+    from tools import fleet as harness
+
+    from ct_mapreduce_tpu.ingest.fleet import partition_map
+    from ct_mapreduce_tpu.serve.client import QueryClient
+    from ct_mapreduce_tpu.telemetry import fleetobs, trace
+    from ct_mapreduce_tpu.utils.miniredis import MiniRedis
+
+    fixture_path = str(tmp_path / "fixture.json")
+    fixture = harness.build_fixture(
+        fixture_path, n_logs=2, entries_per_log=48, dupes=4, max_batch=32)
+    urls = list(fixture["logs"])
+    assert set(partition_map(urls, 2).values()) == {0, 1}
+
+    def http_get(url):
+        try:
+            with urllib.request.urlopen(url, timeout=3.0) as resp:
+                return resp.getcode(), resp.read().decode()
+        except urllib.error.HTTPError as err:
+            return err.code, err.read().decode()
+        except OSError:
+            return -1, ""
+
+    def counter_of(body, name):
+        m = re.search(rf"(?m)^{re.escape(name)} ([0-9eE.+-]+)$", body)
+        return float(m.group(1)) if m else -1.0
+
+    def wait_for(what, probe, seconds):
+        deadline = time.monotonic() + seconds
+        while time.monotonic() < deadline:
+            for w, p in enumerate(procs):
+                assert p.poll() is None, (
+                    f"worker {w} died: {p.communicate()[0][-1500:]}")
+            got = probe()
+            if got is not None:
+                return got
+            time.sleep(0.25)
+        raise AssertionError(f"timed out waiting for {what}")
+
+    mports = [_free_port(), _free_port()]
+    qport = _free_port()
+    trace_paths = [str(tmp_path / f"w{w}-trace.json") for w in range(2)]
+    fleet_url = f"http://127.0.0.1:{mports[0]}/healthz/fleet"
+    insert_key = "ct_fetch_insertCertificate"
+    n_queries = 4
+
+    server = MiniRedis().start()
+    procs: list = []
+    try:
+        procs = [
+            harness.spawn_worker(
+                w, 2, fixture_path, str(tmp_path / f"obs-w{w}"),
+                server.address, checkpoint_period="500ms",
+                coordinator="redis", run_forever=True,
+                query_port=(qport if w == 0 else 0),
+                trace_path=trace_paths[w], metrics_port=mports[w],
+                # Thresholds far away: the SLO rules run and publish
+                # their gauges without a breach.
+                ini_lines=("sloMaxIngestLag = 1000000",
+                           "sloMaxServeP99Ms = 60000"),
+                # Heartbeats every 2 s; 4 s leaves one missed beat.
+                extra_env={"CTMR_FLEET_LIVENESS_S": "4.0"})
+            for w in range(2)
+        ]
+
+        def healthy_rollup():
+            st, raw = http_get(fleet_url)
+            body = json.loads(raw) if st == 200 else {}
+            if body.get("healthy") and body.get("workers_reporting") == 2:
+                return body
+            return None
+
+        rollup = wait_for("a healthy rollup of two", healthy_rollup, 300)
+        assert not rollup["missing"] and rollup["leader_epoch_skew"] <= 1
+        assert "leader" in [e["role"] for e in rollup["workers"].values()]
+
+        def quiet_parity():
+            live = [counter_of(
+                http_get(f"http://127.0.0.1:{p}/metrics")[1], insert_key)
+                for p in mports]
+            st, body = http_get(f"http://127.0.0.1:{mports[0]}/metrics/fleet")
+            if (st == 200 and min(live) > 0
+                    and counter_of(body, insert_key) == sum(live)):
+                return body
+            return None
+
+        mf_body = wait_for("fleet and live insert counters to agree",
+                           quiet_parity, 180)
+        assert fleetobs.fleet_counter_parity(mf_body) == []
+        for w in range(2):
+            assert f'{insert_key}{{worker="{w}"}}' in mf_body
+            assert f'slo_degraded{{worker="{w}"}}' in mf_body
+        publishes = [counter_of(
+            http_get(f"http://127.0.0.1:{p}/metrics")[1],
+            "fleet_obs_publishes") for p in mports]
+        assert min(publishes) > 0
+
+        wait_for("the query plane",
+                 lambda: (http_get(f"http://127.0.0.1:{qport}/healthz")[0]
+                          == 200) or None, 60)
+        trace.enable(str(tmp_path / "client-trace.json"))
+        try:
+            client = QueryClient(f":{qport}", timeout_s=10.0)
+            for i in range(n_queries):
+                assert "results" in client.query_one(
+                    "obs-issuer", "2031-06-15", f"0bad{i:04x}")
+            client_doc_path = trace.export()
+        finally:
+            trace.disable()
+        with open(client_doc_path) as fh:
+            client_doc = json.load(fh)
+
+        os.kill(procs[1].pid, signal.SIGSTOP)
+        try:
+            def degraded():
+                st, raw = http_get(fleet_url)
+                return json.loads(raw) if st == 503 and raw else None
+
+            flip = wait_for("the rollup to turn 503", degraded, 60)
+        finally:
+            os.kill(procs[1].pid, signal.SIGCONT)
+        assert any("worker 1" in r for r in flip.get("degraded", [])), flip
+        wait_for("the rollup to recover", healthy_rollup, 90)
+
+        for p in procs:  # a clean stop: each worker exports its ring
+            os.kill(p.pid, signal.SIGTERM)
+        outs = [p.communicate(timeout=180)[0] for p in procs]
+    finally:
+        for p in procs:
+            if p.poll() is None:
+                os.kill(p.pid, signal.SIGCONT)
+                p.kill()
+                p.wait(timeout=10)
+        server.stop()
+    for w, (p, out) in enumerate(zip(procs, outs)):
+        assert p.returncode == 0, (w, out[-1500:])
+
+    docs = []
+    for path in trace_paths:
+        with open(path) as fh:
+            docs.append(json.load(fh))
+    assert all(any(e.get("ph") in ("X", "i") for e in d["traceEvents"])
+               for d in docs)
+    merged = fleetobs.merge_traces([client_doc] + docs)
+    events = [e for e in merged["traceEvents"] if e.get("ph") != "M"]
+    assert len({e.get("pid") for e in events}) >= 3
+    labels = {e["args"]["name"] for e in merged["traceEvents"]
+              if e.get("ph") == "M" and e.get("name") == "process_name"}
+    for want in ("worker 0 (", "worker 1 ("):
+        assert any(lab.startswith(want) for lab in labels), labels
+    minted = {e["args"]["trace_id"] for e in client_doc["traceEvents"]
+              if e.get("name") == "query.client"
+              and "trace_id" in e.get("args", {})}
+    assert len(minted) == n_queries
+    assert any(e.get("args", {}).get("trace_id") in minted
+               and e.get("pid") != os.getpid() for e in events)
+
+
 @pytest.mark.parametrize("scenario,kill_env", [
     # Die right after the delta segment's rename, BEFORE the manifest
     # update: the durable chain is still the pre-tick one; the stray
@@ -337,7 +583,19 @@ def test_fleet_kill_points_ck02(tmp_path, compile_cache, scenario,
     restarted worker resumes through it to the uninterrupted run's
     aggregate. The self-kill rides ckpt.kill_point (CTMR_CKPT_KILL),
     so death lands deterministically at the boundary under test —
-    victim cache policy as in the round-14 test (read-only consume)."""
+    victim cache policy as in the round-14 test (read-only consume).
+
+    The victim reaches its kill point whenever ONE save lands before
+    the log's last page is folded: that save is the base, the next one
+    with new rows appends a segment (mid-segment dies there), and the
+    one after it (a tick, the log's exit save or the round's) must
+    compact under maxChain=1 (mid-compaction dies there). The only
+    losing order is a first save after the whole log, which leaves one
+    base, no-op saves and exit code 0: at a page every 150 ms against
+    a 300 ms tick the stream lasted a second and a loaded machine lost
+    that race about one run in three. A page a second against a 100 ms
+    tick puts six seconds and some sixty idle ticks before the last
+    page; the victim still dies after its second or third page."""
     from tools import fleet as harness
 
     from ct_mapreduce_tpu.agg import ckpt
@@ -362,7 +620,7 @@ def test_fleet_kill_points_ck02(tmp_path, compile_cache, scenario,
         try:
             victim = harness.spawn_worker(
                 0, 1, fixture_path, wdir, server.address,
-                checkpoint_period="300ms", throttle_ms=150,
+                checkpoint_period="100ms", throttle_ms=1000,
                 coordinator="redis", compile_cache_readonly=True)
             out = victim.communicate(timeout=300)[0]
         finally:
@@ -377,9 +635,9 @@ def test_fleet_kill_points_ck02(tmp_path, compile_cache, scenario,
         # and the manifest update never publishes a torn state.
         chain = ckpt.resolve_chain(npz)
         assert len(chain.segments) == 0, scenario
-        if scenario == "mid-segment":
-            # The renamed-but-unlisted segment really is on disk.
-            assert os.path.exists(ckpt.segment_path(npz, 1)), scenario
+        # The segment really is on disk: renamed but unlisted, or
+        # listed by the stale manifest of the chain the anchor replaced.
+        assert os.path.exists(ckpt.segment_path(npz, 1)), scenario
         victim_snap = harness.merged_snapshot([npz])
         assert victim_snap["total"] > 0
 

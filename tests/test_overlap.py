@@ -280,8 +280,8 @@ def test_overlap_queue_highwater_gauges():
 
 def test_overlap_lock_wait_sampled_outside_store_envelope():
     """dispatchLockWait is its own sample and the storeCertificate
-    envelope opens only after the lock is held — the bench's submit
-    budget must not fold lock contention into submit cost."""
+    envelope opens only after the lock is held — a submit budget
+    must not fold lock contention into submit cost."""
     from ct_mapreduce_tpu.telemetry import metrics as tmetrics
 
     sink_metrics = tmetrics.InMemSink()
@@ -301,3 +301,33 @@ def test_overlap_lock_wait_sampled_outside_store_envelope():
     # paths), all non-negative.
     assert samples["ct-fetch.dispatchLockWait"]["count"] >= 4
     assert samples["ct-fetch.dispatchLockWait"]["min"] >= 0.0
+
+
+def test_traced_overlap_run_has_its_three_stages(tmp_path):
+    """A traced run of the real sink with overlap workers records
+    ``ingest.decode``, ``ingest.submit`` and ``ingest.drain`` spans,
+    one a chunk and each with busy time, and ``tools/traceview.py``
+    reads the exported artifact into that per-stage summary
+    (tests/test_trace.py shows the same on a fake sink)."""
+    from ct_mapreduce_tpu.telemetry import trace
+    from tools import traceview
+
+    n_chunks = 4
+    trace.enable(ring_size=4096)
+    try:
+        agg, sink = make_sink(overlap_workers=2, depth=2)
+        for i in range(n_chunks):
+            sink.store_raw_batch(wire_batch(i * 32, 32))
+        sink.close()
+        assert agg.drain().total == n_chunks * 32
+        path = str(tmp_path / "overlap.json")
+        trace.export(path)
+    finally:
+        trace.disable()
+    stages = ("ingest.decode", "ingest.submit", "ingest.drain")
+    summary = traceview.stage_summary(traceview.load(path), stages=stages)
+    assert summary.pop("_wall_s") > 0
+    assert set(summary) == set(stages)
+    for name, s in summary.items():
+        assert s["count"] == n_chunks, (name, s)
+        assert s["busy_s"] > 0, (name, s)

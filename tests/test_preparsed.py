@@ -243,14 +243,17 @@ def test_filter_routing_parity_with_walker_lane():
     issuer = certgen.make_cert(serial=1, issuer_cn="Route CA", is_ca=True,
                                not_after=FUTURE)
     boundary = now.replace(minute=30)  # expires within the current hour
+    # Before the factory's default not_before (2024), so give its own:
+    # the `cryptography` builder refuses not_after < not_before.
+    expired = now - datetime.timedelta(days=6 * 365)
     pairs = [
         (certgen.make_cert(serial=10, issuer_cn="Route CA", is_ca=False,
                            not_after=FUTURE), issuer),
         (certgen.make_cert(serial=11, issuer_cn="Route CA", is_ca=True,
                            not_after=FUTURE), issuer),  # filtered: CA
         (certgen.make_cert(serial=12, issuer_cn="Route CA", is_ca=False,
-                           not_after=datetime.datetime(
-                               2020, 1, 1, tzinfo=UTC)), issuer),  # expired
+                           not_before=expired - datetime.timedelta(days=365),
+                           not_after=expired), issuer),  # expired
         (certgen.make_cert(serial=13, issuer_cn="Route CA", is_ca=False,
                            not_after=boundary), issuer),  # boundary → host
         (certgen.make_cert(serial=14, issuer_cn="Other CA", is_ca=False,

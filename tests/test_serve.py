@@ -588,8 +588,8 @@ def test_query_server_getcert_proxy():
 
 
 def test_serve_batch_spans_recorded(template):
-    """serve.batch spans carry lane counts — what the bench serve leg
-    derives its batching-effectiveness gate from."""
+    """serve.batch spans carry lane counts — what batching
+    effectiveness is read from."""
     from ct_mapreduce_tpu.telemetry import trace
 
     tracer = trace.enable()
@@ -622,6 +622,52 @@ def test_serve_batch_spans_recorded(template):
         assert len(waits) == 8
     finally:
         trace.disable()
+
+
+def test_device_replicas_answer_through_the_jitted_contains(template):
+    """A device oracle with two replicas, read off its own spans: every
+    ``serve.lookup`` ran in device mode, both replicas took batches in
+    turn, the membership program itself ran (``serve.contains_device``)
+    and nothing slid onto the host mirror."""
+    from ct_mapreduce_tpu.telemetry import metrics as tmetrics
+    from ct_mapreduce_tpu.telemetry import trace
+
+    agg = TpuAggregator(capacity=1 << 12, batch_size=64)
+    agg.ingest([(syncerts.stamp_serial(template, j), template.issuer_der)
+                for j in range(32)])
+    issuer_id, eh = _identity(template)
+    idx = agg.registry.index_of_issuer_id(issuer_id)
+    sink = tmetrics.InMemSink()
+    prev = tmetrics.get_sink()
+    tmetrics.set_sink(sink)
+    tracer = trace.enable()
+    t0 = tracer.now_us()
+    try:
+        oracle = MembershipOracle(agg, max_batch=64, max_delay_s=0.001,
+                                  max_staleness_s=1e9, device=True,
+                                  replicas=2, cache_size=0)
+        try:
+            oracle.snapshots.warm()
+            for j in range(8):  # one batch a call: the views alternate
+                known, ghost = oracle.query_raw([
+                    (idx, eh, _serial_bytes(template, j)),
+                    (idx, eh, _serial_bytes(template, 10_000 + j))])
+                assert known[0] is True and ghost[0] is False
+        finally:
+            oracle.close()
+        spans = [e for e in tracer.events()
+                 if e.get("ph") == "X" and e["ts"] >= t0]
+    finally:
+        trace.disable()
+        tmetrics.set_sink(prev)
+    lookups = [e for e in spans if e["name"] == "serve.lookup"]
+    assert len(lookups) == 8
+    assert all(e["args"]["device"] == 1 for e in lookups)
+    assert {e["args"]["replica"] for e in lookups} == {0, 1}
+    contains = [e for e in spans if e["name"] == "serve.contains_device"]
+    assert len(contains) == 8
+    assert not any(e["name"] == "serve.contains_host" for e in spans)
+    assert sink.snapshot()["counters"].get("serve.device_fallback", 0) == 0
 
 
 # -- replica pool (round 12) ----------------------------------------------

@@ -205,3 +205,33 @@ def test_sink_accepts_preparsed_with_mesh():
     assert agg.metrics["inserted"] == len(pairs)
     # The undecidable lane took the exact host lane via walker replay.
     assert agg.metrics["host_lane"] == 1
+
+
+@pytest.mark.parametrize("shards", [0, 8], ids=["one-chip", "mesh8"])
+def test_clean_stream_reads_back_only_the_compact_flag_block(shards):
+    """With no lane flagged, what comes back from the device a chunk is
+    the fixed block (two count words and the compacted ids, at most
+    ``flag_cap`` of them), never a status word a lane: the same budget
+    on one chip and reassembled from a mesh's shards."""
+    from ct_mapreduce_tpu.agg.aggregator import TpuAggregator
+    from ct_mapreduce_tpu.agg.sharded_agg import ShardedAggregator
+    from ct_mapreduce_tpu.telemetry import metrics as tmetrics
+
+    n, chunk = 512, 256
+    fx = _fixtures(n)
+    now = fx[6]
+    agg = (ShardedAggregator(_mesh(shards), capacity=1 << 12,
+                             batch_size=chunk, now=now) if shards
+           else TpuAggregator(capacity=1 << 12, batch_size=chunk, now=now))
+    sink = tmetrics.InMemSink()
+    prev = tmetrics.get_sink()
+    tmetrics.set_sink(sink)
+    try:
+        (res,), agg = _run_preparsed(agg, fx)
+    finally:
+        tmetrics.set_sink(prev)
+    assert res.was_unknown.all() and agg.metrics["overflow"] == 0
+    flag_cap = min(1024, max(64, chunk // 64), chunk)
+    got = sink.snapshot()["counters"]["ingest.d2h_flag_bytes"]
+    assert 0 < got <= 4 * (2 + flag_cap) * (n // chunk), got
+    assert got < 4 * n

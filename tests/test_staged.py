@@ -192,6 +192,43 @@ def test_staged_partial_ring_flushes_at_barrier():
     assert samples["ingest.dispatch_chunks"]["max"] == K - 1
 
 
+def test_staged_run_fuses_k_chunks_an_execution():
+    """What a staged run is made of, from its own spans and counters:
+    2K chunks go to the device in 2 ``device.step_staged`` executions
+    where the per-chunk path runs 2K ``device.step``s, every dispatch
+    carries K chunks, and the staging copy has its span and its byte
+    counter."""
+    from ct_mapreduce_tpu.telemetry import trace
+
+    batches = [wire_batch(i * FLUSH, FLUSH) for i in range(2 * K)]
+
+    def traced(k_per):
+        sink_m = tmetrics.InMemSink()
+        prev = tmetrics.get_sink()
+        tmetrics.set_sink(sink_m)
+        tracer = trace.enable(ring_size=4096)
+        t0 = tracer.now_us()
+        try:
+            out = replay(batches, overlap_workers=2, k_per=k_per)
+            names = [e["name"] for e in tracer.events()
+                     if e.get("ph") == "X" and e["ts"] >= t0]
+        finally:
+            trace.disable()
+            tmetrics.set_sink(prev)
+        return out, names, sink_m.snapshot()
+
+    serial, serial_names, _ = traced(1)
+    staged, names, snap = traced(K)
+    assert staged["counts"] == serial["counts"]
+    assert serial_names.count("device.step") == 2 * K
+    assert names.count("device.step_staged") == 2
+    assert names.count("device.step") == 0
+    chunks = snap["samples"]["ingest.dispatch_chunks"]
+    assert chunks["min"] == chunks["max"] == K
+    assert names.count("ingest.h2d") > 0
+    assert snap["counters"]["ingest.h2d_bytes"] > 0
+
+
 def test_staged_ring_survives_error_latch():
     """A drain-stage failure latches the overlap pipeline mid-staging:
     close() raises OverlapError, chunks parked in the ring are dropped

@@ -5,7 +5,7 @@ The reference gates its integration tier on a reachable Redis
 this tier tiny — one compile, a few seconds of chip time — it exists
 to prove the shipping step (device build -> parse -> filter ->
 fingerprint -> dedup insert -> counts) runs end to end on real
-hardware with exact results, not to benchmark it (bench.py does that).
+hardware with exact results, not to benchmark it (benchmark/ does that).
 """
 
 import numpy as np
@@ -150,7 +150,7 @@ def test_sharded_step_on_chip_mesh():
 def test_e2e_ingest_leg_small_on_hardware():
     """Wire format → decode → pack → H2D → device step → drain, on the
     chip, at small scale: the production AggregatorSink path with exact
-    totals and per-issuer attribution (the shape bench.py's e2e leg
+    totals and per-issuer attribution (the shape the benchmark
     measures at full size)."""
     import base64
 

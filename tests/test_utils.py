@@ -109,79 +109,3 @@ def test_build_device_batches_unique_valid_rows():
 
     with pytest.raises(ValueError):
         syncerts.build_device_batches(tpl, 1, 4, len(tpl.leaf_der) - 1)
-
-
-def test_build_mixed_device_batches_realistic_mix():
-    """The realistic-mix synthesis: RSA + ECDSA templates, many
-    issuers, varied serial lengths (8..20) in ONE device batch; every
-    lane is canonical DER for ITS template, epoch window (serial bytes
-    1..4) left zero, lane counters unique, and the fused step ingests
-    the whole mix with exact counts."""
-    import numpy as np
-
-    from ct_mapreduce_tpu.core import der as hostder
-    from ct_mapreduce_tpu.core import packing
-    from ct_mapreduce_tpu.ops import buckettable, pipeline
-    from ct_mapreduce_tpu.utils import syncerts
-
-    tpls = [
-        syncerts.make_template("Mix CA ec8", serial_len=8),
-        syncerts.make_template("Mix CA ec16", serial_len=16),
-        syncerts.make_template("Mix CA rsa20", key_type="rsa2048",
-                               serial_len=20, rich_extensions=True),
-        syncerts.make_template("Mix CA ec9", serial_len=9,
-                               rich_extensions=True),
-    ]
-    assert len(tpls[2].leaf_der) > 1024  # RSA leaves the friendly regime
-    g, b, pad = 2, 256, 2048
-    w = syncerts.zipf_weights(len(tpls))
-    ms = syncerts.build_mixed_device_batches(tpls, w, g, b, pad, seed=3)
-    datas = np.asarray(ms.datas)
-    lens = np.asarray(ms.lens)
-    assert datas.shape == (g, b, pad)
-    assert set(np.unique(ms.template_of)) == {0, 1, 2, 3}
-
-    for gi in range(g):
-        for li in (0, 1, 7, b - 1):
-            t = tpls[ms.template_of[li]]
-            row = bytes(datas[gi, li, : lens[gi, li]])
-            assert lens[gi, li] == len(t.leaf_der)
-            fields = hostder.parse_cert(row)  # still canonical DER
-            assert fields.serial_len == t.serial_len
-            serial = row[t.serial_off : t.serial_off + t.serial_len]
-            assert serial[0] == 0x4D
-            assert serial[1:4] == b"\x00" * 3  # epoch window untouched
-            cnt = int.from_bytes(serial[-4:], "big")
-            assert cnt == gi * b + li
-
-    # The fused step ingests the mix exactly: all fresh on the first
-    # pass, all known on the replay, per-issuer counts match the draw.
-    table = buckettable.make_table(1 << 12)
-    now_hour = 500_000
-    no_cn = (np.zeros((0, 32), np.uint8), np.zeros((0, 2), np.int32))
-    import jax.numpy as jnp
-
-    table, out = pipeline.ingest_core(
-        table, jnp.asarray(datas[0]), jnp.asarray(lens[0]),
-        jnp.asarray(ms.issuer_idx), jnp.asarray(np.ones((b,), bool)),
-        jnp.int32(now_hour), jnp.int32(packing.DEFAULT_BASE_HOUR),
-        jnp.asarray(no_cn[0]), jnp.asarray(no_cn[1]))
-    assert bool(np.asarray(out.was_unknown).all())
-    assert not np.asarray(out.host_lane).any()
-    counts = np.asarray(out.issuer_unknown_counts)
-    for t_id in range(len(tpls)):
-        assert counts[t_id] == (ms.template_of == t_id).sum()
-    table, out2 = pipeline.ingest_core(
-        table, jnp.asarray(datas[0]), jnp.asarray(lens[0]),
-        jnp.asarray(ms.issuer_idx), jnp.asarray(np.ones((b,), bool)),
-        jnp.int32(now_hour), jnp.int32(packing.DEFAULT_BASE_HOUR),
-        jnp.asarray(no_cn[0]), jnp.asarray(no_cn[1]))
-    assert not np.asarray(out2.was_unknown).any()
-    # Batch g=1 differs only in lane counters — all fresh again.
-    table, out3 = pipeline.ingest_core(
-        table, jnp.asarray(datas[1]), jnp.asarray(lens[1]),
-        jnp.asarray(ms.issuer_idx), jnp.asarray(np.ones((b,), bool)),
-        jnp.int32(now_hour), jnp.int32(packing.DEFAULT_BASE_HOUR),
-        jnp.asarray(no_cn[0]), jnp.asarray(no_cn[1]))
-    assert bool(np.asarray(out3.was_unknown).all())
-    assert int(np.asarray(table.count)) == 2 * b

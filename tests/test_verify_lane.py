@@ -154,6 +154,31 @@ def test_sink_lane_outcomes_staged():
     _check_outcomes(agg, sink, expect, len(pairs))
 
 
+def test_device_verify_spans_cover_every_device_lane():
+    """The kernels really ran, and in batches: the ``device.verify``
+    spans of a traced run carry exactly the device-decidable lanes,
+    more than one to an execution, and each curve's Q-table holds its
+    one log key."""
+    from ct_mapreduce_tpu.telemetry import trace
+
+    pairs, expect = _corpus()
+    tracer = trace.enable()
+    t0 = tracer.now_us()
+    try:
+        _agg, sink = _run_sink(pairs, chunks_per_dispatch=2)
+        spans = [e for e in tracer.events()
+                 if e.get("ph") == "X" and e["name"] == "device.verify"
+                 and e["ts"] >= t0]
+    finally:
+        trace.disable()
+    lanes = sum(int(e["args"]["lanes"]) for e in spans)
+    assert spans and lanes == expect["device"]
+    assert lanes / len(spans) > 1.0
+    qtable = sink.verifier.health()["qtable"]
+    assert qtable["p256"]["occupancy"] == 1
+    assert qtable["p384"]["occupancy"] == 1
+
+
 def test_lane_python_extraction_parity(serial_run, monkeypatch):
     """CTMR_NATIVE=0 (pure-python decode AND extraction) produces the
     exact same verify outcomes — the degradation contract end to end."""

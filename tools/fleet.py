@@ -13,7 +13,7 @@ entries.
     python tools/fleet.py --workers 2 --logs 4 --entries-per-log 256
 
 Child mode (`--child`) is one worker process; the parent (and
-tests/test_multiprocess.py, bench.run_fleet_smoke) spawns it. A child
+tests/test_multiprocess.py) spawns it. A child
 killed mid-run (SIGKILL) and respawned resumes from its checkpoint
 cursor in miniredis — the warm-restart contract — which the
 kill-and-resume test drives directly.

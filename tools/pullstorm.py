@@ -21,9 +21,8 @@ storms them with N simulated clients:
   (a configurable fraction pulls an upstream container instead).
 
 Reports bytes-on-wire against the full-pull counterfactual, the 304
-ratio, and latency percentiles. A scaled-down leg gates in tier-1
-via ``bench.run_distrib_smoke``; the full 10K-client run is recorded
-in BENCHLOG.
+ratio, and latency percentiles. A scaled-down storm runs in tier-1
+(tests/test_distrib.py), counted in pulls and bytes.
 
     python tools/pullstorm.py --clients 10000 --epochs 6 --workers 2
 """
