@@ -2153,8 +2153,14 @@ class TpuAggregator:
                     f"recorded {len(rows)} rows, device inserted "
                     f"{self._ckpt_dev_inserted}")
                 return False
-            blob = self._ckpt_segment_blob(rows, host_adds)
+            # Shadow first: the decode thread registers issuers under
+            # no lock of ours, so one may land between the two reads of
+            # the registry. Read in this order it is in this segment
+            # and again in the next (replay is idempotent); the other
+            # way round it is in neither, and its rows restore to an
+            # issuer the registry never had.
             shadow = self._ckpt_take_shadow()
+            blob = self._ckpt_segment_blob(rows, host_adds)
             self._ckpt_clear_log()
         if not rows and not host_adds and not self._ckpt_blob_nonempty(blob):
             # Nothing churned since the last durable tick: the chain

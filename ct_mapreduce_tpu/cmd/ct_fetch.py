@@ -523,7 +523,12 @@ def main(argv: list[str] | None = None) -> int:
             "last_progress": last,
             "progress": {u: {"pos": p, "end": e}
                          for u, (p, e) in engine.progress().items()},
-            "entry_queue_depth": engine.entry_queue.qsize(),
+            # Items waiting, in the unit named beside it (a get-entries
+            # response an item where the sink takes raw batches); the
+            # channel's bound counts entries either way.
+            "entry_queue_depth": engine.entry_queue.depth(),
+            "entry_queue_depth_unit": ("pages" if engine.raw_batches
+                                       else "entries"),
         }
         ovl = getattr(sink, "_overlap", None)
         if ovl is not None:

@@ -6,7 +6,10 @@ ct-fetch.go:83-488) on Python threads and a bounded queue:
 
 - one downloader thread per log URL (ct-fetch.go:527-565), fetching
   ranges of 1000 and decoding leaves (ct-fetch.go:398-488);
-- a shared bounded entry queue, capacity 16,384 (ct-fetch.go:132);
+- a shared entry channel bounded in ENTRIES, whatever the size of its
+  items: 16,384 (ct-fetch.go:132) in front of a consumer that takes an
+  entry at a time, one device batch where whole get-entries responses
+  feed a sink that pauses for a batch (:class:`_EntryChannel`);
 - ``num_threads`` store workers draining the queue into a sink
   (ct-fetch.go:140-145,180-246);
 - a save ticker checkpointing each log's cursor every ``save_period``
@@ -200,7 +203,8 @@ class AggregatorSink:
         self._dispatch_lock = threading.Lock()  # one device stream
         # Host↔device pipelining (deviceQueueDepth, SURVEY §2.2 PP row;
         # the reference overlaps download and store with goroutines + a
-        # 16,384-slot channel, ct-fetch.go:132,398-488): device steps
+        # 16,384-entry channel, ct-fetch.go:132,398-488; here the
+        # channel holds one batch of this sink, LogSyncEngine): device steps
         # are SUBMITTED without readback and consumed once more than
         # `device_queue_depth` batches are in flight, so decode of
         # batch N+1 overlaps the device step of batch N. Depth 0 =
@@ -1134,7 +1138,7 @@ class LogWorker:
 
     def run(
         self,
-        out: "queue.Queue",
+        out: "_EntryChannel",
         stop: threading.Event,
         save_period_s: float = 900.0,
         progress=None,
@@ -1256,7 +1260,8 @@ class LogWorker:
         # Back-pressure: the time the downloader stands still because
         # the store side has not taken what it already fetched.
         submitted = False
-        with trace.span("fetch.enqueue", cat="fetch", depth=out.qsize()), \
+        with trace.span("fetch.enqueue", cat="fetch", depth=out.depth(),
+                        entries=out.qsize()), \
                 metrics.measure("LogWorker", self.client.short_url,
                                 "submitToChannel"):
             while not stop.is_set():
@@ -1285,12 +1290,68 @@ class LogWorker:
         return len(page)
 
 
-class _AccountingQueue:
-    """Facade over the shared entry queue that bumps the engine's
-    per-log outstanding watermark on each successful put (the blocking
-    semantics are the inner queue's own)."""
+def _entries_of(item) -> int:
+    """What an item of the channel weighs: a response its entries, an
+    entry one; so do an empty response and the ``None`` a store thread
+    stops on, because ``get`` reads a channel of weight 0 as empty."""
+    return max(1, len(item)) if isinstance(item, RawBatch) else 1
 
-    def __init__(self, inner: "queue.Queue", on_put):
+
+class _EntryChannel(queue.Queue):
+    """The shared entry channel, bounded by the ENTRIES it holds and not
+    by its items: a get-entries response weighs what it carries (a log
+    caps a response anywhere from 32 to 1,024 entries), a decoded entry
+    one. ``put`` admits an item while the entries already inside are
+    under ``capacity``, so a response larger than the room left, or than
+    the whole bound, still goes in and nothing deadlocks on an odd page
+    size; the channel then holds less than ``capacity`` plus one
+    response. Blocking, timeouts, ``task_done``/``join`` are
+    ``queue.Queue``'s own (they count items); ``qsize()`` is entries,
+    ``depth()`` items."""
+
+    def __init__(self, capacity: int):
+        self.high_water = 0  # most entries held at once
+        super().__init__(maxsize=max(1, int(capacity)))
+
+    @property
+    def capacity(self) -> int:
+        return self.maxsize
+
+    def _init(self, maxsize: int) -> None:
+        self.queue: deque = deque()
+        self._entries = 0
+
+    def _qsize(self) -> int:
+        return self._entries
+
+    def _put(self, item) -> None:
+        self.queue.append(item)
+        self._entries += _entries_of(item)
+        if self._entries > self.high_water:
+            self.high_water = self._entries
+            metrics.set_gauge("ingest", "channel_high_water_entries",
+                              value=float(self._entries))
+        if self._entries < self.capacity:
+            # One `get` can make room for several small responses and
+            # wakes one putter: pass the baton while there is room.
+            self.not_full.notify()
+
+    def _get(self):
+        item = self.queue.popleft()
+        self._entries -= _entries_of(item)
+        return item
+
+    def depth(self) -> int:
+        with self.mutex:
+            return len(self.queue)
+
+
+class _AccountingQueue:
+    """Facade over the shared entry channel that bumps the engine's
+    per-log outstanding watermark on each successful put (the blocking
+    semantics are the channel's own)."""
+
+    def __init__(self, inner: _EntryChannel, on_put):
         self._inner = inner
         self._on_put = on_put
 
@@ -1301,6 +1362,9 @@ class _AccountingQueue:
     def qsize(self) -> int:
         return self._inner.qsize()
 
+    def depth(self) -> int:
+        return self._inner.depth()
+
 
 class LogSyncEngine:
     """Queue + worker-pool runtime (ct-fetch.go:83-178).
@@ -1308,6 +1372,21 @@ class LogSyncEngine:
     ``start_store_threads`` spawns the consumers; ``sync_log`` spawns
     one downloader thread per URL; ``stop`` + ``join`` replicate the
     WaitGroup shutdown ordering of main() (ct-fetch.go:610-620).
+
+    All logs share one channel, bounded in entries
+    (:class:`_EntryChannel`). Per-entry mode: ``queue_capacity``, the
+    reference's 16,384 (ct-fetch.go:132), in front of a consumer that
+    takes an entry at a time. ``raw_batches``: the sink takes responses
+    at memcpy speed until one cuts a batch and then leaves the channel
+    alone for as long as that batch's decode, submit and fold take, so
+    the channel holds one batch of the sink it feeds (``flush_size``
+    entries, which is part of what a raw-batch sink is) and the
+    downloaders fetch through the pause, however short a batch makes
+    it. The width is derived, not set: host memory is one
+    batch of response bytes a process, beside the batch the sink
+    accumulates. A cursor save still waits for every entry its log
+    enqueued (``_pre_cursor_save``): the width changes how much can be
+    outstanding, never whether the cursor may pass it.
     """
 
     def __init__(
@@ -1334,10 +1413,12 @@ class LogSyncEngine:
         self.save_period_s = save_period_s
         self.raw_batches = raw_batches
         if raw_batches:
-            # Queue items are whole get-entries responses (≤ BATCH_SIZE
-            # entries each); keep the same total-entry bound.
-            queue_capacity = max(2, queue_capacity // BATCH_SIZE)
-        self.entry_queue: "queue.Queue" = queue.Queue(maxsize=queue_capacity)
+            # Items are whole get-entries responses and the consumer
+            # pauses once a batch: hold one batch of the sink's.
+            queue_capacity = int(sink.flush_size)
+        self.entry_queue = _EntryChannel(queue_capacity)
+        metrics.set_gauge("ingest", "channel_capacity_entries",
+                          value=float(self.entry_queue.capacity))
         self.stop_event = threading.Event()
         self._store_threads: list[threading.Thread] = []
         self._download_threads: list[threading.Thread] = []
@@ -1410,14 +1491,14 @@ class LogSyncEngine:
             self._store_threads.append(t)
 
     def _account_enqueued(self, item) -> None:
-        n = len(item) if isinstance(item, RawBatch) else 1
+        n = _entries_of(item)
         with self._outstanding_cond:
             self._outstanding[item.log_url] = (
                 self._outstanding.get(item.log_url, 0) + n
             )
 
     def _account_stored(self, item) -> None:
-        n = len(item) if isinstance(item, RawBatch) else 1
+        n = _entries_of(item)
         with self._outstanding_cond:
             self._outstanding[item.log_url] = (
                 self._outstanding.get(item.log_url, 0) - n

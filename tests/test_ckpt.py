@@ -135,6 +135,39 @@ def test_restored_writer_extends_chain(tmp_path):
         harness.ckpt_state_digest(r)
 
 
+def test_issuer_registered_during_a_segment_save_is_not_lost(tmp_path):
+    """The store thread's decode registers issuers under none of the
+    save's locks. One that lands between the save's two reads of the
+    registry (the shadow and the segment's adds) must reach the chain
+    in this segment or the next: rows that name it are then already
+    durable, and a later tick with nothing else dirty is a no-op."""
+    from ct_mapreduce_tpu.core.types import Issuer
+
+    agg, eh, path = _mk(tmp_path)
+    agg.save_checkpoint(path)
+    harness.ckpt_churn(agg, eh, 11, ENTRIES)
+    late = Issuer.from_string("bGF0ZS1pc3N1ZXItcmVnaXN0ZXJlZC1taWQtc2F2ZQ==")
+
+    def lands_after(read):
+        def reads_then_registers(*args):
+            out = read(*args)
+            agg.registry.assign_issuer(late)
+            return out
+        return reads_then_registers
+
+    # Whichever read of the registry the save makes first, the issuer
+    # is registered right after it and before the other.
+    agg._ckpt_take_shadow = lands_after(agg._ckpt_take_shadow)
+    agg.registry.ids_from = lands_after(agg.registry.ids_from)
+    agg.save_checkpoint(path)
+    agg.save_checkpoint(path)
+    assert agg.registry.index_of_issuer_id(late.id()) is not None
+    assert harness.ckpt_state_digest(_reader(path)) == \
+        harness.ckpt_state_digest(agg)
+    assert _reader(path).registry.index_of_issuer_id(late.id()) == \
+        agg.registry.index_of_issuer_id(late.id())
+
+
 def test_empty_tick_writes_no_segment(tmp_path):
     agg, eh, path = _mk(tmp_path)
     agg.save_checkpoint(path)

@@ -216,3 +216,49 @@ def test_traceview_cli(tmp_path, capsys):
     out = capsys.readouterr().out
     assert "stage.a" in out and "stage.b" in out
     assert "trace wall:" in out
+
+
+def _x(name, ts_ms, dur_ms, **args):
+    return {"ph": "X", "name": name, "ts": ts_ms * 1e3, "dur": dur_ms * 1e3,
+            "pid": 1, "tid": 1, "args": args}
+
+
+def test_traceview_split_by_what_overlaps(tmp_path, capsys):
+    """``--split``: pages fetched while the store thread works against
+    pages fetched while it waits, inside the batches asked for."""
+    events = [
+        _x("sink.accumulate", 9, 1, batch=1),  # cut at 10 ms
+        _x("sink.accumulate", 99, 1, batch=2),  # cut at 100 ms
+        _x("ingest.decode", 20, 10), _x("ingest.submit", 30, 20),
+        _x("fetch.get_entries", 5, 2),    # before the window: left out
+        _x("fetch.get_entries", 12, 2),   # clear
+        _x("fetch.get_entries", 18, 4),   # half covered: covered
+        _x("fetch.get_entries", 25, 6),   # spans decode and submit
+        _x("fetch.get_entries", 49, 4),   # a quarter covered: clear
+        _x("fetch.get_entries", 60, 2),   # clear
+        _x("fetch.get_entries", 120, 50),  # after the window: left out
+    ]
+    window = traceview.cut_window(events, 1, 2)
+    assert window == (10e3, 100e3)
+    split = traceview.split_by_overlap(
+        events, "fetch.get_entries", {"ingest.decode", "ingest.submit"},
+        *window)
+    assert split["covered"] == {"n": 2, "median_ms": 5.0, "mean_ms": 5.0,
+                                "share": 0.75}
+    assert split["clear"]["n"] == 3
+    assert split["clear"]["median_ms"] == 2.0
+    assert split["clear"]["share"] == pytest.approx(0.25 / 3)
+    whole = traceview.split_by_overlap(events, "fetch.get_entries",
+                                       {"ingest.decode"})
+    assert (whole["covered"]["n"], whole["clear"]["n"]) == (2, 5)
+    path = str(tmp_path / "split.json")
+    with open(path, "w") as fh:
+        json.dump({"traceEvents": events}, fh)
+    assert traceview.main([path, "--split", "fetch.get_entries", "--against",
+                           "ingest.decode,ingest.submit",
+                           "--between", "1,2"]) == 0
+    out = capsys.readouterr().out
+    assert "covered      2  median 5.000 ms" in out
+    assert traceview.main([path, "--split", "no.such", "--against", "x"]) == 1
+    with pytest.raises(ValueError):
+        traceview.cut_window(events, 1, 3)

@@ -12,6 +12,8 @@ Usage:
   python tools/traceview.py /tmp/trace.json [--stages name1,name2,...]
   python tools/traceview.py /tmp/trace.json --batch 7
   python tools/traceview.py /tmp/trace.json --batches
+  python tools/traceview.py /tmp/trace.json --split fetch.get_entries \
+      --against ingest.decode,ingest.submit [--between 6,22]
   python tools/traceview.py --merge w0.json w1.json ... \
       [--skew pairs.json] [--out merged.json]
 
@@ -20,6 +22,16 @@ cut recorded for it, then every span that carries ``batch=N`` as a
 tree by ``parent``, per thread, with each span's self time (its
 duration less its children's). ``--batches`` prints one row per batch:
 the decode's phases, the submit, the fold's device wait.
+
+``--split NAME --against A,B`` is the only view of the GIL the spans
+give: every ``NAME`` span goes into one of two groups by whether spans
+of the ``--against`` names (on whatever thread) cover at least half of
+it, and each group prints its count, the median and mean of its
+durations and the mean share covered. A page fetched while the store
+thread decodes or submits against one fetched while it waits; a fold
+with pages in flight against one without. ``--between LO,HI`` keeps
+the spans that start between the cuts of batches LO and HI
+(``sink.accumulate`` carries ``batch`` on the span that cuts one).
 
 ``--merge`` (round 23) stitches the per-process trace files of a live
 ``tools/fleet.py`` run into ONE Perfetto-loadable timeline: each file
@@ -38,7 +50,9 @@ parsing half of tests/test_trace.py and tests/test_overlap.py.
 from __future__ import annotations
 
 import argparse
+import bisect
 import json
+import statistics
 import sys
 from collections import defaultdict
 
@@ -225,6 +239,60 @@ def batch_table(events: list[dict]) -> list[dict]:
     return [rows[b] for b in sorted(rows)]
 
 
+def cut_window(events: list[dict], lo: int, hi: int) -> tuple:
+    """``(t0_us, t1_us)``: the ends of the spans that cut batches
+    ``lo`` and ``hi``."""
+    cuts = {e["args"]["batch"]: e["ts"] + e.get("dur", 0.0)
+            for e in complete_spans(events)
+            if e["name"] == "sink.accumulate" and "batch" in e.get("args", {})}
+    if lo not in cuts or hi not in cuts:
+        raise ValueError(f"no sink.accumulate span cut batch {lo} or {hi}")
+    return cuts[lo], cuts[hi]
+
+
+def split_by_overlap(events: list[dict], name: str, against,
+                     t0_us: float = None, t1_us: float = None) -> dict:
+    """``{"covered": stats, "clear": stats}`` over the spans called
+    ``name`` (those that start in [t0_us, t1_us], if given): covered
+    where spans of the ``against`` names cover at least half of the
+    span. ``stats`` is ``{"n", "median_ms", "mean_ms", "share"}``,
+    ``share`` the group's mean covered share."""
+    spans = complete_spans(events)
+    cover: list[list[float]] = []  # the union of the `against` spans
+    for e in spans:
+        if e["name"] not in against:
+            continue
+        lo, hi = e["ts"], e["ts"] + e.get("dur", 0.0)
+        if cover and lo <= cover[-1][1]:
+            cover[-1][1] = max(cover[-1][1], hi)
+        else:
+            cover.append([lo, hi])
+    ends = [hi for _lo, hi in cover]
+    groups = {"covered": [], "clear": []}
+    for e in spans:
+        if e["name"] != name or not e.get("dur"):
+            continue
+        lo, hi = e["ts"], e["ts"] + e["dur"]
+        if (t0_us is not None and lo < t0_us) or (
+                t1_us is not None and lo > t1_us):
+            continue
+        got = 0.0
+        for a, b in cover[bisect.bisect_right(ends, lo):]:
+            if a >= hi:
+                break
+            got += min(b, hi) - max(a, lo)
+        share = got / e["dur"]
+        groups["covered" if share >= 0.5 else "clear"].append(
+            (e["dur"] / 1e3, share))
+    return {
+        key: ({"n": len(rows),
+               "median_ms": statistics.median(ms for ms, _s in rows),
+               "mean_ms": statistics.fmean(ms for ms, _s in rows),
+               "share": statistics.fmean(s for _ms, s in rows)}
+              if rows else {"n": 0})
+        for key, rows in groups.items()}
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("trace", nargs="+",
@@ -245,6 +313,14 @@ def main(argv=None) -> int:
                     help="print this ingest batch's lineage")
     ap.add_argument("--batches", action="store_true",
                     help="print one row per ingest batch")
+    ap.add_argument("--split", default="",
+                    help="span name to split by what overlaps it")
+    ap.add_argument("--against", default="",
+                    help="comma-separated span names that cover (with "
+                         "--split)")
+    ap.add_argument("--between", default="",
+                    help="LO,HI: only spans that start between the cuts "
+                         "of these two batches (with --split)")
     args = ap.parse_args(argv)
     stages = [s for s in args.stages.split(",") if s] or None
     if args.merge:
@@ -260,6 +336,17 @@ def main(argv=None) -> int:
         return 2
     else:
         events = load(args.trace[0])
+    if args.split:
+        against = {s for s in args.against.split(",") if s}
+        window = (cut_window(events, *map(int, args.between.split(",")))
+                  if args.between else (None, None))
+        split = split_by_overlap(events, args.split, against, *window)
+        print(f"{args.split} by {'+'.join(sorted(against))}")
+        for key, st in split.items():
+            print(f"{key:>8} {st['n']:>6}" + (
+                f"  median {st['median_ms']:.3f} ms  mean {st['mean_ms']:.3f}"
+                f" ms  covered {100 * st['share']:.1f}%" if st["n"] else ""))
+        return 0 if any(st["n"] for st in split.values()) else 1
     if args.batch:
         lines = batch_lineage(events, args.batch)
         print("\n".join(lines) or f"no span carries batch={args.batch}")
