@@ -264,11 +264,16 @@ class MembershipOracle:
 
     def _run_batch(self, items: list) -> list:
         view = self.snapshots.view()
+        # The view that answers, on the batcher's serve.batch too: its
+        # epoch is a snapshot.capture's, so a batch joins its capture.
+        answered_by = {"epoch": view.epoch,
+                       "age_ms": round(view.age_s() * 1e3, 3)}
+        trace.annotate(**answered_by)
         with trace.span(
                 "serve.lookup", cat="serve", lanes=len(items),
-                epoch=view.epoch, device=int(view._device),
+                device=int(view._device),
                 replica=(-1 if view.replica_ix is None
-                         else int(view.replica_ix))):
+                         else int(view.replica_ix)), **answered_by):
             known = view.lookup(items)
         age = view.age_s()
         return [(bool(k), view.epoch, age) for k in known]
@@ -393,6 +398,17 @@ def _parse_query(q: dict, oracle: MembershipOracle):
     except ValueError as err:
         raise ValueError(f"bad expDate {exp!r}: {err}") from None
     return (oracle.resolve_issuer(issuer), eh, serial)
+
+
+class _QueryHTTPServer(ThreadingHTTPServer):
+    # The standard library listens with a backlog of 5. Independent
+    # clients arrive in bursts (after any pause of this process or its
+    # machine, everything that was due meanwhile arrives at once), and
+    # connections beyond the backlog are dropped in the kernel, where
+    # no 429 says so: they come back in step, a second, three and seven
+    # seconds later, and collide again. The admission queue sheds load
+    # (``Overloaded``); the socket must not.
+    request_queue_size = 1024
 
 
 class QueryServer:
@@ -769,7 +785,7 @@ class QueryServer:
             def log_message(self, *args):  # no per-request stderr spam
                 pass
 
-        self._server = ThreadingHTTPServer((self.host, self.port), Handler)
+        self._server = _QueryHTTPServer((self.host, self.port), Handler)
         self.port = self._server.server_address[1]  # resolve port 0
         self._thread = threading.Thread(
             target=self._server.serve_forever, name="query-serve",

@@ -125,6 +125,7 @@ class TableView:
         dev_rows=None,
         created_wall: Optional[float] = None,
         verify_counts: Optional[dict] = None,
+        through_entries: int = 0,
     ) -> None:
         self.epoch = epoch
         self.rows = rows  # host mirror; None while the view is on device
@@ -154,6 +155,13 @@ class TableView:
         self._dev_blocks = (None if dev_rows is None
                             else _shard_blocks(dev_rows, layout))
         self.replica_ix = None  # pool slot this view serves from
+        # Entries folded into the aggregate when the capture held the
+        # fold lock: what this view is known to include.
+        self.through_entries = int(through_entries)
+        # Table bytes this view's capture moved over the host link, in
+        # either direction: none for a device view, the rows read out
+        # for a host mirror.
+        self.host_bytes = 0 if rows is None else int(rows.nbytes)
 
     @property
     def _device(self) -> bool:
@@ -187,6 +195,7 @@ class TableView:
         is then dropped."""
         incr_counter("serve", "device_fallback")
         self.rows = np.asarray(self._dev_rows)
+        self.host_bytes += int(self.rows.nbytes)
         self._dev_rows = None
         self._dev_blocks = None
 
@@ -363,8 +372,9 @@ def capture_view(agg, epoch: int, device: bool = False) -> TableView:
     per-field property reads."""
     t0 = time.time()
     rows = dev_rows = None
-    with agg._fold_lock:
-        with agg._table_lock:
+    with agg._fold_lock, trace.span("snapshot.locked", cat="serve"):
+        with agg._table_lock, \
+                trace.span("snapshot.copy_dispatch", cat="serve"):
             dedup = getattr(agg, "dedup", None)
             if dedup is not None:  # mesh-sharded: global row view
                 live = dedup.rows
@@ -385,13 +395,17 @@ def capture_view(agg, epoch: int, device: bool = False) -> TableView:
                     incr_counter("serve", "device_fallback")
             if dev_rows is None:
                 rows = np.asarray(live)
-        host_serials = {k: frozenset(v)
-                        for k, v in agg.host_serials.items() if v}
-        issuer_totals = agg.issuer_totals.copy()
-        crl_counts = {i: len(s) for i, s in agg.crl_sets.items()}
-        dn_counts = {i: len(s) for i, s in agg.dn_sets.items()}
-        verify_counts = agg.verify_counts()
-        table_fill = agg._table_fill
+        with trace.span("snapshot.host_freeze", cat="serve"):
+            host_serials = {k: frozenset(v)
+                            for k, v in agg.host_serials.items() if v}
+            issuer_totals = agg.issuer_totals.copy()
+            crl_counts = {i: len(s) for i, s in agg.crl_sets.items()}
+            dn_counts = {i: len(s) for i, s in agg.dn_sets.items()}
+            verify_counts = agg.verify_counts()
+            table_fill = agg._table_fill
+            # Every folded lane ends in exactly one of the three.
+            through = (agg.metrics["inserted"] + agg.metrics["known"]
+                       + agg.metrics["host_lane"])
     return TableView(
         epoch=epoch, rows=rows, layout=layout, n_shards=n_shards,
         max_probes=agg.max_probes, base_hour=agg.base_hour,
@@ -402,6 +416,7 @@ def capture_view(agg, epoch: int, device: bool = False) -> TableView:
         dev_rows=dev_rows,
         created_wall=t0,
         verify_counts=verify_counts,
+        through_entries=through,
     )
 
 
@@ -459,26 +474,41 @@ class ReplicaPool:
         make that window observable."""
         return self._refreshing
 
+    def _next_slot(self) -> int:
+        """The slot the next capture lands in: the first empty one,
+        else the stalest replica's. Caller holds ``_lock``; captures
+        are one at a time, so the answer holds until it is adopted."""
+        if len(self._replicas) < self.n_replicas:
+            return len(self._replicas)
+        return min(range(len(self._replicas)),
+                   key=lambda i: self._replicas[i].epoch)
+
     def _capture(self) -> TableView:
         with self._lock:
             self._epoch += 1
             epoch = self._epoch
+            slot = self._next_slot()
+        # snapshot.capture's self time is the wait for the fold lock.
         with trace.span("serve.snapshot", cat="serve", epoch=epoch), \
-                measure("serve", "replica_swap_s"):
+                measure("serve", "replica_swap_s"), \
+                trace.span("snapshot.capture", cat="serve", epoch=epoch,
+                           replica=slot) as cap:
             v = capture_view(self._agg, epoch, device=self._device)
-            v.pin()  # wait on THIS thread, not the serving path
+            with trace.span("snapshot.wait_copy", cat="serve"):
+                v.pin()  # wait on THIS thread, not the serving path
+            cap.set(through_entries=v.through_entries,
+                    host_bytes=v.host_bytes)
+        v.replica_ix = slot
+        incr_counter("snapshot", "copies")
+        incr_counter("snapshot", "host_bytes", value=float(v.host_bytes))
         return v
 
     def _adopt(self, v: TableView) -> None:
         with self._lock:
-            if len(self._replicas) < self.n_replicas:
-                v.replica_ix = len(self._replicas)
+            if v.replica_ix == len(self._replicas):
                 self._replicas.append(v)
             else:
-                stale = min(range(len(self._replicas)),
-                            key=lambda i: self._replicas[i].epoch)
-                v.replica_ix = stale
-                self._replicas[stale] = v
+                self._replicas[v.replica_ix] = v
             n = len(self._replicas)
         incr_counter("serve", "replica_refresh")
         set_gauge("serve", "replicas", value=float(n))

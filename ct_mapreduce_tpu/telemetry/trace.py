@@ -481,6 +481,15 @@ def span(name: str, cat: str = "", **args):
     return t.span(name, cat, **args)
 
 
+def annotate(**args) -> None:
+    """Add arguments to the innermost span open on the calling thread
+    (:meth:`_Span.set` from below it: the callee learns what the
+    caller's span should say). Nothing where no span is open."""
+    stack = getattr(_ctx, "stack", None)
+    if stack:
+        stack[-1].set(**args)
+
+
 def instant(name: str, cat: str = "", **args) -> None:
     t = _tracer
     if t is not None:

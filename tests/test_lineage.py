@@ -293,6 +293,32 @@ def test_identity_arguments_pass_down_the_stack():
     assert ev["t.child"]["parent"] == ev["t.root"]["id"]
 
 
+def test_annotate_reaches_the_innermost_open_span_only():
+    """``trace.annotate``: the callee names what the caller's span
+    should say (``serve.batch`` learns the epoch that answered it);
+    nothing where no span is open, on another thread, or with the
+    tracer off."""
+    trace.annotate(lost=1)  # tracer off, no span: nothing, no error
+    trace.enable()
+    trace.annotate(lost=2)
+
+    def other_thread():
+        trace.annotate(lost=3)
+
+    with trace.span("t.outer", k=1):
+        with trace.span("t.inner"):
+            trace.annotate(epoch=5, age_ms=1.5)
+            t = threading.Thread(target=other_thread)
+            t.start()
+            t.join()
+        with trace.span("t.sibling"):
+            pass
+    ev = {e["name"]: e for e in trace.snapshot_events() if e["ph"] != "M"}
+    assert ev["t.inner"]["args"] == {"epoch": 5, "age_ms": 1.5}
+    assert ev["t.outer"]["args"] == {"k": 1}
+    assert "args" not in ev["t.sibling"]
+
+
 def test_a_reused_thread_ident_keeps_every_name():
     """The OS hands a dead thread's ident to the next thread: each
     still gets its own thread_name record."""

@@ -1094,3 +1094,173 @@ def test_resolve_serve_layering(monkeypatch):
         (3, True, 16)  # explicit beats env
     monkeypatch.setenv("CTMR_SERVE_REPLICAS", "banana")
     assert resolve_serve()[0] == 2  # unparseable env ignored
+
+
+# -- the snapshot. span family (ISSUE 33) -----------------------------------
+
+
+def _traced_spans(tracer, t0):
+    return [e for e in tracer.events()
+            if e.get("ph") == "X" and e["ts"] >= t0]
+
+
+@pytest.mark.parametrize("device", [True, False],
+                         ids=["device-views", "host-views"])
+def test_capture_emits_the_snapshot_family(template, device):
+    """One capture is one ``snapshot.capture`` under ``serve.snapshot``
+    with its four children, each inside its parent's time; it says
+    which replica it fills, through which folded entry it reads and how
+    many table bytes crossed the host link: none for a device copy, the
+    table's (one direction: read out) for a host mirror."""
+    from ct_mapreduce_tpu.telemetry import metrics as tmetrics
+    from ct_mapreduce_tpu.telemetry import trace
+
+    agg = TpuAggregator(capacity=1 << 12, batch_size=64)
+    agg.ingest([(syncerts.stamp_serial(template, j), template.issuer_der)
+                for j in range(40)])
+    table_bytes = int(np.asarray(agg.table.rows).nbytes)
+    sink = tmetrics.InMemSink()
+    prev = tmetrics.get_sink()
+    tmetrics.set_sink(sink)
+    tracer = trace.enable()
+    t0 = tracer.now_us()
+    try:
+        pool = ReplicaPool(agg, n_replicas=2, device=device)
+        view = pool.refresh()
+        spans = _traced_spans(tracer, t0)
+    finally:
+        trace.disable()
+        tmetrics.set_sink(prev)
+    by_name = {e["name"]: e for e in spans}
+    assert sorted(by_name) == [
+        "serve.snapshot", "snapshot.capture", "snapshot.copy_dispatch",
+        "snapshot.host_freeze", "snapshot.locked", "snapshot.wait_copy"]
+    capture = by_name["snapshot.capture"]
+    assert capture["parent"] == by_name["serve.snapshot"]["id"]
+    assert by_name["snapshot.locked"]["parent"] == capture["id"]
+    assert by_name["snapshot.wait_copy"]["parent"] == capture["id"]
+    for inner in ("snapshot.copy_dispatch", "snapshot.host_freeze"):
+        assert by_name[inner]["parent"] == by_name["snapshot.locked"]["id"]
+    for e in spans:
+        if e["parent"]:
+            outer = next(p for p in spans if p["id"] == e["parent"])
+            assert outer["ts"] <= e["ts"]
+            assert e["ts"] + e["dur"] <= outer["ts"] + outer["dur"]
+    want_bytes = 0 if device else table_bytes
+    assert capture["args"] == {
+        "epoch": 1, "replica": 0, "through_entries": 40,
+        "host_bytes": want_bytes}
+    assert view.replica_ix == 0 and view.through_entries == 40
+    counters = sink.snapshot()["counters"]
+    assert counters["snapshot.copies"] == 1
+    assert counters["snapshot.host_bytes"] == want_bytes
+
+
+def test_a_batch_names_the_capture_that_made_its_view(template):
+    """``serve.batch`` and ``serve.lookup`` carry the ``epoch`` and
+    ``age_ms`` of the view that answered, and that epoch is one a
+    ``snapshot.capture`` finished before the batch began."""
+    from ct_mapreduce_tpu.telemetry import trace
+
+    agg = TpuAggregator(capacity=1 << 12, batch_size=64)
+    agg.ingest([(syncerts.stamp_serial(template, j), template.issuer_der)
+                for j in range(16)])
+    issuer_id, eh = _identity(template)
+    idx = agg.registry.index_of_issuer_id(issuer_id)
+    tracer = trace.enable()
+    t0 = tracer.now_us()
+    try:
+        oracle = MembershipOracle(agg, max_batch=64, max_delay_s=0.001,
+                                  max_staleness_s=1e9, replicas=2,
+                                  cache_size=0)
+        try:
+            oracle.snapshots.warm()
+            for j in range(6):
+                assert oracle.query_raw(
+                    [(idx, eh, _serial_bytes(template, j))])[0][0] is True
+        finally:
+            oracle.close()
+        spans = _traced_spans(tracer, t0)
+    finally:
+        trace.disable()
+    captures = {e["args"]["epoch"]: e for e in spans
+                if e["name"] == "snapshot.capture"}
+    assert sorted(captures) == [1, 2]
+    assert [captures[k]["args"]["replica"] for k in (1, 2)] == [0, 1]
+    batches = [e for e in spans if e["name"] == "serve.batch"]
+    lookups = {e["parent"]: e for e in spans if e["name"] == "serve.lookup"}
+    assert len(batches) == 6
+    assert {b["args"]["epoch"] for b in batches} == {1, 2}
+    for b in batches:
+        made = captures[b["args"]["epoch"]]
+        assert made["ts"] + made["dur"] <= b["ts"]
+        assert b["args"]["age_ms"] >= 0.0
+        inner = lookups[b["id"]]["args"]
+        assert (inner["epoch"], inner["age_ms"]) == (
+            b["args"]["epoch"], b["args"]["age_ms"])
+        assert inner["replica"] == made["args"]["replica"]
+
+
+def test_the_copy_keeps_the_name_the_device_trace_is_matched_on():
+    """``benchmark/readers/copy_roofline.py`` finds the copies by their
+    XLA module, ``jit_snapshot_copy`` (docs/METRICS.md)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ct_mapreduce_tpu.serve.snapshot import snapshot_copy
+
+    rows = jax.ShapeDtypeStruct((64, 8), jnp.uint32)
+    assert "@jit_snapshot_copy" in snapshot_copy.lower(rows).as_text()
+
+
+def test_a_burst_of_connections_is_queued_not_dropped(template):
+    """100 clients connect in the same instant, as they do after any
+    pause of the process or its machine (on the chip a 2.3 s freeze
+    turned into 107 requests with no answer in 10 s: PERF.md, PR 33).
+    Beyond the standard library's backlog of 5 the kernel drops them
+    and they return in step, 1, 3 and 7 s later; with room to wait in,
+    every one is answered at once."""
+    import asyncio
+
+    agg = TpuAggregator(capacity=1 << 12, batch_size=64)
+    agg.ingest([(syncerts.stamp_serial(template, 1), template.issuer_der)])
+    issuer_id, eh = _identity(template)
+    srv = QueryServer(agg, 0, host="127.0.0.1").start()
+    body = json.dumps({"issuer": issuer_id,
+                       "expDate": ExpDate.from_unix_hour(eh).id(),
+                       "serial": _serial_bytes(template, 1).hex()}).encode()
+    request = (b"POST /query HTTP/1.1\r\nHost: x\r\nConnection: close\r\n"
+               b"Content-Type: application/json\r\nContent-Length: %d\r\n\r\n"
+               % len(body)) + body
+
+    async def ask() -> tuple[float, bytes]:
+        t0 = time.monotonic()
+        reader, writer = await asyncio.open_connection("127.0.0.1", srv.port)
+        writer.write(request)
+        await writer.drain()
+        raw = await reader.read(-1)
+        writer.close()
+        return time.monotonic() - t0, raw
+
+    async def burst():
+        return await asyncio.wait_for(
+            asyncio.gather(*[ask() for _ in range(100)]), timeout=120)
+
+    idx = agg.registry.index_of_issuer_id(issuer_id)
+    try:
+        srv.oracle.snapshots.warm()
+        for lanes in (16, 32, 64, 128, 256):  # compile every batch width
+            srv.oracle.query_raw(
+                [(idx, eh, _serial_bytes(template, 1000 * lanes + j))
+                 for j in range(lanes)])
+        answers = asyncio.run(burst())
+    finally:
+        srv.stop()
+    assert all(raw.startswith(b"HTTP/1.0 200") or raw.startswith(
+        b"HTTP/1.1 200") for _s, raw in answers)
+    assert all(b'"known": true' in raw for _s, raw in answers)
+    # Dropped connections come back 1, 3, 7 and 15 s later, and by the
+    # third wave some still collide; a burst that was queued is
+    # answered in half a second on an idle machine.
+    assert max(s for s, _raw in answers) < 6.5, sorted(
+        s for s, _raw in answers)[-5:]
