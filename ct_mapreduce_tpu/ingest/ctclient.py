@@ -19,14 +19,24 @@ Mirrors the reference's use of certificate-transparency-go's
 
 The transport is injectable — ``transport(url) -> (status, headers,
 body)`` — so tests and the zero-egress benchmark environment can serve
-synthetic logs without sockets; the default uses urllib.
+synthetic logs without sockets. The default, :func:`_default_transport`,
+talks ``http.client`` and keeps one connection a (thread, origin) open,
+as the reference's ``net/http`` client does: a log's ``get-sth`` and
+every ``get-entries`` of its downloader go over one socket (and one TLS
+session), a connection the server closed meanwhile is replaced without
+a backoff, and what ``http.client`` does not do (a redirect, a proxy
+from the environment) is handed, that request alone, to the one-shot
+``urllib`` path, :func:`_oneshot_transport`.
 """
 
 from __future__ import annotations
 
+import http.client
 import json
+import threading
 import time
 import urllib.error
+import urllib.parse
 import urllib.request
 from dataclasses import dataclass
 from typing import Callable, Optional
@@ -59,15 +69,99 @@ def short_url(url: str) -> str:
     return url.rstrip("/")
 
 
-def _urllib_transport(url: str) -> tuple[int, dict, bytes]:
-    req = urllib.request.Request(
-        url, headers={"User-Agent": "ct-mapreduce-tpu/0.1"}
-    )
+_USER_AGENT = "ct-mapreduce-tpu/0.1"
+_TIMEOUT_S = 60
+_REDIRECTS = frozenset({301, 302, 303, 307, 308})
+
+# What a kept connection raises when the far end closed it while it
+# idled or answered; on a connection just made they are the log's own.
+_STALE = (ConnectionError, http.client.BadStatusLine,
+          http.client.IncompleteRead)
+
+
+def _oneshot_transport(url: str) -> tuple[int, dict, bytes]:
+    """One request through ``urllib``'s opener, a connection of its
+    own: it follows redirects and knows the environment's proxies."""
+    req = urllib.request.Request(url, headers={"User-Agent": _USER_AGENT})
     try:
-        with urllib.request.urlopen(req, timeout=60) as resp:
+        with urllib.request.urlopen(req, timeout=_TIMEOUT_S) as resp:
             return resp.status, dict(resp.headers), resp.read()
     except urllib.error.HTTPError as err:
         return err.code, dict(err.headers or {}), err.read()
+
+
+class _KeptConnections(dict):
+    """One thread's open connections by origin. The thread's last
+    reference is its ``threading.local`` slot, so they close with it."""
+
+    def __del__(self):
+        for conn in self.values():
+            conn.close()
+
+
+_thread = threading.local()
+
+
+def _proxied(parts: urllib.parse.SplitResult) -> bool:
+    """Whether ``urllib`` would send this request to a proxy."""
+    return (bool(urllib.request.getproxies().get(parts.scheme))
+            and not urllib.request.proxy_bypass(parts.netloc))
+
+
+def _get(conn: http.client.HTTPConnection, target: str):
+    """One ``GET`` and its whole response; a connection that did not
+    give one is closed."""
+    try:
+        conn.request("GET", target, headers={"User-Agent": _USER_AGENT})
+        resp = conn.getresponse()
+        return resp, resp.read()
+    except BaseException:
+        conn.close()
+        raise
+
+
+def _default_transport(url: str) -> tuple[int, dict, bytes]:
+    """``GET url`` over the calling thread's kept connection to that
+    origin, made at the first request; the response is read whole
+    before the next request goes out. A kept connection found dead is
+    replaced and the request sent once more (``ingest.conn.stale``): a
+    server may drop an idle connection at any time, and that is no
+    error of the log. Every request counts under ``ingest.conn.opened``
+    or ``ingest.conn.reused``, and says which on the caller's open span
+    (``fetch.get_entries``' ``reused``)."""
+    parts = urllib.parse.urlsplit(url)
+    kept = getattr(_thread, "kept", None)
+    if kept is None:
+        kept = _thread.kept = _KeptConnections()
+    origin = (parts.scheme, parts.netloc)
+    conn = kept.pop(origin, None)
+    if conn is None and (parts.scheme not in ("http", "https")
+                         or _proxied(parts)):
+        return _oneshot_transport(url)
+    target = (parts.path or "/") + ("?" + parts.query if parts.query else "")
+    reused = conn is not None
+    if reused:
+        try:
+            resp, body = _get(conn, target)
+        except _STALE:
+            incr_counter("ingest", "conn", "stale")
+            reused = False
+    if not reused:
+        make = (http.client.HTTPSConnection if parts.scheme == "https"
+                else http.client.HTTPConnection)
+        conn = make(parts.hostname, parts.port, timeout=_TIMEOUT_S)
+        incr_counter("ingest", "conn", "opened")
+        resp, body = _get(conn, target)
+    else:
+        incr_counter("ingest", "conn", "reused")
+    trace.annotate(reused=int(reused))
+    if resp.will_close:
+        conn.close()
+    else:
+        kept[origin] = conn
+    if resp.status in _REDIRECTS and resp.headers.get("Location"):
+        return _oneshot_transport(url)
+    return resp.status, dict(resp.headers), body
 
 
 @dataclass
@@ -130,7 +224,7 @@ class CTLogClient:
             log_url = "https://" + log_url
         self.log_url = log_url.rstrip("/")
         self.short_url = short_url(log_url)
-        self.transport = transport or _urllib_transport
+        self.transport = transport or _default_transport
         self.sleep = sleep
         self.max_retries = max_retries
         # Adaptive get-entries window: starts at the spec maximum and

@@ -41,7 +41,7 @@ def _patch_transport(monkeypatch, log):
     """Route CTLogClient's default transport to the fake log."""
     from ct_mapreduce_tpu.ingest import ctclient
 
-    monkeypatch.setattr(ctclient, "_urllib_transport", log.transport)
+    monkeypatch.setattr(ctclient, "_default_transport", log.transport)
 
 
 @pytest.mark.parametrize("mesh_shape,expect_sharded", [

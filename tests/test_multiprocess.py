@@ -286,8 +286,8 @@ def test_fleet_kill_and_resume(tmp_path, compile_cache):
         resume_state = cache.load_log_state(short_url(url))
         resume_cursor = resume_state.max_entry if resume_state else 0
         transport = harness.FixtureTransport(fixture)
-        orig_transport = ctclient._urllib_transport
-        ctclient._urllib_transport = transport
+        orig_transport = ctclient._default_transport
+        ctclient._default_transport = transport
         try:
             ini = os.path.join(wdir, "resume.ini")
             harness.write_worker_ini(
@@ -295,7 +295,7 @@ def test_fleet_kill_and_resume(tmp_path, compile_cache):
                 checkpoint_period="300ms", coordinator="redis")
             rc = ct_fetch.main(["-config", ini, "-nobars"])
         finally:
-            ctclient._urllib_transport = orig_transport
+            ctclient._default_transport = orig_transport
         cache.close()
     finally:
         server.stop()
@@ -356,10 +356,10 @@ def test_fleet_healthz_section_served_live(tmp_path):
             time.sleep(0.05)
 
     server = MiniRedis().start()
-    orig_transport = ctclient._urllib_transport
+    orig_transport = ctclient._default_transport
     paced = harness.FixtureTransport(fixture, throttle_ms=150)
     paced.max_batch = 16
-    ctclient._urllib_transport = paced
+    ctclient._default_transport = paced
     poller = threading.Thread(target=poll, daemon=True)
     try:
         harness.write_worker_ini(
@@ -371,7 +371,7 @@ def test_fleet_healthz_section_served_live(tmp_path):
     finally:
         stop.set()
         poller.join(5)
-        ctclient._urllib_transport = orig_transport
+        ctclient._default_transport = orig_transport
         server.stop()
     assert rc == 0
     assert bodies, "no /healthz body carried the fleet section"
@@ -647,8 +647,8 @@ def test_fleet_kill_points_ck02(tmp_path, compile_cache, scenario,
         from ct_mapreduce_tpu.cmd import ct_fetch
 
         transport = harness.FixtureTransport(fixture)
-        orig_transport = ctclient._urllib_transport
-        ctclient._urllib_transport = transport
+        orig_transport = ctclient._default_transport
+        ctclient._default_transport = transport
         try:
             ini = os.path.join(wdir, "resume.ini")
             harness.write_worker_ini(
@@ -656,7 +656,7 @@ def test_fleet_kill_points_ck02(tmp_path, compile_cache, scenario,
                 checkpoint_period="300ms", coordinator="redis")
             rc = ct_fetch.main(["-config", ini, "-nobars"])
         finally:
-            ctclient._urllib_transport = orig_transport
+            ctclient._default_transport = orig_transport
     finally:
         server.stop()
     assert rc == 0

@@ -89,7 +89,7 @@ def test_ct_fetch_names_its_device(tmp_path, monkeypatch, capsys):
     from ct_mapreduce_tpu.ingest import ctclient
 
     monkeypatch.setattr(
-        ctclient, "_urllib_transport",
+        ctclient, "_default_transport",
         lambda url: (200, {}, b'{"tree_size": 0, "timestamp": 0}'))
     ct_fetch.main(["-config", str(_tpu_ini(tmp_path)), "-nobars"])
     dev = jax.devices()[0]

@@ -138,7 +138,7 @@ def install_transport(fixture: dict, throttle_ms: float = 0.0) -> None:
     """Route CTLogClient's default transport to the fixture."""
     from ct_mapreduce_tpu.ingest import ctclient
 
-    ctclient._urllib_transport = FixtureTransport(fixture, throttle_ms)
+    ctclient._default_transport = FixtureTransport(fixture, throttle_ms)
 
 
 # -- snapshots -----------------------------------------------------------
@@ -403,12 +403,12 @@ def run_serial_reference(fixture: dict, state_dir: str,
     ini = os.path.join(state_dir, "serial.ini")
     state = os.path.join(state_dir, "serial.npz")
     write_worker_ini(ini, fixture, state)
-    orig_transport = ctclient._urllib_transport
+    orig_transport = ctclient._default_transport
     install_transport(fixture)
     try:
         rc = ct_fetch.main(["-config", ini, "-nobars"])
     finally:
-        ctclient._urllib_transport = orig_transport
+        ctclient._default_transport = orig_transport
     if rc != 0:
         raise RuntimeError(f"serial reference run failed rc={rc}")
     agg = HostSnapshotAggregator(capacity=1 << 10)
