@@ -65,6 +65,15 @@ from ct_mapreduce_tpu.telemetry.metrics import (
 )
 
 
+def _donating_backend() -> bool:
+    """Whether the walker step donates its row buffer: everywhere but
+    on the CPU backend, whose XLA cannot alias this layout and warns on
+    every dispatch."""
+    import jax
+
+    return jax.default_backend() != "cpu"
+
+
 # Layout selection lives beside the insert dispatch (CTMR_TABLE,
 # default bucket); re-exported here for the aggregator's callers.
 _table_layout = pipeline.table_layout
@@ -1166,6 +1175,13 @@ class TpuAggregator:
         for start in range(0, n, self.batch_size):
             end = min(start + self.batch_size, n)
             m = end - start
+            # Every dispatch says how far short of the batch it was (0
+            # for a whole one), so a reader tells "none was short" from
+            # "this program does not count them".
+            incr_counter("ingest", "partial_batches",
+                         value=float(m < self.batch_size))
+            incr_counter("ingest", "partial_lanes",
+                         value=float(self.batch_size - m))
             if m == self.batch_size:
                 batch = packing.PackedBatch(
                     data[start:end], length[start:end],
@@ -1735,21 +1751,29 @@ class TpuAggregator:
         self._device_written = True
         import jax
 
-        # Device-resident rows (the overlapped/pipelined ingest path
-        # device_puts them ahead of the dispatch) are donated through
-        # the step — the caller keeps a host copy for host-lane slices,
-        # so the row buffer is dead weight after this dispatch and XLA
-        # may reuse its HBM. NumPy rows keep the non-donating wrapper,
-        # as does the CPU backend (its XLA can't alias this layout and
-        # warns on every dispatch).
-        step = (pipeline.ingest_step_donated
-                if isinstance(batch.data, jax.Array)
-                and jax.default_backend() != "cpu"
-                else pipeline.ingest_step)
+        # One program a row shape, whichever way the rows came: rows
+        # already on the device (the pipelined ingest path device_puts
+        # them ahead of the dispatch) and NumPy rows (a chunk short of
+        # the batch, padded on the host; the per-entry lane) both go
+        # through the donating step, the second kind put on the device
+        # here. Two wrappers were two programs, and the second compiled
+        # (minutes, on the chip) at the first short chunk. The row
+        # buffer is donated — the caller keeps a host copy for
+        # host-lane slices, so it is dead weight after this dispatch
+        # and XLA may reuse its HBM. The CPU backend keeps the
+        # non-donating wrapper for both kinds (its XLA can't alias this
+        # layout and warns on every dispatch).
+        data = batch.data
+        if _donating_backend():
+            step = pipeline.ingest_step_donated
+            if not isinstance(data, jax.Array):
+                data = jax.device_put(data)
+        else:
+            step = pipeline.ingest_step
         with trace.span("device.step", cat="device"), self._table_lock:
             self.table, out = step(
                 self.table,
-                batch.data,
+                data,
                 batch.length,
                 batch.issuer_idx,
                 batch.valid,

@@ -329,7 +329,8 @@ class AggregatorSink:
                 self.entries_in += len(raw)
                 if len(self._pending_raw) >= self.flush_size:
                     chunk = self._cut_raw()
-                    sp.set(batch=chunk.batch, pages=chunk.pages)
+                    sp.set(batch=chunk.batch, pages=chunk.pages,
+                           logs=len({page[0] for page in chunk.pages}))
         if chunk:
             self._dispatch_raw(chunk)
 
@@ -1054,10 +1055,14 @@ class LogWorker:
         limit: int = 0,
         pre_save=None,
         state_suffix: str = "",
+        exit_save=None,
     ):
         self.client = client
         self.database = database
         self.pre_save = pre_save  # runs before each durable cursor write
+        # Takes the worker at its clean exit in place of ``save_state``:
+        # the engine's one checkpoint a round (LogSyncEngine._exit_save).
+        self.exit_save = exit_save
         # Fleet stripe mode (ingest/fleet.py::partition_range): workers
         # share one log but own disjoint [offset, offset+limit) index
         # ranges, so each stripe keeps its OWN durable cursor under
@@ -1106,17 +1111,27 @@ class LogWorker:
         aggregate snapshot) at its next batch boundary."""
         self._save_signal.set()
 
-    def save_state(self, reason: str = "exit") -> None:
+    @property
+    def moved(self) -> bool:
+        """Whether the cursor stands anywhere but where it is durable:
+        a log that gave nothing since its last save has nothing for a
+        checkpoint to cover."""
+        return self.position != self.log_state.max_entry
+
+    def save_state(self, reason: str = "exit", covered: bool = False) -> None:
         """Persist the cursor (ct-fetch.go:371-392): dual-written by
         the database facade (cache + backend). ``pre_save`` (e.g. the
         aggregate snapshot) must succeed first — a cursor must never
         durably advance past entries whose aggregation isn't durable.
         ``reason`` (exit / savePeriod / fleet) names the save in the
-        trace, and the checkpoint it causes."""
+        trace, and the checkpoint it causes. ``covered``: the caller
+        has a checkpoint on disk that holds every entry up to
+        ``position`` (or the cursor did not move), so ``pre_save`` is
+        not run again for this log."""
         with trace.span("fetch.save_cursor", cat="fetch",
                         log=self.client.short_url, position=self.position,
                         reason=reason):
-            if self.pre_save is not None:
+            if self.pre_save is not None and not covered:
                 self.pre_save()
             self.log_state.max_entry = self.position
             if self.last_entry_time is not None:
@@ -1167,7 +1182,10 @@ class LogWorker:
                     "LogWorker", self.client.short_url, "saveStateError"
                 )
             raise
-        self.save_state()
+        if self.exit_save is not None:
+            self.exit_save(self)
+        else:
+            self.save_state()
         return enqueued
 
     def _run_loop(
@@ -1437,6 +1455,22 @@ class LogSyncEngine:
         # tick can ask each to save at its next batch boundary.
         self._active_workers: list[LogWorker] = []
         self._active_lock = threading.Lock()
+        # One checkpoint a round (_exit_save): the seats of the
+        # downloaders that still fetch, the workers that have reached
+        # their end and wait for the checkpoint that covers them, how a
+        # save that claimed one fared (did its cursor land), and
+        # whether a checkpoint is being written now.
+        self._round_cond = threading.Condition()
+        self._fetching: set = set()
+        self._round_logs = 0
+        self._parked: list[LogWorker] = []
+        self._covered: dict[LogWorker, bool] = {}
+        self._saving = 0
+        # When anything last moved (a response enqueued, stored, a
+        # checkpoint finished) and the longest the round has seen
+        # nothing move: what a parked log judges a standstill by.
+        self._moved_at = time.monotonic()
+        self._longest_pause = 0.0
 
     # -- health surface (ct-fetch.go:567-597) ---------------------------
     def last_updates(self) -> dict[str, datetime]:
@@ -1496,6 +1530,7 @@ class LogSyncEngine:
             self._outstanding[item.log_url] = (
                 self._outstanding.get(item.log_url, 0) + n
             )
+            self._note_moved()
 
     def _account_stored(self, item) -> None:
         n = _entries_of(item)
@@ -1503,21 +1538,156 @@ class LogSyncEngine:
             self._outstanding[item.log_url] = (
                 self._outstanding.get(item.log_url, 0) - n
             )
+            self._note_moved()
             self._outstanding_cond.notify_all()
 
-    def _pre_cursor_save(self, log_url: str) -> None:
+    def _note_moved(self) -> None:
+        """Something moved: a response went into the channel or through
+        the sink, a checkpoint ended. Caller holds ``_outstanding_cond``."""
+        now = time.monotonic()
+        self._longest_pause = max(self._longest_pause, now - self._moved_at)
+        self._moved_at = now
+
+    # -- one checkpoint a round -------------------------------------------
+    # A cursor save costs a checkpoint of the whole table (the reference
+    # pays a Redis write), so a round of N logs must not pay N. A log
+    # that reaches its end while others of the round still fetch parks:
+    # it neither flushes the shared accumulator nor saves. Whoever saves
+    # next — the round's last downloader at its own exit, a savePeriod
+    # or fleet tick of a running one, an error-path save — writes ONE
+    # checkpoint and after it the cursor of every parked log whose
+    # entries had all passed the sink before that checkpoint's flush.
+    # The order of the guarantee is unchanged for every log: the
+    # aggregate on disk covers an entry before any cursor on disk does.
+    STILL_FLOOR_S = 2.0  # a standstill is at least this long ...
+    STILL_PAUSES = 4.0  # ... and this many of the round's longest pause
+
+    def _join_round(self) -> object:
+        """A downloader starts: its seat among those still fetching."""
+        seat = object()
+        with self._round_cond:
+            if not self._fetching and not self._parked:
+                self._round_logs = 0
+                with self._outstanding_cond:
+                    self._moved_at = time.monotonic()
+                    self._longest_pause = 0.0
+            self._fetching.add(seat)
+            self._round_logs += 1
+        return seat
+
+    def _leave_round(self, seat: object) -> None:
+        """The downloader fetches no more (whoever says so first)."""
+        with self._round_cond:
+            self._fetching.discard(seat)
+            self._round_cond.notify_all()
+
+    def _standstill_in_s(self) -> float:
+        """Seconds until the parked caller may call the running
+        downloaders stood still (0: now). Nothing of theirs went into
+        the channel and nothing through the sink for ``STILL_PAUSES``
+        times the longest such pause the round has seen (a transport
+        that blocks, a log in back-off); a checkpoint being written
+        holds everything, so the clock starts after it. Caller holds
+        ``_round_cond``."""
+        if self._saving:
+            return self.STILL_FLOOR_S
+        bound = max(self.STILL_FLOOR_S,
+                    self.STILL_PAUSES * self._longest_pause)
+        return max(0.0, bound - (time.monotonic() - self._moved_at))
+
+    def _exit_save(self, worker: LogWorker, seat: object) -> None:
+        """A downloader's clean exit (``LogWorker.exit_save``). A log
+        whose cursor did not move saves nothing. The round's last
+        downloader saves for everyone parked; one that ends earlier
+        parks until a checkpoint has covered it, or until the others
+        stand still, when it saves as a log alone would."""
+        with trace.span("round.cursor_wait", cat="round",
+                        log=worker.client.short_url,
+                        position=worker.position) as sp:
+            moved = worker.moved
+            if self.checkpoint_hook is None or not moved:
+                # No checkpoint to share (a cursor save costs a cursor
+                # write), or nothing new for one to cover.
+                sp.set(how="alone" if moved else "unmoved")
+                worker.save_state(covered=not moved)
+                return
+            closing: list[LogWorker] = []
+            with self._round_cond:
+                self._leave_round(seat)
+                self._parked.append(worker)
+                while worker in self._parked and self._fetching:
+                    wait_s = self._standstill_in_s()
+                    if wait_s <= 0.0:
+                        break
+                    self._round_cond.wait(wait_s)
+                if worker in self._parked:
+                    self._parked.remove(worker)
+                    how = "gave_up"
+                    if not self._fetching:
+                        # The round's last: everyone parked is its to
+                        # save (under this lock, so one of them closes).
+                        how = "closed"
+                        closing, self._parked = self._parked, []
+                else:  # a save claimed this log: its outcome
+                    self._round_cond.wait_for(lambda: worker in self._covered)
+                    how = ("covered" if self._covered.pop(worker)
+                           else "save_failed")
+            sp.set(how=how)
+            if how == "covered":
+                return
+            with trace.span("round.save", cat="round", reason="exit",
+                            logs=self._round_logs) as save:
+                saved = [worker, *self._pre_cursor_save(
+                    worker.client.log_url, closing)]
+                worker.save_state(covered=True)
+                save.set(cursors=len(saved), entries=sum(
+                    w.position - w.start_pos for w in saved))
+
+    def _pre_cursor_save(self, log_url: str,
+                         closing: Optional[list] = None) -> list[LogWorker]:
         """Make everything log ``log_url``'s cursor covers durable:
         wait until every entry *this log* enqueued has passed through
         the sink (a per-log watermark — the downloader is the one
         waiting, so its count only drains; other logs keep flowing),
-        then run the checkpoint hook to flush + snapshot."""
-        with trace.span("ckpt.wait_outstanding", cat="ckpt"), \
-                self._outstanding_cond:
-            self._outstanding_cond.wait_for(
-                lambda: self._outstanding.get(log_url, 0) <= 0
-            )
-        if self.checkpoint_hook is not None:
-            self.checkpoint_hook()
+        then run the checkpoint hook to flush + snapshot.
+
+        The checkpoint also covers, and this returns, the parked logs
+        whose cursors are written after it: ``closing`` (the round's
+        last downloader took them all: nobody fetches any more, so
+        their counts only drain too and are waited for) and whichever
+        others have all their entries through the sink by then."""
+        def drained(url: str) -> bool:
+            return self._outstanding.get(url, 0) <= 0
+
+        claimed = list(closing or ())
+        ok = False
+        try:
+            with trace.span("ckpt.wait_outstanding", cat="ckpt"), \
+                    self._outstanding_cond:
+                self._outstanding_cond.wait_for(lambda: all(map(
+                    drained, [log_url, *(w.client.log_url for w in claimed)])))
+            if self.checkpoint_hook is None:
+                return []
+            with self._round_cond, self._outstanding_cond:
+                claimed += [w for w in self._parked
+                            if drained(w.client.log_url)]
+                self._parked = [w for w in self._parked if w not in claimed]
+                self._saving += 1
+            try:
+                self.checkpoint_hook()
+            finally:
+                with self._round_cond, self._outstanding_cond:
+                    self._saving -= 1
+                    self._note_moved()
+            for w in claimed:
+                w.save_state(covered=True)
+            ok = True
+        finally:
+            if claimed:
+                with self._round_cond:
+                    self._covered.update(dict.fromkeys(claimed, ok))
+                    self._round_cond.notify_all()
+        return claimed
 
     # -- external checkpoint trigger (fleet epoch ticks) ----------------
     def checkpoint_now(self) -> None:
@@ -1544,6 +1714,8 @@ class LogSyncEngine:
         eff_offset = self.offset if offset is None else offset
         eff_limit = self.limit if limit is None else limit
 
+        seat = self._join_round()
+
         def run() -> None:
             worker = None
             try:
@@ -1555,6 +1727,7 @@ class LogSyncEngine:
                     # watermark key must match it.
                     pre_save=lambda: self._pre_cursor_save(client.log_url),
                     state_suffix=state_suffix,
+                    exit_save=lambda w: self._exit_save(w, seat),
                 )
                 with self._active_lock:
                     self._active_workers.append(worker)
@@ -1570,6 +1743,7 @@ class LogSyncEngine:
                 metrics.incr_counter("ct-fetch", "syncLogError")
                 self.errors.append(f"{log_url}: {err}")
             finally:
+                self._leave_round(seat)
                 if worker is not None:
                     with self._active_lock:
                         with contextlib.suppress(ValueError):

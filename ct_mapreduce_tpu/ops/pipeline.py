@@ -547,8 +547,10 @@ ingest_step_preparsed_donated = functools.partial(
 # so donating `data` lets XLA reuse ~batch-size HBM per in-flight batch
 # instead of holding the input rows live alongside the step's
 # intermediates — at deviceQueueDepth 2 that is two full batches of
-# headroom. Callers that keep NumPy rows (tail chunks, the synchronous
-# per-entry path) stay on `ingest_step`.
+# headroom. NumPy rows (a chunk short of the batch, the synchronous
+# per-entry path) are put on the device by the aggregator and take this
+# entry point too, so a row shape is one program; `ingest_step` is the
+# CPU backend's, which cannot alias the donated layout.
 ingest_step_donated = functools.partial(
     jax.jit,
     static_argnames=("num_issuers", "max_probes"),

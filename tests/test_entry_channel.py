@@ -349,8 +349,10 @@ def test_cursor_never_passes_the_sink(stalled):
     assert len(db.saves) >= (2 if stalled else len(log.starts))
     for cursor, through_sink in db.saves:
         assert cursor <= through_sink, db.saves
-    # The hook ran before each write, with the log's entries all stored.
-    assert len(hooks) >= len(db.saves)
+    # The hook ran before each write that moved the cursor, with the
+    # log's entries all stored (the exit save after a tick that already
+    # covered the last page writes the same cursor and no checkpoint).
+    assert len(hooks) >= len({cursor for cursor, _ in db.saves})
 
 
 def test_stop_while_blocked_in_put_leaves_the_cursor_on_that_page():

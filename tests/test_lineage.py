@@ -160,10 +160,14 @@ def test_parents_form_a_tree_per_thread(tmp_path):
             ("ingest.submit", "device.step"),
             ("device.readback", "device.fold"),
             ("device.fold", "fold.wait_device"),
-            ("fetch.save_cursor", "ckpt.wait_outstanding"),
-            ("fetch.save_cursor", "ckpt.save")} <= child_of
+            # The exit save is the round's (PR 36): the checkpoint is
+            # its child, and so is each cursor write it is followed by.
+            ("round.cursor_wait", "round.save"),
+            ("round.save", "ckpt.wait_outstanding"),
+            ("round.save", "ckpt.save"),
+            ("round.save", "fetch.save_cursor")} <= child_of
     roots = {e["name"] for e in events if not e["parent"]}
-    assert {"fetch.page", "fetch.save_cursor", "sink.queue_wait",
+    assert {"fetch.page", "round.cursor_wait", "sink.queue_wait",
             "sink.accumulate", "ingest.decode", "ingest.submit_locked",
             "ckpt.save"} <= roots
 
@@ -192,7 +196,8 @@ def test_checkpoint_save_has_its_three_phases(tmp_path):
     """The cursor save's checkpoint is a full base (a count-only sink
     cannot extend a chain): d2h, write and seal are its children and
     fit inside it; the round's own save after it has nothing to write.
-    The save the cursor caused carries the cursor save's reason."""
+    The save the round's end caused carries its reason, ``exit``, and
+    the cursor is written after it, both under ``round.save``."""
     trace.enable()
     agg = run_fetch(tmp_path)
     events = spans()
@@ -209,7 +214,11 @@ def test_checkpoint_save_has_its_three_phases(tmp_path):
     cursor = next(e for e in events if e["name"] == "fetch.save_cursor")
     assert cursor["args"] == {"log": LOG, "position": ENTRIES,
                               "reason": "exit"}
-    assert full["parent"] == cursor["id"]
+    round_save = next(e for e in events if e["name"] == "round.save")
+    assert round_save["args"] == {"reason": "exit", "logs": 1, "cursors": 1,
+                                  "entries": ENTRIES}
+    assert full["parent"] == cursor["parent"] == round_save["id"]
+    assert full["ts"] + full["dur"] <= cursor["ts"]  # checkpoint, then cursor
     assert full["args"]["reason"] == "exit" and "reason" not in noop["args"]
     assert not [e for e in events if e["parent"] == noop["id"]]
 
