@@ -50,6 +50,7 @@ from urllib.parse import urlparse
 
 import numpy as np
 
+from ct_mapreduce_tpu import native
 from ct_mapreduce_tpu.agg import ckpt
 from ct_mapreduce_tpu.core import der as hostder
 from ct_mapreduce_tpu.core import packing
@@ -63,6 +64,59 @@ from ct_mapreduce_tpu.telemetry.metrics import (
     measure,
     set_gauge,
 )
+
+
+def _rep_windows_numpy(rows2d, row_sel, issuers, o, ln):
+    """Representative lane per distinct (issuer, window bytes), by
+    gathering every lane's window and sorting them: tens of MB of
+    temporaries a 65,536-lane batch. The routine for what the native
+    pass does not read, and the oracle of its tests."""
+    width = int(ln.max(initial=0))
+    k = row_sel.shape[0]
+    cols = o[:, None] + np.arange(width, dtype=o.dtype)[None, :]
+    cols = np.clip(cols, 0, rows2d.shape[1] - 1)
+    wins = rows2d[row_sel[:, None], cols]
+    wins[np.arange(width)[None, :] >= ln[:, None]] = 0
+    # Row-wise unique via a contiguous byte-row void view —
+    # ~an order of magnitude cheaper than np.unique(axis=0)'s
+    # int64 lexsort at these shapes (measured on the e2e leg).
+    tag8 = np.empty((k, width + 6), np.uint8)
+    tag8[:, 0:4] = (
+        issuers.astype(np.uint32).view(np.uint8).reshape(k, 4))
+    tag8[:, 4:6] = ln.astype(np.uint16).view(np.uint8).reshape(k, 2)
+    tag8[:, 6:] = wins
+    v = np.ascontiguousarray(tag8).view(
+        np.dtype((np.void, tag8.shape[1])))
+    _, first = np.unique(v.ravel(), return_index=True)
+    return first
+
+
+def _window_reps(rows2d, row_sel, issuers, o, ln):
+    """One representative lane per distinct (issuer, window bytes) of
+    the selection, and the lanes that took the NumPy routine.
+
+    The native pass (``ctmr_unique_windows``) reads each window where it
+    lies and copies none. What it does not read takes
+    :func:`_rep_windows_numpy` as every lane did before: all lanes
+    where the library did not load or the rows are not contiguous
+    bytes, else the lanes it hands back (a negative length, a window
+    that does not lie wholly inside its row), whose clipping is that
+    routine's. What decides is in the input; there is no setting."""
+    nothing = np.zeros((0,), np.int64)
+    if int(ln.max(initial=0)) <= 0:
+        # No lane has a byte: no representative, not one empty window
+        # an issuer (a batch of certificates without the extension).
+        return nothing, nothing
+    got = native.unique_windows(rows2d, row_sel, issuers, o, ln)
+    if got is None:
+        every = np.arange(row_sel.shape[0], dtype=np.int64)
+        return _rep_windows_numpy(rows2d, row_sel, issuers, o, ln), every
+    first, rest = got
+    if rest.size:
+        sub = _rep_windows_numpy(
+            rows2d, row_sel[rest], issuers[rest], o[rest], ln[rest])
+        first = np.concatenate([first, rest[sub]])
+    return first, rest
 
 
 def _donating_backend() -> bool:
@@ -1198,12 +1252,15 @@ class TpuAggregator:
                 pval = np.zeros((b,), bool)
                 pval[:m] = valid[start:end]
                 batch = packing.PackedBatch(pdata, plen, pidx, pval)
-            device_pos = [start + j for j in range(m) if valid[start + j]]
+            # The valid positions as one index array: the fold works on
+            # arrays, and a list of 65,536 ints a batch is Python
+            # objects made here only to be turned back there.
+            device_pos = start + np.flatnonzero(
+                np.asarray(valid[start:end], bool))
             # lanes in the packed batch correspond 1:1 with positions
             # only when every lane is valid; map explicitly otherwise.
             if len(device_pos) != m:
-                lane_of_pos = {start + j: j for j in range(m)}
-                lane_of = lambda pos, _m=lane_of_pos: _m[pos]  # noqa: E731
+                lane_of = lambda pos, _s=start: pos - _s  # noqa: E731
             else:
                 lane_of = None
             out = self._device_step_packed(batch)  # async dispatch
@@ -1274,8 +1331,7 @@ class TpuAggregator:
             batch = packing.PackedBatch(
                 rows, length[k, :n_k], issuer_idx[k, :n_k], valid[k, :n_k]
             )
-            lanes = np.nonzero(valid[k])[0]
-            device_pos = [k * b + int(j) for j in lanes]
+            device_pos = k * b + np.flatnonzero(valid[k])
             if len(device_pos) == b:
                 lane_of = None  # contiguous full chunk: lane == index
             else:
@@ -1652,7 +1708,7 @@ class TpuAggregator:
         if lane_of is None:
             lanes = np.arange(n, dtype=np.int64)
         else:
-            lanes = np.array([lane_of(p) for p in device_pos], dtype=np.int64)
+            lanes = np.asarray(lane_of(pos_arr), dtype=np.int64)
         hl_l = hl[lanes]
         host_pos = [int(p) for p in pos_arr[hl_l]]
         okm = ~hl_l
@@ -1793,61 +1849,52 @@ class TpuAggregator:
         All arrays are pre-selected to the was-unknown lanes: ``rows2d``
         is a HOST-resident padded-row matrix, ``row_sel`` the row per
         lane, ``issuers``/offsets/lengths aligned with it. Work is
-        reduced to UNIQUE byte windows first (np.unique over the
-        extracted windows, C-speed) so per-chunk Python cost is
-        O(#distinct issuers/CRL encodings), not O(batch)."""
-        if row_sel.size == 0:
+        reduced to one representative lane a distinct ``(issuer, window
+        bytes)`` first (:func:`_window_reps`), so per-chunk Python cost
+        is O(#distinct issuers/CRL encodings), not O(batch)."""
+        n = int(row_sel.shape[0])
+        if n == 0:
             return
-
-        def rep_windows(o, ln):
-            """Representative index (into the selection) per unique
-            (issuer, window bytes)."""
-            width = int(ln.max(initial=0))
-            if width == 0:
-                return np.zeros((0,), np.int64)
-            k = row_sel.shape[0]
-            cols = o[:, None] + np.arange(width, dtype=o.dtype)[None, :]
-            cols = np.clip(cols, 0, rows2d.shape[1] - 1)
-            wins = rows2d[row_sel[:, None], cols]
-            wins[np.arange(width)[None, :] >= ln[:, None]] = 0
-            # Row-wise unique via a contiguous byte-row void view —
-            # ~an order of magnitude cheaper than np.unique(axis=0)'s
-            # int64 lexsort at these shapes (measured on the e2e leg).
-            tag8 = np.empty((k, width + 6), np.uint8)
-            tag8[:, 0:4] = (
-                issuers.astype(np.uint32).view(np.uint8).reshape(k, 4))
-            tag8[:, 4:6] = ln.astype(np.uint16).view(np.uint8).reshape(k, 2)
-            tag8[:, 6:] = wins
-            v = np.ascontiguousarray(tag8).view(
-                np.dtype((np.void, tag8.shape[1])))
-            _, first = np.unique(v.ravel(), return_index=True)
-            return first
-
-        for i in rep_windows(in_off, in_len):
-            idx = int(issuers[i])
-            raw_name = rows2d[
-                row_sel[i], in_off[i] : in_off[i] + in_len[i]].tobytes()
-            if (idx, raw_name) not in self._dn_raw_seen:
-                self._dn_raw_seen.add((idx, raw_name))
-                try:
-                    rdns, _ = hostder.parse_name(raw_name, 0)
-                    dn = hostder.render_dn(rdns)
-                    self.dn_sets.setdefault(idx, set()).add(dn)
-                except Exception:
-                    pass
-        for i in rep_windows(dp_off, dp_len):
-            if dp_len[i] <= 0:
-                continue
-            idx = int(issuers[i])
-            raw_dp = rows2d[
-                row_sel[i], dp_off[i] : dp_off[i] + dp_len[i]].tobytes()
-            if (idx, raw_dp) not in self._crl_raw_seen:
-                self._crl_raw_seen.add((idx, raw_dp))
-                try:
-                    urls = hostder._parse_crldp(raw_dp, 0)
-                except Exception:
-                    urls = []
-                self._add_crls(idx, urls)
+        with trace.span("fold.metadata", cat="fold", lanes=n) as sp:
+            dn_reps, dn_np = _window_reps(
+                rows2d, row_sel, issuers, in_off, in_len)
+            for i in dn_reps:
+                idx = int(issuers[i])
+                raw_name = rows2d[
+                    row_sel[i], in_off[i] : in_off[i] + in_len[i]].tobytes()
+                if (idx, raw_name) not in self._dn_raw_seen:
+                    self._dn_raw_seen.add((idx, raw_name))
+                    try:
+                        rdns, _ = hostder.parse_name(raw_name, 0)
+                        dn = hostder.render_dn(rdns)
+                        self.dn_sets.setdefault(idx, set()).add(dn)
+                    except Exception:
+                        pass
+            crl_reps, crl_np = _window_reps(
+                rows2d, row_sel, issuers, dp_off, dp_len)
+            for i in crl_reps:
+                if dp_len[i] <= 0:
+                    continue
+                idx = int(issuers[i])
+                raw_dp = rows2d[
+                    row_sel[i], dp_off[i] : dp_off[i] + dp_len[i]].tobytes()
+                if (idx, raw_dp) not in self._crl_raw_seen:
+                    self._crl_raw_seen.add((idx, raw_dp))
+                    try:
+                        urls = hostder._parse_crldp(raw_dp, 0)
+                    except Exception:
+                        urls = []
+                    self._add_crls(idx, urls)
+            # A lane that took the NumPy routine for either window
+            # counts once. Both counters move on every fold (by 0 where
+            # no lane did), so a reader tells "none fell back" from
+            # "this program does not count them".
+            fallback = int(np.union1d(dn_np, crl_np).size)
+            distinct = int(dn_reps.size + crl_reps.size)
+            sp.set(fallback_lanes=fallback, distinct=distinct)
+        incr_counter("fold", "meta_lanes", value=float(n))
+        incr_counter("fold", "meta_fallback_lanes", value=float(fallback))
+        incr_counter("fold", "meta_distinct", value=float(distinct))
 
     def _add_crls(self, issuer_idx: int, urls: list[str]) -> None:
         """http/https only; ldap silently dropped

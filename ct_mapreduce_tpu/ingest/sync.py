@@ -200,6 +200,13 @@ class AggregatorSink:
         self._pending_raw = _RawChunk()
         self._batch_seq = 0  # raw chunks cut so far; under _lock
         self._lock = threading.Lock()
+        # Cuts taken from the accumulators and not yet handed to the
+        # device (or the overlap scheduler): neither pending nor in
+        # flight, so a flush waits for those older than itself
+        # (_handing_over). Numbered in the order cut; under _lock.
+        self._cut_seq = 0
+        self._open_cuts: set[int] = set()
+        self._cut_handed = threading.Condition(self._lock)
         self._dispatch_lock = threading.Lock()  # one device stream
         # Host↔device pipelining (deviceQueueDepth, SURVEY §2.2 PP row;
         # the reference overlaps download and store with goroutines + a
@@ -311,28 +318,34 @@ class AggregatorSink:
             metrics.incr_counter("ct-fetch", "noChainError")
             return
         batch: Optional[list[tuple[bytes, bytes]]] = None
+        cut = 0
         with self._lock:
             self._pending.append((entry.cert_der, entry.issuer_der))
             self.entries_in += 1
             if len(self._pending) >= self.flush_size:
                 batch, self._pending = self._pending, []
+                cut = self._open_cut()
         if batch:
-            self._dispatch(batch)
+            with self._handing_over(cut):
+                self._dispatch(batch)
 
     def store_raw_batch(self, raw: "RawBatch") -> None:
         """Accumulate an undecoded get-entries response; decoded and
         dispatched natively in flush-size chunks."""
         chunk: Optional[_RawChunk] = None
+        cut = 0
         with trace.span("sink.accumulate", cat="sink", n=len(raw)) as sp:
             with self._lock:
                 self._pending_raw.add_page(raw)
                 self.entries_in += len(raw)
                 if len(self._pending_raw) >= self.flush_size:
                     chunk = self._cut_raw()
+                    cut = self._open_cut()
                     sp.set(batch=chunk.batch, pages=chunk.pages,
                            logs=len({page[0] for page in chunk.pages}))
         if chunk:
-            self._dispatch_raw(chunk)
+            with self._handing_over(cut):
+                self._dispatch_raw(chunk)
 
     def _cut_raw(self) -> "_RawChunk":
         """Take what has accumulated as one chunk and number it: the
@@ -341,6 +354,23 @@ class AggregatorSink:
         self._batch_seq += 1
         chunk.batch = self._batch_seq
         return chunk
+
+    def _open_cut(self) -> int:
+        """A cut left the accumulators: its number, open until it has
+        been handed over. Caller holds ``_lock``."""
+        self._cut_seq += 1
+        self._open_cuts.add(self._cut_seq)
+        return self._cut_seq
+
+    @contextlib.contextmanager
+    def _handing_over(self, cut: int):
+        """Around the dispatch of cut ``cut``, however it ends."""
+        try:
+            yield
+        finally:
+            with self._cut_handed:
+                self._open_cuts.discard(cut)
+                self._cut_handed.notify_all()
 
     def _dispatch_raw(self, chunk: "_RawChunk") -> None:
         if self._overlap is not None:
@@ -824,10 +854,19 @@ class AggregatorSink:
             batch, self._pending = self._pending, []
             if self._pending_raw:
                 raw = self._cut_raw()
-        if batch:
-            self._dispatch(batch)
-        if raw:
-            self._dispatch_raw(raw)
+            mine = self._open_cut()
+        with self._handing_over(mine):
+            if batch:
+                self._dispatch(batch)
+            if raw:
+                self._dispatch_raw(raw)
+        # A cut that a store thread (or another flush) took before this
+        # one looked and has not handed over yet is in neither place
+        # this flush reads: its entries count as stored for their logs'
+        # cursors, so the barrier waits for it. Later cuts are not its.
+        with self._cut_handed:
+            self._cut_handed.wait_for(
+                lambda: not any(c < mine for c in self._open_cuts))
         if self._overlap is not None:
             # Barrier through the scheduler: every chunk handed to it is
             # decoded, stepped, and folded before flush returns (and any

@@ -14,6 +14,8 @@ import subprocess
 import threading
 from typing import Optional
 
+import numpy as np
+
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "ctmr_native.cpp")
 _LOCK = threading.Lock()
@@ -231,9 +233,75 @@ def load() -> Optional[ctypes.CDLL]:
             lib.has_scan = lib.has_strs
         except AttributeError:
             lib.has_scan = False
+        # Distinct byte windows of a batch (PR 37): reads the host rows
+        # where they lie, no Python object, so it stays on this handle.
+        # Same stale-library contract: `unique_windows` checks
+        # `has_uniq` and its caller keeps the NumPy routine.
+        try:
+            lib.ctmr_unique_windows.restype = ctypes.c_int64
+            lib.ctmr_unique_windows.argtypes = [
+                ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
+                ctypes.c_int64,
+                ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p,
+                ctypes.c_void_p, ctypes.c_void_p,
+                ctypes.c_void_p, ctypes.c_void_p, i64p,
+            ]
+            lib.has_uniq = True
+        except AttributeError:
+            lib.has_uniq = False
         _LIB = lib
         return _LIB
 
 
 def available() -> bool:
     return load() is not None
+
+
+def _lossless(a, dtype):
+    """``a`` as a contiguous array of ``dtype``, or None where a value
+    would not survive the conversion."""
+    a = np.asarray(a)
+    if a.dtype != dtype:
+        b = a.astype(dtype)
+        if not np.array_equal(a, b):
+            return None
+        a = b
+    return np.ascontiguousarray(a)
+
+
+def unique_windows(rows2d, row_sel, issuers, off, ln):
+    """First lane of every distinct ``(issuer, length, window bytes)``
+    among lanes ``i`` whose window ``rows2d[row_sel[i], off[i] : off[i]
+    + ln[i]]`` lies wholly inside its row, and the lanes it left to the
+    caller (a negative length, a window past the row's ends), both
+    ascending: ``(first, rest)`` (``ctmr_unique_windows``). None where
+    the library is unavailable or the input is not what it reads: rows
+    that are not uint8 with each row's bytes contiguous, a row index
+    outside the matrix, an index or offset no int32 / int64 holds."""
+    lib = load()
+    if lib is None or not getattr(lib, "has_uniq", False):
+        return None
+    if (not isinstance(rows2d, np.ndarray) or rows2d.ndim != 2
+            or rows2d.dtype != np.uint8
+            or (rows2d.shape[1] > 1 and rows2d.strides[1] != 1)):
+        return None
+    # Lanes as the function reads them: int64 rows, int32 the rest.
+    lanes = [_lossless(row_sel, np.int64)] + [
+        _lossless(a, np.int32) for a in (issuers, off, ln)]
+    if any(a is None for a in lanes):
+        return None
+    n = int(lanes[0].shape[0])
+    first = np.empty((n,), np.int64)
+    rest = np.empty((n,), np.int64)
+    n_rest = ctypes.c_int64(0)
+    count = lib.ctmr_unique_windows(
+        rows2d.ctypes.data, rows2d.shape[0], rows2d.strides[0],
+        rows2d.shape[1],
+        n, *(a.ctypes.data for a in lanes),
+        first.ctypes.data, rest.ctypes.data, ctypes.byref(n_rest),
+    )
+    if count < 0:
+        return None
+    # Copies, so that a few hundred indices do not keep two lane-sized
+    # buffers alive.
+    return first[:count].copy(), rest[: n_rest.value].copy()

@@ -1622,3 +1622,135 @@ int64_t ctmr_scan_entries(
 }
 
 }  // extern "C"
+
+// ---------------------------------------------------------------------
+// Distinct byte windows of a batch (PR 37). The fold asks one question
+// a batch and metadata kind: which distinct (issuer, window bytes) do
+// the was-unknown lanes hold (an issuer's name, a CRL distribution
+// point: a few hundred on a real log, of 65,536 lanes). It is answered
+// where the windows lie, in one pass: hash (issuer, length, bytes),
+// look the hash up in an open-addressed table of first lanes, compare
+// the bytes on a hit. No window is copied and nothing is sorted.
+
+namespace uniqwin {
+
+inline uint64_t load64(const uint8_t* p) {
+  uint64_t v;
+  std::memcpy(&v, p, 8);
+  return v;
+}
+
+inline uint64_t mix(uint64_t h) {
+  h ^= h >> 32;
+  h *= 0xd6e8feb86659fd93ULL;
+  h ^= h >> 32;
+  return h;
+}
+
+inline uint64_t hash_window(int32_t issuer, int32_t len, const uint8_t* p) {
+  uint64_t h = mix(((uint64_t)(uint32_t)issuer << 32) | (uint32_t)len);
+  int32_t i = 0;
+  for (; i + 8 <= len; i += 8) h = mix(h ^ load64(p + i));
+  if (i < len) {
+    uint64_t tail = 0;
+    std::memcpy(&tail, p + i, (size_t)(len - i));
+    h = mix(h ^ tail);
+  }
+  return h;
+}
+
+}  // namespace uniqwin
+
+extern "C" {
+
+// Lane i of [0, n) names the window rows[row_sel[i] * row_stride +
+// off[i], + len[i]) of issuer issuers[i]; `rows` is n_rows rows of
+// row_width contiguous bytes, row_stride bytes apart. A lane whose
+// window lies wholly inside its row (or is empty) is one of a class
+// (issuer, len, bytes); first[0, return value) are the first lanes of
+// the classes, ascending. Every other lane (a negative length, a
+// window that starts before or ends after its row) is listed in
+// rest[0, *n_rest), ascending, for the caller's own routine: the
+// clipping rules for those are NumPy's and stay there. Equality is by
+// memcmp, never by hash alone; the table doubles when half full, so
+// the distinct count has no cap. -1 (and nothing to read) on a row
+// index outside [0, n_rows). `first` and `rest` hold n lanes each.
+// Touches no Python object: loaded on the GIL-releasing handle.
+int64_t ctmr_unique_windows(
+    const uint8_t* rows, int64_t n_rows, int64_t row_stride,
+    int64_t row_width,
+    int64_t n, const int64_t* row_sel, const int32_t* issuers,
+    const int32_t* off, const int32_t* len,
+    int64_t* first, int64_t* rest, int64_t* n_rest) {
+  using uniqwin::hash_window;
+  *n_rest = 0;
+  for (int64_t i = 0; i < n; ++i)
+    if (row_sel[i] < 0 || row_sel[i] >= n_rows) return -1;
+  auto inside = [&](int64_t i) {
+    return len[i] == 0 ||
+           (len[i] > 0 && off[i] >= 0 &&
+            (int64_t)off[i] + (int64_t)len[i] <= row_width);
+  };
+  auto window = [&](int64_t i) {
+    // An empty window has no bytes to point at: its offset may be
+    // anything, so it is never added to the base.
+    return len[i] == 0 ? rows : rows + row_sel[i] * row_stride + off[i];
+  };
+  // slot: 1 + index into `first` (0 = empty), beside the full hash so a
+  // probe compares bytes only where 64 bits agree and growth rehashes
+  // without reading a window again.
+  int64_t cap = 1024;
+  std::vector<int64_t> slot((size_t)cap, 0);
+  std::vector<uint64_t> slot_hash((size_t)cap, 0);
+  int64_t count = 0;
+  const int64_t kAhead = 8;
+  for (int64_t i = 0; i < n; ++i) {
+    if (i + kAhead < n && inside(i + kAhead) && len[i + kAhead] > 0) {
+      // A 64-byte window at any offset lies on two cache lines.
+      const uint8_t* a = window(i + kAhead);
+      __builtin_prefetch(a);
+      __builtin_prefetch(a + len[i + kAhead] - 1);
+    }
+    if (!inside(i)) {
+      rest[(*n_rest)++] = i;
+      continue;
+    }
+    const uint8_t* w = window(i);
+    const uint64_t h = hash_window(issuers[i], len[i], w);
+    int64_t s = (int64_t)(h & (uint64_t)(cap - 1));
+    bool found = false;
+    while (slot[(size_t)s] != 0) {
+      if (slot_hash[(size_t)s] == h) {
+        const int64_t j = first[slot[(size_t)s] - 1];
+        if (issuers[j] == issuers[i] && len[j] == len[i] &&
+            std::memcmp(window(j), w, (size_t)len[i]) == 0) {
+          found = true;
+          break;
+        }
+      }
+      s = (s + 1) & (cap - 1);
+    }
+    if (found) continue;
+    first[count++] = i;
+    slot[(size_t)s] = count;
+    slot_hash[(size_t)s] = h;
+    if (count * 2 > cap) {
+      const int64_t grown = cap * 2;
+      std::vector<int64_t> slot2((size_t)grown, 0);
+      std::vector<uint64_t> hash2((size_t)grown, 0);
+      for (int64_t t = 0; t < cap; ++t) {
+        if (slot[(size_t)t] == 0) continue;
+        int64_t u = (int64_t)(slot_hash[(size_t)t] & (uint64_t)(grown - 1));
+        while (slot2[(size_t)u] != 0) u = (u + 1) & (grown - 1);
+        slot2[(size_t)u] = slot[(size_t)t];
+        hash2[(size_t)u] = slot_hash[(size_t)t];
+      }
+      slot.swap(slot2);
+      slot_hash.swap(hash2);
+      cap = grown;
+    }
+  }
+  return count;
+}
+
+}  // extern "C"

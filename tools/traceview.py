@@ -21,7 +21,8 @@ Usage:
 cut recorded for it, then every span that carries ``batch=N`` as a
 tree by ``parent``, per thread, with each span's self time (its
 duration less its children's). ``--batches`` prints one row per batch:
-the decode's phases, the submit, the fold's device wait.
+the decode's phases, the submit, the fold's device wait and its
+metadata pass.
 
 ``--split NAME --against A,B`` is the only view of the GIL the spans
 give: every ``NAME`` span goes into one of two groups by whether spans
@@ -215,7 +216,7 @@ def batch_lineage(events: list[dict], batch: int) -> list[str]:
 BATCH_COLUMNS = ("decode.concat_b64", "decode.native_call",
                  "decode.issuer_groups", "decode.pack",
                  "native.decode_batch", "ingest.decode", "ingest.submit",
-                 "fold.wait_device", "device.fold")
+                 "fold.wait_device", "fold.metadata", "device.fold")
 
 
 def batch_table(events: list[dict]) -> list[dict]:
