@@ -25,7 +25,9 @@ from ct_mapreduce_tpu.analysis.metric_registry import (
 )
 
 EMIT_FUNCS = {"span", "instant"}
-# The tracer API itself, not a call site.
+# The tracer API itself: the names it passes through are its callers',
+# not call sites. A literal there is a span the tracer records itself
+# (the GIL probe's).
 EXCLUDE_MODULES = ("ct_mapreduce_tpu/telemetry/trace.py",)
 
 
@@ -43,18 +45,16 @@ class SpanRegistryChecker(Checker):
         self.call_sites: dict[str, list[str]] = {}
 
     def visit_Call(self, node: ast.Call, ctx: Ctx) -> None:
-        if ctx.module.relpath in EXCLUDE_MODULES:
-            return
         fn = node.func
         name = (fn.attr if isinstance(fn, ast.Attribute)
                 else fn.id if isinstance(fn, ast.Name) else None)
         if name not in EMIT_FUNCS or not node.args:
             return
         arg = node.args[0]
-        span_name = (arg.value
-                     if isinstance(arg, ast.Constant)
-                     and isinstance(arg.value, str)
-                     else "*")
+        literal = isinstance(arg, ast.Constant) and isinstance(arg.value, str)
+        if ctx.module.relpath in EXCLUDE_MODULES and not literal:
+            return
+        span_name = arg.value if literal else "*"
         where = f"{ctx.module.relpath}:{node.lineno}"
         self.call_sites.setdefault(span_name, []).append(where)
 

@@ -12,9 +12,12 @@ import ctypes
 import os
 import subprocess
 import threading
+import time
 from typing import Optional
 
 import numpy as np
+
+from ct_mapreduce_tpu.telemetry import trace
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "ctmr_native.cpp")
@@ -249,12 +252,45 @@ def load() -> Optional[ctypes.CDLL]:
             lib.has_uniq = True
         except AttributeError:
             lib.has_uniq = False
+        # Return stamps and the GIL probe's sleep (PR 38). The stamps
+        # are read with the GIL held (the PyDLL handle, as the gather
+        # is): a CDLL call would hand the GIL round once more. Same
+        # stale-library contract: `note_return` and the tracer's probe
+        # check `has_stamp` and say nothing.
+        try:
+            stamps = ctypes.PyDLL(so).ctmr_call_stamps
+            stamps.restype = None
+            stamps.argtypes = [i64p]
+            lib.call_stamps = stamps
+            lib.ctmr_sleep_stamp.restype = ctypes.c_int64
+            lib.ctmr_sleep_stamp.argtypes = [ctypes.c_int64]
+            lib.has_stamp = True
+        except (AttributeError, OSError):
+            lib.has_stamp = False
         _LIB = lib
         return _LIB
 
 
 def available() -> bool:
     return load() is not None
+
+
+def note_return(lib) -> None:
+    """For the line after a call through the GIL-releasing handle, under
+    an open span and with the tracer on: adds to that span's
+    ``native_us`` the call's time inside the library (entry to return,
+    GIL released, whatever threads it used) and to its ``gil_us`` the
+    time from the library's return to this function's first
+    instruction, which the thread spent taking the GIL back. Both add
+    up over a span's calls. Nothing with a library that does not stamp
+    its calls."""
+    back = time.perf_counter_ns()  # CLOCK_MONOTONIC, as the stamps are
+    if not getattr(lib, "has_stamp", False):
+        return
+    pair = (ctypes.c_int64 * 2)()
+    lib.call_stamps(pair)
+    trace.annotate_sum(native_us=(pair[1] - pair[0]) / 1e3,
+                       gil_us=max(back - pair[1], 0) / 1e3)
 
 
 def _lossless(a, dtype):
@@ -300,6 +336,8 @@ def unique_windows(rows2d, row_sel, issuers, off, ln):
         n, *(a.ctypes.data for a in lanes),
         first.ctypes.data, rest.ctypes.data, ctypes.byref(n_rest),
     )
+    if trace.enabled():
+        note_return(lib)
     if count < 0:
         return None
     # Copies, so that a few hundred indices do not keep two lane-sized

@@ -22,6 +22,37 @@
 #include <thread>
 #include <vector>
 
+#include <time.h>
+
+namespace stamp {
+
+// When the calling thread's last stamped entry point began and when it
+// returned, on CLOCK_MONOTONIC (Python's perf_counter_ns and
+// monotonic_ns on Linux). The entry points the ingest calls through the
+// GIL-releasing handle open a Scope first thing; Python reads the pair
+// back through ctmr_call_stamps with the GIL held, and what its own
+// clock says then, less `returned`, is what re-acquiring the GIL cost
+// (telemetry/trace.py: the spans' native_us / gil_us). The stamps are
+// taken whether or not anyone reads them: two vDSO calls.
+struct Last {
+  int64_t entered = 0;
+  int64_t returned = 0;
+};
+thread_local Last last;
+
+inline int64_t now_ns() {
+  timespec ts;
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return (int64_t)ts.tv_sec * 1000000000 + (int64_t)ts.tv_nsec;
+}
+
+struct Scope {
+  Scope() { last.entered = now_ns(); }
+  ~Scope() { last.returned = now_ns(); }
+};
+
+}  // namespace stamp
+
 namespace pool {
 
 // Persistent, lazily-grown worker pool shared by the *_mt entry
@@ -427,6 +458,7 @@ int64_t ctmr_decode_entries(
     int64_t* issuer_off, int32_t* issuer_len,
     int32_t* status,
     uint8_t* scratch, int64_t scratch_cap) {
+  stamp::Scope stamped;
   return decode_entries(
       n, B64Col{li_buf, nullptr, li_off}, B64Col{ed_buf, nullptr, ed_off},
       pad_len, data, length, ts_ms, entry_ty, issuer_buf, issuer_cap,
@@ -887,6 +919,7 @@ int64_t ctmr_decode_entries_mt(
     int32_t* status,
     uint8_t* scratch, int64_t scratch_each,
     int64_t threads, int64_t* chunk_used) {
+  stamp::Scope stamped;
   return decode_entries_mt(
       n, B64Col{li_buf, nullptr, li_off}, B64Col{ed_buf, nullptr, ed_off},
       pad_len, data, length, ts_ms, entry_ty, issuer_buf, issuer_cap,
@@ -912,6 +945,7 @@ int64_t ctmr_decode_entries_strs(
     int32_t* status,
     uint8_t* scratch, int64_t scratch_each,
     int64_t threads, int64_t* chunk_used) {
+  stamp::Scope stamped;
   return decode_entries_mt(
       n, B64Col{nullptr, li_ptr, li_off}, B64Col{nullptr, ed_ptr, ed_off},
       pad_len, data, length, ts_ms, entry_ty, issuer_buf, issuer_cap,
@@ -1547,6 +1581,7 @@ extern "C" {
 int64_t ctmr_scan_entries(
     const char* body, int64_t len, int64_t cap,
     int64_t* li_off, int64_t* li_len, int64_t* ed_off, int64_t* ed_len) {
+  stamp::Scope stamped;
   using jsonscan::is_key;
   jsonscan::Cur c{body, body + len};
   const char* s = nullptr;
@@ -1682,6 +1717,7 @@ int64_t ctmr_unique_windows(
     int64_t n, const int64_t* row_sel, const int32_t* issuers,
     const int32_t* off, const int32_t* len,
     int64_t* first, int64_t* rest, int64_t* n_rest) {
+  stamp::Scope stamped;
   using uniqwin::hash_window;
   *n_rest = 0;
   for (int64_t i = 0; i < n; ++i)
@@ -1751,6 +1787,31 @@ int64_t ctmr_unique_windows(
     }
   }
   return count;
+}
+
+}  // extern "C"
+
+extern "C" {
+
+// The calling thread's last stamped call: out[0] when it entered the
+// library, out[1] when it returned. Loaded through ctypes.PyDLL like
+// ctmr_gather_strs, so reading them gives the GIL to nobody.
+void ctmr_call_stamps(int64_t* out) {
+  out[0] = stamp::last.entered;
+  out[1] = stamp::last.returned;
+}
+
+// Sleeps `ns` and says when it woke: the GIL probe's call
+// (telemetry/trace.py). The thread has really blocked, as after a
+// recv, so what Python's clock reads after the call, less this, is the
+// wait a woken thread had for the GIL. Loaded on the GIL-releasing
+// handle.
+int64_t ctmr_sleep_stamp(int64_t ns) {
+  timespec req;
+  req.tv_sec = (time_t)(ns / 1000000000);
+  req.tv_nsec = (long)(ns % 1000000000);
+  nanosleep(&req, nullptr);
+  return stamp::now_ns();
 }
 
 }  // extern "C"

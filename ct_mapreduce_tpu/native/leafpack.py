@@ -20,7 +20,7 @@ from typing import NamedTuple, Optional, Sequence
 
 import numpy as np
 
-from ct_mapreduce_tpu.native import load as load_native
+from ct_mapreduce_tpu.native import load as load_native, note_return
 from ct_mapreduce_tpu.telemetry import trace
 
 # Status codes — keep in sync with ctmr_native.cpp.
@@ -364,6 +364,8 @@ def scan_entries(body: bytes, cap: int) -> Optional[EntryPage]:
     n = lib.ctmr_scan_entries(
         body, len(body), cols.shape[1],
         *(cols[k].ctypes.data_as(i64p) for k in range(4)))
+    if trace.enabled():
+        note_return(lib)
     if n < 0:
         return None
     return EntryPage(body, *(cols[k, :n] for k in range(4)))
@@ -675,6 +677,8 @@ def _decode_native(
             scratch.ctypes.data_as(u8p), scratch_each,
             *pool,
         )
+        if trace.enabled():
+            note_return(lib)
     if rc < 0:
         return None
     return issuer_off, issuer_len, issuer_buf

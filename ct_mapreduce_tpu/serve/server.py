@@ -410,6 +410,15 @@ class _QueryHTTPServer(ThreadingHTTPServer):
     # (``Overloaded``); the socket must not.
     request_queue_size = 1024
 
+    def process_request_thread(self, request, client_address):
+        """A connection thread's whole life, from its first instruction
+        to the socket's close, under one span: request line and header
+        parsing, the handlers (and the ``serve.wait`` they cause), the
+        response write. Its ``tdur`` is what the front costs in CPU a
+        connection; the handler says what the connection carried."""
+        with trace.span("front.conn", cat="front"):
+            super().process_request_thread(request, client_address)
+
 
 class QueryServer:
     """Background HTTP server for the query plane (``queryPort``).
@@ -698,6 +707,18 @@ class QueryServer:
         server = self
 
         class Handler(BaseHTTPRequestHandler):
+            def handle(self):
+                # What the connection carried, for its front.conn span:
+                # requests that reached a handler and the bytes of
+                # their bodies and of the answers' (headers not
+                # counted).
+                self.carried = {"requests": 0, "bytes_in": 0,
+                                "bytes_out": 0}
+                try:
+                    super().handle()
+                finally:
+                    trace.annotate(**self.carried)
+
             def _trace_ctx(self):
                 """Cross-process correlation (round 23): adopt the
                 client's traceparent header so every span this request
@@ -727,17 +748,21 @@ class QueryServer:
                 view = memoryview(payload)
                 for off in range(0, len(view), 1 << 20):
                     self.wfile.write(view[off: off + (1 << 20)])
+                self.carried["bytes_out"] += len(payload)
                 if code >= 400:
                     incr_counter("serve", "http_errors")
 
             def do_POST(self):  # noqa: N802 (http.server API)
+                self.carried["requests"] += 1
                 path = self.path.split("?", 1)[0].rstrip("/") or "/"
                 if path != "/query":
                     self._respond(404, {"error": "not found"})
                     return
                 try:
                     length = int(self.headers.get("Content-Length", "0"))
-                    body = json.loads(self.rfile.read(length) or b"{}")
+                    raw = self.rfile.read(length)
+                    self.carried["bytes_in"] += len(raw)
+                    body = json.loads(raw or b"{}")
                     if not isinstance(body, dict):
                         raise ValueError("body must be a JSON object")
                 except (ValueError, json.JSONDecodeError) as err:
@@ -751,6 +776,7 @@ class QueryServer:
                         500, {"error": f"{type(err).__name__}: {err}"})
 
             def do_GET(self):  # noqa: N802
+                self.carried["requests"] += 1
                 raw_path, _, qs = self.path.partition("?")
                 path = raw_path.rstrip("/") or "/"
                 with self._trace_ctx():

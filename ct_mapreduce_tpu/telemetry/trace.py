@@ -38,8 +38,25 @@ are per-CHUNK, but the disabled path must still cost nothing):
   work that continues on another thread passes ``batch=`` itself.
   :meth:`_Span.set` adds arguments known only once the work is done.
 - **Drops are counted.** The ring forgets its oldest events;
-  :func:`dropped` (and ``otherData.dropped`` in an export) says how
-  many, so a reader can refuse a window it did not see whole.
+  :meth:`SpanTracer.dropped` (and ``otherData.dropped`` in an export)
+  says how many, so a reader can refuse a window it did not see whole.
+- **Working is told from waiting.** A span reads its thread's CPU clock
+  (``time.thread_time_ns``) beside the wall clock, and its event
+  carries the format's own thread-clock fields ``tts`` / ``tdur`` (µs,
+  shown as CPU duration by Perfetto). ``dur - tdur`` is what the thread
+  spent off a core under the span: blocked on a socket, a lock, a
+  queue, the device, or waiting for the GIL; for a span that blocks on
+  nothing it is the GIL wait. A span around a native call that
+  releases the GIL also says how long the library ran (``native_us``)
+  and how long the thread then took to get the GIL back (``gil_us``:
+  ``native.note_return``).
+- **The GIL is probed.** While the module-level tracer is on, the
+  thread ``ctmr-gil-probe`` sleeps 10 ms in the native library, which
+  stamps the clock as it wakes, and records a span ``gil.probe`` whose
+  ``wait_us`` is how much later Python ran again: what a thread pays
+  for the GIL each time a ``recv`` or a native call returns. No probe
+  where the native library is absent or does not stamp, nor for a
+  :class:`SpanTracer` built directly.
 
 Enabling: the ``CTMR_TRACE=<path>`` environment variable (read at
 import, so every entry point — ct-fetch, tests — gets it for
@@ -205,7 +222,7 @@ _NULL_SPAN = _NullSpan()
 class _Span:
     """One live span; records a complete ("X") event on exit."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_ann",
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_c0", "_ann",
                  "id", "parent")
 
     def __init__(self, tracer: "SpanTracer", name: str, cat: str, args):
@@ -248,10 +265,14 @@ class _Span:
                 self._ann.__enter__()
             except Exception:
                 self._ann = None  # tracing must never break the pipeline
+        # The CPU clock is read inside the wall clock's readings, at
+        # both ends, so tdur never exceeds dur.
         self._t0 = time.perf_counter_ns()
+        self._c0 = time.thread_time_ns()
         return self
 
     def __exit__(self, *exc):
+        c1 = time.thread_time_ns()
         t1 = time.perf_counter_ns()
         if self._ann is not None:
             try:
@@ -264,7 +285,8 @@ class _Span:
         elif self in stack:  # exited out of order: keep the rest sound
             stack.remove(self)
         self._tracer._complete(self._name, self._cat, self._t0, t1,
-                               self._args, self.id, self.parent)
+                               self._c0, c1, self._args, self.id,
+                               self.parent)
         return False
 
 
@@ -332,12 +354,16 @@ class SpanTracer:
         return merged
 
     def _complete(self, name: str, cat: str, t0_ns: int, t1_ns: int,
-                  args, span_id: int, parent: int) -> None:
+                  c0_ns: int, c1_ns: int, args, span_id: int,
+                  parent: int) -> None:
         ev = {
             "name": name,
             "ph": "X",
             "ts": (t0_ns - self._t0_ns) / 1e3,
             "dur": max(t1_ns - t0_ns, 0) / 1e3,
+            # The thread's own CPU clock, from the thread's start.
+            "tts": c0_ns / 1e3,
+            "tdur": max(c1_ns - c0_ns, 0) / 1e3,
             "pid": self._pid,
             "tid": self._tid(),
             "id": span_id,
@@ -360,6 +386,7 @@ class SpanTracer:
             "ph": "i",
             "s": "t",  # thread-scoped instant
             "ts": self.now_us(),
+            "tts": time.thread_time_ns() / 1e3,
             "pid": self._pid,
             "tid": self._tid(),
             "id": next(self._ids),
@@ -424,9 +451,61 @@ class SpanTracer:
         return path
 
 
+# -- the GIL probe ------------------------------------------------------
+
+PROBE_PERIOD_NS = 10_000_000  # 100 wake-ups a second
+
+
+class _GilProbe:
+    """The thread ``ctmr-gil-probe`` of one tracer: it sleeps in the
+    native library, GIL released, which stamps CLOCK_MONOTONIC as it
+    wakes (``ctmr_sleep_stamp``), and records a span ``gil.probe`` whose
+    ``wait_us`` is this thread's first reading of the same clock less
+    that stamp: how long a thread that has just been woken waits for
+    the GIL, as the downloader does after every ``recv`` and the store
+    thread after every native call. It ends when told to, or when the
+    module's tracer is no longer the one it records into."""
+
+    def __init__(self, tracer: SpanTracer, sleep_stamp):
+        self.tracer = tracer
+        self._sleep_stamp = sleep_stamp
+        self._stop = threading.Event()
+        self._thread = threading.Thread(
+            target=self._run, name="ctmr-gil-probe", daemon=True)
+        self._thread.start()
+
+    def _run(self) -> None:
+        tracer, sleep_stamp = self.tracer, self._sleep_stamp
+        while not self._stop.is_set() and _tracer is tracer:
+            with tracer.span("gil.probe", cat="gil") as sp:
+                woke = sleep_stamp(PROBE_PERIOD_NS)
+                back = time.monotonic_ns()
+                sp.set(wait_us=max(back - woke, 0) / 1e3)
+
+    def alive(self) -> bool:
+        return self._thread.is_alive()
+
+    def stop(self) -> None:
+        self._stop.set()
+        self._thread.join()
+
+
+def _start_probe(tracer: SpanTracer) -> Optional[_GilProbe]:
+    try:
+        from ct_mapreduce_tpu import native
+
+        lib = native.load()
+    except Exception:
+        return None  # tracing must never break the pipeline
+    if lib is None or not getattr(lib, "has_stamp", False):
+        return None
+    return _GilProbe(tracer, lib.ctmr_sleep_stamp)
+
+
 # -- module-level tracer (the hot path reads one global) ----------------
 
 _tracer: Optional[SpanTracer] = None
+_probe: Optional[_GilProbe] = None
 _atexit_registered = False
 
 
@@ -443,7 +522,7 @@ def enable(path: Optional[str] = None, ring_size: Optional[int] = None,
     """Install the global tracer (idempotent: re-enabling with a path
     updates the export path of the live tracer rather than dropping
     its ring)."""
-    global _tracer, _atexit_registered
+    global _tracer, _probe, _atexit_registered
     if ring_size is None:
         ring_size = int(os.environ.get("CTMR_TRACE_RING", DEFAULT_RING))
     if jax_annotations is None:
@@ -456,6 +535,8 @@ def enable(path: Optional[str] = None, ring_size: Optional[int] = None,
             _tracer.path = path
         if jax_annotations:
             _tracer.jax_annotations = True
+    if _probe is None or _probe.tracer is not _tracer or not _probe.alive():
+        _probe = _start_probe(_tracer)
     if not _atexit_registered:
         atexit.register(_export_at_exit)
         _atexit_registered = True
@@ -463,8 +544,12 @@ def enable(path: Optional[str] = None, ring_size: Optional[int] = None,
 
 
 def disable() -> None:
-    global _tracer
+    """Take the global tracer away and end its probe thread."""
+    global _tracer, _probe
     _tracer = None
+    if _probe is not None:
+        _probe.stop()
+        _probe = None
 
 
 def _export_at_exit() -> None:
@@ -490,27 +575,27 @@ def annotate(**args) -> None:
         stack[-1].set(**args)
 
 
+def annotate_sum(**args) -> None:
+    """:func:`annotate` for numbers that add up over a span's life
+    (the native calls under it): each is added to what the innermost
+    span already says under that name."""
+    stack = getattr(_ctx, "stack", None)
+    if stack:
+        have = stack[-1]._args
+        for key, val in args.items():
+            have[key] = have.get(key, 0) + val
+
+
 def instant(name: str, cat: str = "", **args) -> None:
     t = _tracer
     if t is not None:
         t.instant(name, cat, **args)
 
 
-def now_us() -> float:
-    t = _tracer
-    return t.now_us() if t is not None else 0.0
-
-
 def snapshot_events() -> list[dict]:
     """Current ring contents (for the flight recorder); [] when off."""
     t = _tracer
     return t.events() if t is not None else []
-
-
-def dropped() -> int:
-    """Events the ring has forgotten; 0 when off."""
-    t = _tracer
-    return t.dropped() if t is not None else 0
 
 
 def export(path: Optional[str] = None) -> Optional[str]:

@@ -48,6 +48,21 @@ from ct_mapreduce_tpu.analysis import witness as _lock_witness  # noqa: E402
 _lock_witness.install()
 
 
+@pytest.fixture(autouse=True)
+def no_span_attributes_from_an_earlier_test():
+    """Process-wide span attributes are the worker process's, not a
+    test's: ``ingest/fleet.py`` stamps ``epoch`` on every later span and
+    only ``ct_fetch.main`` takes it off, so a fleet test that ran in
+    the same xdist worker before (which files share one is the
+    scheduler's choice) put ``epoch`` into every span a later test
+    compares whole (``test_multilog_round.py``, PR 37; four tests of
+    ``test_lineage.py`` in one of PR 38's three whole runs)."""
+    from ct_mapreduce_tpu.telemetry import trace
+
+    trace.set_process_attrs(**dict.fromkeys(trace.get_process_attrs()))
+    yield
+
+
 @pytest.fixture
 def benchmark_checkout(tmp_path, monkeypatch, request):
     """For the modules that run ``benchmark/tests`` in tier-1 (each names
@@ -75,17 +90,39 @@ def benchmark_checkout(tmp_path, monkeypatch, request):
         if "xla_force_host_platform_device_count" not in f))
 
 
+def _first_shared_metric(per_layer: list) -> int:
+    """Where the metrics of several cells begin in ``BENCHMARK.json``'s
+    ``per_layer``: every cell's own block stands before (PR 37 listed
+    the first of them; what PR 38 lists after it, two of one cell
+    among them, is no cell's own block either)."""
+    return next(i for i, m in enumerate(per_layer)
+                if len(m.get("workloads", ())) > 1)
+
+
+@pytest.fixture
+def own_blocks_only(monkeypatch, request):
+    """For the same modules: their tests of what a cell lists read
+    ``BENCHMARK.json`` whole (every metric that lists the cell alone is
+    the cell's; the cell's block ends the list). Theirs then see the
+    list as it stood before the first metric of several cells."""
+    theirs = request.module.theirs
+    whole = theirs.bench_json()
+    cut = _first_shared_metric(whole["per_layer"])
+    then = dict(whole, per_layer=whole["per_layer"][:cut])
+    monkeypatch.setattr(theirs, "bench_json", lambda: then)
+
+
 @pytest.fixture
 def shared_metrics_aside(monkeypatch, request):
     """For the same modules: their per-cell tests hold a traced line to
-    the metrics that list that cell alone, and a PR that lists a metric
-    for several cells may edit no file under ``benchmark/`` (ROADMAP
+    the metrics of that cell's own block, and a PR that lists a metric
+    after the blocks may edit no file under ``benchmark/`` (ROADMAP
     R0). Their ``rehearse_cell`` hands over the line without the
-    metrics of several cells, which land here by name for the wrapper
-    to judge."""
+    metrics listed from the first of several cells on, which land here
+    by name for the wrapper to judge."""
     theirs = request.module.theirs
-    shared = [m["name"] for m in theirs.bench_json()["per_layer"]
-              if len(m.get("workloads", ())) > 1]
+    listed = theirs.bench_json()["per_layer"]
+    shared = [m["name"] for m in listed[_first_shared_metric(listed):]]
     aside: dict = {}
     rehearse = theirs.rehearse_cell
 

@@ -146,7 +146,7 @@ def test_parents_form_a_tree_per_thread(tmp_path):
         if not e["parent"]:
             continue
         up = by_id[e["parent"]]
-        assert up["tid"] == e["tid"] and up["ph"] == "X"
+        assert up["tid"] == e["tid"] and up["ph"] == "X", (up, e)
         assert up["ts"] <= e["ts"]
         assert e["ts"] + e.get("dur", 0) <= up["ts"] + up["dur"] + 1e-3
     child_of = {(by_id[e["parent"]]["name"], e["name"])
@@ -182,7 +182,9 @@ def test_page_spans_say_what_was_fetched(tmp_path):
         got = [e for e in kids if e["name"] == "fetch.get_entries"]
         assert len(got) == 1 and got[0]["args"]["attempts"] == 1
         assert got[0]["args"]["bytes"] > PAGE * 100
-        parsed = [e["args"] for e in kids if e["name"] == "fetch.parse_json"]
+        parsed = [{k: v for k, v in e["args"].items()
+                   if k not in ("native_us", "gil_us")}
+                  for e in kids if e["name"] == "fetch.parse_json"]
         assert parsed == [{"n": PAGE, "scanned": int(available())}]
         put = [e for e in kids if e["name"] == "fetch.enqueue"]
         assert len(put) == 1 and put[0]["args"]["depth"] >= 0
@@ -244,7 +246,7 @@ def test_tracer_off_records_nothing_and_the_samples_still_flow(tmp_path):
     noop = trace.span("fetch.page", cat="fetch", log=LOG)
     assert noop is trace.span("ckpt.save") and noop.set(n=1) is noop
     run_fetch(tmp_path)
-    assert trace.snapshot_events() == [] and trace.dropped() == 0
+    assert trace.snapshot_events() == [] and trace.get_tracer() is None
     snap = metrics.get_sink().snapshot()
     pages = ENTRIES // PAGE
     assert snap["samples"][f"LogWorker.{LOG}.submitToChannel"]["count"] \
@@ -343,12 +345,14 @@ def test_a_reused_thread_ident_keeps_every_name():
 
 
 def test_the_ring_counts_what_it_drops(tmp_path):
-    tracer = trace.enable(ring_size=32)
-    assert trace.dropped() == 0
+    # Installed bare: the module's enable() starts the GIL probe, whose
+    # spans would be counted among these.
+    tracer = trace._tracer = trace.SpanTracer(ring_size=32)
+    assert tracer.dropped() == 0
     for i in range(200):
         with trace.span("s", i=i):
             pass
-    assert trace.dropped() == 168 and len(spans()) == 32
+    assert tracer.dropped() == 168 and len(spans()) == 32
     # From several threads at once, still exact.
     def burst():
         for _ in range(50):
@@ -359,12 +363,12 @@ def test_the_ring_counts_what_it_drops(tmp_path):
         t.start()
     for t in threads:
         t.join(timeout=30)
-    assert trace.dropped() == 368
+    assert tracer.dropped() == 368
     path = trace.export(str(tmp_path / "ring.json"))
     with open(path) as fh:
         assert json.load(fh)["otherData"]["dropped"] == 368
     tracer.clear()
-    assert trace.dropped() == 0 and spans() == []
+    assert tracer.dropped() == 0 and spans() == []
 
 
 def test_trace_annotation_is_given_the_scalar_arguments(monkeypatch):
@@ -388,7 +392,8 @@ def test_trace_annotation_is_given_the_scalar_arguments(monkeypatch):
         with trace.span("sink.accumulate", pages=[[LOG, 0, 15]], n=8,
                         log=LOG, odd="a=b,c") as sp:
             sp.set(late=1)
-    assert calls == [
+    # (the GIL probe's spans are mirrored like any other)
+    assert [c for c in calls if c[0] != "gil.probe"] == [
         ("ingest.decode", {"batch": 3, "entries": 16}),
         ("sink.accumulate", {"n": 8, "log": LOG, "batch": 3})]
 

@@ -307,6 +307,64 @@ def test_b64_columns_in_place_or_joined_same_batch(
 
 
 @pytest.mark.skipif(not available(), reason="no C++ compiler")
+@pytest.mark.parametrize("threads", [1, 3])
+@pytest.mark.parametrize("stamps", [True, False], ids=["stamped", "stale"])
+def test_the_native_call_says_when_it_returned(stamps, threads, monkeypatch):
+    """``decode.native_call`` carries ``native_us`` (inside the library,
+    GIL released) and ``gil_us`` (its return to the next Python
+    instruction), both inside the span; a prebuilt .so without the
+    stamps leaves both out and decodes the same batch."""
+    from ct_mapreduce_tpu.native import load
+    from ct_mapreduce_tpu.telemetry import trace
+
+    lis, eds, _expect, _issuer = _wire_batch()
+    lis, eds = lis * 40, eds * 40
+    if not stamps:
+        monkeypatch.setattr(load(), "has_stamp", False)
+    want = leafpack._decode_python(list(lis), list(eds), 2048)
+    tracer = trace._tracer = trace.SpanTracer(ring_size=64)
+    try:
+        got = leafpack.decode_raw_batch(lis, eds, 2048, threads=threads)
+    finally:
+        trace._tracer = None
+    for fld in ("data", "length", "timestamp_ms", "entry_type", "status",
+                "issuer_group"):
+        np.testing.assert_array_equal(getattr(got, fld), getattr(want, fld))
+    (call,) = [e for e in tracer.events() if e["name"] == "decode.native_call"]
+    others = [e for e in tracer.events() if e.get("ph") == "X"
+              and e["name"] != "decode.native_call"]
+    assert not any("gil_us" in e.get("args", {}) for e in others)
+    if not stamps:
+        assert set(call["args"]) == {"threads", "pad"}
+        return
+    args = call["args"]
+    assert args["native_us"] > 0 and args["gil_us"] >= 0
+    assert args["native_us"] + args["gil_us"] <= call["dur"]
+    # With the tracer off the call is not followed up at all.
+    calls = []
+    monkeypatch.setattr(leafpack, "note_return", calls.append)
+    leafpack.decode_raw_batch(lis, eds, 2048, threads=threads)
+    assert calls == []
+
+
+def test_the_stamp_follows_the_sleep_it_timed():
+    """``ctmr_sleep_stamp`` stamps the clock ``monotonic_ns`` reads, as
+    it wakes: after the sleep it was asked for, before Python runs
+    again."""
+    import time
+
+    from ct_mapreduce_tpu.native import load
+
+    lib = load()
+    if lib is None or not lib.has_stamp:
+        pytest.skip("no native library that stamps")
+    before = time.monotonic_ns()
+    woke = lib.ctmr_sleep_stamp(2_000_000)
+    after = time.monotonic_ns()
+    assert before + 2_000_000 <= woke <= after
+
+
+@pytest.mark.skipif(not available(), reason="no C++ compiler")
 @pytest.mark.parametrize("stale", [False, True], ids=["in_place", "stale"])
 @pytest.mark.parametrize("case,exc", [
     ("non_ascii_leaf_input", UnicodeEncodeError),

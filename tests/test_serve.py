@@ -624,6 +624,86 @@ def test_serve_batch_spans_recorded(template):
         trace.disable()
 
 
+def test_a_connection_threads_whole_life_is_one_front_conn_span(template):
+    """``front.conn`` covers a connection thread from its first
+    instruction to the socket's close: one span a connection, the
+    parent of the ``serve.wait`` its request causes, with what the
+    connection carried; over a kept-alive connection the requests add
+    up under one span. The front speaks HTTP/1.0 and so closes after
+    every answer; the handler is told 1.1 for the second half only."""
+    import http.client
+
+    from ct_mapreduce_tpu.telemetry import trace
+
+    agg = TpuAggregator(capacity=1 << 12, batch_size=64)
+    agg.ingest([(syncerts.stamp_serial(template, j), template.issuer_der)
+                for j in range(1, 6)])
+    issuer_id, eh = _identity(template)
+    # A serial a request: one asked twice is answered from the cache,
+    # with no wait in the batcher's queue.
+    bodies = [json.dumps({"issuer": issuer_id,
+                          "expDate": ExpDate.from_unix_hour(eh).id(),
+                          "serial": _serial_bytes(template, j).hex()}).encode()
+              for j in range(1, 6)]
+    body = bodies[0]
+    assert {len(b) for b in bodies} == {len(body)}
+    srv = QueryServer(agg, 0, host="127.0.0.1").start()
+    tracer = trace._tracer = trace.SpanTracer(ring_size=4096)
+
+    def conns():
+        return [e for e in tracer.events() if e.get("name") == "front.conn"]
+
+    def settled(n):  # the span closes after the client has its answer
+        deadline = time.monotonic() + 10
+        while len(conns()) < n and time.monotonic() < deadline:
+            time.sleep(0.01)
+        return conns()
+
+    try:
+        answers = []
+        for one in bodies[:3]:
+            conn = http.client.HTTPConnection("127.0.0.1", srv.port, timeout=10)
+            conn.request("POST", "/query", one,
+                         {"Content-Type": "application/json"})
+            resp = conn.getresponse()
+            answers.append(resp.read())
+            conn.close()
+        spans = settled(3)
+        assert len(spans) == 3 and len({e["id"] for e in spans}) == 3
+        waits = [e for e in tracer.events() if e.get("name") == "serve.wait"]
+        assert sorted(w["parent"] for w in waits) \
+            == sorted(e["id"] for e in spans)
+        for e, answer in zip(sorted(spans, key=lambda e: e["ts"]), answers):
+            assert e["parent"] == 0 and e["cat"] == "front"
+            assert e["args"] == {"requests": 1, "bytes_in": len(body),
+                                 "bytes_out": len(answer)}
+            assert 0 < e["tdur"] <= e["dur"] + 50.0
+            (wait,) = [w for w in waits if w["parent"] == e["id"]]
+            assert e["ts"] <= wait["ts"] and wait["dur"] <= e["dur"]
+        # Kept alive: two queries and a 404 over one connection.
+        srv._server.RequestHandlerClass.protocol_version = "HTTP/1.1"
+        conn = http.client.HTTPConnection("127.0.0.1", srv.port, timeout=10)
+        sent = 0
+        for path, one in zip(("/query", "/query", "/nowhere"), bodies[3:] * 2):
+            conn.request("POST", path, one,
+                         {"Content-Type": "application/json"})
+            resp = conn.getresponse()
+            sent += len(resp.read())
+            assert resp.status == (200 if path == "/query" else 404)
+        conn.close()
+        (kept,) = settled(4)[3:]
+        assert kept["args"] == {"requests": 3, "bytes_in": 2 * len(body),
+                                "bytes_out": sent}
+        assert sum(1 for w in tracer.events()
+                   if w.get("name") == "serve.wait"
+                   and w["parent"] == kept["id"]) == 2
+    finally:
+        trace._tracer = None
+        srv.stop()
+    # Tracer off: the connection is served as before, under no span.
+    assert trace.span("front.conn") is trace._NULL_SPAN
+
+
 def test_device_replicas_answer_through_the_jitted_contains(template):
     """A device oracle with two replicas, read off its own spans: every
     ``serve.lookup`` ran in device mode, both replicas took batches in

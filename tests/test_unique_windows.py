@@ -317,6 +317,10 @@ def test_the_span_says_what_the_counters_say(monkeypatch):
     assert span["args"]["lanes"] == count["lanes"]
     assert span["args"]["fallback_lanes"] == count["fallback_lanes"] > 0
     assert span["args"]["distinct"] == count["distinct"]
+    # Both native passes of the fold (names, CRLDP) add to the span's
+    # time inside the library and to what the GIL cost on their return.
+    assert span["args"]["native_us"] > 0 and span["args"]["gil_us"] >= 0
+    assert span["args"]["native_us"] + span["args"]["gil_us"] <= span["dur"]
 
 
 def test_same_entries_with_the_library_and_without(monkeypatch):

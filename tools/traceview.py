@@ -6,7 +6,17 @@ which": per span name this prints span count, total busy seconds,
 occupancy (busy / trace wall), and the largest gap between consecutive
 spans of that stage — a stage with low occupancy and large gaps is
 waiting on its upstream; stages whose occupancies sum past 1.0 are
-genuinely overlapping.
+genuinely overlapping. Beside the wall seconds stand the seconds the
+stage's threads were on a core (``cpu_s``, the spans' ``tdur``) and off
+one (``off_s``: blocked, or waiting for the GIL). Under the stages, one
+line a thread family (downloader, store, batcher, front, refresh):
+wall under its threads' spans, CPU, off-core, seconds inside native
+calls with the GIL released (``native_us``) and seconds spent taking it
+back after them (``gil_us``): who holds the GIL. A family is told by
+its outermost spans (the front's are ``front.conn`` [requests,
+bytes_in, bytes_out], a connection thread's whole life; its ``tts`` is
+near zero, a thread's CPU clock starts with it). The last line is the
+GIL probe's (``gil.probe`` [wait_us]): what a woken thread waited for it.
 
 Usage:
   python tools/traceview.py /tmp/trace.json [--stages name1,name2,...]
@@ -22,10 +32,12 @@ cut recorded for it, then every span that carries ``batch=N`` as a
 tree by ``parent``, per thread, with each span's self time (its
 duration less its children's). ``--batches`` prints one row per batch:
 the decode's phases, the submit, the fold's device wait and its
-metadata pass.
+metadata pass, then the decode's, the submit's and the fold's CPU and
+off-core milliseconds and what the batch's native calls waited for the
+GIL on their return (``gil``).
 
-``--split NAME --against A,B`` is the only view of the GIL the spans
-give: every ``NAME`` span goes into one of two groups by whether spans
+``--split NAME --against A,B`` is the view of the GIL from before the
+spans carried CPU time: every ``NAME`` span goes into one of two groups by whether spans
 of the ``--against`` names (on whatever thread) cover at least half of
 it, and each group prints its count, the median and mean of its
 durations and the mean share covered. A page fetched while the store
@@ -121,9 +133,12 @@ def stage_summary(events: list[dict], stages=None,
     """Per-name span statistics over ``events`` (optionally windowed
     to [t0_us, t1_us] and filtered to ``stages``).
 
-    Returns ``{name: {"count", "busy_s", "first_us", "last_us",
-    "max_gap_s", "occupancy"}}`` plus a ``"_wall_s"`` entry — the span
-    of the whole selection, the denominator of every occupancy.
+    Returns ``{name: {"count", "busy_s", "cpu_s", "offcore_s",
+    "first_us", "last_us", "max_gap_s", "occupancy"}}`` plus a
+    ``"_wall_s"`` entry — the span of the whole selection, the
+    denominator of every occupancy. ``cpu_s`` and ``offcore_s`` are
+    over the spans that carry ``tdur`` (0.0 for a trace from before
+    the field).
     Same-name spans never self-nest in this codebase, so per-name busy
     is a plain duration sum (distinct-name nesting does not
     double-count within a name).
@@ -152,15 +167,91 @@ def stage_summary(events: list[dict], stages=None,
             if prev_end is not None:
                 max_gap = max(max_gap, e["ts"] - prev_end)
             prev_end = max(prev_end or 0.0, e["ts"] + e.get("dur", 0.0))
+        timed = [e for e in evs if "tdur" in e]
+        cpu_us = sum(e["tdur"] for e in timed)
         out[name] = {
             "count": len(evs),
             "busy_s": busy_us / 1e6,
+            "cpu_s": cpu_us / 1e6,
+            "offcore_s": (sum(e.get("dur", 0.0) for e in timed)
+                          - cpu_us) / 1e6,
             "first_us": evs[0]["ts"],
             "last_us": prev_end,
             "max_gap_s": max_gap / 1e6,
             "occupancy": (busy_us / wall_us) if wall_us > 0 else 0.0,
         }
     return out
+
+
+# A thread family by what its outermost spans are called. Not by the
+# thread's name: the OS hands a dead thread's ident to the next one, so
+# over a run one ``tid`` carries a downloader's name and then those of
+# fifty connection threads.
+ROOT_FAMILIES = (("downloader", ("fetch.", "round.")),
+                 ("store", ("ingest.", "sink.")),
+                 ("batcher", ("serve.batch",)),
+                 ("front", ("front.",)),
+                 ("refresh", ("serve.snapshot", "snapshot.")),
+                 ("probe", ("gil.",)))
+
+
+def family_of(root_name: str) -> str:
+    for family, prefixes in ROOT_FAMILIES:
+        if root_name.startswith(prefixes):
+            return family
+    return "other"
+
+
+def thread_families(events: list[dict]) -> dict:
+    """Per thread family ``{"roots", "wall_s", "cpu_s", "offcore_s",
+    "native_s", "gil_s"}``: how many outermost spans the family's
+    threads recorded, their wall, CPU and off-core seconds (over those
+    that carry ``tdur``), and over all the spans under them the seconds
+    inside native calls and taking the GIL back after them. The probe's
+    spans are in no family (:func:`probe_waits`)."""
+    spans = [e for e in complete_spans(events) if "id" in e]
+    by_id = {(e["pid"], e["id"]): e for e in spans}
+    root_of: dict = {}
+
+    def root(e: dict) -> dict:
+        key = (e["pid"], e["id"])
+        if key not in root_of:
+            up = by_id.get((e["pid"], e.get("parent", 0)))
+            root_of[key] = e if up is None else root(up)
+        return root_of[key]
+
+    out: dict = {}
+    for e in spans:
+        top = root(e)
+        family = family_of(top["name"])
+        if family == "probe":
+            continue
+        row = out.setdefault(family, {
+            "roots": 0, "wall_s": 0.0, "cpu_s": 0.0, "offcore_s": 0.0,
+            "native_s": 0.0, "gil_s": 0.0})
+        if e is top:
+            row["roots"] += 1
+            if "tdur" in e:
+                row["wall_s"] += e.get("dur", 0.0) / 1e6
+                row["cpu_s"] += e["tdur"] / 1e6
+                row["offcore_s"] += (e.get("dur", 0.0) - e["tdur"]) / 1e6
+        args = e.get("args", {})
+        row["native_s"] += args.get("native_us", 0.0) / 1e6
+        row["gil_s"] += args.get("gil_us", 0.0) / 1e6
+    return out
+
+
+def nearest_rank(ordered: list[float], percent: int) -> float:
+    """The smallest of the ascending values with ``percent`` % of them
+    at or under it."""
+    return ordered[max(0, -(-percent * len(ordered) // 100) - 1)]
+
+
+def probe_waits(events: list[dict]) -> list[float]:
+    """The GIL probe's ``wait_us`` readings, ascending."""
+    return sorted(e["args"]["wait_us"] for e in events
+                  if e.get("ph") == "X" and e.get("name") == "gil.probe"
+                  and "wait_us" in e.get("args", {}))
 
 
 def self_us(spans: list[dict]) -> dict:
@@ -217,26 +308,36 @@ BATCH_COLUMNS = ("decode.concat_b64", "decode.native_call",
                  "decode.issuer_groups", "decode.pack",
                  "native.decode_batch", "ingest.decode", "ingest.submit",
                  "fold.wait_device", "fold.metadata", "device.fold")
+# The spans of a batch whose CPU and off-core time get a column each.
+BATCH_CPU_COLUMNS = ("ingest.decode", "ingest.submit", "device.fold")
 
 
 def batch_table(events: list[dict]) -> list[dict]:
     """One row per batch: milliseconds under each of ``BATCH_COLUMNS``
     (``native.decode_batch`` as self time: what its three children
     leave, the row allocation), with the native call's ``threads`` and
-    ``pad``."""
+    ``pad``; ``cpu:<name>`` / ``off:<name>`` for ``BATCH_CPU_COLUMNS``
+    (milliseconds on a core and off one), and ``gil``: what the batch's
+    native calls waited for the GIL on their return."""
     spans = complete_spans(events)
     selfs = self_us(spans)
     rows: dict[int, dict] = {}
     for e in spans:
-        batch = e.get("args", {}).get("batch")
+        args = e.get("args", {})
+        batch = args.get("batch")
         if not batch or e["name"] not in BATCH_COLUMNS:
             continue
         row = rows.setdefault(batch, {"batch": batch, "t_s": e["ts"] / 1e6})
+        row["gil"] = row.get("gil", 0.0) + args.get("gil_us", 0.0) / 1e3
+        if e["name"] in BATCH_CPU_COLUMNS and "tdur" in e:
+            for key, us in (("cpu:", e["tdur"]),
+                            ("off:", e["dur"] - e["tdur"])):
+                row[key + e["name"]] = row.get(key + e["name"], 0.0) + us / 1e3
         ms = (selfs[e["pid"], e["id"]] if e["name"] == "native.decode_batch"
               else e["dur"]) / 1e3
         row[e["name"]] = row.get(e["name"], 0.0) + ms
         if e["name"] == "decode.native_call":
-            row["threads"], row["pad"] = e["args"]["threads"], e["args"]["pad"]
+            row["threads"], row["pad"] = args["threads"], args["pad"]
     return [rows[b] for b in sorted(rows)]
 
 
@@ -353,13 +454,18 @@ def main(argv=None) -> int:
         print("\n".join(lines) or f"no span carries batch={args.batch}")
         return 0 if lines else 1
     if args.batches:
+        split = [k + c for c in BATCH_CPU_COLUMNS for k in ("cpu:", "off:")]
         print(f"{'batch':>5} {'t_s':>8} {'thr':>3} {'pad':>5} "
-              + " ".join(f"{c.split('.')[1][:11]:>11}" for c in BATCH_COLUMNS))
+              + " ".join(f"{c.split('.')[1][:11]:>11}" for c in BATCH_COLUMNS)
+              + " " + " ".join(f"{c[:4] + c.split('.')[1][:6]:>10}"
+                               for c in split) + f" {'gil':>7}")
         for row in batch_table(events):
             print(f"{row['batch']:>5} {row['t_s']:>8.2f} "
                   f"{row.get('threads', 0):>3} {row.get('pad', 0):>5} "
                   + " ".join(f"{row.get(c, 0.0):>11.1f}"
-                             for c in BATCH_COLUMNS))
+                             for c in BATCH_COLUMNS)
+                  + " " + " ".join(f"{row.get(c, 0.0):>10.1f}" for c in split)
+                  + f" {row.get('gil', 0.0):>7.2f}")
         return 0
     summary = stage_summary(events, stages=stages)
     wall = summary.pop("_wall_s")
@@ -368,18 +474,37 @@ def main(argv=None) -> int:
         return 1
     print(f"trace wall: {wall:.3f}s over "
           f"{sum(s['count'] for s in summary.values())} spans")
-    hdr = f"{'stage':<28} {'count':>7} {'busy_s':>9} {'occ':>6} {'max_gap_s':>10}"
+    hdr = (f"{'stage':<28} {'count':>7} {'busy_s':>9} {'cpu_s':>9} "
+           f"{'off_s':>9} {'occ':>6} {'max_gap_s':>10}")
     print(hdr)
     print("-" * len(hdr))
     occ_sum = 0.0
     for name in sorted(summary, key=lambda n: -summary[n]["busy_s"]):
         s = summary[name]
+        if name == "gil.probe":  # asleep by design: its line is the last
+            continue
         occ_sum += s["occupancy"]
         print(f"{name:<28} {s['count']:>7} {s['busy_s']:>9.3f} "
+              f"{s['cpu_s']:>9.3f} {s['offcore_s']:>9.3f} "
               f"{s['occupancy']:>6.2f} {s['max_gap_s']:>10.3f}")
-    print(f"{'(sum)':<28} {'':>7} {'':>9} {occ_sum:>6.2f}")
+    print(f"{'(sum)':<28} {'':>7} {'':>9} {'':>9} {'':>9} {occ_sum:>6.2f}")
     if occ_sum > 1.05:
         print("occupancies sum past 1.0: stages are overlapping")
+    families = thread_families(events)
+    if families:
+        print(f"{'threads':<12} {'roots':>7} {'wall_s':>9} {'cpu_s':>9} "
+              f"{'off_s':>9} {'native_s':>9} {'gil_s':>9}")
+        for family in sorted(families, key=lambda f: -families[f]["cpu_s"]):
+            f = families[family]
+            print(f"{family:<12} {f['roots']:>7} {f['wall_s']:>9.3f} "
+                  f"{f['cpu_s']:>9.3f} {f['offcore_s']:>9.3f} "
+                  f"{f['native_s']:>9.3f} {f['gil_s']:>9.3f}")
+    waits = probe_waits(events)
+    if waits:
+        print(f"gil.probe: {len(waits)} wake-ups, waited mean "
+              f"{statistics.fmean(waits) / 1e3:.3f} ms, "
+              f"p95 {nearest_rank(waits, 95) / 1e3:.3f} ms, "
+              f"max {waits[-1] / 1e3:.3f} ms")
     return 0
 
 
