@@ -1025,7 +1025,8 @@ class _RawChunk:
         return [raw.page for raw in self.raws]
 
     def max_leaf_input_len(self) -> int:
-        """The longest base64 ``leaf_input`` of the chunk."""
+        """The longest base64 ``leaf_input`` of the chunk: a ``max`` over
+        the numbers the pages carry from their scan, no array read."""
         return max((raw.page.max_leaf_input_len() for raw in self.raws),
                    default=0)
 

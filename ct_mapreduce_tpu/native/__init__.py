@@ -236,6 +236,23 @@ def load() -> Optional[ctypes.CDLL]:
             lib.has_scan = lib.has_strs
         except AttributeError:
             lib.has_scan = False
+        # A chunk of scanned pages decoded from a page table (PR 39):
+        # the scan also leaves the page's four sizes, and the decoder
+        # builds the per-entry pointer columns itself; no Python object
+        # on either side, so both stay on this handle. Same
+        # stale-library contract: callers check `has_pages` and build
+        # the columns in NumPy. A table holds addresses as int64.
+        try:
+            lib.ctmr_scan_entries_stats.restype = ctypes.c_int64
+            lib.ctmr_scan_entries_stats.argtypes = (
+                lib.ctmr_scan_entries.argtypes + [i64p])
+            lib.ctmr_decode_entries_pages.restype = ctypes.c_int64
+            lib.ctmr_decode_entries_pages.argtypes = [
+                ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64,
+            ] + lib.ctmr_decode_entries_strs.argtypes[5:]
+            lib.has_pages = lib.has_scan
+        except AttributeError:
+            lib.has_pages = False
         # Distinct byte windows of a batch (PR 37): reads the host rows
         # where they lie, no Python object, so it stays on this handle.
         # Same stale-library contract: `unique_windows` checks

@@ -953,6 +953,65 @@ int64_t ctmr_decode_entries_strs(
       chunk_used);
 }
 
+// The same decode over a chunk of get-entries pages kept as bytes (PR
+// 39): `table` has a row of six int64 a page, {entries, address of the
+// body, addresses of its li_off / li_len / ed_off / ed_len columns}, as
+// ctmr_scan_entries left them. The per-entry pointer and prefix-sum
+// columns that ctmr_decode_entries_strs is handed are built here, GIL
+// released: 2 MB of writes for 65,536 entries, which cost the store
+// thread 770 small NumPy calls a batch while it held the GIL and lost
+// it between them. The columns are the calling thread's own and kept
+// from call to call. `n` is the chunk's entry count as the caller
+// sized the outputs by; -1 too where the table's counts do not add up
+// to it.
+int64_t ctmr_decode_entries_pages(
+    int64_t n_pages, const int64_t* table, int64_t n,
+    int64_t pad_len,
+    uint8_t* data, int32_t* length,
+    int64_t* ts_ms, int32_t* entry_ty,
+    uint8_t* issuer_buf, int64_t issuer_cap,
+    int64_t* issuer_off, int32_t* issuer_len,
+    int32_t* status,
+    uint8_t* scratch, int64_t scratch_each,
+    int64_t threads, int64_t* chunk_used) {
+  stamp::Scope stamped;
+  int64_t total = 0;
+  for (int64_t p = 0; p < n_pages; ++p) {
+    int64_t count = table[p * 6];
+    if (count < 0) return -1;
+    total += count;
+  }
+  if (total != n) return -1;
+  thread_local std::vector<const char*> li_ptr, ed_ptr;
+  thread_local std::vector<int64_t> li_sum, ed_sum;
+  li_ptr.resize((size_t)n);
+  ed_ptr.resize((size_t)n);
+  li_sum.resize((size_t)n + 1);
+  ed_sum.resize((size_t)n + 1);
+  li_sum[0] = ed_sum[0] = 0;
+  int64_t a = 0;
+  for (int64_t p = 0; p < n_pages; ++p) {
+    const int64_t* row = table + p * 6;
+    const char* body = (const char*)(intptr_t)row[1];
+    const int64_t* li_off = (const int64_t*)(intptr_t)row[2];
+    const int64_t* li_len = (const int64_t*)(intptr_t)row[3];
+    const int64_t* ed_off = (const int64_t*)(intptr_t)row[4];
+    const int64_t* ed_len = (const int64_t*)(intptr_t)row[5];
+    for (int64_t i = 0; i < row[0]; ++i, ++a) {
+      li_ptr[(size_t)a] = body + li_off[i];
+      ed_ptr[(size_t)a] = body + ed_off[i];
+      li_sum[(size_t)a + 1] = li_sum[(size_t)a] + li_len[i];
+      ed_sum[(size_t)a + 1] = ed_sum[(size_t)a] + ed_len[i];
+    }
+  }
+  return decode_entries_mt(
+      n, B64Col{nullptr, li_ptr.data(), li_sum.data()},
+      B64Col{nullptr, ed_ptr.data(), ed_sum.data()},
+      pad_len, data, length, ts_ms, entry_ty, issuer_buf, issuer_cap,
+      issuer_off, issuer_len, status, scratch, scratch_each, threads,
+      chunk_used);
+}
+
 // Where the bytes of a list's `str` items lie: ptr[i] and the prefix
 // sums off[0..n] for list[i], without copying a byte. Called with the
 // GIL HELD (the Python side loads this entry point through
@@ -1570,18 +1629,18 @@ inline bool is_key(const char* s, int64_t n, const char* name, int64_t len) {
 
 }  // namespace jsonscan
 
-extern "C" {
-
 // Entry count of the get-entries response `body[0, len)`, with entry
 // i's leaf_input at body[li_off[i], li_off[i] + li_len[i]) and its
 // extra_data likewise (absent: length 0 at offset 0); -1 when the scan
 // does not take the document (see above) or it holds more than `cap`
-// entries. Touches no Python object: loaded on the GIL-releasing
-// handle.
-int64_t ctmr_scan_entries(
+// entries. `stats`, where given, receives what the decoder's caller
+// sizes its buffers by, so that nobody reads the columns again for it:
+// the longest leaf_input, the longest extra_data, and the two columns'
+// total bytes.
+static int64_t scan_entries(
     const char* body, int64_t len, int64_t cap,
-    int64_t* li_off, int64_t* li_len, int64_t* ed_off, int64_t* ed_len) {
-  stamp::Scope stamped;
+    int64_t* li_off, int64_t* li_len, int64_t* ed_off, int64_t* ed_len,
+    int64_t* stats) {
   using jsonscan::is_key;
   jsonscan::Cur c{body, body + len};
   const char* s = nullptr;
@@ -1653,7 +1712,45 @@ int64_t ctmr_scan_entries(
   }
   c.ws();
   if (c.p != c.end || !seen_entries) return -1;
+  if (stats != nullptr) {
+    int64_t max_li = 0, max_ed = 0, sum_li = 0, sum_ed = 0;
+    for (int64_t i = 0; i < n; ++i) {
+      max_li = li_len[i] > max_li ? li_len[i] : max_li;
+      max_ed = ed_len[i] > max_ed ? ed_len[i] : max_ed;
+      sum_li += li_len[i];
+      sum_ed += ed_len[i];
+    }
+    stats[0] = max_li;
+    stats[1] = max_ed;
+    stats[2] = sum_li;
+    stats[3] = sum_ed;
+  }
   return n;
+}
+
+extern "C" {
+
+// The scan as the library has had it since PR 30. Touches no Python
+// object: loaded on the GIL-releasing handle, as its sibling is.
+int64_t ctmr_scan_entries(
+    const char* body, int64_t len, int64_t cap,
+    int64_t* li_off, int64_t* li_len, int64_t* ed_off, int64_t* ed_len) {
+  stamp::Scope stamped;
+  return scan_entries(body, len, cap, li_off, li_len, ed_off, ed_len,
+                      nullptr);
+}
+
+// The same scan, leaving the page's four sizes in `stats[0..4)` (PR
+// 39). A sibling and not a new argument: a library from before it is
+// then one without the symbol, never one called with a pointer it does
+// not know of.
+int64_t ctmr_scan_entries_stats(
+    const char* body, int64_t len, int64_t cap,
+    int64_t* li_off, int64_t* li_len, int64_t* ed_off, int64_t* ed_len,
+    int64_t* stats) {
+  stamp::Scope stamped;
+  return scan_entries(body, len, cap, li_off, li_len, ed_off, ed_len,
+                      stats);
 }
 
 }  // extern "C"

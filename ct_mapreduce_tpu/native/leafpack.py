@@ -15,6 +15,7 @@ assert byte equality).
 from __future__ import annotations
 
 import ctypes
+from bisect import bisect_left, bisect_right
 from dataclasses import dataclass
 from typing import NamedTuple, Optional, Sequence
 
@@ -22,6 +23,7 @@ import numpy as np
 
 from ct_mapreduce_tpu.native import load as load_native, note_return
 from ct_mapreduce_tpu.telemetry import trace
+from ct_mapreduce_tpu.telemetry.metrics import incr_counter
 
 # Status codes — keep in sync with ctmr_native.cpp.
 OK = 0
@@ -292,16 +294,43 @@ class EntryPage:
     its ``extra_data`` likewise (length 0: absent or empty). ``body`` is
     what the transport returned when the native scan took the page,
     else the page's strings laid end to end (:func:`page_of_strings`);
-    either way nothing exists per entry but four integers."""
+    either way nothing exists per entry but four integers.
+
+    ``stats`` is what a decode sizes its buffers by: the longest
+    ``leaf_input``, the longest ``extra_data`` and the two columns'
+    total bytes, as Python ints. The scan leaves them; whoever builds
+    a page without them has them computed here, once, on its own
+    thread. ``row`` is the page's line of a chunk's page table
+    (:func:`_page_table`): entry count, address of ``body``, addresses
+    of the four columns. The addresses are those of the buffers the
+    page was built over, which it keeps for as long as it lives: a
+    page's columns are not written to or replaced afterwards."""
 
     body: bytes
     li_off: np.ndarray  # int64[n]
     li_len: np.ndarray  # int64[n]
     ed_off: np.ndarray  # int64[n]
     ed_len: np.ndarray  # int64[n]
+    stats: Optional[tuple] = None  # (max li, max ed, sum li, sum ed)
+
+    def __post_init__(self) -> None:
+        self.li_off, self.li_len, self.ed_off, self.ed_len = cols = tuple(
+            np.ascontiguousarray(c, np.int64) for c in (
+                self.li_off, self.li_len, self.ed_off, self.ed_len))
+        if len({c.shape for c in cols}) != 1 or cols[0].ndim != 1:
+            raise ValueError("a page's four columns are one length")
+        if self.stats is None:
+            li, ed = self.li_len, self.ed_len
+            self.stats = (int(li.max(initial=0)), int(ed.max(initial=0)),
+                          int(li.sum()), int(ed.sum()))
+        # bytes -> a read-only view, no copy: its address is the buffer's
+        body = (np.frombuffer(self.body, np.uint8).ctypes.data
+                if self.body else 0)
+        self._held = (self.body, cols)  # what `row` points into
+        self.row = (len(cols[0]), body, *(c.ctypes.data for c in cols))
 
     def __len__(self) -> int:
-        return len(self.li_off)
+        return self.row[0]
 
     def leaf_input(self, i: int) -> bytes:
         off = int(self.li_off[i])
@@ -321,7 +350,7 @@ class EntryPage:
                             (self.ed_off, self.ed_len)))
 
     def max_leaf_input_len(self) -> int:
-        return int(self.li_len.max(initial=0))
+        return self.stats[0]
 
 
 @dataclass
@@ -355,20 +384,27 @@ def scan_entries(body: bytes, cap: int) -> Optional[EntryPage]:
     byte outside ASCII, a member that is no string, more than ``cap``
     entries, anything malformed) or the library has no scanner. The
     caller then parses the body as JSON, which accepts or raises as it
-    always has."""
+    always has. The same call leaves the page's ``stats`` (a library
+    from before that: the page computes them)."""
     lib = load_native()
     if lib is None or not getattr(lib, "has_scan", False):
         return None
     cols = np.empty((4, max(int(cap), 1)), np.int64)
     i64p = ctypes.POINTER(ctypes.c_int64)
-    n = lib.ctmr_scan_entries(
-        body, len(body), cols.shape[1],
-        *(cols[k].ctypes.data_as(i64p) for k in range(4)))
+    args = (body, len(body), cols.shape[1],
+            *(cols[k].ctypes.data_as(i64p) for k in range(4)))
+    sizes = None
+    if getattr(lib, "has_pages", False):
+        sizes = (ctypes.c_int64 * 4)()
+        n = lib.ctmr_scan_entries_stats(*args, sizes)
+    else:
+        n = lib.ctmr_scan_entries(*args)
     if trace.enabled():
         note_return(lib)
     if n < 0:
         return None
-    return EntryPage(body, *(cols[k, :n] for k in range(4)))
+    return EntryPage(body, *(cols[k, :n] for k in range(4)),
+                     stats=sizes and tuple(sizes))
 
 
 def page_of_strings(leaf_inputs: Sequence[str],
@@ -394,13 +430,83 @@ class _B64Columns(NamedTuple):
     """Both base64 columns as the native decoder takes them. Entry i of
     a column is ``off[i+1] - off[i]`` bytes; ``li``/``ed`` are the
     joined buffers (``bytes``) when ``owner`` is None, else arrays of
-    pointers into the bodies and strings that ``owner`` keeps alive."""
+    pointers into the bodies and strings that ``owner`` keeps alive.
+    The properties are what :func:`_decode_native` sizes its buffers
+    by, under the names :class:`_PageTable` gives them."""
 
     li: object
     li_off: np.ndarray  # int64[n + 1]
     ed: object
     ed_off: np.ndarray  # int64[n + 1]
     owner: Optional[list]
+
+    @property
+    def n(self) -> int:
+        return len(self.li_off) - 1
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.li_off[-1] + self.ed_off[-1])
+
+    @property
+    def longest(self) -> tuple[int, int]:
+        """The longest ``leaf_input`` and the longest ``extra_data``."""
+        return tuple(int(np.max(np.diff(off))) if self.n else 0
+                     for off in (self.li_off, self.ed_off))
+
+    def ed_bytes(self, lo: int, hi: int) -> int:
+        """The ``extra_data`` bytes of lanes ``[lo, hi)``."""
+        return int(self.ed_off[hi] - self.ed_off[lo])
+
+
+class _PageTable(NamedTuple):
+    """A chunk whose every page is an :class:`EntryPage`, as
+    ``ctmr_decode_entries_pages`` takes it: a row a page (``EntryPage.
+    row``), from which the call builds the per-entry columns itself,
+    GIL released. What :func:`_decode_native` has to know before the
+    call comes from the pages' ``stats``: no per-entry array exists on
+    this side. ``owner`` is the pages, alive for the call."""
+
+    rows: np.ndarray  # int64[pages, 6]
+    n: int
+    starts: list  # page p holds lanes [starts[p], starts[p + 1])
+    ed_sums: list  # prefix sums of the pages' extra_data bytes
+    longest: tuple  # (leaf_input, extra_data)
+    nbytes: int
+    owner: list
+
+    def ed_bytes(self, lo: int, hi: int) -> int:
+        """An upper bound on the ``extra_data`` bytes of lanes ``[lo,
+        hi)``: those of every page that has a lane among them (a page
+        is 512 lanes where a thread's range is some 5,000)."""
+        if hi <= lo:
+            return 0
+        first = bisect_right(self.starts, lo) - 1
+        last = bisect_left(self.starts, hi) - 1
+        return self.ed_sums[last + 1] - self.ed_sums[first]
+
+
+def _page_table(pages: Sequence, n: int) -> Optional[_PageTable]:
+    """The chunk's page table, or None where a page is no
+    :class:`EntryPage`. A loop over attributes and Python ints and one
+    ``np.array`` of them: nothing here lets the GIL go, and nothing
+    grows with the entries."""
+    rows = []
+    starts, ed_sums = [0], [0]
+    max_li = max_ed = nbytes = 0
+    for page in pages:
+        if not isinstance(page, EntryPage):
+            return None
+        rows.append(page.row)
+        li, ed, li_sum, ed_sum = page.stats
+        max_li, max_ed = max(max_li, li), max(max_ed, ed)
+        nbytes += li_sum + ed_sum
+        starts.append(starts[-1] + page.row[0])
+        ed_sums.append(ed_sums[-1] + ed_sum)
+    if starts[-1] != n:  # the outputs were sized by n
+        raise ValueError(f"pages hold {starts[-1]} entries, not {n}")
+    return _PageTable(np.array(rows, np.int64).reshape(-1, 6), n, starts,
+                      ed_sums, (max_li, max_ed), nbytes, list(pages))
 
 
 def _flatten(pages: Sequence) -> tuple[list, list]:
@@ -457,25 +563,42 @@ def _ptr_columns(lib, pages: Sequence, n: int) -> tuple[_B64Columns, int]:
     return _B64Columns(ptr[0], off[0], ptr[1], off[1], owner), joined
 
 
-def _b64_columns(lib, pages: Sequence, n: int) -> _B64Columns:
+def _count_pages(pages: Sequence, walked: int) -> None:
+    """``decode.pages_tabled`` / ``decode.pages_walked``, both on every
+    chunk, 0 included: a program that counts them says so each time."""
+    incr_counter("decode", "pages_tabled", value=float(len(pages) - walked))
+    incr_counter("decode", "pages_walked", value=float(walked))
+
+
+def _b64_columns(lib, pages: Sequence, n: int):
     """A chunk's base64 (``n`` entries) as the native call reads it,
     GIL held; each of ``pages`` is an :class:`EntryPage` or a
-    :class:`StrPage`. Nothing is copied where the decoder
-    can be pointed at the bytes (:func:`_ptr_columns`); ``joined``
-    counts the pages that had to be encoded and joined as before
-    (``bytes`` items, a non-ASCII ``str``), and with a library that
-    reads no pointer columns that is the whole chunk, one buffer a
-    column."""
+    :class:`StrPage`. What the chunk is decides the form, no setting
+    does. Every page an :class:`EntryPage` and a library that takes a
+    page table: the table (:func:`_page_table`), and the per-entry
+    columns are the native call's to build. Else the columns are built
+    here, page by page (``walked`` counts those pages): nothing is
+    copied where the decoder can be pointed at the bytes
+    (:func:`_ptr_columns`); ``joined`` counts the pages that had to be
+    encoded and joined as before (``bytes`` items, a non-ASCII
+    ``str``), and with a library that reads no pointer columns that is
+    the whole chunk, one buffer a column."""
     with trace.span("decode.concat_b64", cat="decode") as sp:
-        if getattr(lib, "has_strs", False):
+        joined, walked = 0, len(pages)
+        cols = (_page_table(pages, n)
+                if getattr(lib, "has_pages", False) else None)
+        if cols is not None:
+            walked = 0
+        elif getattr(lib, "has_strs", False):
             cols, joined = _ptr_columns(lib, pages, n)
         else:
             lis, eds = _flatten(pages)
             cols = _B64Columns(*_concat_b64(lis), *_concat_b64(eds),
                                owner=None)
             joined = len(pages)
-        sp.set(bytes=int(cols.li_off[-1] + cols.ed_off[-1]),
-               joined=joined, pages=len(pages))
+        sp.set(bytes=cols.nbytes, joined=joined, pages=len(pages),
+               walked=walked)
+    _count_pages(pages, walked)
     return cols
 
 
@@ -519,10 +642,12 @@ def _decode_raw_pages(
     ``threads`` > 1 splits the batch across the native library's
     persistent worker pool — one ctypes call, lane ranges decoded in
     parallel inside C++ with the GIL released. Measured on the
-    benchmark's TPU v5e host (PERF.md §5, PR 26: 65,536 entries, pad
-    2048, 13 threads): the call takes 32-46 ms, and the Python around
-    it — finding the strings, allocating rows, grouping issuers —
-    another 15-25 ms with the GIL held. ``workers`` is the legacy alias
+    benchmark's TPU v5e host (PERF.md §5, PR 39: 65,536 entries in 128
+    scanned pages, pad 2048, 13 threads): the call takes 40-41 ms, the
+    page table before it 0.3 ms, and the Python after it — allocating
+    rows, grouping issuers — 5-6 ms with the GIL held beside one
+    downloader, 12-19 ms beside three or under queries (the wait for
+    the GIL is most of the difference). ``workers`` is the legacy alias
     for the same knob (used when ``threads`` is unset). Default: the
     :func:`resolve_threads` policy (``CTMR_DECODE_THREADS`` env →
     ``CTMR_DECODE_WORKERS`` → ``os.cpu_count()``, bounded so each
@@ -542,6 +667,7 @@ def _decode_raw_pages(
     lib = (None if os.environ.get("CTMR_NATIVE", "1") == "0"
            else load_native())
     if lib is None:
+        _count_pages(pages, len(pages))
         return _decode_python(*_flatten(pages), pad_len)
 
     t = resolve_threads(n, threads if threads else workers)
@@ -612,7 +738,7 @@ def _issuer_groups(
 
 def _decode_native(
     lib,
-    cols: _B64Columns,
+    cols,
     pad_len: int,
     out: tuple,
     threads: int,
@@ -624,27 +750,25 @@ def _decode_native(
     issuer_len, issuer_buf)`` — identical DERs of one chunk share one
     span; spans carry GLOBAL offsets into the shared buffer, chunk
     ``t``'s within its slice ``[t * iss_each, (t + 1) * iss_each)`` —
-    or None when a chunk's issuer slice overflowed."""
-    li_off, ed_off = cols.li_off, cols.ed_off
-    n = len(li_off) - 1
+    or None when a chunk's issuer slice overflowed. ``cols`` is a
+    :class:`_B64Columns` or a :class:`_PageTable`."""
+    n = cols.n
     data, length, ts, ety, status = out
     issuer_off = np.zeros((n,), np.int64)
     issuer_len = np.zeros((n,), np.int32)
     # Chunk bounds mirror the C split exactly: lane [n*t//T, n*(t+1)//T).
     bounds = [(n * t) // threads for t in range(threads + 1)]
     # Each chunk's issuer slice must hold that chunk's chain bytes;
-    # its base64 extra_data length is a safe upper bound on them
-    # (issuer chain certs are ~1-2 KB).
+    # its base64 extra_data length (or, from a page table, that of the
+    # pages its lanes lie in) is a safe upper bound on them (issuer
+    # chain certs are ~1-2 KB).
     iss_each = max(
         4096,
-        max(int(ed_off[bounds[t + 1]] - ed_off[bounds[t]])
-            for t in range(threads)),
+        max(cols.ed_bytes(bounds[t], bounds[t + 1]) for t in range(threads)),
     )
     issuer_buf = np.zeros((threads * iss_each,), np.uint8)
     # A chunk's scratch must hold one decoded leaf_input + extra_data.
-    max_li = int(np.max(np.diff(li_off))) if n else 0
-    max_ed = int(np.max(np.diff(ed_off))) if n else 0
-    scratch_each = max(max_li + max_ed + 64, 4096)
+    scratch_each = max(sum(cols.longest) + 64, 4096)
     scratch = np.zeros((threads * scratch_each,), np.uint8)
     chunk_used = np.zeros((threads,), np.int64)
 
@@ -652,21 +776,25 @@ def _decode_native(
     i32p = ctypes.POINTER(ctypes.c_int32)
     u8p = ctypes.POINTER(ctypes.c_uint8)
     pool = (threads, chunk_used.ctypes.data_as(i64p))
-    if cols.owner is not None:
-        fn, li, ed = (lib.ctmr_decode_entries_strs,
-                      cols.li.ctypes.data, cols.ed.ctypes.data)
-    elif threads > 1:
-        fn, li, ed = lib.ctmr_decode_entries_mt, cols.li, cols.ed
-    else:  # also what a stale library without the pool still has
-        fn, li, ed, pool = lib.ctmr_decode_entries, cols.li, cols.ed, ()
+    if isinstance(cols, _PageTable):
+        fn = lib.ctmr_decode_entries_pages
+        b64 = (len(cols.rows), cols.rows.ctypes.data, n)
+    else:
+        if cols.owner is not None:
+            fn, li, ed = (lib.ctmr_decode_entries_strs,
+                          cols.li.ctypes.data, cols.ed.ctypes.data)
+        elif threads > 1:
+            fn, li, ed = lib.ctmr_decode_entries_mt, cols.li, cols.ed
+        else:  # also what a stale library without the pool still has
+            fn, li, ed, pool = lib.ctmr_decode_entries, cols.li, cols.ed, ()
+        b64 = (n, li, cols.li_off.ctypes.data_as(i64p),
+               ed, cols.ed_off.ctypes.data_as(i64p))
     # The one call that releases the GIL; what stands around it in
     # native.decode_batch is Python.
     with trace.span("decode.native_call", cat="decode",
                     threads=int(threads), pad=int(pad_len)):
         rc = fn(
-            n,
-            li, li_off.ctypes.data_as(i64p),
-            ed, ed_off.ctypes.data_as(i64p),
+            *b64,
             pad_len,
             data.ctypes.data_as(u8p), length.ctypes.data_as(i32p),
             ts.ctypes.data_as(i64p), ety.ctypes.data_as(i32p),

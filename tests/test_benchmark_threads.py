@@ -14,5 +14,58 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmark.tests import test_threads as theirs  # noqa: E402,F401
 from benchmark.tests.test_threads import *  # noqa: E402,F401,F403
 
+PAGES_WALKED = {
+    "name": "decode.pages_walked", "unit": "pages", "better": "lower",
+    "source": "program_counter", "layer": "native decode + pack",
+    "moves": "ingest_entries_per_s", "workloads": list(theirs.ALL_CELLS)}
+
+
+@pytest.fixture(autouse=True)
+def listed_up_to_the_seven(monkeypatch):
+    """Theirs hold PR 38's seven to the END of ``per_layer`` (``[-7:]``),
+    and a PR that lists a metric after them may edit no file under
+    ``benchmark/`` (ROADMAP R0): theirs see the list as it stood when
+    the seven ended it; what came after is the fixture's value."""
+    whole = theirs.bench_json()
+    names = [m["name"] for m in whole["per_layer"]]
+    cut = names.index(theirs.GIL_METRICS[-1]) + 1
+    then = dict(whole, per_layer=whole["per_layer"][:cut])
+    monkeypatch.setattr(theirs, "bench_json", lambda: then)
+    return whole["per_layer"][cut:]
+
+
+def test_the_seven_stand_at_the_end_of_the_list(  # noqa: F811
+        listed_up_to_the_seven):
+    """Theirs, and after the seven: ``decode.pages_walked`` (PR 39), one
+    entry for all three cells, read by the reader of
+    ``fold.meta_fallback_lanes``."""
+    theirs.test_the_seven_stand_at_the_end_of_the_list()
+    assert listed_up_to_the_seven == [PAGES_WALKED]
+    assert theirs.layer_file("decode.pages_walked") == {
+        "reader": "counter_sum",
+        "params": {"key": "decode.pages_walked", "phase": "round"}}
+
+
+@pytest.mark.parametrize("increments, want", [
+    ([], "ABSENT"),  # the parent: a program that does not count them
+    ([0.0, 0.0, 0.0], 0.0),  # every chunk went over as a page table
+    ([0.0, 128.0, 3.0], 131.0)])
+def test_pages_walked_reads_the_rounds_increments(increments, want):
+    """The reader on what the harness's emitter records: no increment
+    of the counter in the whole round, not one of 0, leaves the metric
+    out by name; else the sum over ``[t_open, t_durable]``."""
+    out = {"t_open": 10.0, "t_durable": 20.0,
+           "counters": [(9.0, "decode.pages_walked", 5.0) for _ in increments]
+           + [(11.0 + k, "decode.pages_walked", v)
+              for k, v in enumerate(increments)]
+           + [(12.0, "decode.pages_tabled", 128.0)]}
+    metrics, absent = theirs.layers.read_metrics(
+        [PAGES_WALKED], "backfill-3log", {"out": out})
+    if want == "ABSENT":
+        assert absent == ["decode.pages_walked"] and metrics == {}
+    else:
+        assert absent == [] and metrics == {
+            "decode.pages_walked": {"value": want, "unit": "pages"}}
+
 pytestmark = [pytest.mark.timeout(300),
               pytest.mark.usefixtures("benchmark_checkout")]

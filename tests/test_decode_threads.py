@@ -85,6 +85,29 @@ def test_decode_byte_exact_across_thread_counts():
         _assert_batches_equal(base, got, f"threads={t}")
 
 
+@pytest.mark.parametrize("page", [1, 37, 600])
+@pytest.mark.parametrize("threads", [1, 2, 3, 7, 16])
+def test_page_table_decode_byte_exact_across_thread_counts(threads, page):
+    """The same corpus as pages kept as bytes: the native call builds
+    the pointer columns from the chunk's page table, and every thread
+    count and page size gives the serial decode of the two lists; each
+    thread's issuer slice is sized from the pages its lanes lie in, an
+    upper bound that never made a chunk overflow into the retry."""
+    from ct_mapreduce_tpu.telemetry import metrics
+
+    lis, eds = _wire_corpus()
+    base = leafpack.decode_raw_batch(lis, eds, 2048, threads=1)
+    before = dict(metrics.get_sink().snapshot()["counters"])
+    pages = _as_pages(lis, eds, page)
+    got = leafpack.decode_raw_pages(pages, 2048, threads=threads)
+    _assert_batches_equal(base, got, f"pages of {page}, threads={threads}")
+    after = metrics.get_sink().snapshot()["counters"]
+    assert after["decode.pages_tabled"] - before.get(
+        "decode.pages_tabled", 0.0) == len(pages)
+    assert after["decode.pages_walked"] == before.get(
+        "decode.pages_walked", 0.0)
+
+
 def test_sidecars_byte_exact_across_thread_counts_mutation_fuzz():
     """threads=N sidecar extraction over the SAME mutation-fuzz corpus
     test_preparsed.py pins against the device walker — including the
@@ -202,18 +225,31 @@ def _grouping_corpus(name: str):
     return _CORPORA[name]
 
 
-@pytest.mark.parametrize("as_bytes", [False, True], ids=["str", "bytes"])
+def _as_pages(lis, eds, page: int) -> list:
+    """The two columns cut into ``EntryPage``s of ``page`` entries: the
+    form the downloader enqueues, which goes to the decoder as a page
+    table (PR 39)."""
+    return [leafpack.page_of_strings(lis[a:a + page], eds[a:a + page])
+            for a in range(0, len(lis), page)]
+
+
+@pytest.mark.parametrize("form", ["str", "bytes", "pages"])
 @pytest.mark.parametrize("threads", [1, 2, 4, 13])
 @pytest.mark.parametrize(
     "corpus", ["interleaved", "nochain_chunk", "empty_extra", "late_issuer"])
-def test_issuer_groups_match_python_lane(corpus, threads, as_bytes):
-    """Every thread count, ``str`` and ``bytes`` columns alike, gives
-    the pure-Python lane's batch: arrays, group ids, group order."""
+def test_issuer_groups_match_python_lane(corpus, threads, form):
+    """Every thread count, ``str`` and ``bytes`` columns and a page
+    table alike, gives the pure-Python lane's batch: arrays, group ids,
+    group order."""
     lis, eds, want = _grouping_corpus(corpus)
-    if as_bytes:
+    if form == "bytes":
         lis = [s.encode() for s in lis]
         eds = [s.encode() for s in eds]
-    got = leafpack.decode_raw_batch(lis, eds, 2048, threads=threads)
+    if form == "pages":  # 19 a page: no chunk of any count ends on one
+        got = leafpack.decode_raw_pages(_as_pages(lis, eds, 19), 2048,
+                                        threads=threads)
+    else:
+        got = leafpack.decode_raw_batch(lis, eds, 2048, threads=threads)
     _assert_batches_equal(want, got, f"{corpus} threads={threads}")
     n_groups = {"interleaved": 3, "nochain_chunk": 2, "empty_extra": 0,
                 "late_issuer": 2}[corpus]

@@ -526,3 +526,407 @@ def test_sink_takes_list_and_page_batches_in_one_chunk():
     assert dict(got.counts) == dict(want.counts)
     # the edit is decoded: one entry seen twice, one never
     assert feed(["page", "thawed", "page", "page"]).total == want.total - 1
+
+
+# -- a chunk of pages kept as bytes goes over as a page table (PR 39) ------
+
+_WIRE: list = []
+
+
+def wire_cycle(first: int, count: int) -> list[dict]:
+    """``count`` wire entries from ``first`` on, the fixture's 104 taken
+    round and round (the bad entry and the chainless one come by again
+    every time)."""
+    if not _WIRE:
+        _WIRE.extend(wire_entries())
+    return [_WIRE[(first + i) % N_ENTRIES] for i in range(count)]
+
+
+def table_chunk(n_pages: int, shape: str) -> list:
+    """A chunk whose every page is an ``EntryPage``, of uneven page
+    sizes (1 to PAGE entries) so that no thread's lane range ends where
+    a page does. ``shape``: ``scanned`` (every page a scanned body);
+    ``empty_page`` (the second page, or the only one, has no entry);
+    ``no_extra`` (the first page's entries have no ``extra_data``
+    member: the scanner says length 0 at offset 0); ``scan_beside_join``
+    (every other page laid out by ``page_of_strings``)."""
+    pages, first = [], 0
+    for k in range(n_pages):
+        part = wire_cycle(first, 1 + (k * 5) % PAGE)
+        first += len(part)
+        if shape == "empty_page" and k == min(1, n_pages - 1):
+            part = []
+        if shape == "no_extra" and k == 0:
+            part = [{"leaf_input": e["leaf_input"]} for e in part]
+        if shape == "scan_beside_join" and k % 2:
+            pages.append(leafpack.page_of_strings(
+                [e["leaf_input"] for e in part],
+                [e["extra_data"] for e in part]))
+        else:
+            pages.append(leafpack.scan_entries(
+                compact({"entries": part}), PAGE))
+        assert isinstance(pages[-1], leafpack.EntryPage)
+        assert len(pages[-1]) == len(part)
+    return pages
+
+
+def page_counters() -> dict:
+    return {k: v for k, v in metrics.get_sink().snapshot()["counters"].items()
+            if k.startswith("decode.pages_")}
+
+
+def decode_both_ways(pages, pad, threads, monkeypatch):
+    """The chunk decoded from its page table, and by the columns built
+    page by page as before PR 39 (the library's flag saying it takes no
+    table); the counters say which way each went."""
+    got = leafpack.decode_raw_pages(pages, pad, threads=threads)
+    assert page_counters() == {"decode.pages_tabled": float(len(pages)),
+                               "decode.pages_walked": 0.0}
+    metrics.set_sink(metrics.InMemSink())
+    with monkeypatch.context() as m:
+        m.setattr(load(), "has_pages", False)
+        want = leafpack.decode_raw_pages(pages, pad, threads=threads)
+    assert page_counters() == {"decode.pages_tabled": 0.0,
+                               "decode.pages_walked": float(len(pages))}
+    metrics.set_sink(metrics.InMemSink())
+    return got, want
+
+
+@needs_native
+@pytest.mark.parametrize("threads", [1, 3])
+@pytest.mark.parametrize("shape", ["scanned", "empty_page", "no_extra",
+                                   "scan_beside_join"])
+@pytest.mark.parametrize("n_pages", [1, 2, 128, 300])
+def test_page_table_decodes_to_the_bytes_the_walked_columns_give(
+        n_pages, shape, threads, monkeypatch):
+    """Byte for byte the same ``DecodedBatch`` (rows, lengths,
+    timestamps, entry types, statuses, issuer groups in first-appearance
+    order) whether the native call expands the chunk's page table or
+    the store thread builds the pointer columns in NumPy; and, for the
+    chunks small enough to ask it, what the pure-Python lane gives."""
+    pages = table_chunk(n_pages, shape)
+    got, want = decode_both_ways(pages, 2048, threads, monkeypatch)
+    assert_same_batches([got], [want])
+    assert got.issuers == want.issuers
+    assert len(got.status) == sum(map(len, pages))
+    if n_pages <= 2:
+        lis, eds = leafpack._flatten(pages)
+        assert_same_batches([got],
+                            [leafpack._decode_python(lis, eds, 2048)])
+    elif shape != "no_extra":  # the cycle's 17th and 40th came by
+        assert {leafpack.OK, leafpack.BAD_B64, leafpack.NO_CHAIN} <= set(
+            got.status.tolist())
+    if shape == "no_extra":
+        assert set(got.status[:len(pages[0])].tolist()) <= {
+            leafpack.NO_CHAIN, leafpack.BAD_B64}
+
+
+@needs_native
+@pytest.mark.parametrize("threads", [1, 4])
+def test_too_long_redecode_sees_the_same_maximum_from_the_table(
+        threads, monkeypatch):
+    """A precert whose certificate rides in ``extra_data`` past the
+    narrow rows, in a chunk of scanned pages: the sink picks the narrow
+    pad from the pages' stored maxima (no NumPy), the narrow decode says
+    TOO_LONG, one full-width redecode follows: the pads and every
+    decoded batch are what the walked columns give."""
+    from tests import certgen
+
+    issuer_der = ISSUERS[0]
+    big = certgen.make_cert(
+        serial=77, issuer_cn="Scan CA 0", subject_cn="pc.example.com",
+        is_ca=False, not_after=FUTURE, extra_extensions=30,
+        extra_ext_size=40)
+    assert AggregatorSink.PAD_LEN // 2 < len(big) <= AggregatorSink.PAD_LEN
+    pre = {"leaf_input": base64.b64encode(leaflib.encode_leaf_input(
+               b"\x00" * 10, 7, entry_type=leaflib.PRECERT_ENTRY)).decode(),
+           "extra_data": base64.b64encode(leaflib.encode_extra_data(
+               [issuer_der], entry_type=leaflib.PRECERT_ENTRY,
+               pre_certificate=big)).decode()}
+    parts = [wire_cycle(0, PAGE), wire_cycle(PAGE, 3) + [pre],
+             wire_cycle(PAGE + 3, PAGE)]
+
+    def feed():
+        seen = []
+        orig = leafpack.decode_raw_pages
+
+        def spy(pages, pad_len, workers=None, threads=None):
+            seen.append((pad_len, orig(pages, pad_len, workers=workers,
+                                       threads=threads)))
+            return seen[-1][1]
+
+        agg = TpuAggregator(capacity=1 << 12, batch_size=BATCH, now=NOW)
+        sink = AggregatorSink(agg, flush_size=BATCH,
+                              decode_threads=threads)
+        with monkeypatch.context() as m:
+            m.setattr(leafpack, "decode_raw_pages", spy)
+            first = 0
+            for part in parts:
+                sink.store_raw_batch(RawBatch(
+                    start_index=first, log_url="log",
+                    page=leafpack.scan_entries(compact({"entries": part}),
+                                               PAGE)))
+                first += len(part)
+            sink.flush()
+            sink.close()
+        return seen, agg.drain()
+
+    got, got_snap = feed()
+    assert page_counters() == {"decode.pages_tabled": 6.0,  # decoded twice
+                               "decode.pages_walked": 0.0}
+    monkeypatch.setattr(load(), "has_pages", False)
+    want, want_snap = feed()
+    narrow = AggregatorSink.PAD_LEN // 2
+    assert [p for p, _ in got] == [p for p, _ in want] == [
+        narrow, AggregatorSink.PAD_LEN]
+    assert (got[0][1].status == leafpack.TOO_LONG).sum() == 1
+    assert not (got[1][1].status == leafpack.TOO_LONG).any()
+    assert_same_batches([d for _, d in got], [d for _, d in want])
+    assert got_snap.total == want_snap.total > 0
+    assert dict(got_snap.counts) == dict(want_snap.counts)
+
+
+@needs_native
+@pytest.mark.parametrize("kinds", [("scan", "list", "scan"),
+                                   ("list",) * 3, ("join", "scan", "list")])
+def test_a_str_page_in_the_chunk_takes_the_walk_and_counts_it(kinds):
+    """One ``StrPage`` among the pages and the whole chunk's columns are
+    built page by page as before: every page counts as walked, the span
+    says so, and the batch is the all-list chunk's."""
+    trace.enable(ring_size=64)
+    try:
+        got = leafpack.decode_raw_pages(chunk_pages(kinds), 2048, threads=2)
+        (span,) = [e for e in trace.snapshot_events()
+                   if e["name"] == "decode.concat_b64"]
+    finally:
+        trace.disable()
+    assert page_counters() == {"decode.pages_tabled": 0.0,
+                               "decode.pages_walked": float(len(kinds))}
+    assert span["args"]["walked"] == span["args"]["pages"] == len(kinds)
+    assert span["args"]["joined"] == 0
+    want = leafpack.decode_raw_pages(chunk_pages(("list",) * len(kinds)),
+                                     2048, threads=1)
+    assert_same_batches([got], [want])
+
+
+@needs_native
+def test_the_span_of_a_tabled_chunk_keeps_its_arguments():
+    """``decode.concat_b64`` [bytes, joined, pages, walked]: the same
+    bytes as the walked columns add up to, nothing joined, nothing
+    walked."""
+    pages = table_chunk(5, "scan_beside_join")
+    trace.enable(ring_size=64)
+    try:
+        leafpack.decode_raw_pages(pages, 2048, threads=1)
+        (span,) = [e for e in trace.snapshot_events()
+                   if e["name"] == "decode.concat_b64"]
+    finally:
+        trace.disable()
+    cols, joined = leafpack._ptr_columns(load(), pages, sum(map(len, pages)))
+    assert joined == 0
+    assert {k: span["args"][k] for k in ("bytes", "joined", "pages",
+                                         "walked")} == {
+        "bytes": cols.nbytes, "joined": 0, "pages": 5, "walked": 0}
+    assert cols.nbytes == sum(
+        len(e["leaf_input"]) + len(e["extra_data"])
+        for e in wire_cycle(0, sum(map(len, pages))))
+
+
+@pytest.mark.parametrize("native_off", [False, True],
+                         ids=["library", "CTMR_NATIVE=0"])
+def test_no_library_counts_every_page_walked(native_off, monkeypatch):
+    """The pure-Python lane takes the pages apart entry by entry: all
+    walked, none tabled, and the same batch."""
+    if native_off:
+        monkeypatch.setenv("CTMR_NATIVE", "0")
+    else:
+        monkeypatch.setattr(leafpack, "load_native", lambda: None)
+    pages = [leafpack.page_of_strings(
+        [e["leaf_input"] for e in wire_cycle(k * PAGE, PAGE)],
+        [e["extra_data"] for e in wire_cycle(k * PAGE, PAGE)])
+        for k in range(3)]
+    got = leafpack.decode_raw_pages(pages, 2048)
+    assert page_counters() == {"decode.pages_tabled": 0.0,
+                               "decode.pages_walked": 3.0}
+    lis, eds = leafpack._flatten(pages)
+    assert_same_batches([got], [leafpack._decode_python(lis, eds, 2048)])
+
+
+def fuzzed_page_strings(rng) -> tuple[list, list]:
+    n = int(rng.integers(0, 40))
+    alphabet = np.frombuffer(
+        b"ABCDEFGHIJKLMNOPQRSTUVWXYZabcdefghijklmnopqrstuvwxyz0123456789+/=",
+        np.uint8)
+
+    def text(limit: int) -> str:
+        size = int(rng.integers(0, limit))
+        return alphabet[rng.integers(0, len(alphabet), size)].tobytes(
+            ).decode()
+
+    return ([text(3000) for _ in range(n)],
+            [text(5000) if rng.integers(3) else "" for _ in range(n)])
+
+
+@needs_native
+@pytest.mark.parametrize("stale", [False, True], ids=["scan", "stale"])
+@pytest.mark.parametrize("seed", range(6))
+def test_a_pages_stored_sizes_are_its_columns_maxima_and_sums(
+        seed, stale, monkeypatch):
+    """What the scan leaves in ``stats`` (and what a page computes for
+    itself under a library from before PR 39, or when laid out from
+    strings) is ``li_len.max()``, ``ed_len.max()`` and the two sums, on
+    fuzzed pages: lengths 0 to 5,000, absent ``extra_data``, empty
+    pages."""
+    if stale:
+        monkeypatch.setattr(load(), "has_pages", False)
+    rng = np.random.default_rng(3900 + seed)
+    for _ in range(25):
+        lis, eds = fuzzed_page_strings(rng)
+        ents = [{"leaf_input": li, **({"extra_data": ed} if ed else {})}
+                for li, ed in zip(lis, eds)]
+        for page in (leafpack.scan_entries(compact({"entries": ents}), 64),
+                     leafpack.page_of_strings(lis, eds)):
+            assert page_strings(page) == (lis, eds)
+            assert page.stats == (
+                max(map(len, lis), default=0), max(map(len, eds), default=0),
+                sum(map(len, lis)), sum(map(len, eds)))
+            assert page.stats == (
+                int(page.li_len.max(initial=0)),
+                int(page.ed_len.max(initial=0)),
+                int(page.li_len.sum()), int(page.ed_len.sum()))
+            assert all(type(v) is int for v in page.stats)
+            assert page.max_leaf_input_len() == page.stats[0]
+            assert page.row[0] == len(page) == len(lis)
+
+
+@needs_native
+def test_a_chunks_longest_leaf_input_is_a_max_over_stored_ints():
+    """``_RawChunk.max_leaf_input_len``: the pages' stored numbers, for
+    ``EntryPage`` and ``StrPage`` alike, and what NumPy said before."""
+    from ct_mapreduce_tpu.ingest.sync import _RawChunk
+
+    chunk = _RawChunk()
+    assert chunk.max_leaf_input_len() == 0
+    pages = table_chunk(7, "scan_beside_join") + chunk_pages(("list",))
+    for k, page in enumerate(pages):
+        chunk.add_page(RawBatch(start_index=100 * k, log_url="log",
+                                page=page)
+                       if isinstance(page, leafpack.EntryPage) else
+                       RawBatch(page.leaf_inputs, page.extra_datas,
+                                100 * k, "log"))
+    lis, _ = leafpack._flatten(pages)
+    assert chunk.max_leaf_input_len() == max(map(len, lis))
+    assert type(chunk.max_leaf_input_len()) is int
+
+
+@needs_native
+def test_the_table_bounds_a_lane_ranges_extra_data_by_its_pages():
+    """``_PageTable.ed_bytes``: never under the exact prefix-sum
+    difference for any lane range, equal to it where the range is whole
+    pages, and no more than the pages the range touches (empty pages
+    among them cost nothing)."""
+    pages = table_chunk(40, "empty_page") + [
+        leafpack.page_of_strings([], [])] + table_chunk(9, "no_extra")
+    n = sum(map(len, pages))
+    table = leafpack._page_table(pages, n)
+    exact, _ = leafpack._ptr_columns(load(), pages, n)
+    assert (table.n, table.nbytes, table.longest) == (
+        exact.n, exact.nbytes, exact.longest)
+    assert table.rows.shape == (len(pages), 6) and table.starts[-1] == n
+    rng = np.random.default_rng(39)
+    for _ in range(400):
+        lo, hi = sorted(int(x) for x in rng.integers(0, n + 1, 2))
+        assert table.ed_bytes(lo, hi) >= exact.ed_bytes(lo, hi)
+    for p in range(len(pages)):
+        for q in range(p, len(pages)):
+            lo, hi = table.starts[p], table.starts[q + 1]
+            assert table.ed_bytes(lo, hi) == exact.ed_bytes(lo, hi)
+    whole = table.ed_bytes(0, n)
+    assert whole == exact.ed_bytes(0, n) == sum(p.stats[3] for p in pages)
+    # one lane inside page 3 is bounded by that page alone
+    mid = table.starts[3] + 1
+    assert table.ed_bytes(mid, mid + 1) == pages[3].stats[3]
+    assert leafpack._page_table(
+        pages[:2] + chunk_pages(("list",)), 0) is None
+
+
+@needs_native
+def test_the_owner_keeps_a_chunks_pages_for_the_call_and_no_longer():
+    """The table holds addresses, so what it points into must outlive
+    the call: ``owner`` has the pages (each keeps its body and columns)
+    until the columns object goes; after a whole decode nothing holds
+    them."""
+    import gc
+    import weakref
+
+    pages = table_chunk(6, "scan_beside_join")
+    n = sum(map(len, pages))
+    refs = [weakref.ref(p) for p in pages]
+    cols = leafpack._b64_columns(load(), pages, n)
+    assert isinstance(cols, leafpack._PageTable)
+    del pages
+    gc.collect()
+    assert all(r() is not None for r in refs)  # not before
+    span = leafpack._decode_native(
+        load(), cols, 2048,
+        (np.zeros((n, 2048), np.uint8), np.zeros((n,), np.int32),
+         np.zeros((n,), np.int64), np.zeros((n,), np.int32),
+         np.zeros((n,), np.int32)), 2)
+    assert span is not None
+    del cols
+    gc.collect()
+    assert all(r() is None for r in refs)  # after
+    pages = table_chunk(6, "scanned")
+    refs = [weakref.ref(p) for p in pages]
+    dec = leafpack.decode_raw_pages(pages, 2048, threads=2)
+    del pages
+    gc.collect()
+    assert all(r() is None for r in refs) and len(dec.status) > 0
+
+
+def numpy_c_calls(fn) -> int:
+    """How many calls into NumPy's C functions ``fn()`` makes on this
+    thread (``sys.setprofile``'s ``c_call`` events whose callee is
+    NumPy's, a function of the module or a method of an array)."""
+    import sys
+
+    seen = []
+
+    def prof(_frame, event, arg):
+        if event != "c_call":
+            return
+        owner = getattr(arg, "__self__", None)
+        module = (getattr(arg, "__module__", None)
+                  or type(owner).__module__ or "")
+        if module.split(".")[0] == "numpy" or isinstance(
+                owner, (np.ndarray, np.generic)):
+            seen.append(arg)
+
+    sys.setprofile(prof)
+    try:
+        fn()
+    finally:
+        sys.setprofile(None)
+    return len(seen)
+
+
+@needs_native
+def test_numpy_calls_before_the_native_call_do_not_grow_with_the_pages(
+        monkeypatch):
+    """Between the cut and ``decode.native_call`` the store thread makes
+    the same number of NumPy calls for 16 pages as for 256 when the
+    chunk goes over as a table (each would hand the GIL round beside
+    the downloaders); the walk it replaces makes some a page, which is
+    also the proof that the profile sees them."""
+    lib = load()
+    small, large = table_chunk(16, "scanned"), table_chunk(256, "scanned")
+
+    def calls(pages):
+        n = sum(map(len, pages))
+        return numpy_c_calls(lambda: leafpack._b64_columns(lib, pages, n))
+
+    tabled = calls(small), calls(large)
+    assert tabled[0] == tabled[1] <= 4, tabled
+    monkeypatch.setattr(lib, "has_pages", False)
+    walked = calls(small), calls(large)
+    assert walked[1] - walked[0] >= 2 * (256 - 16), walked
