@@ -729,6 +729,23 @@ class TpuAggregator:
         instead of trusting positions."""
         return 1
 
+    def put_rows(self, data: np.ndarray):
+        """Start the H2D transfer of one batch's rows and say where
+        they live: here the default device (asynchronous: the caller
+        enqueues it ahead of the dispatch lock, so the transfer rides
+        beside the previous step). A mesh puts each row block straight
+        on the chip that will parse it."""
+        import jax
+
+        return jax.device_put(data)
+
+    def _checkpoint_table(self):
+        """The table state a full save copies off the device: ``rows``
+        and ``count`` as they live there (one chip's arrays here; a
+        mesh's row-sharded arrays, which the copy reads shard by shard,
+        each off its own chip). Caller holds the table lock."""
+        return self.table
+
     def _drain_table(self) -> tuple[np.ndarray, np.ndarray]:
         if isinstance(self.table, buckettable.BucketTable):
             return buckettable.drain_np(self.table)
@@ -1287,7 +1304,8 @@ class TpuAggregator:
     # device ahead of the dispatch (the sink's staging ring device_puts
     # the stacked [K, B, L] buffer at submit time so the transfer
     # overlaps the previous envelope's compute). The mesh-sharded
-    # subclass routes rows host-side and overrides this to False.
+    # subclass overrides this to False: it takes the stacked rows as
+    # NumPy and puts each chunk's rows straight on their chips.
     staged_h2d = True
 
     def ingest_staged_submit(
@@ -1686,7 +1704,18 @@ class TpuAggregator:
         self.metrics["filtered_expired"] += int(f_exp.sum())
         self.metrics["filtered_cn"] += int(f_cn.sum())
         if dropped is not None:  # sharded path: routing-cap spill rate
-            self.metrics["dispatch_spill"] += int(dropped.sum())
+            spilled = int(dropped.sum())
+            self.metrics["dispatch_spill"] += spilled
+            # Every batch says both, 0 included: lanes that spilled
+            # past the per-(source, destination) quota to the exact
+            # host lane, and lanes whose fingerprint crossed the
+            # all_to_all to its home shard (a probe overflow was
+            # routed before it spilled).
+            incr_counter("shard", "dispatch_spill_lanes",
+                         value=float(spilled))
+            incr_counter("shard", "lanes_routed", value=float(
+                int((np.asarray(batch.valid, bool) & ~hl & ~f_any).sum())
+                + int(ovf.sum())))
         self.metrics["overflow"] += int(ovf.sum())
         # Device counts are MAX_ISSUERS-long; the host array may have
         # grown past that for registry-overflow issuers (host-lane-only).
@@ -2149,7 +2178,8 @@ class TpuAggregator:
             raise
         incr_counter("ckpt", "full_saves")
         written = os.path.getsize(path)
-        self._save_note = ("full", int(self.table.rows.nbytes), written)
+        self._save_note = ("full", int(self._checkpoint_table().rows.nbytes),
+                           written)
         if knobs.mode == ckpt.MODE_INCREMENTAL:
             ckpt.kill_point("base-post-rename")
             with trace.span("ckpt.seal", cat="ckpt", bytes=written):
@@ -2439,12 +2469,28 @@ class TpuAggregator:
             print(f"filter emission failed ({self.emit_filter_path}): "
                   f"{type(err).__name__}: {err}", file=sys.stderr)
 
+    @staticmethod
+    def _copy_off_device(arr) -> np.ndarray:
+        """A host-owned copy of a device array. One chip: the fetch
+        and a copy of it (``np.asarray`` of a CPU-backend array is a
+        view of the XLA buffer). Row-sharded over a mesh: every shard's
+        transfer is started, then each is written into its place in one
+        buffer, so each shard comes off its own chip and the table is
+        gathered on the host, once."""
+        shards = arr.addressable_shards
+        if len(shards) == 1:
+            return np.array(arr, copy=True)
+        out = np.empty(arr.shape, arr.dtype)
+        for shard in shards:
+            shard.data.copy_to_host_async()
+        for shard in shards:
+            out[shard.index] = np.asarray(shard.data)
+        return out
+
     def _write_npz(self, fh, host_items) -> None:
         """The whole snapshot into ``fh``, flushed and synced: the
         table's copy off the device (``ckpt.d2h``), then compression
         and the write (``ckpt.write``)."""
-        layout = ("bucket" if isinstance(self.table, buckettable.BucketTable)
-                  else "open")
         # ONE device fetch for the whole table: the .keys/.meta
         # properties each read the rows back, so going through them
         # would double checkpoint readback cost for multi-GB tables. Materialized as a
@@ -2454,9 +2500,21 @@ class TpuAggregator:
         # device memory whose lifetime it doesn't own (table swaps and
         # donation policies are backend-dependent); the copy bounds
         # the exposure to a memcpy made while swaps are locked out.
+        shards = self._topology_shards()
         with trace.span("ckpt.d2h", cat="ckpt") as sp, self._table_lock:
-            rows = np.array(self.table.rows, copy=True)
-            sp.set(bytes=int(rows.nbytes))
+            table = self._checkpoint_table()
+            rows = self._copy_off_device(table.rows)
+            count = np.array(table.count)
+            sp.set(bytes=int(rows.nbytes), shards=shards)
+        layout = ("bucket" if isinstance(table, buckettable.BucketTable)
+                  else "open")
+        if shards > 1:
+            # How evenly the key hash fills the shards, at every full
+            # save: the emptiest and the fullest shard's occupied slots
+            # and the mean over the shards.
+            set_gauge("shard", "fill_min", value=float(count.min()))
+            set_gauge("shard", "fill_max", value=float(count.max()))
+            set_gauge("shard", "fill_mean", value=float(count.mean()))
         if layout == "bucket":
             slots = rows[:, : buckettable.SLOTS * 5].reshape(-1, 5)
         else:
@@ -2498,10 +2556,10 @@ class TpuAggregator:
                 # re-hashes via the reinsertion path (_restore_table /
                 # ShardedDedup.bulk_insert_np) when either differs.
                 layout=np.array(layout),
-                n_shards=np.int64(self._topology_shards()),
+                n_shards=np.int64(shards),
                 keys=slots[:, :4],
                 meta=slots[:, 4],
-                count=np.asarray(self.table.count),
+                count=count,
                 registry=np.frombuffer(
                     self.registry.to_json().encode(), dtype=np.uint8
                 ),

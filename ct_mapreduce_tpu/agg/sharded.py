@@ -423,7 +423,9 @@ class ShardedDedup:
         if capacity % self.n_shards:
             raise ValueError("capacity must divide evenly across the mesh")
         per_shard = capacity // self.n_shards
-        row_sharded = NamedSharding(mesh, P(self.axis))
+        # Table rows and batches alike: split along axis 0, block i on
+        # chip i.
+        row_sharded = self.batch_sharding = NamedSharding(mesh, P(self.axis))
         if self.layout == "bucket":
             # The home-bucket mask operates on each LOCAL shard's
             # bucket array inside shard_map, so per-shard BUCKET count
@@ -436,9 +438,9 @@ class ShardedDedup:
             capacity = self.n_shards * nb_loc * buckettable.SLOTS
             # Bucket rows, row-sharded: shard i holds buckets
             # [i*nb_loc, (i+1)*nb_loc).
-            self.rows = jax.device_put(
-                jnp.zeros((self.n_shards * nb_loc, buckettable.ROW_WORDS),
-                          jnp.uint32), row_sharded
+            self.rows = jnp.zeros(  # made sharded: never whole on one chip
+                (self.n_shards * nb_loc, buckettable.ROW_WORDS), jnp.uint32,
+                device=row_sharded,
             )
         else:
             # The triangular-probe mask operates on each LOCAL shard
@@ -448,16 +450,16 @@ class ShardedDedup:
                 raise ValueError("per-shard capacity must be a power of two")
             # Fused table rows (4 fp words + meta), row-sharded over
             # the mesh — same layout as the single-chip TableState.
-            self.rows = jax.device_put(
-                jnp.zeros((capacity, 5), jnp.uint32), row_sharded
+            self.rows = jnp.zeros(
+                (capacity, 5), jnp.uint32, device=row_sharded
             )
         self.capacity = capacity
         self.base_hour = base_hour
         self.num_issuers = num_issuers
         self.max_probes = max_probes
         self.dispatch_factor = dispatch_factor
-        self.count = jax.device_put(
-            jnp.zeros((self.n_shards,), jnp.int32), row_sharded
+        self.count = jnp.zeros(
+            (self.n_shards,), jnp.int32, device=row_sharded
         )
         self._step_cache: dict = {}
 
@@ -527,18 +529,21 @@ class ShardedDedup:
             cn_prefix_lens = np.zeros((0, 2), np.int32)
         b, l = data.shape
         fn = self._compiled(b, l, cn_prefixes.shape[0], cn_prefixes.shape[1])
-        batch_sharding = NamedSharding(self.mesh, P(self.axis))
-        args = [
-            jax.device_put(jnp.asarray(x), batch_sharding)
-            for x in (data, length, issuer_idx, valid)
-        ]
         self.rows, self.count, out = fn(
             self.rows, self.count,
-            *args,
-            jnp.int32(now_hour), jnp.int32(self.base_hour),
-            jnp.asarray(cn_prefixes), jnp.asarray(cn_prefix_lens),
+            *self._place(data, length, issuer_idx, valid),
+            np.int32(now_hour), np.int32(self.base_hour),
+            cn_prefixes, cn_prefix_lens,
         )
         return out
+
+    def _place(self, *arrays):
+        """Each array split along its first axis over the mesh, block i
+        on chip i: NumPy goes from the host straight to its chips (never
+        through ``jnp.asarray``, which would commit the whole of it to
+        the default device first and reshard chip to chip), and an
+        array already placed so is handed through untouched."""
+        return [jax.device_put(x, self.batch_sharding) for x in arrays]
 
     def _preparsed_fn(self, c: int, flag_cap: int):
         """Compiled pre-parsed step for per-shard width ``c`` (cached;
@@ -586,14 +591,11 @@ class ShardedDedup:
         ns = self.n_shards
         c = int(serial_len.shape[0]) // ns
         fn = self._preparsed_fn(c, flag_cap)
-        sh = NamedSharding(self.mesh, P(self.axis))
-        args = [
-            jax.device_put(jnp.asarray(x), sh)
-            for x in (serials, serial_len, not_after_hour,
-                      issuer_idx, insertable)
-        ]
         self.rows, self.count, packed, ovf_bits, counts = fn(
-            self.rows, self.count, *args, jnp.int32(self.base_hour)
+            self.rows, self.count,
+            *self._place(serials, serial_len, not_after_hour,
+                         issuer_idx, insertable),
+            np.int32(self.base_hour),
         )
         return packed, ovf_bits, counts
 
@@ -644,7 +646,6 @@ class ShardedDedup:
         per_shard = [np.flatnonzero(dest == i) for i in range(n)]
         max_len = max(idx.size for idx in per_shard)
         overflowed = 0
-        batch_sharding = NamedSharding(self.mesh, P(self.axis))
         for start in range(0, max_len, chunk):
             width = min(chunk, max_len - start)
             send = np.zeros((n, width, 4), np.uint32)
@@ -657,10 +658,7 @@ class ShardedDedup:
                 valid[i, : sl.size] = True
             fn = self._bulk_insert_fn(width)
             self.rows, self.count, ovf = fn(
-                self.rows, self.count,
-                jax.device_put(jnp.asarray(send), batch_sharding),
-                jax.device_put(jnp.asarray(meta), batch_sharding),
-                jax.device_put(jnp.asarray(valid), batch_sharding),
+                self.rows, self.count, *self._place(send, meta, valid),
             )
             overflowed += int(jnp.sum(ovf))
         return overflowed

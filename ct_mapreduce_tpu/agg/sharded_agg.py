@@ -21,6 +21,7 @@ from ct_mapreduce_tpu.agg.aggregator import TpuAggregator
 from ct_mapreduce_tpu.agg.sharded import ShardedDedup, shard_of_np
 from ct_mapreduce_tpu.core import packing
 from ct_mapreduce_tpu.telemetry import trace
+from ct_mapreduce_tpu.telemetry.metrics import incr_counter
 
 
 def _pack_bits_np(flags: np.ndarray, nb: int) -> np.ndarray:
@@ -216,10 +217,24 @@ class ShardedAggregator(TpuAggregator):
     def _table_fill_exact(self) -> int:
         return self.dedup.total_count()
 
-    # The mesh step reads its rows host-side (shard routing is a
-    # host-computed partition); the staging ring must not ship the
-    # stacked buffer to one device.
+    # The staging ring stacks K chunks into one [K, B, L] buffer and
+    # would put it on the default device; the mesh step wants each
+    # chunk's rows split over the chips instead, so the ring keeps its
+    # buffer on the host and every chunk is placed by put_rows.
     staged_h2d = False
+
+    def put_rows(self, data: np.ndarray):
+        """One batch's rows from the host straight to their shards: row
+        block i of n goes to chip i, which parses it (asynchronous, as
+        the one-chip put). The only crossing of the host-device
+        boundary a batch's rows make."""
+        import jax
+
+        with trace.span("shard.put", cat="device", bytes=int(data.nbytes),
+                        shards=int(self.dedup.n_shards)):
+            rows = jax.device_put(data, self.dedup.batch_sharding)
+        incr_counter("shard", "row_bytes_h2d", value=float(data.nbytes))
+        return rows
 
     def ingest_staged_submit(self, data, length, issuer_idx, valid,
                              host_chunks):
@@ -231,6 +246,8 @@ class ShardedAggregator(TpuAggregator):
         the sink-side contract (one pending per staged flush, drain
         fully async) is identical across topologies."""
         k_chunks, b = np.asarray(length).shape
+        if not isinstance(data, np.ndarray):  # a device envelope read back
+            incr_counter("shard", "row_bytes_d2h", value=float(data.nbytes))
         flat = np.asarray(data).reshape(k_chunks * b, -1)
         return self.ingest_packed_submit(
             flat,
@@ -323,13 +340,25 @@ class ShardedAggregator(TpuAggregator):
 
     def _device_step_packed(self, batch):
         self._device_written = True
+        import jax
+
+        # Rows the ingest path already placed (put_rows, ahead of the
+        # dispatch lock) go through as they are; NumPy rows (a chunk
+        # short of the batch, padded on the host; the per-entry lane)
+        # are placed here, once. Either way the step sees the same
+        # shapes and shardings: one program. Nothing reads rows back.
+        data = batch.data
+        if not isinstance(data, jax.Array):
+            data = self.put_rows(data)
+        incr_counter("shard", "row_bytes_d2h", value=0.0)
         with trace.span("mesh.step", cat="device",
-                        shards=int(self.dedup.n_shards)):
+                        shards=int(self.dedup.n_shards),
+                        lanes=int(data.shape[0])), self._table_lock:
             return self.dedup.step(
-                np.asarray(batch.data),
-                np.asarray(batch.length),
-                np.asarray(batch.issuer_idx),
-                np.asarray(batch.valid),
+                data,
+                batch.length,
+                batch.issuer_idx,
+                batch.valid,
                 now_hour=self._now_hour(),
                 cn_prefixes=self._prefix_arr,
                 cn_prefix_lens=self._prefix_lens,
@@ -339,28 +368,20 @@ class ShardedAggregator(TpuAggregator):
         return self.dedup.n_shards
 
     # -- checkpoint ------------------------------------------------------
-    def _save_full(self, path: str, knobs, compacting: bool = False) -> None:
-        import jax.numpy as jnp
-
+    def _checkpoint_table(self):
+        # The row-sharded arrays as they live on the mesh: the writer's
+        # one copy-out reads each shard off its own chip, and no array
+        # of the table's size is made on a single device. The state
+        # type matches the dedup's layout so the codec writes the right
+        # positional keys/meta + layout + n_shards fields. Only full
+        # (ck01 / CTMRCK02 base) saves copy the table out: a delta
+        # segment's rows come from the fold-time dirty log.
         from ct_mapreduce_tpu.ops import buckettable, hashtable
 
-        # Gather the sharded table to host once, reuse the parent
-        # format (the state type must match the dedup's layout so the
-        # codec writes the right positional keys/meta + layout +
-        # n_shards fields). Only full (ck01 / CTMRCK02 base) saves
-        # gather — a delta segment's rows come from the fold-time
-        # dirty log, which is the whole point of the format.
         state_cls = (buckettable.BucketTable
                      if self.dedup.layout == "bucket"
                      else hashtable.TableState)
-        self.table = state_cls(
-            rows=jnp.asarray(np.asarray(self.dedup.rows)),
-            count=jnp.asarray(np.asarray(self.dedup.count)),
-        )
-        try:
-            super()._save_full(path, knobs, compacting=compacting)
-        finally:
-            self.table = None
+        return state_cls(rows=self.dedup.rows, count=self.dedup.count)
 
     def _restore_table(self, keys, meta, count, layout: str,
                        ckpt_shards: int) -> None:

@@ -604,13 +604,14 @@ class AggregatorSink:
                 and data.shape[0] % self.aggregator.batch_size == 0):
             # Staged mode skips the per-chunk put: the staging ring
             # ships the stacked [K, B, L] buffer in one H2D instead.
-            import jax
-
+            # Where the rows go is the aggregator's to say: one chip's
+            # default device, or each row block straight to its chip of
+            # the mesh (the only time the rows cross to the device).
             # Timing note: device_put ENQUEUES asynchronously, so this
             # sample is submit cost; the transfer itself overlaps the
             # previous step and any residual lands in completeBatch.
             with metrics.measure("ct-fetch", "h2dSubmit"):
-                data = jax.device_put(data)
+                data = self.aggregator.put_rows(data)
         return _PreparedChunk(
             data=data, host_data=data_host, length=dec.length,
             issuer_idx=issuer_idx, valid=valid, dec=dec,
@@ -710,9 +711,10 @@ class AggregatorSink:
         b = max(p.host_data.shape[0] for p in ring)
         width = ring[0].host_data.shape[1]
         agg = self.aggregator
-        # The mesh-sharded step routes rows host-side (staged_h2d is
-        # False there): it keeps the stacked rows on host, so the
-        # buffer must be fresh per envelope, not a recycled one.
+        # The mesh-sharded aggregator takes the stacked rows as NumPy
+        # (staged_h2d is False there) and places each chunk on its
+        # chips itself, asynchronously: the buffer must be fresh per
+        # envelope, not a recycled one.
         reuse = getattr(agg, "staged_h2d", True)
         buf = (self._staging_buffer(k_env, b, width) if reuse
                else np.zeros((k_env, b, width), np.uint8))

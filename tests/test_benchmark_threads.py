@@ -38,9 +38,13 @@ def test_the_seven_stand_at_the_end_of_the_list(  # noqa: F811
         listed_up_to_the_seven):
     """Theirs, and after the seven: ``decode.pages_walked`` (PR 39), one
     entry for all three cells, read by the reader of
-    ``fold.meta_fallback_lanes``."""
+    ``fold.meta_fallback_lanes``; then the four-chip cell's own block
+    (PR 40), which lists no other cell and is listed by none."""
     theirs.test_the_seven_stand_at_the_end_of_the_list()
-    assert listed_up_to_the_seven == [PAGES_WALKED]
+    assert listed_up_to_the_seven[0] == PAGES_WALKED
+    assert all(m["workloads"] == ["backfill-3log-shard4"]
+               and m["name"].startswith("shard4.")
+               for m in listed_up_to_the_seven[1:])
     assert theirs.layer_file("decode.pages_walked") == {
         "reader": "counter_sum",
         "params": {"key": "decode.pages_walked", "phase": "round"}}

@@ -288,11 +288,13 @@ def test_a_restart_from_any_instant_of_the_round_ends_with_the_reference(
     state.mkdir()
     run = Run(state)
     kept: list[tuple[str, str, dict]] = []
+    keeping = threading.Lock()  # the empty log's cursor has a thread of its own
 
     def keep(point: str) -> None:
-        where = tmp_path / f"kept{len(kept)}"
-        shutil.copytree(state, where)
-        kept.append((point, str(where), run.cursors(logs)))
+        with keeping:
+            where = tmp_path / f"kept{len(kept)}"
+            shutil.copytree(state, where)
+            kept.append((point, str(where), run.cursors(logs)))
 
     real_save = run.db.save_log_state
 
