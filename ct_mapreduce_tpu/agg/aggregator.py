@@ -2100,6 +2100,14 @@ class TpuAggregator:
           manifest. Restore replays the chain to the exact state a
           full save would have written.
 
+        A full base holds what the table holds: a bucket table is
+        packed on the device and only its occupied slots (20 B each)
+        and a byte a bucket are copied out and written, stored as they
+        are (``_write_npz``); the table lock is held for the dispatch
+        of that pack, not for the copy. ``load_checkpoint`` reads that
+        form and the positional, deflated one every earlier writer
+        wrote, by what the file says.
+
         Every file lands via temp + fsync + ``os.replace`` so a crash
         mid-write never corrupts the previous durable tick; segments
         land before the manifest that names them, so a torn tick is
@@ -2471,12 +2479,14 @@ class TpuAggregator:
 
     @staticmethod
     def _copy_off_device(arr) -> np.ndarray:
-        """A host-owned copy of a device array. One chip: the fetch
-        and a copy of it (``np.asarray`` of a CPU-backend array is a
-        view of the XLA buffer). Row-sharded over a mesh: every shard's
-        transfer is started, then each is written into its place in one
-        buffer, so each shard comes off its own chip and the table is
-        gathered on the host, once."""
+        """A host-owned copy of a whole device array: what a full save
+        of a table that cannot be packed falls back to (and how the
+        per-bucket fills, a byte a bucket, come out). One chip: the
+        fetch and a copy of it (``np.asarray`` of a CPU-backend array
+        is a view of the XLA buffer). Row-sharded over a mesh: every
+        shard's transfer is started, then each is written into its
+        place in one buffer, so each shard comes off its own chip and
+        the array is gathered on the host, once."""
         shards = arr.addressable_shards
         if len(shards) == 1:
             return np.array(arr, copy=True)
@@ -2487,27 +2497,111 @@ class TpuAggregator:
             out[shard.index] = np.asarray(shard.data)
         return out
 
+    def _pack_programs(self):
+        """``(index, chunk)``: the two programs that pack this
+        aggregator's bucket table where it lives (one chip here; the
+        mesh runs the same two under ``shard_map``, a shard a chip)."""
+        return buckettable.pack_index_jit, buckettable.pack_chunk_jit
+
+    def _pack_dispatch(self, table, count: np.ndarray):
+        """Dispatch the packing of a bucket table: ``(fill, totals,
+        chunks)`` still on the device, or None where the cached fills
+        do not add up to ``count`` (a table whose fill words cannot be
+        trusted is copied out whole). Caller holds the table lock, and
+        may release it on return: the packed chunks are buffers of the
+        save's own, not the table a later step donates, and the
+        programs that read the table are enqueued ahead of that step.
+        The number of chunks follows the occupied count; the program
+        that makes one is the same for every chunk of every save."""
+        index_fn, chunk_fn = self._pack_programs()
+        fill, index = index_fn(table.rows)
+        totals = np.asarray(index[-1]).reshape(count.size, -1)[:, -1]
+        if not np.array_equal(totals, count.reshape(-1)):
+            return None
+        per_chunk = buckettable.pack_chunk_rows(
+            table.rows.shape[0] // count.size)
+        chunks = [
+            chunk_fn(table.rows, index, np.int32(c * per_chunk),
+                     chunk=per_chunk)
+            for c in range(-(-int(totals.max(initial=0)) // per_chunk))]
+        return fill, totals.astype(np.int64), chunks
+
+    def _pack_copy_out(self, fill, totals, chunks):
+        """The packed table off the device: ``(fill uint8[buckets],
+        keys uint32[occupied, 4], meta uint32[occupied], transfers)``.
+        Every chunk a shard has rows in is started on its way at once
+        and freed as it lands; a shard's rows follow the shard before
+        it, so the whole is in the table's bucket order."""
+        base = np.concatenate([[0], np.cumsum(totals)])
+        keys = np.empty((int(base[-1]), 4), np.uint32)
+        meta = np.empty((int(base[-1]),), np.uint32)
+        wanted = []
+        for c, chunk in enumerate(chunks):
+            shards = sorted(chunk.addressable_shards,
+                            key=lambda sh: sh.index[0].start or 0)
+            per_chunk = chunk.shape[0] // (5 * len(shards))
+            for s, shard in enumerate(shards):
+                take = int(min(per_chunk, totals[s] - c * per_chunk))
+                if take > 0:
+                    shard.data.copy_to_host_async()
+                    wanted.append(
+                        (int(base[s]) + c * per_chunk, take, shard.data))
+        chunks.clear()
+        transfers = len(wanted)
+        wanted.reverse()
+        while wanted:
+            lo, take, data = wanted.pop()
+            words = np.asarray(data).reshape(5, -1)  # word-major
+            keys[lo:lo + take] = words[:4, :take].T
+            meta[lo:lo + take] = words[4, :take]
+        return self._copy_off_device(fill), keys, meta, transfers
+
     def _write_npz(self, fh, host_items) -> None:
         """The whole snapshot into ``fh``, flushed and synced: the
-        table's copy off the device (``ckpt.d2h``), then compression
-        and the write (``ckpt.write``)."""
-        # ONE device fetch for the whole table: the .keys/.meta
-        # properties each read the rows back, so going through them
-        # would double checkpoint readback cost for multi-GB tables. Materialized as a
-        # HOST-OWNED copy under the table lock — np.asarray of a
-        # CPU-backend jax array is a zero-copy VIEW of the XLA buffer,
-        # and the long savez_compressed window below must not read
-        # device memory whose lifetime it doesn't own (table swaps and
-        # donation policies are backend-dependent); the copy bounds
-        # the exposure to a memcpy made while swaps are locked out.
+        table's contents off the device (``ckpt.d2h``), then the write
+        (``ckpt.write``).
+
+        A bucket table leaves the device PACKED: under the table lock
+        the save only dispatches the programs that write every bucket's
+        fill and the occupied slots' five words, densely and in bucket
+        order, into buffers of its own (``_pack_dispatch``); the
+        copy-out of ``occupied x 20 B`` and a byte a bucket happens
+        after the lock is released. The file then holds ``fill``
+        beside ``keys`` / ``meta`` of the occupied slots, all three
+        stored as they are (fingerprints are SHA-256 output: deflate
+        buys nothing and is slowest on them). A layout that does not
+        fill contiguously (``CTMR_TABLE=open``), or a bucket table
+        whose cached fills disagree with its count, is copied out
+        whole under the lock and written positionally and deflated, as
+        every base was before; ``ckpt.base_unpacked`` counts those."""
         shards = self._topology_shards()
-        with trace.span("ckpt.d2h", cat="ckpt") as sp, self._table_lock:
-            table = self._checkpoint_table()
-            rows = self._copy_off_device(table.rows)
-            count = np.array(table.count)
-            sp.set(bytes=int(rows.nbytes), shards=shards)
-        layout = ("bucket" if isinstance(table, buckettable.BucketTable)
-                  else "open")
+        with trace.span("ckpt.d2h", cat="ckpt") as sp:
+            with self._table_lock:
+                table = self._checkpoint_table()
+                count = np.array(table.count)
+                bucket = isinstance(table, buckettable.BucketTable)
+                packed = self._pack_dispatch(table, count) if bucket else None
+                if packed is None:
+                    # A HOST-OWNED copy made under the lock: np.asarray
+                    # of a CPU-backend jax array is a zero-copy VIEW of
+                    # the XLA buffer, and the long write below must not
+                    # read device memory whose lifetime it doesn't own
+                    # (a step may donate the table).
+                    rows = self._copy_off_device(table.rows)
+            if packed is not None:
+                fill, keys, meta, transfers = self._pack_copy_out(*packed)
+                table_members = {"fill": fill, "keys": keys, "meta": meta}
+                capacity = fill.shape[0] * buckettable.SLOTS
+            else:
+                slots = (rows[:, : buckettable.SLOTS * 5].reshape(-1, 5)
+                         if bucket else rows)
+                table_members = {"keys": slots[:, :4], "meta": slots[:, 4]}
+                capacity, transfers = slots.shape[0], shards
+            sp.set(bytes=sum(int(a.nbytes) for a in table_members.values()),
+                   shards=shards, occupied=int(count.sum()),
+                   capacity=int(capacity), chunks=transfers)
+        incr_counter("ckpt", "base_unpacked", value=float(packed is None))
+        layout = "bucket" if bucket else "open"
         if shards > 1:
             # How evenly the key hash fills the shards, at every full
             # save: the emptiest and the fullest shard's occupied slots
@@ -2515,10 +2609,6 @@ class TpuAggregator:
             set_gauge("shard", "fill_min", value=float(count.min()))
             set_gauge("shard", "fill_max", value=float(count.max()))
             set_gauge("shard", "fill_mean", value=float(count.mean()))
-        if layout == "bucket":
-            slots = rows[:, : buckettable.SLOTS * 5].reshape(-1, 5)
-        else:
-            slots = rows
         extra = {}
         if self.filter_capture is not None:
             # Filter capture rides the checkpoint ONLY when the feature
@@ -2547,18 +2637,20 @@ class TpuAggregator:
                     [format(hashes.get((i, e), 0), "032x").encode()
                      for i, e, _ in f_items], dtype=object)
         with trace.span("ckpt.write", cat="ckpt") as sp:
-            np.savez_compressed(
-                fh,
-                # (keys, meta, count) stays the cross-version wire format;
-                # `layout` records slot positioning (bucket i//SLOTS vs
-                # open-addressed chains) and `n_shards` the key-routing
-                # topology, so restore rebuilds the same structure — or
-                # re-hashes via the reinsertion path (_restore_table /
-                # ShardedDedup.bulk_insert_np) when either differs.
+            ckpt.write_npz(fh, dict(
+                # `layout` records how the table's members map back to
+                # a table structure (bucket i//SLOTS vs open-addressed
+                # chains) and `n_shards` the key-routing topology; a
+                # `fill` member says the base is packed (keys / meta are
+                # the occupied slots in bucket order) and its absence
+                # that they are positional, one row a slot, as every
+                # writer before PR 42 wrote them. Restore rebuilds the
+                # same structure, or re-hashes via the reinsertion path
+                # (_restore_table / ShardedDedup.bulk_insert_np) when
+                # topology or layout differ.
                 layout=np.array(layout),
                 n_shards=np.int64(shards),
-                keys=slots[:, :4],
-                meta=slots[:, 4],
+                **table_members,
                 count=count,
                 registry=np.frombuffer(
                     self.registry.to_json().encode(), dtype=np.uint8
@@ -2588,9 +2680,8 @@ class TpuAggregator:
                     ).encode(),
                     dtype=np.uint8,
                 ),
-                allow_pickle=True,
                 **extra,
-            )
+            ), stored=table_members if packed is not None else ())
             fh.flush()
             os.fsync(fh.fileno())
             sp.set(bytes=fh.tell())
@@ -2602,41 +2693,59 @@ class TpuAggregator:
 
         return jnp.asarray(arr)
 
-    def _restore_table(self, keys, meta, count, layout: str,
-                       ckpt_shards: int) -> None:
-        """Rebuild table state from checkpoint (keys, meta) rows.
+    @staticmethod
+    def _occupied_rows(keys, meta, fill):
+        """``(keys, meta, capacity)`` for the reinsertion path: a packed
+        base's rows are the occupied ones already and its fills say the
+        slots it had; a positional base is scanned for them."""
+        if fill is not None:
+            return keys, meta, int(fill.shape[0]) * buckettable.SLOTS
+        occ = keys.any(axis=-1)
+        return keys[occ], meta[occ], int(keys.shape[0])
 
-        Positions written by a matching topology restore as a raw row
-        copy; a snapshot from a different shard count (its slot
+    def _restore_table(self, keys, meta, count, layout: str,
+                       ckpt_shards: int, fill=None) -> None:
+        """Rebuild table state from a base's table members.
+
+        With ``fill`` the base is packed: ``keys`` / ``meta`` are the
+        occupied slots in bucket order, and a matching topology
+        rebuilds the very rows they were packed from. Without, they
+        are positional (one row a slot, what every earlier writer
+        wrote) and a matching topology restores as a raw row copy.
+        Either way a snapshot from a different shard count (its slot
         positions encode dest * nb_local + local hash, unreachable by
         this topology's hashes) re-hashes every occupied row through
         the reinsertion path instead — silent positional trust would
         make contains/insert miss those keys and double-count."""
         if ckpt_shards != self._topology_shards():
-            occ = keys.any(axis=-1)
-            self.capacity = self._rebuild_table(
-                max(int(keys.shape[0]), 1))
-            overflow = self._bulk_reinsert(keys[occ], meta[occ])
+            keys, meta, ckpt_cap = self._occupied_rows(keys, meta, fill)
+            self.capacity = self._rebuild_table(max(ckpt_cap, 1))
+            overflow = self._bulk_reinsert(keys, meta)
             if overflow:
                 raise RuntimeError(
                     f"checkpoint restore overflowed {overflow} rows "
                     f"re-hashing a {ckpt_shards}-shard snapshot; "
                     f"increase tableBits (capacity {self.capacity})"
                 )
-            self._table_fill = int(occ.sum())
+            self._table_fill = int(keys.shape[0])
             return
         if layout == "bucket":
-            slots = hashtable.fuse_rows(keys, meta)
-            nb = slots.shape[0] // buckettable.SLOTS
-            rows = np.zeros((nb, buckettable.ROW_WORDS), np.uint32)
-            rows[:, : buckettable.SLOTS * 5] = slots.reshape(nb, -1)
-            # The device insert trusts the cached fill word; positional
-            # snapshots (and pre-round-5 ones especially) don't carry it.
-            buckettable.fill_counts_np(rows)
+            if fill is not None:
+                rows = buckettable.unpack_np(fill, keys, meta)
+            else:
+                slots = hashtable.fuse_rows(keys, meta)
+                rows = np.zeros((slots.shape[0] // buckettable.SLOTS,
+                                 buckettable.ROW_WORDS), np.uint32)
+                rows[:, : buckettable.SLOTS * 5] = slots.reshape(
+                    rows.shape[0], -1)
+                # The device insert trusts the cached fill word;
+                # positional snapshots (and pre-round-5 ones
+                # especially) don't carry it.
+                buckettable.fill_counts_np(rows)
             self.table = buckettable.BucketTable(
                 rows=self._asarray(rows), count=self._asarray(count),
             )
-            self.capacity = nb * buckettable.SLOTS
+            self.capacity = rows.shape[0] * buckettable.SLOTS
         else:
             self.table = hashtable.TableState(
                 rows=self._asarray(hashtable.fuse_rows(keys, meta)),
@@ -2662,8 +2771,8 @@ class TpuAggregator:
 
     def _load_base(self, path: str) -> None:
         z = np.load(path, allow_pickle=True)
-        # Checkpoint format stays (keys, meta, count) for cross-version
-        # stability; `layout` (absent in pre-round-4 snapshots ⇒ open)
+        # The table's members are (keys, meta, count) in every version;
+        # `layout` (absent in pre-round-4 snapshots ⇒ open)
         # says how slot positions map back to a table structure, and
         # `n_shards` (absent in pre-round-5 snapshots ⇒ 1) which
         # key-routing topology wrote them. The snapshot's layout wins
@@ -2671,9 +2780,12 @@ class TpuAggregator:
         # structure that wrote them.
         layout = str(z["layout"]) if "layout" in z else "open"
         ckpt_shards = int(z["n_shards"]) if "n_shards" in z else 1
+        # A `fill` member (PR 42 on) says keys / meta are the occupied
+        # slots in bucket order; a base without one is positional.
         self._restore_table(
             np.asarray(z["keys"]), np.asarray(z["meta"]),
             np.asarray(z["count"]), layout, ckpt_shards,
+            fill=np.asarray(z["fill"]) if "fill" in z else None,
         )
         self._device_written = bool(np.asarray(z["count"]).sum() > 0)
         self._table_fill = int(np.asarray(z["count"]).sum())

@@ -43,8 +43,11 @@ import os
 import signal
 import struct
 import tempfile
+import zipfile
 import zlib
 from typing import Any, NamedTuple, Optional
+
+import numpy as np
 
 from ct_mapreduce_tpu.config.profile import (
     Knob,
@@ -158,6 +161,25 @@ def file_sha256(path: str) -> str:
         for chunk in iter(lambda: fh.read(1 << 20), b""):
             h.update(chunk)
     return h.hexdigest()
+
+
+def write_npz(fh, members: dict, stored=()) -> None:
+    """The base's container: an ``.npz`` as ``np.load`` reads it (a zip
+    of one ``.npy`` a member, in the order of their names), deflated
+    member by member as ``np.savez_compressed`` writes it, except the
+    members named in ``stored``, which go in as they are: a packed
+    table's ``keys`` are SHA-256 output, which deflate cannot shrink
+    and is slowest on. Every entry carries the zip epoch for a
+    timestamp, so the same state writes the same bytes."""
+    with zipfile.ZipFile(fh, mode="w", compression=zipfile.ZIP_DEFLATED,
+                         allowZip64=True) as zf:
+        for name, arr in sorted(members.items()):
+            info = zipfile.ZipInfo(name + ".npy")
+            info.compress_type = (zipfile.ZIP_STORED if name in stored
+                                  else zipfile.ZIP_DEFLATED)
+            with zf.open(info, "w", force_zip64=True) as fid:
+                np.lib.format.write_array(fid, np.asanyarray(arr),
+                                          allow_pickle=True)
 
 
 def chain_token(prev_token: str, payload_sha: str) -> str:

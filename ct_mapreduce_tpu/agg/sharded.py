@@ -663,6 +663,40 @@ class ShardedDedup:
             overflowed += int(jnp.sum(ovf))
         return overflowed
 
+    def pack_programs(self):
+        """``(index, chunk)`` of ``buckettable``'s packing under
+        ``shard_map``: every shard packs its own row block on its own
+        chip (fills, search index and chunks all stay split along axis
+        0, shard i's part on chip i), and a chunk's first row is one
+        replicated scalar, the same for every shard."""
+        fns = self._step_cache.get("pack")
+        if fns is None:
+            A = P(self.axis)
+
+            def index_fn(rows):
+                # The index's depth follows the LOCAL row block, so its
+                # specs come from tracing it on one.
+                local = jax.ShapeDtypeStruct(
+                    (rows.shape[0] // self.n_shards, rows.shape[1]),
+                    rows.dtype)
+                specs = jax.tree.map(
+                    lambda _: A, jax.eval_shape(buckettable.pack_index, local))
+                return shard_map(buckettable.pack_index, mesh=self.mesh,
+                                 in_specs=A, out_specs=specs,
+                                 check_vma=False)(rows)
+
+            def chunk_fn(rows, index, start, chunk):
+                return shard_map(
+                    functools.partial(buckettable.pack_chunk, chunk=chunk),
+                    mesh=self.mesh,
+                    in_specs=(A, jax.tree.map(lambda _: A, index), P()),
+                    out_specs=A, check_vma=False)(rows, index, start)
+
+            fns = self._step_cache["pack"] = (
+                jax.jit(index_fn),
+                jax.jit(chunk_fn, static_argnames=("chunk",)))
+        return fns
+
     def total_count(self) -> int:
         return int(jnp.sum(self.count))
 

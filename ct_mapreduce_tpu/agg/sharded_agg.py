@@ -369,12 +369,12 @@ class ShardedAggregator(TpuAggregator):
 
     # -- checkpoint ------------------------------------------------------
     def _checkpoint_table(self):
-        # The row-sharded arrays as they live on the mesh: the writer's
-        # one copy-out reads each shard off its own chip, and no array
-        # of the table's size is made on a single device. The state
-        # type matches the dedup's layout so the codec writes the right
-        # positional keys/meta + layout + n_shards fields. Only full
-        # (ck01 / CTMRCK02 base) saves copy the table out: a delta
+        # The row-sharded arrays as they live on the mesh: the writer
+        # packs each shard on its own chip and reads it off that chip,
+        # and no array of the table's size is made on a single device.
+        # The state type matches the dedup's layout so the codec writes
+        # the right keys/meta + layout + n_shards fields. Only full
+        # (ck01 / CTMRCK02 base) saves read the table: a delta
         # segment's rows come from the fold-time dirty log.
         from ct_mapreduce_tpu.ops import buckettable, hashtable
 
@@ -383,16 +383,35 @@ class ShardedAggregator(TpuAggregator):
                      else hashtable.TableState)
         return state_cls(rows=self.dedup.rows, count=self.dedup.count)
 
+    def _pack_programs(self):
+        return self.dedup.pack_programs()
+
     def _restore_table(self, keys, meta, count, layout: str,
-                       ckpt_shards: int) -> None:
-        # Restore by REINSERTION, not raw row copy: a checkpoint may come
-        # from a different topology (single chip, another mesh size) or
-        # layout, and a key's home shard, bucket, and probe sequence all
-        # depend on both — only re-hashing every occupied row is always
-        # correct. (A same-topology fast path could raw-copy, but
-        # restores are rare and reinsertion keeps one code path.)
-        occ = keys.any(axis=-1)
-        ckpt_cap = int(keys.shape[0])
+                       ckpt_shards: int, fill=None) -> None:
+        # A packed base (``fill``) of this very topology — same layout,
+        # shard count and capacity — rebuilds the rows it was packed
+        # from and puts each shard's block on its chip. Anything else
+        # restores by REINSERTION, not raw row copy: a checkpoint may
+        # come from a different topology (single chip, another mesh
+        # size) or layout, and a key's home shard, bucket, and probe
+        # sequence all depend on both — only re-hashing every occupied
+        # row is always correct.
+        from ct_mapreduce_tpu.ops import buckettable
+
+        self.table = None
+        packed = fill is not None
+        keys, meta, ckpt_cap = self._occupied_rows(keys, meta, fill)
+        if (packed and layout == self.dedup.layout == "bucket"
+                and ckpt_shards == self.dedup.n_shards
+                and ckpt_cap == self.dedup.capacity):
+            import jax
+
+            self.dedup.rows = jax.device_put(
+                buckettable.unpack_np(fill, keys, meta),
+                self.dedup.batch_sharding)
+            self.dedup.count = jax.device_put(
+                np.asarray(count, np.int32), self.dedup.batch_sharding)
+            return
         target_cap = max(self.dedup.capacity, ckpt_cap)
         self.dedup = ShardedDedup(
             self.mesh,
@@ -401,14 +420,13 @@ class ShardedAggregator(TpuAggregator):
             max_probes=self.max_probes,
             dispatch_factor=self.dedup.dispatch_factor,
         )
-        overflow = self.dedup.bulk_insert_np(keys[occ], meta[occ])
+        overflow = self.dedup.bulk_insert_np(keys, meta)
         if overflow:
             raise RuntimeError(
                 f"checkpoint restore overflowed {overflow} rows; "
                 f"increase tableBits (capacity {self.dedup.capacity})"
             )
         self.capacity = self.dedup.capacity
-        self.table = None
 
     def _mesh_capacity(self, capacity: int) -> int:
         """Round capacity so each shard gets a power-of-two slice."""

@@ -583,3 +583,108 @@ def bulk_insert_np(rows_np: np.ndarray, keys: np.ndarray,
         h[order[~ok]] = (h[order[~ok]] + 1) & (nb - 1)
     rows_np[:, FILL_WORD] = fill.astype(np.uint32)
     return int(alive.sum())
+
+
+# -- packing for a full checkpoint ------------------------------------------
+#
+# A full base holds the occupied slots, and only they leave the device.
+# Slots fill contiguously, so a bucket's occupied slots are its first
+# ``fill``; the packed form is every bucket's fill beside the occupied
+# slots' five words in bucket order. The device produces it a CHUNK of
+# output rows at a time (one program, the chunk's first row traced):
+# the work and the bytes follow the occupied count, not the capacity.
+
+#: Output rows of one packed chunk (20 B a row: 5.2 MB a chunk).
+PACK_CHUNK = 1 << 18
+_PACK_RADIX = 128  # one tile-aligned row of the search index
+_PACK_TOP = 1024  # index entries compared densely, without a gather
+
+
+def pack_chunk_rows(n_buckets: int) -> int:
+    """Rows of a packed chunk for a table of ``n_buckets`` (a table
+    smaller than ``PACK_CHUNK`` is one chunk)."""
+    return min(PACK_CHUNK, n_buckets * SLOTS)
+
+
+def pack_index(rows: jax.Array):
+    """``(fill uint8[nb], index)`` of a table's rows, traceable.
+
+    ``fill`` is the cached ``FILL_WORD`` column: one strided read, no
+    temporary of the table's size (recounting occupancy from the key
+    words materialized 2 GB of lane-shifted flags under the v5e
+    compiler). A caller that must not trust the cache compares
+    ``index[-1][-1]``, the occupied count the fills add up to, with
+    the table's own ``count``. ``index`` is the search structure
+    :func:`pack_chunk` walks: the inclusive running sum of ``fill`` as
+    rows of 128, then every row's last entry again as rows of 128, and
+    so on down to at most 1,024 entries."""
+    fill = jnp.minimum(rows[:, FILL_WORD], SLOTS).astype(jnp.int32)
+    cur = jnp.cumsum(fill, dtype=jnp.int32)
+    index = []
+    while cur.shape[0] > _PACK_TOP:
+        level = cur.reshape(-1, _PACK_RADIX)
+        index.append(level)
+        cur = level[:, -1]
+    index.append(cur)
+    return fill.astype(jnp.uint8), tuple(index)
+
+
+def pack_chunk(rows: jax.Array, index, start: jax.Array, chunk: int):
+    """Occupied slots ``start .. start+chunk`` (in bucket order) as
+    ``uint32[5 * chunk]``: the chunk's first key words, then its
+    second, third and fourth, then its meta words (word-major and flat:
+    no small minor dimension, and no sublane padding of five rows to
+    eight, so a chunk is 20 B a row in HBM too). Rows past the occupied
+    count read 0.
+
+    Output row ``j`` lives in bucket ``b`` = how many buckets' running
+    sums are at most ``j``, found coarse to fine: a dense compare
+    against the top of ``index``, then one 128-entry row gather a
+    level; its slot is ``j`` less the largest running sum at most
+    ``j``. One gather of the bucket's row and five masked lane sums
+    pick the slot's words."""
+    nb = rows.shape[0]
+    j = start + jnp.arange(chunk, dtype=jnp.int32)
+    top = index[-1]
+    le = top[None, :] <= j[:, None]
+    g = jnp.sum(le, axis=1, dtype=jnp.int32)
+    base = jnp.max(jnp.where(le, top[None, :], 0), axis=1)
+    for level in reversed(index[:-1]):
+        row = level[jnp.minimum(g, level.shape[0] - 1)]  # [chunk, 128]
+        le = row <= j[:, None]
+        g = g * _PACK_RADIX + jnp.sum(le, axis=1, dtype=jnp.int32)
+        base = jnp.maximum(base, jnp.max(jnp.where(le, row, 0), axis=1))
+    live = j < top[-1]
+    bucket = rows[jnp.minimum(g, nb - 1)]  # [chunk, 128]
+    off = (jnp.arange(ROW_WORDS, dtype=jnp.int32)[None, :]
+           - ((j - base) * 5)[:, None])
+    words = [
+        jnp.where(live, jnp.sum(jnp.where(off == i, bucket, 0), axis=1,
+                                dtype=jnp.uint32), 0)
+        for i in range(5)]
+    return jnp.concatenate(words)
+
+
+pack_index_jit = jax.jit(pack_index)
+pack_chunk_jit = jax.jit(pack_chunk, static_argnames=("chunk",))
+
+
+def unpack_np(fill: np.ndarray, keys: np.ndarray,
+              meta: np.ndarray) -> np.ndarray:
+    """The rows a packed base was packed from, fill word included:
+    bucket ``b`` takes the next ``fill[b]`` of ``keys`` / ``meta`` into
+    its first slots."""
+    nb = fill.shape[0]
+    fill = fill.astype(np.int64)
+    if int(fill.sum()) != keys.shape[0] or keys.shape[0] != meta.shape[0]:
+        raise ValueError(
+            f"packed base: {keys.shape[0]} keys and {meta.shape[0]} meta "
+            f"words for fills that sum to {int(fill.sum())}")
+    rows = np.zeros((nb, ROW_WORDS), np.uint32)
+    bucket = np.repeat(np.arange(nb), fill)
+    slot = np.arange(keys.shape[0]) - np.repeat(np.cumsum(fill) - fill, fill)
+    slots = rows[:, : SLOTS * 5].reshape(nb, SLOTS, 5)
+    slots[bucket, slot, :4] = keys
+    slots[bucket, slot, 4] = meta
+    rows[:, FILL_WORD] = fill
+    return rows

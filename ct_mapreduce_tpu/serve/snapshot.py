@@ -367,9 +367,8 @@ def capture_view(agg, epoch: int, device: bool = False) -> TableView:
     :meth:`TableView.pin` waits for the copy off every lock. A host
     mirror (``device=False``; off the TPU, also a device view whose
     copy cannot be dispatched) reads the rows to host memory under the
-    table lock, the checkpoint writer's one-fetch idiom
-    (``_write_npz``): a single fetch of ``table.rows`` rather than
-    per-field property reads."""
+    table lock: a single fetch of ``table.rows`` rather than per-field
+    property reads."""
     t0 = time.time()
     rows = dev_rows = None
     with agg._fold_lock, trace.span("snapshot.locked", cat="serve"):

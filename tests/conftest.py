@@ -119,10 +119,16 @@ def shared_metrics_aside(monkeypatch, request):
     after the blocks may edit no file under ``benchmark/`` (ROADMAP
     R0). Their ``rehearse_cell`` hands over the line without the
     metrics listed from the first of several cells on, which land here
-    by name for the wrapper to judge."""
+    by name for the wrapper to judge. A cell whose own block stands
+    after them (``backfill-3log-shard4``'s, which its module names as
+    ``HOST_METRICS`` and ``DEVICE_METRICS``) keeps that block in the
+    line."""
     theirs = request.module.theirs
     listed = theirs.bench_json()["per_layer"]
-    shared = [m["name"] for m in listed[_first_shared_metric(listed):]]
+    own = {*getattr(theirs, "HOST_METRICS", ()),
+           *getattr(theirs, "DEVICE_METRICS", ())}
+    shared = [m["name"] for m in listed[_first_shared_metric(listed):]
+              if m["name"] not in own]
     aside: dict = {}
     rehearse = theirs.rehearse_cell
 

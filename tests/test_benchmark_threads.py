@@ -20,6 +20,12 @@ PAGES_WALKED = {
     "moves": "ingest_entries_per_s", "workloads": list(theirs.ALL_CELLS)}
 
 
+UNPACKED_SAVES = {
+    "name": "ckpt.unpacked_saves", "unit": "n", "better": "lower",
+    "source": "program_counter", "layer": "checkpoint", "moves": "setup_s",
+    "workloads": [*theirs.ALL_CELLS, "backfill-3log-shard4"]}
+
+
 @pytest.fixture(autouse=True)
 def listed_up_to_the_seven(monkeypatch):
     """Theirs hold PR 38's seven to the END of ``per_layer`` (``[-7:]``),
@@ -39,15 +45,23 @@ def test_the_seven_stand_at_the_end_of_the_list(  # noqa: F811
     """Theirs, and after the seven: ``decode.pages_walked`` (PR 39), one
     entry for all three cells, read by the reader of
     ``fold.meta_fallback_lanes``; then the four-chip cell's own block
-    (PR 40), which lists no other cell and is listed by none."""
+    (PR 40), which lists no other cell and was listed by none; then
+    ``ckpt.unpacked_saves`` (PR 42), the 85th and the first to list all
+    four cells, read by the same reader."""
     theirs.test_the_seven_stand_at_the_end_of_the_list()
     assert listed_up_to_the_seven[0] == PAGES_WALKED
     assert all(m["workloads"] == ["backfill-3log-shard4"]
                and m["name"].startswith("shard4.")
-               for m in listed_up_to_the_seven[1:])
+               for m in listed_up_to_the_seven[1:-1])
+    assert listed_up_to_the_seven[-1] == UNPACKED_SAVES
+    assert len(theirs.bench_json()["per_layer"]) \
+        + len(listed_up_to_the_seven) == 85
     assert theirs.layer_file("decode.pages_walked") == {
         "reader": "counter_sum",
         "params": {"key": "decode.pages_walked", "phase": "round"}}
+    assert theirs.layer_file("ckpt.unpacked_saves") == {
+        "reader": "counter_sum",
+        "params": {"key": "ckpt.base_unpacked", "phase": "round"}}
 
 
 @pytest.mark.parametrize("increments, want", [
@@ -70,6 +84,30 @@ def test_pages_walked_reads_the_rounds_increments(increments, want):
     else:
         assert absent == [] and metrics == {
             "decode.pages_walked": {"value": want, "unit": "pages"}}
+
+
+@pytest.mark.parametrize("increments, want", [
+    ([], "ABSENT"),  # the parent: a program that writes no packed base
+    ([0.0], 0.0),  # the round's one full save was packed
+    ([0.0, 1.0], 1.0)])  # one save copied the whole table out
+@pytest.mark.parametrize("cell", UNPACKED_SAVES["workloads"])
+def test_unpacked_saves_reads_the_rounds_increments(cell, increments, want):
+    """``ckpt.unpacked_saves`` in each of the four cells: the warm-up
+    round's save lies before ``t_open`` and is not counted; no
+    increment in the round leaves the metric out by name."""
+    out = {"t_open": 10.0, "t_durable": 20.0,
+           "counters": [(4.0, "ckpt.base_unpacked", 1.0)] * bool(increments)
+           + [(19.0 + k / 2, "ckpt.base_unpacked", v)
+              for k, v in enumerate(increments)]
+           + [(19.5, "ckpt.full_saves", 1.0)]}
+    metrics, absent = theirs.layers.read_metrics(
+        [UNPACKED_SAVES], cell, {"out": out})
+    if want == "ABSENT":
+        assert absent == ["ckpt.unpacked_saves"] and metrics == {}
+    else:
+        assert absent == [] and metrics == {
+            "ckpt.unpacked_saves": {"value": want, "unit": "n"}}
+
 
 pytestmark = [pytest.mark.timeout(300),
               pytest.mark.usefixtures("benchmark_checkout")]

@@ -300,17 +300,25 @@ def test_a_full_save_reads_the_shards_where_they_live(tmp_path, restore_into):
     assert {s.data.shape[0] for s in rows.addressable_shards} \
         == {rows.shape[0] // SHARDS}
     (d2h,) = spans("ckpt.d2h")
-    assert (d2h["args"]["bytes"], d2h["args"]["shards"]) \
-        == (int(rows.nbytes), SHARDS)
-    gauges = metrics.get_sink().snapshot()["gauges"]
     fills = [int(c) for c in np.asarray(count)]
+    # Since PR 42 a shard is packed on its own chip and only its
+    # occupied slots (20 B each) and a byte a bucket cross, one chunk a
+    # shard here; before, the span said the whole table's bytes.
+    assert d2h["args"] == {
+        "bytes": sum(fills) * 20 + rows.shape[0], "shards": SHARDS,
+        "occupied": sum(fills), "capacity": agg.dedup.capacity,
+        "chunks": SHARDS}
+    assert counters()["ckpt.base_unpacked"] == 0.0
+    gauges = metrics.get_sink().snapshot()["gauges"]
     assert (gauges["shard.fill_min"], gauges["shard.fill_max"]) \
         == (min(fills), max(fills))
     assert gauges["shard.fill_mean"] == pytest.approx(sum(fills) / SHARDS)
     with np.load(path, allow_pickle=True) as z:
         assert int(z["n_shards"]) == SHARDS
         assert list(z["count"]) == fills
-        assert z["keys"].shape[0] == agg.dedup.capacity
+        # A packed base: a fill a bucket, the occupied slots beside it.
+        assert z["fill"].shape[0] * buckettable.SLOTS == agg.dedup.capacity
+        assert z["keys"].shape[0] == z["meta"].shape[0] == sum(fills)
     if restore_into == "one-chip":
         cold = TpuAggregator(capacity=1 << 12, batch_size=BATCH, now=NOW)
     else:

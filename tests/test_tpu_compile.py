@@ -68,6 +68,61 @@ def test_contains_compiles_for_v5e_at_serve_width(one_chip):
     assert mem.temp_size_in_bytes < 1 << 30
 
 
+def test_a_full_saves_packing_compiles_for_v5e_without_a_table_sized_temporary(
+        one_chip, topo):
+    """The two programs a full save dispatches under the table lock
+    (PR 42), at the 2^26-slot table: the index reads the fill column
+    with no temporary at all (a recount from the key words asked the
+    v5e compiler for 2 GB of lane-shifted flags), a chunk's temporary
+    is its three 128-word gathers, and a chunk is 5 x 262,144 words, flat.
+    On the four-chip host the same two run a shard a chip."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from ct_mapreduce_tpu.ops import buckettable
+
+    def compiled(fn, *shapes):
+        return jax.jit(fn).lower(*shapes).compile()
+
+    def shaped(tree, sharding):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=sharding), tree)
+
+    rows = jax.ShapeDtypeStruct((1 << 22, 128), jnp.uint32, sharding=one_chip)
+    index_mem = compiled(buckettable.pack_index, rows).memory_analysis()
+    assert index_mem.temp_size_in_bytes < 64 << 20
+    _fill, index = jax.eval_shape(buckettable.pack_index, rows)
+    assert [a.shape for a in index] == [(32768, 128), (256, 128), (256,)]
+    chunk = buckettable.pack_chunk_rows(1 << 22)
+    assert chunk == 1 << 18
+    start = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    chunk_mem = compiled(
+        functools.partial(buckettable.pack_chunk, chunk=chunk),
+        rows, shaped(index, one_chip), start).memory_analysis()
+    assert chunk_mem.temp_size_in_bytes < 512 << 20
+    assert chunk_mem.output_size_in_bytes == 5 * chunk * 4  # 20 B a row
+
+    # shard:4, as ShardedDedup.pack_programs builds them.
+    from jax import shard_map
+
+    mesh = Mesh(np.array(topo.devices[:4]), ("shard",))
+    split = NamedSharding(mesh, P("shard"))
+    rows4 = jax.ShapeDtypeStruct((1 << 22, 128), jnp.uint32, sharding=split)
+    local = jax.ShapeDtypeStruct((1 << 20, 128), jnp.uint32)
+    specs = jax.tree.map(lambda _: P("shard"),
+                         jax.eval_shape(buckettable.pack_index, local))
+    mapped = shard_map(buckettable.pack_index, mesh=mesh,
+                       in_specs=P("shard"), out_specs=specs, check_vma=False)
+    on_four = compiled(mapped, rows4)
+    assert on_four.memory_analysis().temp_size_in_bytes < 64 << 20
+    text = on_four.as_text()
+    assert "all-gather" not in text and "all-reduce" not in text
+
+
 def _tpu_ini(tmp_path, log_url="https://ct.example.com/none"):
     ini = tmp_path / "ct.ini"
     ini.write_text(
