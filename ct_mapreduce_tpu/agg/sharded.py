@@ -106,90 +106,66 @@ def _shard_of(keys: jax.Array, n_shards: int) -> jax.Array:
     return (h % np.uint32(n_shards)).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("n_shards", "max_probes"))
-def _contains_global_bucket(
-    table_rows: jax.Array, keys: jax.Array,
-    n_shards: int, max_probes: int,
-) -> jax.Array:
-    """Membership over the globally-viewed bucket-sharded table:
-    shard-of-key addressing + the local bucket-hop probe of
-    ``buckettable.contains``, as one gather-only jit."""
-    nb_total = table_rows.shape[0]
-    nb_loc = nb_total // n_shards
-    b = keys.shape[0]
-    keys = buckettable._desentinel(keys.astype(jnp.uint32))
-    dest = _shard_of(keys, n_shards)
-    h0 = buckettable._home_bucket(keys, nb_loc)
-    S = buckettable.SLOTS
-
-    def cond(carry):
-        hops, _h, open_, _found = carry
-        return (hops < max_probes) & jnp.any(open_)
-
-    def round_body(carry):
-        hops, h, open_, found = carry
-        row = table_rows[dest * nb_loc + h]  # [B, 128]
-        match = jnp.zeros((b,), bool)
-        has_empty = jnp.zeros((b,), bool)
-        for s in range(S):
-            w = [row[:, s * 5 + i] for i in range(4)]
-            match = match | (
-                (w[0] == keys[:, 0]) & (w[1] == keys[:, 1])
-                & (w[2] == keys[:, 2]) & (w[3] == keys[:, 3]))
-            has_empty = has_empty | ((w[0] | w[1] | w[2] | w[3]) == 0)
-        found = found | (open_ & match)
-        open_ = open_ & ~match & ~has_empty
-        h = jnp.where(open_, (h + 1) & (nb_loc - 1), h)
-        return hops + 1, h, open_, found
-
-    _, _, _, found = jax.lax.while_loop(
-        cond, round_body,
-        (jnp.int32(0), h0, jnp.ones((b,), bool), jnp.zeros((b,), bool)))
-    return found
+def probe_width(lanes: int) -> int:
+    """The compiled width of a membership probe of ``lanes`` lanes: the
+    next power of two, 16 at least (the one-chip probe's rule)."""
+    return max(16, 1 << max(0, (lanes - 1).bit_length()))
 
 
-@functools.partial(jax.jit, static_argnames=("n_shards", "max_probes"))
-def _contains_global(
-    table_rows: jax.Array, keys: jax.Array,
-    n_shards: int, max_probes: int,
-) -> jax.Array:
-    """Membership over the globally-viewed sharded table: shard-of-key
-    addressing + the local triangular probe, as one gather-only jit (no
-    shard_map — XLA inserts any needed collectives for the gathers)."""
-    capacity = table_rows.shape[0]
-    cap_loc = capacity // n_shards
-    keys = hashtable._desentinel(keys.astype(jnp.uint32))
-    dest = _shard_of(keys, n_shards)
-    home = hashtable._home_slot(keys, cap_loc)
-    b = keys.shape[0]
-    W = min(hashtable.PROBE_WIDTH, max_probes)
+def route_to_shards(fps: np.ndarray, n_shards: int):
+    """A batch's fingerprints ``uint32[n, 4]`` laid out by home shard
+    as ``uint32[n_shards, width, 4]``, and where each lane went
+    (``dest``, ``pos``: lane ``i`` is ``[dest[i], pos[i]]``). ``width``
+    is :func:`probe_width` of the batch's lane count and of nothing
+    else, so it holds the batch whatever its fingerprints hash to (all
+    of them to one shard at worst): the shape a probe compiles for
+    follows from how many lanes were asked after, never from which."""
+    n = fps.shape[0]
+    dest = shard_of_np(fps, n_shards)
+    order = np.argsort(dest, kind="stable")
+    per_shard = np.bincount(dest, minlength=n_shards)
+    starts = np.cumsum(per_shard) - per_shard
+    pos = np.empty((n,), np.int64)
+    pos[order] = np.arange(n) - starts[dest[order]]
+    keys = np.zeros((n_shards, probe_width(n), 4), np.uint32)
+    keys[dest, pos] = fps
+    return keys, dest, pos
 
-    def cond(carry):
-        _r, _found, open_ = carry
-        return jnp.any(open_)
 
-    def round_body(carry):
-        r, found, open_ = carry
-        # Windowed early-exit scan (shared with hashtable.contains):
-        # typically ONE table gather instead of max_probes of them.
-        _slots, match_j, empty_j = hashtable._probe_window(
-            table_rows, keys, home, r, W, max_probes, cap_loc,
-            slot_base=dest * cap_loc,
-        )
-        found = found | (open_ & jnp.any(
-            match_j & (jnp.cumsum(empty_j, axis=-1) == 0), axis=-1
-        ))
-        still = open_ & ~jnp.any(match_j | empty_j, axis=-1)
-        r = jnp.where(still, r + W, r)
-        open_ = still & (r < max_probes)
-        return r, found, open_
+@functools.lru_cache(maxsize=None)
+def _shard_contains_program(mesh: Mesh, axis: str, layout: str,
+                            max_probes: int):
+    """The membership probe of a row-sharded table as ONE program over
+    the mesh: every shard probes its own row block with its slice of
+    the routed keys (the layout's own ``contains``; no collective).
+    Jitted once a mesh and layout, compiled once a width; its XLA
+    module is ``jit_shard_contains`` (docs/METRICS.md)."""
+    if layout == "bucket":
+        state_cls, contains = buckettable.BucketTable, buckettable.contains
+    else:
+        state_cls, contains = hashtable.TableState, hashtable.contains
 
-    _, found, _ = jax.lax.while_loop(
-        cond, round_body,
-        (jnp.zeros((b,), jnp.int32), jnp.zeros((b,), bool),
-         jnp.ones((b,), bool)),
-    )
-    return found
+    def local(block, keys):
+        state = state_cls(block, jnp.zeros((), jnp.int32))
+        return contains(state, keys[0], max_probes=max_probes)[None]
+
+    def shard_contains(rows, keys):
+        return shard_map(local, mesh=mesh, in_specs=(P(axis), P(axis)),
+                         out_specs=P(axis), check_vma=False)(rows, keys)
+
+    return jax.jit(shard_contains)
+
+
+def shard_contains(rows: jax.Array, keys: np.ndarray, layout: str,
+                   max_probes: int) -> np.ndarray:
+    """bool[n_shards, width]: routed ``keys`` (:func:`route_to_shards`)
+    probed against the row-sharded table ``rows``, shard ``i``'s block
+    with ``keys[i]`` on shard ``i``'s chip. One placement of the keys,
+    one dispatch, one readback, whatever shards the batch touches."""
+    split = rows.sharding  # rows and keys alike: block i on chip i
+    fn = _shard_contains_program(split.mesh, split.spec[0], layout,
+                                 max_probes)
+    return np.asarray(fn(rows, jax.device_put(keys, split)))
 
 
 def _dispatch(
@@ -704,18 +680,17 @@ class ShardedDedup:
         """Batched membership probe against the sharded table.
 
         Mirrors the sharded insert addressing exactly: home shard from
-        `_shard_of`, then the local triangular probe within that
-        shard's row block (each shard's `hashtable.insert` runs on its
-        local slice, so local capacity masks the slot). Used by the
-        host lane's cross-domain dedup guard."""
+        `_shard_of` (routed on the host), then the layout's local probe
+        within that shard's row block, every shard on its own chip
+        under ``shard_map`` (:func:`shard_contains`): no chip gathers
+        from another's rows. Used by the host lane's cross-domain dedup
+        guard; the caller holds the table lock."""
         if fps_np.size == 0:
             return np.zeros((0,), bool)
-        fn = (_contains_global_bucket if self.layout == "bucket"
-              else _contains_global)
-        return np.asarray(fn(
-            self.rows, jnp.asarray(fps_np.astype(np.uint32)),
-            n_shards=self.n_shards, max_probes=self.max_probes,
-        ))
+        keys, dest, pos = route_to_shards(
+            np.asarray(fps_np, np.uint32).reshape(-1, 4), self.n_shards)
+        return shard_contains(self.rows, keys, self.layout,
+                              self.max_probes)[dest, pos]
 
     def drain_np(self) -> tuple[np.ndarray, np.ndarray]:
         if self.layout == "bucket":

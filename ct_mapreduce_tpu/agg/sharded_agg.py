@@ -212,7 +212,10 @@ class ShardedAggregator(TpuAggregator):
         return self.dedup.drain_np()
 
     def _device_contains(self, fps: np.ndarray) -> np.ndarray:
-        return self.dedup.contains_np(fps)
+        # Under the table lock, as on one chip: the mesh step donates
+        # the rows, so a probe racing it would read a deleted array.
+        with self._table_lock:
+            return self.dedup.contains_np(fps)
 
     def _table_fill_exact(self) -> int:
         return self.dedup.total_count()

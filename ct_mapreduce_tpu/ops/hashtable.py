@@ -167,11 +167,9 @@ def _probe_window(
     W: int,
     max_probes: int,
     chain_capacity: int,
-    slot_base: jax.Array | int = 0,
 ):
     """One W-wide window of triangular-chain probes: the shared access
-    pattern of ``insert``, ``contains`` and the sharded membership scan
-    (``slot_base`` offsets into a shard's row block).
+    pattern of ``insert`` and ``contains``.
 
     ``table_rows`` is the fused uint32[capacity, 5] table (or any
     row array whose first 4 words are the key); matching and the
@@ -181,11 +179,7 @@ def _probe_window(
     positions past ``max_probes`` masked out of both match and empty.
     """
     rj = r[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]  # [B, W]
-    chain = (home[:, None] + (rj * (rj + 1)) // 2) & (chain_capacity - 1)
-    if isinstance(slot_base, int) and slot_base == 0:
-        slots = chain
-    else:
-        slots = slot_base[:, None] + chain
+    slots = (home[:, None] + (rj * (rj + 1)) // 2) & (chain_capacity - 1)
     in_budget = rj < max_probes
     cur = table_rows[slots][..., :4]  # [B, W, 4] key words of each row
     match_j = jnp.all(cur == keys[:, None, :], axis=-1) & in_budget

@@ -34,10 +34,12 @@ swaps to a new epoch at a time, captured and waited for on a
 background thread — so a capture (the fold/table locks, which contend
 with ingest) never stalls the serving path, and serving itself runs
 the jitted ``contains`` kernels on device copies instead of sharing a
-host core with ingest's numpy. On a mesh a replica serves from
-**per-shard row blocks**, the copy's own shards, each on its shard's
-device (queries route by ``shard_of_np`` exactly like ingest lanes);
-on one chip the pool holds N full copies. Mixed epochs across
+host core with ingest's numpy. On a mesh a replica is the row-sharded
+copy itself, shard ``i``'s block on shard ``i``'s chip: a batch's
+fingerprints are routed on the host by ``shard_of_np`` (the ingest
+routing hash) and probed by ONE program over the mesh, each shard
+against its own block (``agg.sharded.shard_contains``); on one chip the
+pool holds N full copies. Mixed epochs across
 replicas are safe by construction: every view is individually
 consistent, answers carry the serving view's epoch + age, and
 membership is monotone (a serial is never deleted), so an older
@@ -80,17 +82,14 @@ def snapshot_copy(rows):
     return jnp.copy(rows)
 
 
-def _shard_blocks(dev_rows, layout: str) -> list:
-    """A row-sharded copy's own shards as ready probe states, shard
-    ``i``'s contiguous row block on shard ``i``'s device (rows + count
-    on the SAME device, so the jitted kernel never crosses chips)."""
+def _one_chip_state(dev_rows, layout: str):
+    """A one-chip copy as a ready probe state (rows + count on the SAME
+    device)."""
     state_cls = (buckettable.BucketTable if layout == "bucket"
                  else hashtable.TableState)
-    shards = sorted(dev_rows.addressable_shards,
-                    key=lambda s: s.index[0].start or 0)
-    return [state_cls(s.data,
-                      jax.device_put(np.zeros((), np.int32), s.device))
-            for s in shards]
+    (shard,) = dev_rows.addressable_shards
+    return state_cls(shard.data,
+                     jax.device_put(np.zeros((), np.int32), shard.device))
 
 
 class TableView:
@@ -151,9 +150,11 @@ class TableView:
         self.created_wall = (time.time() if created_wall is None
                              else created_wall)
         self._dev_rows = dev_rows  # the device copy (device views)
-        # its shards as probe states, one a shard (device views)
-        self._dev_blocks = (None if dev_rows is None
-                            else _shard_blocks(dev_rows, layout))
+        # One chip: the copy as a probe state. A mesh probes the
+        # row-sharded copy as it is.
+        self._dev_state = (_one_chip_state(dev_rows, layout)
+                           if dev_rows is not None and n_shards == 1
+                           else None)
         self.replica_ix = None  # pool slot this view serves from
         # Entries folded into the aggregate when the capture held the
         # fold lock: what this view is known to include.
@@ -197,7 +198,7 @@ class TableView:
         self.rows = np.asarray(self._dev_rows)
         self.host_bytes += int(self.rows.nbytes)
         self._dev_rows = None
-        self._dev_blocks = None
+        self._dev_state = None
 
     # -- membership ------------------------------------------------------
     def contains_fps(self, fps: np.ndarray) -> np.ndarray:
@@ -257,18 +258,34 @@ class TableView:
 
     def _contains_device_pinned(self, fps: np.ndarray) -> np.ndarray:
         if self.n_shards == 1:
-            return self._probe_state(self._dev_blocks[0], fps)
-        # Shard-routed: home shard on host (the ingest routing hash),
-        # then the jitted single-table probe against that shard's
-        # block on that shard's device.
-        from ct_mapreduce_tpu.agg.sharded import shard_of_np
+            return self._probe_state(self._dev_state, fps)
+        # A batch goes to its shards once: routed on the host (the
+        # ingest routing hash) into [n_shards, width, 4], width from
+        # the lane count alone, then one program over the mesh and one
+        # readback, whatever shards the fingerprints hash to.
+        from ct_mapreduce_tpu.agg.sharded import (
+            route_to_shards,
+            shard_contains,
+        )
 
-        dest = shard_of_np(fps, self.n_shards)
-        out = np.zeros((fps.shape[0],), bool)
-        for s in np.unique(dest):
-            sel = dest == s
-            out[sel] = self._probe_state(self._dev_blocks[s], fps[sel])
-        return out
+        n = fps.shape[0]
+        keys, dest, pos = route_to_shards(fps, self.n_shards)
+        with trace.span("qshard.probe", cat="serve", lanes=n,
+                        shards=int(np.unique(dest).size),
+                        width=int(keys.shape[1]),
+                        replica=(-1 if self.replica_ix is None
+                                 else int(self.replica_ix))):
+            found = shard_contains(self._dev_rows, keys, self.layout,
+                                   self.max_probes)
+        self._count_probe(calls=1, lanes=n,
+                          padded=self.n_shards * keys.shape[1] - n)
+        return found[dest, pos]
+
+    @staticmethod
+    def _count_probe(calls: int, lanes: int, padded: int) -> None:
+        incr_counter("qshard", "device_calls", value=float(calls))
+        incr_counter("qshard", "lanes", value=float(lanes))
+        incr_counter("qshard", "padded_lanes", value=float(padded))
 
     def _probe_state(self, state, fps: np.ndarray) -> np.ndarray:
         """Jitted contains against one probe state, pow2-padded
@@ -302,6 +319,7 @@ class TableView:
         out = np.zeros((n,), bool)
         if n == 0:
             return out
+        on_mesh = self._device and self.n_shards > 1
         idx = np.fromiter((it[0] for it in items), np.int64, n)
         eh = np.fromiter((it[1] for it in items), np.int64, n)
         slen = np.fromiter((len(it[2]) for it in items), np.int64, n)
@@ -321,12 +339,20 @@ class TableView:
             fps = packing.fingerprints_np(
                 idx[sel], eh[sel], serials, slen[sel])
             out[sel] = self.contains_fps(fps)
+        elif on_mesh:
+            self._count_probe(calls=0, lanes=0, padded=0)
+        host_lane_hits = 0
         if self.host_serials:
             for p in range(n):
                 if not out[p]:
                     bucket = self.host_serials.get((int(idx[p]), int(eh[p])))
                     if bucket is not None and items[p][2] in bucket:
                         out[p] = True
+                        host_lane_hits += 1
+        if on_mesh:  # the qshard. family: every batch says all five
+            incr_counter("qshard", "batches")
+            incr_counter("qshard", "host_lane_hits",
+                         value=float(host_lane_hits))
         return out
 
     # -- metadata --------------------------------------------------------
@@ -444,8 +470,9 @@ class ReplicaPool:
     cache keys against.
 
     Placement: a replica's copy lives where the live table lives. On a
-    mesh-sharded aggregator it serves from one per-shard row block per
-    device, so no chip ever holds the full global rows; on one chip the
+    mesh-sharded aggregator it is row-sharded as the live table is, one
+    block a chip, so no chip ever holds the full global rows, and a
+    batch is probed by one program over the mesh; on one chip the
     pool holds N full copies beside the live table. ``device=False``
     makes every replica a host-numpy mirror (and, off the TPU, a copy
     that fails does the same per view, loudly, via
@@ -496,7 +523,7 @@ class ReplicaPool:
             with trace.span("snapshot.wait_copy", cat="serve"):
                 v.pin()  # wait on THIS thread, not the serving path
             cap.set(through_entries=v.through_entries,
-                    host_bytes=v.host_bytes)
+                    host_bytes=v.host_bytes, shards=v.n_shards)
         v.replica_ix = slot
         incr_counter("snapshot", "copies")
         incr_counter("snapshot", "host_bytes", value=float(v.host_bytes))

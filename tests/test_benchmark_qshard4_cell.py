@@ -1,0 +1,20 @@
+"""The tests of the cell ``backfill-3log-query-shard4``
+(``benchmark/tests/test_qshard4_cell.py``) as tier-1 tests; see
+``test_benchmark_harness.py``. Two of them are whole rehearsals of the
+committed cell at a tiny table, on a mesh of four of the CPU's virtual
+devices (the rehearsal's child asks for them itself: the fixture takes
+the suite's eight away for the one-chip cells' sake).
+"""
+
+import os
+import sys
+
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmark.tests import test_qshard4_cell as theirs  # noqa: E402,F401
+from benchmark.tests.test_qshard4_cell import *  # noqa: E402,F401,F403
+
+pytestmark = [pytest.mark.timeout(300),
+              pytest.mark.usefixtures("benchmark_checkout")]

@@ -17,6 +17,7 @@ from benchmark.tests import test_shard4_cell as theirs  # noqa: E402
 from benchmark.tests.test_shard4_cell import *  # noqa: E402,F401,F403
 
 UNPACKED_SAVES = "ckpt.unpacked_saves"  # PR 42: all four cells, this one too
+QUERY_CELL = "backfill-3log-query-shard4"  # PR 43: the second on four chips
 
 
 def test_every_metric_of_the_cell_names_a_reader_that_exists(  # noqa: F811
@@ -34,9 +35,30 @@ def test_every_metric_of_the_cell_names_a_reader_that_exists(  # noqa: F811
         theirs, "bench_json",
         lambda: dict(whole, per_layer=whole["per_layer"][:cut]))
     theirs.test_every_metric_of_the_cell_names_a_reader_that_exists()
-    assert names[cut:] == [UNPACKED_SAVES]
+    assert names[cut] == UNPACKED_SAVES
     assert theirs.CELL in whole["per_layer"][cut]["workloads"]
     assert len(whole["per_layer"][cut]["workloads"]) == 4
+    # Then the second four-chip cell's own block (PR 43), which lists
+    # neither this cell nor any other.
+    assert all(m["workloads"] == [QUERY_CELL] and m["name"].startswith(
+        "qshard4.") for m in whole["per_layer"][cut + 1:])
+
+
+def test_the_cell_is_the_control_on_a_mesh_and_nothing_else(  # noqa: F811
+        monkeypatch):
+    """Theirs, for a ``BENCHMARK.json`` with a second cell on four chips
+    (theirs counts one; PR 43 brought ``backfill-3log-query-shard4`` and
+    may edit no file under ``benchmark/``): theirs sees the cells as
+    they stood, and the two on four chips are half of the five, rounded
+    down."""
+    whole = theirs.bench_json()
+    assert [w["name"] for w in whole["workloads"]
+            if w["chips"] == theirs.CHIPS] == [theirs.CELL, QUERY_CELL]
+    assert len(whole["workloads"]) // 2 >= 2
+    monkeypatch.setattr(theirs, "bench_json", lambda: dict(
+        whole, workloads=[w for w in whole["workloads"]
+                          if w["name"] != QUERY_CELL]))
+    theirs.test_the_cell_is_the_control_on_a_mesh_and_nothing_else()
 
 
 def test_the_committed_cell_is_correct_and_every_host_metric_reads(  # noqa: F811

@@ -47,15 +47,19 @@ def test_the_seven_stand_at_the_end_of_the_list(  # noqa: F811
     ``fold.meta_fallback_lanes``; then the four-chip cell's own block
     (PR 40), which lists no other cell and was listed by none; then
     ``ckpt.unpacked_saves`` (PR 42), the 85th and the first to list all
-    four cells, read by the same reader."""
+    four cells, read by the same reader; then the second four-chip
+    cell's own eighteen (PR 43), which list it alone."""
     theirs.test_the_seven_stand_at_the_end_of_the_list()
     assert listed_up_to_the_seven[0] == PAGES_WALKED
     assert all(m["workloads"] == ["backfill-3log-shard4"]
                and m["name"].startswith("shard4.")
-               for m in listed_up_to_the_seven[1:-1])
-    assert listed_up_to_the_seven[-1] == UNPACKED_SAVES
+               for m in listed_up_to_the_seven[1:-19])
+    assert listed_up_to_the_seven[-19] == UNPACKED_SAVES
+    assert all(m["workloads"] == ["backfill-3log-query-shard4"]
+               and m["name"].startswith("qshard4.")
+               for m in listed_up_to_the_seven[-18:])
     assert len(theirs.bench_json()["per_layer"]) \
-        + len(listed_up_to_the_seven) == 85
+        + len(listed_up_to_the_seven) == 103
     assert theirs.layer_file("decode.pages_walked") == {
         "reader": "counter_sum",
         "params": {"key": "decode.pages_walked", "phase": "round"}}

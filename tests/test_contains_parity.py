@@ -140,7 +140,8 @@ def test_contains_parity_within_batch_duplicates():
 
 
 def test_contains_parity_sharded_view():
-    """The sharded global contains (device) vs the query plane's
+    """The sharded contains (device: routed on the host, every shard
+    probing its own block under ``shard_map``) vs the query plane's
     routed host probe (shard_of_np + per-block contains_np) on the
     same sharded rows."""
     import jax
@@ -173,9 +174,14 @@ def test_contains_parity_sharded_view():
         np.ones((len(kept),), bool),
         np.zeros((len(evicted) + len(absent),), bool),
     ])
-    dev = np.asarray(sharded._contains_global(
-        jnp.asarray(rows), jnp.asarray(probe),
-        n_shards=n_shards, max_probes=max_probes))
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    mesh = Mesh(np.array(jax.devices()), (sharded.AXIS,))
+    keys, dest_k, pos = sharded.route_to_shards(probe, n_shards)
+    assert keys.shape == (n_shards, sharded.probe_width(len(probe)), 4)
+    dev = sharded.shard_contains(
+        jax.device_put(rows, NamedSharding(mesh, PartitionSpec(sharded.AXIS))),
+        keys, "open", max_probes)[dest_k, pos]
     # The routed host probe, as the query plane's sharded view runs it.
     dest_p = sharded.shard_of_np(probe, n_shards)
     host = np.zeros((len(probe),), bool)

@@ -807,9 +807,10 @@ def test_replica_pool_mixed_epoch_parity_fuzz(template, device):
 
 
 def test_replica_pool_shard_routed_block_pinning(template):
-    """On a multi-device mesh the pool pins per-shard row blocks, each
-    on its shard's own device — never the full global rows on one chip
-    — and the shard-routed probe answers with exact parity."""
+    """On a multi-device mesh a replica is the row-sharded copy itself,
+    each shard's row block on its shard's own device — never the full
+    global rows on one chip — and the shard-routed probe answers with
+    exact parity."""
     import jax
     from jax.sharding import Mesh
 
@@ -826,13 +827,16 @@ def test_replica_pool_shard_routed_block_pinning(template):
     devs = jax.devices()
     for v in pool._replicas:
         assert v.n_shards == mesh.devices.size
-        assert v._dev_blocks is not None, "replica pinned no blocks"
+        assert v._dev_rows is not None, "replica holds no device copy"
+        assert v._dev_state is None  # the one-chip probe state
         assert v.rows is None, "a device replica made a host array"
         block = v.n_rows // v.n_shards
-        assert len(v._dev_blocks) == v.n_shards
-        for s, state in enumerate(v._dev_blocks):
-            assert state.rows.shape[0] == block
-            assert list(state.rows.devices()) == [devs[s % len(devs)]]
+        shards = sorted(v._dev_rows.addressable_shards,
+                        key=lambda s: s.index[0].start or 0)
+        assert len(shards) == v.n_shards
+        for s, shard in enumerate(shards):
+            assert shard.data.shape[0] == block
+            assert shard.device == devs[s % len(devs)]
     items = [(idx, eh, _serial_bytes(template, j)) for j in range(80)]
     for v in pool._replicas:
         got = v.lookup(items)
@@ -840,6 +844,211 @@ def test_replica_pool_shard_routed_block_pinning(template):
         # Device parity against the pure-host routed mirror.
         host = capture_view(agg, epoch=99).lookup(items)
         assert np.array_equal(got, host)
+
+
+# -- the query plane on a mesh of four: a batch goes to its shards once -----
+
+MESH_CHIPS = 4
+FED = 768  # serials through the device lane, on the mesh and on one chip
+HOST_LANE = range(5000, 5008)  # serials landed in the exact host lane
+WIDTHS = (16, 32, 64, 128, 256, 512, 1024, 2048, 4096)  # a cell's warmup_lanes
+
+
+def _mesh4():
+    import jax
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(jax.devices()[:MESH_CHIPS]), ("shard",))
+
+
+@pytest.fixture(scope="module")
+def fed_mesh_and_chip(template):
+    """The same serials through a ``ShardedAggregator`` on a mesh of
+    four and through a one-chip ``TpuAggregator``, a few of them into
+    the exact host lane; and the plain reference: a ``set`` of every
+    ``(issuer, expDate, serial)`` fed."""
+    from ct_mapreduce_tpu.agg.sharded_agg import ShardedAggregator
+
+    issuer_id, eh = _identity(template)
+    aggs = (ShardedAggregator(_mesh4(), capacity=1 << 13, batch_size=256),
+            TpuAggregator(capacity=1 << 13, batch_size=256))
+    fed = set()
+    for agg in aggs:
+        agg.ingest([(syncerts.stamp_serial(template, j), template.issuer_der)
+                    for j in range(FED)])
+        idx = agg.registry.get_or_assign(template.issuer_der)
+        for j in HOST_LANE:
+            fields = hostder.parse_cert(syncerts.stamp_serial(template, j))
+            agg._host_dedup(fields, idx, fields.not_after_unix_hour)
+    for j in [*range(FED), *HOST_LANE]:
+        fed.add((issuer_id, eh, _serial_bytes(template, j)))
+    assert aggs[0].dedup.n_shards == MESH_CHIPS
+    return aggs, fed
+
+
+def _mixed_batch(rng, lanes: int) -> list[int]:
+    """Serial numbers of a batch: fed ones, never-fed ones, host-lane
+    ones, drawn with replacement (so a large batch repeats many)."""
+    pools = (np.arange(FED), np.arange(10**6, 10**6 + 4 * FED),
+             np.array(HOST_LANE))
+    which = rng.choice(3, size=lanes, p=(0.6, 0.3, 0.1))
+    picks = [int(rng.choice(pools[w])) for w in which]
+    if lanes >= 2:
+        picks[-1] = picks[0]  # a duplicate in every batch that has room
+    return picks
+
+
+@pytest.mark.parametrize("lanes", [1, 2, 17, 4096])
+def test_sharded_device_view_equals_set_mirror_and_one_chip(
+        template, fed_mesh_and_chip, lanes):
+    """Membership has no tolerance: the sharded device view answers a
+    mixed batch (known, never fed, host-lane, duplicates) lane for lane
+    as the plain ``set``, as its own host mirror and as the one-chip
+    aggregator fed the same serials."""
+    (mesh_agg, chip_agg), fed = fed_mesh_and_chip
+    issuer_id, eh = _identity(template)
+    rng = np.random.default_rng([20261001, lanes])
+    serials = [_serial_bytes(template, j)
+               for j in _mixed_batch(rng, lanes)]
+    want = np.array([(issuer_id, eh, sb) in fed for sb in serials])
+    if lanes >= 17:
+        assert want.any() and not want.all()
+    answers = {}
+    for name, agg, device in (("mesh device view", mesh_agg, True),
+                              ("mesh host mirror", mesh_agg, False),
+                              ("one chip", chip_agg, True)):
+        idx = agg.registry.index_of_issuer_id(issuer_id)
+        view = capture_view(agg, epoch=1, device=device)
+        assert view._device is device
+        answers[name] = view.lookup([(idx, eh, sb) for sb in serials])
+    for name, got in answers.items():
+        assert np.array_equal(got, want), name
+
+
+def test_sharded_host_lane_guard_probes_each_shard_on_its_own_chip(
+        template, fed_mesh_and_chip):
+    """``ShardedAggregator._device_contains`` (the host lane's guard)
+    routes on the host and probes under ``shard_map``, under the table
+    lock: equal to the host mirror on fed and never-fed fingerprints."""
+    from ct_mapreduce_tpu.core import packing
+
+    (mesh_agg, _chip), _fed = fed_mesh_and_chip
+    _issuer_id, eh = _identity(template)
+    idx = mesh_agg.registry.get_or_assign(template.issuer_der)
+    fps = np.array([packing.fingerprint_host(idx, eh,
+                                             _serial_bytes(template, j))
+                    for j in [*range(0, FED, 7), *range(10**6, 10**6 + 50)]],
+                   np.uint32)
+    got = mesh_agg._device_contains(fps)
+    mirror = capture_view(mesh_agg, epoch=1, device=False)
+    assert np.array_equal(got, mirror._contains_host(fps))
+    assert got[: len(range(0, FED, 7))].all() and not got[-50:].any()
+    assert mesh_agg._device_contains(np.zeros((0, 4), np.uint32)).shape == (0,)
+
+
+def _fps_to_shard(rng, lanes: int, shard: int) -> np.ndarray:
+    """Random fingerprints that all hash to one shard of four."""
+    from ct_mapreduce_tpu.agg.sharded import shard_of_np
+
+    out = np.zeros((0, 4), np.uint32)
+    while out.shape[0] < lanes:
+        fps = rng.integers(1, 1 << 32, size=(8 * lanes, 4), dtype=np.uint32)
+        out = np.concatenate([out, fps[shard_of_np(fps, MESH_CHIPS) == shard]])
+    return out[:lanes]
+
+
+@pytest.mark.parametrize("seed", [11, 2147488905])
+def test_no_probe_compiles_after_warm_up_whatever_the_seed(
+        fed_mesh_and_chip, seed):
+    """The set of probe programs is fixed by the widths warmed, not by
+    the draw: after one batch a width (as a cell's generator sends
+    them) 200 seeded batches of 1-4,096 lanes, among them batches whose
+    every lane hashes to one shard, compile nothing."""
+    import jax.monitoring
+
+    (mesh_agg, _chip), _fed = fed_mesh_and_chip
+    compiled: list[str] = []
+    listening = [True]
+
+    def on_compile(event: str, _seconds: float, **_kw) -> None:
+        if listening[0] and event.endswith("backend_compile_duration"):
+            compiled.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        rng = np.random.default_rng(seed)
+        views = [capture_view(mesh_agg, epoch=e, device=True) for e in (1, 2)]
+        for width in WIDTHS:  # the warm-up: one view is enough
+            fps = rng.integers(1, 1 << 32, size=(width, 4), dtype=np.uint32)
+            assert not views[0].contains_fps(fps).any()
+        compiled.clear()
+        for k in range(200):
+            lanes = int(2 ** rng.uniform(0, 12))
+            if k % 25 == 0:
+                fps = _fps_to_shard(rng, lanes, shard=k // 25 % MESH_CHIPS)
+            else:
+                fps = rng.integers(1, 1 << 32, size=(lanes, 4),
+                                   dtype=np.uint32)
+            assert views[k % 2].contains_fps(fps).shape == (lanes,)
+        assert views[0].contains_fps(
+            _fps_to_shard(rng, 4096, shard=3)).shape == (4096,)
+        assert compiled == []
+    finally:
+        listening[0] = False
+
+
+def test_mesh_view_says_the_qshard_family_every_batch(
+        template, fed_mesh_and_chip):
+    """Span ``qshard.probe`` [lanes, shards, width, replica] round the
+    one dispatch, the five counters on every batch (a batch with no
+    device-eligible lane says them by 0), ``snapshot.capture`` says
+    ``shards``; a one-chip view says none of the family."""
+    from ct_mapreduce_tpu.telemetry import metrics as tmetrics
+    from ct_mapreduce_tpu.telemetry import trace
+
+    (mesh_agg, chip_agg), _fed = fed_mesh_and_chip
+    issuer_id, eh = _identity(template)
+    sink = tmetrics.InMemSink()
+    prev = tmetrics.get_sink()
+    tmetrics.set_sink(sink)
+    prev_tracer = trace._tracer
+    tracer = trace._tracer = trace.SpanTracer(ring_size=4096)
+    try:
+        pool = ReplicaPool(mesh_agg, n_replicas=1, max_staleness_s=1e9,
+                           device=True).warm()
+        view = pool.view()
+        idx = mesh_agg.registry.index_of_issuer_id(issuer_id)
+        known = [(idx, eh, _serial_bytes(template, j)) for j in range(20)]
+        host_lane = [(idx, eh, _serial_bytes(template, HOST_LANE[0]))]
+        assert view.lookup(known + host_lane).all()
+        counters = sink.snapshot()["counters"]
+        assert {k: v for k, v in counters.items()
+                if k.startswith("qshard.")} == {
+            "qshard.batches": 1.0, "qshard.device_calls": 1.0,
+            "qshard.lanes": 21.0,
+            "qshard.padded_lanes": MESH_CHIPS * 32 - 21.0,
+            "qshard.host_lane_hits": 1.0}
+        # No lane a device could hold: the batch says the family by 0.
+        assert not view.lookup([(idx, eh, b"\x01" * 64)]).any()
+        counters = sink.snapshot()["counters"]
+        assert counters["qshard.batches"] == 2.0
+        assert counters["qshard.device_calls"] == 1.0
+        spans = {e["name"]: e for e in tracer.events() if e.get("ph") == "X"}
+        probe = spans["qshard.probe"]["args"]
+        assert (probe["lanes"], probe["width"], probe["replica"]) == (21, 32, 0)
+        assert 1 <= probe["shards"] <= MESH_CHIPS
+        assert spans["snapshot.capture"]["args"]["shards"] == MESH_CHIPS
+        # One chip: its own program and span, nothing of the family.
+        before = dict(sink.snapshot()["counters"])
+        idx1 = chip_agg.registry.index_of_issuer_id(issuer_id)
+        assert capture_view(chip_agg, epoch=1, device=True).lookup(
+            [(idx1, eh, _serial_bytes(template, 3))]).all()
+        after = sink.snapshot()["counters"]
+        assert {k: v for k, v in after.items() if k.startswith("qshard.")} \
+            == {k: v for k, v in before.items() if k.startswith("qshard.")}
+    finally:
+        trace._tracer = prev_tracer
+        tmetrics.set_sink(prev)
 
 
 def _fail(msg):
@@ -1180,8 +1389,11 @@ def test_resolve_serve_layering(monkeypatch):
 
 
 def _traced_spans(tracer, t0):
+    """The program's spans since ``t0``; the GIL probe's own (a thread
+    ``trace.enable()`` starts, 100 a second) are not the test's."""
     return [e for e in tracer.events()
-            if e.get("ph") == "X" and e["ts"] >= t0]
+            if e.get("ph") == "X" and e["ts"] >= t0
+            and e["name"] != "gil.probe"]
 
 
 @pytest.mark.parametrize("device", [True, False],
@@ -1229,7 +1441,7 @@ def test_capture_emits_the_snapshot_family(template, device):
     want_bytes = 0 if device else table_bytes
     assert capture["args"] == {
         "epoch": 1, "replica": 0, "through_entries": 40,
-        "host_bytes": want_bytes}
+        "host_bytes": want_bytes, "shards": 1}
     assert view.replica_ix == 0 and view.through_entries == 40
     counters = sink.snapshot()["counters"]
     assert counters["snapshot.copies"] == 1

@@ -123,6 +123,33 @@ def test_a_full_saves_packing_compiles_for_v5e_without_a_table_sized_temporary(
     assert "all-gather" not in text and "all-reduce" not in text
 
 
+@pytest.mark.parametrize("width", [16, 4096])
+def test_the_mesh_probe_compiles_for_a_v5e_2x2_with_no_collective(topo, width):
+    """The query plane's probe of a table sharded over four chips at
+    2^26 slots a chip (``loglist3-serve-shard4``): one program over the
+    mesh a width, every shard's block an argument on its own chip and
+    never copied, nothing crossing chips."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from ct_mapreduce_tpu.agg import sharded
+
+    mesh = Mesh(np.array(topo.devices[:4]), (sharded.AXIS,))
+    split = NamedSharding(mesh, P(sharded.AXIS))
+    rows = jax.ShapeDtypeStruct((4 << 22, 128), jnp.uint32, sharding=split)
+    keys = jax.ShapeDtypeStruct((4, width, 4), jnp.uint32, sharding=split)
+    fn = sharded._shard_contains_program(mesh, sharded.AXIS, "bucket", 32)
+    compiled = fn.lower(rows, keys).compile()
+    mem = compiled.memory_analysis()  # a chip's
+    assert mem.argument_size_in_bytes >= (1 << 22) * 512
+    assert mem.temp_size_in_bytes < 64 << 20
+    text = compiled.as_text()
+    assert not any(op in text for op in (
+        "all-gather", "all-reduce", "all-to-all", "collective-permute"))
+    assert jax.eval_shape(fn, rows, keys).shape == (4, width)
+
+
 def _tpu_ini(tmp_path, log_url="https://ct.example.com/none"):
     ini = tmp_path / "ct.ini"
     ini.write_text(
