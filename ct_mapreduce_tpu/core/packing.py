@@ -27,6 +27,9 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from ct_mapreduce_tpu import native
+from ct_mapreduce_tpu.telemetry.metrics import incr_counter
+
 MAX_SERIAL_BYTES = 46  # fits a single SHA-256 block with the prefix
 FP_MSG_BYTES = 9 + MAX_SERIAL_BYTES  # ≤ 55 ⇒ single block after padding
 
@@ -172,18 +175,47 @@ def fingerprints_np(
     serials: np.ndarray,
     serial_len: np.ndarray,
 ) -> np.ndarray:
-    """Vectorized host mirror of the device fingerprint pipeline
+    """Host mirror of the device fingerprint pipeline
     (:func:`ct_mapreduce_tpu.ops.pipeline.fingerprints` →
     ``sha256_fingerprint64``): ``uint32[n, 4]`` dedup-key words from
     the sidecar's compact per-lane fields, no device round trip.
 
-    The sharded pre-parsed lane uses this to compute every lane's home
-    shard ON THE HOST (routing is a pure function of the fingerprint),
-    so sidecars partition per shard before H2D and no ``all_to_all``
-    runs on device. Bytes of ``serials`` past ``serial_len`` must
-    already be zero (the sidecar serial window guarantees it), exactly
-    as the device path assumes.
+    The query plane keys every batch of lookups with it; the sharded
+    pre-parsed lane uses it to compute every lane's home shard ON THE
+    HOST (routing is a pure function of the fingerprint), so sidecars
+    partition per shard before H2D and no ``all_to_all`` runs on
+    device. Bytes of ``serials`` past ``serial_len`` must already be
+    zero (the sidecar serial window guarantees it), exactly as the
+    device path assumes.
+
+    One native call with the GIL released (``ctmr_fingerprints``), at
+    every ``n``; :func:`_fingerprints_numpy` where the library cannot
+    answer. What decides is in the input; there is no setting. Every
+    call says how many lanes it had and how many of them took the NumPy
+    routine (counters ``fp.lanes`` / ``fp.fallback_lanes``, 0 included).
     """
+    n = int(len(issuer_idx))
+    fps = native.fingerprints(issuer_idx, exp_hour, serials, serial_len)
+    fallback = 0
+    if fps is None:
+        fps = _fingerprints_numpy(issuer_idx, exp_hour, serials, serial_len)
+        fallback = n
+    incr_counter("fp", "lanes", value=float(n))
+    incr_counter("fp", "fallback_lanes", value=float(fallback))
+    return fps
+
+
+def _fingerprints_numpy(
+    issuer_idx: np.ndarray,
+    exp_hour: np.ndarray,
+    serials: np.ndarray,
+    serial_len: np.ndarray,
+) -> np.ndarray:
+    """:func:`fingerprints_np` as SHA-256 written in vectorised NumPy:
+    some 4,000 array operations whatever ``n`` (4 ms of interpreter for
+    one lane, 2 us a lane at 4,096), all under the GIL. The routine for
+    a host whose native library is missing or older than
+    ``ctmr_fingerprints``, and the oracle of its tests."""
     n = int(len(issuer_idx))
     if n == 0:
         return np.zeros((0, 4), np.uint32)

@@ -24,6 +24,9 @@ _SRC = os.path.join(_HERE, "ctmr_native.cpp")
 _LOCK = threading.Lock()
 _LIB: Optional[ctypes.CDLL] = None
 _LOAD_FAILED = False
+# core.packing.MAX_SERIAL_BYTES, which ctmr_fingerprints has compiled
+# in (core/ imports this package, not the other way round).
+FP_WINDOW_BYTES = 46
 
 
 def _so_path() -> str:
@@ -269,6 +272,21 @@ def load() -> Optional[ctypes.CDLL]:
             lib.has_uniq = True
         except AttributeError:
             lib.has_uniq = False
+        # The dedup key's host fingerprint (PR 44): one SHA-256 block a
+        # lane over four plain columns, no Python object, so it stays on
+        # this handle. Same stale-library contract: `fingerprints`
+        # checks `has_fp` and `core.packing.fingerprints_np` keeps the
+        # NumPy routine.
+        try:
+            lib.ctmr_fingerprints.restype = ctypes.c_int64
+            lib.ctmr_fingerprints.argtypes = [
+                ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p,
+                ctypes.c_void_p, ctypes.c_int64,
+                ctypes.c_void_p, ctypes.c_void_p,
+            ]
+            lib.has_fp = True
+        except AttributeError:
+            lib.has_fp = False
         # Return stamps and the GIL probe's sleep (PR 38). The stamps
         # are read with the GIL held (the PyDLL handle, as the gather
         # is): a CDLL call would hand the GIL round once more. Same
@@ -360,3 +378,36 @@ def unique_windows(rows2d, row_sel, issuers, off, ln):
     # Copies, so that a few hundred indices do not keep two lane-sized
     # buffers alive.
     return first[:count].copy(), rest[: n_rest.value].copy()
+
+
+def fingerprints(issuer_idx, exp_hour, serials, serial_len):
+    """``uint32[n, 4]``: words 4..7 of the SHA-256 of every lane's
+    fingerprint message (``ctmr_fingerprints``; the layout is
+    ``core.packing``'s). The columns are converted as the NumPy routine
+    it answers for converts them (issuer and hour wrap to ``uint32``,
+    the length to ``int64``, the serial windows to ``uint8``), so any
+    dtype or a list will do. None where the library is unavailable or
+    the input is not what it reads: columns that are not ``n`` long, a
+    window that is not ``core.packing.MAX_SERIAL_BYTES`` wide, a length
+    outside the window."""
+    lib = load()
+    if lib is None or not getattr(lib, "has_fp", False):
+        return None
+    # astype copies: a column comes out dense whatever view came in.
+    ii, eh = (np.asarray(a).astype(np.uint32)
+              for a in (issuer_idx, exp_hour))
+    slen = np.asarray(serial_len).astype(np.int64)
+    win = np.asarray(serials, np.uint8)
+    n = int(ii.shape[0]) if ii.ndim == 1 else -1
+    if (eh.shape != (n,) or slen.shape != (n,)
+            or win.shape != (n, FP_WINDOW_BYTES)):
+        return None
+    if win.strides[1] != 1:  # a row's bytes lie side by side
+        win = np.ascontiguousarray(win)
+    out = np.empty((n, 4), np.uint32)
+    done = lib.ctmr_fingerprints(
+        n, ii.ctypes.data, eh.ctypes.data, win.ctypes.data,
+        win.strides[0], slen.ctypes.data, out.ctypes.data)
+    if trace.enabled():
+        note_return(lib)
+    return out if done == n else None

@@ -1890,6 +1890,48 @@ int64_t ctmr_unique_windows(
 
 extern "C" {
 
+// The dedup key of lane i of [0, n), as core/packing.py states it and
+// ops/pipeline.py::fingerprints computes it on the device: SHA-256 of
+// expHour(4B BE) | issuerIdx(4B BE) | serialLen(1B) | the serial, words
+// 4..7 of the digest into out[4 * i, + 4). `serials` is n windows of
+// 46 bytes (packing.MAX_SERIAL_BYTES), serial_stride bytes apart; the
+// whole window goes into the block, as in the NumPy routine this
+// answers for (bytes past the serial are the caller's zeros), then the
+// FIPS padding: 0x80 at 9 + len, the bit count in the last two bytes.
+// 9 + 46 = 55 bytes: always one block. -1, and nothing written, where a
+// length lies outside [0, 46]: the caller's own routine says what that
+// means. Touches no Python object: loaded on the GIL-releasing handle.
+int64_t ctmr_fingerprints(
+    int64_t n, const uint32_t* issuer_idx, const uint32_t* exp_hour,
+    const uint8_t* serials, int64_t serial_stride,
+    const int64_t* serial_len, uint32_t* out) {
+  stamp::Scope stamped;
+  const int64_t kWindow = 46;
+  for (int64_t i = 0; i < n; ++i)
+    if (serial_len[i] < 0 || serial_len[i] > kWindow) return -1;
+  for (int64_t i = 0; i < n; ++i) {
+    uint8_t blk[64] = {0};
+    for (int j = 0; j < 4; ++j) {
+      blk[j] = (uint8_t)(exp_hour[i] >> (24 - 8 * j));
+      blk[4 + j] = (uint8_t)(issuer_idx[i] >> (24 - 8 * j));
+    }
+    blk[8] = (uint8_t)serial_len[i];
+    std::memcpy(blk + 9, serials + i * serial_stride, (size_t)kWindow);
+    const int64_t msg_len = 9 + serial_len[i];
+    blk[msg_len] = 0x80;
+    blk[62] = (uint8_t)((msg_len * 8) >> 8);
+    blk[63] = (uint8_t)(msg_len * 8);
+    sctext::Sha256 sha;
+    sha.block(blk);
+    for (int j = 0; j < 4; ++j) out[4 * i + j] = sha.h[4 + j];
+  }
+  return n;
+}
+
+}  // extern "C"
+
+extern "C" {
+
 // The calling thread's last stamped call: out[0] when it entered the
 // library, out[1] when it returned. Loaded through ctypes.PyDLL like
 // ctmr_gather_strs, so reading them gives the GIL to nobody.

@@ -39,9 +39,12 @@ def test_every_metric_of_the_cell_names_a_reader_that_exists(  # noqa: F811
     assert theirs.CELL in whole["per_layer"][cut]["workloads"]
     assert len(whole["per_layer"][cut]["workloads"]) == 4
     # Then the second four-chip cell's own block (PR 43), which lists
-    # neither this cell nor any other.
+    # neither this cell nor any other; then the query cells' one (PR
+    # 44), which does not list this cell.
     assert all(m["workloads"] == [QUERY_CELL] and m["name"].startswith(
-        "qshard4.") for m in whole["per_layer"][cut + 1:])
+        "qshard4.") for m in whole["per_layer"][cut + 1:-1])
+    assert whole["per_layer"][-1]["name"] == "fp.fallback_lanes"
+    assert theirs.CELL not in whole["per_layer"][-1]["workloads"]
 
 
 def test_the_cell_is_the_control_on_a_mesh_and_nothing_else(  # noqa: F811

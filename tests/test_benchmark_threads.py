@@ -26,6 +26,13 @@ UNPACKED_SAVES = {
     "workloads": [*theirs.ALL_CELLS, "backfill-3log-shard4"]}
 
 
+FP_FALLBACK = {
+    "name": "fp.fallback_lanes", "unit": "n", "better": "lower",
+    "source": "program_counter", "layer": "query plane",
+    "moves": "ingest_entries_per_s",
+    "workloads": ["backfill-1log-query", "backfill-3log-query-shard4"]}
+
+
 @pytest.fixture(autouse=True)
 def listed_up_to_the_seven(monkeypatch):
     """Theirs hold PR 38's seven to the END of ``per_layer`` (``[-7:]``),
@@ -48,24 +55,30 @@ def test_the_seven_stand_at_the_end_of_the_list(  # noqa: F811
     (PR 40), which lists no other cell and was listed by none; then
     ``ckpt.unpacked_saves`` (PR 42), the 85th and the first to list all
     four cells, read by the same reader; then the second four-chip
-    cell's own eighteen (PR 43), which list it alone."""
+    cell's own eighteen (PR 43), which list it alone; then
+    ``fp.fallback_lanes`` (PR 44), the 104th, which lists the two query
+    cells and is read by the same reader again."""
     theirs.test_the_seven_stand_at_the_end_of_the_list()
     assert listed_up_to_the_seven[0] == PAGES_WALKED
     assert all(m["workloads"] == ["backfill-3log-shard4"]
                and m["name"].startswith("shard4.")
-               for m in listed_up_to_the_seven[1:-19])
-    assert listed_up_to_the_seven[-19] == UNPACKED_SAVES
+               for m in listed_up_to_the_seven[1:-20])
+    assert listed_up_to_the_seven[-20] == UNPACKED_SAVES
     assert all(m["workloads"] == ["backfill-3log-query-shard4"]
                and m["name"].startswith("qshard4.")
-               for m in listed_up_to_the_seven[-18:])
+               for m in listed_up_to_the_seven[-19:-1])
+    assert listed_up_to_the_seven[-1] == FP_FALLBACK
     assert len(theirs.bench_json()["per_layer"]) \
-        + len(listed_up_to_the_seven) == 103
+        + len(listed_up_to_the_seven) == 104
     assert theirs.layer_file("decode.pages_walked") == {
         "reader": "counter_sum",
         "params": {"key": "decode.pages_walked", "phase": "round"}}
     assert theirs.layer_file("ckpt.unpacked_saves") == {
         "reader": "counter_sum",
         "params": {"key": "ckpt.base_unpacked", "phase": "round"}}
+    assert theirs.layer_file("fp.fallback_lanes") == {
+        "reader": "counter_sum",
+        "params": {"key": "fp.fallback_lanes", "phase": "round"}}
 
 
 @pytest.mark.parametrize("increments, want", [
@@ -111,6 +124,33 @@ def test_unpacked_saves_reads_the_rounds_increments(cell, increments, want):
     else:
         assert absent == [] and metrics == {
             "ckpt.unpacked_saves": {"value": want, "unit": "n"}}
+
+
+@pytest.mark.parametrize("increments, want", [
+    ([], "ABSENT"),  # the parent: its fingerprint says nothing
+    ([0.0, 0.0, 0.0], 0.0),  # every batch of lookups took the native call
+    ([0.0, 2.0, 1.0], 3.0)])  # two batches of 2 and 1 lanes took NumPy's
+@pytest.mark.parametrize("cell", FP_FALLBACK["workloads"])
+def test_fp_fallback_lanes_reads_the_rounds_increments(cell, increments, want):
+    """``fp.fallback_lanes`` in each of the two query cells, on a
+    recorded ring: the generators' warm-up lookups lie before ``t_open``
+    and are not counted; no increment in the round, not one of 0,
+    leaves the metric out by name. The cells without queries do not
+    list it."""
+    out = {"t_open": 10.0, "t_durable": 20.0,
+           "counters": [(4.0, "fp.fallback_lanes", 16.0)] * bool(increments)
+           + [(11.0 + k, "fp.fallback_lanes", v)
+              for k, v in enumerate(increments)]
+           + [(11.5, "fp.lanes", 2.0)]}
+    metrics, absent = theirs.layers.read_metrics(
+        [FP_FALLBACK], cell, {"out": out})
+    if want == "ABSENT":
+        assert absent == ["fp.fallback_lanes"] and metrics == {}
+    else:
+        assert absent == [] and metrics == {
+            "fp.fallback_lanes": {"value": want, "unit": "n"}}
+    assert theirs.layers.read_metrics(
+        [FP_FALLBACK], "backfill-3log", {"out": out}) == ({}, [])
 
 
 pytestmark = [pytest.mark.timeout(300),

@@ -1051,6 +1051,69 @@ def test_mesh_view_says_the_qshard_family_every_batch(
         tmetrics.set_sink(prev)
 
 
+@pytest.mark.parametrize("where", ["one chip", "mesh of four"])
+def test_lookup_answers_the_same_with_the_native_fingerprint_and_without(
+        template, fed_mesh_and_chip, monkeypatch, where):
+    """``TableView.lookup`` keys its eligible lanes with
+    ``fingerprints_np``: one native call (PR 44), or the NumPy routine
+    where the library is older than the symbol. Known, never-fed,
+    host-lane, oversize-serial and unknown-issuer items answer the same
+    either way and as the plain ``set``; ``fp.lanes`` counts the
+    eligible lanes and ``fp.fallback_lanes`` says which routine keyed
+    them; on the mesh the ``qshard.`` family still says all five every
+    batch."""
+    from ct_mapreduce_tpu import native
+    from ct_mapreduce_tpu.core import packing
+    from ct_mapreduce_tpu.telemetry import metrics as tmetrics
+
+    if not getattr(native.load(), "has_fp", False):
+        pytest.skip("native library unavailable")
+    (mesh_agg, chip_agg), fed = fed_mesh_and_chip
+    agg = chip_agg if where == "one chip" else mesh_agg
+    issuer_id, eh = _identity(template)
+    idx = agg.registry.index_of_issuer_id(issuer_id)
+    known = [(idx, eh, _serial_bytes(template, j)) for j in (0, 5, FED - 1)]
+    never = [(idx, eh, _serial_bytes(template, 10**6 + j)) for j in range(2)]
+    host_lane = [(idx, eh, _serial_bytes(template, HOST_LANE[0]))]
+    oversize = [(idx, eh, b"\x01" * (packing.MAX_SERIAL_BYTES + 1))]
+    stranger = [(-1, eh, _serial_bytes(template, 0))]
+    items = known + never + host_lane + oversize + stranger
+    want = np.array([(issuer_id, eh, sb) in fed and i >= 0
+                     for i, _eh, sb in items])
+    assert want.tolist() == [True] * 3 + [False] * 2 + [True] + [False] * 2
+    eligible = len(known + never + host_lane)
+    view = capture_view(agg, epoch=1, device=True)
+    prev = tmetrics.get_sink()
+    said = {}
+    try:
+        for how in ("native", "numpy"):
+            if how == "numpy":
+                monkeypatch.setattr(native.load(), "has_fp", False)
+            sink = tmetrics.InMemSink()
+            tmetrics.set_sink(sink)
+            assert np.array_equal(view.lookup(items), want), how
+            # No lane a device could hold: no fingerprint, no call.
+            assert not view.lookup(oversize + stranger).any()
+            said[how] = sink.snapshot()["counters"]
+    finally:
+        tmetrics.set_sink(prev)
+    assert said["native"]["fp.lanes"] == said["numpy"]["fp.lanes"] == eligible
+    assert said["native"]["fp.fallback_lanes"] == 0.0
+    assert said["numpy"]["fp.fallback_lanes"] == eligible
+    family = {k: v for k, v in said["native"].items()
+              if k.startswith("qshard.")}
+    assert family == {k: v for k, v in said["numpy"].items()
+                      if k.startswith("qshard.")}
+    if where == "one chip":
+        assert family == {}
+    else:
+        assert family == {
+            "qshard.batches": 2.0, "qshard.device_calls": 1.0,
+            "qshard.lanes": float(eligible),
+            "qshard.padded_lanes": MESH_CHIPS * 16.0 - eligible,
+            "qshard.host_lane_hits": 1.0}
+
+
 def _fail(msg):
     def boom(*_a, **_k):
         raise RuntimeError(msg)
