@@ -2,7 +2,8 @@
 every documented span name has a call site.
 
 The round-23 generalization of the ``metric-registry`` rule to the
-tracer surface: collect every ``span(...)``/``instant(...)`` call site
+tracer surface: collect every ``span(...)``/``instant(...)``/
+``record_span(...)`` call site
 in the package (literal first argument becomes the name, a dynamic one
 becomes ``*``) and diff against the backtick-quoted bullets of the
 ``## Trace spans`` sections in ``docs/METRICS.md`` — the same file,
@@ -24,7 +25,7 @@ from ct_mapreduce_tpu.analysis.metric_registry import (
     key_matches,
 )
 
-EMIT_FUNCS = {"span", "instant"}
+EMIT_FUNCS = {"span", "instant", "record_span"}
 # The tracer API itself: the names it passes through are its callers',
 # not call sites. A literal there is a span the tracer records itself
 # (the GIL probe's).

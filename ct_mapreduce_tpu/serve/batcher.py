@@ -10,11 +10,14 @@ admission control so overload sheds loudly instead of queueing without
 bound.
 
 :class:`MicroBatcher` is that loop, oracle-agnostic: callers
-``submit()`` lists of opaque items and block; one worker thread
+``admit()`` lists of opaque items with a completion callback (the query
+front's one thread: it never waits here, the worker hands the answer
+back to its loop) or ``submit()`` them and block (``admit`` plus a
+wait, for every caller with a thread of its own); one worker thread
 collects whatever is queued — releasing a batch as soon as
 ``max_batch`` lanes are waiting or ``max_delay_s`` has passed since
 the OLDEST queued request — runs ``run_batch`` over the concatenation,
-and scatters results back. Guarantees:
+and scatters results back. Guarantees, whichever way a request came in:
 
 - **Bounded wait.** A request waits at most ``max_delay_s`` for its
   batch to form, plus at most one in-flight batch execution before its
@@ -51,25 +54,51 @@ class DeadlineExceeded(RuntimeError):
     """The request's deadline passed before its batch executed."""
 
 
+class _Request:
+    """One admission: its parts in the queue (one, or an oversized
+    bulk's sub-requests) and whom to tell once the last is done."""
+
+    __slots__ = ("parts", "remaining", "on_done", "enq_t")
+
+    def __init__(self, on_done: Callable[[], None], enq_t: float) -> None:
+        self.parts: list[_Pending] = []
+        self.remaining = 0
+        self.on_done = on_done
+        self.enq_t = enq_t
+
+    def results(self) -> list:
+        """The answers in admission order, or the first part's error
+        raised. Only after ``on_done`` was called."""
+        err = next((p.error for p in self.parts if p.error is not None),
+                   None)
+        if err is not None:
+            raise err
+        if len(self.parts) == 1:
+            return self.parts[0].result
+        return [r for p in self.parts for r in p.result]
+
+
 class _Pending:
-    __slots__ = ("items", "deadline", "enq_t", "done", "result", "error",
+    __slots__ = ("items", "deadline", "request", "result", "error",
                  "trace_ctx")
 
     def __init__(self, items: list, deadline: Optional[float],
-                 enq_t: float) -> None:
+                 request: _Request) -> None:
         self.items = items
         self.deadline = deadline
-        self.enq_t = enq_t
-        self.done = threading.Event()
+        self.request = request
         self.result: Optional[list] = None
         self.error: Optional[Exception] = None
         # Cross-process correlation (round 23): the submitter's trace
         # context crosses to the batch worker thread with the request.
         self.trace_ctx = trace.get_trace_context()
+        request.parts.append(self)
+        request.remaining += 1
 
 
 class MicroBatcher:
-    """Coalesce concurrent ``submit()`` calls into bounded batches.
+    """Coalesce concurrent ``admit()`` / ``submit()`` calls into bounded
+    batches.
 
     ``run_batch(items) -> results`` must be length-preserving; it runs
     on the single worker thread, so an oracle that is not itself
@@ -107,15 +136,16 @@ class MicroBatcher:
         self._thread.start()
 
     # -- client side -----------------------------------------------------
-    def submit(self, items: list, timeout_s: Optional[float] = None) -> list:
-        """Run ``items`` through the oracle as part of some batch;
-        blocks until the batch executes. Raises :class:`Overloaded` on
-        a full admission queue and :class:`DeadlineExceeded` when
-        ``timeout_s`` elapses first."""
-        if not items:
-            return []
+    def admit(self, items: list, timeout_s: Optional[float],
+              on_done: Callable[[], None]) -> _Request:
+        """Queue ``items`` (not empty) for some batch and return at
+        once. ``on_done()`` is called once, when every part has its
+        result or its error (:meth:`_Request.results` then gives them):
+        on the worker thread, or on :meth:`close`'s; it must not block.
+        Raises :class:`Overloaded` on a full admission queue."""
         now = time.monotonic()
         n = len(items)
+        request = _Request(on_done, now)
         with self._cv:
             if self._closed:
                 raise RuntimeError("MicroBatcher is closed")
@@ -126,32 +156,48 @@ class MicroBatcher:
                     f"queued, cap {self.max_queue_lanes}); retry later")
             deadline = None if timeout_s is None else now + timeout_s
             if n <= self.max_batch:
-                parts = [_Pending(items, deadline, now)]
+                _Pending(items, deadline, request)
             else:
                 # Oversized bulk: admit as max_batch-sized sub-requests
                 # under this ONE admission decision (all or shed), so
                 # the worker can legally coalesce and cap every batch.
                 incr_counter("serve", "split_requests")
-                parts = [
-                    _Pending(items[i : i + self.max_batch], deadline, now)
-                    for i in range(0, n, self.max_batch)
-                ]
-            self._queue.extend(parts)
+                for i in range(0, n, self.max_batch):
+                    _Pending(items[i : i + self.max_batch], deadline,
+                             request)
+            self._queue.extend(request.parts)
             self._queued_lanes += n
             set_gauge("serve", "queue_lanes", value=float(self._queued_lanes))
             incr_counter("serve", "requests")
             incr_counter("serve", "lanes", value=float(n))
             self._cv.notify()
-        with trace.span("serve.wait", cat="serve", lanes=n):
-            for p in parts:
-                p.done.wait()
-        add_sample("serve", "wait_s", value=time.monotonic() - now)
-        err = next((p.error for p in parts if p.error is not None), None)
-        if err is not None:
-            raise err
-        if len(parts) == 1:
-            return parts[0].result
-        return [r for p in parts for r in p.result]
+        return request
+
+    def submit(self, items: list, timeout_s: Optional[float] = None) -> list:
+        """Run ``items`` through the oracle as part of some batch;
+        blocks until the batch executes. Raises :class:`Overloaded` on
+        a full admission queue and :class:`DeadlineExceeded` when
+        ``timeout_s`` elapses first."""
+        if not items:
+            return []
+        done = threading.Event()
+        request = self.admit(items, timeout_s, done.set)
+        with trace.span("serve.wait", cat="serve", lanes=len(items)):
+            done.wait()
+        add_sample("serve", "wait_s",
+                   value=time.monotonic() - request.enq_t)
+        return request.results()
+
+    def _settle(self, p: _Pending) -> None:
+        """``p`` has its result or its error: tell its request's owner
+        if it was the last part. Parts settle on the worker thread and
+        on close()'s, which may have given up joining the worker: the
+        count is kept under the queue's lock."""
+        with self._cv:
+            p.request.remaining -= 1
+            last = p.request.remaining == 0
+        if last:
+            p.request.on_done()
 
     def queue_lanes(self) -> int:
         with self._cv:
@@ -170,7 +216,7 @@ class MicroBatcher:
             self._queued_lanes = 0
         for p in drained:
             p.error = RuntimeError("MicroBatcher closed")
-            p.done.set()
+            self._settle(p)
 
     # -- worker side -----------------------------------------------------
     def _collect(self) -> list:
@@ -185,7 +231,7 @@ class MicroBatcher:
             # are waiting, or max_delay_s after the OLDEST request
             # enqueued — whichever first. New arrivals notify. (Only
             # this worker pops, so the queue cannot empty mid-wait.)
-            due = self._queue[0].enq_t + self.max_delay_s
+            due = self._queue[0].request.enq_t + self.max_delay_s
             while self._queued_lanes < self.max_batch and not self._closed:
                 remaining = due - time.monotonic()
                 if remaining <= 0:
@@ -219,7 +265,7 @@ class MicroBatcher:
                     p.error = DeadlineExceeded(
                         f"deadline passed {now - p.deadline:.3f}s before "
                         "the batch executed")
-                    p.done.set()
+                    self._settle(p)
                 else:
                     live.append(p)
             if not live:
@@ -245,7 +291,7 @@ class MicroBatcher:
                 incr_counter("serve", "batch_errors")
                 for p in live:
                     p.error = err
-                    p.done.set()
+                    self._settle(p)
                 continue
             incr_counter("serve", "batches")
             add_sample("serve", "batch_lanes", value=float(len(flat)))
@@ -253,4 +299,4 @@ class MicroBatcher:
             for p in live:
                 p.result = list(results[pos : pos + len(p.items)])
                 pos += len(p.items)
-                p.done.set()
+                self._settle(p)

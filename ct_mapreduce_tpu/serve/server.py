@@ -22,6 +22,14 @@ Endpoints:
   credentials/limits) fetches one entry and returns its PEM, so edge
   clients need no direct log access.
 
+One front thread (:class:`_Front`, ``query-front``): a ``selectors``
+loop accepts, reads, answers and closes every connection, so a query
+costs no thread's life and no ``http.server`` parse. ``POST /query``
+never blocks it (the batcher calls back, the loop writes the answer);
+the ``GET`` routes above, which can block or stream, run on a small
+fixed pool. The protocol is HTTP/1.0-style: the answer closes the
+connection unless the client sent ``Connection: keep-alive``.
+
 The oracle half (:class:`MembershipOracle`) is independent of HTTP —
 tests drive it in-process — and composes the
 three serving primitives, hottest first:
@@ -36,11 +44,17 @@ directives (and their ``CTMR_SERVE_*`` env equivalents) tune the tier.
 
 from __future__ import annotations
 
+import errno
 import json
 import os
+import selectors
+import socket
 import threading
 import time
-from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
+from collections import deque
+from concurrent.futures import ThreadPoolExecutor
+from functools import partial
+from http import HTTPStatus
 from typing import Optional
 
 import numpy as np
@@ -55,7 +69,7 @@ from ct_mapreduce_tpu.serve.batcher import (
 from ct_mapreduce_tpu.serve.cache import HotSerialCache
 from ct_mapreduce_tpu.serve.snapshot import ReplicaPool
 from ct_mapreduce_tpu.telemetry import trace
-from ct_mapreduce_tpu.telemetry.metrics import incr_counter
+from ct_mapreduce_tpu.telemetry.metrics import add_sample, incr_counter
 
 
 _SERVE_KNOBS = (
@@ -160,6 +174,26 @@ class FilterTier:
                     g, [items[i][2] for i in lanes])
                 out[np.asarray(lanes)[~hit]] = True
         return out
+
+
+class Admitted:
+    """A query between :meth:`MembershipOracle.admit` and the
+    ``query_raw`` that finishes it: the lanes the cache and the filter
+    tier answered (``out``), those that wait for the table (``miss``,
+    indices into ``items``) and their place in the batcher's queue."""
+
+    __slots__ = ("items", "out", "miss", "request")
+
+    def __init__(self, items: list, out: list, miss: list) -> None:
+        self.items = items
+        self.out = out
+        self.miss = miss
+        self.request = None
+
+    @property
+    def lanes(self) -> int:
+        """Lanes in the batcher's queue: 0 says nothing waits."""
+        return len(self.miss) if self.request is not None else 0
 
 
 class MembershipOracle:
@@ -278,16 +312,11 @@ class MembershipOracle:
         age = view.age_s()
         return [(bool(k), view.epoch, age) for k in known]
 
-    def query_raw(self, items: list,
-                  timeout_s: Optional[float] = None) -> list:
-        """items: [(issuer_idx, exp_hour, serial_bytes)] →
-        [(known, epoch, staleness_s)]. Cache hits answer immediately
-        (valid while their epoch >= the pool's floor — equivalent to
-        the round-robin picking the stalest replica); cache misses
-        consult the filter tier when armed (cascade-negative lanes
-        answer at the tier's epoch, no table view touched); the rest
-        batch through the oracle, each sub-batch answered by ONE
-        pinned view."""
+    def _before_the_table(self, items: list) -> "Admitted":
+        """What needs no table view: the cache answers what it holds
+        (valid while an entry's epoch >= the pool's floor — equivalent
+        to the round-robin picking the stalest replica), the filter
+        tier when armed answers its negatives (at the tier's epoch)."""
         n = len(items)
         out: list = [None] * n
         if self.cache is None:
@@ -306,9 +335,8 @@ class MembershipOracle:
             if n - len(miss):
                 incr_counter("serve", "cache_hit",
                              value=float(n - len(miss)))
-            if not miss:
-                return out
-            incr_counter("serve", "cache_miss", value=float(len(miss)))
+            if miss:
+                incr_counter("serve", "cache_miss", value=float(len(miss)))
         tier = self.filter_tier if self.filter_first else None
         if tier is not None and miss:
             neg = tier.negatives([items[i] for i in miss])
@@ -326,17 +354,46 @@ class MembershipOracle:
                 incr_counter("serve", "filter_forward",
                              value=float(len(fwd)))
             miss = fwd
-        if not miss:
-            return out
-        res = self.batcher.submit([items[i] for i in miss],
-                                  timeout_s=timeout_s)
+        return Admitted(items, out, miss)
+
+    def admit(self, items: list, timeout_s: Optional[float],
+              answered) -> "Admitted":
+        """:meth:`query_raw` up to its wait: the cache and the filter
+        tier answer what they can and the rest is admitted to the
+        batcher (:class:`Overloaded` raises here). ``answered()`` is
+        then called once, on the batcher's thread, when ``query_raw``
+        of what this returns will not wait; never where no lane needed
+        the table (``lanes`` 0: ``query_raw`` may be called at once)."""
+        asked = self._before_the_table(items)
+        if asked.miss:
+            asked.request = self.batcher.admit(
+                [items[i] for i in asked.miss], timeout_s, answered)
+        return asked
+
+    def query_raw(self, items, timeout_s: Optional[float] = None) -> list:
+        """items: [(issuer_idx, exp_hour, serial_bytes)] →
+        [(known, epoch, staleness_s)]: the cache, the filter tier, and
+        the rest batched through the oracle, each sub-batch answered by
+        ONE pinned view. Blocks until the batch ran — unless ``items``
+        is what :meth:`admit` returned for them and ``answered`` was
+        called: the query front's loop asks in those two halves and
+        waits in neither. (The second half is this method and no other,
+        so that every answer of the plane still leaves through
+        ``query_raw``: what wraps it sees them all.)"""
+        asked = (items if isinstance(items, Admitted)
+                 else self._before_the_table(items))
+        if not asked.miss:
+            return asked.out
+        sub = [asked.items[i] for i in asked.miss]
+        res = (self.batcher.submit(sub, timeout_s=timeout_s)
+               if asked.request is None else asked.request.results())
         done = time.time()
-        for i, r in zip(miss, res):
-            out[i] = r
+        for i, it, r in zip(asked.miss, sub, res):
+            asked.out[i] = r
             if self.cache is not None:
-                self.cache.put(items[i], known=r[0], epoch=r[1],
+                self.cache.put(it, known=r[0], epoch=r[1],
                                created_wall=done - r[2])
-        return out
+        return asked.out
 
     def resolve_issuer(self, issuer_id: str) -> int:
         idx = self._agg.registry.index_of_issuer_id(issuer_id)
@@ -400,34 +457,22 @@ def _parse_query(q: dict, oracle: MembershipOracle):
     return (oracle.resolve_issuer(issuer), eh, serial)
 
 
-class _QueryHTTPServer(ThreadingHTTPServer):
-    # The standard library listens with a backlog of 5. Independent
-    # clients arrive in bursts (after any pause of this process or its
-    # machine, everything that was due meanwhile arrives at once), and
-    # connections beyond the backlog are dropped in the kernel, where
-    # no 429 says so: they come back in step, a second, three and seven
-    # seconds later, and collide again. The admission queue sheds load
-    # (``Overloaded``); the socket must not.
-    request_queue_size = 1024
-
-    def process_request_thread(self, request, client_address):
-        """A connection thread's whole life, from its first instruction
-        to the socket's close, under one span: request line and header
-        parsing, the handlers (and the ``serve.wait`` they cause), the
-        response write. Its ``tdur`` is what the front costs in CPU a
-        connection; the handler says what the connection carried."""
-        with trace.span("front.conn", cat="front"):
-            super().process_request_thread(request, client_address)
+def _ready(code: int, body: dict):
+    """A ``finish`` whose answer is known already."""
+    return lambda: (code, body)
 
 
 class QueryServer:
-    """Background HTTP server for the query plane (``queryPort``).
+    """Background HTTP server for the query plane (``queryPort``): the
+    oracle, the routes' handlers, and the one thread that talks to the
+    clients (:class:`_Front`). Port 0 binds ephemeral (tests),
+    ``stop()`` shuts down cleanly. ``transport`` overrides the CT-log
+    HTTP transport for the ``/getcert`` proxy (tests route it at an
+    in-process fake log)."""
 
-    Mirrors :class:`~ct_mapreduce_tpu.telemetry.promhttp.MetricsServer`
-    mechanics: ``ThreadingHTTPServer`` on a daemon thread, port 0 binds
-    ephemeral (tests), ``stop()`` shuts down cleanly. ``transport``
-    overrides the CT-log HTTP transport for the ``/getcert`` proxy
-    (tests route it at an in-process fake log)."""
+    # The deployments' request deadline: a connection on which no byte
+    # moves for this long is dropped.
+    idle_s = 10.0
 
     def __init__(self, agg, port: int, host: str = "0.0.0.0",
                  max_batch: int = 4096, max_delay_s: float = 0.002,
@@ -453,27 +498,38 @@ class QueryServer:
         # Optional SLO probe (round 23): a callable returning the
         # current breach-reason list; non-empty flips /healthz to 503.
         self.slo_check = None
-        self._server: Optional[ThreadingHTTPServer] = None
-        self._thread: Optional[threading.Thread] = None
+        self._front: Optional[_Front] = None
 
     # -- request handling ------------------------------------------------
-    def handle_query(self, body: dict) -> tuple[int, dict]:
+    def begin_query(self, body: dict, answered):
+        """A ``/query`` in two halves, so that the front's loop waits in
+        neither: parse and admit now, ``finish() -> (code, body)``
+        later. Returns ``(finish, lanes)``. With ``lanes`` > 0 the
+        request is in the batcher's queue: ``answered()`` will be
+        called once, on the batcher's thread, and ``finish`` only after
+        it. With 0, ``finish`` may be called at once: a 400, a 429, or
+        an answer the cache or the filter tier had whole."""
         queries = body.get("queries")
         single = queries is None
         if single:
             queries = [body]
         if not isinstance(queries, list) or not queries:
-            return 400, {"error": "queries must be a non-empty list"}
+            return _ready(400, {"error": "queries must be a non-empty list"}), 0
         try:
             items = [_parse_query(q, self.oracle) for q in queries]
         except (ValueError, AttributeError, TypeError) as err:
-            return 400, {"error": str(err)}
+            return _ready(400, {"error": str(err)}), 0
         timeout_ms = body.get("timeoutMs")
         timeout_s = float(timeout_ms) / 1e3 if timeout_ms else None
         try:
-            results = self.oracle.query_raw(items, timeout_s=timeout_s)
+            asked = self.oracle.admit(items, timeout_s, answered)
         except Overloaded as err:
-            return 429, {"error": "overloaded", "detail": str(err)}
+            return _ready(429, {"error": "overloaded", "detail": str(err)}), 0
+        return partial(self._finish_query, asked, single), asked.lanes
+
+    def _finish_query(self, asked: Admitted, single: bool) -> tuple[int, dict]:
+        try:
+            results = self.oracle.query_raw(asked)
         except DeadlineExceeded as err:
             return 504, {"error": "deadline_exceeded", "detail": str(err)}
         # A result row comes from one pinned view, but rows can span
@@ -490,6 +546,20 @@ class QueryServer:
         if single:
             out["known"] = out["results"][0]["known"]
         return 200, out
+
+    def handle_get(self, path: str, qs: str, headers) -> tuple:
+        """The routes that can block or stream (the front runs them on
+        its pool): ``(code, body[, extra headers])``."""
+        from urllib.parse import parse_qsl, unquote
+
+        if path == "/healthz":
+            return self.handle_healthz()
+        if path.startswith("/issuer/"):
+            return self.handle_issuer(unquote(path[len("/issuer/"):]))
+        if path == "/getcert":
+            return self.handle_getcert(dict(parse_qsl(qs)))
+        return self.handle_filter(
+            unquote(path[len("/filter"):]).lstrip("/"), req_headers=headers)
 
     def handle_issuer(self, issuer_id: str) -> tuple[int, dict]:
         meta = self.oracle.issuer_meta(issuer_id)
@@ -509,7 +579,8 @@ class QueryServer:
         """One distribution payload: strong-ETag conditional GET
         (If-None-Match ⇒ 304 with zero body bytes), Accept-Encoding
         negotiation against the pre-compressed cache, and per-artifact
-        cache headers."""
+        cache headers. ``req_headers``: the request's, by lower-cased
+        name, as the front parses them."""
         from email.utils import formatdate
 
         headers = {"ETag": etag, "Cache-Control": cache_control,
@@ -519,7 +590,7 @@ class QueryServer:
                                                   usegmt=True)
         if epoch is not None:
             headers["X-Filter-Epoch"] = str(epoch)
-        inm = (req_headers.get("If-None-Match", "")
+        inm = (req_headers.get("if-none-match", "")
                if req_headers else "")
         if inm and (inm.strip() == "*"
                     or etag in [t.strip() for t in inm.split(",")]):
@@ -530,7 +601,7 @@ class QueryServer:
             from ct_mapreduce_tpu.distrib import negotiate_encoding
 
             enc = negotiate_encoding(
-                req_headers.get("Accept-Encoding", ""))
+                req_headers.get("accept-encoding", ""))
             if enc:
                 payload = distributor.encoded(cache_key, blob, enc)
                 headers["Content-Encoding"] = enc
@@ -704,124 +775,466 @@ class QueryServer:
 
     # -- server lifecycle ------------------------------------------------
     def start(self) -> "QueryServer":
-        server = self
-
-        class Handler(BaseHTTPRequestHandler):
-            def handle(self):
-                # What the connection carried, for its front.conn span:
-                # requests that reached a handler and the bytes of
-                # their bodies and of the answers' (headers not
-                # counted).
-                self.carried = {"requests": 0, "bytes_in": 0,
-                                "bytes_out": 0}
-                try:
-                    super().handle()
-                finally:
-                    trace.annotate(**self.carried)
-
-            def _trace_ctx(self):
-                """Cross-process correlation (round 23): adopt the
-                client's traceparent header so every span this request
-                produces on this thread carries its trace_id."""
-                ids = trace.parse_traceparent(
-                    self.headers.get(trace.TRACEPARENT_HEADER, "") or "")
-                if ids is None:
-                    return trace.trace_context(None)
-                return trace.trace_context(*ids)
-
-            def _respond(self, code: int, body, headers=None) -> None:
-                if isinstance(body, (bytes, bytearray)):
-                    payload, ctype = bytes(body), "application/octet-stream"
-                else:
-                    payload, ctype = json.dumps(body).encode(), \
-                        "application/json"
-                self.send_response(code)
-                self.send_header("Content-Type", ctype)
-                self.send_header("Content-Length", str(len(payload)))
-                for name, value in sorted((headers or {}).items()):
-                    self.send_header(name, value)
-                self.end_headers()
-                # Large-artifact publish path (round 19): a 10⁸-scale
-                # filter is ~100 MB — stream it in 1 MB slices so the
-                # socket layer never buffers a second full copy and
-                # slow clients don't pin one giant write.
-                view = memoryview(payload)
-                for off in range(0, len(view), 1 << 20):
-                    self.wfile.write(view[off: off + (1 << 20)])
-                self.carried["bytes_out"] += len(payload)
-                if code >= 400:
-                    incr_counter("serve", "http_errors")
-
-            def do_POST(self):  # noqa: N802 (http.server API)
-                self.carried["requests"] += 1
-                path = self.path.split("?", 1)[0].rstrip("/") or "/"
-                if path != "/query":
-                    self._respond(404, {"error": "not found"})
-                    return
-                try:
-                    length = int(self.headers.get("Content-Length", "0"))
-                    raw = self.rfile.read(length)
-                    self.carried["bytes_in"] += len(raw)
-                    body = json.loads(raw or b"{}")
-                    if not isinstance(body, dict):
-                        raise ValueError("body must be a JSON object")
-                except (ValueError, json.JSONDecodeError) as err:
-                    self._respond(400, {"error": f"bad request: {err}"})
-                    return
-                try:
-                    with self._trace_ctx():
-                        self._respond(*server.handle_query(body))
-                except Exception as err:  # the server must answer
-                    self._respond(
-                        500, {"error": f"{type(err).__name__}: {err}"})
-
-            def do_GET(self):  # noqa: N802
-                self.carried["requests"] += 1
-                raw_path, _, qs = self.path.partition("?")
-                path = raw_path.rstrip("/") or "/"
-                with self._trace_ctx():
-                    self._dispatch_get(path, qs)
-
-            def _dispatch_get(self, path: str, qs: str) -> None:
-                try:
-                    if path == "/healthz":
-                        self._respond(*server.handle_healthz())
-                    elif path.startswith("/issuer/"):
-                        from urllib.parse import unquote
-
-                        self._respond(*server.handle_issuer(
-                            unquote(path[len("/issuer/"):])))
-                    elif path == "/filter" or path.startswith("/filter/"):
-                        from urllib.parse import unquote
-
-                        self._respond(*server.handle_filter(
-                            unquote(path[len("/filter"):]).lstrip("/"),
-                            req_headers=self.headers))
-                    elif path == "/getcert":
-                        from urllib.parse import parse_qsl
-
-                        self._respond(
-                            *server.handle_getcert(dict(parse_qsl(qs))))
-                    else:
-                        self._respond(404, {"error": "not found"})
-                except Exception as err:
-                    self._respond(
-                        500, {"error": f"{type(err).__name__}: {err}"})
-
-            def log_message(self, *args):  # no per-request stderr spam
-                pass
-
-        self._server = _QueryHTTPServer((self.host, self.port), Handler)
-        self.port = self._server.server_address[1]  # resolve port 0
-        self._thread = threading.Thread(
-            target=self._server.serve_forever, name="query-serve",
-            daemon=True)
-        self._thread.start()
+        self._front = _Front(self, self.host, self.port)
+        self.port = self._front.port  # resolve port 0
+        self._front.start()
         return self
 
     def stop(self) -> None:
-        if self._server is not None:
-            self._server.shutdown()
-            self._server.server_close()
-            self._server = None
+        if self._front is not None:
+            self._front.stop()
+            self._front = None
         self.oracle.close()
+
+
+class _Conn:
+    """One accepted connection, between the loop's turns."""
+
+    __slots__ = ("sock", "inbuf", "out", "watching", "keep", "active",
+                 "wait", "requests", "bytes_in", "bytes_out", "tracer",
+                 "span_id", "t0_ns", "tts_ns", "cpu_ns")
+
+    def __init__(self, sock: socket.socket, now: float) -> None:
+        self.sock: Optional[socket.socket] = sock
+        self.inbuf = bytearray()
+        self.out = b""          # what of the answer is not sent yet
+        self.watching = 0       # the selector events it is registered for
+        self.keep = False       # the client asked to keep the connection
+        self.active = now       # the last byte moved (time.monotonic)
+        self.wait = None        # a /query in the batcher's queue
+        self.requests = self.bytes_in = self.bytes_out = 0
+        self.tracer = None      # front.conn: the tracer it began under
+
+
+class _BadRequest(Exception):
+    """A request the front refuses before any handler: (code, why)."""
+
+
+class _Front:
+    """The query plane's front: ONE thread (``query-front``) and a
+    ``selectors`` loop over the listening socket and every connection.
+    It accepts, reads until a request is whole, parses the request line
+    and the headers from the bytes, answers and closes; no connection
+    has a thread and none goes through ``http.server``. A ``POST
+    /query`` is admitted to the batcher with a callback and the loop
+    moves on: the batcher's thread puts the connection on ``_done`` and
+    wakes the loop (a byte down a socketpair, one for all the answers of
+    a batch), which writes the answer. The routes that can block or
+    stream (``/filter...``, ``/getcert``, ``/issuer/``, ``/healthz``)
+    leave the loop for a pool of ``POOL_THREADS``, which runs the
+    handler and writes on the connection's blocking socket, then hands
+    the connection back; ``front.pool_requests`` counts them.
+
+    Sockets stay blocking and every ``recv`` / ``send`` of the loop
+    passes ``MSG_DONTWAIT``: a connection that has its request behind
+    its ``connect`` (the common case) costs accept, recv, send, close
+    and never meets the selector. The protocol is the old front's: the
+    answer closes the connection (``HTTP/1.0``) unless the client sent
+    ``Connection: keep-alive`` (then ``HTTP/1.1``, and requests may be
+    pipelined). A header block over ``MAX_HEAD`` or a body over
+    ``MAX_BODY`` is refused and the connection closed; one on which no
+    byte moves for ``idle_s`` is dropped."""
+
+    # The standard library listens with a backlog of 5. Independent
+    # clients arrive in bursts (after any pause of this process or its
+    # machine, everything that was due meanwhile arrives at once), and
+    # connections beyond the backlog are dropped in the kernel, where
+    # no 429 says so: they come back in step, a second, three and seven
+    # seconds later, and collide again. The admission queue sheds load
+    # (``Overloaded``); the socket must not.
+    BACKLOG = 1024
+    MAX_HEAD = 64 << 10
+    MAX_BODY = 16 << 20
+    POOL_THREADS = 4
+    SWEEP_S = 1.0        # how often idle connections are looked for
+
+    def __init__(self, server: QueryServer, host: str, port: int) -> None:
+        self._server = server
+        self.idle_s = float(server.idle_s)
+        self._listener = socket.create_server((host, port),
+                                              backlog=self.BACKLOG)
+        self._listener.setblocking(False)
+        self.port = self._listener.getsockname()[1]
+        self._sel = selectors.DefaultSelector()
+        self._sel.register(self._listener, selectors.EVENT_READ, None)
+        # The batcher's thread and the pool's hand connections back
+        # here; the flag keeps it to one wake-up byte a drain.
+        self._wake_r, self._wake_w = socket.socketpair()
+        self._wake_r.setblocking(False)
+        self._wake_w.setblocking(False)
+        self._sel.register(self._wake_r, selectors.EVENT_READ, self)
+        self._done: deque = deque()
+        self._woken = False
+        self._live: set[_Conn] = set()
+        self._stopping = False
+        self._pool = ThreadPoolExecutor(self.POOL_THREADS,
+                                        thread_name_prefix="query-pool")
+        self._thread = threading.Thread(target=self._run, name="query-front",
+                                        daemon=True)
+
+    def start(self) -> None:
+        self._thread.start()
+
+    def stop(self) -> None:
+        """Nobody still connected is answered: the loop ends, a pool
+        thread in a write is freed by its socket's shutdown."""
+        self._stopping = True
+        self._post(None)
+        self._thread.join()
+        for conn in list(self._live):
+            try:
+                conn.sock.shutdown(socket.SHUT_RDWR)
+            except OSError:
+                pass
+        self._pool.shutdown(wait=True, cancel_futures=True)
+        for conn in list(self._live):
+            self._close(conn)
+        self._sel.close()
+        for sock in (self._listener, self._wake_r, self._wake_w):
+            sock.close()
+
+    # -- the loop --------------------------------------------------------
+    def _run(self) -> None:
+        swept = time.monotonic()
+        cpu = 0
+        while not self._stopping:
+            watched = len(self._sel.get_map()) > 2
+            ready = self._sel.select(self.SWEEP_S if watched else None)
+            tracer = trace.get_tracer()
+            # The CPU clock is read once a turn, at its end, and only
+            # under a tracer (a read is a syscall on the chip's host): a
+            # turn runs from the end of the one before it, the loop's
+            # own wait for the event included, so the connections'
+            # shares add up to all the CPU this thread used.
+            if tracer is None:
+                cpu = 0
+            elif not cpu:
+                cpu = time.thread_time_ns()
+            for key, _ in ready:
+                if key.data is None:
+                    cpu = self._accept(tracer, cpu)
+                elif key.data is self:
+                    cpu = self._drain(tracer, cpu)
+                else:
+                    cpu = self._turn(key.data, self._ready, tracer, cpu)
+            now = time.monotonic()
+            if watched and now - swept >= self.SWEEP_S:
+                swept = now
+                for conn in [k.data for k in self._sel.get_map().values()
+                             if isinstance(k.data, _Conn)
+                             and now - k.data.active > self.idle_s]:
+                    cpu = self._turn(conn, self._close, tracer, cpu)
+
+    def _turn(self, conn: _Conn, step, tracer, cpu: int) -> int:
+        """One turn of the loop for one connection: ``step(conn)``, then
+        the thread's CPU since ``cpu`` (the end of the turn before)
+        added to the connection's and, if the turn closed it, its
+        ``front.conn`` recorded. Returns the CPU clock for the next
+        turn. A turn that raises costs its connection, never the
+        loop."""
+        try:
+            step(conn)
+        except Exception:
+            self._close(conn)
+        if tracer is None:
+            return cpu
+        now = time.thread_time_ns()
+        if conn.tracer is tracer:
+            conn.cpu_ns += now - cpu
+            if conn.sock is None:
+                conn.tracer = None
+                tracer.record_span(
+                    "front.conn", "front", conn.t0_ns,
+                    time.perf_counter_ns(), tts_ns=conn.tts_ns,
+                    tdur_ns=conn.cpu_ns, span_id=conn.span_id,
+                    requests=conn.requests, bytes_in=conn.bytes_in,
+                    bytes_out=conn.bytes_out)
+        return now
+
+    def _accept(self, tracer, cpu: int) -> int:
+        """One connection off the backlog (the selector says so again
+        while there are more) and its first turn: the request is
+        usually right behind the ``connect``."""
+        t0_ns = time.perf_counter_ns() if tracer is not None else 0
+        try:
+            sock, _ = self._listener.accept()
+        except OSError as err:
+            if err.errno in (errno.EMFILE, errno.ENFILE, errno.ENOBUFS,
+                             errno.ENOMEM):
+                time.sleep(0.05)  # no descriptor to accept into: not a spin
+            return cpu
+        conn = _Conn(sock, time.monotonic())
+        self._live.add(conn)
+        if tracer is not None:
+            conn.tracer, conn.span_id = tracer, tracer.next_id()
+            conn.t0_ns, conn.tts_ns, conn.cpu_ns = t0_ns, cpu, 0
+        return self._turn(conn, self._read, tracer, cpu)
+
+    def _post(self, conn: Optional[_Conn]) -> None:
+        """From any thread: ``conn`` has its answer (the batcher's
+        thread) or was answered (the pool's); the loop takes it from
+        here. The flag is cleared before a drain begins, so a post that
+        finds it set is drained by the wake-up that set it."""
+        self._done.append(conn)
+        if not self._woken:
+            self._woken = True
+            try:
+                self._wake_w.send(b"\0")
+            except OSError:
+                pass  # full of wake-ups already, or stopped
+
+    def _drain(self, tracer, cpu: int) -> int:
+        try:
+            self._wake_r.recv(4096)
+        except OSError:
+            pass
+        self._woken = False
+        while self._done:
+            conn = self._done.popleft()
+            if conn is not None and conn.sock is not None:
+                cpu = self._turn(conn, self._handed_back, tracer, cpu)
+        return cpu
+
+    # -- a connection's turns ---------------------------------------------
+    def _watch(self, conn: _Conn, events: int) -> None:
+        if conn.watching == 0:
+            self._sel.register(conn.sock, events, conn)
+        elif conn.watching != events:
+            self._sel.modify(conn.sock, events, conn)
+        conn.watching = events
+
+    def _unwatch(self, conn: _Conn) -> None:
+        if conn.watching:
+            self._sel.unregister(conn.sock)
+            conn.watching = 0
+
+    def _close(self, conn: _Conn) -> None:
+        if conn.sock is not None:
+            self._unwatch(conn)
+            conn.sock.close()
+            conn.sock = None
+            self._live.discard(conn)
+
+    def _ready(self, conn: _Conn) -> None:
+        if conn.watching == selectors.EVENT_WRITE:
+            self._write(conn)
+        else:
+            self._read(conn)
+
+    def _read(self, conn: _Conn) -> None:
+        try:
+            data = conn.sock.recv(1 << 16, socket.MSG_DONTWAIT)
+        except (BlockingIOError, InterruptedError):
+            self._watch(conn, selectors.EVENT_READ)
+            return
+        except OSError:
+            data = b""
+        if not data:  # the client is gone
+            self._close(conn)
+            return
+        conn.active = time.monotonic()
+        conn.inbuf += data
+        self._advance(conn)
+
+    def _advance(self, conn: _Conn) -> None:
+        """Serve what ``inbuf`` holds: a whole request is dispatched,
+        and the connection reads no further until it is answered; a
+        partial one waits for its bytes."""
+        try:
+            request = self._parse(conn.inbuf)
+        except _BadRequest as bad:
+            conn.keep = False
+            self._unwatch(conn)
+            self._respond(conn, bad.args[0], {"error": bad.args[1]})
+            return
+        if request is None:
+            self._watch(conn, selectors.EVENT_READ)
+            return
+        self._unwatch(conn)
+        self._dispatch(conn, *request)
+
+    def _parse(self, buf: bytearray):
+        """``(method, target, headers, body)`` off the front of ``buf``
+        once the header block and ``Content-Length`` bytes are in, else
+        None; :class:`_BadRequest` for what no handler should see."""
+        end = buf.find(b"\r\n\r\n")
+        if end < 0 or end > self.MAX_HEAD:
+            if len(buf) > self.MAX_HEAD:
+                raise _BadRequest(431, "bad request: header block over "
+                                       f"{self.MAX_HEAD} bytes")
+            return None
+        lines = buf[:end].decode("latin-1").split("\r\n")
+        try:
+            method, target, _version = lines[0].split()
+        except ValueError:
+            raise _BadRequest(
+                400, f"bad request: request line {lines[0]!r}") from None
+        headers: dict[str, str] = {}
+        for line in lines[1:]:
+            name, colon, value = line.partition(":")
+            if colon:
+                headers[name.strip().lower()] = value.strip()
+        try:
+            length = int(headers.get("content-length", "0"))
+            if length < 0:
+                raise ValueError
+        except ValueError:
+            raise _BadRequest(400, "bad request: Content-Length "
+                              f"{headers['content-length']!r}") from None
+        if length > self.MAX_BODY:
+            raise _BadRequest(413, "bad request: body over "
+                                   f"{self.MAX_BODY} bytes")
+        if len(buf) < end + 4 + length:
+            return None
+        body = bytes(buf[end + 4: end + 4 + length])
+        del buf[: end + 4 + length]
+        return method, target, headers, body
+
+    def _dispatch(self, conn: _Conn, method: str, target: str,
+                  headers: dict, body: bytes) -> None:
+        conn.requests += 1
+        conn.bytes_in += len(body)
+        conn.keep = "keep-alive" in headers.get("connection", "").lower()
+        path, _, qs = target.partition("?")
+        path = path.rstrip("/") or "/"
+        pooled = method == "GET" and (
+            path in ("/healthz", "/getcert", "/filter")
+            or path.startswith(("/issuer/", "/filter/")))
+        incr_counter("front", "requests")
+        incr_counter("front", "pool_requests", value=float(pooled))
+        if pooled:
+            self._pool.submit(self._pooled, conn, path, qs, headers)
+        elif method == "POST" and path == "/query":
+            self._query(conn, headers, body)
+        elif method in ("GET", "POST"):
+            self._respond(conn, 404, {"error": "not found"})
+        else:
+            self._respond(conn, 501, {
+                "error": f"unsupported method {method!r}"})
+
+    def _query(self, conn: _Conn, headers: dict, body: bytes) -> None:
+        try:
+            doc = json.loads(body or b"{}")
+            if not isinstance(doc, dict):
+                raise ValueError("body must be a JSON object")
+        except ValueError as err:  # JSONDecodeError, UnicodeDecodeError
+            self._respond(conn, 400, {"error": f"bad request: {err}"})
+            return
+        # Cross-process correlation (round 23): the client's traceparent
+        # goes into the batcher's queue with the request, and onto the
+        # serve.wait this connection records.
+        ids = trace.parse_traceparent(
+            headers.get(trace.TRACEPARENT_HEADER)) or (None,)
+        try:
+            with trace.trace_context(*ids):
+                finish, lanes = self._server.begin_query(
+                    doc, partial(self._post, conn))
+            if lanes:
+                conn.wait = (finish, lanes, time.perf_counter_ns(), ids)
+            else:
+                self._respond(conn, *finish())
+        except Exception as err:  # the server must answer
+            self._respond(conn, 500,
+                          {"error": f"{type(err).__name__}: {err}"})
+
+    def _handed_back(self, conn: _Conn) -> None:
+        """``conn`` is off ``_done``: the batcher has its answer, or the
+        pool wrote it."""
+        if conn.wait is None:
+            self._answered(conn)
+            return
+        finish, lanes, t0_ns, ids = conn.wait
+        conn.wait = None
+        t1_ns = time.perf_counter_ns()
+        add_sample("serve", "wait_s", value=(t1_ns - t0_ns) / 1e9)
+        if conn.tracer is not None:
+            with trace.trace_context(*ids):
+                conn.tracer.record_span("serve.wait", "serve", t0_ns, t1_ns,
+                                        parent=conn.span_id, lanes=lanes)
+        try:
+            answer = finish()
+        except Exception as err:  # the server must answer
+            answer = 500, {"error": f"{type(err).__name__}: {err}"}
+        self._respond(conn, *answer)
+
+    def _encode(self, conn: _Conn, code: int, body,
+                headers=None) -> tuple[bytes, bytes]:
+        """The answer's head and payload; what it carries is counted."""
+        if isinstance(body, (bytes, bytearray)):
+            payload, ctype = bytes(body), "application/octet-stream"
+        else:
+            payload, ctype = json.dumps(body).encode(), "application/json"
+        lines = [f"HTTP/1.{int(conn.keep)} {code} {HTTPStatus(code).phrase}",
+                 f"Content-Type: {ctype}",
+                 f"Content-Length: {len(payload)}"]
+        lines += [f"{name}: {value}"
+                  for name, value in sorted((headers or {}).items())]
+        if conn.keep:
+            lines.append("Connection: keep-alive")
+        conn.bytes_out += len(payload)
+        if code >= 400:
+            incr_counter("serve", "http_errors")
+        return ("\r\n".join(lines) + "\r\n\r\n").encode("latin-1"), payload
+
+    def _respond(self, conn: _Conn, code: int, body, headers=None) -> None:
+        head, payload = self._encode(conn, code, body, headers)
+        conn.out = head + payload
+        self._write(conn)
+
+    def _write(self, conn: _Conn) -> None:
+        try:
+            sent = conn.sock.send(conn.out, socket.MSG_DONTWAIT)
+        except (BlockingIOError, InterruptedError):
+            sent = 0
+        except OSError:
+            self._close(conn)
+            return
+        if sent < len(conn.out):
+            if sent:
+                conn.active = time.monotonic()
+                conn.out = conn.out[sent:]
+            self._watch(conn, selectors.EVENT_WRITE)
+            return
+        conn.out = b""
+        self._answered(conn)
+
+    def _answered(self, conn: _Conn) -> None:
+        """The answer is out: the next request of a kept connection
+        (it may be in ``inbuf`` already), or the close."""
+        if conn.keep:
+            conn.active = time.monotonic()
+            self._advance(conn)
+        else:
+            self._close(conn)
+
+    # -- the pool --------------------------------------------------------
+    def _pooled(self, conn: _Conn, path: str, qs: str,
+                headers: dict) -> None:
+        """On a pool thread: a route that can block (``/getcert`` asks a
+        log) or stream (a filter is up to 100 MB), written on the
+        connection's own blocking socket, at most 1 MB a ``send`` so
+        that the socket layer never holds a second copy; a client that
+        takes no byte for ``idle_s`` is dropped. Its CPU is the
+        connection's."""
+        cpu = time.thread_time_ns() if conn.tracer is not None else 0
+        ids = trace.parse_traceparent(
+            headers.get(trace.TRACEPARENT_HEADER)) or (None,)
+        try:
+            with trace.trace_context(*ids):
+                answer = self._server.handle_get(path, qs, headers)
+        except Exception as err:  # the server must answer
+            answer = 500, {"error": f"{type(err).__name__}: {err}"}
+        head, payload = self._encode(conn, *answer)
+        view = memoryview(payload)
+        try:
+            conn.sock.settimeout(self.idle_s)
+            conn.sock.sendall(head)
+            off = 0
+            while off < len(view):
+                off += conn.sock.send(view[off: off + (1 << 20)])
+            conn.sock.settimeout(None)
+        except OSError:
+            conn.keep = False
+        if conn.tracer is not None:
+            conn.cpu_ns += time.thread_time_ns() - cpu
+        self._post(conn)

@@ -37,6 +37,9 @@ are per-CHUNK, but the disabled path must still cost nothing):
   carries the same ``batch`` without each callee being handed it;
   work that continues on another thread passes ``batch=`` itself.
   :meth:`_Span.set` adds arguments known only once the work is done.
+  A span that is no ``with`` block (a connection served in turns by
+  the query front's loop) is handed over whole, with the ``parent`` its
+  owner kept: :meth:`SpanTracer.record_span`.
 - **Drops are counted.** The ring forgets its oldest events;
   :meth:`SpanTracer.dropped` (and ``otherData.dropped`` in an export)
   says how many, so a reader can refuse a window it did not see whole.
@@ -378,6 +381,24 @@ class SpanTracer:
 
     def span(self, name: str, cat: str = "", **args) -> _Span:
         return _Span(self, name, cat, args)
+
+    def next_id(self) -> int:
+        """The ``id`` of a span that :meth:`record_span` will record
+        later: its children name it as ``parent`` before it ends."""
+        return next(self._ids)
+
+    def record_span(self, name: str, cat: str, t0_ns: int, t1_ns: int,
+                    tts_ns: int = 0, tdur_ns: int = 0, span_id: int = 0,
+                    parent: int = 0, **args) -> None:
+        """A complete span whose life was not one ``with`` block on one
+        thread's stack: a connection that a loop serves in turns, among
+        the turns of others. The caller read the clocks (``t0_ns`` /
+        ``t1_ns`` on ``time.perf_counter_ns``; ``tts_ns`` the recording
+        thread's CPU clock at the first turn, ``tdur_ns`` the CPU of the
+        turns, summed) and names the ``parent`` itself. Recorded by the
+        calling thread, with its trace context."""
+        self._complete(name, cat, t0_ns, t1_ns, tts_ns, tts_ns + tdur_ns,
+                       args, span_id or next(self._ids), parent)
 
     def instant(self, name: str, cat: str = "", **args) -> None:
         stack = getattr(_ctx, "stack", None)

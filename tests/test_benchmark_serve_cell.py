@@ -29,13 +29,15 @@ def test_the_committed_cell_is_correct_and_every_host_metric_reads(  # noqa: F81
         shared_metrics_aside):
     """Theirs, with the metrics listed after the cells' own blocks read
     apart: no lane of the cell's folds took the NumPy routine (PR 37),
-    no lane of its lookups' fingerprints either (PR 44), and each of PR
-    38's seven has a number."""
+    no lane of its lookups' fingerprints either (PR 44), no request
+    left the front's loop for its pool (PR 45), and each of PR 38's
+    seven has a number."""
     theirs.test_the_committed_cell_is_correct_and_every_host_metric_reads()
     assert shared_metrics_aside.pop("fold.meta_fallback_lanes") == 0.0
     assert shared_metrics_aside.pop("decode.pages_walked") == 0.0  # PR 39
     assert shared_metrics_aside.pop("ckpt.unpacked_saves") == 0.0  # PR 42
     assert shared_metrics_aside.pop("fp.fallback_lanes") == 0.0  # PR 44
+    assert shared_metrics_aside.pop("front.pool_requests") == 0.0  # PR 45
     assert sorted(shared_metrics_aside) == sorted(GIL_METRICS)
     assert all(v >= 0.0 for v in shared_metrics_aside.values())
     assert shared_metrics_aside["front.cpu_ms_per_request"] > 0.0

@@ -40,11 +40,16 @@ def test_every_metric_of_the_cell_names_a_reader_that_exists(  # noqa: F811
     assert len(whole["per_layer"][cut]["workloads"]) == 4
     # Then the second four-chip cell's own block (PR 43), which lists
     # neither this cell nor any other; then the query cells' one (PR
-    # 44), which does not list this cell.
+    # 44) and the front's two (PR 45: the second four-chip cell's, and
+    # the query cells'), none of which lists this cell: it has no
+    # query plane.
     assert all(m["workloads"] == [QUERY_CELL] and m["name"].startswith(
-        "qshard4.") for m in whole["per_layer"][cut + 1:-1])
-    assert whole["per_layer"][-1]["name"] == "fp.fallback_lanes"
-    assert theirs.CELL not in whole["per_layer"][-1]["workloads"]
+        "qshard4.") for m in whole["per_layer"][cut + 1:-3])
+    assert names[-3:] == ["fp.fallback_lanes",
+                          "qshard4.front_cpu_ms_per_request",
+                          "front.pool_requests"]
+    assert all(theirs.CELL not in m["workloads"]
+               for m in whole["per_layer"][-3:])
 
 
 def test_the_cell_is_the_control_on_a_mesh_and_nothing_else(  # noqa: F811

@@ -33,6 +33,20 @@ FP_FALLBACK = {
     "workloads": ["backfill-1log-query", "backfill-3log-query-shard4"]}
 
 
+FRONT_CPU_MESH = {
+    "name": "qshard4.front_cpu_ms_per_request", "unit": "ms",
+    "better": "lower", "source": "program_span",
+    "layer": "host threads (the GIL)", "moves": "ingest_entries_per_s",
+    "workloads": ["backfill-3log-query-shard4"]}
+
+
+POOL_REQUESTS = {
+    "name": "front.pool_requests", "unit": "n", "better": "lower",
+    "source": "program_counter", "layer": "query plane",
+    "moves": "ingest_entries_per_s",
+    "workloads": ["backfill-1log-query", "backfill-3log-query-shard4"]}
+
+
 @pytest.fixture(autouse=True)
 def listed_up_to_the_seven(monkeypatch):
     """Theirs hold PR 38's seven to the END of ``per_layer`` (``[-7:]``),
@@ -57,19 +71,32 @@ def test_the_seven_stand_at_the_end_of_the_list(  # noqa: F811
     four cells, read by the same reader; then the second four-chip
     cell's own eighteen (PR 43), which list it alone; then
     ``fp.fallback_lanes`` (PR 44), the 104th, which lists the two query
-    cells and is read by the same reader again."""
+    cells and is read by the same reader again; then PR 45's two: the
+    front's CPU a request in the mesh cell, which is the seventh of the
+    seven under that cell's name (its file is that one's, byte for
+    byte), and ``front.pool_requests``, the 106th, the same reader
+    over both query cells."""
     theirs.test_the_seven_stand_at_the_end_of_the_list()
     assert listed_up_to_the_seven[0] == PAGES_WALKED
     assert all(m["workloads"] == ["backfill-3log-shard4"]
                and m["name"].startswith("shard4.")
-               for m in listed_up_to_the_seven[1:-20])
-    assert listed_up_to_the_seven[-20] == UNPACKED_SAVES
+               for m in listed_up_to_the_seven[1:-22])
+    assert listed_up_to_the_seven[-22] == UNPACKED_SAVES
     assert all(m["workloads"] == ["backfill-3log-query-shard4"]
                and m["name"].startswith("qshard4.")
-               for m in listed_up_to_the_seven[-19:-1])
-    assert listed_up_to_the_seven[-1] == FP_FALLBACK
+               for m in listed_up_to_the_seven[-21:-3])
+    assert listed_up_to_the_seven[-3:] == [FP_FALLBACK, FRONT_CPU_MESH,
+                                           POOL_REQUESTS]
     assert len(theirs.bench_json()["per_layer"]) \
-        + len(listed_up_to_the_seven) == 104
+        + len(listed_up_to_the_seven) == 106
+    here = os.path.join(theirs.BENCH, "layers")
+    with open(os.path.join(here, "front.cpu_ms_per_request.json"), "rb") as a, \
+            open(os.path.join(
+                here, "qshard4.front_cpu_ms_per_request.json"), "rb") as b:
+        assert a.read() == b.read()
+    assert theirs.layer_file("front.pool_requests") == {
+        "reader": "counter_sum",
+        "params": {"key": "front.pool_requests", "phase": "round"}}
     assert theirs.layer_file("decode.pages_walked") == {
         "reader": "counter_sum",
         "params": {"key": "decode.pages_walked", "phase": "round"}}
@@ -151,6 +178,66 @@ def test_fp_fallback_lanes_reads_the_rounds_increments(cell, increments, want):
             "fp.fallback_lanes": {"value": want, "unit": "n"}}
     assert theirs.layers.read_metrics(
         [FP_FALLBACK], "backfill-3log", {"out": out}) == ({}, [])
+
+
+@pytest.mark.parametrize("increments, want", [
+    ([], "ABSENT"),  # the parent: a thread a connection, no such counter
+    ([0.0, 0.0, 0.0], 0.0),  # every request was served by the loop
+    ([0.0, 1.0, 0.0], 1.0)])  # one left it for the pool
+@pytest.mark.parametrize("cell", POOL_REQUESTS["workloads"])
+def test_pool_requests_reads_the_rounds_increments(cell, increments, want):
+    """``front.pool_requests`` in each of the two query cells: the
+    generators' warm-up requests lie before ``t_open`` and are not
+    counted; a program whose front never adds to the counter, not even
+    0, leaves the metric out by name. The cells without queries do not
+    list it."""
+    out = {"t_open": 10.0, "t_durable": 20.0,
+           "counters": [(4.0, "front.pool_requests", 1.0)] * bool(increments)
+           + [(11.0 + k, "front.pool_requests", v)
+              for k, v in enumerate(increments)]
+           + [(11.0 + k, "front.requests", 1.0)
+              for k, _ in enumerate(increments)]}
+    metrics, absent = theirs.layers.read_metrics(
+        [POOL_REQUESTS], cell, {"out": out})
+    if want == "ABSENT":
+        assert absent == ["front.pool_requests"] and metrics == {}
+    else:
+        assert absent == [] and metrics == {
+            "front.pool_requests": {"value": want, "unit": "n"}}
+    assert theirs.layers.read_metrics(
+        [POOL_REQUESTS], "backfill-3log-shard4", {"out": out}) == ({}, [])
+
+
+def test_the_mesh_cells_front_cpu_reads_what_the_one_chip_cells_does():
+    """``qshard4.front_cpu_ms_per_request`` on the recorded ring and on
+    the hand-made window: the number ``front.cpu_ms_per_request`` reads
+    there, in its own cell alone; a ring without the ``front.`` family
+    (a program older than the span) leaves it out by name, as it does
+    the one-chip cell's. A connection the loop served in turns and one a
+    thread lived for are the same span to the reader: ``tdur`` summed
+    over the connections that end in the window, by their ``requests``."""
+    ctx = theirs.recorded()
+    one_chip = theirs.bench_json()["per_layer"][-1]
+    assert one_chip["name"] == "front.cpu_ms_per_request"
+    mesh, absent = theirs.layers.read_metrics(
+        [FRONT_CPU_MESH], "backfill-3log-query-shard4", ctx)
+    flat, _ = theirs.layers.read_metrics(
+        [one_chip], "backfill-1log-query", ctx)
+    assert absent == [] and mesh == {FRONT_CPU_MESH["name"]: {
+        "value": pytest.approx(0.8833897142857143), "unit": "ms"}}
+    assert mesh[FRONT_CPU_MESH["name"]] \
+        == flat["front.cpu_ms_per_request"]
+    assert theirs.layers.read_metrics(
+        [FRONT_CPU_MESH], "backfill-1log-query", ctx) == ({}, [])
+    window = theirs.ctx_of(theirs.EVENTS)
+    assert theirs.layers.read_metrics(
+        [FRONT_CPU_MESH], "backfill-3log-query-shard4", window)[0][
+            FRONT_CPU_MESH["name"]]["value"] == pytest.approx(1.5)
+    older = theirs.ctx_of([e for e in theirs.EVENTS
+                           if e["name"] != "front.conn"])
+    assert theirs.layers.read_metrics(
+        [FRONT_CPU_MESH], "backfill-3log-query-shard4", older) \
+        == ({}, [FRONT_CPU_MESH["name"]])
 
 
 pytestmark = [pytest.mark.timeout(300),

@@ -624,13 +624,13 @@ def test_serve_batch_spans_recorded(template):
         trace.disable()
 
 
-def test_a_connection_threads_whole_life_is_one_front_conn_span(template):
-    """``front.conn`` covers a connection thread from its first
-    instruction to the socket's close: one span a connection, the
-    parent of the ``serve.wait`` its request causes, with what the
-    connection carried; over a kept-alive connection the requests add
-    up under one span. The front speaks HTTP/1.0 and so closes after
-    every answer; the handler is told 1.1 for the second half only."""
+def test_a_connections_whole_life_is_one_front_conn_span(template):
+    """``front.conn`` covers a connection from its accept to the
+    socket's close: one span a connection, recorded by the front's one
+    thread, the parent of the ``serve.wait`` its request causes, with
+    what the connection carried and the CPU of its turns, summed; over a
+    kept connection the requests add up under one span. The front closes
+    after every answer unless the client asks to keep the connection."""
     import http.client
 
     from ct_mapreduce_tpu.telemetry import trace
@@ -666,6 +666,7 @@ def test_a_connection_threads_whole_life_is_one_front_conn_span(template):
             conn.request("POST", "/query", one,
                          {"Content-Type": "application/json"})
             resp = conn.getresponse()
+            assert resp.version == 10 and resp.will_close
             answers.append(resp.read())
             conn.close()
         spans = settled(3)
@@ -673,30 +674,46 @@ def test_a_connection_threads_whole_life_is_one_front_conn_span(template):
         waits = [e for e in tracer.events() if e.get("name") == "serve.wait"]
         assert sorted(w["parent"] for w in waits) \
             == sorted(e["id"] for e in spans)
+        names = {m["tid"]: m["args"]["name"] for m in tracer.events()
+                 if m.get("ph") == "M"}
         for e, answer in zip(sorted(spans, key=lambda e: e["ts"]), answers):
             assert e["parent"] == 0 and e["cat"] == "front"
+            assert names[e["tid"]] == "query-front"
             assert e["args"] == {"requests": 1, "bytes_in": len(body),
                                  "bytes_out": len(answer)}
             assert 0 < e["tdur"] <= e["dur"] + 50.0
             (wait,) = [w for w in waits if w["parent"] == e["id"]]
+            assert wait["tid"] == e["tid"] and wait["args"] == {"lanes": 1}
             assert e["ts"] <= wait["ts"] and wait["dur"] <= e["dur"]
+            assert wait["tdur"] == 0.0  # nobody is parked in it
         # Kept alive: two queries and a 404 over one connection.
-        srv._server.RequestHandlerClass.protocol_version = "HTTP/1.1"
         conn = http.client.HTTPConnection("127.0.0.1", srv.port, timeout=10)
         sent = 0
         for path, one in zip(("/query", "/query", "/nowhere"), bodies[3:] * 2):
             conn.request("POST", path, one,
-                         {"Content-Type": "application/json"})
+                         {"Content-Type": "application/json",
+                          "Connection": "keep-alive"})
             resp = conn.getresponse()
             sent += len(resp.read())
             assert resp.status == (200 if path == "/query" else 404)
+            assert resp.version == 11 and not resp.will_close
         conn.close()
         (kept,) = settled(4)[3:]
-        assert kept["args"] == {"requests": 3, "bytes_in": 2 * len(body),
+        assert kept["args"] == {"requests": 3, "bytes_in": 3 * len(body),
                                 "bytes_out": sent}
         assert sum(1 for w in tracer.events()
                    if w.get("name") == "serve.wait"
                    and w["parent"] == kept["id"]) == 2
+        # tdur is the CPU of the connection's turns on the one front
+        # thread: all of them together cannot exceed what that thread
+        # used, and the kept connection's three requests cost more than
+        # none.
+        front = [e for e in tracer.events() if e.get("name") == "front.conn"]
+        assert len({e["tid"] for e in front}) == 1
+        ordered = sorted(front, key=lambda e: e["tts"])
+        assert sum(e["tdur"] for e in front) <= (
+            ordered[-1]["tts"] + ordered[-1]["tdur"] - ordered[0]["tts"] + 1.0)
+        assert kept["tdur"] > 0
     finally:
         trace._tracer = None
         srv.stop()
@@ -1619,3 +1636,518 @@ def test_a_burst_of_connections_is_queued_not_dropped(template):
     # answered in half a second on an idle machine.
     assert max(s for s, _raw in answers) < 6.5, sorted(
         s for s, _raw in answers)[-5:]
+
+
+# -- the callback admission (PR 45) --------------------------------------
+
+
+def test_batcher_admit_hands_results_to_its_callback():
+    """``admit`` returns at once and parks nobody: the worker calls back
+    once a request, whole requests are never split under ``max_batch``,
+    and a bulk over it calls back once, its parts in order."""
+    sizes = []
+
+    def oracle(items):
+        sizes.append(len(items))
+        return [it * 2 for it in items]
+
+    b = MicroBatcher(oracle, max_batch=4, max_delay_s=0.005)
+    try:
+        done = threading.Semaphore(0)
+        calls = []
+
+        def on_done(tag):
+            calls.append((tag, threading.current_thread().name))
+            done.release()
+
+        before = threading.active_count()
+        small = [b.admit([k, k + 100, k + 200], None, lambda k=k: on_done(k))
+                 for k in range(3)]
+        bulk = b.admit(list(range(10)), None, lambda: on_done("bulk"))
+        assert threading.active_count() == before
+        for _ in range(4):
+            assert done.acquire(timeout=5)
+        assert sorted(str(t) for t, _ in calls) == ["0", "1", "2", "bulk"]
+        assert {name for _, name in calls} == {"serve-batcher"}
+        for k, request in enumerate(small):
+            assert request.results() == [2 * k, 2 * k + 200, 2 * k + 400]
+        assert bulk.results() == [2 * k for k in range(10)]
+        assert max(sizes) <= 4 and sum(sizes) == 19
+        assert b.queue_lanes() == 0
+    finally:
+        b.close()
+
+
+def test_batcher_admit_sheds_at_admission_and_expires_through_the_callback():
+    """The guarantees hold whichever way a request came in: a full
+    queue raises ``Overloaded`` from ``admit`` itself (no callback), a
+    deadline passed in the queue reaches the callback as the request's
+    error, and ``close`` lets the worker finish what is queued, calls
+    back for it, and admits nothing more."""
+    release = threading.Event()
+
+    def oracle(items):
+        release.wait(timeout=5)
+        return items
+
+    b = MicroBatcher(oracle, max_batch=2, max_delay_s=0.001,
+                     max_queue_lanes=3)
+    done = threading.Semaphore(0)
+    try:
+        first = b.admit([0], None, done.release)
+        time.sleep(0.05)  # the worker is inside the oracle with [0]
+        late = b.admit([1], 0.01, done.release)
+        kept = b.admit([2, 3], None, done.release)
+        with pytest.raises(Overloaded):
+            b.admit([4], None, done.release)
+        time.sleep(0.05)
+        release.set()
+        for _ in range(3):
+            assert done.acquire(timeout=5)
+        assert first.results() == [0] and kept.results() == [2, 3]
+        with pytest.raises(DeadlineExceeded):
+            late.results()
+        assert not done.acquire(blocking=False)  # the shed one: no call
+        release.clear()
+        stuck = b.admit([5], None, done.release)
+        time.sleep(0.05)
+        queued = b.admit([6], None, done.release)
+        threading.Timer(0.05, release.set).start()
+        b.close()
+        for _ in range(2):
+            assert done.acquire(timeout=5)
+        assert stuck.results() == [5] and queued.results() == [6]
+        with pytest.raises(RuntimeError, match="closed"):
+            b.admit([7], None, done.release)
+    finally:
+        release.set()
+        b.close()
+
+
+def test_oracle_admit_then_query_raw_does_not_wait(template):
+    """``query_raw`` of what ``admit`` returned, after ``answered`` was
+    called, is the blocking ``query_raw``'s answer without the wait, and
+    fills the cache as it does; lanes the cache holds are not admitted
+    again (``lanes`` 0, ``answered`` never called)."""
+    agg = TpuAggregator(capacity=1 << 12, batch_size=64)
+    agg.ingest([(syncerts.stamp_serial(template, j), template.issuer_der)
+                for j in range(4)])
+    issuer_id, eh = _identity(template)
+    idx = agg.registry.index_of_issuer_id(issuer_id)
+    items = [(idx, eh, _serial_bytes(template, j)) for j in (0, 3, 999)]
+    oracle = MembershipOracle(agg, max_batch=64, max_delay_s=0.001)
+    try:
+        answered = threading.Event()
+        asked = oracle.admit(items, None, answered.set)
+        assert asked.lanes == 3
+        assert answered.wait(timeout=10)
+        t0 = time.monotonic()
+        got = oracle.query_raw(asked)
+        assert time.monotonic() - t0 < 0.5
+        assert [r[0] for r in got] == [True, True, False]
+        assert [r[0] for r in oracle.query_raw(items)] == [True, True, False]
+        again = oracle.admit(items, None, lambda: pytest.fail("admitted"))
+        assert again.lanes == 0 and oracle.cache.stats()["cache_hits"] >= 3
+        assert oracle.query_raw(again) == again.out
+    finally:
+        oracle.close()
+
+
+# -- one front thread (PR 45) ---------------------------------------------
+
+
+def _front_server(template, n=8, **kwargs):
+    agg = TpuAggregator(capacity=1 << 12, batch_size=64)
+    agg.ingest([(syncerts.stamp_serial(template, j), template.issuer_der)
+                for j in range(n)])
+    issuer_id, eh = _identity(template)
+
+    def query(j):
+        return {"issuer": issuer_id,
+                "expDate": ExpDate.from_unix_hour(eh).id(),
+                "serial": _serial_bytes(template, j).hex()}
+
+    kwargs.setdefault("max_delay_s", 0.001)
+    return QueryServer(agg, 0, host="127.0.0.1", **kwargs), query
+
+
+def _raw_request(doc, keep=False, path="/query"):
+    body = json.dumps(doc).encode()
+    return (f"POST {path} HTTP/1.1\r\nHost: x\r\n"
+            f"Connection: {'keep-alive' if keep else 'close'}\r\n"
+            "Content-Type: application/json\r\n"
+            f"Content-Length: {len(body)}\r\n\r\n").encode() + body
+
+
+def _read_response(sock):
+    """One response off ``sock``: (status, headers, body)."""
+    raw = b""
+    while b"\r\n\r\n" not in raw:
+        chunk = sock.recv(65536)
+        if not chunk:
+            raise ConnectionError(f"closed after {raw!r}")
+        raw += chunk
+    head, _, rest = raw.partition(b"\r\n\r\n")
+    lines = head.decode().split("\r\n")
+    headers = {k.lower(): v.strip() for k, _, v in
+               (line.partition(":") for line in lines[1:])}
+    want = int(headers["content-length"])
+    while len(rest) < want:
+        chunk = sock.recv(65536)
+        if not chunk:
+            raise ConnectionError("closed inside the body")
+        rest += chunk
+    assert len(rest) == want, "bytes past the answer"
+    return int(lines[0].split()[1]), headers, rest
+
+
+def _front_threads():
+    return sorted(t.name for t in threading.enumerate()
+                  if t.name.startswith(("query-", "Thread-")))
+
+
+def test_front_answers_200_concurrent_connections_on_one_thread(template):
+    """200 clients connect and ask at once; every one is answered, and
+    the process has the one front thread it had before them: no thread
+    a connection, and the pool's threads were never started."""
+    import socket
+
+    srv, query = _front_server(template)
+    srv.start()
+    try:
+        before = _front_threads()
+        assert before.count("query-front") == 1
+        socks = [socket.create_connection(("127.0.0.1", srv.port), timeout=10)
+                 for _ in range(200)]
+        seen = set()
+        for j, sock in enumerate(socks):
+            sock.sendall(_raw_request(query(j % 16)))
+            seen.update(_front_threads())
+        answers = []
+        for sock in socks:
+            status, headers, body = _read_response(sock)
+            seen.update(_front_threads())
+            assert sock.recv(16) == b""  # closed after the answer
+            sock.close()
+            answers.append((status, json.loads(body)["known"]))
+        assert answers == [(200, j % 16 < 8) for j in range(200)]
+        assert sorted(seen) == before == _front_threads()
+        assert not [n for n in before if n.startswith("query-pool")]
+    finally:
+        srv.stop()
+    assert "query-front" not in _front_threads()
+
+
+@pytest.mark.parametrize("how", ["split", "pipelined"])
+def test_front_reads_a_request_in_pieces_and_two_at_once(template, how):
+    """A request that arrives over several ``send``s is served once it
+    is whole; two requests sent back to back on a kept connection are
+    answered in order, and the connection stays open for a third."""
+    import socket
+
+    srv, query = _front_server(template)
+    srv.start()
+    try:
+        sock = socket.create_connection(("127.0.0.1", srv.port), timeout=10)
+        if how == "split":
+            raw = _raw_request(query(1))
+            for cut in (raw[:7], raw[7:40], raw[40:-9], raw[-9:]):
+                sock.sendall(cut)
+                time.sleep(0.05)
+            status, headers, body = _read_response(sock)
+            assert status == 200 and json.loads(body)["known"] is True
+            assert "connection" not in headers and sock.recv(16) == b""
+        else:
+            sock.sendall(_raw_request(query(2), keep=True)
+                         + _raw_request(query(999), keep=True))
+            first = _read_response(sock)
+            second = _read_response(sock)
+            assert [json.loads(r[2])["known"] for r in (first, second)] \
+                == [True, False]
+            assert first[1]["connection"] == "keep-alive"
+            sock.sendall(_raw_request(query(3)))  # and now: close
+            status, headers, body = _read_response(sock)
+            assert json.loads(body)["known"] is True
+            assert sock.recv(16) == b""
+        sock.close()
+    finally:
+        srv.stop()
+
+
+@pytest.mark.parametrize("what, code", [("head", 431), ("body", 413),
+                                        ("line", 400), ("length", 400),
+                                        ("method", 501), ("route", 404)])
+def test_front_refuses_what_no_handler_should_see(template, what, code):
+    """A header block or a body over the bound, a request line or a
+    ``Content-Length`` that does not parse: a 4xx in JSON and the
+    connection closed, without the bytes having been buffered; a method
+    or a route the plane does not have: 501 / 404."""
+    import socket
+
+    from ct_mapreduce_tpu.serve.server import _Front
+
+    srv, query = _front_server(template)
+    srv.start()
+    try:
+        sock = socket.create_connection(("127.0.0.1", srv.port), timeout=10)
+        if what == "head":
+            sock.sendall(b"POST /query HTTP/1.1\r\n")
+            filler = b"X-Filler: " + b"a" * 1000 + b"\r\n"
+            try:
+                for _ in range(_Front.MAX_HEAD // len(filler) + 2):
+                    sock.sendall(filler)
+            except OSError:
+                pass  # refused and closed under the sender
+        elif what == "body":
+            sock.sendall(b"POST /query HTTP/1.1\r\nContent-Length: %d\r\n\r\n"
+                         % (_Front.MAX_BODY + 1))
+        elif what == "line":
+            sock.sendall(b"NONSENSE\r\n\r\n")
+        elif what == "length":
+            sock.sendall(b"POST /query HTTP/1.1\r\nContent-Length: -4\r\n\r\n")
+        elif what == "method":
+            sock.sendall(b"DELETE /query HTTP/1.1\r\n\r\n")
+        else:
+            sock.sendall(b"GET /nowhere HTTP/1.1\r\n"
+                         b"Connection: keep-alive\r\n\r\n")
+        status, headers, body = _read_response(sock)
+        assert status == code and "error" in json.loads(body)
+        assert headers["content-type"] == "application/json"
+        if what == "route":  # a sound request: the connection is kept
+            assert headers["connection"] == "keep-alive"
+        else:
+            try:
+                assert sock.recv(16) == b""
+            except ConnectionResetError:
+                pass  # closed with the filler unread
+        sock.close()
+        # The plane answers the next client.
+        code, body = _post(f"http://127.0.0.1:{srv.port}/query", query(1))
+        assert code == 200 and body["known"] is True
+    finally:
+        srv.stop()
+
+
+def test_front_drops_a_silent_client_at_the_deadline(template):
+    """A client that connects and says nothing (or half a request) is
+    dropped once no byte has moved for ``idle_s``; it holds no thread,
+    and the clients beside it are answered meanwhile at their usual
+    speed."""
+    import socket
+
+    srv, query = _front_server(template)
+    srv.idle_s = 2.0
+    srv.start()
+    try:
+        silent = socket.create_connection(("127.0.0.1", srv.port), timeout=20)
+        half = socket.create_connection(("127.0.0.1", srv.port), timeout=20)
+        half.sendall(_raw_request(query(1))[:30])
+        t0 = time.monotonic()
+        for j in range(10):
+            code, body = _post(f"http://127.0.0.1:{srv.port}/query",
+                               query(j % 8))
+            assert code == 200 and body["known"] is True
+        # Ten answers while the two are still connected: nobody waited
+        # for their deadline.
+        assert time.monotonic() - t0 < srv.idle_s
+        for sock in (silent, half):
+            assert sock.recv(16) == b""  # dropped, no answer
+            sock.close()
+        assert srv.idle_s <= time.monotonic() - t0 < 15.0
+    finally:
+        srv.stop()
+
+
+@pytest.mark.parametrize("code", [429, 504, 500])
+def test_front_answers_429_504_500_through_the_callback_path(template, code):
+    """A full admission queue answers 429 ``overloaded`` at once, a
+    deadline missed in the queue 504 ``deadline_exceeded`` when the
+    batcher hands the request back, a batch that raised 500 with the
+    exception's name; the loop is not held by any of them."""
+    import socket
+
+    srv, query = _front_server(
+        template, cache_size=-1, max_queue_lanes=2 if code == 429 else 64)
+    release = threading.Event()
+    real = srv.oracle.batcher._run_batch
+
+    def slow(items):
+        release.wait(timeout=10)
+        if code == 500:
+            raise KeyError("the oracle broke")
+        return real(items)
+
+    srv.oracle.batcher._run_batch = slow
+    srv.start()
+    try:
+        first = socket.create_connection(("127.0.0.1", srv.port), timeout=10)
+        first.sendall(_raw_request(query(0)))
+        time.sleep(0.1)  # the batcher is inside the oracle with it
+        second = socket.create_connection(("127.0.0.1", srv.port), timeout=10)
+        if code == 429:
+            second.sendall(_raw_request({"queries": [query(1)] * 3}))
+        elif code == 504:
+            second.sendall(_raw_request(dict(query(1), timeoutMs=20)))
+        else:
+            second.sendall(_raw_request(query(1)))
+        if code == 429:  # answered while the batcher is still stuck
+            status, _, body = _read_response(second)
+            assert (status, json.loads(body)["error"]) == (429, "overloaded")
+        # The loop still serves: a 404 needs no batch.
+        with pytest.raises(urllib.error.HTTPError) as ei:
+            urllib.request.urlopen(
+                f"http://127.0.0.1:{srv.port}/nowhere", timeout=5)
+        assert ei.value.code == 404
+        time.sleep(0.05)
+        release.set()
+        status, _, body = _read_response(first)
+        if code == 500:
+            assert status == 500
+            assert json.loads(body)["error"].startswith("KeyError:")
+        else:
+            assert status == 200 and json.loads(body)["known"] is True
+        if code != 429:
+            status, _, body = _read_response(second)
+            want = {504: "deadline_exceeded"}.get(code)
+            assert status == code
+            assert want is None or json.loads(body)["error"] == want
+        first.close()
+        second.close()
+    finally:
+        release.set()
+        srv.stop()
+
+
+def test_front_streams_a_filter_on_the_pool_while_queries_stay_flat(template):
+    """A 48 MB ``/filter`` download to a client that reads slowly runs on
+    a pool thread with a blocking socket of its own: ``/query`` latency
+    beside it is what it was before it, the download arrives whole, and
+    ``front.pool_requests`` counts it (and nothing else)."""
+    import hashlib
+    import socket
+
+    from ct_mapreduce_tpu.telemetry import metrics as tmetrics
+
+    srv, query = _front_server(template, cache_size=-1)
+    blob = np.random.default_rng(45).bytes(48 << 20)
+    routed = srv.handle_get
+
+    def handle_get(path, qs, headers):
+        if path == "/filter":
+            return 200, blob, {"ETag": '"big"'}
+        return routed(path, qs, headers)
+
+    srv.handle_get = handle_get
+    sink = tmetrics.InMemSink()
+    prev = tmetrics.get_sink()
+    tmetrics.set_sink(sink)
+    srv.start()
+    url = f"http://127.0.0.1:{srv.port}/query"
+
+    def p95(n=60):
+        took = []
+        for j in range(n):
+            t = time.monotonic()
+            assert _post(url, query(j % 8))[0] == 200
+            took.append(time.monotonic() - t)
+        return sorted(took)[int(0.95 * n)]
+
+    try:
+        alone = p95()
+        slow = socket.create_connection(("127.0.0.1", srv.port), timeout=30)
+        slow.sendall(b"GET /filter HTTP/1.1\r\nHost: x\r\n\r\n")
+        time.sleep(0.2)  # the pool thread has filled the socket's buffers
+        assert "query-pool_0" in _front_threads()
+        beside = p95()
+        assert beside < 3 * alone + 0.05, (alone, beside)
+        status, headers, body = _read_response(slow)
+        assert status == 200 and headers["etag"] == '"big"'
+        assert headers["content-type"] == "application/octet-stream"
+        assert hashlib.sha256(body).digest() == hashlib.sha256(blob).digest()
+        assert slow.recv(16) == b""
+        slow.close()
+        counters = sink.snapshot()["counters"]
+        assert counters["front.requests"] == 121.0
+        assert counters["front.pool_requests"] == 1.0
+    finally:
+        tmetrics.set_sink(prev)
+        srv.stop()
+
+
+def test_front_counts_every_request_and_the_pools_with_zero(template):
+    """``front.requests`` and ``front.pool_requests`` are both added to
+    on every request, the second with 0 where the loop answered itself:
+    a reader tells "none left the loop" from "this program has no such
+    counter"."""
+    from ct_mapreduce_tpu.telemetry import metrics as tmetrics
+
+    seen = []
+
+    class Emitter(tmetrics.InMemSink):
+        def incr_counter(self, key, value):
+            if key.startswith("front."):
+                seen.append((key, value))
+            super().incr_counter(key, value)
+
+    srv, query = _front_server(template)
+    prev = tmetrics.get_sink()
+    tmetrics.set_sink(Emitter())
+    srv.start()
+    try:
+        base = f"http://127.0.0.1:{srv.port}"
+        for j in range(3):
+            assert _post(f"{base}/query", query(j))[0] == 200
+        assert seen == [("front.requests", 1.0),
+                        ("front.pool_requests", 0.0)] * 3
+        with urllib.request.urlopen(f"{base}/healthz", timeout=10) as resp:
+            assert json.loads(resp.read())["healthy"]
+        assert seen[-2:] == [("front.requests", 1.0),
+                             ("front.pool_requests", 1.0)]
+    finally:
+        tmetrics.set_sink(prev)
+        srv.stop()
+
+
+def test_front_loses_no_wake_up_under_a_short_switch_interval(template):
+    """The batcher's thread and the pool's hand connections to the loop
+    through a deque and one wake-up byte a drain: with the interpreter
+    switching threads every 10 us and more clients than cores, on both
+    kinds of route at once, every request is answered (a lost wake-up
+    would leave one waiting until the next, or for ever)."""
+    import sys
+
+    srv, query = _front_server(template, cache_size=-1, max_delay_s=0.0)
+    srv.start()
+    base = f"http://127.0.0.1:{srv.port}"
+    answered, errors = [], []
+
+    def client(k):
+        try:
+            for j in range(25):
+                if (j + k) % 5 == 0:
+                    with urllib.request.urlopen(f"{base}/healthz",
+                                                timeout=20) as resp:
+                        answered.append(json.loads(resp.read())["healthy"])
+                else:
+                    code, body = _post(f"{base}/query", query((j + k) % 16),
+                                       timeout=20)
+                    answered.append(code == 200
+                                    and body["known"] is ((j + k) % 16 < 8))
+        except Exception as err:  # a timeout is the lost wake-up
+            errors.append(err)
+
+    before = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        clients = [threading.Thread(target=client, args=(k,))
+                   for k in range(24)]
+        for t in clients:
+            t.start()
+        for t in clients:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in clients)
+    finally:
+        sys.setswitchinterval(before)
+        srv.stop()
+    assert not errors, errors
+    assert len(answered) == 24 * 25 and all(answered)

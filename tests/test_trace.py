@@ -277,6 +277,37 @@ def test_annotate_sum_adds_up_under_the_innermost_span():
     assert outer["args"] == {"native_us": 1.0}
 
 
+def test_record_span_hands_over_a_span_that_was_no_with_block():
+    """A connection the query front serves in turns: its owner kept the
+    clocks and the id, names the parent itself, and the event is a
+    complete span like any other (the thread's name, the trace context,
+    the process attributes), with ``tdur`` the CPU it was told and an
+    id of the tracer's own where none was kept. The thread's stack of
+    open spans is not touched."""
+    tracer = trace._tracer = trace.SpanTracer(ring_size=16)
+    t0 = tracer._t0_ns
+    conn_id = tracer.next_id()
+    with trace.span("around"):
+        with trace.trace_context("ab" * 16, "cd" * 8):
+            tracer.record_span("serve.wait", "serve", t0 + 2_000, t0 + 9_000,
+                               parent=conn_id, lanes=2)
+        tracer.record_span("front.conn", "front", t0 + 1_000, t0 + 11_000,
+                           tts_ns=500_000, tdur_ns=3_000, span_id=conn_id,
+                           requests=1)
+    (wait,), (conn,) = _spans(tracer, "serve.wait"), _spans(tracer, "front.conn")
+    (around,) = _spans(tracer, "around")
+    assert (conn["id"], conn["parent"]) == (conn_id, 0)  # not "around"'s child
+    assert (conn["ts"], conn["dur"], conn["tts"], conn["tdur"]) \
+        == (1.0, 10.0, 500.0, 3.0)
+    assert conn["cat"] == "front" and conn["args"] == {"requests": 1}
+    assert wait["parent"] == conn_id and wait["id"] not in (0, conn_id)
+    assert (wait["ts"], wait["dur"], wait["tdur"]) == (2.0, 7.0, 0.0)
+    assert wait["args"] == {"lanes": 2, "trace_id": "ab" * 16,
+                            "parent_id": "cd" * 8}
+    assert wait["tid"] == conn["tid"] == around["tid"]
+    assert around["parent"] == 0 and not trace._ctx.stack
+
+
 def _probe_waits(seconds: float) -> list[float]:
     tracer = trace.enable(ring_size=4096)
     time.sleep(seconds)

@@ -14,8 +14,10 @@ wall under its threads' spans, CPU, off-core, seconds inside native
 calls with the GIL released (``native_us``) and seconds spent taking it
 back after them (``gil_us``): who holds the GIL. A family is told by
 its outermost spans (the front's are ``front.conn`` [requests,
-bytes_in, bytes_out], a connection thread's whole life; its ``tts`` is
-near zero, a thread's CPU clock starts with it). The last line is the
+bytes_in, bytes_out], a connection's whole life on the front's one
+thread; its ``tdur`` is the CPU of the connection's turns, summed, so
+the family's off-core seconds are mostly waits for a batch). The last
+line is the
 GIL probe's (``gil.probe`` [wait_us]): what a woken thread waited for it.
 
 Usage:
@@ -185,8 +187,8 @@ def stage_summary(events: list[dict], stages=None,
 
 # A thread family by what its outermost spans are called. Not by the
 # thread's name: the OS hands a dead thread's ident to the next one, so
-# over a run one ``tid`` carries a downloader's name and then those of
-# fifty connection threads.
+# over a run one ``tid`` can carry a downloader's name and then those
+# of later threads.
 ROOT_FAMILIES = (("downloader", ("fetch.", "round.")),
                  ("store", ("ingest.", "sink.")),
                  ("batcher", ("serve.batch",)),
