@@ -250,9 +250,9 @@ class PendingIngest:
         self._data = data
         self._length = length
         self._done = False
-        # Overlapped ingest completes pendings from a drain consumer
-        # thread while checkpoint/grow paths may concurrently call
-        # complete_outstanding from the submit thread; the per-pending
+        # The store thread completes pendings in submission order
+        # while a report's drain or a checkpoint may call
+        # complete_outstanding from another thread; the per-pending
         # lock makes the race a cheap no-op for whoever loses it.
         self._lock = threading.Lock()
 
@@ -260,16 +260,15 @@ class PendingIngest:
         with self._lock:
             if self._done:
                 return self._res
-            # Claimed BEFORE the fold (matching the pre-overlap
-            # semantics): a fold that raises must not be retried by a
-            # later completer — a partial fold re-applied would
-            # double-count.
+            # Claimed BEFORE the fold: a fold that raises must not be
+            # retried by a later completer — a partial fold re-applied
+            # would double-count.
             self._done = True
             agg = self._agg
             # All host-state fold-ins serialize on the aggregator-wide
             # fold lock (metrics, issuer_totals, host_serials, and the
             # cross-encoding guard are shared mutable state). FIFO order
-            # is preserved because every completer — the drain consumer
+            # is preserved because every completer — the sink's drain
             # and complete_outstanding alike — takes the OLDEST pending
             # first and blocks on its per-pending lock.
             with trace.span("device.fold", cat="device"), agg._fold_lock:
@@ -349,101 +348,6 @@ class PendingPreparsed:
                 agg._inflight_lanes = max(
                     0, agg._inflight_lanes - len(self._res.was_unknown))
                 agg._fold_preparsed(self._out, self._plan, self._res)
-                incr_counter("aggregator", "batches")
-            return self._res
-
-
-class _NpStagedChunkOut:
-    """One chunk's slice of a staged envelope readback, shaped like a
-    host-resident :class:`~ct_mapreduce_tpu.ops.pipeline.StepOut` so
-    ``_consume_out``'s NumPy branch folds it through the exact same
-    code path as the serial step (parity by construction). ``packed``
-    is one ``int32[7, B]`` row of the envelope's ``[K, 7, B]`` packed
-    readback — the bit layout of ``_pack_out``, assembled on device by
-    ``pipeline.pack_lane_words``."""
-
-    def __init__(self, packed_row: np.ndarray, serials: np.ndarray,
-                 issuer_unknown_counts: np.ndarray) -> None:
-        flags = packed_row[0]
-        self.host_lane = (flags & 1) != 0
-        self.was_unknown = ((flags >> 1) & 1) != 0
-        self.filtered_ca = ((flags >> 2) & 1) != 0
-        self.filtered_expired = ((flags >> 3) & 1) != 0
-        self.filtered_cn = ((flags >> 4) & 1) != 0
-        self.probe_overflow = ((flags >> 5) & 1) != 0
-        self.not_after_hour = packed_row[1]
-        self.serial_len = packed_row[2]
-        self.crldp_off = packed_row[3]
-        self.crldp_len = packed_row[4]
-        self.issuer_name_off = packed_row[5]
-        self.issuer_name_len = packed_row[6]
-        self.serials = serials
-        self.issuer_unknown_counts = issuer_unknown_counts
-
-
-class PendingStaged:
-    """Async half of :meth:`TpuAggregator.ingest_staged_submit` — one
-    K-chunk walker envelope. Same FIFO / claim-before-fold / fold-lock
-    contract as :class:`PendingIngest`, but the readback is the
-    envelope's ONE packed ``[K, 7, B]`` array (+ the summed issuer
-    counts, + the serial matrix only when the sink keeps PEMs) instead
-    of a packing jit + readback per chunk — and the fold then walks the
-    K chunks through the very same ``_consume_out``/``_host_lanes``
-    code the serial path uses."""
-
-    batch = 0  # as PendingIngest's
-
-    def __init__(self, agg: "TpuAggregator", out, chunks,
-                 res: IngestResult, chunk_width: int) -> None:
-        self._agg = agg
-        self._out = out  # pipeline.StagedStepOut
-        self._chunks = chunks  # [(batch, device_pos, lane_of)]
-        self._res = res
-        self._chunk_width = int(chunk_width)  # pos = k * width + lane
-        self._done = False
-        self._lock = threading.Lock()
-
-    def complete(self) -> IngestResult:
-        with self._lock:
-            if self._done:
-                return self._res
-            self._done = True
-            agg = self._agg
-            with trace.span("device.fold", cat="device"), agg._fold_lock:
-                with contextlib.suppress(ValueError):
-                    agg._outstanding.remove(self)
-                agg._inflight_lanes = max(
-                    0, agg._inflight_lanes - len(self._res.was_unknown))
-                res = self._res
-                with trace.span("fold.wait_device", cat="fold"):
-                    P = np.asarray(self._out.packed)  # the one packed read
-                counts = np.asarray(self._out.issuer_unknown_counts)
-                serials = (np.asarray(self._out.serials)
-                           if agg.want_serials else None)
-                nothing = np.zeros((0,), np.int32)
-                host_lane_total = 0
-                for k, (batch, device_pos, lane_of) in enumerate(
-                        self._chunks):
-                    out_k = _NpStagedChunkOut(
-                        P[k],
-                        serials[k] if serials is not None else P[k, 2:3],
-                        # Counts are device-summed across the envelope;
-                        # attribute them to the first chunk's fold (the
-                        # running totals are order-insensitive sums).
-                        counts if k == 0 else nothing,
-                    )
-                    host_pos = agg._consume_out(
-                        batch, out_k, device_pos, res, lane_of)
-                    host_lane_total += agg._host_lanes(
-                        host_pos,
-                        lambda pos, _b=batch, _k=k: _b.data[
-                            pos - _k * self._chunk_width,
-                            : _b.length[pos - _k * self._chunk_width],
-                        ].tobytes(),
-                        res,
-                    )
-                agg.metrics["host_lane"] += host_lane_total
-                res.host_lane_count = host_lane_total
                 incr_counter("aggregator", "batches")
             return self._res
 
@@ -559,8 +463,8 @@ class TpuAggregator:
         # HBM use).
         self.max_capacity = self._layout_capacity_floor(max_capacity)
         # Serializes host-state fold-ins (PendingIngest.complete /
-        # _consume_out / _host_lanes) across threads — the overlapped
-        # ingest path completes from a drain consumer thread.
+        # _consume_out / _host_lanes) across threads — a report's
+        # drain completes from a thread other than the store's.
         self._fold_lock = threading.Lock()
         # Guards self.table swaps vs concurrent reads: the donated step
         # invalidates the previous table buffer, so a contains probe or
@@ -866,8 +770,8 @@ class TpuAggregator:
         keeps exact counts either way."""
         self.complete_outstanding()
         t0 = time.perf_counter()
-        # Table lock taken only AFTER the completes above: a drain
-        # consumer mid-complete holds the fold lock and may probe the
+        # Table lock taken only AFTER the completes above: a thread
+        # mid-complete holds the fold lock and may probe the
         # table, so grabbing the table lock first would deadlock
         # (fold → table is the global order).
         with self._table_lock:
@@ -1289,8 +1193,8 @@ class TpuAggregator:
     def complete_outstanding(self) -> None:
         """Fold every un-completed submit into host state (FIFO). Any
         reader of aggregate state (drain, checkpoint) calls this first
-        so pipelining can never lose in-flight results. Robust to a
-        drain consumer thread completing (and removing) entries
+        so pipelining can never lose in-flight results. Robust to
+        another thread completing (and removing) entries
         concurrently — whoever loses the per-pending race no-ops."""
         while True:
             try:
@@ -1298,90 +1202,6 @@ class TpuAggregator:
             except IndexError:
                 return
             pending.complete()
-
-    # -- staged device queue (K-chunk walker envelope) -------------------
-    # True when the staged lane wants its row buffers shipped to the
-    # device ahead of the dispatch (the sink's staging ring device_puts
-    # the stacked [K, B, L] buffer at submit time so the transfer
-    # overlaps the previous envelope's compute). The mesh-sharded
-    # subclass overrides this to False: it takes the stacked rows as
-    # NumPy and puts each chunk's rows straight on their chips.
-    staged_h2d = True
-
-    def ingest_staged_submit(
-        self,
-        data,  # uint8[K, B, L] — device array (H2D enqueued) or np
-        length: np.ndarray,  # int32[K, B]
-        issuer_idx: np.ndarray,  # int32[K, B]
-        valid: np.ndarray,  # bool[K, B]
-        host_chunks: list[np.ndarray],  # per REAL chunk: uint8[n_k, L]
-    ) -> "PendingStaged":
-        """Dispatch ONE resident K-chunk walker envelope
-        (:func:`ct_mapreduce_tpu.ops.pipeline.staged_core`) without
-        reading anything back. Chunk ``k``'s lanes land at result
-        positions ``k * B + lane``; chunks past ``len(host_chunks)``
-        are all-invalid padding (the staging ring flushed early).
-        ``host_chunks`` keeps the caller's own host-resident rows alive
-        for host-lane slices and PEM folds — the device buffer may be
-        donated and the staging buffer recycled, so neither is read
-        after this call."""
-        k_chunks, b = length.shape
-        n = k_chunks * b
-        valid = np.asarray(valid, bool)
-        length = np.asarray(length, np.int32)
-        issuer_idx = np.asarray(issuer_idx, np.int32)
-        # Growth estimate counts the REAL chunks' lanes, not the
-        # all-invalid K-axis padding of a partial ring — a tail flush
-        # claiming K×B incoming lanes grew tables 4× early.
-        self.maybe_grow(incoming=sum(
-            int(c.shape[0]) for c in host_chunks))
-        self._inflight_lanes += n
-        res = IngestResult(
-            was_unknown=np.zeros((n,), bool),
-            filtered=np.zeros((n,), bool),
-            exp_hours=np.zeros((n,), np.int32),
-            serials=[None] * n,
-            issuer_idx=issuer_idx.reshape(n).copy(),
-        )
-        chunks = []
-        for k, rows in enumerate(host_chunks):
-            n_k = int(rows.shape[0])
-            batch = packing.PackedBatch(
-                rows, length[k, :n_k], issuer_idx[k, :n_k], valid[k, :n_k]
-            )
-            device_pos = k * b + np.flatnonzero(valid[k])
-            if len(device_pos) == b:
-                lane_of = None  # contiguous full chunk: lane == index
-            else:
-                lane_of = lambda pos, _k=k, _b=b: pos - _k * _b  # noqa: E731
-            chunks.append((batch, device_pos, lane_of))
-        out = self._device_step_staged(data, length, issuer_idx, valid)
-        pending = PendingStaged(self, out, chunks, res, chunk_width=b)
-        self._outstanding.append(pending)
-        return pending
-
-    def _device_step_staged(self, data, length, issuer_idx, valid):
-        self._device_written = True
-        import jax
-
-        # Donation picks by residency and backend exactly like the
-        # walker pair: device-resident rows (the staging ring enqueued
-        # their H2D) donate through the envelope so XLA recycles the
-        # buffer HBM; NumPy rows and the CPU backend (whose XLA can't
-        # alias these layouts and warns per dispatch) stay undonated.
-        step = (pipeline.ingest_step_staged_donated
-                if isinstance(data, jax.Array)
-                and jax.default_backend() != "cpu"
-                else pipeline.ingest_step_staged)
-        with trace.span("device.step_staged", cat="device",
-                        chunks=int(length.shape[0])), self._table_lock:
-            self.table, out = step(
-                self.table, data, length, issuer_idx, valid,
-                np.int32(self._now_hour()), np.int32(self.base_hour),
-                self._prefix_arr, self._prefix_lens,
-                max_probes=self.max_probes,
-            )
-        return out
 
     # -- pre-parsed ingest lane ------------------------------------------
     def ingest_preparsed(self, sidecar, issuer_idx, valid, host_rows,
@@ -1665,40 +1485,23 @@ class TpuAggregator:
         host-resident copy of the full padded rows (by global
         position): metadata windows slice it instead of reading the
         64 MB device batch back."""
-        if isinstance(out.host_lane, np.ndarray):
-            # Host-resident outputs (snapshot reader): direct views.
-            hl = out.host_lane
-            wu = np.array(out.was_unknown)
-            nah = np.asarray(out.not_after_hour)
-            slen = np.asarray(out.serial_len)
-            f_ca = np.asarray(out.filtered_ca)
-            f_exp = np.asarray(out.filtered_expired)
-            f_cn = np.asarray(out.filtered_cn)
-            ovf = np.asarray(out.probe_overflow)
-            d = getattr(out, "dispatch_dropped", None)
-            dropped = np.asarray(d) if d is not None else None
-            dp_off = np.asarray(out.crldp_off)
-            dp_len = np.asarray(out.crldp_len)
-            in_off = np.asarray(out.issuer_name_off)
-            in_len = np.asarray(out.issuer_name_len)
-        else:
-            # ONE device read for the twelve small fields (each
-            # separate buffer read is its own D2H round trip — see
-            # _pack_out). wu/etc. are fresh arrays, so the
-            # cross-encoding guard below may flip lanes freely.
-            with trace.span("fold.wait_device", cat="fold"):
-                P = np.asarray(_pack_out(out))
-            flags = P[0]
-            hl = (flags & 1) != 0
-            wu = ((flags >> 1) & 1) != 0
-            f_ca = ((flags >> 2) & 1) != 0
-            f_exp = ((flags >> 3) & 1) != 0
-            f_cn = ((flags >> 4) & 1) != 0
-            ovf = ((flags >> 5) & 1) != 0
-            dropped = (((flags >> 6) & 1) != 0
-                       if hasattr(out, "dispatch_dropped") else None)
-            nah, slen = P[1], P[2]
-            dp_off, dp_len, in_off, in_len = P[3], P[4], P[5], P[6]
+        # ONE device read for the twelve small fields (each separate
+        # buffer read is its own D2H round trip — see _pack_out).
+        # wu/etc. are fresh arrays, so the cross-encoding guard below
+        # may flip lanes freely.
+        with trace.span("fold.wait_device", cat="fold"):
+            P = np.asarray(_pack_out(out))
+        flags = P[0]
+        hl = (flags & 1) != 0
+        wu = ((flags >> 1) & 1) != 0
+        f_ca = ((flags >> 2) & 1) != 0
+        f_exp = ((flags >> 3) & 1) != 0
+        f_cn = ((flags >> 4) & 1) != 0
+        ovf = ((flags >> 5) & 1) != 0
+        dropped = (((flags >> 6) & 1) != 0
+                   if hasattr(out, "dispatch_dropped") else None)
+        nah, slen = P[1], P[2]
+        dp_off, dp_len, in_off, in_len = P[3], P[4], P[5], P[6]
         f_any = f_ca | f_exp | f_cn
         self.metrics["filtered_ca"] += int(f_ca.sum())
         self.metrics["filtered_expired"] += int(f_exp.sum())
@@ -2932,11 +2735,6 @@ class HostSnapshotAggregator(TpuAggregator):
             "use TpuAggregator/ShardedAggregator to ingest")
 
     def _device_step_preparsed(self, *args, **kwargs):
-        raise RuntimeError(
-            "HostSnapshotAggregator is read-only (reports); "
-            "use TpuAggregator/ShardedAggregator to ingest")
-
-    def _device_step_staged(self, *args, **kwargs):
         raise RuntimeError(
             "HostSnapshotAggregator is read-only (reports); "
             "use TpuAggregator/ShardedAggregator to ingest")

@@ -220,12 +220,6 @@ class ShardedAggregator(TpuAggregator):
     def _table_fill_exact(self) -> int:
         return self.dedup.total_count()
 
-    # The staging ring stacks K chunks into one [K, B, L] buffer and
-    # would put it on the default device; the mesh step wants each
-    # chunk's rows split over the chips instead, so the ring keeps its
-    # buffer on the host and every chunk is placed by put_rows.
-    staged_h2d = False
-
     def put_rows(self, data: np.ndarray):
         """One batch's rows from the host straight to their shards: row
         block i of n goes to chip i, which parses it (asynchronous, as
@@ -238,26 +232,6 @@ class ShardedAggregator(TpuAggregator):
             rows = jax.device_put(data, self.dedup.batch_sharding)
         incr_counter("shard", "row_bytes_h2d", value=float(data.nbytes))
         return rows
-
-    def ingest_staged_submit(self, data, length, issuer_idx, valid,
-                             host_chunks):
-        """Staged lane over the mesh: the fused single-chip envelope
-        doesn't apply (the walker step here is a shard_map program with
-        its own per-chunk dispatch), so the staging ring's K chunks
-        flatten into ONE :meth:`ingest_packed_submit` — per-chunk mesh
-        steps dispatched back to back with a single deferred fold, so
-        the sink-side contract (one pending per staged flush, drain
-        fully async) is identical across topologies."""
-        k_chunks, b = np.asarray(length).shape
-        if not isinstance(data, np.ndarray):  # a device envelope read back
-            incr_counter("shard", "row_bytes_d2h", value=float(data.nbytes))
-        flat = np.asarray(data).reshape(k_chunks * b, -1)
-        return self.ingest_packed_submit(
-            flat,
-            np.asarray(length, np.int32).reshape(-1),
-            np.asarray(issuer_idx, np.int32).reshape(-1),
-            np.asarray(valid, bool).reshape(-1),
-        )
 
     def _device_step_preparsed(self, serials, serial_len, nah,
                                issuer_idx, insertable, flag_cap: int):

@@ -13,8 +13,8 @@ same statement and is safe).
 
 Donating callables are recognized by name (``*_donated``), including
 locals aliased from them — the aggregator's backend-conditional
-``step = (pipeline.ingest_step_staged_donated if ... else
-pipeline.ingest_step_staged)`` donates on real devices, so the alias
+``step = (pipeline.ingest_step_preparsed if ... else
+pipeline.ingest_step_preparsed_donated)`` donates on real devices, so the alias
 is treated as donating (the conservative branch is the one that
 bites). Donated positions come from :data:`DONATED_ARGNUMS`; unknown
 ``*_donated`` names default to position 0 (the table-first
@@ -34,7 +34,6 @@ from ct_mapreduce_tpu.analysis.engine import Checker, Ctx
 DONATED_ARGNUMS: dict[str, tuple[int, ...]] = {
     "ingest_step_donated": (0, 1),
     "ingest_step_preparsed_donated": (0,),
-    "ingest_step_staged_donated": (0, 1),
 }
 DEFAULT_ARGNUMS: tuple[int, ...] = (0,)
 

@@ -87,8 +87,6 @@ LOCKS: tuple[LockDecl, ...] = (
              "claim-before-fold; acquires agg.fold inside"),
     LockDecl("agg.pending", "ct_mapreduce_tpu/agg/aggregator.py",
              "PendingPreparsed", "_lock", 30, "same role, preparsed lane"),
-    LockDecl("agg.pending", "ct_mapreduce_tpu/agg/aggregator.py",
-             "PendingStaged", "_lock", 30, "same role, staged lane"),
     LockDecl("verify.keys", "ct_mapreduce_tpu/verify/lane.py",
              "LogKeyRegistry", "_lock", 36, "trust-anchor map"),
     LockDecl("agg.fold", "ct_mapreduce_tpu/agg/aggregator.py",
@@ -99,7 +97,7 @@ LOCKS: tuple[LockDecl, ...] = (
              "table swaps vs concurrent reads (RLock: grow re-enters)"),
     LockDecl("ingest.pem", "ct_mapreduce_tpu/ingest/sync.py",
              "AggregatorSink", "_pem_lock", 48,
-             "durable PEM tree writes (overlap drain vs per-entry path)"),
+             "durable PEM tree writes (store workers vs per-entry path)"),
     # -- storage backends (inside the ingest chain via _store_pems) ------
     LockDecl("storage.certdb_meta", "ct_mapreduce_tpu/storage/certdb.py",
              "FilesystemDatabase", "_meta_lock", 52,
@@ -127,14 +125,6 @@ LOCKS: tuple[LockDecl, ...] = (
     LockDecl("fleet.service", "ct_mapreduce_tpu/ingest/fleet.py",
              "FleetService", "_lock", 74,
              "claims/partition/errors; released before fabric calls"),
-    LockDecl("overlap.exc", "ct_mapreduce_tpu/ingest/overlap.py",
-             "OverlapIngestPipeline", "_exc_lock", 76, "first-failure latch"),
-    LockDecl("overlap.busy", "ct_mapreduce_tpu/ingest/overlap.py",
-             "OverlapIngestPipeline", "_busy_lock", 78,
-             "per-stage busy accounting"),
-    LockDecl("overlap.highwater", "ct_mapreduce_tpu/ingest/overlap.py",
-             "OverlapIngestPipeline", "_hw_lock", 80,
-             "queue-depth high-water marks"),
     LockDecl("serve.cache", "ct_mapreduce_tpu/serve/cache.py",
              "HotSerialCache", "_lock", 82, "hot-serial LRU"),
     LockDecl("distrib.store", "ct_mapreduce_tpu/distrib/publish.py",

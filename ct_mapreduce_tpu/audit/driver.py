@@ -141,7 +141,6 @@ class AuditDriver:
                  quarantine_dir: str = "",
                  capacity: int = 1 << 14, batch_size: int = 256,
                  flush_size: int = 256, batch_width: int = 0,
-                 chunks_per_dispatch: int = 0,
                  filter_path: str = "", filter_fp: float = 0.01,
                  aggregator=None, sink=None):
         from ct_mapreduce_tpu.agg.aggregator import TpuAggregator
@@ -159,8 +158,7 @@ class AuditDriver:
                                                       filter_fp)
         self.sink = sink or AggregatorSink(
             self.aggregator, flush_size=flush_size,
-            device_queue_depth=0, verify_signatures=True,
-            chunks_per_dispatch=chunks_per_dispatch)
+            device_queue_depth=0, verify_signatures=True)
         if batch_width:
             self.sink.verifier.batch_width = batch_width
         for shard in log_list.shards.values():

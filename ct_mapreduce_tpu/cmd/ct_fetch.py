@@ -128,10 +128,7 @@ def build_sink(config: CTConfig, database, backend=None):
                               device_queue_depth=config.device_queue_depth,
                               decode_workers=config.decode_workers,
                               decode_threads=config.decode_threads,
-                              overlap_workers=config.overlap_workers,
                               preparsed=config.preparsed_ingest or None,
-                              chunks_per_dispatch=config.chunks_per_dispatch,
-                              staging_depth=config.staging_depth,
                               verify_signatures=(config.verify_signatures
                                                  or None),
                               verify_log_keys=(config.verify_log_keys
@@ -515,7 +512,7 @@ def main(argv: list[str] | None = None) -> int:
 
     def healthz() -> dict:
         """The /healthz body: engine stage, last-progress timestamp,
-        and the overlap pipeline's bounded-queue depths."""
+        and the entry channel's depth."""
         updates = engine.last_updates()
         last = max(updates.values()).isoformat() if updates else None
         body = {
@@ -530,9 +527,6 @@ def main(argv: list[str] | None = None) -> int:
             "entry_queue_depth_unit": ("pages" if engine.raw_batches
                                        else "entries"),
         }
-        ovl = getattr(sink, "_overlap", None)
-        if ovl is not None:
-            body["overlap_queues"] = ovl.queue_depths()
         verifier = getattr(sink, "verifier", None)
         if verifier is not None:
             # Round 17: verify-lane knobs, outcome totals, and Q-table

@@ -33,7 +33,6 @@ class CTConfig:
     decode_workers: int = 0  # 0 = auto (cpu count); raw-batch decode pool
     decode_threads: int = 0  # 0 = auto; intra-chunk native decode threads
     # (the persistent C++ worker pool; CTMR_DECODE_THREADS equivalent)
-    overlap_workers: int = 0  # >0 = overlapped ingest (decode‖device‖drain)
     preparsed_ingest: bool = False  # host sidecar extraction + walker-free
     # device step (CTMR_PREPARSED=1 equivalent; needs the native decoder)
     log_expired_entries: bool = False
@@ -60,12 +59,6 @@ class CTConfig:
     table_max_bits: int = 28  # growth ceiling; past it, spill to host lane
     mesh_shape: str = ""  # e.g. "data:4,expert:2"; empty = all devices on data
     device_queue_depth: int = 2
-    chunks_per_dispatch: int = 0  # K walker chunks per resident device
-    # envelope (staged device queue); 0 = CTMR_CHUNKS_PER_DISPATCH env,
-    # then 1 (legacy per-chunk dispatch)
-    staging_depth: int = 0  # staged envelopes in flight before the
-    # submit side blocks (H2D double-buffer depth); 0 =
-    # CTMR_STAGING_DEPTH env, then 2
     agg_state_path: str = ""  # .npz snapshot of device aggregates (tpu backend)
     profile_dir: str = ""  # jax.profiler trace output dir (empty = off)
     trace_path: str = ""  # Chrome trace-event JSON of the ingest spans
@@ -163,7 +156,6 @@ class CTConfig:
         "numThreads": ("num_threads", int),
         "decodeWorkers": ("decode_workers", int),
         "decodeThreads": ("decode_threads", int),
-        "overlapWorkers": ("overlap_workers", int),
         "preparsedIngest": ("preparsed_ingest", bool),
         "logExpiredEntries": ("log_expired_entries", bool),
         "runForever": ("run_forever", bool),
@@ -187,8 +179,6 @@ class CTConfig:
         "tableMaxBits": ("table_max_bits", int),
         "meshShape": ("mesh_shape", str),
         "deviceQueueDepth": ("device_queue_depth", int),
-        "chunksPerDispatch": ("chunks_per_dispatch", int),
-        "stagingDepth": ("staging_depth", int),
         "aggStatePath": ("agg_state_path", str),
         "profileDir": ("profile_dir", str),
         "tracePath": ("trace_path", str),
@@ -355,7 +345,6 @@ class CTConfig:
             "decodeThreads = intra-chunk native decode/sidecar threads "
             "(0 = CTMR_DECODE_THREADS, then cpu count; workers x threads "
             "should stay <= host cores)",
-            "overlapWorkers = overlapped-ingest decode pool size (0 = serial dispatch)",
             "preparsedIngest = host sidecar extraction + walker-free device step",
             "savePeriod = Duration between state saves, e.g. 15m",
             "logList = URLs of the CT Logs, comma delimited",
@@ -374,12 +363,6 @@ class CTConfig:
             "tableMaxBits = log2 growth ceiling; beyond it lanes spill to the exact host lane",
             "meshShape = device mesh, e.g. data:4,expert:2",
             "deviceQueueDepth = host->device prefetch depth",
-            "chunksPerDispatch = walker chunks fused into one resident "
-            "device envelope (staged device queue; 1 = per-chunk "
-            "dispatch, CTMR_CHUNKS_PER_DISPATCH equivalent)",
-            "stagingDepth = staged envelopes in flight before the "
-            "submit side blocks (H2D double-buffer depth, "
-            "CTMR_STAGING_DEPTH equivalent)",
             "aggStatePath = Path for the on-device aggregate snapshot (.npz)",
             "profileDir = Write a jax.profiler trace of the run here",
             "tracePath = Write a Chrome trace-event JSON of the ingest "

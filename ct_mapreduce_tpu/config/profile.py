@@ -19,8 +19,7 @@ A profile is a JSON file (the ``platformProfile`` directive or the
 
     {"version": 1,
      "platform": "tpu-v5e-8",
-     "knobs": {"staging": {"chunksPerDispatch": 8, "stagingDepth": 3},
-               "serve":   {"serveReplicas": 4},
+     "knobs": {"serve":   {"serveReplicas": 4},
                "verify":  {"verifyPrecompWindow": 16},
                "fleet":   {"numWorkers": 4},
                "filter":  {"filterFpRate": 0.005},
@@ -28,8 +27,8 @@ A profile is a JSON file (the ``platformProfile`` directive or the
 
 so a deployment can hand in a versioned data file and every subsystem
 picks its knobs up with zero code changes. Knob names inside a
-section are the directive spellings (``chunksPerDispatch``, not
-``chunks_per_dispatch``). Unknown sections/knobs are ignored (forward
+section are the directive spellings (``serveReplicas``, not
+``serve_replicas``). Unknown sections/knobs are ignored (forward
 compatibility); an unreadable profile warns once and resolves as if
 absent (the config layer's unparseable-value tolerance).
 

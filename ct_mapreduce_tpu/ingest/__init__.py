@@ -20,7 +20,6 @@ from ct_mapreduce_tpu.ingest.fleet import (
     partition_range,
 )
 from ct_mapreduce_tpu.ingest.leaf import DecodedEntry, decode_entry
-from ct_mapreduce_tpu.ingest.overlap import OverlapError, OverlapIngestPipeline
 from ct_mapreduce_tpu.ingest.sync import LogSyncEngine, LogWorker
 
 __all__ = [
@@ -32,8 +31,6 @@ __all__ = [
     "FleetService",
     "LogSyncEngine",
     "LogWorker",
-    "OverlapError",
-    "OverlapIngestPipeline",
     "partition_logs",
     "partition_map",
     "partition_range",

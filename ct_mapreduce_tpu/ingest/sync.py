@@ -54,37 +54,10 @@ from ct_mapreduce_tpu.ingest.leaf import (
     decode_json_entry,
     leaf_timestamp_ms as decode_leaf_timestamp,
 )
-from ct_mapreduce_tpu.config import profile as platprofile
 from ct_mapreduce_tpu.native.leafpack import EntryPage, StrPage
-from ct_mapreduce_tpu.telemetry import metrics, trace
+from ct_mapreduce_tpu.telemetry import flight, metrics, trace
 
 ENTRY_QUEUE_CAPACITY = 16384  # ct-fetch.go:132
-
-_STAGING_KNOBS = (
-    platprofile.Knob("chunksPerDispatch", "CTMR_CHUNKS_PER_DISPATCH", 1,
-                     parse=int, is_set=platprofile.pos_int,
-                     post=lambda v: max(1, int(v))),
-    platprofile.Knob("stagingDepth", "CTMR_STAGING_DEPTH", 2,
-                     parse=int, is_set=platprofile.pos_int,
-                     post=lambda v: max(1, int(v))),
-)
-
-
-def resolve_staging(chunks_per_dispatch: int = 0,
-                    staging_depth: int = 0) -> tuple[int, int]:
-    """Resolve the staged-device-queue knobs through the shared
-    platformProfile ladder (config/profile.py): explicit value (config
-    directive / kwarg) > ``CTMR_CHUNKS_PER_DISPATCH`` /
-    ``CTMR_STAGING_DEPTH`` env > profile ``knobs.staging`` > defaults
-    (K=1 — legacy per-chunk dispatch; depth 2 — double buffer).
-    Unparseable env values are ignored, matching the config layer's
-    tolerance."""
-    r = platprofile.resolve_section("staging", _STAGING_KNOBS, {
-        "chunksPerDispatch": int(chunks_per_dispatch or 0),
-        "stagingDepth": int(staging_depth or 0),
-    })
-    return r["chunksPerDispatch"], r["stagingDepth"]
-
 
 def _resolve_verify_lazy(flag, keys_path, window=None, qtable_size=0):
     """Import-light wrapper around ``verify.lane.resolve_verify`` —
@@ -181,9 +154,7 @@ class AggregatorSink:
 
     def __init__(self, aggregator, flush_size: int = 4096, backend=None,
                  device_queue_depth: int = 2, decode_workers: int = 0,
-                 overlap_workers: int = 0, preparsed: Optional[bool] = None,
-                 decode_threads: int = 0, chunks_per_dispatch: int = 0,
-                 staging_depth: int = 0,
+                 preparsed: Optional[bool] = None, decode_threads: int = 0,
                  verify_signatures: Optional[bool] = None,
                  verify_log_keys: Optional[str] = None,
                  verify_precomp_window: Optional[int] = None,
@@ -195,18 +166,19 @@ class AggregatorSink:
         # reference writes (filesystemdatabase.go:189-208).
         self.backend = backend
         self._allocated: set[tuple[str, str]] = set()
-        self._pem_lock = threading.Lock()  # overlap drains from a thread
+        self._pem_lock = threading.Lock()  # store workers + per-entry path
         self._pending: list[tuple[bytes, bytes]] = []
         self._pending_raw = _RawChunk()
         self._batch_seq = 0  # raw chunks cut so far; under _lock
         self._lock = threading.Lock()
         # Cuts taken from the accumulators and not yet handed to the
-        # device (or the overlap scheduler): neither pending nor in
-        # flight, so a flush waits for those older than itself
-        # (_handing_over). Numbered in the order cut; under _lock.
+        # device: neither pending nor in flight, so a flush waits for
+        # those older than itself (_handing_over). Numbered in the
+        # order cut; under _lock.
         self._cut_seq = 0
         self._open_cuts: set[int] = set()
         self._cut_handed = threading.Condition(self._lock)
+        self._failed = False  # a failure left its flight dump; under _lock
         self._dispatch_lock = threading.Lock()  # one device stream
         # Host↔device pipelining (deviceQueueDepth, SURVEY §2.2 PP row;
         # the reference overlaps download and store with goroutines + a
@@ -223,10 +195,7 @@ class AggregatorSink:
         # CTMR_DECODE_THREADS): the persistent C++ worker pool splits
         # each chunk's decode, row pack, and sidecar extraction over
         # lane ranges. 0 = leafpack auto (env, then cpu count). This is
-        # the knob that makes ONE chunk's host feed scale with cores;
-        # `overlapWorkers` pipelines ACROSS chunks on top of it
-        # (workers × threads should stay ≤ host cores, see
-        # ingest/overlap.py).
+        # the knob that makes ONE chunk's host feed scale with cores.
         self.decode_threads = int(decode_threads) or None
         self._inflight: deque = deque()  # (PendingIngest, der_of)
         # Without a PEM backend the per-entry serial bytes are only
@@ -238,12 +207,6 @@ class AggregatorSink:
             backend is not None
             or getattr(aggregator, "filter_capture", None) is not None)
         self.entries_in = 0
-        # Overlapped ingest (overlapWorkers > 0): raw chunks route
-        # through a three-stage scheduler — decode pool ‖ ordered
-        # device submit ‖ bounded drain consumer — instead of the
-        # caller-thread decode→submit→drain sequence above. Exact
-        # same decode/submit/complete primitives, so results are
-        # parity-identical; only the threading changes.
         # Pre-parsed ingest lane (CTMR_PREPARSED=1 / preparsedIngest
         # directive): the native decoder's sidecar extraction replaces
         # the on-device DER walk — the device step runs fingerprint +
@@ -258,20 +221,6 @@ class AggregatorSink:
 
             preparsed = os.environ.get("CTMR_PREPARSED", "0") == "1"
         self.preparsed = bool(preparsed)
-        # Staged device queue (round 11): `chunksPerDispatch` (K) > 1
-        # routes walker-lane chunks through a staging ring — K decoded
-        # chunks stack into one pinned host buffer, ship in ONE H2D
-        # put, and run as ONE resident K-chunk device envelope
-        # (pipeline.staged_core), dividing the per-dispatch Python +
-        # readback toll by K. `stagingDepth` bounds envelopes that are
-        # submitted-but-unfolded (the double-buffer depth). Explicit
-        # kwarg > CTMR_CHUNKS_PER_DISPATCH / CTMR_STAGING_DEPTH env >
-        # defaults (K=1 → legacy per-chunk dispatch; depth 2).
-        self.chunks_per_dispatch, self.staging_depth = resolve_staging(
-            chunks_per_dispatch, staging_depth)
-        self._staging: list[_PreparedChunk] = []  # the ring (FIFO)
-        self._staging_hw = 0  # high-water occupancy
-        self._staging_bufs: dict[tuple, tuple] = {}  # (K,B,L) → (bufs, idx)
         # Signature-verification lane (round 13): `verifySignatures`
         # directive / CTMR_VERIFY env. Each decoded chunk additionally
         # runs the native SCT extraction pass; P-256-keyed SCTs batch
@@ -299,19 +248,6 @@ class AggregatorSink:
             self.verifier = SignatureVerifier(
                 aggregator, keys, batch_width=v_batch,
                 window=v_window, qtable_size=v_qsize)
-        self.overlap_workers = max(0, int(overlap_workers))
-        self._overlap = None
-        if self.overlap_workers:
-            from ct_mapreduce_tpu.ingest.overlap import OverlapIngestPipeline
-
-            self._overlap = OverlapIngestPipeline(
-                self, decode_workers=self.overlap_workers,
-                # In staged mode the drain bound counts ENVELOPES, and
-                # stagingDepth is that double-buffer depth.
-                queue_depth=(self.staging_depth
-                             if self.chunks_per_dispatch > 1
-                             else max(1, self.device_queue_depth)),
-            )
 
     def store(self, entry: DecodedEntry, log_url: str) -> None:
         if entry.issuer_der is None:
@@ -363,22 +299,34 @@ class AggregatorSink:
         return self._cut_seq
 
     @contextlib.contextmanager
+    def _post_mortem(self):
+        """Around a dispatch or a fold: one that raises leaves the
+        flight dump (trace ring + metric snapshots; no-op without a
+        recorder) before the exception goes on to the caller. A store
+        thread catches and reports it, so the excepthook never sees
+        it. The sink's first failure only: what fails after it is as a
+        rule the same fault again, and one artifact tells it."""
+        try:
+            yield
+        except Exception as err:
+            with self._lock:
+                first, self._failed = not self._failed, True
+            if first:
+                flight.dump(f"ingest dispatch failure: {err!r}")
+            raise
+
+    @contextlib.contextmanager
     def _handing_over(self, cut: int):
         """Around the dispatch of cut ``cut``, however it ends."""
         try:
-            yield
+            with self._post_mortem():
+                yield
         finally:
             with self._cut_handed:
                 self._open_cuts.discard(cut)
                 self._cut_handed.notify_all()
 
     def _dispatch_raw(self, chunk: "_RawChunk") -> None:
-        if self._overlap is not None:
-            # Overlapped mode: the chunk enters the three-stage
-            # scheduler; decode happens on its pool, submission on its
-            # ordered submit thread, completion on its drain consumer.
-            self._overlap.submit_chunk(chunk)
-            return
         with trace.span("ingest.decode", cat="ingest", entries=len(chunk),
                         batch=chunk.batch):
             prep = self._prepare_chunk(chunk)
@@ -386,27 +334,14 @@ class AggregatorSink:
         with trace.span("ingest.submit_locked", cat="ingest",
                         batch=chunk.batch), self._dispatch_lock:
             # Lock wait sampled apart from the storeCertificate
-            # envelope (see ingest/overlap.py's submit loop): multiple
-            # store workers contend here, and the wait is not submit
-            # work.
+            # envelope: multiple store workers contend here, and the
+            # wait is not submit work.
             metrics.add_sample("ct-fetch", "dispatchLockWait",
                                value=time.monotonic() - t_lock)
             with metrics.measure("ct-fetch", "storeCertificate"), \
                     trace.span("ingest.submit", cat="ingest"):
-                self._dispatch_prepared(prep)
-
-    def _dispatch_prepared(self, prep: "_PreparedChunk") -> None:
-        for item in self._submit_chunk(prep):
-            if item[0] == "pending":
-                self._inflight.append((item[1], item[2]))
-            else:  # oversized-lane result: fold PEMs immediately
-                self._store_pems(item[1], item[2])
-        # Staged mode counts in-flight ENVELOPES against stagingDepth
-        # (the double-buffer bound); the legacy per-chunk path keeps
-        # deviceQueueDepth semantics.
-        self._drain_inflight(self.staging_depth
-                             if self.chunks_per_dispatch > 1
-                             else self.device_queue_depth)
+                self._submit_chunk(prep)
+                self._drain_inflight(self.device_queue_depth)
 
     def _prepare_chunk(self, chunk: "_RawChunk") -> "_PreparedChunk":
         """Stage 1 — decode + pack + H2D submit, NO aggregator-state
@@ -537,8 +472,8 @@ class AggregatorSink:
 
         # Signature-verification lane: one more native pass over the
         # packed rows extracts embedded-SCT tuples. Runs on the decode
-        # stage (overlap-friendly); classification and dispatch happen
-        # at submit time under the dispatch lock. The eligible set is
+        # stage; classification and dispatch happen at submit time
+        # under the dispatch lock. The eligible set is
         # the decoded-OK + issuer-mapped lanes BEFORE the sidecar
         # split below — walker-fallback lanes still carry auditable
         # SCTs. (Oversized certs never reach packed rows; their rare
@@ -590,20 +525,16 @@ class AggregatorSink:
 
         # Start the H2D transfer of the big byte rows BEFORE taking the
         # dispatch lock: device_put enqueues asynchronously, so the
-        # transfer of batch N+1 overlaps the device step of batch N
-        # (the decode half of the overlap comes from the decode stage
-        # running ahead of the submit stage). Small arrays stay
-        # host-side — the aggregator reads them for bookkeeping. Tail
-        # chunks (not a multiple of the compiled batch shape) take the
-        # NumPy path: their padding copy happens host-side in the
-        # aggregator. The pre-parsed lane never transfers rows at all
-        # (its device inputs are the compact per-lane fields).
+        # transfer of batch N+1 overlaps the device step of batch N.
+        # Small arrays stay host-side — the aggregator reads them for
+        # bookkeeping. Tail chunks (not a multiple of the compiled
+        # batch shape) take the NumPy path: their padding copy happens
+        # host-side in the aggregator. The pre-parsed lane never
+        # transfers rows at all (its device inputs are the compact
+        # per-lane fields).
         data_host = data
         if (sidecar is None and valid.any()
-                and self.chunks_per_dispatch <= 1
                 and data.shape[0] % self.aggregator.batch_size == 0):
-            # Staged mode skips the per-chunk put: the staging ring
-            # ships the stacked [K, B, L] buffer in one H2D instead.
             # Where the rows go is the aggregator's to say: one chip's
             # default device, or each row block straight to its chip of
             # the mesh (the only time the rows cross to the device).
@@ -632,163 +563,14 @@ class AggregatorSink:
             prep.host_data, prep.length,
         )
 
-    # -- staged device queue (round 11) ----------------------------------
-    def _submit_staged(self, prep: "_PreparedChunk") -> list[tuple]:
-        """Staged walker lane: enqueue the prepared chunk into the
-        staging ring; every K chunks the ring stacks into one pinned
-        host buffer, ships in ONE H2D put, and dispatches as ONE
-        resident K-chunk envelope. Caller holds ``_dispatch_lock`` (the
-        ring is only ever touched under it)."""
-        items: list[tuple] = []
-        ring = self._staging
-        # Ring chunks must share a row width (the narrow/wide
-        # pre-decode bucketing can alternate): a mismatch flushes
-        # what's staged before the new chunk enters.
-        if ring and prep.valid.any() and (
-                ring[0].host_data.shape[1] != prep.host_data.shape[1]):
-            items += self._flush_staging_items()
-        # Chunks carrying host-exact entries (oversized certs, rare
-        # walker-undecidable sidecar lanes) dispatch immediately:
-        # ring-flush → stage → flush again, so the serial path's
-        # intra-chunk order (device lanes, then fallback, then
-        # oversized) — and with it the dedup attribution — is
-        # preserved exactly.
-        host_exact = bool(prep.oversized or prep.walker_fallback)
-        if host_exact:
-            items += self._flush_staging_items()
-        if prep.valid.any():
-            ring.append(prep)
-            depth = len(ring)
-            if depth > self._staging_hw:
-                self._staging_hw = depth
-            metrics.set_gauge("ingest", "staging_ring", value=float(depth))
-            if host_exact or depth >= self.chunks_per_dispatch:
-                items += self._flush_staging_items()
-        if prep.walker_fallback:
-            fb = prep.walker_fallback
-            res_fb = self.aggregator.ingest(fb)
-            items.append(("result", res_fb, lambda pos, _o=fb: _o[pos][0]))
-        if prep.oversized:
-            oversized = prep.oversized
-            res_over = self.aggregator.ingest(oversized)
-            items.append((
-                "result", res_over, lambda pos, _o=oversized: _o[pos][0],
-            ))
-        metrics.incr_counter(
-            "ct-fetch", "insertCertificate",
-            value=float(int(prep.valid.sum()) + len(prep.oversized)
-                        + len(prep.walker_fallback)),
-        )
-        return items
-
-    def _staging_buffer(self, k: int, b: int, width: int) -> np.ndarray:
-        """One of the cycled pinned host staging buffers for this
-        envelope shape. ``stagingDepth`` bounds envelopes in flight, so
-        ``stagingDepth + 2`` buffers guarantee a buffer is only reused
-        after the envelope that shipped from it has been folded (its
-        transfer long since complete)."""
-        key = (k, b, width)
-        bufs, idx = self._staging_bufs.get(key, ([], -1))
-        if len(bufs) < self.staging_depth + 2:
-            bufs.append(np.zeros((k, b, width), np.uint8))
-            idx = len(bufs) - 1
-        else:
-            idx = (idx + 1) % len(bufs)
-        self._staging_bufs[key] = (bufs, idx)
-        return bufs[idx]
-
-    def _flush_staging_items(self) -> list[tuple]:
-        """Dispatch the staging ring as one resident envelope (no-op on
-        an empty ring). Caller holds ``_dispatch_lock``. A partial ring
-        (final flush, host-exact chunk, shape change) pads the K axis
-        with all-invalid chunks so the envelope keeps its compiled
-        shape."""
-        ring, self._staging = self._staging, []
-        if not ring:
-            return []
-        k_env = self.chunks_per_dispatch
-        k_real = len(ring)
-        b = max(p.host_data.shape[0] for p in ring)
-        width = ring[0].host_data.shape[1]
-        agg = self.aggregator
-        # The mesh-sharded aggregator takes the stacked rows as NumPy
-        # (staged_h2d is False there) and places each chunk on its
-        # chips itself, asynchronously: the buffer must be fresh per
-        # envelope, not a recycled one.
-        reuse = getattr(agg, "staged_h2d", True)
-        buf = (self._staging_buffer(k_env, b, width) if reuse
-               else np.zeros((k_env, b, width), np.uint8))
-        length = np.zeros((k_env, b), np.int32)
-        issuer_idx = np.zeros((k_env, b), np.int32)
-        valid = np.zeros((k_env, b), bool)
-        host_chunks: list[np.ndarray] = []
-        for k, p in enumerate(ring):
-            n_k = p.host_data.shape[0]
-            buf[k, :n_k] = p.host_data
-            # Stale rows past n_k (buffer reuse) are harmless — their
-            # lanes stay invalid and the fold never reads them.
-            length[k, :n_k] = p.length
-            issuer_idx[k, :n_k] = p.issuer_idx
-            valid[k, :n_k] = p.valid
-            host_chunks.append(p.host_data)
-        metrics.set_gauge("ingest", "staging_ring", value=0.0)
-        metrics.add_sample("ingest", "dispatch_chunks", value=float(k_real))
-        data = buf
-        if reuse:
-            import jax
-
-            # H2D of the whole envelope, enqueued BEFORE the dispatch:
-            # device_put is asynchronous on accelerator backends, so
-            # this transfer rides alongside the previous envelope's
-            # compute; block_until_ready never runs on the submit side.
-            with trace.span("ingest.h2d", cat="ingest", chunks=k_real,
-                            bytes=int(buf.nbytes)), \
-                    metrics.measure("ct-fetch", "h2dSubmit"):
-                data = jax.device_put(buf)
-            metrics.incr_counter("ingest", "h2d_bytes",
-                                 value=float(buf.nbytes))
-        pending = agg.ingest_staged_submit(
-            data, length, issuer_idx, valid, host_chunks)
-        pending.batch = ring[0].batch  # an envelope folds as its first
-        decs = [p.dec for p in ring]
-
-        def der_of(pos, _decs=decs, _b=b):
-            k, j = divmod(pos, _b)
-            d = _decs[k]
-            return d.data[j, : d.length[j]].tobytes()
-
-        return [("pending", pending, der_of)]
-
-    def staging_depths(self) -> dict[str, int]:
-        """Staging-ring occupancy for ``/healthz`` (merged into the
-        overlap pipeline's ``queue_depths``): a ring pinned below K
-        while the drain is saturated is the drain-starvation signature
-        the prepared/drain gauges alone can't show."""
-        if self.chunks_per_dispatch <= 1:
-            return {}
-        return {
-            "staging_ring": len(self._staging),
-            "staging_ring_capacity": self.chunks_per_dispatch,
-            "staging_ring_highwater": self._staging_hw,
-        }
-
-    def _submit_chunk(self, prep: "_PreparedChunk") -> list[tuple]:
+    def _submit_chunk(self, prep: "_PreparedChunk") -> None:
         """Stage 2 — dispatch the device step(s) for a prepared chunk.
         Caller MUST hold ``_dispatch_lock`` (one device stream; the
-        donated table state serializes submissions). Returns drain
-        items: ``("pending", PendingIngest, der_of)`` entries whose
-        ``complete()`` is stage 3, and ``("result", IngestResult,
-        der_of)`` entries (the rare oversized exact lane, already
-        complete) that only need PEM folding.
-
-        With ``chunksPerDispatch`` > 1 the walker lane detours through
-        the staging ring (``_submit_staged``): a chunk may return no
-        drain items (staged, awaiting ring mates) or one pending
-        covering a whole K-chunk envelope."""
+        donated table state serializes submissions). The pending goes
+        in flight (its ``complete()`` is stage 3); the rare exact
+        lanes (sidecar-undecidable, oversized) come back complete and
+        only need their PEMs folded."""
         self._submit_verify(prep)
-        if self.chunks_per_dispatch > 1 and prep.sidecar is None:
-            return self._submit_staged(prep)
-        items: list[tuple] = []
         if prep.valid.any():
             if prep.sidecar is not None:
                 pending = self.aggregator.ingest_preparsed_submit(
@@ -802,28 +584,19 @@ class AggregatorSink:
                 )
             pending.batch = prep.batch
             dec = prep.dec
-            items.append((
-                "pending", pending,
+            self._inflight.append((
+                pending,
                 lambda pos, _d=dec: _d.data[pos, : _d.length[pos]].tobytes(),
             ))
-        if prep.walker_fallback:
-            fb = prep.walker_fallback
-            res_fb = self.aggregator.ingest(fb)
-            items.append((
-                "result", res_fb, lambda pos, _o=fb: _o[pos][0],
-            ))
-        if prep.oversized:
-            oversized = prep.oversized
-            res_over = self.aggregator.ingest(oversized)
-            items.append((
-                "result", res_over, lambda pos, _o=oversized: _o[pos][0],
-            ))
+        for exact in (prep.walker_fallback, prep.oversized):
+            if exact:
+                self._store_pems(self.aggregator.ingest(exact),
+                                 lambda pos, _o=exact: _o[pos][0])
         metrics.incr_counter(
             "ct-fetch", "insertCertificate",
             value=float(int(prep.valid.sum()) + len(prep.oversized)
                         + len(prep.walker_fallback)),
         )
-        return items
 
     def _complete_item(self, pending, der_of) -> None:
         """Stage 3 — block on one batch's device work and fold it.
@@ -869,29 +642,15 @@ class AggregatorSink:
         with self._cut_handed:
             self._cut_handed.wait_for(
                 lambda: not any(c < mine for c in self._open_cuts))
-        if self._overlap is not None:
-            # Barrier through the scheduler: every chunk handed to it is
-            # decoded, stepped, and folded before flush returns (and any
-            # stage failure surfaces here).
-            self._overlap.drain_all()
         # Same storeCertificate envelope as the dispatch path, so every
         # completeBatch sample is NESTED inside a storeCertificate
         # sample — a budget breakdown subtracts one from the other and
-        # flush-path completes must not skew it. (In overlap mode
-        # completes are NOT nested — they run on the drain thread.)
+        # flush-path completes must not skew it.
         t_lock = time.monotonic()
-        with self._dispatch_lock:
+        with self._post_mortem(), self._dispatch_lock:
             metrics.add_sample("ct-fetch", "dispatchLockWait",
                                value=time.monotonic() - t_lock)
             with metrics.measure("ct-fetch", "storeCertificate"):
-                # Serial staged mode: a partial ring must dispatch at
-                # the barrier (the overlap path flushed it on the
-                # submit thread inside drain_all above).
-                for item in self._flush_staging_items():
-                    if item[0] == "pending":
-                        self._inflight.append((item[1], item[2]))
-                    else:
-                        self._store_pems(item[1], item[2])
                 self._drain_inflight(0)
                 if self.verifier is not None:
                     # Barrier for the verify lane too: the partial
@@ -899,14 +658,8 @@ class AggregatorSink:
                     self.verifier.drain()
 
     def close(self) -> None:
-        """Flush, then stop the overlap scheduler's threads (no-op in
-        serial mode). The sink remains usable for serial dispatch."""
-        try:
-            self.flush()
-        finally:
-            if self._overlap is not None:
-                overlap, self._overlap = self._overlap, None
-                overlap.close()
+        """The last flush. The sink owns no thread, so it stays usable."""
+        self.flush()
 
     def checkpointed_save(self, save_fn) -> None:
         """Flush pending entries, then run ``save_fn`` while holding the
@@ -944,7 +697,7 @@ class AggregatorSink:
         from ct_mapreduce_tpu.core.types import ExpDate, Serial
 
         reg = self.aggregator.registry
-        with self._pem_lock:  # overlap drains + per-entry path may race
+        with self._pem_lock:  # store workers + per-entry path may race
             dirty_days: set[str] = set()
             for pos, sb in enumerate(result.serials):
                 if sb is None or result.filtered[pos]:

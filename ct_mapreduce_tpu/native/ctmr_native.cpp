@@ -88,7 +88,7 @@ class WorkerPool {
       return;
     }
     // One parallel region at a time: the Python side may issue
-    // concurrent decode calls (overlap pipeline workers); the second
+    // concurrent decode calls (several store threads); the second
     // caller just runs serially rather than queueing behind the pool.
     std::unique_lock<std::mutex> region(run_mu_, std::try_to_lock);
     if (!region.owns_lock()) {

@@ -431,95 +431,6 @@ def preparsed_core(
     return table, PreparsedStepOut(packed=packed, overflow_bits=ovf_bits)
 
 
-# -- staged multi-chunk walker envelope ---------------------------------
-#
-# The resident device loop of the staged ingest queue (round 11): K
-# walker chunks run in ONE jitted execution (fori_loop over
-# `ingest_core`, extending the `preparsed_core` K-chunk pattern to the
-# full walker path). Per-lane outputs come back PRE-PACKED — the same
-# int32[7, B] layout the aggregator's `_pack_out` builds per chunk, but
-# assembled on device inside the envelope, so the host reads ONE
-# [K, 7, B] array per dispatch instead of running a packing jit + a
-# readback per chunk. Per-issuer fresh-insert counts accumulate across
-# the K chunks on device (one [num_issuers] vector per dispatch, not
-# K). K chunks per dispatch divides the D2H reads and the Python
-# dispatch overhead by K.
-
-class StagedStepOut(NamedTuple):
-    """Device outputs of the K-chunk walker envelope."""
-
-    packed: jax.Array  # int32[K, 7, B] — per chunk: row 0 = lane flag
-    # word (host_lane | was_unknown<<1 | filtered_ca<<2 |
-    # filtered_expired<<3 | filtered_cn<<4 | probe_overflow<<5 — the
-    # `_pack_out` bit layout), rows 1-6 = not_after_hour, serial_len,
-    # crldp_off, crldp_len, issuer_name_off, issuer_name_len.
-    issuer_unknown_counts: jax.Array  # int32[num_issuers] — summed
-    # across the K chunks (fold order is insensitive to the split).
-    serials: jax.Array  # uint8[K, B, MAX_SERIAL_BYTES]
-
-
-def pack_lane_words(out: StepOut) -> jax.Array:
-    """One chunk's per-lane outputs as int32[7, B] — the readback
-    layout shared with the aggregator's host-side unpack (and its
-    per-chunk `_pack_out` twin for the unstaged path)."""
-    flags = (
-        out.host_lane.astype(jnp.int32)
-        | (out.was_unknown.astype(jnp.int32) << 1)
-        | (out.filtered_ca.astype(jnp.int32) << 2)
-        | (out.filtered_expired.astype(jnp.int32) << 3)
-        | (out.filtered_cn.astype(jnp.int32) << 4)
-        | (out.probe_overflow.astype(jnp.int32) << 5)
-    )
-    return jnp.stack(
-        [flags, out.not_after_hour, out.serial_len,
-         out.crldp_off, out.crldp_len,
-         out.issuer_name_off, out.issuer_name_len], axis=0)
-
-
-def staged_core(
-    table,
-    data: jax.Array,  # uint8[K, B, L]
-    length: jax.Array,  # int32[K, B]
-    issuer_idx: jax.Array,  # int32[K, B]
-    valid: jax.Array,  # bool[K, B]
-    now_hour: jax.Array,
-    base_hour: jax.Array,
-    cn_prefixes: jax.Array,
-    cn_prefix_lens: jax.Array,
-    num_issuers: int = packing.MAX_ISSUERS,
-    max_probes: int = 32,
-) -> tuple:
-    """K full walker chunks in one resident loop. Each iteration is
-    exactly `ingest_core` — parse, filter, fingerprint, insert — so the
-    staged path is parity-identical with K sequential unstaged steps by
-    construction (the dedup table threads through the loop carry in
-    submission order)."""
-    k_chunks, b = length.shape
-    packed0 = jnp.zeros((k_chunks, 7, b), jnp.int32)
-    serials0 = jnp.zeros((k_chunks, b, packing.MAX_SERIAL_BYTES), jnp.uint8)
-    counts0 = jnp.zeros((num_issuers,), jnp.int32)
-
-    def body(k, carry):
-        table, packed, serials, counts = carry
-        table, out = ingest_core(
-            table, data[k], length[k], issuer_idx[k], valid[k],
-            now_hour, base_hour, cn_prefixes, cn_prefix_lens,
-            num_issuers=num_issuers, max_probes=max_probes,
-        )
-        return (
-            table,
-            packed.at[k].set(pack_lane_words(out)),
-            serials.at[k].set(out.serials),
-            counts + out.issuer_unknown_counts,
-        )
-
-    table, packed, serials, counts = jax.lax.fori_loop(
-        0, k_chunks, body, (table, packed0, serials0, counts0)
-    )
-    return table, StagedStepOut(
-        packed=packed, issuer_unknown_counts=counts, serials=serials)
-
-
 # The production entry point: donated table state, cached per shape.
 ingest_step = functools.partial(
     jax.jit,
@@ -541,9 +452,9 @@ ingest_step_preparsed_donated = functools.partial(
     donate_argnums=(0,),
 )(preparsed_core)
 
-# Overlapped-ingest entry point: donates the packed row buffer too.
-# The overlap scheduler hands the step a device-resident batch it will
-# never touch again (host-lane fallbacks slice the separate host copy),
+# Donates the packed row buffer too: the sink hands the step a
+# device-resident batch it will never touch again (host-lane fallbacks
+# slice the separate host copy),
 # so donating `data` lets XLA reuse ~batch-size HBM per in-flight batch
 # instead of holding the input rows live alongside the step's
 # intermediates — at deviceQueueDepth 2 that is two full batches of
@@ -556,22 +467,3 @@ ingest_step_donated = functools.partial(
     static_argnames=("num_issuers", "max_probes"),
     donate_argnums=(0, 1),
 )(ingest_core)
-
-# Staged-envelope entry points (donating and not, picked by backend
-# like the pairs above: CPU's XLA can't alias the donated layouts and
-# warns per dispatch). The donating form donates the TABLE and the
-# [K, B, L] row buffer — the staging ring keeps its own host copy for
-# host-lane slices, so after the dispatch the device rows are dead
-# weight and XLA reuses their HBM for the next staged buffer: that
-# reuse is what makes the two cycled staging buffers a true double
-# buffer instead of 2×K chunks of additional residency.
-ingest_step_staged = functools.partial(
-    jax.jit,
-    static_argnames=("num_issuers", "max_probes"),
-)(staged_core)
-
-ingest_step_staged_donated = functools.partial(
-    jax.jit,
-    static_argnames=("num_issuers", "max_probes"),
-    donate_argnums=(0, 1),
-)(staged_core)

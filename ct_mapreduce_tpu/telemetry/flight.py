@@ -10,8 +10,8 @@ The recorder keeps two rings in memory —
 - the last N metric snapshots (fed by ``MetricsDumper`` ticks and by
   explicit :func:`record_snapshot` calls)
 
-— and on demand (unhandled exception, SIGTERM/SIGUSR1, or the overlap
-pipeline latching a stage failure) dumps both plus a fresh metric
+— and on demand (unhandled exception, SIGTERM/SIGUSR1, or the sink's
+first failed dispatch) dumps both plus a fresh metric
 snapshot to a timestamped JSON file. Dumping is best-effort and
 re-entrant-safe: a recorder failure must never mask the crash it is
 documenting.
@@ -19,8 +19,9 @@ documenting.
 Install points: ``cmd/ct_fetch.py`` installs at startup and dumps from
 its own signal handlers / main-loop except clause (leaving no global
 hooks behind on return), ``engine.prepare_telemetry`` feeds dumper
-snapshots into the ring, and ``ingest/overlap.py`` dumps when a stage
-failure latches (``OverlapError``). The optional ``signals=True`` /
+snapshots into the ring, and ``ingest/sync.py::AggregatorSink`` dumps
+at its first dispatch or fold that raises (a store thread catches the
+exception, so no excepthook sees it). The optional ``signals=True`` /
 ``excepthook=True`` hooks are for long-lived embedders without their
 own handlers. Everything is a no-op until :func:`install` runs, so
 library users and tests see no files unless they opt in.
@@ -71,7 +72,7 @@ class FlightRecorder:
                 self.dir, f"ctmr-flight-{ts}-{os.getpid()}.json")
             with self._lock:
                 # A second dump in the same second (e.g. excepthook
-                # after an overlap latch) appends a suffix, not a
+                # after the sink's dump) appends a suffix, not a
                 # clobber.
                 if path in self.dumps:
                     path = os.path.join(

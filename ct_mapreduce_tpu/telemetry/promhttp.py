@@ -13,7 +13,7 @@ Prometheus text exposition format (version 0.0.4) —
   ``_sum``/``_count``
 
 — and ``/healthz`` as JSON: engine stage, last-progress timestamp, and
-the overlap pipeline's bounded-queue depths, the three numbers that
+the entry channel's depth, the three numbers that
 distinguish "healthy", "decode-starved", and "wedged" at a glance.
 
 No third-party client library: names are sanitized to the Prometheus

@@ -11,7 +11,7 @@ signature bytes are synthetic — which is exactly the contract of the
 ingest path, which parses and never verifies
 (/root/reference/cmd/ct-fetch/ct-fetch.go:198-226).
 
-Used by the overlapped-ingest tests and the benchmark's fixture so
+Used by the ingest tests and the benchmark's fixture so
 both run on any host; ``syncerts.make_template`` falls back to this
 builder when ``cryptography`` is missing, keeping the e2e legs alive
 there too. Issuer identity is SHA-256(SPKI), so each distinct

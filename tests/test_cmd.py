@@ -579,3 +579,56 @@ def test_ct_fetch_starts_query_plane(tmp_path, monkeypatch):
     rc = ct_fetch.main(["-config", str(ini), "-nobars"])
     assert rc == 0
     assert probed["health"]["healthy"] is True
+
+
+def test_healthz_body_of_a_tpu_run_has_its_ingest_keys(tmp_path, monkeypatch):
+    """``metricsPort`` on a plain ``backend = tpu`` run: the ``/healthz``
+    body says the engine's stage, its last progress, each log's
+    position and the entry channel's depth with its unit, and nothing
+    of a dispatch mode that does not exist (PR 46 removed the overlap
+    scheduler's ``overlap_queues`` and the staged ring's
+    ``staging_ring*``). Read as the endpoint serves it, just before
+    ct-fetch stops the server."""
+    import socket
+
+    from ct_mapreduce_tpu.telemetry import promhttp
+
+    log = _fake_log(n=5, dupes=1)
+    _patch_transport(monkeypatch, log)
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    probed = {}
+    orig_stop = promhttp.MetricsServer.stop
+
+    def spy_stop(self):
+        try:
+            probed["code"], probed["body"] = self.healthz()
+        finally:
+            orig_stop(self)
+
+    monkeypatch.setattr(promhttp.MetricsServer, "stop", spy_stop)
+    ini = tmp_path / "ct.ini"
+    ini.write_text(
+        f"logList = {log.url}\n"
+        "backend = tpu\n"
+        "batchSize = 64\n"
+        "tableBits = 12\n"
+        "meshShape = shard:1\n"
+        f"aggStatePath = {tmp_path / 'agg.npz'}\n"
+        f"metricsPort = {port}\n"
+        "healthAddr = \n"
+    )
+    assert ct_fetch.main(["-config", str(ini), "-nobars"]) == 0
+    body = probed["body"]
+    assert probed["code"] == 200 and body["healthy"] is True
+    assert set(body) == {"time", "healthy", "stage", "last_progress",
+                         "progress", "entry_queue_depth",
+                         "entry_queue_depth_unit"}
+    assert body["stage"] == "stopped"
+    assert body["last_progress"] is not None
+    (progress,) = body["progress"].values()
+    assert progress == {"pos": 5, "end": 4}  # next index, last index
+    assert body["entry_queue_depth"] == 0
+    assert body["entry_queue_depth_unit"] == "pages"
+    assert not [k for k in body if "overlap" in k or "staging" in k]

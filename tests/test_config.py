@@ -1,6 +1,8 @@
 """Config layering tests (reference: config/config_test.go:8-86 and
 config.go:183-214 precedence: defaults < ini < env < CLI flags)."""
 
+import pytest
+
 from ct_mapreduce_tpu.config import CTConfig
 
 
@@ -137,34 +139,37 @@ def test_observability_directives(tmp_path):
     assert "tracePath" in usage and "metricsPort" in usage
 
 
-def test_staged_queue_directives(tmp_path, monkeypatch):
-    """stagingDepth / chunksPerDispatch (round 11): ini + env
-    layering, int parse, defaults-off, usage() — and the sink-side
-    CTMR_* env fallback behind the config value."""
-    ini = tmp_path / "ct.ini"
-    ini.write_text("chunksPerDispatch = 8\nstagingDepth = 3\n")
-    cfg = CTConfig.load(argv=["--config", str(ini)], env={})
-    assert cfg.chunks_per_dispatch == 8
-    assert cfg.staging_depth == 3
-    # Env beats file; unparseable env falls back to the file value.
-    cfg2 = CTConfig.load(argv=["--config", str(ini)],
-                         env={"chunksPerDispatch": "16",
-                              "stagingDepth": "4"})
-    assert cfg2.chunks_per_dispatch == 16 and cfg2.staging_depth == 4
-    cfg3 = CTConfig.load(argv=["--config", str(ini)],
-                         env={"chunksPerDispatch": "banana"})
-    assert cfg3.chunks_per_dispatch == 8
-    # Defaults: 0 = resolve via CTMR_* env, then legacy (K=1, depth 2).
-    off = CTConfig.load(argv=[], env={})
-    assert off.chunks_per_dispatch == 0 and off.staging_depth == 0
-    from ct_mapreduce_tpu.ingest.sync import resolve_staging
+REMOVED_DISPATCH_DIRECTIVES = {"overlapWorkers": "overlap_workers",
+                               "chunksPerDispatch": "chunks_per_dispatch",
+                               "stagingDepth": "staging_depth"}
 
-    monkeypatch.delenv("CTMR_CHUNKS_PER_DISPATCH", raising=False)
-    monkeypatch.delenv("CTMR_STAGING_DEPTH", raising=False)
-    assert resolve_staging(off.chunks_per_dispatch,
-                           off.staging_depth) == (1, 2)
-    usage = CTConfig().usage()
-    assert "chunksPerDispatch" in usage and "stagingDepth" in usage
+
+@pytest.mark.parametrize("where", ["ini", "environment"])
+@pytest.mark.parametrize("directive", sorted(REMOVED_DISPATCH_DIRECTIVES))
+def test_removed_dispatch_directives_are_ignored(tmp_path, directive, where):
+    """PR 46 removed the overlap scheduler and the staged ring with
+    their directives. A deployment's old ini or environment still names
+    them: it loads as before (``CTConfig.load`` ignores names it does
+    not know, like config.go), nothing of the name is left on the
+    config or in the usage text, and ``ct-fetch`` builds its sink, the
+    one dispatch path, from it."""
+    from ct_mapreduce_tpu.cmd import ct_fetch
+    from ct_mapreduce_tpu.ingest.sync import AggregatorSink
+
+    ini = tmp_path / "old.ini"
+    ini.write_text("backend = tpu\nbatchSize = 64\ntableBits = 12\n"
+                   "meshShape = shard:1\ndeviceQueueDepth = 3\n"
+                   + (f"{directive} = 2\n" if where == "ini" else ""))
+    env = {directive: "2"} if where == "environment" else {}
+    cfg = CTConfig.load(argv=["--config", str(ini)], env=env)
+    assert cfg.backend == "tpu" and cfg.device_queue_depth == 3
+    assert not hasattr(cfg, REMOVED_DISPATCH_DIRECTIVES[directive])
+    assert directive not in CTConfig().usage()
+    sink, model = ct_fetch.build_sink(cfg, database=None)
+    assert isinstance(sink, AggregatorSink) and model is not None
+    assert sink.device_queue_depth == 3
+    assert not hasattr(sink, REMOVED_DISPATCH_DIRECTIVES[directive])
+    sink.close()
 
 
 def test_query_port_directive(tmp_path):
@@ -334,8 +339,7 @@ def test_platform_profile_feeds_every_resolver(tmp_path, monkeypatch):
     default — a tuned device profile needs no code change."""
     import json
 
-    for k in ("CTMR_PLATFORM_PROFILE", "CTMR_CHUNKS_PER_DISPATCH",
-              "CTMR_STAGING_DEPTH", "CTMR_SERVE_REPLICAS",
+    for k in ("CTMR_PLATFORM_PROFILE", "CTMR_SERVE_REPLICAS",
               "CTMR_SERVE_DEVICE", "CTMR_SERVE_CACHE_SIZE",
               "CTMR_VERIFY", "CTMR_VERIFY_BATCH",
               "CTMR_VERIFY_PRECOMP_WINDOW", "CTMR_NUM_WORKERS",
@@ -343,7 +347,6 @@ def test_platform_profile_feeds_every_resolver(tmp_path, monkeypatch):
         monkeypatch.delenv(k, raising=False)
     from ct_mapreduce_tpu.filter import resolve_filter
     from ct_mapreduce_tpu.ingest.fleet import resolve_fleet
-    from ct_mapreduce_tpu.ingest.sync import resolve_staging
     from ct_mapreduce_tpu.serve.server import resolve_serve
     from ct_mapreduce_tpu.verify.lane import resolve_verify
 
@@ -351,7 +354,6 @@ def test_platform_profile_feeds_every_resolver(tmp_path, monkeypatch):
     prof.write_text(json.dumps({
         "version": 1, "platform": "test-box",
         "knobs": {
-            "staging": {"chunksPerDispatch": 8, "stagingDepth": 3},
             "serve": {"serveReplicas": 5, "serveDevice": False,
                       "serveCacheSize": 512},
             "verify": {"verifyBatch": 4096, "verifyPrecompWindow": 4},
@@ -360,27 +362,24 @@ def test_platform_profile_feeds_every_resolver(tmp_path, monkeypatch):
         }}))
     monkeypatch.setenv("CTMR_PLATFORM_PROFILE", str(prof))
     # Profile supplies the defaults...
-    assert resolve_staging() == (8, 3)
     assert resolve_serve() == (5, False, 512)
     assert resolve_verify()[2] == 4096
     assert resolve_verify()[3] == 4
     assert resolve_fleet()[0] == 4
     assert resolve_filter()[2] == 0.005
     # ...env beats profile...
-    monkeypatch.setenv("CTMR_STAGING_DEPTH", "5")
     monkeypatch.setenv("CTMR_SERVE_REPLICAS", "9")
     monkeypatch.setenv("CTMR_VERIFY_PRECOMP_WINDOW", "8")
-    assert resolve_staging() == (8, 5)
-    assert resolve_serve()[0] == 9
+    assert resolve_serve() == (9, False, 512)
     assert resolve_verify()[3] == 8
     # ...and an explicit directive/kwarg beats both (incl. the
     # 0-is-real sentinel knobs).
-    assert resolve_staging(chunks_per_dispatch=2) == (2, 5)
+    assert resolve_serve(cache_size=64) == (9, False, 64)
     assert resolve_verify(window=0)[3] == 0
     # An unreadable profile resolves as if absent (no crash).
     monkeypatch.setenv("CTMR_PLATFORM_PROFILE", str(tmp_path / "nope"))
-    monkeypatch.delenv("CTMR_STAGING_DEPTH")
-    assert resolve_staging() == (1, 2)
+    monkeypatch.delenv("CTMR_SERVE_REPLICAS")
+    assert resolve_serve() == (2, True, 4096)
     # The directive parses and is documented.
     ini = tmp_path / "p.ini"
     ini.write_text(f"platformProfile = {prof}\ndistribHistory = 6\n"
@@ -407,7 +406,7 @@ def test_fingerprint_match_and_mismatch(tmp_path):
 
     from ct_mapreduce_tpu.config import profile as platprofile
 
-    base = {"version": 1, "knobs": {"staging": {"stagingDepth": 3}}}
+    base = {"version": 1, "knobs": {"serve": {"serveReplicas": 3}}}
     ok = _profile_file(tmp_path, "ok.json", dict(
         base, fingerprint={"host_cores": os.cpu_count() or 1}))
     bad = _profile_file(tmp_path, "bad.json", dict(
@@ -430,7 +429,7 @@ def test_fingerprint_match_and_mismatch(tmp_path):
 def test_provenance_tolerant_load(tmp_path):
     from ct_mapreduce_tpu.config import profile as platprofile
 
-    base = {"version": 1, "knobs": {"staging": {"stagingDepth": 2}}}
+    base = {"version": 1, "knobs": {"serve": {"serveReplicas": 2}}}
     odd = _profile_file(tmp_path, "odd.json", dict(
         base, provenance={
             "future_section": {"future_measure": {"anything": [1]}}},
@@ -440,7 +439,7 @@ def test_provenance_tolerant_load(tmp_path):
     try:
         loaded = platprofile.load_profile(odd)
         assert loaded is not None  # unknown provenance content is fine
-        assert loaded["knobs"]["staging"]["stagingDepth"] == 2
+        assert loaded["knobs"]["serve"]["serveReplicas"] == 2
         assert platprofile.load_profile(bad) is None  # wrong shape
     finally:
         platprofile.invalidate_cache()

@@ -1,7 +1,7 @@
 """Flight recorder (telemetry/flight.py): a wedged/crashed run leaves
 a post-mortem artifact — last trace spans + metric snapshots — on an
-injected drain-stage failure (the OverlapError latch), on signals, and
-via the excepthook."""
+injected fold failure (the sink's own dump), on signals, and via the
+excepthook."""
 
 import base64
 import datetime
@@ -13,7 +13,6 @@ import pytest
 
 from ct_mapreduce_tpu.agg.aggregator import TpuAggregator
 from ct_mapreduce_tpu.ingest import leaf as leaflib
-from ct_mapreduce_tpu.ingest.overlap import OverlapError
 from ct_mapreduce_tpu.ingest.sync import AggregatorSink, RawBatch
 from ct_mapreduce_tpu.telemetry import flight, metrics, trace
 from ct_mapreduce_tpu.utils import minicert
@@ -48,53 +47,55 @@ def _clean_recorder():
 
 
 def test_drain_failure_leaves_flight_dump(tmp_path):
-    """Injected exception in the drain stage mid-ingest: the overlap
-    pipeline latches OverlapError AND the flight recorder writes a dump
-    containing the last spans and a metric snapshot."""
+    """Injected exception in the fold mid-ingest: it reaches the caller
+    of ``store_raw_batch`` as it is, AND the flight recorder writes a
+    dump containing the last spans and a metric snapshot. A store
+    thread catches and reports such a failure, so no excepthook would
+    ever see it: the sink's dump is the only artifact."""
     trace.disable()
     trace.enable(ring_size=4096)
     metrics.set_sink(metrics.InMemSink())
     rec = flight.install(str(tmp_path), signals=False, excepthook=False)
 
     agg = TpuAggregator(capacity=1 << 12, batch_size=32, now=NOW)
-    sink = AggregatorSink(agg, flush_size=32, device_queue_depth=2,
-                          overlap_workers=2)
+    sink = AggregatorSink(agg, flush_size=32, device_queue_depth=2)
     boom = RuntimeError("drain stage exploded")
     calls = {"n": 0}
     orig_complete = sink._complete_item
 
     def failing_complete(pending, der_of):
         calls["n"] += 1
-        if calls["n"] == 2:
+        if calls["n"] in (2, 3):
             raise boom
         orig_complete(pending, der_of)
 
     sink._complete_item = failing_complete
-    with pytest.raises(OverlapError) as exc_info:
+    with pytest.raises(RuntimeError) as exc_info:
         for i in range(4):
             sink.store_raw_batch(wire_batch(i * 32, 32))
-        sink.flush()
-    assert exc_info.value.__cause__ is boom
+    assert exc_info.value is boom
 
     assert rec.dumps, "no flight dump written on drain failure"
     doc = json.load(open(rec.dumps[0]))
     assert "drain stage exploded" in doc["reason"]
     assert doc["pid"] == os.getpid()
     # The last spans are in the artifact — including the ingest stages
-    # that ran before the failure and the latch instant itself.
+    # that ran before the failure.
     names = {e["name"] for e in doc["trace_events"]}
     assert "ingest.decode" in names
-    assert "ingest.drain" in names
-    assert "overlap.stage_error" in names
+    assert "ingest.submit" in names
+    assert "device.readback" in names
     # ... and a metric snapshot taken at dump time.
     assert doc["current_metrics"] is not None
     counters = doc["current_metrics"]["counters"]
-    assert counters.get("overlap.stage_error") == 1
-    with pytest.raises(OverlapError):
+    assert counters.get("ct-fetch.foldedEntries") == 32
+    # The next fold fails too, under close()'s barrier this time: it
+    # raises again and writes NO second artifact for the same fault.
+    with pytest.raises(RuntimeError):
         sink.close()
-    # The latch dumps ONCE (the close() re-raise must not write a
-    # second artifact for the same failure).
     assert len(rec.dumps) == 1
+    assert len(list(tmp_path.iterdir())) == 1
+    sink.close()  # the fault gone, the rest folds
 
 
 def test_snapshot_ring_is_bounded_and_in_dump(tmp_path):

@@ -10,6 +10,7 @@ checkpoint (ct-fetch.go:288-305), tolerate-bad-entries
 import datetime
 import queue
 import threading
+import time
 
 import pytest
 
@@ -793,3 +794,315 @@ def test_oversized_issuer_gets_own_status_no_redecode():
     # force [narrow, full] here.
     assert pads_seen == [sink.PAD_LEN // 2], pads_seen
     assert agg.drain().total == len(small) + 1
+
+
+# -- the one dispatch path: decode -> put -> submit -> fold -----------------
+#
+# Fixtures from ``ct_mapreduce_tpu.utils.minicert`` (hand-assembled
+# canonical DER): the ingest path parses and never verifies, so
+# synthetic signature bytes are within contract.
+
+MINI_NOW = datetime.datetime(2025, 1, 1, tzinfo=UTC)
+
+
+def _mini_issuers():
+    from ct_mapreduce_tpu.utils import minicert
+
+    return [minicert.make_cert(serial=1, issuer_cn=f"Mini CA {k}", is_ca=True)
+            for k in range(2)]
+
+
+def _mini_batch(start: int, n: int):
+    """n wire entries alternating two issuers, serials start..start+n."""
+    import base64
+
+    from ct_mapreduce_tpu.ingest.sync import RawBatch
+    from ct_mapreduce_tpu.utils import minicert
+
+    issuers = _mini_issuers()
+    lis, eds = [], []
+    for j in range(n):
+        k = j % 2
+        leaf = minicert.make_cert(
+            serial=start + j, issuer_cn=f"Mini CA {k}",
+            subject_cn="mini.example", is_ca=False,
+        )
+        lis.append(base64.b64encode(
+            leaflib.encode_leaf_input(leaf, 1000 + start + j)).decode())
+        eds.append(base64.b64encode(
+            leaflib.encode_extra_data([issuers[k]])).decode())
+    return RawBatch(lis, eds, start, "mini-log")
+
+
+def _mini_sink(depth: int = 2, flush_size: int = 32):
+    from ct_mapreduce_tpu.agg.aggregator import TpuAggregator
+
+    agg = TpuAggregator(capacity=1 << 12, batch_size=flush_size,
+                        now=MINI_NOW)
+    return agg, AggregatorSink(agg, flush_size=flush_size,
+                               device_queue_depth=depth)
+
+
+def test_issuer_too_long_status_skips_futile_redecode():
+    """Satellite (ADVICE r05): a >=2 MiB issuer DER gets its own
+    status (ISSUER_TOO_LONG) — the cert itself packed fine, so the
+    batch must NOT pay a full-width redecode that cannot clear it —
+    and the entry still lands via the exact host lane."""
+    import base64
+
+    import numpy as np
+
+    from ct_mapreduce_tpu.ingest.sync import RawBatch
+    from ct_mapreduce_tpu.native import leafpack
+    from ct_mapreduce_tpu.utils import minicert
+
+    huge_issuer = minicert.make_cert(
+        serial=1, issuer_cn="Huge CA", is_ca=True,
+        extra_ext_bytes=(1 << 21) + 256,
+    )
+    assert len(huge_issuer) >= (1 << 21)
+    small = [minicert.make_cert(serial=50 + i, issuer_cn="Mini CA 0",
+                                subject_cn="s.example", is_ca=False)
+             for i in range(3)]
+    victim = minicert.make_cert(serial=99, issuer_cn="Huge CA",
+                                subject_cn="v.example", is_ca=False)
+
+    lis = [base64.b64encode(leaflib.encode_leaf_input(d, i)).decode()
+           for i, d in enumerate(small + [victim])]
+    eds = ([base64.b64encode(
+        leaflib.encode_extra_data([_mini_issuers()[0]])).decode()]
+        * len(small)
+        + [base64.b64encode(
+            leaflib.encode_extra_data([huge_issuer])).decode()])
+
+    # Decoder level: dedicated status on BOTH lanes of the fallback
+    # matrix (native when a compiler exists, pure Python always).
+    dec_py = leafpack._decode_python(lis, eds, 2048)
+    assert dec_py.status[-1] == leafpack.ISSUER_TOO_LONG
+    assert dec_py.length[-1] == len(victim)  # the cert row IS packed
+    from ct_mapreduce_tpu.native import available
+    if available():
+        dec_nat = leafpack.decode_raw_batch(lis, eds, 2048)
+        np.testing.assert_array_equal(dec_nat.status, dec_py.status)
+
+    # Sink level: the narrow pre-decode stays a SINGLE decode (the old
+    # overloaded TOO_LONG forced a futile full-width redecode here).
+    pads_seen = []
+    orig = leafpack.decode_raw_pages
+
+    def spy(pages, pad_len, workers=None, threads=None):
+        pads_seen.append(pad_len)
+        return orig(pages, pad_len, workers=workers, threads=threads)
+
+    agg, sink = _mini_sink(flush_size=64)
+    leafpack.decode_raw_pages = spy
+    try:
+        sink.store_raw_batch(RawBatch(lis, eds, 0, "log"))
+        sink.flush()
+    finally:
+        leafpack.decode_raw_pages = orig
+    assert pads_seen == [sink.PAD_LEN // 2], pads_seen
+    # ... and the oversized-issuer entry still counted, exactly once.
+    assert agg.drain().total == len(small) + 1
+
+
+def test_lock_wait_sampled_outside_store_envelope():
+    """dispatchLockWait is its own sample and the storeCertificate
+    envelope opens only after the lock is held — a submit budget
+    must not fold lock contention into submit cost."""
+    from ct_mapreduce_tpu.telemetry import metrics as tmetrics
+
+    sink_metrics = tmetrics.InMemSink()
+    prev = tmetrics.get_sink()
+    tmetrics.set_sink(sink_metrics)
+    try:
+        agg, sink = _mini_sink(depth=2)
+        for i in range(4):
+            sink.store_raw_batch(_mini_batch(i * 32, 32))
+        sink.close()
+    finally:
+        tmetrics.set_sink(prev)
+    samples = sink_metrics.snapshot()["samples"]
+    assert "ct-fetch.dispatchLockWait" in samples
+    assert "ct-fetch.storeCertificate" in samples
+    # One lock sample per submitted chunk (4 chunks + the flush
+    # barrier), all non-negative.
+    assert samples["ct-fetch.dispatchLockWait"]["count"] >= 4
+    assert samples["ct-fetch.dispatchLockWait"]["min"] >= 0.0
+
+
+def _fail_second_call(obj, name: str, boom: Exception) -> None:
+    """Patch ``obj.name`` so that its second call raises ``boom``."""
+    orig = getattr(obj, name)
+    calls = {"n": 0}
+
+    def failing(*args, **kwargs):
+        calls["n"] += 1
+        if calls["n"] == 2:
+            raise boom
+        return orig(*args, **kwargs)
+
+    setattr(obj, name, failing)
+
+
+def _flush_within(sink, seconds: float) -> None:
+    done = threading.Event()
+    errs = []
+
+    def run():
+        try:
+            sink.flush()
+        except Exception as err:  # reported by the assert below
+            errs.append(err)
+        done.set()
+
+    threading.Thread(target=run, daemon=True).start()
+    assert done.wait(seconds), \
+        "flush() waits for a cut whose dispatch never released it"
+    assert not errs, errs
+
+
+# stage -> (what to patch, entries the aggregate holds at the end of
+# the four batches the test feeds). A batch that died in decode or
+# before its submit never reached the device; a batch whose fold was
+# refused before it began stays outstanding and the report's drain
+# folds it.
+_FAILURES = {
+    "decode": (lambda agg, sink: (sink, "_prepare_chunk"), 3 * 32),
+    "submit": (lambda agg, sink: (agg, "ingest_packed_submit"), 3 * 32),
+    "fold": (lambda agg, sink: (sink, "_complete_item"), 4 * 32),
+}
+
+
+@pytest.mark.parametrize("stage", sorted(_FAILURES))
+def test_a_dispatch_that_raises_releases_its_cut(stage):
+    """Decode, submit or fold raises under ``store_raw_batch``: the
+    exception reaches the caller as it is, the cut it was dispatching
+    is handed over all the same (``_handing_over``), so a later
+    ``flush()`` does not wait for it for ever, the sink goes on
+    taking batches, and ``close()`` works."""
+    agg, sink = _mini_sink(depth=1)
+    boom = RuntimeError(f"{stage} exploded")
+    where, total = _FAILURES[stage]
+    _fail_second_call(*where(agg, sink), boom)
+    fed = 0
+    with pytest.raises(RuntimeError) as exc_info:
+        while fed < 3:
+            fed += 1
+            sink.store_raw_batch(_mini_batch(fed * 32, 32))
+    assert exc_info.value is boom
+    assert sink._open_cuts == set()
+    _flush_within(sink, 60.0)
+    while fed < 4:
+        fed += 1
+        sink.store_raw_batch(_mini_batch(fed * 32, 32))
+    sink.close()
+    assert sink._open_cuts == set() and not sink._inflight
+    assert agg.drain().total == total
+
+
+def test_the_batches_behind_a_failed_fold_are_still_folded_exactly():
+    """A fold raises with later batches already submitted behind it
+    (deviceQueueDepth 2): the next barrier folds those, in order, and
+    every count is exact for all that reached the device."""
+    import numpy as np
+
+    agg, sink = _mini_sink(depth=2)
+    folded = []
+    orig = sink._complete_item
+
+    def complete(pending, der_of):
+        if pending.batch == 2:
+            raise RuntimeError("fold of batch 2 exploded")
+        folded.append(pending.batch)
+        orig(pending, der_of)
+
+    sink._complete_item = complete
+    with pytest.raises(RuntimeError, match="batch 2"):
+        for i in range(5):
+            sink.store_raw_batch(_mini_batch(i * 32, 32))
+    # Batch 2's fold was due when batch 4 was submitted; 3 and 4 wait.
+    assert folded == [1]
+    assert [p.batch for p, _ in sink._inflight] == [3, 4]
+    sink.flush()
+    assert folded == [1, 3, 4] and not sink._inflight
+    snap = agg.drain()  # folds the refused batch 2, still outstanding
+    assert snap.total == 4 * 32
+    assert int(np.asarray(agg.table.count)) == 4 * 32
+    assert agg.metrics["inserted"] == 4 * 32 and agg.metrics["known"] == 0
+    assert sorted(snap.counts.values()) == [2 * 32, 2 * 32]
+
+
+def test_a_per_entry_dispatch_that_raises_releases_its_cut():
+    """The same for ``store()``, the per-entry lane: the batch it cut
+    fails in ``_dispatch`` and the cut is handed over all the same."""
+    from ct_mapreduce_tpu.ingest.leaf import DecodedEntry
+    from ct_mapreduce_tpu.utils import minicert
+
+    agg, sink = _mini_sink(flush_size=4)
+    issuer = _mini_issuers()[0]
+    boom = RuntimeError("ingest exploded")
+    _fail_second_call(agg, "ingest", boom)
+
+    def entry(i):
+        leaf = minicert.make_cert(serial=500 + i, issuer_cn="Mini CA 0",
+                                  subject_cn="e.example", is_ca=False)
+        return DecodedEntry(index=i, timestamp_ms=1,
+                            entry_type=leaflib.X509_ENTRY,
+                            cert_der=leaf, issuer_der=issuer)
+
+    with pytest.raises(RuntimeError) as exc_info:
+        for i in range(8):
+            sink.store(entry(i), "mini-log")
+    assert exc_info.value is boom
+    assert sink._open_cuts == set()
+    _flush_within(sink, 60.0)
+    for i in range(8, 12):
+        sink.store(entry(i), "mini-log")
+    sink.close()
+    assert agg.drain().total == 8  # the second batch of four was lost
+
+
+def test_flush_waits_for_the_cuts_before_it_and_for_no_later_one():
+    """``flush()`` is the barrier a cursor save stands on: a cut that a
+    store thread took before it and is still decoding is in neither
+    accumulator nor in flight, so the flush waits until that cut is
+    handed over; a cut taken after the flush looked is not its to wait
+    for."""
+    agg, sink = _mini_sink(depth=2)
+    release = {1: threading.Event(), 2: threading.Event()}
+    entered = {1: threading.Event(), 2: threading.Event()}
+    orig = sink._prepare_chunk
+
+    def held_prepare(chunk):
+        entered[chunk.batch].set()
+        assert release[chunk.batch].wait(60)
+        return orig(chunk)
+
+    sink._prepare_chunk = held_prepare
+    stores = [threading.Thread(
+        target=sink.store_raw_batch, args=(_mini_batch(i * 32, 32),),
+        daemon=True) for i in range(2)]
+    stores[0].start()
+    assert entered[1].wait(60)
+    flushed = threading.Event()
+    flusher = threading.Thread(
+        target=lambda: (sink.flush(), flushed.set()), daemon=True)
+    flusher.start()
+    # The flush has taken its own (empty) cut once the sink's cut
+    # counter has passed the store's: only then is batch 2 a later one.
+    deadline = time.monotonic() + 60
+    while sink._cut_seq < 2 and time.monotonic() < deadline:
+        time.sleep(0.005)
+    assert sink._cut_seq == 2
+    stores[1].start()
+    assert entered[2].wait(60)
+    assert not flushed.wait(0.3), "flush() passed a cut still decoding"
+    release[1].set()
+    assert flushed.wait(60), "flush() waits for a cut taken after it"
+    assert agg.metrics["inserted"] == 32  # batch 1 folded by the barrier
+    release[2].set()
+    for t in stores + [flusher]:
+        t.join(60)
+    sink.close()
+    assert agg.drain().total == 64

@@ -57,14 +57,13 @@ def fake_log() -> FakeLog:
     return log
 
 
-def run_fetch(tmp_path, overlap_workers: int = 0):
+def run_fetch(tmp_path):
     """The engine as ct-fetch wires it in TPU mode: raw batches, one
     store thread, the checkpoint hook before each cursor save, and the
     round's own save at the end."""
     log = fake_log()
     agg = TpuAggregator(capacity=1 << 12, batch_size=BATCH, now=NOW)
-    sink = AggregatorSink(agg, flush_size=BATCH,
-                          overlap_workers=overlap_workers)
+    sink = AggregatorSink(agg, flush_size=BATCH)
     path = str(tmp_path / "agg.npz")
     engine = LogSyncEngine(
         sink, FilesystemDatabase(MockBackend(), MockRemoteCache()),
@@ -94,7 +93,7 @@ def by_batch(events):
     return out
 
 
-# The spans one batch causes on the serial path, downstream of the cut.
+# The spans one batch causes, downstream of the cut.
 CHAIN = ("sink.accumulate", "ingest.decode", "native.decode_batch",
          "decode.pack", "ingest.submit_locked", "ingest.submit",
          "device.step", "device.readback", "device.fold",
@@ -102,13 +101,11 @@ CHAIN = ("sink.accumulate", "ingest.decode", "native.decode_batch",
 NATIVE = ("decode.concat_b64", "decode.native_call")
 
 
-@pytest.mark.parametrize("overlap_workers", [0, 2])
-def test_every_batch_has_its_whole_lineage(tmp_path, overlap_workers):
+def test_every_batch_has_its_whole_lineage(tmp_path):
     """Pages -> cut -> decode -> native call -> submit -> step ->
-    readback/fold, the same ``batch`` on every span, on whatever
-    thread it ran."""
+    readback/fold, the same ``batch`` on every span."""
     trace.enable()
-    run_fetch(tmp_path, overlap_workers)
+    run_fetch(tmp_path)
     events = spans()
     batches = by_batch(events)
     assert sorted(batches) == [1, 2, 3]
@@ -127,13 +124,6 @@ def test_every_batch_has_its_whole_lineage(tmp_path, overlap_workers):
                 and first <= p["args"]["start"] <= last]
         assert sum(p["n"] for p in mine) == BATCH
         assert names["ingest.decode"][0]["args"]["entries"] == BATCH
-    if overlap_workers:
-        # Decode, submit and drain ran on three threads of their own.
-        tids = {batches[1][k][0]["tid"] for k in
-                ("sink.accumulate", "ingest.decode", "ingest.submit",
-                 "device.readback")}
-        assert len(tids) == 4
-        assert len(batches[1]["ingest.drain"]) == 1
 
 
 def test_parents_form_a_tree_per_thread(tmp_path):

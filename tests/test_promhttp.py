@@ -54,7 +54,7 @@ def _populated_sink() -> InMemSink:
     sink = InMemSink()
     sink.incr_counter("ct-fetch.insertCertificate", 42)
     sink.incr_counter("aggregator.batches", 7)
-    sink.set_gauge("overlap.decode_occupancy", 0.75)
+    sink.set_gauge("ingest.decode_threads", 0.75)
     sink.set_gauge("aggregator.table_load", 0.12)
     for i in range(1, 101):
         sink.add_sample("ct-fetch.dispatchLockWait", i / 1000.0)
@@ -99,13 +99,12 @@ def _get(url: str):
 
 def test_server_metrics_and_healthz():
     sink = _populated_sink()
-    depths = {"prepared": 1, "prepared_capacity": 3,
-              "drain_queue": 2, "drain_queue_capacity": 2}
+    depths = {"queue_lanes": 1, "queue_cap": 3}
     srv = MetricsServer(
         0, host="127.0.0.1", sink=sink,
         health=lambda: {"stage": "syncing",
                         "last_progress": "2026-08-04T00:00:00+00:00",
-                        "overlap_queues": depths}).start()
+                        "serve": depths}).start()
     try:
         code, text = _get(f"http://127.0.0.1:{srv.port}/metrics")
         assert code == 200
@@ -113,7 +112,7 @@ def test_server_metrics_and_healthz():
         snap = sink.snapshot()
         # Counter/gauge values match the snapshot exactly.
         assert fams["ct_fetch_insertCertificate"]["samples"] == [(None, 42.0)]
-        assert fams["overlap_decode_occupancy"]["samples"] == [(None, 0.75)]
+        assert fams["ingest_decode_threads"]["samples"] == [(None, 0.75)]
         flat = dict(fams["ct_fetch_dispatchLockWait"]["samples"])
         assert flat['quantile="0.99"'] == \
             snap["samples"]["ct-fetch.dispatchLockWait"]["p99"]
@@ -124,7 +123,7 @@ def test_server_metrics_and_healthz():
         assert health["healthy"] is True
         assert health["stage"] == "syncing"
         assert health["last_progress"].startswith("2026-08-04")
-        assert health["overlap_queues"] == depths
+        assert health["serve"] == depths
 
         with pytest.raises(urllib.error.HTTPError) as err:
             _get(f"http://127.0.0.1:{srv.port}/nope")

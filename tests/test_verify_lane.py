@@ -101,11 +101,10 @@ def _wire(pairs):
     return lis, eds
 
 
-def _run_sink(pairs, chunks_per_dispatch=1, flush=16):
+def _run_sink(pairs, flush=16):
     agg = TpuAggregator(capacity=1 << 12, batch_size=flush)
     sink = AggregatorSink(agg, flush_size=flush, device_queue_depth=0,
-                          verify_signatures=True,
-                          chunks_per_dispatch=chunks_per_dispatch)
+                          verify_signatures=True)
     sink.verifier.batch_width = 32  # the parity suite's compiled width
     for s in _signers():
         sink.verifier.keys.register_signer(s)
@@ -148,12 +147,6 @@ def test_sink_lane_outcomes_serial(serial_run):
     _check_outcomes(agg, sink, expect, len(pairs))
 
 
-def test_sink_lane_outcomes_staged():
-    pairs, expect = _corpus()
-    agg, sink = _run_sink(pairs, chunks_per_dispatch=2)
-    _check_outcomes(agg, sink, expect, len(pairs))
-
-
 def test_device_verify_spans_cover_every_device_lane():
     """The kernels really ran, and in batches: the ``device.verify``
     spans of a traced run carry exactly the device-decidable lanes,
@@ -165,7 +158,7 @@ def test_device_verify_spans_cover_every_device_lane():
     tracer = trace.enable()
     t0 = tracer.now_us()
     try:
-        _agg, sink = _run_sink(pairs, chunks_per_dispatch=2)
+        _agg, sink = _run_sink(pairs)
         spans = [e for e in tracer.events()
                  if e.get("ph") == "X" and e["name"] == "device.verify"
                  and e["ts"] >= t0]

@@ -59,7 +59,7 @@ per worker/pid, so one ct-query request reads as one flow across both
 processes under one ``trace_id``.
 
 Also importable: ``load(path)`` / ``stage_summary(events)`` are the
-parsing half of tests/test_trace.py and tests/test_overlap.py.
+parsing half of tests/test_trace.py.
 """
 
 from __future__ import annotations
