@@ -4,7 +4,11 @@ Nothing here imports the program. A run's logs are made from ``--seed``
 alone: which entries repeat an earlier serial, which issuer signs each
 serial (Zipf over the issuers of ``fixtures/templates.json``), which
 leaf shape carries it. The expected report follows from the same
-arrays: N - D unique serials and the per-issuer counts.
+arrays: N - D unique serials and the per-issuer counts. Where the
+traffic file has a ``table_prefill`` block the table starts with the
+standing rows of ``prefill.py`` (a fixed data set, not the seed's), the
+seed also decides which entries repeat one of them, and the report
+holds the standing rows beside the logs' own.
 
 Copied from, and owed to, ``chip_smoke.py::Fixture`` and
 ``ct_mapreduce_tpu/utils/syncerts.py`` (``make_wire_batch``,
@@ -22,17 +26,18 @@ from dataclasses import dataclass
 
 import numpy as np
 
+import prefill
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 BATCH = 65536  # entries per device dispatch: the one compiled program
 TS_BASE_MS = 1_700_000_000_000
 # Serial spaces, disjoint by construction: log k's entry i carries
 # serial k * LOG_STRIDE + i (or that of the earlier entry it repeats).
 LOG_STRIDE = 1 << 32
+KNOWN_STREAM = 0x5EED  # the stream that draws the repeats of standing rows
 
 
-def zipf_weights(n: int, s: float) -> np.ndarray:
-    w = 1.0 / np.arange(1, n + 1, dtype=np.float64) ** s
-    return w / w.sum()
+zipf_weights = prefill.zipf_weights
 
 
 def quantile(values: list[float], q: float) -> float:
@@ -59,6 +64,7 @@ class Templates:
             doc = json.load(fh)
         self.serial_len = int(doc["serial_len"])
         self.exp_date_id = doc["exp_date_id"]
+        self.not_after = doc["not_after"]
         self.issuer_ids = [i["issuer_id"] for i in doc["issuers"]]
         self.kinds = sorted(doc["issuers"][0]["leaves"])
         self.leaf: list[dict[str, tuple[bytes, bytes]]] = []
@@ -100,11 +106,22 @@ class LogSpec:
     window_entries: int  # the measured window's, over all logs
     ramp_entries: int = 0  # served before them in the same round
     tail_entries: int = 0  # and after them: the pipeline stays full
+    # {"slots_log2", "load", "known_share"}: the table starts with the
+    # standing rows of prefill.py, and known_share of the entries that
+    # repeat no earlier entry of their log repeat one of those rows.
+    table_prefill: dict | None = None
 
     @property
     def per_log(self) -> int:
         return (self.ramp_entries + self.window_entries
                 + self.tail_entries) // self.logs
+
+    @property
+    def standing(self) -> "prefill.Standing | None":
+        if self.table_prefill is None:
+            return None
+        return prefill.Standing.of(self.table_prefill, self.issuers,
+                                   self.zipf_s)
 
 
 class LogFixture:
@@ -144,16 +161,55 @@ class LogFixture:
         kind_by_entry = rng.choice(
             len(kinds), size=total, p=shares / shares.sum()).astype(np.int8)
         self.kinds = kinds
-        self.issuer_of = issuer_by_entry[at]
         self.kind_of = kind_by_entry[at]
+        # The standing row an entry repeats, or -1. Drawn from a stream
+        # of its own, after everything else: a traffic file without the
+        # block gives the arrays it always gave.
+        self.standing_of = None
+        standing = spec.standing
+        if standing is not None:
+            row_of = self._standing_rows(standing, seed)
+            known = row_of >= 0
+            issuer_by_entry = issuer_by_entry.copy()
+            issuer_by_entry[known] = standing.issuer_of(row_of[known])
+            self.standing_of = row_of[at]
+        self.issuer_of = issuer_by_entry[at]
+
+    def _standing_rows(self, standing, seed: int) -> np.ndarray:
+        """Per entry that repeats no earlier entry of its log: the
+        standing row it repeats (``known_share`` of them), else -1. No
+        row is repeated twice in a run: the q-th such entry of the run
+        (log by log, a fixed stride a log) takes row ``(a q + b) mod
+        rows``, ``a`` prime to ``rows`` and both from the seed alone, so
+        every log of the run draws from the one permutation."""
+        spec, total, rows = self.spec, self.total, standing.rows
+        stride = spec.warmup_entries + spec.per_log
+        if not spec.logs * stride <= rows < 1 << 31:
+            raise ValueError(f"{spec.logs * stride} entries cannot each "
+                             f"repeat a row of their own of {rows}")
+        shared = np.random.default_rng([int(seed), KNOWN_STREAM])
+        a = int(shared.integers(1, rows)) | 1
+        while np.gcd(a, rows) != 1:
+            a += 2
+        b = int(shared.integers(0, rows))
+        mine = np.random.default_rng([int(seed), self.index, KNOWN_STREAM])
+        known = ~self.is_dup & (
+            mine.random(total) < spec.table_prefill["known_share"])
+        q = self.index * stride + np.flatnonzero(known)
+        out = np.full(total, -1, np.int64)
+        out[known] = (a * q + b) % rows  # a, q < rows < 2^31: no overflow
+        return out
 
     @property
     def total(self) -> int:
         return self.warm + self.n
 
     def unique_by_issuer(self, lo: int, hi: int) -> np.ndarray:
-        """Serials first seen in entries ``[lo, hi)``, per issuer."""
+        """Serials first seen in entries ``[lo, hi)`` that the standing
+        table does not hold, per issuer."""
         first = ~self.is_dup[lo:hi]
+        if self.standing_of is not None:
+            first &= self.standing_of[lo:hi] < 0
         return np.bincount(self.issuer_of[lo:hi][first],
                            minlength=self.spec.issuers)
 
@@ -162,9 +218,12 @@ class LogFixture:
         end = min(end, start + self.spec.page - 1, self.total - 1)
         slen = tpl.serial_len - 1
         leaves = [[shapes[k] for k in self.kinds] for shapes in tpl.leaf]
+        serials = self.serial_of[start:end + 1].tolist()
+        if self.standing_of is not None:
+            serials = [s if j < 0 else prefill.SERIAL_BASE + j for s, j in zip(
+                serials, self.standing_of[start:end + 1].tolist())]
         rows = zip(self.issuer_of[start:end + 1].tolist(),
-                   self.kind_of[start:end + 1].tolist(),
-                   self.serial_of[start:end + 1].tolist())
+                   self.kind_of[start:end + 1].tolist(), serials)
         parts = []
         for ts, (issuer, kind, serial) in enumerate(rows, TS_BASE_MS + start):
             head, tail = leaves[issuer][kind]
@@ -191,8 +250,18 @@ class RunFixture:
     def duplicates(self) -> int:
         return int(sum(log.is_dup.sum() for log in self.logs))
 
-    def expected_by_issuer(self) -> np.ndarray:
+    def new_by_issuer(self) -> np.ndarray:
+        """The logs' first sightings that no standing row holds."""
         return sum(log.unique_by_issuer(0, log.total) for log in self.logs)
+
+    def standing_by_issuer(self) -> np.ndarray:
+        standing = self.spec.standing
+        if standing is None:
+            return np.zeros(self.spec.issuers, np.int64)
+        return standing.by_issuer()
+
+    def expected_by_issuer(self) -> np.ndarray:
+        return self.standing_by_issuer() + self.new_by_issuer()
 
     def expected_unique(self) -> int:
         return int(self.expected_by_issuer().sum())

@@ -27,6 +27,7 @@ import threading
 import time
 
 import fixture as fx
+import prefill
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -37,6 +38,8 @@ FOLD_SAMPLE = "ct-fetch.completeBatch"  # one sample per batch folded
 STORE_THREAD = ("ct-fetch.decodeBatch", "ct-fetch.storeCertificate",
                 FOLD_SAMPLE)
 REPLAY = "log_replay"  # the one generator every traffic file has: logserver.py
+LOAD_GAUGE = "aggregator.table_load"  # occupied slots over the table's, at every fold
+PREFILL_CACHE = os.path.join(ROOT, ".bench_cache", "prefill")  # git-ignored
 # The harness's own numbers; a generator's ``VALUES`` may repeat neither.
 VALUES = ("ingest_entries_per_s", "setup_s")
 
@@ -78,7 +81,13 @@ def log_spec(traffic: dict, seconds: float, batch: int) -> fx.LogSpec:
     if g["ramp_batches"] < 1:
         raise RunFailed("the window opens at the ramp's last fold: "
                         "ramp_batches must be 1 or more")
+    block = g.get("table_prefill")
+    if block is not None and sorted(block) != ["known_share", "load",
+                                               "slots_log2"]:
+        raise RunFailed("table_prefill has slots_log2, load and known_share, "
+                        f"not {sorted(block)}")
     return fx.LogSpec(
+        table_prefill=block,
         logs=g["logs"], page=g["page"], dup_share=g["dup_share"],
         leaf_mix=g["leaf_mix"], issuers=g["issuers"], zipf_s=g["zipf_s"],
         warmup_entries=g["warmup_entries"],
@@ -179,6 +188,8 @@ class FoldStamper:
         # the per-layer readers sum them over the window.
         self.samples: list[tuple[float, str, float]] = []
         self.counters: list[tuple[float, str, float]] = []
+        # The table's load as the program states it, with its instants.
+        self.loads: list[tuple[float, float]] = []
         self.cond = threading.Condition()
 
     def add_sample(self, key: str, value: float) -> None:
@@ -193,7 +204,12 @@ class FoldStamper:
         self.counters.append((time.monotonic(), key, value))
 
     def set_gauge(self, key: str, value: float) -> None:
-        pass
+        if key == LOAD_GAUGE:
+            self.loads.append((time.monotonic(), value))
+
+    def load_at(self, t: float) -> float | None:
+        """What the gauge held at ``t``: its last setting up to then."""
+        return next((v for at, v in reversed(self.loads) if at <= t), None)
 
     def wait_for(self, count: int, deadline: float, alive) -> None:
         with self.cond:
@@ -288,6 +304,7 @@ class Conductor:
         self.stamper = FoldStamper()
         self.fetch_returned = threading.Event()
         self.failure: BaseException | None = None
+        self.t_main_called = 0.0  # set as ct_fetch.main is called
         self.out: dict = {}
         self.batch = int(config["directives"]["batchSize"])
 
@@ -342,10 +359,7 @@ class Conductor:
         for name in sorted(os.listdir(self.workdir)):
             path = os.path.join(self.workdir, name)
             if name.startswith(STATE_FILE) and os.path.isfile(path):
-                try:
-                    os.link(path, os.path.join(kept, name))
-                except OSError:  # a file system without hard links
-                    shutil.copy2(path, os.path.join(kept, name))
+                prefill.link_or_copy(path, os.path.join(kept, name))
         return kept
 
     # -- the run ---------------------------------------------------------
@@ -429,11 +443,13 @@ class Conductor:
             t_round_folded=folds[-1], t_last_page=t_last_page,
             t_durable=t_durable, kept=kept, rows=rows,
             folds=folds[warm_batches:], pages=pages,
-            all_pages=stamps["pages"],
+            all_pages=stamps["pages"], t_main_called=self.t_main_called,
             extra_folds=len(self.stamper.stamps) - len(folds),
             samples=[e for e in self.stamper.samples if e[0] >= t_open],
             counters=[e for e in self.stamper.counters if e[0] >= t_open],
             compiles_in_window=self.compiles.within(t_open, t_durable),
+            load_at_warmup_fold=self.stamper.load_at(folds[warm_batches - 1]),
+            load_at_durable=self.stamper.load_at(t_durable),
             gc=[p for p in gclog.pauses if t_first <= p[0] <= t_folded],
             tracer=tracer)
         self.out["live"] = self.live_facts()
@@ -506,9 +522,52 @@ def compare(fixture: fx.RunFixture, tpl: fx.Templates, spec: fx.LogSpec,
         {"what": "round: programs compiled", "got": out["compiles_in_window"],
          "want": 0},
     ]
+    standing = spec.standing
+    if standing is not None:
+        # The rows the live table held once the warm-up round was
+        # folded: the program's gauge of its load then, times the slots.
+        log0 = fixture.logs[0]
+        slots = prefill.table_slots(standing.slots_log2)
+        load = out["load_at_warmup_fold"]
+        stood = fixture.standing_by_issuer()
+        new = fixture.new_by_issuer()
+        checks += [
+            {"what": "restore: rows the live table held when the warm-up "
+                     "round was folded",
+             "got": None if load is None else round(load * slots),
+             "want": standing.rows
+             + int(log0.unique_by_issuer(0, log0.warm).sum())},
+            {"what": "durable report: standing rows missing",
+             "got": sum(max(0, int(stood[k]) - (got.get(ids[k], 0)
+                                                - int(new[k])))
+                        for k in range(spec.issuers)), "want": 0},
+        ]
     for c in checks:
         c["ok"] = c["got"] == c["want"]
     return checks
+
+
+def place_base(spec: fx.LogSpec, seed: int, config: dict, state_path: str,
+               workers: int) -> float | None:
+    """Where the traffic file has a ``table_prefill`` block: the
+    standing table's base checkpoint where ``aggStatePath`` points, as
+    a restarted tailer finds its own; built first if this checkout has
+    none yet. Returns the seconds the build took (0.0: it was cached),
+    None for a cell without the block. The table is not the seed's; the
+    controls that stand in for this function want it (tests/breaks.py)."""
+    standing = spec.standing
+    if standing is None:
+        return None
+    bits = int(config["directives"]["tableBits"])
+    if standing.slots_log2 != bits:
+        raise RunFailed(f"table_prefill's slots_log2 {standing.slots_log2} "
+                        f"is not the configuration's tableBits {bits}")
+    tpl = fx.Templates()
+    path, built_s = prefill.ensure(
+        PREFILL_CACHE, standing, tpl.issuer_ids,
+        prefill.exp_hour_of(tpl.not_after), workers)
+    prefill.put(path, state_path)
+    return built_s
 
 
 class Prepared:
@@ -537,6 +596,15 @@ class Prepared:
         self.logsrv = Child("logserver.py", {
             "seed": seed, "log_spec": self.spec.__dict__,
             "cores": loadgen_cores}, WORK)
+        # Beside the log server's building of its pages and before JAX
+        # is loaded: the standing table, if the cell has one.
+        try:
+            self.prefill_built_s = place_base(
+                self.spec, seed, config, os.path.join(WORK, STATE_FILE),
+                workers=len(os.sched_getaffinity(0)))
+        except BaseException:
+            self.close()
+            raise
 
     def start_generators(self, log_port: int) -> list[Child]:
         """Once the log server listens: each generator beside it gets
@@ -602,6 +670,7 @@ def run_cell(prep: Prepared, *, trace_on: bool, t_start: float,
         # just after it has returned must find this one, not Python's.
         signal.signal(signal.SIGINT, lambda *_: None)
         thread.start()
+        conductor.t_main_called = time.monotonic()
         try:
             rc = ct_fetch.main(["-config", ini, "-nobars"])
         finally:
@@ -690,6 +759,10 @@ def run_cell(prep: Prepared, *, trace_on: bool, t_start: float,
     times = [m[1] for m in marks] + [out["t_first"]]
     setup = {names[i + 1]: times[i + 1] - times[i]
              for i in range(len(times) - 1)}
+    if prep.prefill_built_s is not None:
+        # Inside jax_ready's share (Prepared is made before JAX loads);
+        # 0.0 but in a checkout's first run.
+        setup["of_which_standing_table_built"] = prep.prefill_built_s
     return {
         "correct": all(c["ok"] for c in checks),
         "attempted": sum(p["attempted"] for p in parts.values()),

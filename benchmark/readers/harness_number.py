@@ -28,6 +28,16 @@ def read(params: dict, ctx: dict):
     if which == "peak_hbm_gb":
         peak = ctx["device"]["memory_peak_bytes"]
         return None if peak is None else peak / 1e9
+    if which == "restore_s":
+        # From the call of ct_fetch.main to the first get-entries request
+        # the log server answered after it: the program has its table
+        # back (and its first tree head) and starts to download.
+        served = [p[3] for p in out["all_pages"] if p[3] >= out["t_main_called"]]
+        return min(served) - out["t_main_called"] if served else None
+    if which == "table_load_pct":
+        # The program's own gauge as it stood when the round was durable.
+        load = out["load_at_durable"]
+        return None if load is None else load * 100.0
     if which in ctx["values"]:  # the harness's two and the generators'
         return ctx["values"][which]
     raise ValueError(f"unknown harness number {which!r}")

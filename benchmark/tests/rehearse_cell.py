@@ -9,7 +9,8 @@ log. ``run.py`` has no option that reaches it.
 The cut: ``tableBits`` 18, ``batchSize`` 1,024, 64-entry pages, one
 batch of warm-up, and the window's entries a second in the batch's
 proportion, so that the window keeps its number of batches (64 for
-``backfill-1log-query``); a generator's ``min_age_s`` becomes
+``backfill-1log-query``) and a ``table_prefill`` block's ``slots_log2`` 18
+with the table; a generator's ``min_age_s`` becomes
 ``TINY_MIN_AGE_S`` and its ``rate_per_s`` a quarter of the file's (the
 CPU answers 96 a second alone, with a p99 of seconds; not under a test
 suite's other workers). Every other parameter is the file's own. With
@@ -50,6 +51,8 @@ def tiny(config: dict, traffic: dict) -> tuple[dict, dict]:
             g.update(page=64, warmup_entries=TINY_BATCH,
                      window_entries_per_second=g["window_entries_per_second"]
                      * TINY_BATCH / batch)
+            if "table_prefill" in g:  # the standing table follows tableBits
+                g["table_prefill"] = dict(g["table_prefill"], slots_log2=18)
         else:
             if "min_age_s" in g:
                 g["min_age_s"] = TINY_MIN_AGE_S

@@ -492,3 +492,18 @@ def test_benchmark_json_keeps_the_contract():
         for f in files:
             rel = os.path.relpath(os.path.join(base, f), ROOT)
             assert not bad.search(rel), rel
+
+
+# Tier-1 reaches ``benchmark/tests`` through ``tests/test_benchmark_harness.py``,
+# which runs every test this module holds when it imports it (as
+# ``benchmark.tests.test_benchmark``), "and whatever test is added there
+# later". The tests of the cell ``backfill-1log-loaded`` are a file of
+# their own, ``test_loaded_cell.py``; this hands them to tier-1, with
+# the fixtures they use, and leaves them to their own file by hand.
+if __name__ != "test_benchmark":
+    sys.path.insert(0, HERE)
+    import test_loaded_cell as _loaded  # noqa: E402
+
+    globals().update({name: thing for name, thing in vars(_loaded).items()
+                      if name.startswith("test_")
+                      or name in ("checkout", "suite_devices")})

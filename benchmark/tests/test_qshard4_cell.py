@@ -28,6 +28,7 @@ sys.path.insert(0, BENCH)
 sys.path.insert(0, HERE)
 
 import layers  # noqa: E402
+import listing  # noqa: E402
 import tracing  # noqa: E402
 from layers import ABSENT  # noqa: E402
 from readers import copy_roofline, counter_ratio, shard_copy_roofline  # noqa: E402
@@ -72,8 +73,9 @@ BATCHES = 60  # the window at --seconds 35, as backfill-3log's
 
 
 def bench_json() -> dict:
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
-        return json.load(fh)
+    """As it stood before the cells listed after this file was written
+    (``listing.py``)."""
+    return listing.bench_json(ROOT)
 
 
 def cell_metrics() -> list[dict]:
