@@ -132,6 +132,12 @@ def _donating_backend() -> bool:
 # default bucket); re-exported here for the aggregator's callers.
 _table_layout = pipeline.table_layout
 
+#: Share of ``grow_at`` past which a round's end makes the doubled
+#: table's programs ready (`TpuAggregator.prepare_growth`): load 0.656
+#: at the default ``tableGrowAt`` 0.7. A table below it is more than a
+#: sixteenth of its threshold away from growing and pays nothing.
+GROW_PREPARE_AT = 15 / 16
+
 
 class IssuerRegistry:
     """Dense issuer indexing for device ops.
@@ -516,6 +522,14 @@ class TpuAggregator:
         # estimate trips the threshold.
         self._table_fill = 0
         self._inflight_lanes = 0
+        # Growth on the device (`grow`, `prepare_growth`): the capacity
+        # whose programs were run once against a scratch table and so
+        # sit compiled in jit's caches, and what those programs are
+        # shaped by beside the table: the last step's batch arrays and
+        # the widths membership probes came in.
+        self._grow_ready_for = 0
+        self._step_shapes: Optional[tuple] = None
+        self._contains_shapes: set[tuple] = set()
         self.registry = IssuerRegistry()
         self._fixed_now = now
         # Host-exact lane state: (issuer_idx, exp_hour) → set of serial bytes.
@@ -676,6 +690,7 @@ class TpuAggregator:
         width = max(16, 1 << (n - 1).bit_length())
         if width != n:
             fps = np.pad(np.asarray(fps), ((0, width - n), (0, 0)))
+        self._contains_shapes.add((fps.shape, np.dtype(fps.dtype).str))
         with self._table_lock:
             if isinstance(self.table, buckettable.BucketTable):
                 out = np.asarray(
@@ -756,58 +771,189 @@ class TpuAggregator:
     def _restore_table_state(self, saved) -> None:
         self.table = saved
 
-    def grow(self, new_capacity: int) -> None:
-        """Rebuild the table at ``new_capacity`` and re-hash every
-        occupied row (key home slots and probe chains depend on
-        capacity, so a raw row copy would be wrong — same reasoning as
-        the cross-topology checkpoint restore).
+    def _note_load(self) -> None:
+        """The table's two gauges, set together wherever the fill or
+        the capacity moved: the load the growth policy follows and the
+        slots it is a share of."""
+        set_gauge("aggregator", "table_load",
+                  value=self._table_fill / self.capacity)
+        set_gauge("aggregator", "table_slots", value=float(self.capacity))
 
-        Crash-safe: the old table state is held until the reinsert
-        succeeds. A reinsert that probe-overflows (pathological /
-        adversarial key cluster) retries at double capacity up to the
-        ceiling; if it still overflows, the ORIGINAL state is restored
-        and the error raised — a caller that catches and continues
-        keeps exact counts either way."""
-        self.complete_outstanding()
-        t0 = time.perf_counter()
-        # Table lock taken only AFTER the completes above: a thread
-        # mid-complete holds the fold lock and may probe the
-        # table, so grabbing the table lock first would deadlock
-        # (fold → table is the global order).
-        with self._table_lock:
-            keys, meta = self._drain_table()
+    def _splits_on_device(self) -> bool:
+        """Whether this table doubles where it lives
+        (`buckettable.grow_rehash`): one chip's bucket table. A mesh
+        (its state is `self.dedup`), the open layout and a host-resident
+        snapshot grow through the host, as every table did."""
+        return (isinstance(self.table, buckettable.BucketTable)
+                and not isinstance(self.table.rows, np.ndarray))
+
+    def _split_table(self) -> Optional[tuple[int, int]]:
+        """One doubling on the device, under the table lock: ``(rows,
+        rehomed)``, or None where a row that lay past a full bucket
+        found no room in the doubled table either (the old table is
+        then still the live one). The old table is freed as its name is
+        rebound; no row of either crosses to the host."""
+        import jax
+
+        with trace.span("grow.rehash", cat="device") as sp:
+            new, rehomed, overflowed = buckettable.grow_rehash(
+                self.table, max_probes=self.max_probes)
+            # The one readback (three scalars) is also the wait for
+            # the program.
+            rows, rehomed, overflowed = (
+                int(x) for x in jax.device_get(
+                    (new.count, rehomed, overflowed)))
+            sp.set(rows=rows, rehomed=rehomed)
+        if overflowed:
+            return None
+        self.table = new
+        self.capacity = new.capacity
+        return rows, rehomed
+
+    def _rehash_through_host(self, new_capacity: int) -> int:
+        """The growth every layout can do: drain the occupied rows to
+        the host, build a fresh table and insert them again. A reinsert
+        that probe-overflows (pathological / adversarial key cluster)
+        retries at double capacity up to the ceiling; if it still
+        overflows, the ORIGINAL state is restored and the error raised.
+        Returns the rows re-hashed. Caller holds the table lock."""
+        keys, meta = self._drain_table()
+        saved = self._save_table_state()
+        cap = new_capacity
+        while True:
+            actual = self._rebuild_table(cap)
+            overflow = self._bulk_reinsert(keys, meta)
+            if not overflow:
+                break
+            if cap >= self.max_capacity:
+                self._restore_table_state(saved)
+                raise RuntimeError(
+                    f"table grow overflowed {overflow} rows even at "
+                    f"the max capacity {cap}; original table restored "
+                    "(pathological key distribution)"
+                )
+            cap = min(cap * 2, self.max_capacity)
+        self.capacity = actual
+        return len(keys)
+
+    def grow(self, new_capacity: int) -> None:
+        """Take the table to at least ``new_capacity`` slots, every
+        occupied row in the place the new capacity gives it (home
+        buckets and probe chains depend on capacity, so a raw row copy
+        would be wrong — same reasoning as the cross-topology
+        checkpoint restore).
+
+        One chip's bucket table DOUBLES ON THE DEVICE, as often as it
+        takes (`buckettable.grow_rehash`: one streaming split of the
+        old rows, then an ordinary insert of the few that lay past a
+        full bucket): no row crosses to the host, and where
+        `prepare_growth` ran for this capacity nothing compiles either.
+        Every other table, and a doubling whose re-homed rows found no
+        room, goes through the host (`_rehash_through_host`).
+
+        Crash-safe: the old table is the live one until the new one
+        holds every row; a caller that catches a raised error and
+        continues keeps exact counts either way."""
+        with trace.span("grow.table", cat="device",
+                        from_slots=int(self.capacity)) as sp:
+            with trace.span("grow.wait_outstanding", cat="device"):
+                self.complete_outstanding()
+            t0 = time.perf_counter()
             old_capacity = self.capacity
-            saved = self._save_table_state()
-            cap = new_capacity
-            while True:
-                actual = self._rebuild_table(cap)
-                overflow = self._bulk_reinsert(keys, meta)
-                if not overflow:
-                    break
-                if cap >= self.max_capacity:
-                    self._restore_table_state(saved)
-                    raise RuntimeError(
-                        f"table grow overflowed {overflow} rows even at "
-                        f"the max capacity {cap}; original table restored "
-                        "(pathological key distribution)"
-                    )
-                cap = min(cap * 2, self.max_capacity)
-            self.capacity = actual
-        self._table_fill = len(keys)
+            rows = rehomed = unprepared = host_bytes = 0
+            # Table lock taken only AFTER the completes above: a thread
+            # mid-complete holds the fold lock and may probe the
+            # table, so grabbing the table lock first would deadlock
+            # (fold → table is the global order).
+            with self._table_lock:
+                while (self.capacity < new_capacity
+                       and self.capacity * 2 <= self.max_capacity
+                       and self._splits_on_device()):
+                    ready = self._grow_ready_for == self.capacity * 2
+                    split = self._split_table()
+                    if split is None:
+                        break
+                    unprepared += not ready
+                    rows, moved = split
+                    rehomed += moved
+                if (self.capacity < new_capacity
+                        or self.capacity == old_capacity):
+                    host_bytes = int(self._checkpoint_table().rows.nbytes)
+                    unprepared += 1  # its reinsert is shaped by the rows
+                    rows = self._rehash_through_host(new_capacity)
+            sp.set(rows=rows, to_slots=int(self.capacity))
+        self._table_fill = rows
         # A rehash changes the table's capacity/topology: a delta chain
         # replayed onto the pre-grow base would restore the OLD
         # capacity, diverging from what a full save would record — the
         # next checkpoint must anchor.
         self._ckpt_mark_dirty_lost("table grow")
         incr_counter("aggregator", "table_grow")
-        set_gauge("aggregator", "table_load",
-                  value=self._table_fill / self.capacity)
+        # Every growth says all three, 0 included: table bytes that
+        # crossed to the host, rows inserted again because they lay
+        # past a full bucket, doublings that had to compile.
+        incr_counter("grow", "host_bytes", value=float(host_bytes))
+        incr_counter("grow", "rehomed_rows", value=float(rehomed))
+        incr_counter("grow", "unprepared", value=float(unprepared))
+        self._note_load()
         print(
             f"table grown {old_capacity} → {self.capacity} slots "
-            f"({len(keys)} rows re-hashed in "
-            f"{time.perf_counter() - t0:.2f}s)",
+            f"({rows} rows re-hashed in "
+            f"{time.perf_counter() - t0:.2f}s, {rehomed} re-homed, "
+            f"{host_bytes} B through the host)",
             file=sys.stderr,
         )
+
+    def prepare_growth(self) -> bool:
+        """Make ready every program the doubled table will need, if the
+        table is near its growth: called at a round's end, after the
+        save, when nothing is in flight. Past ``GROW_PREPARE_AT`` of
+        ``grow_at`` (and under the ceiling, once a capacity) the table
+        is doubled into a SCRATCH table by the growth's own program,
+        and the step (at the last batch's shapes), the two programs of
+        a packed save and the membership probe (at the widths seen) are
+        run once against the scratch, which is then dropped: jit's
+        caches hold the executables, so the growth, the steps after it
+        and the next save compile nothing. Costs the doubled table's
+        HBM (4.29 GB at ``tableBits`` 26) for the seconds this takes,
+        and nothing after. True where it ran."""
+        target = self.capacity * 2
+        if (self.grow_at <= 0 or target > self.max_capacity
+                or self._grow_ready_for == target
+                or not self._splits_on_device()
+                or self._table_fill
+                <= GROW_PREPARE_AT * self.grow_at * self.capacity):
+            return False
+        import jax
+
+        with trace.span("grow.prepare", cat="device",
+                        from_slots=int(self.capacity),
+                        to_slots=int(target)) as sp:
+            with self._table_lock:
+                scratch, _, _ = buckettable.grow_rehash(
+                    self.table, max_probes=self.max_probes)
+            programs = 1
+            if self._step_shapes is not None:
+                scratch, out = self._step_program(scratch, *(
+                    np.zeros(shape, dtype)
+                    for shape, dtype in self._step_shapes))
+                _pack_out(out)
+                programs += 1
+            index_fn, chunk_fn = self._pack_programs()
+            _fill, index = index_fn(scratch.rows)
+            last = chunk_fn(
+                scratch.rows, index, np.int32(0),
+                chunk=buckettable.pack_chunk_rows(scratch.rows.shape[0]))
+            programs += 2
+            for shape, dtype in sorted(self._contains_shapes):
+                last = buckettable.contains(
+                    scratch, jax.numpy.asarray(np.zeros(shape, dtype)),
+                    max_probes=self.max_probes)
+                programs += 1
+            jax.block_until_ready((scratch, last))
+            sp.set(programs=programs)
+        self._grow_ready_for = target
+        return True
 
     # -- config ----------------------------------------------------------
     def set_cn_prefixes(self, prefixes: tuple[str, ...]) -> None:
@@ -1458,8 +1604,7 @@ class TpuAggregator:
         self.metrics["known"] += max(dev_known, 0)
         self._table_fill += dev_inserted
         self._ckpt_note_inserted(dev_inserted)
-        set_gauge("aggregator", "table_load",
-                  value=self._table_fill / self.capacity)
+        self._note_load()
 
         host_pos = [int(p) for p in np.nonzero(hl)[0]]
         host_lane_total = self._host_lanes(
@@ -1603,8 +1748,7 @@ class TpuAggregator:
         self.metrics["known"] += max(dev_known, 0)
         self._table_fill += dev_inserted
         self._ckpt_note_inserted(dev_inserted)
-        set_gauge("aggregator", "table_load",
-                  value=self._table_fill / self.capacity)
+        self._note_load()
         return host_pos
 
     def _host_lanes(self, host_pos, der_of, res) -> int:
@@ -1635,42 +1779,48 @@ class TpuAggregator:
             res.exp_hours[pos], res.serials[pos] = eh2, sb
         return len(host_pos)
 
-    def _device_step_packed(self, batch):
-        self._device_written = True
+    def _step_program(self, table, data, length, issuer_idx, valid):
+        """``(table, out)``: the walker step's one program a row shape,
+        whichever way the rows came: rows already on the device (the
+        pipelined ingest path device_puts them ahead of the dispatch)
+        and NumPy rows (a chunk short of the batch, padded on the host;
+        the per-entry lane) both go through the donating step, the
+        second kind put on the device here. Two wrappers were two
+        programs, and the second compiled (minutes, on the chip) at the
+        first short chunk. The row buffer is donated — the caller keeps
+        a host copy for host-lane slices, so it is dead weight after
+        this dispatch and XLA may reuse its HBM. The CPU backend keeps
+        the non-donating wrapper for both kinds (its XLA can't alias
+        this layout and warns on every dispatch). `prepare_growth` runs
+        the same call against its scratch table."""
         import jax
 
-        # One program a row shape, whichever way the rows came: rows
-        # already on the device (the pipelined ingest path device_puts
-        # them ahead of the dispatch) and NumPy rows (a chunk short of
-        # the batch, padded on the host; the per-entry lane) both go
-        # through the donating step, the second kind put on the device
-        # here. Two wrappers were two programs, and the second compiled
-        # (minutes, on the chip) at the first short chunk. The row
-        # buffer is donated — the caller keeps a host copy for
-        # host-lane slices, so it is dead weight after this dispatch
-        # and XLA may reuse its HBM. The CPU backend keeps the
-        # non-donating wrapper for both kinds (its XLA can't alias this
-        # layout and warns on every dispatch).
-        data = batch.data
         if _donating_backend():
             step = pipeline.ingest_step_donated
             if not isinstance(data, jax.Array):
                 data = jax.device_put(data)
         else:
             step = pipeline.ingest_step
+        return step(
+            table,
+            data,
+            length,
+            issuer_idx,
+            valid,
+            np.int32(self._now_hour()),
+            np.int32(self.base_hour),
+            self._prefix_arr,
+            self._prefix_lens,
+            max_probes=self.max_probes,
+        )
+
+    def _device_step_packed(self, batch):
+        self._device_written = True
+        arrays = (batch.data, batch.length, batch.issuer_idx, batch.valid)
+        self._step_shapes = tuple(
+            (tuple(a.shape), np.dtype(a.dtype).str) for a in arrays)
         with trace.span("device.step", cat="device"), self._table_lock:
-            self.table, out = step(
-                self.table,
-                data,
-                batch.length,
-                batch.issuer_idx,
-                batch.valid,
-                np.int32(self._now_hour()),
-                np.int32(self.base_hour),
-                self._prefix_arr,
-                self._prefix_lens,
-                max_probes=self.max_probes,
-            )
+            self.table, out = self._step_program(self.table, *arrays)
         return out
 
     def _accumulate_metadata_lanes(self, rows2d, row_sel, issuers,

@@ -699,6 +699,10 @@ def main(argv: list[str] | None = None) -> int:
                 run_stage["stage"] = "saving"
                 model.save()
                 refresh_serve_filter()
+                # Between rounds, nothing in flight: a table near its
+                # growth threshold has the doubled table's programs
+                # made ready now, so the growth compiles nothing.
+                model.prepare_growth()
             if fleet is not None:
                 # This round's entries are durably folded: drop the
                 # fetch leases so next round's rightful owners (per the

@@ -88,3 +88,9 @@ class IngestModel:
     def save(self) -> None:
         if self.state_path:
             self.aggregator.save_checkpoint(self.state_path)
+
+    def prepare_growth(self) -> bool:
+        """A round's end, after the save: a table near its growth
+        threshold gets the doubled table's programs made ready
+        (`TpuAggregator.prepare_growth`); any other pays a compare."""
+        return self.aggregator.prepare_growth()

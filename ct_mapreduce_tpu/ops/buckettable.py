@@ -606,6 +606,38 @@ def pack_chunk_rows(n_buckets: int) -> int:
     return min(PACK_CHUNK, n_buckets * SLOTS)
 
 
+def _running_index(fill: jax.Array):
+    """The search structure over a per-bucket count: the inclusive
+    running sum of ``fill`` as rows of 128, then every row's last entry
+    again as rows of 128, and so on down to at most 1,024 entries."""
+    cur = jnp.cumsum(fill, dtype=jnp.int32)
+    index = []
+    while cur.shape[0] > _PACK_TOP:
+        level = cur.reshape(-1, _PACK_RADIX)
+        index.append(level)
+        cur = level[:, -1]
+    index.append(cur)
+    return tuple(index)
+
+
+def _locate(index, j: jax.Array):
+    """``(g, base)`` for counted items ``j``: the bucket an item lives
+    in = how many buckets' running sums are at most ``j``, found coarse
+    to fine (a dense compare against the top of ``index``, then one
+    128-entry row gather a level), and the largest running sum at most
+    ``j``: the items before that bucket."""
+    top = index[-1]
+    le = top[None, :] <= j[:, None]
+    g = jnp.sum(le, axis=1, dtype=jnp.int32)
+    base = jnp.max(jnp.where(le, top[None, :], 0), axis=1)
+    for level in reversed(index[:-1]):
+        row = level[jnp.minimum(g, level.shape[0] - 1)]  # [chunk, 128]
+        le = row <= j[:, None]
+        g = g * _PACK_RADIX + jnp.sum(le, axis=1, dtype=jnp.int32)
+        base = jnp.maximum(base, jnp.max(jnp.where(le, row, 0), axis=1))
+    return g, base
+
+
 def pack_index(rows: jax.Array):
     """``(fill uint8[nb], index)`` of a table's rows, traceable.
 
@@ -615,18 +647,9 @@ def pack_index(rows: jax.Array):
     compiler). A caller that must not trust the cache compares
     ``index[-1][-1]``, the occupied count the fills add up to, with
     the table's own ``count``. ``index`` is the search structure
-    :func:`pack_chunk` walks: the inclusive running sum of ``fill`` as
-    rows of 128, then every row's last entry again as rows of 128, and
-    so on down to at most 1,024 entries."""
+    :func:`pack_chunk` walks (:func:`_running_index`)."""
     fill = jnp.minimum(rows[:, FILL_WORD], SLOTS).astype(jnp.int32)
-    cur = jnp.cumsum(fill, dtype=jnp.int32)
-    index = []
-    while cur.shape[0] > _PACK_TOP:
-        level = cur.reshape(-1, _PACK_RADIX)
-        index.append(level)
-        cur = level[:, -1]
-    index.append(cur)
-    return fill.astype(jnp.uint8), tuple(index)
+    return fill.astype(jnp.uint8), _running_index(fill)
 
 
 def pack_chunk(rows: jax.Array, index, start: jax.Array, chunk: int):
@@ -637,24 +660,13 @@ def pack_chunk(rows: jax.Array, index, start: jax.Array, chunk: int):
     eight, so a chunk is 20 B a row in HBM too). Rows past the occupied
     count read 0.
 
-    Output row ``j`` lives in bucket ``b`` = how many buckets' running
-    sums are at most ``j``, found coarse to fine: a dense compare
-    against the top of ``index``, then one 128-entry row gather a
-    level; its slot is ``j`` less the largest running sum at most
-    ``j``. One gather of the bucket's row and five masked lane sums
-    pick the slot's words."""
+    Output row ``j`` lives in the bucket :func:`_locate` finds; its slot
+    is ``j`` less the largest running sum at most ``j``. One gather of
+    the bucket's row and five masked lane sums pick the slot's words."""
     nb = rows.shape[0]
     j = start + jnp.arange(chunk, dtype=jnp.int32)
-    top = index[-1]
-    le = top[None, :] <= j[:, None]
-    g = jnp.sum(le, axis=1, dtype=jnp.int32)
-    base = jnp.max(jnp.where(le, top[None, :], 0), axis=1)
-    for level in reversed(index[:-1]):
-        row = level[jnp.minimum(g, level.shape[0] - 1)]  # [chunk, 128]
-        le = row <= j[:, None]
-        g = g * _PACK_RADIX + jnp.sum(le, axis=1, dtype=jnp.int32)
-        base = jnp.maximum(base, jnp.max(jnp.where(le, row, 0), axis=1))
-    live = j < top[-1]
+    g, base = _locate(index, j)
+    live = j < index[-1][-1]
     bucket = rows[jnp.minimum(g, nb - 1)]  # [chunk, 128]
     off = (jnp.arange(ROW_WORDS, dtype=jnp.int32)[None, :]
            - ((j - base) * 5)[:, None])
@@ -688,3 +700,151 @@ def unpack_np(fill: np.ndarray, keys: np.ndarray,
     slots[bucket, slot, 4] = meta
     rows[:, FILL_WORD] = fill
     return rows
+
+
+# -- growth on the device ----------------------------------------------------
+#
+# ``_home_bucket`` is ``h & (n_buckets - 1)``: doubling the buckets
+# splits bucket ``b`` into ``b`` and ``b + n_buckets`` by one more bit
+# of ``h``, and a row that lies in its home bucket goes to one of the
+# two whatever else the table holds. So a table is grown by ONE
+# streaming pass over the old rows (a block of buckets at a time: read
+# a row, write two, each compacted to the front and with its fill
+# word), and only the rows that lay PAST a full bucket (~0.3% at load
+# 0.69) are inserted the ordinary way afterwards, a chunk at a time,
+# found among the old rows by the search index a packed save uses.
+# Every shape follows the two capacities; nothing follows the row
+# count, and no row leaves the device.
+
+#: Old buckets the split pass takes at a time (4 MB read, 8 MB written).
+SPLIT_BLOCK = 1 << 13
+#: Rows of one ordinary insert of rows that lay past a full bucket.
+REHOME_CHUNK = 1 << 16
+
+
+def _slot_at_home(blk: jax.Array, s: int, bucket: jax.Array, nb: int):
+    """``(words, h, occupied, home)`` of slot ``s`` of the buckets
+    ``blk`` (numbered ``bucket`` in a table of ``nb``): its five words
+    as [B] columns, its key's hash, whether it holds a row, and whether
+    that row lies in its home bucket."""
+    w = [blk[:, s * 5 + i] for i in range(5)]
+    occ = (w[0] | w[1] | w[2] | w[3]) != 0
+    h = w[0] ^ (w[1] * np.uint32(0x9E3779B9))
+    home = occ & ((h & np.uint32(nb - 1)).astype(jnp.int32) == bucket)
+    return w, h, occ, home
+
+
+def _split_block(blk: jax.Array, first: jax.Array, nb: int):
+    """``(lo, hi, past)`` of old buckets ``first .. first+B``: the rows
+    of each that lie in their home bucket, dealt to the two buckets the
+    doubled table has for it (compacted, fill word set), and how many
+    of its rows lie past their home (they stay behind for
+    :func:`past_home_chunk`)."""
+    b = blk.shape[0]
+    bucket = first + jnp.arange(b, dtype=jnp.int32)
+    # One 256-word row a bucket: ``lo`` in words 0..127, ``hi`` in
+    # 128..255, composed by the insert's select chain (the layout rule
+    # there: [B] vectors and full-width rows, nothing in between).
+    col = jnp.arange(2 * ROW_WORDS, dtype=jnp.int32)[None, :]
+    out = jnp.zeros((b, 2 * ROW_WORDS), jnp.uint32)
+    n_lo = jnp.zeros((b,), jnp.int32)
+    n_hi = jnp.zeros((b,), jnp.int32)
+    past = jnp.zeros((b,), jnp.int32)
+    for s in range(SLOTS):
+        w, h, occ, home = _slot_at_home(blk, s, bucket, nb)
+        hi = (h & np.uint32(nb)) != 0
+        tgt = jnp.where(hi, n_hi * 5 + ROW_WORDS, n_lo * 5)
+        off = col - tgt[:, None]
+        val = jnp.where(
+            off == 0, w[0][:, None],
+            jnp.where(off == 1, w[1][:, None],
+                      jnp.where(off == 2, w[2][:, None],
+                                jnp.where(off == 3, w[3][:, None],
+                                          w[4][:, None]))))
+        out = jnp.where(home[:, None] & (off >= 0) & (off < 5), val, out)
+        n_lo = n_lo + (home & ~hi).astype(jnp.int32)
+        n_hi = n_hi + (home & hi).astype(jnp.int32)
+        past = past + (occ & ~home).astype(jnp.int32)
+    out = jnp.where(col == FILL_WORD, n_lo.astype(jnp.uint32)[:, None], out)
+    out = jnp.where(col == ROW_WORDS + FILL_WORD,
+                    n_hi.astype(jnp.uint32)[:, None], out)
+    return out[:, :ROW_WORDS], out[:, ROW_WORDS:], past
+
+
+def split_rows(rows: jax.Array):
+    """``(rows of the doubled table, past int32[nb])``: every row that
+    lies in its home bucket, in the bucket the doubled table has for
+    it; per old bucket, how many rows were left behind."""
+    nb = rows.shape[0]
+    block = min(nb, SPLIT_BLOCK)
+
+    def body(i, carry):
+        new_rows, past = carry
+        first = i * block
+        lo, hi, p = _split_block(
+            jax.lax.dynamic_slice_in_dim(rows, first, block), first, nb)
+        new_rows = jax.lax.dynamic_update_slice_in_dim(new_rows, lo, first, 0)
+        new_rows = jax.lax.dynamic_update_slice_in_dim(
+            new_rows, hi, nb + first, 0)
+        return new_rows, jax.lax.dynamic_update_slice_in_dim(
+            past, p, first, 0)
+
+    return jax.lax.fori_loop(
+        0, nb // block, body,
+        (jnp.zeros((2 * nb, ROW_WORDS), jnp.uint32),
+         jnp.zeros((nb,), jnp.int32)))
+
+
+def past_home_chunk(rows: jax.Array, index, start: jax.Array, chunk: int):
+    """``(keys uint32[chunk, 4], meta uint32[chunk], valid bool[chunk])``:
+    rows ``start .. start+chunk``, in bucket order, of those that lie
+    past their home bucket; ``index`` is :func:`_running_index` of
+    :func:`split_rows`' ``past``. The bucket is found as
+    :func:`pack_chunk` finds it; the slot is the bucket's ``j - base``-th
+    that holds such a row."""
+    nb = rows.shape[0]
+    j = start + jnp.arange(chunk, dtype=jnp.int32)
+    g, base = _locate(index, j)
+    g = jnp.minimum(g, nb - 1)
+    blk = rows[g]  # [chunk, 128]
+    want = j - base
+    seen = jnp.zeros((chunk,), jnp.int32)
+    picked = [jnp.zeros((chunk,), jnp.uint32) for _ in range(5)]
+    for s in range(SLOTS):
+        w, _h, occ, home = _slot_at_home(blk, s, g, nb)
+        away = occ & ~home
+        take = away & (seen == want)
+        picked = [jnp.where(take, w[i], picked[i]) for i in range(5)]
+        seen = seen + away.astype(jnp.int32)
+    return jnp.stack(picked[:4], axis=1), picked[4], j < index[-1][-1]
+
+
+@functools.partial(jax.jit, static_argnames=("max_probes",))
+def grow_rehash(state: BucketTable, max_probes: int = 32):
+    """The table at twice the buckets: ``(new_state, rehomed int32[],
+    overflowed int32[])``, one program (XLA module ``jit_grow_rehash``:
+    the benchmark's readers find it by that name). ``rehomed`` rows lay
+    past a full bucket and were inserted the ordinary way (which keeps
+    the lookup invariant: every other row lies in its home bucket);
+    ``overflowed`` of them found no room within ``max_probes`` hops and
+    are NOT in the new table: the caller keeps the old one, which this
+    does not donate."""
+    new_rows, past = split_rows(state.rows)
+    index = _running_index(past)
+    rehomed = index[-1][-1]
+    table = BucketTable(new_rows, state.count - rehomed)
+
+    def cond(carry):
+        return carry[0] * REHOME_CHUNK < rehomed
+
+    def body(carry):
+        i, table, ovf = carry
+        keys, meta, valid = past_home_chunk(
+            state.rows, index, i * REHOME_CHUNK, REHOME_CHUNK)
+        table, _wu, o = insert(table, keys, meta, valid,
+                               max_probes=max_probes)
+        return i + 1, table, ovf + jnp.sum(o, dtype=jnp.int32)
+
+    _, table, ovf = jax.lax.while_loop(
+        cond, body, (jnp.int32(0), table, jnp.int32(0)))
+    return table, rehomed, ovf

@@ -123,6 +123,45 @@ def test_a_full_saves_packing_compiles_for_v5e_without_a_table_sized_temporary(
     assert "all-gather" not in text and "all-reduce" not in text
 
 
+def test_a_growths_split_compiles_for_v5e_a_block_at_a_time(one_chip):
+    """The two parts of ``buckettable.grow_rehash`` that are new in PR
+    48, at the 2^26-slot table (the third, the ordinary insert, is the
+    step's own and compiles for minutes): the split reads the old rows
+    a block of buckets at a time, so its temporaries are a block's and
+    its output is the doubled table and a count a bucket; a chunk of
+    the rows that lay past a full bucket is one 128-word gather."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from ct_mapreduce_tpu.ops import buckettable
+
+    def shaped(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    nb = 1 << 22
+    rows = jax.ShapeDtypeStruct((nb, 128), jnp.uint32, sharding=one_chip)
+    split = jax.jit(buckettable.split_rows).lower(rows).compile()
+    mem = split.memory_analysis()
+    assert mem.output_size_in_bytes >= 2 * nb * 512 + nb * 4
+    assert mem.temp_size_in_bytes < 64 << 20
+    _new, past = jax.eval_shape(buckettable.split_rows, rows)
+    index = jax.eval_shape(buckettable._running_index, past)
+    chunk = jax.jit(functools.partial(
+        buckettable.past_home_chunk, chunk=buckettable.REHOME_CHUNK)).lower(
+            rows, shaped(index),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    assert chunk.memory_analysis().temp_size_in_bytes < 1 << 30
+    keys, meta, valid = jax.eval_shape(
+        functools.partial(buckettable.past_home_chunk,
+                          chunk=buckettable.REHOME_CHUNK),
+        rows, index, jax.ShapeDtypeStruct((), jnp.int32))
+    assert keys.shape == (65536, 4) and meta.shape == valid.shape == (65536,)
+
+
 @pytest.mark.parametrize("width", [16, 4096])
 def test_the_mesh_probe_compiles_for_a_v5e_2x2_with_no_collective(topo, width):
     """The query plane's probe of a table sharded over four chips at
