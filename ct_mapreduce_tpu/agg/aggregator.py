@@ -803,7 +803,9 @@ class TpuAggregator:
             rows, rehomed, overflowed = (
                 int(x) for x in jax.device_get(
                     (new.count, rehomed, overflowed)))
-            sp.set(rows=rows, rehomed=rehomed)
+            sp.set(rows=rows, rehomed=rehomed,
+                   split=("mosaic" if buckettable.split_runs_compiled()
+                          else "interpret"))
         if overflowed:
             return None
         self.table = new

@@ -710,89 +710,212 @@ def unpack_np(fill: np.ndarray, keys: np.ndarray,
 # two whatever else the table holds. So a table is grown by ONE
 # streaming pass over the old rows (a block of buckets at a time: read
 # a row, write two, each compacted to the front and with its fill
-# word), and only the rows that lay PAST a full bucket (~0.3% at load
-# 0.69) are inserted the ordinary way afterwards, a chunk at a time,
+# word), and only the rows that lay PAST a full bucket (~0.5% at load
+# 0.70) are inserted the ordinary way afterwards, a chunk at a time,
 # found among the old rows by the search index a packed save uses.
 # Every shape follows the two capacities; nothing follows the row
 # count, and no row leaves the device.
+#
+# The layout rule of the insert holds here too, and more strictly:
+# every intermediate is a full [B, 128] row or a [B] vector made by a
+# row reduction, and NO column is sliced out of a row. Lane ``l`` of a
+# row is word ``l % 5`` of slot ``l // 5``, so a lane roll by one puts
+# every slot's second word under its first (all 24 hashes in one
+# multiply and XOR), one running sum along the lanes ranks every slot
+# among its class (:func:`_slot_classes`), and the word-parallel
+# compress moves the kept slots to the front (:func:`_compact`). The
+# split is a Pallas kernel a block of buckets (:func:`_split_kernel`):
+# ``pltpu.roll`` is the chip's lane rotate, and a block is dealt a
+# tile of rows at a time, so that the chain's intermediates stay a
+# tile's and only the block and its two halves cross to HBM.
+#
+# MEASURED (PR 49, one v5e, the split alone on 2^22 buckets at load
+# 0.69, every candidate's rows equal to the first's word for word):
+#   the select chain (PR 48)                              0.728 s
+#   this arithmetic under XLA (``jnp.roll``, a ``fori_loop``
+#     over blocks of 8,192 buckets)                       0.110 s
+#   this arithmetic as the Pallas kernel, 1,024-bucket blocks dealt
+#     8 / 16 / 32 / 64 / 128 / 256 / 512 / 1,024 rows at a time
+#       0.560 / 0.283 / 0.150 / 0.078 / 0.045 / 0.036 / 0.032 / 0.033 s
+# The faster one ships. The block's size moves nothing (128 .. 4,096
+# buckets read the same at a given tile); the rows dealt at a time do:
+# the chain is one dependent operation after the other, so its speed
+# is the number of independent registers each operation has in flight,
+# up to 512 rows (0.0315 s at 512-bucket blocks dealt whole: shipped).
+# Inside ``jit_grow_rehash`` the select chain took 1.03 s (6.3 GB/s,
+# 0.58% of what a copy of the same bytes takes: the [B] columns it
+# sliced out of a [B, 128] block, 120 lane-to-sublane extractions a
+# block, and the five-deep ``where`` that put each back over a
+# [B, 256] row cost far more than their count) and the program 1.346 s;
+# it now takes 0.32 s, 0.27 of it the six ordinary inserts of the
+# rows that lay past a full bucket.
 
-#: Old buckets the split pass takes at a time (4 MB read, 8 MB written).
-SPLIT_BLOCK = 1 << 13
+#: Old buckets one step of the split's grid takes.
+SPLIT_BLOCK = 512
+#: Rows of it the kernel deals at a time (the probe: all of them).
+SPLIT_TILE = 512
 #: Rows of one ordinary insert of rows that lay past a full bucket.
 REHOME_CHUNK = 1 << 16
 
-
-def _slot_at_home(blk: jax.Array, s: int, bucket: jax.Array, nb: int):
-    """``(words, h, occupied, home)`` of slot ``s`` of the buckets
-    ``blk`` (numbered ``bucket`` in a table of ``nb``): its five words
-    as [B] columns, its key's hash, whether it holds a row, and whether
-    that row lies in its home bucket."""
-    w = [blk[:, s * 5 + i] for i in range(5)]
-    occ = (w[0] | w[1] | w[2] | w[3]) != 0
-    h = w[0] ^ (w[1] * np.uint32(0x9E3779B9))
-    home = occ & ((h & np.uint32(nb - 1)).astype(jnp.int32) == bucket)
-    return w, h, occ, home
+# A slot's class, written over its five lanes; the three share a word
+# so that one running sum along the lanes counts all of them (a count
+# is at most 24, a field has five bits).
+_LO, _HI, _AWAY = 0, 5, 10
 
 
-def _split_block(blk: jax.Array, first: jax.Array, nb: int):
-    """``(lo, hi, past)`` of old buckets ``first .. first+B``: the rows
-    of each that lie in their home bucket, dealt to the two buckets the
-    doubled table has for it (compacted, fill word set), and how many
-    of its rows lie past their home (they stay behind for
+def _left(x: jax.Array, k: int) -> jax.Array:
+    """``x`` with every row rolled ``k`` lanes to the left, wrapping:
+    lane ``l`` takes lane ``l + k``. The XLA spelling; the split's
+    kernel passes the chip's lane rotate in its place."""
+    return jnp.roll(x, -k, axis=1)
+
+
+def _slot_classes(blk: jax.Array, bucket: jax.Array, nb: int, left=_left):
+    """``(cls, run, lane, slot)`` of bucket rows ``blk`` (numbered
+    ``bucket``, which broadcasts against them, in a table of ``nb``),
+    every one a full row, all 24 slots at once.
+
+    ``cls`` holds, on each of a slot's five lanes, ``1 << _LO`` where
+    the slot's row lies in its home bucket and the doubled table keeps
+    it in bucket ``b``, ``1 << _HI`` where it goes to ``b + nb``,
+    ``1 << _AWAY`` where the row lies past its home, 0 where the slot
+    is empty (and on words 120..127). ``run`` is the running sum of
+    ``cls`` over the slots up to and including a lane's own, so
+    ``run - cls`` is a slot's rank among its class, and from lane 120
+    on ``run`` is the row's three totals."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
+    slot = (lane * 52429) >> 18  # lane // 5, for a lane under 2^14
+    nxt = left(blk, 1)
+    # At a slot's first lane: its key's hash, and the OR of its four
+    # key words (lanes l .. l+3).
+    h = blk ^ (nxt * np.uint32(0x9E3779B9))
+    pair = blk | nxt
+    occupied = (pair | left(pair, 2)) != 0
+    home = jax.lax.bitcast_convert_type(
+        h & np.uint32(nb - 1), jnp.int32) == bucket
+    upper = (h & np.uint32(nb)) != 0
+    first = jnp.where(
+        occupied & (lane == slot * 5) & (lane < SLOTS * 5),
+        jnp.where(home, jnp.where(upper, 1 << _HI, 1 << _LO), 1 << _AWAY),
+        0)
+    two = first | left(first, ROW_WORDS - 1)
+    cls = two | left(two, ROW_WORDS - 2) | left(first, ROW_WORDS - 4)
+    run = cls
+    for k in (5, 10, 20, 40, 80):
+        run = run + jnp.where(lane >= k, left(run, ROW_WORDS - k), 0)
+    return cls, run, lane, slot
+
+
+def _compact(blk: jax.Array, keep: jax.Array, dist: jax.Array, left=_left):
+    """The rows ``blk`` with every kept slot moved ``dist`` slots to
+    the left and every other word zero. ``dist`` never falls along a
+    row (it is a slot's number less its rank among the kept), so the
+    word-parallel compress holds: five conditional rolls by 1, 2, 4, 8
+    and 16 slots, lowest bit first, the distance carried with the
+    data; two kept slots never meet, and none wraps."""
+    data = jnp.where(keep, blk, 0)
+    dist = jnp.where(keep, dist, 0)
+    for bit in range(5):
+        came, came_dist = left(data, 5 << bit), left(dist, 5 << bit)
+        arrives = (came_dist & (1 << bit)) != 0
+        leaves = (dist & (1 << bit)) != 0
+        data = jnp.where(arrives, came, jnp.where(leaves, 0, data))
+        dist = jnp.where(arrives, came_dist, jnp.where(leaves, 0, dist))
+    return data
+
+
+def _deal(blk: jax.Array, bucket: jax.Array, nb: int, left=_left):
+    """``(lo, hi, past)`` of old bucket rows ``blk``: the rows of each
+    that lie in their home bucket, dealt to the two buckets the doubled
+    table has for it (in slot order, compacted to the front, fill word
+    set, every other word zero), and, on every lane, how many of its
+    rows lie past their home (they stay behind for
     :func:`past_home_chunk`)."""
-    b = blk.shape[0]
-    bucket = first + jnp.arange(b, dtype=jnp.int32)
-    # One 256-word row a bucket: ``lo`` in words 0..127, ``hi`` in
-    # 128..255, composed by the insert's select chain (the layout rule
-    # there: [B] vectors and full-width rows, nothing in between).
-    col = jnp.arange(2 * ROW_WORDS, dtype=jnp.int32)[None, :]
-    out = jnp.zeros((b, 2 * ROW_WORDS), jnp.uint32)
-    n_lo = jnp.zeros((b,), jnp.int32)
-    n_hi = jnp.zeros((b,), jnp.int32)
-    past = jnp.zeros((b,), jnp.int32)
-    for s in range(SLOTS):
-        w, h, occ, home = _slot_at_home(blk, s, bucket, nb)
-        hi = (h & np.uint32(nb)) != 0
-        tgt = jnp.where(hi, n_hi * 5 + ROW_WORDS, n_lo * 5)
-        off = col - tgt[:, None]
-        val = jnp.where(
-            off == 0, w[0][:, None],
-            jnp.where(off == 1, w[1][:, None],
-                      jnp.where(off == 2, w[2][:, None],
-                                jnp.where(off == 3, w[3][:, None],
-                                          w[4][:, None]))))
-        out = jnp.where(home[:, None] & (off >= 0) & (off < 5), val, out)
-        n_lo = n_lo + (home & ~hi).astype(jnp.int32)
-        n_hi = n_hi + (home & hi).astype(jnp.int32)
-        past = past + (occ & ~home).astype(jnp.int32)
-    out = jnp.where(col == FILL_WORD, n_lo.astype(jnp.uint32)[:, None], out)
-    out = jnp.where(col == ROW_WORDS + FILL_WORD,
-                    n_hi.astype(jnp.uint32)[:, None], out)
-    return out[:, :ROW_WORDS], out[:, ROW_WORDS:], past
+    cls, run, lane, slot = _slot_classes(blk, bucket, nb, left)
+    before = run - cls
+    halves = []
+    for field in (_LO, _HI):
+        rows = _compact(blk, ((cls >> field) & 1) != 0,
+                        slot - ((before >> field) & 31), left)
+        fill = jax.lax.bitcast_convert_type((run >> field) & 31, jnp.uint32)
+        halves.append(jnp.where(lane == FILL_WORD, fill, rows))
+    return halves[0], halves[1], run >> _AWAY
+
+
+def split_runs_compiled() -> bool:
+    """Whether the split's kernel is compiled for the chip (Mosaic) or
+    interpreted, which is what every other backend gets: the same
+    arithmetic, row for row."""
+    return jax.default_backend() == "tpu"
+
+
+def _split_kernel(rows_ref, new_ref, past_ref, away_ref, *, nb: int,
+                  tile: int):
+    """One block of old buckets: ``rows_ref`` uint32[B, 128] in,
+    ``new_ref`` uint32[2, B, 128] (the block's buckets and their upper
+    twins) and ``past_ref`` int32[1, 1, B] out. The block is dealt
+    ``tile`` rows at a time, so that every intermediate of
+    :func:`_deal` is a tile's and none is the block's; ``away_ref``
+    float32[B, 128] keeps each row's count of rows past home (on lanes
+    120..127) until the block's counts are turned onto the lanes in
+    one product."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    block = rows_ref.shape[0]
+    first = pl.program_id(0) * block
+
+    def left(x, k):
+        return pltpu.roll(x, (ROW_WORDS - k) % ROW_WORDS, 1)
+
+    def deal_tile(t, carry):
+        at = pl.multiple_of(t * tile, tile)
+        bucket = (first + at) + jax.lax.broadcasted_iota(
+            jnp.int32, (tile, ROW_WORDS), 0)
+        lo, hi, away = _deal(rows_ref[pl.ds(at, tile), :], bucket, nb, left)
+        new_ref[0, pl.ds(at, tile), :] = lo
+        new_ref[1, pl.ds(at, tile), :] = hi
+        away_ref[pl.ds(at, tile), :] = away.astype(jnp.float32)
+        return carry
+
+    jax.lax.fori_loop(0, block // tile, deal_tile, 0)
+    # past[b] = away[b, 120]: a [8, 128] x [B, 128]^T product with ones
+    # on lane 120 (counts up to 24: exact in bf16, summed in f32).
+    pick = (jax.lax.broadcasted_iota(jnp.int32, (8, ROW_WORDS), 1)
+            == FILL_WORD).astype(jnp.bfloat16)
+    turned = jax.lax.dot_general(
+        pick, away_ref[...].astype(jnp.bfloat16),
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    past_ref[0] = turned[:1].astype(jnp.int32)
 
 
 def split_rows(rows: jax.Array):
     """``(rows of the doubled table, past int32[nb])``: every row that
     lies in its home bucket, in the bucket the doubled table has for
-    it; per old bucket, how many rows were left behind."""
+    it; per old bucket, how many rows were left behind. One Pallas
+    kernel over blocks of ``SPLIT_BLOCK`` old buckets: a block is read
+    once and its two halves written once."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
     nb = rows.shape[0]
     block = min(nb, SPLIT_BLOCK)
-
-    def body(i, carry):
-        new_rows, past = carry
-        first = i * block
-        lo, hi, p = _split_block(
-            jax.lax.dynamic_slice_in_dim(rows, first, block), first, nb)
-        new_rows = jax.lax.dynamic_update_slice_in_dim(new_rows, lo, first, 0)
-        new_rows = jax.lax.dynamic_update_slice_in_dim(
-            new_rows, hi, nb + first, 0)
-        return new_rows, jax.lax.dynamic_update_slice_in_dim(
-            past, p, first, 0)
-
-    return jax.lax.fori_loop(
-        0, nb // block, body,
-        (jnp.zeros((2 * nb, ROW_WORDS), jnp.uint32),
-         jnp.zeros((nb,), jnp.int32)))
+    new_rows, past = pl.pallas_call(
+        functools.partial(_split_kernel, nb=nb, tile=min(block, SPLIT_TILE)),
+        grid=(nb // block,),
+        in_specs=[pl.BlockSpec((block, ROW_WORDS), lambda i: (i, 0))],
+        out_specs=[
+            pl.BlockSpec((2, block, ROW_WORDS), lambda i: (0, i, 0)),
+            pl.BlockSpec((1, 1, block), lambda i: (i, 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((2, nb, ROW_WORDS), jnp.uint32),
+            jax.ShapeDtypeStruct((nb // block, 1, block), jnp.int32),
+        ],
+        scratch_shapes=[pltpu.VMEM((block, ROW_WORDS), jnp.float32)],
+        interpret=not split_runs_compiled(),
+    )(rows)
+    return new_rows.reshape(2 * nb, ROW_WORDS), past.reshape(nb)
 
 
 def past_home_chunk(rows: jax.Array, index, start: jax.Array, chunk: int):
@@ -801,21 +924,19 @@ def past_home_chunk(rows: jax.Array, index, start: jax.Array, chunk: int):
     past their home bucket; ``index`` is :func:`_running_index` of
     :func:`split_rows`' ``past``. The bucket is found as
     :func:`pack_chunk` finds it; the slot is the bucket's ``j - base``-th
-    that holds such a row."""
+    that holds such a row: one equality on the slots' ranks among them
+    and five masked lane sums."""
     nb = rows.shape[0]
     j = start + jnp.arange(chunk, dtype=jnp.int32)
     g, base = _locate(index, j)
     g = jnp.minimum(g, nb - 1)
     blk = rows[g]  # [chunk, 128]
-    want = j - base
-    seen = jnp.zeros((chunk,), jnp.int32)
-    picked = [jnp.zeros((chunk,), jnp.uint32) for _ in range(5)]
-    for s in range(SLOTS):
-        w, _h, occ, home = _slot_at_home(blk, s, g, nb)
-        away = occ & ~home
-        take = away & (seen == want)
-        picked = [jnp.where(take, w[i], picked[i]) for i in range(5)]
-        seen = seen + away.astype(jnp.int32)
+    cls, run, lane, slot = _slot_classes(blk, g[:, None], nb)
+    wanted = (((cls >> _AWAY) != 0)
+              & (((run - cls) >> _AWAY) == (j - base)[:, None]))
+    word = lane - slot * 5
+    picked = [jnp.sum(jnp.where(wanted & (word == i), blk, 0), axis=1,
+                      dtype=jnp.uint32) for i in range(5)]
     return jnp.stack(picked[:4], axis=1), picked[4], j < index[-1][-1]
 
 

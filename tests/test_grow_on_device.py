@@ -135,10 +135,161 @@ def test_grown_on_the_device_is_the_reference_grown_on_the_host(case):
     assert int(a.count) == int(b.count) == len(keys) + 96
 
 
+# -- the split against its restatement, word for word ------------------------
+
+
+def keys_hashing_to(rng, h: np.ndarray) -> np.ndarray:
+    """Random fingerprints whose hash is ``h``, every bit of it."""
+    keys = rng.integers(0, 1 << 32, size=(len(h), 4), dtype=np.uint64).astype(
+        np.uint32)
+    keys[:, 0] = h.astype(np.uint32) ^ (keys[:, 1] * MIX)
+    return keys
+
+
+def put(rows: np.ndarray, bucket: int, keys: np.ndarray, rng) -> None:
+    """``keys`` into the next free slots of ``bucket``, as an insert
+    would leave them (contiguous, fill word kept)."""
+    fill = int(rows[bucket, bt.FILL_WORD])
+    assert fill + len(keys) <= bt.SLOTS
+    slots = rows[:, : bt.SLOTS * 5].reshape(-1, bt.SLOTS, 5)
+    slots[bucket, fill:fill + len(keys), :4] = keys
+    slots[bucket, fill:fill + len(keys), 4] = rng.integers(
+        0, 1 << 32, size=len(keys), dtype=np.uint64).astype(np.uint32)
+    rows[bucket, bt.FILL_WORD] = fill + len(keys)
+
+
+def corner_table(corner: str, nb: int = 16) -> np.ndarray:
+    """A hand-built table around one bucket (5) in a given state, the
+    buckets around it ordinary."""
+    rng = np.random.default_rng(len(corner))
+    rows = np.zeros((nb, bt.ROW_WORDS), np.uint32)
+    above = rng.integers(0, 1 << 20, size=bt.SLOTS).astype(np.uint32) * (
+        2 * nb)  # the bits over the doubled table's
+    for b in (2, 3, 9):
+        put(rows, b, keys_at_home(rng, np.full(7, b), nb), rng)
+    if corner == "an-empty-bucket":
+        assert not rows[5].any() and not rows[0].any()
+    elif corner == "a-bucket-all-lo":
+        put(rows, 5, keys_hashing_to(rng, above + 5), rng)
+    elif corner == "a-bucket-all-hi":
+        put(rows, 5, keys_hashing_to(rng, above + nb + 5), rng)
+    elif corner == "lo-and-hi-interleaved":
+        h = above + 5 + nb * (np.arange(bt.SLOTS) % 2)
+        put(rows, 5, keys_hashing_to(rng, h), rng)
+    elif corner == "a-full-bucket-all-past-home":
+        # Bucket 4 is full of its own, so 24 more rows of home 4 hopped
+        # into bucket 5 and fill it; bucket 5's own row lies in 6.
+        put(rows, 4, keys_at_home(rng, np.full(bt.SLOTS, 4), nb), rng)
+        put(rows, 5, keys_at_home(rng, np.full(bt.SLOTS, 4), nb), rng)
+        put(rows, 6, keys_at_home(rng, np.array([5, 6, 6]), nb), rng)
+    elif corner == "home-and-past-home-interleaved":
+        homes = np.where(np.arange(20) % 3 == 1, 4, 5)
+        put(rows, 5, keys_at_home(rng, homes, nb), rng)
+    elif corner == "past-home-wraps-to-bucket-0":
+        put(rows, 0, keys_at_home(rng, np.array([nb - 1, 0, nb - 1]), nb),
+            rng)
+    else:
+        raise AssertionError(corner)
+    return rows
+
+
+def split_np(rows: np.ndarray):
+    """The split restated: ``(new rows, past, rows left behind)``. A
+    bucket's 24 slots in order; a slot that holds a row whose hash
+    names this bucket is dealt to ``b`` or ``b + nb`` by the hash's
+    next bit, behind the slots dealt there before it; any other
+    occupied slot is left behind (in bucket-then-slot order)."""
+    nb = rows.shape[0]
+    new = np.zeros((2 * nb, bt.ROW_WORDS), np.uint32)
+    past = np.zeros(nb, np.int32)
+    behind = []
+    for b in range(nb):
+        for s in range(bt.SLOTS):
+            slot = rows[b, 5 * s:5 * s + 5]
+            if not slot[:4].any():
+                continue
+            h = int(slot[0]) ^ (int(slot[1]) * int(MIX) & 0xFFFFFFFF)
+            if h & (nb - 1) != b:
+                past[b] += 1
+                behind.append(slot)
+                continue
+            to = b + (h & nb)
+            n = int(new[to, bt.FILL_WORD])
+            new[to, 5 * n:5 * n + 5] = slot
+            new[to, bt.FILL_WORD] = n + 1
+    return new, past, np.array(behind, np.uint32).reshape(-1, 5)
+
+
+SPLIT_CASES = {
+    # name: (how the table is made, SPLIT_BLOCK or None for the default)
+    "load-0.1": (lambda: table_at(10, 64, 0.1, ((7, 30),))[0], None),
+    "load-0.5": (lambda: table_at(50, 64, 0.5)[0], None),
+    "load-0.69": (lambda: table_at(69, 128, 0.69)[0], None),
+    "load-0.69-in-eight-blocks": (lambda: table_at(69, 128, 0.69)[0], 16),
+    "load-0.9-in-four-blocks": (lambda: table_at(90, 64, 0.9)[0], 16),
+    "crowded-buckets": (
+        lambda: table_at(31, 64, 0.3, ((5, 30), (6, 24), (40, 49)))[0], None),
+    "crowded-buckets-in-two-blocks": (
+        lambda: table_at(31, 64, 0.3, ((5, 30), (6, 24), (40, 49)))[0], 32),
+    "one-bucket": (lambda: table_at(1, 1, 0.5)[0], None),
+    "fewer-buckets-than-a-tile": (lambda: table_at(4, 4, 0.6)[0], None),
+}
+for _corner in ("an-empty-bucket", "a-bucket-all-lo", "a-bucket-all-hi",
+                "lo-and-hi-interleaved", "a-full-bucket-all-past-home",
+                "home-and-past-home-interleaved",
+                "past-home-wraps-to-bucket-0"):
+    SPLIT_CASES[_corner] = (
+        lambda corner=_corner: corner_table(corner), None)
+SPLIT_CASES["a-full-bucket-all-past-home-across-blocks"] = (
+    lambda: corner_table("a-full-bucket-all-past-home"), 8)
+
+
+@pytest.mark.parametrize("case", sorted(SPLIT_CASES))
+def test_the_split_is_its_restatement_word_for_word(case, monkeypatch):
+    """``split_rows`` against :func:`split_np`: the doubled table's
+    rows (slot order kept within each half, fill word, every spare
+    word zero) and ``past``; then ``past_home_chunk`` against the rows
+    the restatement left behind, in its order, a chunk of 16 at a time
+    (so a bucket's run of such rows is cut by a chunk's end)."""
+    make, block = SPLIT_CASES[case]
+    rows = make()
+    nb = rows.shape[0]
+    if block is not None:
+        assert nb > block
+        monkeypatch.setattr(bt, "SPLIT_BLOCK", block)
+        monkeypatch.setattr(bt, "SPLIT_TILE", 8)
+    else:
+        assert nb <= bt.SPLIT_BLOCK
+    want, want_past, behind = split_np(rows)
+    new_rows, past = jax.jit(bt.split_rows)(jnp.asarray(rows))
+    got = np.asarray(new_rows)
+    assert got.shape == (2 * nb, bt.ROW_WORDS) and got.dtype == np.uint32
+    assert (np.asarray(past) == want_past).all()
+    assert (got == want).all(), np.argwhere(got != want)[:4]
+    assert not got[:, bt.FILL_WORD + 1:].any()
+    if "past-home" in case:
+        assert len(behind) >= 2
+    if case == "a-full-bucket-all-past-home":
+        assert want_past[5] == bt.SLOTS and not want[5].any() \
+            and not want[5 + nb].any()
+    chunk = 16
+    index = bt._running_index(past)
+    assert int(index[-1][-1]) == len(behind)
+    fetch = jax.jit(bt.past_home_chunk, static_argnames=("chunk",))
+    for start in range(0, len(behind) + 1, chunk):
+        keys, meta, valid = (np.asarray(a) for a in fetch(
+            jnp.asarray(rows), index, jnp.int32(start), chunk=chunk))
+        n = min(chunk, len(behind) - start)
+        assert valid.tolist() == [True] * n + [False] * (chunk - n)
+        assert (keys[:n] == behind[start:start + n, :4]).all()
+        assert (meta[:n] == behind[start:start + n, 4]).all()
+
+
 def test_the_split_takes_a_table_a_block_at_a_time(monkeypatch):
     """More buckets than a block: the loop's slices land where a whole
     pass would put them."""
     monkeypatch.setattr(bt, "SPLIT_BLOCK", 16)
+    monkeypatch.setattr(bt, "SPLIT_TILE", 8)
     rows, keys, _meta = table_at(11, 64, 0.8)
     new_rows, past = jax.jit(bt.split_rows)(jnp.asarray(rows))
     got = np.asarray(new_rows)
@@ -295,6 +446,9 @@ def test_after_prepare_a_growth_its_step_and_its_save_compile_nothing(
         assert spans["grow.table"]["args"] == {
             "from_slots": 384, "to_slots": 768, "rows": 256}
         assert spans["grow.rehash"]["args"]["rows"] == 256
+        # Which of the split kernel's two code paths ran: off the chip,
+        # the interpreted one.
+        assert spans["grow.rehash"]["args"]["split"] == "interpret"
         assert spans["grow.rehash"]["args"]["rehomed"] \
             == got["grow.rehomed_rows"]
         assert spans["grow.rehash"]["parent"] == spans["grow.table"]["id"]
