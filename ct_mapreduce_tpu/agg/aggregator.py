@@ -2648,6 +2648,48 @@ class TpuAggregator:
 
         return jnp.asarray(arr)
 
+    def _unpack_base(self, fill: np.ndarray, keys: np.ndarray,
+                     meta: np.ndarray):
+        """A packed base's bucket rows, built where the table lives:
+        here on the device. The packed stream is put as the file holds
+        it (``occupied x 20 B`` and a byte and a running sum a bucket),
+        a piece at a time, each put riding beside the unpack of the
+        piece before, and ``buckettable.unpack_rows`` builds the rows
+        INTO the table this aggregator already has (donated; a table
+        of another shape is let go first), so at no instant do two
+        tables live on the device, and the pieces are freed as they are
+        consumed. The host-only snapshot reader overrides this with
+        ``unpack_np``. The fills are checked against the rows
+        (``packed_pieces``) before the table is touched or anything is
+        put."""
+        import jax
+        import jax.numpy as jnp
+
+        nb = int(fill.shape[0])
+        shape = (nb, buckettable.ROW_WORDS)
+        with trace.span("restore.put", cat="ckpt") as sp:
+            base, pieces = buckettable.packed_pieces(
+                fill, keys, meta, buckettable.unpack_piece_rows(nb))
+            rows, self.table = self.table.rows, None
+            if rows.shape != shape:
+                del rows  # gone before the table of the base's shape is made
+                rows = jnp.zeros(shape, jnp.uint32)
+            fill_d, base_d = jax.device_put(fill), jax.device_put(base)
+            block = min(nb, buckettable.UNPACK_BLOCK)
+            sent, count = fill.nbytes + base.nbytes, 0
+            for start, lo, hi, keys_piece, meta_piece in pieces:
+                rows = buckettable.unpack_rows(
+                    rows, fill_d, base_d, jax.device_put(keys_piece),
+                    jax.device_put(meta_piece), start, lo, hi, block=block)
+                sent += keys_piece.nbytes + meta_piece.nbytes
+                count += 1
+            sp.set(bytes=int(sent), pieces=count)
+        with trace.span("restore.unpack", cat="device",
+                        rows=int(keys.shape[0]), buckets=nb, where="device"):
+            rows.block_until_ready()
+        incr_counter("restore", "host_unpacked", value=0.0)
+        return rows
+
     @staticmethod
     def _occupied_rows(keys, meta, fill):
         """``(keys, meta, capacity)`` for the reinsertion path: a packed
@@ -2686,7 +2728,7 @@ class TpuAggregator:
             return
         if layout == "bucket":
             if fill is not None:
-                rows = buckettable.unpack_np(fill, keys, meta)
+                rows = self._unpack_base(fill, keys, meta)
             else:
                 slots = hashtable.fuse_rows(keys, meta)
                 rows = np.zeros((slots.shape[0] // buckettable.SLOTS,
@@ -2697,9 +2739,9 @@ class TpuAggregator:
                 # positional snapshots (and pre-round-5 ones
                 # especially) don't carry it.
                 buckettable.fill_counts_np(rows)
+                rows = self._asarray(rows)
             self.table = buckettable.BucketTable(
-                rows=self._asarray(rows), count=self._asarray(count),
-            )
+                rows=rows, count=self._asarray(count))
             self.capacity = rows.shape[0] * buckettable.SLOTS
         else:
             self.table = hashtable.TableState(
@@ -2714,8 +2756,13 @@ class TpuAggregator:
         hash-validates every link before anything is applied, so a
         torn tick (crash between segment and manifest renames) loads
         as the previous durable state, never a partial one."""
-        chain = ckpt.resolve_chain(path)
-        self._load_base(path)
+        with trace.span("restore.base", cat="ckpt") as sp:
+            with trace.span("restore.verify", cat="ckpt"):
+                chain = ckpt.resolve_chain(path)
+            self._load_base(path)
+            sp.set(rows=self._table_fill,
+                   buckets=getattr(self.table, "n_buckets", 0),
+                   bytes=os.path.getsize(path))
         for header, dev_rows, host_rows, blob in chain.segments:
             self._ckpt_replay_segment(header, dev_rows, host_rows, blob)
         if chain.segments:
@@ -2725,7 +2772,14 @@ class TpuAggregator:
                        len(chain.segments))
 
     def _load_base(self, path: str) -> None:
-        z = np.load(path, allow_pickle=True)
+        with trace.span("restore.read", cat="ckpt"):
+            z = np.load(path, allow_pickle=True)
+            keys, meta = np.asarray(z["keys"]), np.asarray(z["meta"])
+            count = np.asarray(z["count"])
+            # A `fill` member (PR 42 on) says keys / meta are the
+            # occupied slots in bucket order; a base without one is
+            # positional.
+            fill = np.asarray(z["fill"]) if "fill" in z else None
         # The table's members are (keys, meta, count) in every version;
         # `layout` (absent in pre-round-4 snapshots ⇒ open)
         # says how slot positions map back to a table structure, and
@@ -2735,15 +2789,10 @@ class TpuAggregator:
         # structure that wrote them.
         layout = str(z["layout"]) if "layout" in z else "open"
         ckpt_shards = int(z["n_shards"]) if "n_shards" in z else 1
-        # A `fill` member (PR 42 on) says keys / meta are the occupied
-        # slots in bucket order; a base without one is positional.
-        self._restore_table(
-            np.asarray(z["keys"]), np.asarray(z["meta"]),
-            np.asarray(z["count"]), layout, ckpt_shards,
-            fill=np.asarray(z["fill"]) if "fill" in z else None,
-        )
-        self._device_written = bool(np.asarray(z["count"]).sum() > 0)
-        self._table_fill = int(np.asarray(z["count"]).sum())
+        self._restore_table(keys, meta, count, layout, ckpt_shards, fill=fill)
+        del keys, meta, fill
+        self._device_written = bool(count.sum() > 0)
+        self._table_fill = int(count.sum())
         self._inflight_lanes = 0
         self.base_hour = int(z["base_hour"])
         self.registry = IssuerRegistry.from_json(z["registry"].tobytes().decode())
@@ -2828,6 +2877,17 @@ class HostSnapshotAggregator(TpuAggregator):
 
     def _asarray(self, arr: np.ndarray):
         return np.asarray(arr)
+
+    def _unpack_base(self, fill: np.ndarray, keys: np.ndarray,
+                     meta: np.ndarray):
+        """A report process must not claim the device: the rows are
+        built in NumPy (``restore.host_unpacked`` counts it)."""
+        with trace.span("restore.unpack", cat="ckpt",
+                        rows=int(keys.shape[0]), buckets=int(fill.shape[0]),
+                        where="host"):
+            rows = buckettable.unpack_np(fill, keys, meta)
+        incr_counter("restore", "host_unpacked", value=1.0)
+        return rows
 
     def _bulk_reinsert(self, keys: np.ndarray, meta: np.ndarray) -> int:
         """Host-only reinsertion (topology-mismatched snapshots must

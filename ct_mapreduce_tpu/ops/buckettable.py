@@ -681,25 +681,219 @@ pack_index_jit = jax.jit(pack_index)
 pack_chunk_jit = jax.jit(pack_chunk, static_argnames=("chunk",))
 
 
+def packed_ends(fill: np.ndarray, keys: np.ndarray,
+                meta: np.ndarray) -> np.ndarray:
+    """The inclusive running sum of a packed base's ``fill`` (int64), or
+    the ``ValueError`` of a base whose fills do not add up to its rows:
+    the check every reader of a packed base makes before it builds, or
+    puts, anything."""
+    ends = np.cumsum(fill, dtype=np.int64)
+    total = int(ends[-1]) if ends.shape[0] else 0
+    if total != keys.shape[0] or keys.shape[0] != meta.shape[0]:
+        raise ValueError(
+            f"packed base: {keys.shape[0]} keys and {meta.shape[0]} meta "
+            f"words for fills that sum to {total}")
+    if total and int(fill.max()) > SLOTS:
+        raise ValueError(
+            f"packed base: a fill of {int(fill.max())} where a bucket "
+            f"has {SLOTS} slots")
+    return ends
+
+
 def unpack_np(fill: np.ndarray, keys: np.ndarray,
               meta: np.ndarray) -> np.ndarray:
     """The rows a packed base was packed from, fill word included:
     bucket ``b`` takes the next ``fill[b]`` of ``keys`` / ``meta`` into
-    its first slots."""
+    its first slots. The plain reference of :func:`unpack_rows`, and
+    what a reader that may not touch a device builds its table with."""
     nb = fill.shape[0]
+    ends = packed_ends(fill, keys, meta)
     fill = fill.astype(np.int64)
-    if int(fill.sum()) != keys.shape[0] or keys.shape[0] != meta.shape[0]:
-        raise ValueError(
-            f"packed base: {keys.shape[0]} keys and {meta.shape[0]} meta "
-            f"words for fills that sum to {int(fill.sum())}")
     rows = np.zeros((nb, ROW_WORDS), np.uint32)
     bucket = np.repeat(np.arange(nb), fill)
-    slot = np.arange(keys.shape[0]) - np.repeat(np.cumsum(fill) - fill, fill)
+    slot = np.arange(keys.shape[0]) - np.repeat(ends - fill, fill)
     slots = rows[:, : SLOTS * 5].reshape(nb, SLOTS, 5)
     slots[bucket, slot, :4] = keys
     slots[bucket, slot, 4] = meta
     rows[:, FILL_WORD] = fill
     return rows
+
+
+# -- a packed base, unpacked on the device ------------------------------------
+#
+# The save's mirror: the packed stream goes to the device as the file
+# holds it (``keys`` flat as rows of 128 lanes, 32 packed rows each;
+# ``meta`` flat, 128 a row; a byte a bucket of ``fill``) and the bucket
+# rows are built there. Bucket ``b``'s row is a window of the stream:
+# its key words are flat words ``4 * base[b] ..`` (96 at most, so they
+# lie in two adjacent 128-lane rows), its meta words are
+# ``base[b] ..`` (24 at most: two adjacent rows again), ``base`` the
+# exclusive running sum of ``fill``. Two aligned row gathers, one
+# select and one lane roll by the window's offset bring each window to
+# lane 0; a fixed spread then moves key word ``4s + w`` to lane
+# ``5s + w`` and meta word ``s`` to lane ``5s + 4``. Every intermediate
+# is a full [B, 128] row or a [B] vector; no column is sliced out of a
+# row (the layout rule of the growth, below).
+#
+# The stream goes over a PIECE at a time (a fixed count of packed rows
+# and a halo, zero-padded at the stream's end), so that no compiled
+# shape follows the row count and a piece's transfer rides beside the
+# unpack of the one before. A piece builds the buckets whose first row
+# lies in it, a block of buckets at a time, into the table it is given
+# (donated): the table the constructor made is the one restored.
+
+#: Packed rows of one piece (20 B a row: 84 MB a piece).
+UNPACK_PIECE = 1 << 22
+#: Packed rows a piece carries past its end: the bucket that starts on
+#: a piece's last row ends 23 rows later (a whole 128-lane row of meta).
+UNPACK_HALO = 128
+#: Buckets one step of the piece's loop builds.
+UNPACK_BLOCK = 8192
+
+
+def unpack_piece_rows(n_buckets: int) -> int:
+    """Packed rows of a piece for a table of ``n_buckets`` (a table
+    smaller than ``UNPACK_PIECE`` is one piece), in whole 128-lane rows
+    of meta words."""
+    return min(UNPACK_PIECE, -(-n_buckets * SLOTS // ROW_WORDS) * ROW_WORDS)
+
+
+def _spread_steps(src: np.ndarray, dst: np.ndarray):
+    """The conditional rolls to the right, ``[(k, arrives bool[128])]``,
+    that take lane ``src[i]`` of a row to lane ``dst[i]`` for every
+    ``i`` (distances that never fall along the row, highest bit first:
+    the word-parallel expand, :func:`_compact` backwards). Worked out
+    here once, in NumPy: the pattern is every row's."""
+    at = np.full(ROW_WORDS, -1)
+    dist = np.zeros(ROW_WORDS, np.int64)
+    at[src], dist[src] = np.arange(src.shape[0]), dst - src
+    steps = []
+    for bit in reversed(range(7)):
+        k = 1 << bit
+        came_at, came_dist = np.roll(at, k), np.roll(dist, k)
+        arrives = (came_at >= 0) & ((came_dist & k) != 0)
+        leaves = (at >= 0) & ((dist & k) != 0)
+        if not arrives.any():
+            continue
+        if (arrives & (at >= 0) & ~leaves).any():
+            raise AssertionError("two lanes of a spread meet")
+        at = np.where(arrives, came_at, np.where(leaves, -1, at))
+        dist = np.where(arrives, came_dist - k, np.where(leaves, 0, dist))
+        steps.append((k, arrives))
+    if not np.array_equal(at[dst], np.arange(src.shape[0])):
+        raise AssertionError("a spread that does not arrive")
+    return steps
+
+
+_KEY_LANES = np.arange(SLOTS * 4)
+#: Key word ``4s + w`` of a window at lane 0 to lane ``5s + w``.
+_KEY_SPREAD = _spread_steps(_KEY_LANES, _KEY_LANES + _KEY_LANES // 4)
+#: Meta word ``s`` of a window at lane 4 to lane ``5s + 4``: the
+#: distance ``4s`` has the key spread's five bits, two places up.
+_META_AT = 4
+_META_SPREAD = _spread_steps(np.arange(SLOTS) + _META_AT,
+                             np.arange(SLOTS) * 5 + 4)
+_META_LANES = np.isin(np.arange(ROW_WORDS), np.arange(SLOTS) * 5 + 4)
+
+
+def _window_to_lane0(lane, first, second, offset, bits, shift=0):
+    """Rows whose lane ``i`` is word ``offset + i - shift`` of
+    ``first`` and ``second`` laid end to end (``offset`` int32[B, 1],
+    under 128 and with only ``bits`` set): one select between the two,
+    then a roll to the left by ``offset - shift``, a conditional roll a
+    bit."""
+    x = jnp.where(lane < offset, second, first)
+    amount = (offset - shift) & (ROW_WORDS - 1)
+    for bit in bits:
+        x = jnp.where((amount & (1 << bit)) != 0,
+                      jnp.roll(x, -(1 << bit), axis=1), x)
+    return x
+
+
+def _spread(x, steps):
+    for k, arrives in steps:
+        x = jnp.where(arrives[None, :], jnp.roll(x, k, axis=1), x)
+    return x
+
+
+@functools.partial(jax.jit, static_argnames=("block",), donate_argnums=(0,))
+def unpack_rows(rows: jax.Array, fill: jax.Array, base: jax.Array,
+                keys_piece: jax.Array, meta_piece: jax.Array,
+                start: jax.Array, lo: jax.Array, hi: jax.Array, *,
+                block: int):
+    """``rows`` (donated) with buckets ``lo .. hi`` built from one piece
+    of a packed base; XLA module ``jit_unpack_rows``.
+
+    ``fill`` uint8[nb] and ``base`` int32[nb] (its exclusive running
+    sum) are the whole table's; ``keys_piece`` uint32[(P + halo) / 32,
+    128] and ``meta_piece`` uint32[(P + halo) / 128, 128] are packed
+    rows ``start .. start + P + halo`` as the file holds them;
+    ``lo .. hi`` are the buckets whose first packed row lies in
+    ``start .. start + P`` (every other bucket of a block keeps the row
+    it has). Shapes follow the bucket count and the piece: none follows
+    the row count."""
+    piece = meta_piece.shape[0] * ROW_WORDS - UNPACK_HALO
+    lane = jax.lax.broadcasted_iota(jnp.int32, (block, ROW_WORDS), 1)
+    meta_lane = jnp.asarray(_META_LANES)[None, :]
+
+    def build(blk, rows):
+        b0 = blk * block
+        f = jax.lax.dynamic_slice(fill, (b0,), (block,)).astype(jnp.int32)
+        at = jax.lax.dynamic_slice(base, (b0,), (block,)) - start
+        mine = (at >= 0) & (at < piece)
+        at = jnp.clip(at, 0, piece - 1)
+        word = at * 4
+        kq, mq = word >> 7, at >> 7
+        keys = _window_to_lane0(
+            lane, keys_piece[kq], keys_piece[kq + 1],
+            (word & (ROW_WORDS - 1))[:, None], bits=(2, 3, 4, 5, 6))
+        meta = _window_to_lane0(
+            lane, meta_piece[mq], meta_piece[mq + 1],
+            (at & (ROW_WORDS - 1))[:, None], bits=range(7), shift=_META_AT)
+        row = jnp.where(meta_lane, _spread(meta, _META_SPREAD),
+                        _spread(keys, _KEY_SPREAD))
+        row = jnp.where(lane < 5 * f[:, None], row, 0)
+        row = jnp.where(lane == FILL_WORD, f[:, None].astype(jnp.uint32), row)
+        old = jax.lax.dynamic_slice(rows, (b0, 0), (block, ROW_WORDS))
+        return jax.lax.dynamic_update_slice(
+            rows, jnp.where(mine[:, None], row, old), (b0, 0))
+
+    return jax.lax.fori_loop(lo // block, (hi + block - 1) // block,
+                             build, rows)
+
+
+def packed_pieces(fill: np.ndarray, keys: np.ndarray, meta: np.ndarray,
+                  piece: int):
+    """``(base int32[nb], pieces)`` of a packed base for
+    :func:`unpack_rows`, on the host, or :func:`packed_ends`'
+    ``ValueError``: ``pieces`` yields ``(start, lo, hi, keys_piece,
+    meta_piece)``, the stream cut every ``piece`` rows.
+    A piece is a view of ``keys`` / ``meta`` (no copy) but for the
+    stream's end, which is padded with zeros. Every bucket's first row
+    lies in exactly one piece (an empty bucket's is the next bucket's;
+    past the last row, the row count's own piece), so the pieces
+    together write every bucket."""
+    nb, rows = fill.shape[0], keys.shape[0]
+    base = packed_ends(fill, keys, meta) - fill
+    starts = np.arange(rows // piece + 1, dtype=np.int64) * piece
+    cuts = np.append(np.searchsorted(base, starts, side="left"), nb)
+    span = piece + UNPACK_HALO
+
+    def window(flat, first, length):
+        part = flat[first:first + length]
+        if part.shape[0] < length:
+            part = np.concatenate(
+                [part, np.zeros(length - part.shape[0], flat.dtype)])
+        return part.reshape(-1, ROW_WORDS)
+
+    def pieces():
+        kflat, mflat = keys.reshape(-1), meta.reshape(-1)
+        for i, start in enumerate(starts):
+            yield (np.int32(start), np.int32(cuts[i]), np.int32(cuts[i + 1]),
+                   window(kflat, 4 * start, 4 * span),
+                   window(mflat, start, span))
+
+    return base.astype(np.int32), pieces()
 
 
 # -- growth on the device ----------------------------------------------------

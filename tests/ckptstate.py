@@ -51,6 +51,16 @@ def ckpt_churn(agg, eh: int, n: int, start: int) -> None:
     PRE-PARSED lane — the bulk-reinsert path build_aggregator uses
     bypasses fold-time dirty logging, which is fine for the base
     corpus but would make incremental-checkpoint churn invisible."""
+    res = fold_serials(agg, eh, n, start)
+    assert int(res.was_unknown.sum()) == n, (
+        f"churn batch not fresh: {int(res.was_unknown.sum())}/{n} "
+        "unknown (counter overlap with the base corpus?)")
+
+
+def fold_serials(agg, eh: int, n: int, start: int):
+    """Fold synthetic serials ``start .. start + n`` through the
+    pre-parsed lane, fresh or not; the fold's result (``was_unknown``
+    a lane)."""
     import numpy as np
 
     from ct_mapreduce_tpu.core import packing
@@ -75,12 +85,9 @@ def ckpt_churn(agg, eh: int, n: int, start: int) -> None:
         spki_off=zeros, spki_len=zeros, crldp_off=zeros,
         crldp_len=zeros,
     )
-    res = agg.ingest_preparsed(
+    return agg.ingest_preparsed(
         sc, np.zeros((n,), np.int32), np.ones((n,), bool),
         serials, np.full((n,), s, np.int32))
-    assert int(res.was_unknown.sum()) == n, (
-        f"churn batch not fresh: {int(res.was_unknown.sum())}/{n} "
-        "unknown (counter overlap with the base corpus?)")
 
 
 def ckpt_state_digest(agg) -> str:

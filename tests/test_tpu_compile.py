@@ -175,6 +175,41 @@ def test_a_growths_split_compiles_for_v5e_a_block_at_a_time(
     assert "tpu_custom_call" in lowered
 
 
+@pytest.mark.parametrize("bits", [22, 23])
+def test_a_restore_s_unpack_compiles_for_v5e_into_the_table_it_is_given(
+        one_chip, bits):
+    """``buckettable.unpack_rows`` at the two restoring cells' tables
+    (2^22 and 2^23 buckets: ``backfill-1log-growing``,
+    ``backfill-1log-loaded``): the table is aliased to the output (no
+    second one), a piece costs its own bytes on the device (20 B a
+    packed row: no small minor dimension), the loop over blocks of
+    buckets has no temporary of the table's size, and the program
+    lowers under the name ``docs/METRICS.md`` gives it."""
+    import jax
+    import jax.numpy as jnp
+
+    from ct_mapreduce_tpu.ops import buckettable
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    nb = 1 << bits
+    piece = buckettable.unpack_piece_rows(nb)
+    assert piece == 1 << 22
+    span = piece + buckettable.UNPACK_HALO
+    scalar = shaped((), jnp.int32)
+    args = (shaped((nb, 128), jnp.uint32), shaped((nb,), jnp.uint8),
+            shaped((nb,), jnp.int32), shaped((span // 32, 128), jnp.uint32),
+            shaped((span // 128, 128), jnp.uint32), scalar, scalar, scalar)
+    lowered = buckettable.unpack_rows.lower(
+        *args, block=buckettable.UNPACK_BLOCK)
+    assert "module @jit_unpack_rows" in lowered.as_text()
+    mem = lowered.compile().memory_analysis()
+    assert mem.alias_size_in_bytes == mem.output_size_in_bytes == nb * 512
+    assert mem.argument_size_in_bytes - nb * 512 <= span * 20 + nb * 5 + (64 << 10)
+    assert mem.temp_size_in_bytes < 64 << 20
+
+
 @pytest.mark.parametrize("width", [16, 4096])
 def test_the_mesh_probe_compiles_for_a_v5e_2x2_with_no_collective(topo, width):
     """The query plane's probe of a table sharded over four chips at
