@@ -277,7 +277,8 @@ class PendingIngest:
             # is preserved because every completer — the sink's drain
             # and complete_outstanding alike — takes the OLDEST pending
             # first and blocks on its per-pending lock.
-            with trace.span("device.fold", cat="device"), agg._fold_lock:
+            with trace.span("device.fold", cat="device") as sp, \
+                    agg._fold_lock:
                 with contextlib.suppress(ValueError):
                     agg._outstanding.remove(self)
                 agg._inflight_lanes = max(
@@ -295,6 +296,7 @@ class PendingIngest:
                     )
                 agg.metrics["host_lane"] += host_lane_total
                 res.host_lane_count = host_lane_total
+                agg._filter_fold_done(sp)
                 incr_counter("aggregator", "batches")
             return self._res
 
@@ -313,6 +315,8 @@ class _PreparsedPlan:
     f_ca: np.ndarray  # bool[n]
     f_expired: np.ndarray
     f_cn: np.ndarray
+    cn_passed: np.ndarray  # the CN predicate's yes and its "the host
+    cn_undec: np.ndarray  # lane decides"; all False without a filter
     passed: np.ndarray
     insertable: np.ndarray
     static_host_lane: np.ndarray  # host-lane lanes known before insert
@@ -322,6 +326,27 @@ class _PreparsedPlan:
     n: int
     chunk: int  # device chunk width (batch_size)
     flag_cap: int
+
+
+@dataclass
+class _FilterFold:
+    """What the CN filter decided in the fold under way (its holder has
+    the fold lock): lanes by the device's or the pre-parsed lane's
+    verdict, the positions handed to the exact host lane because
+    neither could decide, and what that lane dropped of them.
+    ``TpuAggregator._filter_fold_done`` emits and clears it."""
+
+    passed: int = 0
+    dropped: int = 0
+    undecided: set = field(default_factory=set)  # positions in the result
+    host_dropped: int = 0
+    filtered_cn: int = 0  # every lane the fold dropped by its CN
+
+
+#: Rows of the device's prefix array under a CN filter, whatever the
+#: directive holds: the source's one prefix and a CA's family of names
+#: fit, and a longer list takes the next power of two (one more program).
+CN_PREFIX_ROWS = 8
 
 
 class PendingPreparsed:
@@ -348,12 +373,14 @@ class PendingPreparsed:
                 return self._res
             self._done = True
             agg = self._agg
-            with trace.span("device.fold", cat="device"), agg._fold_lock:
+            with trace.span("device.fold", cat="device") as sp, \
+                    agg._fold_lock:
                 with contextlib.suppress(ValueError):
                     agg._outstanding.remove(self)
                 agg._inflight_lanes = max(
                     0, agg._inflight_lanes - len(self._res.was_unknown))
                 agg._fold_preparsed(self._out, self._plan, self._res)
+                agg._filter_fold_done(sp)
                 incr_counter("aggregator", "batches")
             return self._res
 
@@ -389,11 +416,15 @@ def _pack_out(out):
     Every separate device-buffer read is its own D2H round trip, so
     the consume path fetches one packed array instead of twelve
     buffers. Cached per output type (StepOut/ShardedStepOut
-    carry different flag sets); jit itself caches per shape."""
+    carry different flag sets) and per filter on or off (under a CN
+    filter the flags word also says what the predicate said of each
+    lane; without one the program is the one it was); jit itself caches
+    per shape."""
     import jax
     import jax.numpy as jnp
 
-    key = type(out)
+    cn_said = out.cn_undecidable is not None
+    key = (type(out), cn_said)
     fn = _pack_out_cache.get(key)
     if fn is None:
         has_dropped = hasattr(out, "dispatch_dropped")
@@ -410,6 +441,10 @@ def _pack_out(out):
                 | ((o.dispatch_dropped.astype(jnp.int32) << 6)
                    if has_dropped else 0)
             )
+            if cn_said:
+                flags = (flags
+                         | (o.cn_undecidable.astype(jnp.int32) << 7)
+                         | (o.cn_passed.astype(jnp.int32) << 8))
             return jnp.stack(
                 [flags, o.not_after_hour, o.serial_len,
                  o.crldp_off, o.crldp_len,
@@ -580,6 +615,7 @@ class TpuAggregator:
         # groups' cascades carry over between emissions.
         self._filter_build_cache = None
         self.set_cn_prefixes(cn_prefixes)
+        self._cn_fold = _FilterFold()
         self.metrics: dict[str, int] = {
             "inserted": 0, "known": 0, "filtered_ca": 0, "filtered_expired": 0,
             "filtered_cn": 0, "host_lane": 0, "parse_errors": 0, "overflow": 0,
@@ -959,22 +995,79 @@ class TpuAggregator:
 
     # -- config ----------------------------------------------------------
     def set_cn_prefixes(self, prefixes: tuple[str, ...]) -> None:
+        """The directive's prefixes as the step takes them: ONE shape
+        whatever they are, ``uint8[CN_PREFIX_ROWS, K]`` with ``K`` the
+        widest window the device serves, so an edit of the directive
+        compiles nothing (a first compile of the step is minutes on the
+        chip). Unused rows are dead (lengths -1: they match nothing);
+        an empty element is a live row of length 0 and matches every
+        name, as the reference's ``strings.HasPrefix`` does. A prefix
+        longer than ``K`` is compared on its head; head-matching lanes
+        route to the exact host lane (pipeline._cn_prefix_match
+        "undecidable"), so the device never silently decides on a
+        truncated prefix. No prefix at all is the unfiltered step's own
+        shape, as it always was."""
         self.cn_prefixes = tuple(prefixes)
+        if not prefixes:
+            self._prefix_arr = np.zeros((0, 1), np.uint8)
+            self._prefix_lens = np.zeros((0, 2), np.int32)
+            return
         encoded = [p.encode("utf-8") for p in prefixes]
-        # Device window sized to the longest prefix, capped at what a
-        # single fixed window can serve. Prefixes longer than the cap
-        # are compared on their head; head-matching lanes route to the
-        # exact host lane (pipeline._cn_prefix_match "undecidable"),
-        # so the device never silently decides on a truncated prefix.
-        cap = der_kernel.MAX_FIXED_WINDOW_BYTES
-        k = max(1, min(cap, max((len(b) for b in encoded), default=1)))
-        arr = np.zeros((len(prefixes), k), np.uint8)
-        lens = np.zeros((len(prefixes), 2), np.int32)
+        k = der_kernel.MAX_FIXED_WINDOW_BYTES
+        rows = max(CN_PREFIX_ROWS, 1 << (len(encoded) - 1).bit_length())
+        if rows > CN_PREFIX_ROWS:
+            print(f"issuerCNFilter has {len(encoded)} prefixes: the ingest "
+                  f"step takes {rows} rows of them, not {CN_PREFIX_ROWS} "
+                  "(a program of its own)", file=sys.stderr)
+        arr = np.zeros((rows, k), np.uint8)
+        lens = np.full((rows, 2), -1, np.int32)
         for i, b in enumerate(encoded):
             head = b[:k]
             arr[i, : len(head)] = np.frombuffer(head, np.uint8)
             lens[i] = (len(head), len(b))
         self._prefix_arr, self._prefix_lens = arr, lens
+
+    def _count_filtered(self, ca: int = 0, expired: int = 0,
+                        cn: int = 0) -> None:
+        """Lanes the filters dropped, counted where the reference counts
+        them (``certIsFilteredOut``, ct-fetch.go:44-70): in this
+        aggregator's ``metrics`` and in the process's
+        ``ct-fetch.certIsFilteredOut.{CA,expired,cn}`` counters, which
+        ``DatabaseSink`` keeps for the other backends. Called once a
+        lane: by the fold for what the device or the pre-parsed lane
+        decided, by the exact host lane for what it decided. Under a CN
+        filter ``.cn`` moves on every call, by 0 too, so that "none was
+        dropped" reads 0."""
+        self._cn_fold.filtered_cn += cn
+        self.metrics["filtered_ca"] += ca
+        self.metrics["filtered_expired"] += expired
+        self.metrics["filtered_cn"] += cn
+        if ca:
+            incr_counter("ct-fetch", "certIsFilteredOut", "CA",
+                         value=float(ca))
+        if expired:
+            incr_counter("ct-fetch", "certIsFilteredOut", "expired",
+                         value=float(expired))
+        if cn or self.cn_prefixes:
+            incr_counter("ct-fetch", "certIsFilteredOut", "cn",
+                         value=float(cn))
+
+    def _filter_fold_done(self, span=None) -> None:
+        """The end of one batch's fold (the caller holds the fold lock):
+        under a CN filter, what the predicate decided goes to the
+        ``filter.`` counters, 0 included, and the batch's drops onto the
+        fold's span; without one nothing is emitted."""
+        fold, self._cn_fold = self._cn_fold, _FilterFold()
+        if not self.cn_prefixes:
+            return
+        if span is not None:
+            span.set(filtered_cn=fold.filtered_cn)
+        incr_counter("filter", "cn_passed", value=float(fold.passed))
+        incr_counter("filter", "cn_dropped", value=float(fold.dropped))
+        incr_counter("filter", "cn_undecidable",
+                     value=float(len(fold.undecided)))
+        incr_counter("filter", "cn_host_dropped",
+                     value=float(fold.host_dropped))
 
     def _now_hour(self) -> int:
         now = self._fixed_now or datetime.now(timezone.utc)
@@ -1239,6 +1332,8 @@ class TpuAggregator:
                 )
         self.metrics["host_lane"] += host_lane_total
         res.host_lane_count = host_lane_total
+        with self._fold_lock:
+            self._filter_fold_done()
         incr_counter("aggregator", "batches")
         return res
 
@@ -1393,10 +1488,12 @@ class TpuAggregator:
         if self.cn_prefixes:
             cn_hit, cn_undec0 = self._cn_verdict_np(
                 host_rows, sidecar.cn_off, sidecar.cn_len)
-            cn_undec = ok & ~f_ca & ~f_expired & ~cn_hit & cn_undec0
-            f_cn = ok & ~f_ca & ~f_expired & ~cn_hit & ~cn_undec
+            reached = ok & ~f_ca & ~f_expired
+            cn_passed = reached & cn_hit
+            cn_undec = reached & ~cn_hit & cn_undec0
+            f_cn = reached & ~cn_hit & ~cn_undec
         else:
-            f_cn = cn_undec = np.zeros_like(ok)
+            f_cn = cn_undec = cn_passed = np.zeros_like(ok)
         passed = ok & ~f_ca & ~f_expired & ~f_cn
 
         hour_off = nah.astype(np.int64) - self.base_hour
@@ -1450,7 +1547,8 @@ class TpuAggregator:
         )
         plan = _PreparsedPlan(
             sidecar=sidecar, issuer_idx=issuer_idx, valid=valid, f_ca=f_ca,
-            f_expired=f_expired, f_cn=f_cn, passed=passed,
+            f_expired=f_expired, f_cn=f_cn, cn_passed=cn_passed,
+            cn_undec=cn_undec, passed=passed,
             insertable=insertable, static_host_lane=static_host_lane,
             serial_bytes=serial_bytes, host_rows=host_rows,
             length=np.asarray(length, np.int32), n=n, chunk=b,
@@ -1462,14 +1560,18 @@ class TpuAggregator:
 
     def _cn_verdict_np(self, rows: np.ndarray, cn_off: np.ndarray,
                        cn_len: np.ndarray):
-        """Host mirror of ``pipeline._cn_prefix_match`` — same K-byte
-        device window, same truncated-prefix "undecidable" routing, so
-        the pre-parsed lane spills exactly the lanes the walker lane
-        spills (the host could decide long prefixes exactly, but then
-        the two lanes would disagree on host-lane counts)."""
+        """Host mirror of ``pipeline._cn_prefix_match`` — same arrays,
+        same K-byte device window, same dead rows, same "undecidable"
+        routing of a truncated prefix and of a name the scan could not
+        say (``cn_len`` -1), so the pre-parsed lane spills exactly the
+        lanes the walker lane spills (the host could decide long
+        prefixes exactly, but then the two lanes would disagree on
+        host-lane counts)."""
         prefixes, lens = self._prefix_arr, self._prefix_lens
         k = prefixes.shape[1]
         n = rows.shape[0]
+        unsaid = cn_len < 0
+        cn_len = np.maximum(cn_len, 0)
         cols = cn_off[:, None].astype(np.int64) + np.arange(k)
         oob = cols >= rows.shape[1]
         np.clip(cols, 0, rows.shape[1] - 1, out=cols)
@@ -1479,14 +1581,14 @@ class TpuAggregator:
         dev_lens, true_lens = lens[:, 0], lens[:, 1]
         eq = window[:, None, :] == prefixes[None, :, :]
         care = np.arange(k)[None, None, :] < dev_lens[None, :, None]
-        full = np.all(eq | ~care, axis=-1)
+        full = np.all(eq | ~care, axis=-1) & (dev_lens >= 0)[None, :]
         truncated = (true_lens > dev_lens)[None, :]
         hit = np.any(
             full & (cn_len[:, None] >= dev_lens[None, :]) & ~truncated,
             axis=-1)
-        undec = np.any(
+        undec = ~hit & (unsaid | np.any(
             full & (cn_len[:, None] >= true_lens[None, :]) & truncated,
-            axis=-1)
+            axis=-1))
         return hit, undec
 
     def _device_step_preparsed(self, serials, serial_len, nah, issuer_idx,
@@ -1560,9 +1662,12 @@ class TpuAggregator:
             counts += row[2 + nb + cap:].astype(np.int64)
 
         f_any = plan.f_ca | plan.f_expired | plan.f_cn
-        self.metrics["filtered_ca"] += int(plan.f_ca.sum())
-        self.metrics["filtered_expired"] += int(plan.f_expired.sum())
-        self.metrics["filtered_cn"] += int(plan.f_cn.sum())
+        n_cn = int(plan.f_cn.sum())
+        self._count_filtered(ca=int(plan.f_ca.sum()),
+                             expired=int(plan.f_expired.sum()), cn=n_cn)
+        self._cn_fold.passed += int(plan.cn_passed.sum())
+        self._cn_fold.dropped += n_cn
+        self._cn_fold.undecided.update(np.flatnonzero(plan.cn_undec).tolist())
         self.metrics["overflow"] += int(ovf.sum())
         self.issuer_totals[: counts.shape[0]] += counts
 
@@ -1650,9 +1755,14 @@ class TpuAggregator:
         nah, slen = P[1], P[2]
         dp_off, dp_len, in_off, in_len = P[3], P[4], P[5], P[6]
         f_any = f_ca | f_exp | f_cn
-        self.metrics["filtered_ca"] += int(f_ca.sum())
-        self.metrics["filtered_expired"] += int(f_exp.sum())
-        self.metrics["filtered_cn"] += int(f_cn.sum())
+        n_cn = int(f_cn.sum())
+        self._count_filtered(ca=int(f_ca.sum()), expired=int(f_exp.sum()),
+                             cn=n_cn)
+        self._cn_fold.dropped += n_cn
+        cn_undec = None
+        if out.cn_undecidable is not None:  # a CN filter is configured
+            cn_undec = ((flags >> 7) & 1) != 0
+            self._cn_fold.passed += int(((flags >> 8) & 1).sum())
         if dropped is not None:  # sharded path: routing-cap spill rate
             spilled = int(dropped.sum())
             self.metrics["dispatch_spill"] += spilled
@@ -1690,6 +1800,8 @@ class TpuAggregator:
             lanes = np.asarray(lane_of(pos_arr), dtype=np.int64)
         hl_l = hl[lanes]
         host_pos = [int(p) for p in pos_arr[hl_l]]
+        if cn_undec is not None:
+            self._cn_fold.undecided.update(pos_arr[cn_undec[lanes]].tolist())
         okm = ~hl_l
         f_l = f_any[lanes]
         res.filtered[pos_arr[okm]] = f_l[okm]
@@ -1761,8 +1873,11 @@ class TpuAggregator:
         plus a D2H read — per-cert probing would erode the pipelining
         the sink provides)."""
         staged = []  # (pos, fields, eh) — lanes that reached dedup
+        undecided = self._cn_fold.undecided
         for pos in host_pos:
-            fields, x = self._host_filter(der_of(pos), int(res.issuer_idx[pos]))
+            fields, x = self._host_filter(
+                der_of(pos), int(res.issuer_idx[pos]),
+                cn_undecided=pos in undecided)
             if fields is None:
                 u, f, eh, sb = x
                 res.was_unknown[pos], res.filtered[pos] = u, f
@@ -1891,17 +2006,20 @@ class TpuAggregator:
             if parsed.scheme in ("http", "https"):
                 self.crl_sets.setdefault(issuer_idx, set()).add(parsed.geturl())
 
-    def _host_filter(self, der: bytes, issuer_idx: int):
+    def _host_filter(self, der: bytes, issuer_idx: int,
+                     cn_undecided: bool = False):
         """Tolerant host parse + reference filters. Returns
         ``(fields, exp_hour)`` when the lane reaches dedup, else
-        ``(None, (was_unknown, filtered, exp_hour, serial))``."""
+        ``(None, (was_unknown, filtered, exp_hour, serial))``.
+        ``cn_undecided``: the device (or the pre-parsed lane) left this
+        lane's CN verdict to the full parse here."""
         try:
             fields = hostder.parse_cert(der)
         except Exception:
             self.metrics["parse_errors"] += 1
             return None, (False, False, 0, None)
         if fields.is_ca:
-            self.metrics["filtered_ca"] += 1
+            self._count_filtered(ca=1)
             return None, (False, True, 0, None)
         eh = fields.not_after_unix_hour
         # Exact instant compare, like the reference's NotAfter.Before(now)
@@ -1910,12 +2028,12 @@ class TpuAggregator:
         # (expiring this hour) here, so this compare is what decides it.
         now = self._fixed_now or datetime.now(timezone.utc)
         if fields.not_after < now:
-            self.metrics["filtered_expired"] += 1
+            self._count_filtered(expired=1)
             return None, (False, True, 0, None)
-        if self.cn_prefixes and not any(
-            fields.issuer_cn.startswith(p) for p in self.cn_prefixes
-        ):
-            self.metrics["filtered_cn"] += 1
+        if self.cn_prefixes and not hostder.cn_permitted(
+                fields.issuer_cn_bytes, self.cn_prefixes):
+            self._count_filtered(cn=1)
+            self._cn_fold.host_dropped += cn_undecided
             return None, (False, True, 0, None)
         return fields, eh
 

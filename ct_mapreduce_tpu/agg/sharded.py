@@ -32,7 +32,7 @@ tests and on a TPU pod slice in production.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -81,6 +81,9 @@ class ShardedStepOut(NamedTuple):
     dispatch_dropped: jax.Array  # bool[B] — lane spilled past the
     # per-(src,dst) routing cap to the exact host lane (surfaced as the
     # aggregator's `dispatch_spill` metric so routing skew is observable)
+    # bool[B] under a CN filter, None without one (pipeline.LocalLanes).
+    cn_passed: Optional[jax.Array] = None
+    cn_undecidable: Optional[jax.Array] = None
 
 
 def shard_of_np(keys: np.ndarray, n_shards: int) -> np.ndarray:
@@ -303,6 +306,8 @@ def _local_step(
             issuer_name_len=parsed.issuer_len,
             probe_overflow=probe_overflow,
             dispatch_dropped=dispatch_dropped,
+            cn_passed=lanes.cn_passed,
+            cn_undecidable=lanes.cn_undecidable,
         ),
     )
 
@@ -463,6 +468,7 @@ class ShardedDedup:
             axis=self.axis,
         )
         A = P(self.axis)
+        said = A if p else None  # the CN predicate's two, under a filter
         mapped = shard_map(
             local,
             mesh=self.mesh,
@@ -482,6 +488,7 @@ class ShardedDedup:
                     has_crldp=A, crldp_off=A, crldp_len=A,
                     issuer_name_off=A, issuer_name_len=A,
                     probe_overflow=A, dispatch_dropped=A,
+                    cn_passed=said, cn_undecidable=said,
                 ),
             ),
             check_vma=False,

@@ -163,10 +163,21 @@ def _decode_oid(content: bytes) -> str:
     return ".".join(str(p) for p in parts)
 
 
+TAG_BMP_STRING = 0x1E
+# The value types Go's encoding/asn1 hands pkix.Name as a string:
+# UTF8String, NumericString, PrintableString, T61String and IA5String
+# byte for byte (``string(bytes)``), a BMPString transcoded from UTF-16.
+# A value of any other type leaves CommonName (and SerialNumber) alone.
+RAW_STRING_TAGS = (0x0C, 0x12, 0x13, 0x14, 0x16)
+GO_STRING_TAGS = frozenset(RAW_STRING_TAGS + (TAG_BMP_STRING,))
+
+
 @dataclass
 class NameAttribute:
     oid: bytes
     value: str
+    tag: int = 0x0C  # the value's type (UTF8String where not given)
+    raw: bytes = b""  # the value's content bytes as encoded
 
 
 def parse_name(buf: bytes, off: int) -> tuple[list[list[NameAttribute]], int]:
@@ -210,10 +221,12 @@ def parse_name(buf: bytes, off: int) -> tuple[list[list[NameAttribute]], int]:
                 raise DerError("attribute value overruns its ATV frame")
             raw = bytes(buf[val_off : val_off + val_len])
             try:
-                value = raw.decode("utf-8")
+                value = raw.decode(
+                    "utf-16-be" if val_tag == TAG_BMP_STRING else "utf-8")
             except UnicodeDecodeError:
                 value = raw.decode("latin-1")
-            rdn.append(NameAttribute(oid=oid, value=value))
+            rdn.append(NameAttribute(oid=oid, value=value, tag=val_tag,
+                                     raw=raw))
             apos = seq_off + seq_len
         rdns.append(rdn)
         pos = set_end
@@ -285,15 +298,44 @@ def render_dn(rdns: list[list[NameAttribute]]) -> str:
     return ",".join(parts)
 
 
+def _common_name_attr(rdns: list[list[NameAttribute]]):
+    """The attribute Go's pkix FillFromRDNSequence leaves in CommonName:
+    the LAST CN of the whole Name, multi-valued RDNs included, whose
+    value is of a type encoding/asn1 returns as a string."""
+    found = None
+    for rdn in rdns:
+        for attr in rdn:
+            if attr.oid == OID_COMMON_NAME and attr.tag in GO_STRING_TAGS:
+                found = attr
+    return found
+
+
 def common_name(rdns: list[list[NameAttribute]]) -> str:
     """The CommonName, last occurrence winning — Go pkix
     FillFromRDNSequence overwrites CommonName per occurrence."""
-    cn = ""
-    for rdn in rdns:
-        for attr in rdn:
-            if attr.oid == OID_COMMON_NAME:
-                cn = attr.value
-    return cn
+    attr = _common_name_attr(rdns)
+    return "" if attr is None else attr.value
+
+
+def common_name_bytes(rdns: list[list[NameAttribute]]) -> bytes:
+    """The bytes of Go's ``CommonName`` string, which is what
+    ``strings.HasPrefix`` compares: the value as encoded (Go does not
+    re-decode a T61String or check a UTF8String's bytes against a
+    charset here), a BMPString as the UTF-8 of its text."""
+    attr = _common_name_attr(rdns)
+    if attr is None:
+        return b""
+    if attr.tag == TAG_BMP_STRING:
+        return attr.value.encode("utf-8", "surrogatepass")
+    return attr.raw
+
+
+def cn_permitted(issuer_cn: bytes, prefixes) -> bool:
+    """``certIsFilteredOut``'s third test turned round
+    (/root/reference/cmd/ct-fetch/ct-fetch.go:56-62): does the issuer's
+    CommonName start with one of the directive's prefixes, byte for
+    byte as Go's strings do. An empty prefix permits every name."""
+    return any(issuer_cn.startswith(p.encode("utf-8")) for p in prefixes)
 
 
 @dataclass
@@ -320,6 +362,7 @@ class CertFields:
     issuer_len: int = 0
     tbs_off: int = 0
     tbs_len: int = 0
+    issuer_cn_bytes: bytes = b""  # what the CN filter compares
 
     @property
     def not_after_unix_hour(self) -> int:
@@ -510,6 +553,7 @@ def parse_cert(der: bytes) -> CertFields:
         issuer_len=issuer_end - issuer_start,
         tbs_off=cert_off,
         tbs_len=_skip(der, cert_off) - cert_off,
+        issuer_cn_bytes=common_name_bytes(issuer_rdns),
     )
 
 

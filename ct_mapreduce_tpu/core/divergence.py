@@ -70,12 +70,11 @@ def _walker_fields_mismatch(der: bytes, out, i: int, ref) -> str | None:
     the strict host parse; returns a repro string on mismatch."""
     from ct_mapreduce_tpu.core import der as hostder
 
+    # A CN the scan left unsaid (length -1: the host lane's to read,
+    # der_kernel._scan_issuer_cn) is no field to compare.
+    unsaid = int(out.issuer_cn_len[i]) < 0
     cn_bytes = der[int(out.issuer_cn_off[i]):
                    int(out.issuer_cn_off[i]) + int(out.issuer_cn_len[i])]
-    try:  # mirror the host's utf-8-then-latin-1 decode (der.py)
-        cn_str = cn_bytes.decode("utf-8")
-    except UnicodeDecodeError:
-        cn_str = cn_bytes.decode("latin-1")
     if bool(out.has_crldp[i]):
         try:
             dev_urls = hostder._parse_crldp(der, int(out.crldp_off[i]))
@@ -91,7 +90,7 @@ def _walker_fields_mismatch(der: bytes, out, i: int, ref) -> str | None:
             or int(out.spki_len[i]) != ref.spki_len
             or int(out.issuer_off[i]) != ref.issuer_off
             or int(out.issuer_len[i]) != ref.issuer_len
-            or cn_str != ref.issuer_cn
+            or (not unsaid and cn_bytes != ref.issuer_cn_bytes)
             or bool(out.has_crldp[i]) != bool(ref.crl_distribution_points)
             or sorted(dev_urls) != sorted(ref.crl_distribution_points)):
         return (

@@ -108,9 +108,8 @@ class DatabaseSink:
         if not self.log_expired_entries and fields.not_after < now:
             metrics.incr_counter("ct-fetch", "certIsFilteredOut", "expired")
             return True
-        if self.cn_filters and not any(
-            fields.issuer_cn.startswith(p) for p in self.cn_filters
-        ):
+        if self.cn_filters and not hostder.cn_permitted(
+                fields.issuer_cn_bytes, self.cn_filters):
             metrics.incr_counter("ct-fetch", "certIsFilteredOut", "cn")
             return True
         return False

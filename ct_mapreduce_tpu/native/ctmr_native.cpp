@@ -479,7 +479,7 @@ int64_t ctmr_decode_entries(
 // deliberately: fixed byte-window limits around each merged header
 // group (reads outside a window see zeros), long-form lengths capped
 // at 3 octets, the MAX_RDNS/MAX_EXTS scan budgets, first-ATV-per-RDN /
-// first-CN-wins CN selection, day<=31 non-calendar time validation,
+// last-CN-wins CN selection and its "cannot say" (cn_len -1), day<=31 non-calendar time validation,
 // and the extnValue-overrun lane rejection. tests/test_preparsed.py
 // pins `extract == parse_certs` across the mutation fuzz.
 
@@ -598,42 +598,55 @@ struct Sidecar {
   int32_t crldp_off = 0, crldp_len = 0;
 };
 
-// _scan_issuer_cn: first CN (OID 2.5.4.3) via first-ATV-per-RDN-SET
-// rounds in an 8-word (32B) window per round; structural breaks stop
-// the scan silently (never affect the lane's ok).
+// _scan_issuer_cn: the issuer Name's CommonName (OID 2.5.4.3) as Go's
+// pkix.Name fills it (the LAST CN wins), read from the first ATV of
+// each RDN SET in an 8-word (32B) window per round; cn_len -1 where the
+// scan cannot say what Go would hold (an RDN that is not a SET of
+// exactly one well-formed ATV, a CN whose value is no byte-for-byte
+// string type or does not fill its ATV, a Name not walked to its end):
+// the CN filter then hands the lane to the exact host lane. Never
+// affects the lane's ok.
+inline bool raw_string_tag(int64_t t) {
+  return t == 0x0C || t == 0x12 || t == 0x13 || t == 0x14 || t == 0x16;
+}
+
 inline void scan_issuer_cn(const Row& r, int64_t off, int64_t end,
                            bool alive0, Sidecar* s) {
   constexpr int W = 32;
   int64_t p = off, cn_off = 0, cn_len = 0;
   int cnt = 0;
-  bool alive = alive0;
+  bool alive = alive0, undec = false;
   while (alive && p < end && cnt < kMaxRdns) {
     int64_t a = (p < 0 ? 0 : p) & 3;
     Hdr set = read_header(r, p, 0, end, W);
-    bool set_ok = set.ok && set.tag == 0x31;
     int64_t da = set.hlen;
     Hdr atv = read_header(r, p, da, end, W);
     int64_t dro = da + atv.hlen;
     Hdr oid = read_header(r, p, dro, end, W);
+    bool plain = set.ok && set.tag == 0x31 && atv.ok && atv.tag == 0x30
+        && set.clen == atv.hlen + atv.clen && oid.ok && oid.tag == 0x06;
     int64_t ro = a + dro + oid.hlen;
-    bool is_cn = set_ok && atv.ok && atv.tag == 0x30 && oid.ok
-        && oid.tag == 0x06 && oid.clen == 3
+    bool is_cn = plain && oid.clen == 3
         && r.wbyte(p, ro, W) == 0x55 && r.wbyte(p, ro + 1, W) == 0x04
         && r.wbyte(p, ro + 2, W) == 0x03;
     int64_t dv = dro + oid.hlen + oid.clen;
     Hdr val = read_header(r, p, dv, end, W);
-    if (is_cn && val.ok && cn_len == 0) {
+    bool good = val.ok && raw_string_tag(val.tag)
+        && oid.hlen + oid.clen + val.hlen + val.clen == atv.clen;
+    if (is_cn && good) {
       cn_off = p + dv + val.hlen;
       cn_len = val.clen;
     }
+    undec = undec || !plain || (is_cn && !good);
     if (set.ok) {
       p += set.hlen + set.clen;
       ++cnt;
     }
     alive = alive && set.ok;
   }
-  s->cn_off = (int32_t)cn_off;
-  s->cn_len = (int32_t)cn_len;
+  undec = undec || (alive0 && p != end);
+  s->cn_off = undec ? 0 : (int32_t)cn_off;
+  s->cn_len = undec ? -1 : (int32_t)cn_len;
 }
 
 // _scan_extensions + _ext_round: BasicConstraints CA + CRLDP windows,

@@ -11,8 +11,9 @@ map stage actually consumes:
 - BasicConstraints CA flag and CRL-distribution-points presence
   (filter + metadata triggers, /root/reference/cmd/ct-fetch/ct-fetch.go:47-50,
   /root/reference/storage/issuermetadata.go:92-138),
-- first CommonName of the issuer DN (the CN-prefix filter,
-  /root/reference/cmd/ct-fetch/ct-fetch.go:56-62),
+- the issuer DN's CommonName as Go's pkix.Name fills it, or "cannot
+  say" (the CN-prefix filter,
+  /root/reference/cmd/ct-fetch/ct-fetch.go:56-62; _scan_issuer_cn),
 - SPKI TLV offset/length (issuer identity when a lane's cert is used
   as an issuer).
 
@@ -60,6 +61,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ct_mapreduce_tpu.core.der import RAW_STRING_TAGS
+
 MAX_RDNS = 12  # RDN components scanned in the issuer Name
 MAX_EXTS = 24  # extensions scanned in the TBS
 
@@ -90,7 +93,8 @@ class ParsedCerts(NamedTuple):
     is_ca: jax.Array  # bool
     has_crldp: jax.Array  # bool
     issuer_cn_off: jax.Array
-    issuer_cn_len: jax.Array  # 0 ⇒ no CN present
+    issuer_cn_len: jax.Array  # 0 ⇒ no CN present; -1 ⇒ the scan cannot
+    # say what Go's pkix.Name holds (see _scan_issuer_cn): host lane
     issuer_off: jax.Array  # full issuer Name TLV (host DN-cache key)
     issuer_len: jax.Array
     spki_off: jax.Array  # offset of the full SPKI TLV
@@ -395,15 +399,33 @@ def _parse_time_w(win, a, delta, p):
 
 
 def _scan_issuer_cn(rows: _Rows, name_off, name_end, hdr_ok0):
-    """First CN (OID 2.5.4.3) value inside the issuer Name.
+    """The CommonName (OID 2.5.4.3) of the issuer Name as Go's
+    ``pkix.Name.FillFromRDNSequence`` fills ``cert.Issuer.CommonName``
+    (the LAST CN attribute of the whole Name wins), or "cannot say".
 
     Name ::= SEQUENCE OF RelativeDistinguishedName;
     RDN ::= SET OF AttributeTypeAndValue;
     ATV ::= SEQUENCE { type OID, value ANY }.
-    Returns (cn_off, cn_len) with len 0 when absent. Runs as a
-    superblock loop (see _scan_extensions): one row pass fetches each
-    lane 512 bytes; a typical issuer Name (3–6 RDNs, tens of bytes)
-    scans in a single fetch.
+    Returns ``(cn_off, cn_len)``: the value's content window, ``cn_len``
+    0 when the Name has no CN (Go's ``""``), and ``cn_len`` -1 when the
+    scan cannot say what Go would hold, so that the CN filter hands the
+    lane to the exact host lane (``pipeline.local_lanes``: ``cn_undec``)
+    instead of deciding it. The scan reads the FIRST attribute of each
+    RDN and nothing behind it, so it says "cannot say" for:
+
+    - an RDN that is not a SET of exactly one well-formed ATV (a
+      multi-valued RDN may hold a CN behind its first attribute; a
+      malformed one is the host parse's to reject);
+    - a CN whose value is not of a type Go returns byte for byte
+      (``core.der.RAW_STRING_TAGS``) or does not fill its ATV exactly;
+    - a Name the scan did not walk to its end (more than ``MAX_RDNS``
+      RDNs, or a header that does not parse).
+
+    Every other Name is decided as Go decides it: two CN attributes give
+    the second, an empty CN value gives ``""``. Runs as a superblock
+    loop (see _scan_extensions): one row pass fetches each lane 512
+    bytes; a typical issuer Name (3-6 RDNs, tens of bytes) scans in a
+    single fetch.
     """
     b = name_off.shape[0]
     zero = jnp.zeros((b,), jnp.int32)
@@ -411,44 +433,52 @@ def _scan_issuer_cn(rows: _Rows, name_off, name_end, hdr_ok0):
     stride = (supw - 8 - _BLOCK_WORDS) * 4
     outer_max = -(-(rows.n_words * 4) // stride) + 1
 
-    def rdn_round(win, a, p, cn_off, cn_len, alive, cnt, active):
+    def rdn_round(win, a, p, cn_off, cn_len, undec, alive, cnt, active):
         d0 = jnp.zeros_like(p)
         tag, clen, hlen, hok = _read_header_w(win, a, d0, p, name_end)
-        set_ok = active & hok & (tag == 0x31)
-        # Only the first ATV of each RDN SET is examined (multi-valued
-        # RDNs are vanishingly rare; such lanes simply find no CN here,
-        # and the CN filter then falls back to the host lane decision).
         da = hlen
         atag, aclen, ahlen, aok = _read_header_w(win, a, da, p, name_end)
         do = da + ahlen
         otag, oclen, ohlen, ook = _read_header_w(win, a, do, p, name_end)
+        # One ATV that fills its SET, with an OID first: anything else
+        # is not this scan's to read.
+        plain = (
+            hok & (tag == 0x31) & aok & (atag == 0x30)
+            & (clen == ahlen + aclen) & ook & (otag == 0x06)
+        )
         ro = a + do + ohlen
         is_cn = (
-            set_ok & aok & (atag == 0x30) & ook & (otag == 0x06) & (oclen == 3)
+            active & plain & (oclen == 3)
             & (_wbyte(win, ro) == 0x55)
             & (_wbyte(win, ro + 1) == 0x04)
             & (_wbyte(win, ro + 2) == 0x03)
         )
         dv = do + ohlen + oclen
         vtag, vclen, vhlen, vok = _read_header_w(win, a, dv, p, name_end)
-        take = is_cn & vok & (cn_len == 0)
+        # Content bytes that ARE Go's string; a BMPString is transcoded
+        # and any other type leaves CommonName alone: not ours to compare.
+        raw = functools.reduce(
+            jnp.logical_or, [vtag == t for t in RAW_STRING_TAGS])
+        good = vok & raw & (ohlen + oclen + vhlen + vclen == aclen)
+        take = is_cn & good
         cn_off = jnp.where(take, p + dv + vhlen, cn_off)
         cn_len = jnp.where(take, vclen, cn_len)
+        undec = undec | (active & ~plain) | (is_cn & ~good)
         p = jnp.where(active & hok, p + hlen + clen, p)
         cnt = cnt + (active & hok).astype(jnp.int32)
         alive = alive & jnp.where(active, hok, True)
-        return p, cn_off, cn_len, alive, cnt
+        return p, cn_off, cn_len, undec, alive, cnt
 
     # Superblock loops (see _scan_extensions — same structure, same
     # window bytes per round as the old one-row-pass-per-RDN loop):
     # one row pass fetches each lane 512 bytes; RDNs are a few tens of
     # bytes, so a typical issuer Name scans in ONE fetch.
     def outer_cond(carry):
-        r_out, _p, _co, _cl, _alive, _cnt, live = carry
+        r_out, _p, _co, _cl, _un, _alive, _cnt, live = carry
         return (r_out < outer_max) & jnp.any(live)
 
     def outer_body(carry):
-        r_out, p, cn_off, cn_len, alive, cnt, live = carry
+        r_out, p, cn_off, cn_len, undec, alive, cnt, live = carry
         bi0 = p >> (2 + 4)
         sup = _sup_fetch(rows, bi0)
 
@@ -456,31 +486,35 @@ def _scan_issuer_cn(rows: _Rows, name_off, name_end, hdr_ok0):
             return jnp.any(c[-1])
 
         def inner_body(c):
-            p, cn_off, cn_len, alive, cnt, go = c
+            p, cn_off, cn_len, undec, alive, cnt, go = c
             win, a = _sup_window(sup, p, bi0, 8)
-            p, cn_off, cn_len, alive, cnt = rdn_round(
-                win, a, p, cn_off, cn_len, alive, cnt, go
+            p, cn_off, cn_len, undec, alive, cnt = rdn_round(
+                win, a, p, cn_off, cn_len, undec, alive, cnt, go
             )
             wloc = (p >> 2) - bi0 * _BLOCK_WORDS
             go = (alive & (p < name_end) & (cnt < MAX_RDNS)
                   & (wloc <= supw - 8))
-            return p, cn_off, cn_len, alive, cnt, go
+            return p, cn_off, cn_len, undec, alive, cnt, go
 
         # `live` doubles as the first round's go: a lane freshly
         # anchored at bi0 = p >> 6 always has wloc0 in [0, 16), so the
         # fit guard is trivially true.
-        p, cn_off, cn_len, alive, cnt, _go = jax.lax.while_loop(
-            inner_cond, inner_body, (p, cn_off, cn_len, alive, cnt, live)
+        p, cn_off, cn_len, undec, alive, cnt, _go = jax.lax.while_loop(
+            inner_cond, inner_body,
+            (p, cn_off, cn_len, undec, alive, cnt, live)
         )
         live = alive & (p < name_end) & (cnt < MAX_RDNS)
-        return r_out + 1, p, cn_off, cn_len, alive, cnt, live
+        return r_out + 1, p, cn_off, cn_len, undec, alive, cnt, live
 
     live0 = hdr_ok0 & (name_off < name_end)
-    (_r, _p, cn_off, cn_len, _alive, _cnt, _live) = jax.lax.while_loop(
+    (_r, p, cn_off, cn_len, undec, _alive, _cnt, _live) = jax.lax.while_loop(
         outer_cond, outer_body,
-        (jnp.int32(0), name_off, zero, zero, hdr_ok0, zero, live0),
+        (jnp.int32(0), name_off, zero, zero, jnp.zeros((b,), bool),
+         hdr_ok0, zero, live0),
     )
-    return cn_off, cn_len
+    # A Name not walked to its end may hold a CN the scan never saw.
+    undec = undec | (hdr_ok0 & (p != name_end))
+    return jnp.where(undec, 0, cn_off), jnp.where(undec, -1, cn_len)
 
 
 def _scan_extensions(rows: _Rows, ext_off, ext_end, alive0):

@@ -20,7 +20,7 @@ entries (/root/reference/cmd/ct-fetch/ct-fetch.go:206-225).
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +86,9 @@ class StepOut(NamedTuple):
     issuer_name_len: jax.Array  # int32[B]
     probe_overflow: jax.Array  # bool[B] — insert exhausted its probe
     # chain (spills to the exact host lane; `overflow` metric)
+    # bool[B] under a CN filter, None without one (LocalLanes has both).
+    cn_passed: Optional[jax.Array] = None
+    cn_undecidable: Optional[jax.Array] = None
 
 
 def fingerprints(
@@ -132,37 +135,49 @@ def _cn_prefix_match(
 ) -> tuple[jax.Array, jax.Array]:
     """Does the issuer CN start with any configured prefix?
 
-    prefixes: uint8[P, K] (first K bytes of each prefix, K ≤
-    der_kernel.MAX_FIXED_WINDOW_BYTES); prefix_lens: int32[P, 2] —
-    column 0 the device-comparable length (= min(len, K)), column 1
-    the TRUE configured length. P == 0 handled by the caller (filter
-    disabled). ``rows`` are the shared word-packed rows
+    prefixes: uint8[P, K], the first K bytes of each prefix; prefix_lens:
+    int32[P, 2]: column 0 the device-comparable length (= min(len, K)),
+    column 1 the TRUE configured length, both -1 on a DEAD row, which
+    matches nothing. ``TpuAggregator.set_cn_prefixes`` builds both at
+    one shape whatever the directive says (P a fixed number of rows, K =
+    der_kernel.MAX_FIXED_WINDOW_BYTES, unused rows dead), so that the
+    step is one program under every filter. A live row of length 0 (an
+    empty element of the directive) matches every name, as
+    ``strings.HasPrefix(name, "")`` does. P == 0 is handled by the
+    caller (filter disabled). ``rows`` are the shared word-packed rows
     (:func:`der_kernel.window_bytes_rows` — gather-free).
 
     Returns ``(hit, undecidable)`` bool[B]: ``hit`` = definitely
-    matches some prefix; ``undecidable`` = matches the K-byte head of
-    a LONGER-than-K prefix and is long enough that the tail could
-    match — the device cannot decide, so the lane must take the exact
-    host lane (the reference compares full prefixes,
+    matches some prefix; ``undecidable`` = no hit, and either the name
+    matches the K-byte head of a LONGER-than-K prefix and is long
+    enough that the tail could match, or the scan could not say what
+    the CommonName is (``cn_len`` -1, :func:`der_kernel._scan_issuer_cn`).
+    The device cannot decide such a lane, so it takes the exact host
+    lane (the reference compares full prefixes with the full name,
     /root/reference/cmd/ct-fetch/ct-fetch.go:56-62).
     """
     k = prefixes.shape[1]
+    unsaid = cn_len < 0
+    cn_len = jnp.maximum(cn_len, 0)
     window = der_kernel.window_bytes_rows(rows, cn_off, k).astype(jnp.uint8)
     inside = jnp.arange(k, dtype=jnp.int32)[None, :] < cn_len[:, None]
     window = jnp.where(inside, window, 0)
     dev_lens = prefix_lens[:, 0]
     true_lens = prefix_lens[:, 1]
+    live = (dev_lens >= 0)[None, :]
     # [B, P, K] compare, masked beyond each prefix's device length
     eq = window[:, None, :] == prefixes[None, :, :]
     care = jnp.arange(k, dtype=jnp.int32)[None, None, :] < dev_lens[None, :, None]
-    full = jnp.all(eq | ~care, axis=-1)  # [B, P]
+    full = jnp.all(eq | ~care, axis=-1) & live  # [B, P]
     truncated = (true_lens > dev_lens)[None, :]
+    # An unsaid name compares as the empty one: only an empty prefix,
+    # which every name starts with, hits it.
     hit = jnp.any(
         full & (cn_len[:, None] >= dev_lens[None, :]) & ~truncated, axis=-1
     )
-    undecidable = jnp.any(
+    undecidable = ~hit & (unsaid | jnp.any(
         full & (cn_len[:, None] >= true_lens[None, :]) & truncated, axis=-1
-    )
+    ))
     return hit, undecidable
 
 
@@ -174,6 +189,10 @@ class LocalLanes(NamedTuple):
     filtered_ca: jax.Array
     filtered_expired: jax.Array
     filtered_cn: jax.Array
+    # What the CN predicate said of the lanes that reached it; None
+    # where no filter is configured (the trace is then what it was).
+    cn_passed: Optional[jax.Array]  # the name starts with a prefix
+    cn_undecidable: Optional[jax.Array]  # the host lane's to decide
     passed: jax.Array  # survived all filters
     device_exact: jax.Array  # serial/meta/issuer fit the device schema
     insertable: jax.Array  # passed & device_exact
@@ -220,12 +239,16 @@ def local_lanes(
             cn_prefixes, cn_prefix_lens,
         )
         # A lane matching only the truncated head of an over-long
-        # prefix is NOT filtered here — it routes to the exact host
-        # lane below (device_exact), where full prefixes decide.
-        cn_undec = ok & ~f_ca & ~f_expired & ~cn_hit & cn_undec
-        f_cn = ok & ~f_ca & ~f_expired & ~cn_hit & ~cn_undec
+        # prefix, or whose Name the scan could not read as Go does, is
+        # NOT filtered here — it routes to the exact host lane below
+        # (device_exact), where the full parse and full prefixes decide.
+        reached = ok & ~f_ca & ~f_expired
+        cn_passed = reached & cn_hit
+        cn_undec = reached & ~cn_hit & cn_undec
+        f_cn = reached & ~cn_hit & ~cn_undec
     else:
         f_cn = cn_undec = jnp.zeros_like(ok)
+        cn_passed = None
     passed = ok & ~f_ca & ~f_expired & ~f_cn
 
     # Device-exactness gate: lanes outside the packed schema go host-side.
@@ -254,6 +277,8 @@ def local_lanes(
         filtered_ca=f_ca,
         filtered_expired=f_expired,
         filtered_cn=f_cn,
+        cn_passed=cn_passed,
+        cn_undecidable=None if cn_passed is None else cn_undec,
         passed=passed,
         device_exact=device_exact,
         insertable=passed & device_exact,
@@ -284,8 +309,11 @@ def ingest_core(
         reference filters ``NotAfter.Before(now)``).
       base_hour: scalar int32 — meta-word epoch base.
       cn_prefixes/cn_prefix_lens: uint8[P, K]/int32[P, 2]
-        (device-comparable length, true length); P == 0 disables
-        the CN filter (shape is static ⇒ config changes recompile once).
+        (device-comparable length, true length; -1 on a dead row);
+        P == 0 disables the CN filter. The shapes are static, and
+        ``TpuAggregator.set_cn_prefixes`` gives every filter the same
+        ones: the step has two programs a batch shape in all, filter
+        off and filter on, whatever the directive says.
     """
     lanes = local_lanes(
         data, length, issuer_idx, valid, now_hour, base_hour,
@@ -322,6 +350,8 @@ def ingest_core(
         crldp_len=parsed.crldp_len,
         issuer_name_off=parsed.issuer_off,
         issuer_name_len=parsed.issuer_len,
+        cn_passed=lanes.cn_passed,
+        cn_undecidable=lanes.cn_undecidable,
     )
 
 

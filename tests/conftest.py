@@ -34,9 +34,6 @@ def compile_cache_env(path) -> dict:
     }
 
 
-import json  # noqa: E402
-import sys  # noqa: E402
-
 import pytest  # noqa: E402
 
 # Lock-order witness across the WHOLE suite (round 16): every
@@ -91,93 +88,6 @@ def benchmark_checkout(tmp_path, monkeypatch, request):
     monkeypatch.setenv("XLA_FLAGS", " ".join(
         f for f in os.environ.get("XLA_FLAGS", "").split()
         if "xla_force_host_platform_device_count" not in f))
-
-
-def _first_shared_metric(per_layer: list) -> int:
-    """Where the metrics of several cells begin in ``BENCHMARK.json``'s
-    ``per_layer``: every cell's own block stands before (PR 37 listed
-    the first of them; what PR 38 lists after it, two of one cell
-    among them, is no cell's own block either)."""
-    return next(i for i, m in enumerate(per_layer)
-                if len(m.get("workloads", ())) > 1)
-
-
-@pytest.fixture
-def own_blocks_only(monkeypatch, request):
-    """For the same modules: their tests of what a cell lists read
-    ``BENCHMARK.json`` whole (every metric that lists the cell alone is
-    the cell's; the cell's block ends the list). Theirs then see the
-    list as it stood before the first metric of several cells."""
-    theirs = request.module.theirs
-    whole = theirs.bench_json()
-    cut = _first_shared_metric(whole["per_layer"])
-    then = dict(whole, per_layer=whole["per_layer"][:cut])
-    monkeypatch.setattr(theirs, "bench_json", lambda: then)
-
-
-@pytest.fixture
-def shared_metrics_aside(monkeypatch, request):
-    """For the same modules: their per-cell tests hold a traced line to
-    the metrics of that cell's own block, and a PR that lists a metric
-    after the blocks may edit no file under ``benchmark/`` (ROADMAP
-    R0). Their ``rehearse_cell`` hands over the line without the
-    metrics listed from the first of several cells on, which land here
-    by name for the wrapper to judge. A cell whose own block stands
-    after them (``backfill-3log-shard4``'s, which its module names as
-    ``HOST_METRICS`` and ``DEVICE_METRICS``) keeps that block in the
-    line."""
-    theirs = request.module.theirs
-    listed = theirs.bench_json()["per_layer"]
-    own = {*getattr(theirs, "HOST_METRICS", ()),
-           *getattr(theirs, "DEVICE_METRICS", ())}
-    shared = [m["name"] for m in listed[_first_shared_metric(listed):]
-              if m["name"] not in own]
-    aside: dict = {}
-    rehearse = theirs.rehearse_cell
-
-    def rehearse_cell(*args):
-        lines = rehearse(*args)
-        for line in lines:
-            if isinstance(line, list):
-                aside.update({name: line[0].pop(name)["value"]
-                              for name in shared if name in line[0]})
-        return lines
-
-    monkeypatch.setattr(theirs, "rehearse_cell", rehearse_cell)
-    return aside
-
-
-GROWING_CELL = "backfill-1log-growing"  # PR 48; its own tests find it by name
-
-
-@pytest.fixture(autouse=True)
-def listed_before_the_growing_cell(monkeypatch, request):
-    """For the same modules (each names what it imported from
-    ``benchmark/tests`` ``theirs``): the per-cell tests written before
-    PR 48 hold places in ``BENCHMARK.json`` ("the cell's block ends the
-    list", ``== 106``, ``[-3:]``), as do their wrappers here, and read
-    the file through ``benchmark/tests/listing.py``, which takes out the
-    cells its ``LATER_CELLS`` names; a PR that adds a cell may edit no
-    file under ``benchmark/`` (ROADMAP R0k). So ``listing.bench_json``
-    takes this cell out too (its entry, its configuration and the
-    metrics that list it alone), and ``test_loaded_cell.py``'s
-    ``whole_bench``, which reads the file itself, gives the list as it
-    stood when that cell ended it."""
-    theirs = getattr(request.module, "theirs", None)
-    listing = sys.modules.get("listing")
-    if theirs is None or listing is None:
-        return
-    later = tuple(listing.LATER_CELLS) + (GROWING_CELL,)
-
-    def bench_json(root: str) -> dict:
-        with open(os.path.join(root, "BENCHMARK.json")) as fh:
-            return listing.before(json.load(fh), later=later)
-
-    monkeypatch.setattr(listing, "bench_json", bench_json)
-    if hasattr(theirs, "whole_bench"):
-        whole = theirs.whole_bench
-        monkeypatch.setattr(theirs, "whole_bench", lambda: listing.before(
-            whole(), later=(GROWING_CELL,)))
 
 
 def on_tpu() -> bool:

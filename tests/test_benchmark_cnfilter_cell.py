@@ -1,0 +1,19 @@
+"""The tests of the cell ``backfill-1log-cnfilter``
+(``benchmark/tests/test_cnfilter_cell.py``) as tier-1 tests; see
+``test_benchmark_harness.py``. Four of them are whole rehearsals of the
+committed cell at a tiny table (the sound run and its three controls);
+theirs find the cell by name, so they read ``BENCHMARK.json`` whole.
+"""
+
+import os
+import sys
+
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmark.tests import test_cnfilter_cell as theirs  # noqa: E402,F401
+from benchmark.tests.test_cnfilter_cell import *  # noqa: E402,F401,F403
+
+pytestmark = [pytest.mark.timeout(300),
+              pytest.mark.usefixtures("benchmark_checkout")]

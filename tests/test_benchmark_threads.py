@@ -47,67 +47,6 @@ POOL_REQUESTS = {
     "workloads": ["backfill-1log-query", "backfill-3log-query-shard4"]}
 
 
-@pytest.fixture(autouse=True)
-def listed_up_to_the_seven(monkeypatch):
-    """Theirs hold PR 38's seven to the END of ``per_layer`` (``[-7:]``),
-    and a PR that lists a metric after them may edit no file under
-    ``benchmark/`` (ROADMAP R0): theirs see the list as it stood when
-    the seven ended it; what came after is the fixture's value."""
-    whole = theirs.bench_json()
-    names = [m["name"] for m in whole["per_layer"]]
-    cut = names.index(theirs.GIL_METRICS[-1]) + 1
-    then = dict(whole, per_layer=whole["per_layer"][:cut])
-    monkeypatch.setattr(theirs, "bench_json", lambda: then)
-    return whole["per_layer"][cut:]
-
-
-def test_the_seven_stand_at_the_end_of_the_list(  # noqa: F811
-        listed_up_to_the_seven):
-    """Theirs, and after the seven: ``decode.pages_walked`` (PR 39), one
-    entry for all three cells, read by the reader of
-    ``fold.meta_fallback_lanes``; then the four-chip cell's own block
-    (PR 40), which lists no other cell and was listed by none; then
-    ``ckpt.unpacked_saves`` (PR 42), the 85th and the first to list all
-    four cells, read by the same reader; then the second four-chip
-    cell's own eighteen (PR 43), which list it alone; then
-    ``fp.fallback_lanes`` (PR 44), the 104th, which lists the two query
-    cells and is read by the same reader again; then PR 45's two: the
-    front's CPU a request in the mesh cell, which is the seventh of the
-    seven under that cell's name (its file is that one's, byte for
-    byte), and ``front.pool_requests``, the 106th, the same reader
-    over both query cells."""
-    theirs.test_the_seven_stand_at_the_end_of_the_list()
-    assert listed_up_to_the_seven[0] == PAGES_WALKED
-    assert all(m["workloads"] == ["backfill-3log-shard4"]
-               and m["name"].startswith("shard4.")
-               for m in listed_up_to_the_seven[1:-22])
-    assert listed_up_to_the_seven[-22] == UNPACKED_SAVES
-    assert all(m["workloads"] == ["backfill-3log-query-shard4"]
-               and m["name"].startswith("qshard4.")
-               for m in listed_up_to_the_seven[-21:-3])
-    assert listed_up_to_the_seven[-3:] == [FP_FALLBACK, FRONT_CPU_MESH,
-                                           POOL_REQUESTS]
-    assert len(theirs.bench_json()["per_layer"]) \
-        + len(listed_up_to_the_seven) == 106
-    here = os.path.join(theirs.BENCH, "layers")
-    with open(os.path.join(here, "front.cpu_ms_per_request.json"), "rb") as a, \
-            open(os.path.join(
-                here, "qshard4.front_cpu_ms_per_request.json"), "rb") as b:
-        assert a.read() == b.read()
-    assert theirs.layer_file("front.pool_requests") == {
-        "reader": "counter_sum",
-        "params": {"key": "front.pool_requests", "phase": "round"}}
-    assert theirs.layer_file("decode.pages_walked") == {
-        "reader": "counter_sum",
-        "params": {"key": "decode.pages_walked", "phase": "round"}}
-    assert theirs.layer_file("ckpt.unpacked_saves") == {
-        "reader": "counter_sum",
-        "params": {"key": "ckpt.base_unpacked", "phase": "round"}}
-    assert theirs.layer_file("fp.fallback_lanes") == {
-        "reader": "counter_sum",
-        "params": {"key": "fp.fallback_lanes", "phase": "round"}}
-
-
 @pytest.mark.parametrize("increments, want", [
     ([], "ABSENT"),  # the parent: a program that does not count them
     ([0.0, 0.0, 0.0], 0.0),  # every chunk went over as a page table
@@ -217,8 +156,8 @@ def test_the_mesh_cells_front_cpu_reads_what_the_one_chip_cells_does():
     thread lived for are the same span to the reader: ``tdur`` summed
     over the connections that end in the window, by their ``requests``."""
     ctx = theirs.recorded()
-    one_chip = theirs.bench_json()["per_layer"][-1]
-    assert one_chip["name"] == "front.cpu_ms_per_request"
+    one_chip = next(m for m in theirs.bench_json()["per_layer"]
+                    if m["name"] == "front.cpu_ms_per_request")
     mesh, absent = theirs.layers.read_metrics(
         [FRONT_CPU_MESH], "backfill-3log-query-shard4", ctx)
     flat, _ = theirs.layers.read_metrics(

@@ -319,8 +319,9 @@ def batch_table(events: list[dict]) -> list[dict]:
     (``native.decode_batch`` as self time: what its three children
     leave, the row allocation), with the native call's ``threads`` and
     ``pad``; ``cpu:<name>`` / ``off:<name>`` for ``BATCH_CPU_COLUMNS``
-    (milliseconds on a core and off one), and ``gil``: what the batch's
-    native calls waited for the GIL on their return."""
+    (milliseconds on a core and off one), ``gil``: what the batch's
+    native calls waited for the GIL on their return, and under
+    ``issuerCNFilter`` ``cn_drop``: the lanes the CN test dropped."""
     spans = complete_spans(events)
     selfs = self_us(spans)
     rows: dict[int, dict] = {}
@@ -340,6 +341,8 @@ def batch_table(events: list[dict]) -> list[dict]:
         row[e["name"]] = row.get(e["name"], 0.0) + ms
         if e["name"] == "decode.native_call":
             row["threads"], row["pad"] = args["threads"], args["pad"]
+        if "filtered_cn" in args:  # device.fold under issuerCNFilter
+            row["cn_drop"] = row.get("cn_drop", 0) + args["filtered_cn"]
     return [rows[b] for b in sorted(rows)]
 
 
@@ -457,17 +460,21 @@ def main(argv=None) -> int:
         return 0 if lines else 1
     if args.batches:
         split = [k + c for c in BATCH_CPU_COLUMNS for k in ("cpu:", "off:")]
+        table = batch_table(events)
+        filtered = any("cn_drop" in row for row in table)
         print(f"{'batch':>5} {'t_s':>8} {'thr':>3} {'pad':>5} "
               + " ".join(f"{c.split('.')[1][:11]:>11}" for c in BATCH_COLUMNS)
               + " " + " ".join(f"{c[:4] + c.split('.')[1][:6]:>10}"
-                               for c in split) + f" {'gil':>7}")
-        for row in batch_table(events):
+                               for c in split) + f" {'gil':>7}"
+              + (f" {'cn_drop':>7}" if filtered else ""))
+        for row in table:
             print(f"{row['batch']:>5} {row['t_s']:>8.2f} "
                   f"{row.get('threads', 0):>3} {row.get('pad', 0):>5} "
                   + " ".join(f"{row.get(c, 0.0):>11.1f}"
                              for c in BATCH_COLUMNS)
                   + " " + " ".join(f"{row.get(c, 0.0):>10.1f}" for c in split)
-                  + f" {row.get('gil', 0.0):>7.2f}")
+                  + f" {row.get('gil', 0.0):>7.2f}"
+                  + (f" {row.get('cn_drop', 0):>7}" if filtered else ""))
         return 0
     summary = stage_summary(events, stages=stages)
     wall = summary.pop("_wall_s")
